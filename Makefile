@@ -3,7 +3,7 @@
 PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: all test test-fast lint lint-json lint-changed lint-sarif lint-update-baseline ci-static bench bench-all bench-fused bench-mesh bench-hostprof bench-trend bench-paced bench-replicas drill eval native proto run-risk run-wallet dryrun clean soak soak-wire soak-chaos soak-fleet-chaos soak-chaos-ledger soak-slo soak-online soak-drift soak-session soak-deadline replay-verify fleet api-test migrate-up migrate-down migrate-status seed docker-build docker-push infra-up infra-down
+.PHONY: all test test-fast chip-smoke lint lint-json lint-changed lint-sarif lint-update-baseline ci-static bench bench-all bench-fused bench-mesh bench-hostprof bench-trend bench-paced bench-replicas drill eval native proto run-risk run-wallet dryrun clean soak soak-wire soak-chaos soak-fleet-chaos soak-chaos-ledger soak-slo soak-online soak-drift soak-session soak-deadline replay-verify fleet api-test migrate-up migrate-down migrate-status seed docker-build docker-push infra-up infra-down
 
 all: native test
 
@@ -13,6 +13,13 @@ test:
 
 test-fast:
 	$(PY) -m pytest tests/ -x -q -p no:cacheprovider
+
+# The quickest proof the system still starts on the chip: one process,
+# the real server over gRPC at the flagship width, trainer hot-swap,
+# every Pallas kernel, the data=4 mesh when four devices are visible.
+# Needs a TPU; exits non-zero without one (never a CPU fallback).
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # In-tree static analyzer (no linter ships in this image): rule engine
 # with JAX hot-path (JX*), lock-discipline (CC*), metrics/measurement
@@ -47,11 +54,14 @@ ci-static:
 	$(PY) -m tools.analysis --format=sarif > analysis.sarif
 	$(PY) tools/benchtrend.py --gate
 
-# Headline benchmark (driver contract: one JSON line) — real device.
+# Headline benchmark (driver contract: one JSON line). Needs a TPU
+# (or an explicit JAX_PLATFORMS=cpu rig); a failed arm is the exit code.
 bench:
 	$(PY) bench.py
 
-# The full benchmark matrix (five BASELINE configs + wallet pipeline).
+# The full benchmark matrix (five BASELINE configs + wallet pipeline):
+# one child process per config, the parent never touches JAX, a failed
+# child fails the run.
 bench-all:
 	$(PY) benchmarks/run_all.py
 
@@ -207,6 +217,7 @@ seed:
 # Model quality on labeled synthetic fraud: trains multitask + GBDT and
 # writes EVAL.json (AUC / PR / calibration; trained > mock > rules).
 # The model-validate capability of the reference Makefile:215-225.
+# Quality, not speed: the committed file is a JAX_PLATFORMS=cpu run.
 eval:
 	$(PY) -m igaming_platform_tpu.train.eval --out EVAL.json
 
@@ -256,5 +267,5 @@ dryrun:
 	$(CPU_ENV) $(PY) __graft_entry__.py
 
 clean:
-	rm -rf native/lib .pytest_cache
+	rm -rf native/lib .pytest_cache .jax_cache chiprun_out
 	find . -name __pycache__ -type d -exec rm -rf {} +

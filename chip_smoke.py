@@ -1,0 +1,822 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the risk.v1 scoring path once, through the entry points a user
+calls, at the full width of the flagship model (the multitask ensemble at
+``DEFAULT_TRUNK``, serving shape ``batch_size=8192``), in ONE process that
+never starts a child needing the chip:
+
+    environment -> native -> server (one chip) -> trainer -> kernels
+                -> mesh (>=4 devices) -> cache
+
+Each phase is a plain function returning a report dict and raising on any
+failed check — there is no ``try/except`` that turns a failure into a
+label. ``__main__`` demands a TPU: under ``JAX_PLATFORMS=cpu``, on a
+machine with no chip, or in a directory without the rest of the repo it
+exits non-zero and prints no result. Tests call the phases at a tiny size
+on the CPU (passing ``interpret=True`` themselves).
+
+Last line of stdout on success, the device as JAX reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The full per-phase report goes to ``chiprun_out/chip_smoke.json`` under
+the working directory.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+import os
+import shutil
+import socket
+import sys
+import time
+import urllib.request
+import warnings
+from unittest import mock
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+# Without the rest of the repo beside it this import fails, and the
+# script exits non-zero having printed nothing — as it must.
+from igaming_platform_tpu.core.devices import (  # noqa: E402
+    device_label as device_stamp,
+    enable_persistent_compile_cache,
+)
+
+# Bounds the repo already states (train/device_parity.py, the host-tier
+# note in serve/scorer.py): chip-vs-CPU fraud probability within 1e-2,
+# integer score within +-1 (same action wherever the score agrees).
+PROB_BOUND = 1e-2
+# Pallas kernels multiply in bf16 on the MXU, as the XLA einsum they
+# replace does at default precision; the reference here is the einsum at
+# HIGHEST precision, so the bound is the bf16-product envelope measured
+# on a v5e (forward <= 5e-3, backward <= 1.2e-2 on O(1) values).
+ATTN_FWD_TOL = 2e-2
+ATTN_BWD_TOL = 4e-2
+GBDT_TOL = 1e-5
+
+
+def check(cond: bool, message: str) -> None:
+    """A failed check fails the phase (``assert`` would vanish under -O)."""
+    if not cond:
+        raise RuntimeError(f"chip_smoke check failed: {message}")
+
+
+# ---------------------------------------------------------------------------
+# Phase: environment
+
+
+def phase_environment(require_tpu: bool = True) -> dict:
+    """Backend, versions, the compile cache in effect, and a known peak
+    for this ``device_kind`` (an unknown kind is an error here, not null
+    utilisation later)."""
+    import jax
+    import jaxlib
+
+    from igaming_platform_tpu.obs.perfmodel import peak_for
+    from igaming_platform_tpu.serve.server import device_gate
+
+    backend = device_gate()
+    if require_tpu:
+        check(backend == "tpu",
+              f"no TPU: jax.default_backend() is {backend!r} "
+              f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r})")
+    cache_dir = enable_persistent_compile_cache()
+    try:
+        import libtpu
+
+        libtpu_version = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        libtpu_version = None
+    report = {
+        "device": device_stamp(),
+        "backend": backend,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu_version,
+        "cache_dir": cache_dir,
+        "cache_dir_from_env": bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+        "jax_platforms": jax.config.jax_platforms,
+    }
+    if require_tpu:
+        peaks = peak_for(jax.devices()[0])
+        check(peaks is not None,
+              f"no peak table entry for device_kind "
+              f"{jax.devices()[0].device_kind!r} (obs/perfmodel._PEAKS)")
+        report["peak_flops"], report["peak_hbm_bytes_s"] = peaks
+    return report
+
+
+# ---------------------------------------------------------------------------
+# Phase: native
+
+
+def phase_native(force: bool = True) -> dict:
+    """Rebuild native/lib/*.so from native/*.cpp on THIS host, then
+    require both bindings. No g++ is a failure with that message — never
+    a quiet run on the Python store."""
+    from igaming_platform_tpu.serve import native_build
+
+    gxx = shutil.which("g++")
+    check(gxx is not None, "no g++ on this machine: native/lib cannot be "
+          "built, and the scoring path must not fall back to the Python "
+          "feature store / per-row codec")
+    t0 = time.perf_counter()
+    lib_dir = native_build.ensure_built(force=force)
+    build_s = time.perf_counter() - t0
+    check(lib_dir is not None, "native build failed (see the "
+          "igaming_platform_tpu.serve.native_build warning above)")
+
+    from igaming_platform_tpu.serve.native_store import native_available
+    from igaming_platform_tpu.serve.wire import native_wire_available
+
+    check(native_available(), "native feature store did not load")
+    check(native_wire_available(), "native wire codec did not load")
+    return {"device": device_stamp(), "gxx": gxx, "rebuilt": force,
+            "build_s": round(build_s, 2), "lib_dir": lib_dir}
+
+
+# ---------------------------------------------------------------------------
+# Phase: server
+
+
+def _http_get(port: int, path: str) -> tuple[int, str]:
+    with urllib.request.urlopen(f"http://localhost:{port}{path}",
+                                timeout=30) as resp:
+        return resp.status, resp.read().decode()
+
+
+def _metric(text: str, name: str) -> float:
+    """Sum of a metric's samples in Prometheus text (0 when absent)."""
+    total = 0.0
+    for line in text.splitlines():
+        if line.startswith(name) and line[len(name):len(name) + 1] in (" ", "{"):
+            total += float(line.rsplit(" ", 1)[1])
+    return total
+
+
+def _port_free(port: int) -> bool:
+    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
+        s.settimeout(1.0)
+        return s.connect_ex(("127.0.0.1", port)) != 0
+
+
+def _rows(resp) -> dict:
+    """ScoreBatchResponse -> columns (reason codes kept as sorted tuples)."""
+    import numpy as np
+
+    res = resp.results
+    return {
+        "score": np.array([r.score for r in res], np.int32),
+        "action": np.array([r.action for r in res], np.int32),
+        "rule_score": np.array([r.rule_score for r in res], np.int32),
+        "ml_score": np.array([r.ml_score for r in res], np.float32),
+        "reasons": [tuple(sorted(r.reason_codes)) for r in res],
+    }
+
+
+def _no_degraded(reasons, where: str) -> None:
+    bad = [r for r in reasons if "DEGRADED_CPU_HEURISTIC" in r]
+    check(not bad, f"{where}: {len(bad)} response(s) carry "
+          "DEGRADED_CPU_HEURISTIC")
+
+
+def _on_platform(tree, platform: str) -> bool:
+    import jax
+
+    leaves = [a for a in jax.tree_util.tree_leaves(tree)
+              if hasattr(a, "devices")]
+    return bool(leaves) and all(
+        d.platform == platform for a in leaves for d in a.devices())
+
+
+def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
+                 mesh_devices: int = 0, abuse_events: int = 300,
+                 steady_passes: int = 6, singles: int = 4,
+                 train_steps: int = 5, train_batch: int | None = None,
+                 reference: dict | None = None) -> dict:
+    """The assembly ``serve/server.py:main()`` makes — device_gate,
+    compile cache, ``RiskServer`` — with the multitask backend at
+    DEFAULT_TRUNK and seeded params, driven over a real gRPC socket.
+
+    Traffic runs as: a first pass of every RPC kind (admissions and
+    first-call compiles happen here and are reported), then a steady
+    window — the same kinds again, a threshold flip, the trainer's
+    hot-swap — in which the device-dispatch counter must rise by exactly
+    the chunks sent and nothing may compile.
+
+    ``mesh_devices`` boots the same server on a ``data=N`` mesh;
+    ``reference`` (a one-chip report) makes the index+session trace a
+    bit-exact parity check against it."""
+    import grpc
+    import jax
+    import numpy as np
+
+    from igaming_platform_tpu.core.config import RiskServiceConfig, ScoringConfig
+    from igaming_platform_tpu.models.ensemble import make_score_fn
+    from igaming_platform_tpu.models.multitask import DEFAULT_TRUNK, init_multitask
+    from igaming_platform_tpu.models.sequence import EVENT_DIM
+    from igaming_platform_tpu.proto_gen.risk.v1 import risk_pb2
+    from igaming_platform_tpu.serve.grpc_server import make_risk_stub
+    from igaming_platform_tpu.serve.server import RiskServer, device_gate
+    from igaming_platform_tpu.serve.wire import encode_index_batch
+
+    platform = jax.devices()[0].platform
+    report: dict = {"device": device_stamp(), "batch_size": batch_size,
+                    "mesh_devices": mesh_devices, "trunk": list(DEFAULT_TRUNK)}
+
+    # -- boot, exactly as main() does --------------------------------------
+    device_gate()
+    enable_persistent_compile_cache()
+    config = RiskServiceConfig.from_env()
+    config = dataclasses.replace(
+        config, mesh_devices=mesh_devices,
+        batcher=dataclasses.replace(config.batcher, batch_size=batch_size))
+    params = {"multitask": jax.device_get(init_multitask(jax.random.key(0)))}
+    t0 = time.perf_counter()
+    with warnings.catch_warnings(record=True) as caught, \
+            mock.patch.dict(os.environ, FEATURE_CACHE="1", SESSION_STATE="1"):
+        warnings.simplefilter("always")
+        server = RiskServer(config, ml_backend="multitask", params=params,
+                            grpc_port=0, http_port=0)
+    report["boot_s"] = round(time.perf_counter() - t0, 2)
+    try:
+        donated = [str(w.message) for w in caught
+                   if "donated buffers were not usable" in str(w.message)]
+        check(not donated, f"warm-up warned about unusable donations: {donated}")
+        inner = server.engine.inner
+        telemetry = server.telemetry
+        check(telemetry is not None, "runtime telemetry is not installed")
+
+        def compiles() -> int:
+            return telemetry.compile_watcher.compiles_total
+
+        def dispatches() -> int:
+            return int(_metric(_http_get(server.http_port, "/metrics")[1],
+                               "risk_device_dispatches_total"))
+
+        host_tier = inner._fn_host is not None
+        report["host_tier_built"] = host_tier
+        report["host_tier_rows"] = inner._host_tier if host_tier else 0
+        report["feature_store"] = type(inner.features).__name__
+        if platform == "tpu":
+            check(report["feature_store"] == "NativeFeatureStore",
+                  "a TPU boot must serve from the native feature store")
+        check(inner.cache is not None and inner.session is not None,
+              "feature cache / session plane were not built at boot")
+
+        # -- traffic content (seeded; time-independent features only, so
+        #    every gather of a row is bit-identical whenever it happens) ---
+        rng = np.random.default_rng(0)
+        n = batch_size
+        accounts = [f"smoke-{i}" for i in range(n)]
+        for a in accounts:
+            inner.features.load_batch_features(
+                a,
+                total_deposits=int(rng.integers(0, 500_000)),
+                total_withdrawals=int(rng.integers(0, 300_000)),
+                deposit_count=int(rng.integers(0, 60)),
+                withdraw_count=int(rng.integers(0, 30)),
+                total_bets=int(rng.integers(0, 900_000)),
+                total_wins=int(rng.integers(0, 800_000)),
+                bet_count=int(rng.integers(0, 400)),
+                win_count=int(rng.integers(0, 200)),
+                bonus_claim_count=int(rng.integers(0, 8)))
+        amounts = [int(v) for v in rng.integers(100, 200_000, n)]
+        types = [("deposit", "bet", "withdraw")[int(v)]
+                 for v in rng.integers(0, 3, n)]
+        batch_req = risk_pb2.ScoreBatchRequest(transactions=[
+            risk_pb2.ScoreTransactionRequest(
+                account_id=accounts[i], amount=amounts[i],
+                transaction_type=types[i]) for i in range(n)])
+        batch_bytes = batch_req.SerializeToString()
+        for _ in range(abuse_events):
+            server.abuse.record_event(
+                "smoke-abuser", int(rng.integers(100, 50_000)),
+                ("bonus_grant", "bonus_wager", "withdraw")[int(rng.integers(0, 3))],
+                game_weight=float(rng.random()))
+        check(server.abuse.history_length("smoke-abuser") >= min(abuse_events, 256),
+              "abuse history was not recorded")
+
+        channel = grpc.insecure_channel(
+            f"localhost:{server.grpc_port}",
+            options=[("grpc.max_receive_message_length", 64 << 20),
+                     ("grpc.max_send_message_length", 64 << 20)])
+        stub = make_risk_stub(channel)
+        raw_batch = channel.unary_unary(
+            "/risk.v1.RiskService/ScoreBatch",
+            request_serializer=lambda b: b,
+            response_deserializer=risk_pb2.ScoreBatchResponse.FromString)
+
+        def index_frame(rows: int) -> bytes:
+            return encode_index_batch(accounts[:rows], amounts[:rows],
+                                      types[:rows])
+
+        def chunks(rows: int) -> int:
+            return math.ceil(rows / batch_size)
+
+        index_trace: list[dict] = []
+
+        def score_index(rows: int) -> dict:
+            cols = _rows(raw_batch(index_frame(rows), timeout=120))
+            check(len(cols["score"]) == rows, f"index RPC returned "
+                  f"{len(cols['score'])} of {rows} rows")
+            _no_degraded(cols["reasons"], f"index ScoreBatch({rows})")
+            index_trace.append(cols)
+            return cols
+
+        def score_singles(count: int) -> list:
+            out = []
+            for i in range(count):
+                resp, call = stub.ScoreTransaction.with_call(
+                    risk_pb2.ScoreTransactionRequest(
+                        account_id=accounts[i], amount=amounts[i],
+                        transaction_type=types[i]), timeout=60)
+                _no_degraded([tuple(resp.reason_codes)], "ScoreTransaction")
+                versions = [v for k, v in (call.trailing_metadata() or ())
+                            if k == "risk-model-version"]
+                check(not any("degraded" in v for v in versions),
+                      f"ScoreTransaction carried model version {versions}")
+                out.append(resp)
+            return out
+
+        def check_abuse():
+            resp = stub.CheckBonusAbuse(risk_pb2.CheckBonusAbuseRequest(
+                account_id="smoke-abuser", bonus_id="welcome"), timeout=120)
+            check("DEGRADED_CPU_HEURISTIC" not in resp.signals,
+                  "CheckBonusAbuse answered from the degraded heuristic")
+            check(0.0 <= resp.abuse_score <= 1.0
+                  and math.isfinite(resp.abuse_score),
+                  f"abuse score {resp.abuse_score} out of range")
+            return resp
+
+        def predict_ltv():
+            resp = stub.PredictLTV(risk_pb2.PredictLTVRequest(
+                account_id=accounts[0]), timeout=60)
+            check(math.isfinite(resp.predicted_ltv), "PredictLTV not finite")
+            return resp
+
+        # -- first pass: every RPC kind once -------------------------------
+        c_boot = compiles()
+        first: dict = {}
+        d1 = dispatches()
+        row_proto = _rows(stub.ScoreBatch(batch_req, timeout=180))
+        row_raw = _rows(raw_batch(batch_bytes, timeout=180))
+        first["row_batches"] = dispatches() - d1
+        check(first["row_batches"] == 2 * chunks(n),
+              f"two {n}-row ScoreBatch RPCs made {first['row_batches']} "
+              f"dispatches, expected {2 * chunks(n)}")
+        check(len(row_proto["score"]) == n, "row ScoreBatch lost rows")
+        _no_degraded(row_proto["reasons"] + row_raw["reasons"], "row ScoreBatch")
+        for k in ("score", "action", "rule_score"):
+            check(np.array_equal(row_proto[k], row_raw[k]),
+                  f"proto and raw-bytes ScoreBatch disagree on {k}")
+        check(np.array_equal(row_proto["ml_score"].view(np.int32),
+                             row_raw["ml_score"].view(np.int32)),
+              "proto and raw-bytes ScoreBatch disagree on ml_score bits")
+
+        # Index mode at the full shape, cold sessions: the cached gather
+        # claims bit-identity with the row path (serve/device_cache.py).
+        d2 = dispatches()
+        idx_full = score_index(n)
+        first["index_full_incl_admission"] = dispatches() - d2
+        for k in ("score", "action", "rule_score"):
+            check(np.array_equal(idx_full[k], row_raw[k]),
+                  f"index mode vs row path: {k} differs on "
+                  f"{int(np.sum(idx_full[k] != row_raw[k]))} rows")
+        check(np.array_equal(idx_full["ml_score"].view(np.int32),
+                             row_raw["ml_score"].view(np.int32)),
+              "index mode vs row path: ml_score bits differ")
+        check(all("SESSION_COLD" in r for r in idx_full["reasons"]),
+              "first index pass should be all session-cold rows")
+        d3 = dispatches()
+        for rows in small_rows:
+            score_index(rows)
+        first["index_small"] = dispatches() - d3
+        d4 = dispatches()
+        abuse = check_abuse()
+        first["abuse"] = dispatches() - d4
+        check(first["abuse"] == 1, f"CheckBonusAbuse made {first['abuse']} "
+              "dispatches, expected 1")
+        ltv = predict_ltv()
+        # Interactive traffic goes LAST in each window: a single arms the
+        # burn->shed gate for BURN_SHED_IDLE_S, and the slow first-call
+        # RPCs above (compiles inside requests) burn the fast SLO window.
+        d0 = dispatches()
+        single_resps = score_singles(singles)
+        first["singles"] = dispatches() - d0
+        check(first["singles"] == singles,
+              f"{singles} sequential singles made {first['singles']} dispatches")
+        launched = json.loads(_http_get(
+            server.http_port, "/debug/telemetryz")[1])["compile"]["signature_names"]
+        on_host = any(s.split(":")[0] in ("fused_host_step", "packed_step_host")
+                      for s in launched)
+        report["single_tier"] = "host-cpu" if on_host else "device"
+        check(on_host == host_tier, f"singles answered by "
+              f"{report['single_tier']} but host tier built={host_tier}")
+        report["first_pass_dispatches"] = first
+        report["first_pass_compiles"] = compiles() - c_boot
+        report["abuse_score"] = round(abuse.abuse_score, 6)
+        report["ltv_segment"] = int(ltv.segment)
+
+        # -- chip vs CPU on the same rows, same graph ----------------------
+        x, bl = inner.features.decode_gather(batch_bytes)
+        cpu = jax.devices("cpu")[0]
+        cfg = ScoringConfig()
+        thr = np.array([cfg.block_threshold, cfg.review_threshold], np.int32)
+        ref = jax.jit(make_score_fn(cfg, "multitask"))(
+            jax.device_put(params, cpu), jax.device_put(x, cpu),
+            jax.device_put(bl, cpu), jax.device_put(thr, cpu))
+        ref = {k: np.asarray(v) for k, v in ref.items()}
+        dscore = np.abs(row_raw["score"] - ref["score"])
+        dprob = np.abs(row_raw["ml_score"] - ref["ml_score"])
+        same_score = dscore == 0
+        report["vs_cpu"] = {
+            "rows": n,
+            "max_score_delta": int(dscore.max()),
+            "max_prob_delta": float(dprob.max()),
+            "rows_score_differs": int((~same_score).sum()),
+            "action_mismatch": int((row_raw["action"] != ref["action"]).sum()),
+        }
+        check(dscore.max() <= 1, f"score differs from the CPU run by "
+              f"{int(dscore.max())} (> 1)")
+        check(dprob.max() <= PROB_BOUND, f"fraud probability differs from the "
+              f"CPU run by {dprob.max():.4g} (> {PROB_BOUND})")
+        check(np.array_equal(row_raw["action"][same_score],
+                             ref["action"][same_score]),
+              "same score, different action vs the CPU run")
+        check(np.array_equal(row_raw["rule_score"], ref["rule_score"]),
+              "rule score differs from the CPU run")
+        for i, resp in enumerate(single_resps):
+            check(abs(resp.score - int(ref["score"][i])) <= 1,
+                  f"single {i}: score {resp.score} vs CPU {int(ref['score'][i])}")
+
+        # -- placement -----------------------------------------------------
+        packed, _ = inner.launch_packed(x, bl)
+        placement = {
+            "params": _on_platform(inner.get_params(), platform),
+            "feature_table": _on_platform(inner.cache.table, platform),
+            "session_ring": _on_platform(inner.session.session_ring, platform),
+            "packed_result": _on_platform(packed, platform),
+        }
+        jax.device_get(packed)
+        report["placement"] = placement
+        check(all(placement.values()),
+              f"state or results not on a {platform} device: {placement}")
+        seq_len = min(server.abuse.max_history, 64)
+        abuse_x = np.zeros((1, seq_len, EVENT_DIM), np.float32)
+        lowered = server.abuse._fn.lower(server.abuse.params, abuse_x).as_text()
+        report["abuse_seq_len"] = seq_len
+        report["abuse_mosaic_call"] = "tpu_custom_call" in lowered
+        if platform == "tpu":
+            check(report["abuse_mosaic_call"], "the abuse step's lowered "
+                  "text has no Mosaic custom call: the Pallas flash kernel "
+                  "is not on the serving path")
+        if mesh_devices:
+            from igaming_platform_tpu.parallel.state_sharding import per_shard_nbytes
+
+            shard_report = {}
+            for name, arr in (("feature_table", inner.cache.table),
+                              ("session_ring", inner.session.session_ring)):
+                devs = {s.device.id for s in arr.addressable_shards}
+                per = per_shard_nbytes(arr)
+                total = int(np.prod(arr.shape)) * arr.dtype.itemsize
+                shard_report[name] = {"devices": sorted(devs),
+                                      "per_shard_bytes": per, "total_bytes": total}
+                check(len(devs) == mesh_devices,
+                      f"{name} sits on {len(devs)} device(s), not {mesh_devices}")
+                check(all(abs(b - total / mesh_devices) <= 0.02 * total
+                          for b in per),
+                      f"{name} per-shard bytes {per} are not total/{mesh_devices}")
+            report["shards"] = shard_report
+
+        # -- steady window: exact dispatches, no compile -------------------
+        # Bulk admissions shed (by design) while the fast SLO window
+        # burns AND interactive traffic was seen in the last
+        # BURN_SHED_IDLE_S: wait for the gate to disarm, as a bulk
+        # client honouring the pushback would.
+        t_gate = time.perf_counter()
+        while json.loads(_http_get(server.http_port, "/debug/deadlinez")[1]
+                         )["burn_gate"]["shedding"]:
+            check(time.perf_counter() - t_gate < 90.0,
+                  "the burn->shed gate did not disarm within 90 s")
+            time.sleep(0.5)
+        report["burn_gate_wait_s"] = round(time.perf_counter() - t_gate, 1)
+        c_steady, d_steady = compiles(), dispatches()
+        ready_code, ready_body = _http_get(server.http_port, "/ready")
+        sent = 0
+        for _ in range(steady_passes):
+            for rows in small_rows:
+                score_index(rows)
+                sent += chunks(rows)
+        _rows(raw_batch(batch_bytes, timeout=180))
+        sent += chunks(n)
+        check_abuse()
+        sent += 1
+        predict_ltv()
+        flip = stub.UpdateThresholds(risk_pb2.UpdateThresholdsRequest(
+            block_threshold=0, review_threshold=0), timeout=30)
+        check(flip.success, "UpdateThresholds failed")
+        flipped = score_index(small_rows[0])
+        sent += chunks(small_rows[0])
+        check(bool(np.all(flipped["action"] == 3)),
+              "block=0/review=0 did not flip every action to block")
+        stub.UpdateThresholds(risk_pb2.UpdateThresholdsRequest(
+            block_threshold=cfg.block_threshold,
+            review_threshold=cfg.review_threshold), timeout=30)
+        restored = score_index(small_rows[0])
+        sent += chunks(small_rows[0])
+        check(not np.all(restored["action"] == 3),
+              "thresholds were not restored")
+
+        # -- trainer: beside the live server, then hot-swap ----------------
+        if train_steps:
+            c_train = compiles()
+            report["trainer"] = phase_trainer(
+                server, lambda: _rows(raw_batch(batch_bytes, timeout=180)),
+                row_raw, compiles, steps=train_steps, batch_size=train_batch)
+            sent += chunks(n)
+            # The trainer's own programs are new code, not a recompile
+            # of anything the server warmed.
+            c_steady += report["trainer"]["train_compiles"]
+            check(compiles() - c_train == report["trainer"]["train_compiles"],
+                  "ScoreBatch after swap_params recompiled")
+        score_singles(singles)
+        sent += singles
+        report["steady"] = {
+            "chunks_sent": sent,
+            "dispatches": dispatches() - d_steady,
+            "compiles": compiles() - c_steady,
+        }
+        check(report["steady"]["dispatches"] == sent,
+              f"steady window: {report['steady']['dispatches']} dispatches "
+              f"for {sent} chunks sent")
+        check(report["steady"]["compiles"] == 0,
+              f"{report['steady']['compiles']} compile(s) after warm-up: "
+              f"{list(telemetry.compile_watcher.events)[-3:]}")
+        session = json.loads(_http_get(server.http_port, "/debug/sessionz")[1])
+        report["session_rows"] = session["rows"]
+        check(session["rows"]["warm"] > 0, "no session ever became warm")
+
+        # -- health --------------------------------------------------------
+        metrics_text = _http_get(server.http_port, "/metrics")[1]
+        sup = json.loads(_http_get(server.http_port, "/debug/supervisorz")[1])
+        report["supervisor"] = sup["state"]
+        report["device_breaker"] = sup["breakers"].get("device", {}).get("state")
+        check(sup["state"] == "serving", f"supervisor is {sup['state']}")
+        check(report["device_breaker"] in (None, "closed"),
+              f"device breaker is {report['device_breaker']}")
+        check(_metric(metrics_text, "risk_watchdog_trips_total") == 0,
+              "the device-step watchdog tripped")
+        check(_metric(metrics_text, "risk_degraded_responses_total") == 0,
+              "degraded responses were served")
+        check(ready_code == 200 and json.loads(ready_body)["ready"] is True,
+              f"/ready said {ready_code} {ready_body}")
+        report["ready"] = True
+
+        # -- parity against the one-chip run -------------------------------
+        if reference is not None:
+            want = reference["index_trace"]
+            check(len(want) == len(index_trace), "index traces differ in length")
+            for i, (a, b) in enumerate(zip(index_trace, want)):
+                for k in ("score", "action", "rule_score"):
+                    check(np.array_equal(a[k], b[k]),
+                          f"mesh vs one chip: RPC {i} {k} differs")
+                check(np.array_equal(a["ml_score"].view(np.int32),
+                                     b["ml_score"].view(np.int32)),
+                      f"mesh vs one chip: RPC {i} ml_score bits differ")
+                check(a["reasons"] == b["reasons"],
+                      f"mesh vs one chip: RPC {i} reason codes differ")
+            report["parity_vs_one_chip"] = f"bit-exact over {len(want)} RPCs"
+        channel.close()
+    finally:
+        grpc_port, http_port = server.grpc_port, server.http_port
+        server.shutdown(grace=5.0)
+    check(_port_free(grpc_port) and _port_free(http_port),
+          f"shutdown left a port bound (grpc {grpc_port}, http {http_port})")
+    report["ports_released"] = True
+    report["index_trace"] = index_trace
+    return report
+
+
+# ---------------------------------------------------------------------------
+# Phase: trainer
+
+
+def phase_trainer(server, score_batch, before: dict, compiles, *,
+                  steps: int = 5, batch_size: int | None = None) -> dict:
+    """A few steps of ``train.trainer.Trainer`` at the default
+    ``TrainConfig`` beside the LIVE server, finite loss, then
+    ``swap_params`` into the serving engine and one more ScoreBatch
+    (``score_batch()`` -> columns) that must not recompile — the
+    "training and serving share the pod" claim, once, on the chip.
+    Called by the server phase, which owns the live server."""
+    import numpy as np
+
+    from igaming_platform_tpu.train.trainer import TrainConfig, Trainer
+
+    c0 = compiles()
+    cfg = TrainConfig() if batch_size is None else TrainConfig(batch_size=batch_size)
+    trainer = Trainer(cfg)
+    metrics = trainer.fit(steps)
+    check(all(math.isfinite(v) for v in metrics.values()),
+          f"training metrics not finite: {metrics}")
+    train_compiles = compiles() - c0
+    server.engine.swap_params({"multitask": trainer.export_params()})
+    after = score_batch()
+    _no_degraded(after["reasons"], "ScoreBatch after swap_params")
+    check(not np.array_equal(after["ml_score"], before["ml_score"]),
+          "swap_params did not change the served model")
+    return {"device": device_stamp(), "steps": steps,
+            "batch_size": cfg.batch_size, "trunk": list(cfg.trunk),
+            "loss": round(metrics["loss"], 6),
+            "train_compiles": train_compiles,
+            "served_fingerprint": server.engine.inner.params_fingerprint}
+
+
+# ---------------------------------------------------------------------------
+# Phase: kernels
+
+
+def phase_kernels(interpret: bool = False, *,
+                  attention_shapes: tuple = ((1, 2, 64, 32), (8, 2, 256, 32),
+                                             (2, 8, 2048, 16), (1, 8, 8192, 16)),
+                  backward_shape: tuple = (2, 8, 2048, 16),
+                  gbdt_batch: int = 8192, gbdt_tile: int = 256) -> dict:
+    """Every Pallas entry point at the shapes the repo uses — flash
+    forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
+    and tiled (S=8192), backward at S=2048, the GBDT forest at
+    [8192, 30] — each against its XLA reference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from igaming_platform_tpu.core.features import NUM_FEATURES
+    from igaming_platform_tpu.models.gbdt import gbdt_raw, init_gbdt
+    from igaming_platform_tpu.ops.gbdt_matmul import gbdt_raw_matmul, precompute_selector
+    from igaming_platform_tpu.ops.pallas import flash_attention as fa
+    from igaming_platform_tpu.ops.pallas.gbdt_kernel import gbdt_raw_pallas
+
+    report: dict = {"device": device_stamp(), "interpret": interpret}
+
+    def einsum_ref(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+    def qkv(shape):
+        ks = jax.random.split(jax.random.key(shape[2]), 3)
+        return [jax.random.normal(k, shape, jnp.float32) for k in ks]
+
+    for shape in attention_shapes:
+        q, k, v = qkv(shape)
+        out = fa.flash_attention(q, k, v, interpret=interpret)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(einsum_ref)(q, k, v)
+        err = float(jnp.max(jnp.abs(out - want)))
+        variant = "resident" if shape[2] <= fa._RESIDENT_MAX_S else "tiled"
+        report[f"flash_fwd_{variant}_S{shape[2]}_Dh{shape[3]}"] = err
+        check(bool(jnp.all(jnp.isfinite(out))) and err <= ATTN_FWD_TOL,
+              f"flash forward {shape}: max err {err} > {ATTN_FWD_TOL}")
+
+    q, k, v = qkv(backward_shape)
+    grad_flash = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(
+            fa.flash_attention(q, k, v, interpret=interpret) ** 2),
+        argnums=(0, 1, 2)))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        grad_ref = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(einsum_ref(q, k, v) ** 2),
+            argnums=(0, 1, 2)))(q, k, v)
+    errs = [float(jnp.max(jnp.abs(a - b))) for a, b in zip(grad_flash, grad_ref)]
+    report[f"flash_bwd_S{backward_shape[2]}_Dh{backward_shape[3]}"] = errs
+    check(max(errs) <= ATTN_BWD_TOL,
+          f"flash backward {backward_shape}: max err {errs} > {ATTN_BWD_TOL}")
+
+    forest = init_gbdt(jax.random.key(0))
+    x = np.random.default_rng(0).random(
+        (gbdt_batch, NUM_FEATURES)).astype(np.float32)
+    sel = jnp.asarray(precompute_selector(np.asarray(forest["feat"]), NUM_FEATURES))
+    got = np.asarray(gbdt_raw_pallas(forest, x, sel=sel, tile_b=gbdt_tile,
+                                     interpret=interpret))
+    for name, fn in (("matmul", lambda: gbdt_raw_matmul(forest, sel, x)),
+                     ("gather", lambda: gbdt_raw(forest, x))):
+        err = float(np.max(np.abs(got - np.asarray(jax.jit(fn)()))))
+        report[f"gbdt_vs_{name}"] = err
+        check(err <= GBDT_TOL, f"GBDT kernel vs {name} form: {err} > {GBDT_TOL}")
+    return report
+
+
+# ---------------------------------------------------------------------------
+# Phase: mesh
+
+
+def phase_mesh(reference: dict | None, n_devices: int = 4, **server_kwargs) -> dict:
+    """The server phase again on a ``data=N`` mesh (MESH_DEVICES=N):
+    state shards on N distinct devices at ~1/N bytes each, one dispatch
+    per chunk, scores bit-exact against the one-chip run of the same
+    RPC sequence. Fewer devices: not run — and says so."""
+    import jax
+
+    have = len(jax.devices())
+    if have < n_devices:
+        return {"device": device_stamp(),
+                "result": f"not_run ({have} device)"}
+    report = phase_server(mesh_devices=n_devices, reference=reference,
+                          **server_kwargs)
+    report["result"] = "passed"
+    return report
+
+
+# ---------------------------------------------------------------------------
+# Phase: cache
+
+
+def phase_cache(watcher, cache_dir: str | None) -> dict:
+    """Persistent-cache hits and total compile seconds of this run, from
+    a process-wide ``obs/runtime_telemetry.CompileWatcher`` (seconds
+    include cache retrievals) — a second run in the same directory shows
+    hits and a smaller total."""
+    entries = 0
+    if cache_dir and os.path.isdir(cache_dir):
+        entries = sum(1 for e in os.scandir(cache_dir) if e.is_file())
+    snap = watcher.snapshot()
+    return {"device": device_stamp(), "cache_dir": cache_dir,
+            "entries_on_disk": entries,
+            "compiles": snap["compiles_total"],
+            "compile_s": round(snap["compile_wall_ms_total"] / 1000.0, 2),
+            "persistent_cache_hits": snap["persistent_cache_hits"],
+            "persistent_cache_misses": snap["persistent_cache_misses"]}
+
+
+# ---------------------------------------------------------------------------
+
+
+def _summary_line(name: str, report: dict) -> str:
+    dev = report.get("device", {})
+    keep = {k: v for k, v in report.items()
+            if k not in ("device", "index_trace") and not isinstance(v, (dict, list))}
+    return (f"[chip_smoke] {name}: platform={dev.get('platform')} "
+            f"kind={dev.get('kind')} count={dev.get('count')} "
+            + json.dumps(keep, default=str))
+
+
+def main() -> int:
+    import logging
+
+    logging.basicConfig(
+        level=logging.INFO, stream=sys.stderr,
+        format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    from igaming_platform_tpu.obs.runtime_telemetry import CompileWatcher
+
+    t_start = time.perf_counter()
+    watcher = CompileWatcher()  # process-wide: boot compiles included
+    watcher.install_listener()
+    reports: dict = {}
+
+    def run(name: str, fn, *args, **kwargs) -> dict:
+        t0 = time.perf_counter()
+        rep = fn(*args, **kwargs)
+        rep["seconds"] = round(time.perf_counter() - t0, 2)
+        reports[name] = rep
+        print(_summary_line(name, rep), flush=True)
+        return rep
+
+    env = run("environment", phase_environment)
+    run("native", phase_native)
+    one_chip = run("server", phase_server)
+    reports["trainer"] = one_chip.pop("trainer")  # ran beside the live server
+    print(_summary_line("trainer", reports["trainer"]), flush=True)
+    run("kernels", phase_kernels)
+    run("mesh", phase_mesh, one_chip)
+    run("cache", phase_cache, watcher, env["cache_dir"])
+
+    for rep in reports.values():
+        rep.pop("index_trace", None)
+    summary = {
+        "phases": {k: v.get("result", "passed") for k, v in reports.items()},
+        "mesh": reports["mesh"]["result"],
+        "wall_s": round(time.perf_counter() - t_start, 1),
+        "reports": reports,
+        "claim": None,
+    }
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", "chip_smoke.json"), "w",
+              encoding="utf-8") as f:
+        json.dump(summary, f, indent=1, default=str)
+    print(f"[chip_smoke] mesh: {summary['mesh']}; wall {summary['wall_s']} s; "
+          f"compile {reports['cache']['compile_s']} s over "
+          f"{reports['cache']['compiles']} programs, "
+          f"{reports['cache']['persistent_cache_hits']} persistent-cache hits",
+          flush=True)
+    print(json.dumps({"ok": True, "device": env["device"]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

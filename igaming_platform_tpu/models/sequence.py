@@ -20,9 +20,10 @@ propagates the [B, S/seq, D] sharding through it untouched.
 
 from __future__ import annotations
 
+import logging
 import math
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any
 
 import jax
@@ -31,10 +32,12 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from igaming_platform_tpu.core.compat import axis_size as _axis_size, shard_map
+from jax import shard_map
 from igaming_platform_tpu.parallel.mesh import AXIS_DATA, AXIS_SEQ
 
 Params = dict[str, Any]
+
+logger = logging.getLogger(__name__)
 
 # Per-event feature layout for wagering histories:
 # [log-amount, log-dt, 8-way tx-type one-hot, game-weight, balance-ratio]
@@ -120,6 +123,14 @@ def _sinusoidal_positions(seq_len: int, d_model: int) -> np.ndarray:
 # -- attention cores ---------------------------------------------------------
 
 
+@lru_cache(maxsize=None)
+def _announce_core(core: str, backend: str) -> None:
+    """Log, once per (core, backend), which attention core serves the
+    dense path — the choice is made at trace time and is otherwise
+    invisible."""
+    logger.info("attention core: %s (backend=%s)", core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
+
+
 def _dense_attention(q, k, v):
     """q,k,v: [B, H, S, Dh] -> [B, H, S, Dh]; full softmax attention.
 
@@ -129,8 +140,11 @@ def _dense_attention(q, k, v):
     """
     from igaming_platform_tpu.ops.pallas.flash_attention import flash_attention, supports
 
-    if jax.default_backend() == "tpu" and supports(q.shape):
+    backend = jax.default_backend()
+    if backend == "tpu" and supports(q.shape):
+        _announce_core("pallas-flash", backend)
         return flash_attention(q, k, v)
+    _announce_core("xla-einsum", backend)
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     p = jax.nn.softmax(s, axis=-1)
@@ -144,7 +158,7 @@ def _ring_attention_local(q, k, v):
     softmax normaliser accumulates online (flash-attention style), so no
     [S, S] matrix and no full-sequence KV ever exist on one device.
     """
-    n = _axis_size(AXIS_SEQ)
+    n = lax.axis_size(AXIS_SEQ)
     scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, s_loc, dh = q.shape
 

@@ -428,7 +428,7 @@ class ServiceMetrics:
         self.engine_rebuilds_total = self.registry.counter(
             f"{service}_engine_rebuilds_total",
             "Scoring-engine tear-down+rebuild cycles completed after a "
-            "watchdog trip (the wedged-tunnel recovery path)",
+            "watchdog trip (the wedged-device recovery path)",
         )
         self.follower_resurrections_total = self.registry.counter(
             f"{service}_follower_resurrections_total",

@@ -71,6 +71,8 @@ class CompileWatcher:
     """
 
     _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+    _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+    _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
     def __init__(self, metrics=None, max_events: int = 64):
         self.metrics = metrics
@@ -80,6 +82,10 @@ class CompileWatcher:
         self.compiles_total = 0
         self.compile_wall_ms_total = 0.0
         self.new_signatures_total = 0
+        # Persistent compile cache (core/devices.py): a compile request
+        # served from disk is a hit, one written to disk a miss.
+        self.cache_hits_total = 0
+        self.cache_misses_total = 0
         self.events: deque = deque(maxlen=max_events)
         self._listener_installed = False
 
@@ -93,7 +99,16 @@ class CompileWatcher:
         except Exception:  # noqa: BLE001 — monitoring seam is optional
             return
         monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
         self._listener_installed = True
+
+    def _on_event(self, name: str, **_kw) -> None:
+        if name == self._CACHE_HIT_EVENT:
+            with self._lock:
+                self.cache_hits_total += 1
+        elif name == self._CACHE_MISS_EVENT:
+            with self._lock:
+                self.cache_misses_total += 1
 
     def _on_duration(self, name: str, duration_s: float, **_kw) -> None:
         if name != self._COMPILE_EVENT:
@@ -132,7 +147,13 @@ class CompileWatcher:
             return {
                 "compiles_total": self.compiles_total,
                 "compile_wall_ms_total": round(self.compile_wall_ms_total, 3),
+                "persistent_cache_hits": self.cache_hits_total,
+                "persistent_cache_misses": self.cache_misses_total,
                 "signatures": self.new_signatures_total,
+                # Which programs have launched (name:shape:dtype) — the
+                # host-tier and device-tier steps carry different names,
+                # so this says which tier has answered.
+                "signature_names": sorted(self._signatures)[:128],
                 "recent_events": list(self.events),
             }
 

@@ -344,21 +344,20 @@ def flash_attention(
     *,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """[B, H, S, Dh] q,k,v -> [B, H, S, Dh] full (non-causal) attention.
 
     S must be divisible by the (effective) block sizes — the serving path
     pads event histories to a fixed max_len, so this holds on the hot
     path; `supports()` lets callers fall back to the dense core otherwise.
+    ``interpret=True`` runs the Pallas interpreter — the only way to
+    execute the kernel off-TPU, and always the caller's explicit choice.
     """
     b, h, s, dh = q.shape
     bq, bk = _eff_block(s, block_q), _eff_block(s, block_k)
     if s % bq != 0 or s % bk != 0:
         raise ValueError(f"seq len {s} not divisible by blocks ({bq}, {bk})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
     f = _flash_with_vjp(block_q, block_k, interpret)
     out = f(q.reshape(b * h, s, dh), k.reshape(b * h, s, dh),
             v.reshape(b * h, s, dh))

@@ -14,8 +14,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from igaming_platform_tpu.core.compat import axis_size as _axis_size
-
 
 def psum(x, axis: str):
     """All-reduce sum over ``axis`` (gradient sync, ensemble reduction)."""
@@ -49,7 +47,7 @@ def all_to_all(x, axis: str, *, split_axis: int, concat_axis: int):
 def ppermute_ring(x, axis: str, *, shift: int = 1):
     """Rotate shards around the ``axis`` ring by ``shift`` steps — the
     nearest-neighbour ICI pattern under ring attention / pipelining."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name=axis, perm=perm)
 
@@ -59,7 +57,7 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    return _axis_size(axis)
+    return lax.axis_size(axis)
 
 
 # -- host-facing sharding helpers -------------------------------------------

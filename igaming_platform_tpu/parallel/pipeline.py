@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from igaming_platform_tpu.core.compat import shard_map
+from jax import shard_map
 from igaming_platform_tpu.parallel.mesh import AXIS_MODEL
 
 

@@ -173,7 +173,7 @@ def make_sharded_scatter(plan: SlotShardingPlan, ndim: int):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from igaming_platform_tpu.core.compat import shard_map
+    from jax import shard_map
 
     sm = shard_map(
         scatter_slots,
@@ -192,7 +192,7 @@ def make_sharded_ring_sync(plan: SlotShardingPlan):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from igaming_platform_tpu.core.compat import shard_map
+    from jax import shard_map
 
     def sync(ring_l, cur_l, len_l, slots, w, c, l):  # noqa: E741
         return (scatter_slots(ring_l, slots, w),

@@ -30,7 +30,7 @@ from igaming_platform_tpu.models.sequence import (
 
 class AbuseShed(RuntimeError):
     """Raised when the abuse path sheds load instead of serving a
-    degraded score (ABUSE_CPU_POLICY=shed on a CPU-fallback deployment).
+    degraded score (ABUSE_CPU_POLICY=shed on a CPU-backend boot).
     The gRPC layer maps it to UNAVAILABLE — loud, countable, never a
     silently-slow or silently-different answer."""
 
@@ -44,7 +44,7 @@ class SequenceAbuseDetector:
     - ``"heuristic"``: vectorized scalar pattern-matching over the same
       ring buffers — the class of signals the reference itself ships
       (engine.go:462-466 / bonus_engine.go:268-275 match on scalar
-      aggregates). For ``SERVE_DEVICE_FALLBACK=cpu`` deployments where
+      aggregates). For boots on the CPU backend (``JAX_PLATFORMS=cpu``), where
       the transformer would collapse to ~80 seq/s; responses carry a
       DEGRADED_CPU_HEURISTIC signal so the degradation is visible.
     - ``"shed"``: refuse with :class:`AbuseShed` (→ gRPC UNAVAILABLE).
@@ -165,7 +165,7 @@ class SequenceAbuseDetector:
     def _heuristic_one(self, account_id: str) -> tuple[float, list[str]]:
         """Scalar pattern-matching over the encoded ring buffer — the
         reference's own abuse signal class (engine.go:462-466), kept as
-        the CPU-fallback scorer. O(history) numpy, no device."""
+        the CPU-backend scorer. O(history) numpy, no device."""
         from igaming_platform_tpu.models.sequence import TX_TYPE_INDEX
 
         with self._lock:

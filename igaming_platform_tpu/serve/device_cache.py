@@ -1,11 +1,9 @@
 """Device-resident HBM feature cache: ship indices + deltas, not rows.
 
-The round-5 evidence (`artifacts_r05/BENCH_MATRIX.json` vs the CPU
-control) shows the device e2e scoring path losing to the same code on
-CPU because every bulk RPC ships a full `[N, 30]` float32 feature matrix
-across a link-bound host->device wire while the chip sits ~1% busy. The
-fix is the "keep hot state next to the accelerator, stream only the
-novel bytes" pattern (arXiv:2109.09541, arXiv:2010.04804): the
+Every bulk row-wire RPC ships a full `[N, 30]` float32 feature matrix
+host->device for a step that leaves the chip almost idle. This is the
+"keep hot state next to the accelerator, stream only the novel bytes"
+pattern (arXiv:2109.09541, arXiv:2010.04804): the
 per-ACCOUNT feature row lives in a device-resident table and the wire
 carries only
 

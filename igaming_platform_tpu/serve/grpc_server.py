@@ -152,7 +152,7 @@ class HealthServicer:
 
 
 class _AdaptiveBulkGate:
-    """Bounded bulk-admission gate with p99 feedback (VERDICT r05 Weak #1).
+    """Bounded bulk-admission gate with p99 feedback.
 
     A plain semaphore at BULK_MAX_INFLIGHT holds the configured limit even
     when the host is slower than the one it was measured on. This gate
@@ -388,12 +388,9 @@ class RiskGrpcService:
         # the remaining gRPC workers and the host CPU stay available for
         # interactive traffic instead of drowning in bulk encode/decode.
         # The reference has no admission control at all (its flat-out
-        # tail is unbounded queueing). Default gate is the MEASURED-good
-        # value: the flat-out A/B on the round-5 host
-        # (artifacts_r05/SOAK_flatout_admission_gate2.json vs the wider
-        # gate) shows 2 in-flight holds single-txn p99 at 48 ms where 4
-        # lets it reach 95 ms — with bulk still 1.7x the 100k/s bar (bulk
-        # is link-bound, not admission-bound). On hosts where even 2 is
+        # tail is unbounded queueing). The default gate of 2 in-flight
+        # trades bulk throughput for the interactive tail (the knee on
+        # the current chip: not measured). On hosts where even 2 is
         # too generous, the p99-feedback controller (_AdaptiveBulkGate)
         # tightens further: single-txn latencies above BULK_P99_SLO_MS
         # (default 50, 0 disables) shrink the limit toward 1, and it

@@ -4,9 +4,10 @@
 serve.feature_store.InMemoryFeatureStore (sliding windows, HLL
 cardinalities, TTL'd sums, sessions, batch aggregates) with the per-event
 update and the [B, 30] gather executed in C++ — the host-side hot path of
-the ingest bridge (SURVEY.md §2.2 "native ingest bridge"). Builds on
-demand with g++ (native/build.sh); callers fall back to the Python store
-when the toolchain or .so is unavailable (``native_available()``).
+the ingest bridge (SURVEY.md §2.2 "native ingest bridge"). Built on
+demand with g++ for this host (serve/native_build.py);
+``native_available()`` is False when the toolchain is missing, and the
+caller decides whether that is fatal (it is on an accelerator boot).
 
 String account ids map to dense indices here; device/IP strings hash to
 stable 64-bit values (blake2b, matching serve.hll).
@@ -17,23 +18,13 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
-import subprocess
-import threading
 import time
 
 import numpy as np
 
 from igaming_platform_tpu.core.features import F, NUM_FEATURES
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native"
-)
-_LIB_PATH = os.path.join(_NATIVE_DIR, "lib", "libfeature_store.so")
-
 _TX_TYPE_CODES = {"deposit": 0, "withdraw": 1, "bet": 2, "win": 3}
-
-_build_lock = threading.Lock()
-
 
 _hash_cache: dict[str, int] = {}
 
@@ -50,43 +41,13 @@ def _hash64(value: str) -> int:
     return h
 
 
-def build_native(force: bool = False) -> str | None:
-    """Compile the shared library if needed; returns its path or None.
-    A .so older than its source is rebuilt (stale-binary guard)."""
-    with _build_lock:
-        src = os.path.join(_NATIVE_DIR, "feature_store.cpp")
-        if not os.path.exists(src):
-            return _LIB_PATH if os.path.exists(_LIB_PATH) else None
-        if (
-            os.path.exists(_LIB_PATH)
-            and not force
-            and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(src)
-        ):
-            return _LIB_PATH
-        try:
-            subprocess.run(
-                ["sh", os.path.join(_NATIVE_DIR, "build.sh")],
-                check=True, capture_output=True, timeout=120,
-            )
-        except (subprocess.CalledProcessError, subprocess.TimeoutExpired, FileNotFoundError):
-            return None
-        return _LIB_PATH if os.path.exists(_LIB_PATH) else None
-
-
 def _load_lib():
-    path = build_native()
-    if path is None:
+    from igaming_platform_tpu.serve.native_build import ensure_built
+
+    lib_dir = ensure_built()
+    if lib_dir is None:
         return None
-    try:
-        return _bind(ctypes.CDLL(path))
-    except AttributeError:
-        # A prebuilt .so from before a symbol was added (mtime passed the
-        # staleness guard, or the source is absent). Rebuild for the NEXT
-        # process — re-dlopening the same path in THIS one would return
-        # the already-mapped stale handle (glibc caches by path; ctypes
-        # never dlcloses) — and fall back to the Python store now.
-        build_native(force=True)
-        return None
+    return _bind(ctypes.CDLL(os.path.join(lib_dir, "libfeature_store.so")))
 
 
 def _bind(lib):

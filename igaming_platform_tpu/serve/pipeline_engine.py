@@ -1,10 +1,10 @@
 """Pipelined host engine — stage workers overlap host work with device steps.
 
-BENCH_r05 put the device step at 0.116 ms while end-to-end ScoreBatch
-throughput sat at ~200k txns/s: the time lives in the serial Python host
-path (wire decode -> gather -> pad -> H2D -> readback -> encode), not on
-the TPU — the "Scaling TensorFlow to 300M predictions/sec" lesson that at
-high QPS the pre/post-processing pipeline is the wall. This module
+The device step is a small fraction of an end-to-end ScoreBatch: the
+time lives in the serial Python host path (wire decode -> gather -> pad
+-> H2D -> readback -> encode), not on the TPU — the "Scaling TensorFlow
+to 300M predictions/sec" lesson that at high QPS the pre/post-processing
+pipeline is the wall. This module
 rebuilds the wire scoring hot path as a staged pipeline so host work for
 batch N+1 overlaps the device step for batch N and the readback/encode of
 batch N-1:

@@ -466,7 +466,7 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
     if plan is not None:
         from jax.sharding import PartitionSpec as P
 
-        from igaming_platform_tpu.core.compat import shard_map
+        from jax import shard_map
 
         outs = ([P(), plan.spec(3), plan.spec(1), plan.spec(1)]
                 + ([P()] if sketch else []) + ([P()] if shadow else []))

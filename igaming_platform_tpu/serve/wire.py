@@ -10,25 +10,21 @@ built once per transaction, re-designed as one batch encode).
 through the normal serializer seam; byte-parity with the Python
 protobuf serializer is pinned in tests/test_wire_codec.py.
 
-Falls back to reporting unavailable when the native toolchain/.so is
-missing — callers keep the per-row path in that case.
+``native_wire_available()`` is False when the library cannot be built
+for this host (serve/native_build.py) — callers keep the per-row path in
+that case.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
-import subprocess
 import threading
 
 import numpy as np
 
 from igaming_platform_tpu.core.enums import REASON_BIT_ORDER
-
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native"
-)
-_LIB_PATH = os.path.join(_NATIVE_DIR, "lib", "libwire_codec.so")
 
 _build_lock = threading.Lock()
 _lib = None
@@ -50,13 +46,14 @@ def _load():
     with _build_lock:
         if _lib is not None or _load_failed:
             return _lib
+        from igaming_platform_tpu.serve.native_build import ensure_built
+
+        lib_dir = ensure_built()
+        if lib_dir is None:
+            _load_failed = True
+            return None
         try:
-            if not os.path.exists(_LIB_PATH):
-                subprocess.run(
-                    ["sh", os.path.join(_NATIVE_DIR, "build.sh")],
-                    check=True, capture_output=True, timeout=120,
-                )
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(os.path.join(lib_dir, "libwire_codec.so"))
             lib.encode_score_batch.restype = ctypes.c_int64
             lib.encode_score_batch.argtypes = [
                 ctypes.c_int32,
@@ -74,7 +71,9 @@ def _load():
                 ctypes.c_int64,                  # out_cap
             ]
             _lib = lib
-        except Exception:  # noqa: BLE001 — toolchain absent => fallback
+        except (OSError, AttributeError):  # unloadable library => per-row path
+            logging.getLogger(__name__).warning(
+                "native wire codec did not load", exc_info=True)
             _load_failed = True
     return _lib
 

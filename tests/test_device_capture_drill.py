@@ -1,7 +1,5 @@
-"""End-to-end drill of the on-device capture script (round-4 verdict
-weak #2: the script guarding the round's most important deliverable was
-itself untested — paths, env plumbing, and redirections had never
-produced an artifact set).
+"""End-to-end drill of the on-device capture script: its paths, env
+plumbing and redirections must produce a whole artifact set.
 
 Runs `benchmarks/device_capture.sh` with CAPTURE_QUICK=1 in CPU mode
 into a scratch dir and asserts every artifact of all six stages appears,
@@ -38,9 +36,6 @@ def test_capture_script_produces_all_artifacts(tmp_path):
         os.environ,
         CAPTURE_QUICK="1",
         JAX_PLATFORMS="cpu",
-        # The harnesses' own device probe must not burn its full budget
-        # per stage in a CPU drill.
-        DEVICE_PROBE_BUDGET_S="5",
     )
     proc = subprocess.run(
         ["sh", os.path.join(REPO, "benchmarks", "device_capture.sh"), str(out_dir)],

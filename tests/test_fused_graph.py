@@ -631,9 +631,30 @@ def test_gbdt_int8_quantization_close_to_f32():
     # the disclosed error mode, bounded by the flipped leaf's weight.
     assert np.mean(diff) < 2e-2
     assert np.quantile(diff, 0.9) < 6e-2
-    assert np.max(diff) < 0.1
     # Half the rows are flip-free and match to f32/bf16 rounding.
     assert np.quantile(diff, 0.5) < 5e-3
+    # The worst row is bounded by what the quantization GUARANTEES, not
+    # by a constant that holds for one seed's draws (the old `< 0.1` pin
+    # read 0.1017 under a newer JAX's random stream): a tree whose
+    # splits all agree moves only by its leaf's int8 rounding (half a
+    # step); a tree with a flipped split can land on any other leaf
+    # (at most max-min of that tree's leaves, plus the rounding); and
+    # the sigmoid's slope is at most 1/4.
+    import ml_dtypes
+
+    bf16 = ml_dtypes.bfloat16
+    thr = np.asarray(params["thr"], np.float32)
+    leaves = np.asarray(params["leaves"], np.float32)
+    feat = np.asarray(params["feat"])
+    gathered = x[:, feat.reshape(-1)].reshape(x.shape[0], *feat.shape)
+    thr_q = np.asarray(q["thr_q"]).astype(bf16) * np.asarray(q["thr_scale"]).astype(bf16)
+    flipped = ((gathered > thr[None])
+               != (gathered.astype(bf16) > thr_q[None])).any(axis=-1)
+    half_step = np.asarray(q["leaf_scale"], np.float32)[:, 0] / 2.0
+    spread = leaves.max(axis=1) - leaves.min(axis=1)
+    margin_bound = (half_step[None] + flipped * spread[None]).sum(axis=1)
+    assert np.all(diff <= 0.25 * margin_bound + 1e-6)
+    assert flipped.any(), "no split flipped: the draw no longer tests the envelope"
 
 
 # ---------------------------------------------------------------------------

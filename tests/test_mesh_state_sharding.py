@@ -434,7 +434,7 @@ def test_gather_scatter_slots_match_numpy():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from igaming_platform_tpu.core.compat import shard_map
+    from jax import shard_map
     from igaming_platform_tpu.parallel import state_sharding as ss
 
     mesh = _mesh(4)

@@ -36,7 +36,7 @@ def test_pallas_kernel_matches_gather(forest):
 
     params, x = forest
     a = np.asarray(gbdt_raw(params, x))
-    b = np.asarray(gbdt_raw_pallas(params, x, tile_b=64, interpret=True))
+    b = np.asarray(gbdt_raw_pallas(params, x, tile_b=128, interpret=True))
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
-from igaming_platform_tpu.core.compat import shard_map
+from jax import shard_map
 
 from igaming_platform_tpu.core.config import ScoringConfig
 from igaming_platform_tpu.models.ensemble import make_score_fn

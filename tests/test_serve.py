@@ -1,7 +1,5 @@
 """Serving layer tests: feature store, HLL, batcher, TPU scoring engine."""
 
-import os
-
 import numpy as np
 
 from igaming_platform_tpu.core.config import BatcherConfig, ScoringConfig
@@ -394,92 +392,7 @@ def test_abuse_detector_long_history_ring_matches_dense():
     np.testing.assert_allclose(s_ring, s_dense, rtol=2e-4, atol=2e-5)
 
 
-def test_device_gate_refuses_degraded_boot_unless_opted_in(monkeypatch):
-    """On a wedged device tunnel the server must exit loudly, not hang
-    half-booted; SERVE_DEVICE_FALLBACK=cpu opts into host serving.
-
-    The probe is stubbed (not driven through env) so its _pin_cpu side
-    effects cannot leak a CPU pin into the rest of the session."""
-    import pytest
-
-    from igaming_platform_tpu.core import devices
-    from igaming_platform_tpu.serve.server import device_gate
-
-    monkeypatch.setattr(devices, "ensure_responsive_device",
-                        lambda *a, **k: "cpu (device tunnel unresponsive)")
-    monkeypatch.delenv("SERVE_DEVICE_FALLBACK", raising=False)
-    with pytest.raises(SystemExit):
-        device_gate()
-
-    monkeypatch.setenv("SERVE_DEVICE_FALLBACK", "cpu")
-    device_gate()  # opted in: warns and continues
-
-    # Healthy device: no gate at all.
-    monkeypatch.setattr(devices, "ensure_responsive_device",
-                        lambda *a, **k: None)
-    monkeypatch.delenv("SERVE_DEVICE_FALLBACK", raising=False)
-    device_gate()
-
-
-def test_persistent_compile_cache_config(monkeypatch, tmp_path):
-    """The cache is a TPU-boot-time optimization: disabled outright on
-    the CPU backend (reloading CPU AOT results trips XLA's SIGILL-hazard
-    feature-mismatch warning even same-host), keyed by backend + host
-    fingerprint otherwise, and '0' disables."""
-    import jax
-
-    from igaming_platform_tpu.core.devices import cache_dir_for, host_fingerprint
-    from igaming_platform_tpu.serve.server import enable_persistent_compile_cache
-
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        target = str(tmp_path / "xla")
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
-        # Tests run on the CPU backend: never cached.
-        assert jax.default_backend() == "cpu"
-        assert enable_persistent_compile_cache() is None
-
-        # The accelerator path resolves <base>/<backend>-<fingerprint>.
-        expected = os.path.join(target, f"tpu-{host_fingerprint()}")
-        assert cache_dir_for("tpu", target) == expected
-
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "0")
-        assert enable_persistent_compile_cache() is None
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
-
-
-def test_compile_cache_rejects_foreign_host_entries(tmp_path):
-    """An entry written under one host feature set lands in a directory
-    another feature set never resolves — the SIGILL-by-deserialization
-    path is structurally impossible, not merely survived."""
-    from igaming_platform_tpu.core.devices import host_fingerprint
-
-    a = tmp_path / "cpuinfo_a"
-    b = tmp_path / "cpuinfo_b"
-    a.write_text("flags\t\t: fpu sse sse2 avx avx2 avx512f\n")
-    b.write_text("flags\t\t: fpu sse sse2 avx avx2\n")
-    fp_a, fp_b = host_fingerprint(str(a)), host_fingerprint(str(b))
-    assert fp_a != fp_b
-
-    # Flag ORDER must not change the key (kernels list flags stably, but
-    # the fingerprint should not depend on it).
-    a2 = tmp_path / "cpuinfo_a2"
-    a2.write_text("flags\t\t: avx512f avx2 avx sse2 sse fpu\n")
-    assert host_fingerprint(str(a2)) == fp_a
-
-    # A cache entry written under fingerprint A is invisible under B.
-    base = tmp_path / "cache"
-    dir_a = base / f"cpu-{fp_a}"
-    dir_a.mkdir(parents=True)
-    (dir_a / "some-executable").write_bytes(b"\x00xla")
-    dir_b = base / f"cpu-{fp_b}"
-    assert not dir_b.exists()
-
-
-# -- CPU-fallback abuse policies (engine.go:462-466 floor semantics) ---------
+# -- CPU-backend abuse policies (engine.go:462-466 floor semantics) ----------
 
 
 def _planted_abuser(det):
@@ -503,7 +416,7 @@ def _normal_player(det):
 
 def test_abuse_heuristic_policy_separates_abuser_from_normal():
     """ABUSE_CPU_POLICY=heuristic: scalar pattern-matching over the same
-    ring buffers keeps the abuse path alive on CPU fallback; responses
+    ring buffers keeps the abuse path alive on a CPU boot; responses
     are flagged DEGRADED_CPU_HEURISTIC."""
     from igaming_platform_tpu.serve.abuse import SequenceAbuseDetector
 

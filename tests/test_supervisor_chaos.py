@@ -6,7 +6,7 @@ The acceptance bar of the supervisor PR, as tests:
 - kill a follower mid-soak: the front never wedges, serves single-host
   degraded responses BIT-EXACT to the full-mesh ones, and returns to
   full-mesh SERVING within the backoff budget once the follower restarts;
-- inject the round-4 tunnel wedge at the readback seam: the watchdog
+- inject a readback that never returns at the readback seam: the watchdog
   fails the in-flight window with UNAVAILABLE + retry-pushback metadata,
   the engine rebuilds (warmup replay), and subsequent RPCs succeed;
 - take the feature store down: ScoreTransaction keeps answering —
@@ -255,7 +255,7 @@ def test_feature_store_outage_serves_degraded_heuristic():
 
 
 # ---------------------------------------------------------------------------
-# Device-step watchdog (the tunnel-wedge shape)
+# Device-step watchdog (a step that never returns)
 
 
 def test_wedge_trips_watchdog_then_rpcs_recover():

@@ -47,9 +47,9 @@ def main() -> None:
     ap.add_argument("--threshold", type=float, default=0.5)
     args = ap.parse_args()
 
-    import jax
+    from igaming_platform_tpu.core.devices import require_device
 
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
+    require_device()
 
     from igaming_platform_tpu.models.sequence import sequence_forward
     from igaming_platform_tpu.serve.abuse import SequenceAbuseDetector

@@ -29,7 +29,7 @@ from tools.analysis.engine import (
 
 REPO_ROOT = Path(__file__).resolve().parent.parent.parent
 ROOTS = ("igaming_platform_tpu", "benchmarks", "tests", "tools")
-TOP_FILES = ("bench.py", "__graft_entry__.py")
+TOP_FILES = ("bench.py", "__graft_entry__.py", "chip_smoke.py")
 # proto_gen is generated; the fixture corpus under tests/ is a zoo of
 # deliberate violations the driver must not trip over in repo mode.
 EXCLUDED_PARTS = {"proto_gen", "fixtures"}

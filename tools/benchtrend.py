@@ -1,7 +1,7 @@
 """Perf-trajectory table over the committed bench artifacts.
 
 Every PR since r01 has committed a measured JSON artifact
-(``BENCH_r05.json``, ``DEADLINE_r12.json``, ``FUSED_r14.json``, ...).
+(``DEADLINE_r12.json``, ``SESSION_r13.json``, ``MESH_r15.json``, ...).
 Each records its own gates, but nothing reads them TOGETHER — a slow
 regression that stays inside each PR's noise bar is invisible until
 someone diffs artifacts by hand. This tool is that diff: it parses every
@@ -46,7 +46,7 @@ _ARTIFACT_RE = re.compile(
 # recursively (first depth-first hit). Order encodes preference: the
 # headline e2e figure beats a nested arm figure.
 FLAT_OUT_PATHS = (
-    "e2e_txns_per_sec",                  # BENCH_r03+ wire headline
+    "e2e_txns_per_sec",                  # bench.py wire headline
     "flat_out.txns_per_sec",             # DEADLINE_r12
     "session_ab.rows_per_s_session_on",  # SESSION_r13 stateful flat-out
     "hostprof_on_txns_per_sec",          # HOSTPROF_r16 profiled arm
@@ -54,11 +54,11 @@ FLAT_OUT_PATHS = (
 )
 PACED_P99_PATHS = (
     "paced.rpc_p99_ms",              # DEADLINE_r12 open-loop paced
-    "fused_arm.paced_rpc_p99_ms",    # FUSED_r14
+    "fused_arm.paced_rpc_p99_ms",    # bench.py --fused
     "sharded_arm.paced_rpc_p99_ms",  # MESH_r15
 )
 E2E_P99_PATHS = (
-    "e2e_rpc_p99_ms",        # BENCH_r03+
+    "e2e_rpc_p99_ms",        # bench.py
     "flat_out.rpc_p99_ms",   # DEADLINE_r12 closed-loop arm
     "rpc_p99_ms",            # soak / matrix lines
 )
@@ -132,7 +132,7 @@ def _extract(doc, paths) -> tuple[float | None, str | None]:
 
 def _headline_throughput(doc) -> tuple[float | None, str | None]:
     """The earliest artifacts' {metric, value} headline when it is a
-    throughput (BENCH_r01/r02 device figures)."""
+    throughput (the matrix lines' shape)."""
     if not isinstance(doc, dict):
         return None, None
     metric = doc.get("metric")

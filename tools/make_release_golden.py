@@ -43,7 +43,9 @@ def main() -> None:
     import jax
     from flax import serialization
 
-    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise SystemExit("release goldens pin CPU numerics: run with "
+                         "JAX_PLATFORMS=cpu")
 
     from igaming_platform_tpu.core.config import ScoringConfig
     from igaming_platform_tpu.models.ensemble import make_score_fn
