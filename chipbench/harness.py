@@ -1,0 +1,555 @@
+"""One run of one cell: boot the risk.v1 server in-process, fill its
+resident state, check its outputs against the plain reference, warm the
+cell's shapes, measure a window, reduce what it left behind.
+
+The program is driven through its normal entry points only
+(``serve/server.RiskServer``, a real gRPC socket, ``ScoreBatch``); the
+harness edits nothing in it. Two seams are used from outside:
+``serve/ledger.wall_clock`` (the program's injected clock seam) is held
+to a seeded time during the output check, so that inter-event gaps come
+from the seed; and the transformer session head's parameters are
+replaced by a seeded tree of the same shapes before any traffic.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import threading
+import time
+
+import numpy as np
+
+from chipbench import reference, traffic, trace_reduce
+from chipbench.readers import READERS, Readings, roofline_bound
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(HERE, "out")
+FILL_NOW = 1_800_000_000.0  # the fixed "now" of every admission gather
+SCORE_BATCH = "/risk.v1.RiskService/ScoreBatch"
+# The rehearsal (CPU, --rehearse) runs every phase at this size.
+REHEARSAL = {"resident_accounts": 4096, "store_loaded_accounts": 1024,
+             "fill_chunk": 1024, "pool_frames": 64}
+TRACE_SLICE_S = 2.5
+RPC_TIMEOUT_S = 120.0
+
+
+def process_age_s() -> float:
+    """Seconds since this process was created (so set-up includes the
+    interpreter's start and every import)."""
+    with open("/proc/self/stat", encoding="ascii") as f:
+        start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+    with open("/proc/uptime", encoding="ascii") as f:
+        uptime = float(f.read().split()[0])
+    return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+
+
+def log(msg: str) -> None:
+    print(f"[chipbench {process_age_s():7.2f}s] {msg}", flush=True)
+
+
+class Clock:
+    """Stands in for ``serve/ledger.wall_clock`` while the check runs."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self) -> float:
+        return self.now
+
+
+class Run:
+    def __init__(self, spec: dict, *, seed: int, seconds: float, trace: bool,
+                 rehearse: bool):
+        self.spec, self.seed, self.seconds = spec, int(seed), float(seconds)
+        self.trace, self.rehearse = trace, rehearse
+        self.config = dict(spec["config"])
+        self.mix = dict(spec["traffic"])
+        if rehearse:
+            for key in ("resident_accounts", "store_loaded_accounts",
+                        "fill_chunk"):
+                self.config[key] = REHEARSAL[key]
+            self.config["env"] = dict(
+                self.config["env"],
+                FEATURE_CACHE_CAPACITY=str(REHEARSAL["resident_accounts"]))
+            self.mix["pool_frames"] = REHEARSAL["pool_frames"]
+        self.phase_s: dict[str, float] = {}
+        self.pool = None
+        self.index_mode = self.mix["rpc"] == "index"
+
+    # -- boot ---------------------------------------------------------------
+
+    def boot(self) -> None:
+        t0 = time.perf_counter()
+        os.environ.update(self.config["env"])
+        import jax
+
+        from igaming_platform_tpu.core import devices as devices_mod
+
+        backend = devices_mod.require_device()
+        if backend == "cpu" and not self.rehearse:
+            raise SystemExit("no accelerator: this benchmark measures a TPU; "
+                             "pass --rehearse for a CPU rehearsal")
+        chips = int(self.spec["cell"]["chips"])
+        if backend != "cpu" and len(jax.devices()) < chips:
+            raise SystemExit(f"the cell needs {chips} chip(s); JAX found "
+                             f"{len(jax.devices())}")
+        self.jax, self.device = jax, jax.devices()[0]
+        devices_mod.enable_persistent_compile_cache()
+
+        import dataclasses
+
+        import grpc
+
+        from igaming_platform_tpu.core.config import RiskServiceConfig
+        from igaming_platform_tpu.serve.server import RiskServer, device_gate
+
+        device_gate()
+        self.params = reference.make_params(self.seed, tuple(self.config["trunk"]))
+        config = RiskServiceConfig.from_env()
+        config = dataclasses.replace(config, batcher=dataclasses.replace(
+            config.batcher, batch_size=int(self.config["env"]["BATCH_SIZE"])))
+        self.server = RiskServer(config, ml_backend=self.config["ml_backend"],
+                                 params=self.params, grpc_port=0, http_port=0)
+        inner = self.inner = self.server.engine.inner
+        if inner.cache is None or inner.session is None:
+            raise SystemExit("the server booted without its feature cache or "
+                             "session plane")
+        if backend != "cpu" and type(inner.features).__name__ != "NativeFeatureStore":
+            raise SystemExit("a TPU boot must serve from the native feature store")
+        self.head = inner.session.head
+        self.head_params = None
+        if self.head == "transformer":
+            self.head_params = reference.make_head_params(self.seed)
+            inner.session.head_params = jax.device_put(self.head_params, self.device)
+        self.channel = grpc.insecure_channel(
+            f"localhost:{self.server.grpc_port}",
+            options=[("grpc.max_receive_message_length", 64 << 20),
+                     ("grpc.max_send_message_length", 64 << 20)])
+        self.call = self.channel.unary_unary(
+            SCORE_BATCH, request_serializer=lambda b: b,
+            response_deserializer=lambda b: b)
+        self.phase_s["boot"] = time.perf_counter() - t0
+        log(f"boot {self.phase_s['boot']:.2f} s on {self.device.device_kind} "
+            f"(head={self.head})")
+
+    def shutdown(self) -> None:
+        self.channel.close()
+        self.server.shutdown(grace=5.0)
+
+    # -- resident state ------------------------------------------------------
+
+    def fill(self) -> None:
+        """Admit every resident account through the cache's own lookup, in
+        equal chunks, so one CLOCK governs table and ring as in serving."""
+        t0 = time.perf_counter()
+        cfg, inner = self.config, self.inner
+        resident = int(cfg["resident_accounts"])
+        self.pop = pop = traffic.Population(self.mix, resident, self.seed)
+        rng = traffic.rng_for(self.seed, "store")
+        loaded = min(int(cfg["store_loaded_accounts"]), resident)
+        agg = {
+            "total_deposits": rng.integers(0, 500_000, loaded),
+            "total_withdrawals": rng.integers(0, 300_000, loaded),
+            "deposit_count": rng.integers(0, 60, loaded),
+            "withdraw_count": rng.integers(0, 30, loaded),
+            "total_bets": rng.integers(0, 900_000, loaded),
+            "total_wins": rng.integers(0, 800_000, loaded),
+            "bet_count": rng.integers(0, 400, loaded),
+            "win_count": rng.integers(0, 200, loaded),
+            "bonus_claim_count": rng.integers(0, 8, loaded),
+        }
+        age_days = rng.uniform(0.5, 900.0, loaded)
+        cols = {k: v.tolist() for k, v in agg.items()}
+        created = (FILL_NOW - age_days * 86_400.0).tolist()
+        load = inner.features.load_batch_features
+        for r in range(loaded):
+            load(pop.id_of_rank(r), created_at=created[r],
+                 **{k: v[r] for k, v in cols.items()})
+        self.phase_s["store"] = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        chunk = int(cfg["fill_chunk"])
+        # The ring's admission sync (SessionStateManager._sync) does not
+        # compile on a v5e once the ring passes ~1.5M slots: XLA re-lays the
+        # whole ring out in (8,128) tiles, 10.7 times its size (PERF.md,
+        # PR 24). A never-seen account's window is all zeros, which is what a
+        # freshly booted ring already holds in every slot, so the fill admits
+        # with the hook detached and leaves the same state the sync would:
+        # every resident account's window is EMPTY when the window starts
+        # (`session_events_preloaded` 0, under `reduced`). No entry point of
+        # the program can preload them: a warm window lives in a host buffer
+        # of its own per account (`_AcctSession`, 3 KB), and only scoring
+        # traffic writes one.
+        hook, inner.cache.session_hook = inner.cache.session_hook, None
+        try:
+            for lo in range(0, resident, chunk):
+                inner.cache.lookup(pop.ids[lo:lo + chunk], now=FILL_NOW)
+        finally:
+            inner.cache.session_hook = hook
+        self.jax.block_until_ready((inner.cache.table, inner.session.session_ring))
+        stats = inner.cache.stats()
+        if stats["occupancy"] != resident or stats["evictions"]:
+            raise SystemExit(f"fill left the cache at {stats}")
+        self.phase_s["fill"] = time.perf_counter() - t1
+        log(f"fill: population+store {self.phase_s['store']:.2f} s, "
+            f"{resident} accounts admitted in {self.phase_s['fill']:.2f} s, "
+            f"{int(cfg['session_events_preloaded'])} session events preloaded")
+        self.memory_line("after fill")
+
+    def memory_line(self, when: str) -> dict:
+        stats = self.device.memory_stats() or {}
+        log(f"memory {when}: bytes_in_use={stats.get('bytes_in_use')} "
+            f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}")
+        return stats
+
+    # -- the output check ----------------------------------------------------
+
+    def check(self) -> tuple[bool, dict]:
+        """Send the seeded check sequence once and hold every reply to the
+        plain reference at the configuration's stated precision."""
+        from igaming_platform_tpu.serve import ledger as ledger_mod
+
+        t0 = time.perf_counter()
+        cfg, inner = self.config, self.inner
+        seq = traffic.check_sequence(
+            self.mix, self.pop, self.seed,
+            loaded=int(cfg["store_loaded_accounts"]),
+            stored=int(cfg["store_accounts"]))
+        clock, real_clock = Clock(), ledger_mod.wall_clock
+        self.check_log = []
+        ledger_mod.wall_clock = clock
+        try:
+            for rpc in seq:
+                n = len(rpc["ids"])
+                clock.now = rpc["clock"]
+                gather_now = FILL_NOW if self.index_mode else time.time()
+                base = self.base_rows(rpc["ids"], gather_now)
+                reply = self.call(traffic.encode_frame(
+                    self.mix["rpc"], rpc["ids"], rpc["amounts"], rpc["types"]),
+                    timeout=300)
+                got = traffic.decode_proto_response(reply)
+                if len(got["score"]) != n:
+                    raise SystemExit(f"check RPC returned {len(got['score'])} "
+                                     f"of {n} rows")
+                self.check_log.append((rpc, base, got))
+        finally:
+            ledger_mod.wall_clock = real_clock
+        verdict = self.judge(cfg["precision"]["reference_operand_dtype"])
+        self.phase_s["check"] = time.perf_counter() - t0
+        return verdict
+
+    def base_rows(self, ids: list[str], now: float) -> np.ndarray:
+        """The feature store's host gather of the accounts' base rows (no
+        transaction context), as the cache takes them at admission."""
+        store, n = self.inner.features, len(ids)
+        if hasattr(store, "gather_columns"):
+            return store.gather_columns(ids, [0] * n, [""] * n, now=now)[0]
+        x = np.zeros((n, reference.N_FEATURES), np.float32)
+        for i, a in enumerate(ids):
+            store.fill_row(x[i], a, 0, "", now=now)
+        return x
+
+    def judge(self, operand_dtype: str, control: bool = False) -> tuple[bool, dict]:
+        """The recorded replies against the reference at the configuration's
+        stated precision. With ``control`` the replies are set aside and
+        the reference computed with operands rounded to ``operand_dtype``,
+        one precision step down, stands in the program's place: it has to
+        come out as not correct."""
+        cfg = self.config
+        stated = cfg["precision"]["reference_operand_dtype"]
+        # XLA's CPU backend multiplies float32 operands as they are; only
+        # the MXU rounds them. The trunk casts explicitly on both.
+        head_stated = "float32" if self.device.platform == "cpu" else stated
+
+        def replay(dtype, head_dtype):
+            ref = reference.Reference(
+                self.params, head=self.head, head_params=self.head_params,
+                n_events=int(cfg["env"]["SESSION_EVENTS"]),
+                operand_dtype=dtype, head_operand_dtype=head_dtype)
+            for rpc, base, _ in self.check_log:
+                if self.index_mode:
+                    yield ref.score_index(rpc["ids"], base, rpc["amounts"],
+                                          rpc["types"], rpc["clock"])
+                else:
+                    yield ref.score_rows(base, rpc["amounts"], rpc["types"])
+
+        wants = list(replay(stated, head_stated))
+        exacts = list(replay("float32", "float32"))
+        if control:
+            gots = [reference.as_reply(o)
+                    for o in replay(operand_dtype, operand_dtype)]
+        else:
+            gots = [got for _, _, got in self.check_log]
+        numbers = reference.merge([reference.compare(g, w, e)
+                                   for g, w, e in zip(gots, wants, exacts)])
+        ok, lines = reference.judge(numbers, cfg["limits"])
+        tag = "control" if control else "check"
+        for line in lines:
+            log(line.replace("check", tag, 1))
+        log(f"{tag} rows={numbers['rows']} warm={numbers['warm_rows']} "
+            f"folded={numbers['folded_rows']} operands={operand_dtype}")
+        return ok, numbers
+
+    # -- counters ------------------------------------------------------------
+
+    def counters(self) -> dict:
+        """Every counter the per-layer readers may name: the program's
+        metric registry by Prometheus name (labels summed), the cache's and
+        session plane's own counters, the compile count."""
+        out: dict[str, float] = {}
+        for line in self.server.metrics.registry.render_text().splitlines():
+            if line.startswith("# TYPE ") and line.endswith(" counter"):
+                # a counter nothing has incremented renders no sample: it
+                # reads 0, not "nothing to read"
+                out.setdefault(line.split(" ")[2], 0.0)
+            if line.startswith("#") or " " not in line:
+                continue
+            name, value = line.rsplit(" ", 1)
+            name = name.split("{", 1)[0]
+            try:
+                out[name] = out.get(name, 0.0) + float(value)
+            except ValueError:
+                continue
+        for k, v in self.inner.cache.stats().items():
+            out[f"cache.{k}"] = float(v)
+        snap = self.inner.session.snapshot()
+        out["session.appends"] = float(snap["appends"])
+        for k, v in snap["rows"].items():
+            out[f"session.rows_{k}"] = float(v)
+        out["compiles"] = float(
+            self.server.telemetry.compile_watcher.compiles_total)
+        return out
+
+    def stage_totals(self) -> dict:
+        from igaming_platform_tpu.obs import hostprof
+
+        stages = hostprof.get_default().snapshot()["stages"]
+        return {name: float(s["total_us"]) for name, s in stages.items()}
+
+    # -- the window ----------------------------------------------------------
+
+    def warm_up(self, pool) -> None:
+        """One frame of every size the window will send (the check has sent
+        them once already; this also starts every client thread's path)."""
+        t0 = time.perf_counter()
+        seen = set()
+        for payload, rows in pool:
+            if rows not in seen:
+                seen.add(rows)
+                for _ in range(3):
+                    self.call(payload, timeout=300)
+        self.phase_s["warm_up"] = time.perf_counter() - t0
+
+    def window(self) -> dict:
+        import grpc
+
+        if self.pool is None:
+            self.pool = traffic.build_pool(self.mix, self.pop, self.seed)
+            # the population (a list of every account id) has done its work:
+            # a real client holds no such list, and the collector would
+            # walk it
+            self.pop = None
+        pool = self.pool
+        self.warm_up(pool)
+        mix, seconds = self.mix, self.seconds
+        records: list[tuple] = []  # (due, done, rows, ok)
+        lock = threading.Lock()
+        c0, s0 = self.counters(), self.stage_totals()
+        tracer = Tracer(self) if self.trace else None
+        self.setup_s = process_age_s()
+        t0 = time.perf_counter()
+        t_end = t0 + seconds
+
+        def closed_client(c: int, k: int) -> None:
+            mine, i = pool[c::k], 0
+            local, due = [], time.perf_counter()
+            while due < t_end:
+                payload, rows = mine[i % len(mine)]
+                i += 1
+                try:
+                    self.call(payload, timeout=RPC_TIMEOUT_S)
+                    ok = True
+                except grpc.RpcError:
+                    ok = False
+                done = time.perf_counter()
+                local.append((due, done, rows, ok))
+                due = done
+            with lock:
+                records.extend(local)
+
+        k = int(mix["clients"])
+        threads = [threading.Thread(target=closed_client, args=(c, k),
+                                    name=f"chipbench-client-{c}")
+                   for c in range(k)]
+        for t in threads:
+            t.start()
+        if tracer:
+            tracer.slice(t0, seconds)
+        for t in threads:
+            t.join()
+        if tracer:
+            tracer.load()
+        t_last = max(r[1] for r in records)
+        c1, s1 = self.counters(), self.stage_totals()
+        log(f"window: {len(records)} RPCs in {t_last - t0:.3f} s")
+        return self.reduce(records, t0, t_last, c0, c1, s0, s1, tracer)
+
+    # -- reduction -----------------------------------------------------------
+
+    def reduce(self, records, t0, t_last, c0, c1, s0, s1, tracer) -> dict:
+        due, done, rows, ok = np.array(records, float).T
+        ok = ok.astype(bool)
+        elapsed = t_last - t0
+        rows_ok = int(rows[ok].sum())
+        # a shed or failed RPC counts as slower than any reply
+        latency_ms = np.where(ok, (done - due) * 1000.0, np.inf)
+        pads: dict[int, int] = {}  # padded batch -> executions
+        for size, count in zip(*np.unique(rows[ok], return_counts=True)):
+            shape = int(self.inner._pick_shape(int(size)))
+            pads[shape] = pads.get(shape, 0) + int(count)
+        chunk_rows = int(self.config["env"]["BATCH_SIZE"])
+        chunks_ok = int(np.ceil(rows[ok] / chunk_rows).sum())
+        counters = {k: c1[k] - c0.get(k, 0.0) for k in c1}
+        counters.update({"client.rpcs_sent": float(len(rows)),
+                         "client.rpcs_ok": float(ok.sum()),
+                         "client.rows_ok": float(rows_ok),
+                         "client.chunks_ok": float(chunks_ok)})
+        # the median reply time of all RPCs of the window, failed ones
+        # counted as slower than any reply
+        p50 = float(np.percentile(latency_ms, 50))
+        if not np.isfinite(p50):
+            p50 = RPC_TIMEOUT_S * 1e3
+        end_to_end = {"txns_per_s": rows_ok / elapsed, "rpc_p50_ms": p50,
+                      "setup_s": self.setup_s}
+        # in-window identities: they are part of `correct`
+        scored = counters["session.appends"] + counters["session.rows_bypass"]
+        failed_chunks = int(np.ceil(rows[~ok] / chunk_rows).sum())
+        dispatches = counters["risk_device_dispatches_total"]
+        identities = {
+            "rows_acked_minus_rows_scored": (rows_ok - scored, 0, 0),
+            "dispatches_minus_chunks": (dispatches - chunks_ok, 0, failed_chunks),
+            "compiles_in_window": (counters["compiles"], 0, 0),
+        }
+        correct = True
+        for key, (value, lo, hi) in identities.items():
+            good = lo <= value <= hi
+            correct = correct and good
+            log(f"check {key} = {value!r} limit [{lo}, {hi}] "
+                f"{'ok' if good else 'FAILED'}")
+        readings = Readings(
+            config=self.config, rows_ok=rows_ok,
+            stages={k: s1[k] - s0.get(k, 0.0) for k in s1},
+            counters=counters,
+            latency_ms=latency_ms,
+            index_mode=self.index_mode, device_kind=self.device.device_kind,
+            pad_rows=pads)
+        breakdown = None
+        device_extra = {}
+        if tracer is not None and tracer.result is not None:
+            readings.trace, readings.trace_window, spans = tracer.result
+            busy = trace_reduce.busy_seconds(readings.trace, readings.trace_window)
+            lo, hi = readings.trace_window
+            device_extra = {"busy_s": busy, "window_s": (hi - lo) / 1e9}
+            breakdown = {
+                "device_ops": trace_reduce.top_device_ops(
+                    readings.trace, readings.trace_window),
+                "idle_gaps": trace_reduce.idle_gaps(
+                    readings.trace, readings.trace_window, spans),
+            }
+        per_layer = {}
+        for m in self.spec["per_layer"]:
+            value = READERS[m["reader"]](m, readings)
+            if value is not None:
+                per_layer[m["name"]] = {"value": float(value), "unit": m["unit"]}
+            if m["reader"] == "trace_roofline_share" and value is not None:
+                log(f"{m['name']} is bound by {roofline_bound(m, readings)}")
+        if readings.trace is not None and readings.trace.device_ops:
+            # a traced line that lacks a metric this cell owes is refused
+            for m in self.spec["per_layer"]:
+                if m["name"] not in per_layer:
+                    log(f"MISSING per-layer metric {m['name']}: its reader "
+                        f"{m['reader']} found nothing to read in this cell")
+        finite = latency_ms[np.isfinite(latency_ms)]
+        log("latency ms: " + json.dumps({
+            f"p{q}": round(float(np.percentile(finite, q)), 3)
+            for q in (50, 75, 90, 95, 99, 100)}))
+        from igaming_platform_tpu.obs import hostprof
+        log("gc: " + json.dumps(hostprof.get_default().gc_snapshot())[:600])
+        log("stages us/row: " + json.dumps(
+            {k: round(v / max(rows_ok, 1), 3)
+             for k, v in sorted(readings.stages.items()) if v}))
+        return {
+            "correct": correct, "attempted": int(len(rows)),
+            "failed": int((~ok).sum()), "elapsed_s": elapsed,
+            "end_to_end": {
+                m["name"]: {"value": float(end_to_end[m["name"]]),
+                            "unit": m["unit"]}
+                for m in self.spec["end_to_end"]},
+            "per_layer": per_layer, "breakdown": breakdown,
+            "device_extra": device_extra,
+        }
+
+
+class Tracer:
+    """Profiles a short slice of the window and collects the program's
+    own spans over it, both on one clock."""
+
+    def __init__(self, run: Run):
+        self.run = run
+        self.dir = os.path.join(OUT_DIR, "trace", run.spec["cell"]["name"])
+        self.spans: list[tuple] = []
+        self.result = None
+
+    def _sink(self, span) -> None:
+        if span.mono_end:
+            self.spans.append((span.name, span.mono_start, span.mono_end))
+
+    def slice(self, t0: float, seconds: float) -> None:
+        """Called on the main thread while the clients run."""
+        import shutil
+
+        from igaming_platform_tpu.obs import tracing
+
+        jax = self.run.jax
+        length = min(TRACE_SLICE_S, seconds / 2)
+        start_at = t0 + min(2.0, seconds / 4)
+        shutil.rmtree(self.dir, ignore_errors=True)
+        time.sleep(max(0.0, start_at - time.perf_counter()))
+        jax.profiler.start_trace(self.dir)
+        tracing.add_span_sink(self._sink)
+        mark_lo = time.perf_counter()
+        with jax.profiler.TraceAnnotation("chipbench_mark_lo"):
+            time.sleep(0.001)
+        time.sleep(length)
+        mark_hi = time.perf_counter()
+        with jax.profiler.TraceAnnotation("chipbench_mark_hi"):
+            time.sleep(0.001)
+        tracing.remove_span_sink(self._sink)
+        jax.profiler.stop_trace()
+        self.marks = (mark_lo, mark_hi)
+
+    def load(self) -> None:
+        """After the window: read the trace and put the spans on its clock."""
+        try:
+            path = trace_reduce.find_xplane(self.dir)
+        except FileNotFoundError as exc:
+            log(f"trace: {exc}")
+            return
+        trace, summary = trace_reduce.load_xplane(path)
+        os.makedirs(OUT_DIR, exist_ok=True)
+        trace_reduce.dump_json(trace, os.path.join(OUT_DIR, "trace_cut.json"),
+                               limit=400)
+        with open(os.path.join(OUT_DIR, "trace_summary.json"), "w",
+                  encoding="utf-8") as f:
+            json.dump(summary, f, indent=1)
+        marks = {name: start for name, start, _ in trace.host_marks}
+        if "chipbench_mark_lo" not in marks or "chipbench_mark_hi" not in marks:
+            log(f"trace: marks not found among {sorted(marks)}")
+            return
+        lo, hi = marks["chipbench_mark_lo"], marks["chipbench_mark_hi"]
+        offset_ns = lo - int(self.marks[0] * 1e9)  # trace clock - perf_counter
+        spans = [(name, int(a * 1e9) + offset_ns, int((b - a) * 1e9))
+                 for name, a, b in self.spans]
+        self.result = (trace, (lo, hi), spans)
+        log(f"trace: {sum(len(v) for v in trace.device_ops.values())} device "
+            f"ops, {len(spans)} program spans over {(hi - lo) / 1e9:.3f} s")
