@@ -3,16 +3,24 @@
 PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: all test test-fast chip-smoke lint lint-json lint-changed lint-sarif lint-update-baseline ci-static bench bench-all bench-fused bench-mesh bench-hostprof bench-trend bench-paced bench-replicas drill eval native proto run-risk run-wallet dryrun clean soak soak-wire soak-chaos soak-fleet-chaos soak-chaos-ledger soak-slo soak-online soak-drift soak-session soak-deadline replay-verify fleet api-test migrate-up migrate-down migrate-status seed docker-build docker-push infra-up infra-down
+.PHONY: all test test-loaded chip-smoke lint lint-json lint-changed lint-sarif lint-update-baseline ci-static bench bench-all bench-fused bench-mesh bench-hostprof bench-trend bench-paced bench-replicas drill eval native proto run-risk run-wallet dryrun clean soak soak-wire soak-chaos soak-fleet-chaos soak-chaos-ledger soak-slo soak-online soak-drift soak-session soak-deadline replay-verify fleet api-test migrate-up migrate-down migrate-status seed docker-build docker-push infra-up infra-down
 
 all: native test
 
-# Full test suite on the virtual 8-device CPU mesh.
-test:
-	$(PY) -m pytest tests/ -q
+# The tier-1 suite as the driver runs it (/root/TESTS_LAST_RUN.json):
+# 'not slow', six xdist workers, one file per worker at a time, on the
+# virtual 8-device CPU mesh (tests/conftest.py). The driver's clock is
+# 1470 s; `timeout` holds a local run to the same.
+TIER1 = timeout -k 10 1470 env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
+	--continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile -p no:randomly
 
-test-fast:
-	$(PY) -m pytest tests/ -x -q -p no:cacheprovider
+test:
+	$(TIER1)
+
+# Rehearsal of the driver's machine: the same command held to one core.
+# It must end in half the clock (735 s) with the same count.
+test-loaded:
+	taskset -c 0 $(TIER1)
 
 # The quickest proof the system still starts on the chip: one process,
 # the real server over gRPC at the flagship width, trainer hot-swap,

@@ -89,7 +89,8 @@ def config1_single_txn_latency(n_requests: int = 200, batch_size: int = 256) -> 
 
 
 def config2_replay_throughput(
-    n_events: int = 10_000, batch_size: int = 4096, pipeline_depth: int = 8
+    n_events: int = 10_000, batch_size: int = 4096, pipeline_depth: int = 8,
+    store_max_accounts: int | None = None,
 ) -> dict:
     from igaming_platform_tpu.core.config import BatcherConfig
     from igaming_platform_tpu.serve.bridge import ScoringBridge
@@ -111,11 +112,15 @@ def config2_replay_throughput(
             for i in range(n)
         ]
 
-    from igaming_platform_tpu.serve.native_store import best_feature_store
+    from igaming_platform_tpu.serve.native_store import (
+        DEFAULT_MAX_ACCOUNTS,
+        best_feature_store,
+    )
 
     engine = TPUScoringEngine(
         batcher_config=BatcherConfig(batch_size=batch_size, max_wait_ms=1.0),
-        feature_store=best_feature_store(),
+        feature_store=best_feature_store(
+            max_accounts=store_max_accounts or DEFAULT_MAX_ACCOUNTS),
     )
     bridge = ScoringBridge(engine, default_broker(), publish_risk_events=False)
     try:
@@ -141,7 +146,8 @@ def config2_replay_throughput(
         engine.close()
 
 
-def config3_sequence_throughput(batch: int = 64, seq_len: int = 256, iters: int = 20) -> dict:
+def config3_sequence_throughput(batch: int = 64, seq_len: int = 256, iters: int = 20,
+                                long_s: int = 2048) -> dict:
     import jax
 
     from igaming_platform_tpu.models.sequence import (
@@ -174,7 +180,6 @@ def config3_sequence_throughput(batch: int = 64, seq_len: int = 256, iters: int 
     # Long-context point: S=2048 event histories through the Pallas
     # flash-attention core (BASELINE config 3's long-sequence story) —
     # smaller batch, same model. Reported alongside the short-seq figure.
-    long_s = 2048
     long_batch = max(8, batch // 8)
     x_long = np.random.default_rng(1).normal(
         size=(long_batch, long_s, EVENT_DIM)

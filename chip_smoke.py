@@ -48,6 +48,7 @@ from igaming_platform_tpu.core.devices import (  # noqa: E402
     device_label as device_stamp,
     enable_persistent_compile_cache,
 )
+from igaming_platform_tpu.serve.native_store import DEFAULT_MAX_ACCOUNTS  # noqa: E402
 
 # Bounds the repo already states (train/device_parity.py, the host-tier
 # note in serve/scorer.py): chip-vs-CPU fraud probability within 1e-2,
@@ -200,7 +201,8 @@ def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
                  mesh_devices: int = 0, abuse_events: int = 300,
                  steady_passes: int = 6, singles: int = 4,
                  train_steps: int = 5, train_batch: int | None = None,
-                 reference: dict | None = None) -> dict:
+                 reference: dict | None = None,
+                 store_max_accounts: int = DEFAULT_MAX_ACCOUNTS) -> dict:
     """The assembly ``serve/server.py:main()`` makes — device_gate,
     compile cache, ``RiskServer`` — with the multitask backend at
     DEFAULT_TRUNK and seeded params, driven over a real gRPC socket.
@@ -244,7 +246,8 @@ def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
             mock.patch.dict(os.environ, FEATURE_CACHE="1", SESSION_STATE="1"):
         warnings.simplefilter("always")
         server = RiskServer(config, ml_backend="multitask", params=params,
-                            grpc_port=0, http_port=0)
+                            grpc_port=0, http_port=0,
+                            store_max_accounts=store_max_accounts)
     report["boot_s"] = round(time.perf_counter() - t0, 2)
     try:
         donated = [str(w.message) for w in caught
@@ -260,6 +263,13 @@ def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
         def dispatches() -> int:
             return int(_metric(_http_get(server.http_port, "/metrics")[1],
                                "risk_device_dispatches_total"))
+
+        def hedges() -> int:
+            # A single whose collect overruns the step model's stall
+            # threshold is launched a second time and the two race
+            # (serve/batcher._collect_hedged): an honest second dispatch.
+            return int(json.loads(_http_get(
+                server.http_port, "/debug/deadlinez")[1])["batches_hedged"])
 
         host_tier = inner._fn_host is not None
         report["host_tier_built"] = host_tier
@@ -408,11 +418,13 @@ def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
         # Interactive traffic goes LAST in each window: a single arms the
         # burn->shed gate for BURN_SHED_IDLE_S, and the slow first-call
         # RPCs above (compiles inside requests) burn the fast SLO window.
-        d0 = dispatches()
+        d0, h0 = dispatches(), hedges()
         single_resps = score_singles(singles)
         first["singles"] = dispatches() - d0
-        check(first["singles"] == singles,
-              f"{singles} sequential singles made {first['singles']} dispatches")
+        first["singles_hedged"] = hedges() - h0
+        check(first["singles"] == singles + first["singles_hedged"],
+              f"{singles} sequential singles made {first['singles']} "
+              f"dispatches ({first['singles_hedged']} hedged)")
         launched = json.loads(_http_get(
             server.http_port, "/debug/telemetryz")[1])["compile"]["signature_names"]
         on_host = any(s.split(":")[0] in ("fused_host_step", "packed_step_host")
@@ -508,7 +520,7 @@ def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
                   "the burn->shed gate did not disarm within 90 s")
             time.sleep(0.5)
         report["burn_gate_wait_s"] = round(time.perf_counter() - t_gate, 1)
-        c_steady, d_steady = compiles(), dispatches()
+        c_steady, d_steady, h_steady = compiles(), dispatches(), hedges()
         ready_code, ready_body = _http_get(server.http_port, "/ready")
         sent = 0
         for _ in range(steady_passes):
@@ -552,11 +564,13 @@ def phase_server(batch_size: int = 8192, *, small_rows: tuple = (64, 256),
         report["steady"] = {
             "chunks_sent": sent,
             "dispatches": dispatches() - d_steady,
+            "hedged": hedges() - h_steady,
             "compiles": compiles() - c_steady,
         }
-        check(report["steady"]["dispatches"] == sent,
+        check(report["steady"]["dispatches"]
+              == sent + report["steady"]["hedged"],
               f"steady window: {report['steady']['dispatches']} dispatches "
-              f"for {sent} chunks sent")
+              f"for {sent} chunks sent ({report['steady']['hedged']} hedged)")
         check(report["steady"]["compiles"] == 0,
               f"{report['steady']['compiles']} compile(s) after warm-up: "
               f"{list(telemetry.compile_watcher.events)[-3:]}")

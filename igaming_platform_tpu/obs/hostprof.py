@@ -327,8 +327,12 @@ class HostProfiler:
         self._installed = False
         self._gc_installed = False
         # GC accounting (all guarded by _lock except the start stamp,
-        # which only the collecting thread touches while it holds the GIL).
+        # which only the collecting thread touches while it holds the GIL,
+        # and the queue between the GC hook and _drain_gc; bounded, so a
+        # process that completes no span and takes no snapshot cannot
+        # grow it without end).
         self._gc_start_ns: dict[int, int] = {}
+        self._gc_pending: deque = deque(maxlen=65_536)
         self._gc_collections: dict[int, int] = {}
         self._gc_pause_ms_total: dict[int, float] = {}
         self._gc_pauses: deque = deque(maxlen=_GC_PAUSE_RING)
@@ -376,6 +380,8 @@ class HostProfiler:
     def _on_span(self, span) -> None:
         """Extra span sink (tracing): every completed span lands here.
         Must stay O(1) and never raise — it runs on serving threads."""
+        if self._gc_pending:
+            self._drain_gc()
         name = span.name
         us = span.duration_ms * 1000.0
         if name.startswith("rpc."):
@@ -416,6 +422,11 @@ class HostProfiler:
     # -- GC watch ------------------------------------------------------------
 
     def _gc_callback(self, phase: str, info: dict) -> None:
+        """Takes no lock: a collection starts wherever an allocation
+        happens, including on a thread that holds ``_lock`` or a metric's
+        lock (a snapshot building its lists, a /metrics render), and none
+        of them is reentrant. The pause is queued (``deque.append`` is
+        atomic) and folded in by :meth:`_drain_gc` from ordinary code."""
         try:
             gen = int(info.get("generation", 0))
             if phase == "start":
@@ -433,6 +444,18 @@ class HostProfiler:
                 root = span.root if span.root is not None else span
                 if root.name.startswith("rpc."):
                     inflight[root.span_id] = root.trace_id
+            self._gc_pending.append(
+                (gen, pause_ms, info.get("collected"), sorted(inflight.values())))
+        except Exception:  # noqa: BLE001 — a GC hook must never break collection
+            pass
+
+    def _drain_gc(self) -> None:
+        """Fold queued GC pauses into the accounting and the metrics."""
+        while True:
+            try:
+                gen, pause_ms, collected, trace_ids = self._gc_pending.popleft()
+            except IndexError:
+                return
             with self._lock:
                 self._gc_collections[gen] = self._gc_collections.get(gen, 0) + 1
                 self._gc_pause_ms_total[gen] = (
@@ -440,19 +463,17 @@ class HostProfiler:
                 self._gc_pauses.append({
                     "generation": gen,
                     "pause_ms": round(pause_ms, 4),
-                    "collected": info.get("collected"),
-                    "inflight_rpcs": len(inflight),
-                    "trace_ids": sorted(inflight.values())[:4],
+                    "collected": collected,
+                    "inflight_rpcs": len(trace_ids),
+                    "trace_ids": trace_ids[:4],
                 })
-                if inflight:
+                if trace_ids:
                     self._gc_pauses_in_rpc += 1
                     self._gc_pause_in_rpc_ms += pause_ms
             m = self.metrics
             if m is not None:
                 m.gc_collections_total.inc(generation=str(gen))
                 m.gc_pause_ms.observe(pause_ms, generation=str(gen))
-        except Exception:  # noqa: BLE001 — a GC hook must never break collection
-            pass
 
     # -- snapshots -----------------------------------------------------------
 
@@ -508,6 +529,7 @@ class HostProfiler:
         return {"stages": out, "rpc": rpc_block}
 
     def gc_snapshot(self) -> dict:
+        self._drain_gc()
         with self._lock:
             return {
                 "collections": {str(g): n for g, n
@@ -531,6 +553,7 @@ class HostProfiler:
 
     def reset(self) -> None:
         """Zero the accounting (bench arms isolate their windows)."""
+        self._gc_pending.clear()
         with self._lock:
             self._stages.clear()
             self._rpc = _StageAcc()

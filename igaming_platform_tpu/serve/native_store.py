@@ -26,6 +26,10 @@ from igaming_platform_tpu.core.features import F, NUM_FEATURES
 
 _TX_TYPE_CODES = {"deposit": 0, "withdraw": 1, "bet": 2, "win": 3}
 
+# Accounts a store holds unless its caller sizes it: 4.2 KB each,
+# allocated and touched eagerly (4.48 GB).
+DEFAULT_MAX_ACCOUNTS = 1_000_000
+
 _hash_cache: dict[str, int] = {}
 
 
@@ -126,7 +130,7 @@ def native_available() -> bool:
 class NativeFeatureStore:
     """C++-backed feature store with the InMemoryFeatureStore interface."""
 
-    def __init__(self, max_accounts: int = 1_000_000, history_capacity: int = 128,
+    def __init__(self, max_accounts: int = DEFAULT_MAX_ACCOUNTS, history_capacity: int = 128,
                  hll_precision: int = 10):
         if not native_available():
             raise RuntimeError("native feature store unavailable (g++ build failed)")
@@ -385,11 +389,12 @@ class NativeFeatureStore:
         return x[:rc], bl[:rc].astype(bool)
 
 
-def best_feature_store(**kwargs):
-    """Native store when the toolchain allows, Python store otherwise."""
+def best_feature_store(max_accounts: int = DEFAULT_MAX_ACCOUNTS, **kwargs):
+    """Native store (of ``max_accounts``) when the toolchain allows,
+    Python store otherwise."""
     if native_available():
         try:
-            return NativeFeatureStore()
+            return NativeFeatureStore(max_accounts=max_accounts)
         except RuntimeError:
             pass
     from igaming_platform_tpu.serve.feature_store import InMemoryFeatureStore

@@ -527,6 +527,14 @@ class RouterForwardError(RuntimeError):
     UNAVAILABLE with the standard retry-pushback hint."""
 
 
+# A replica's status that walks the ring instead of answering the caller:
+# UNAVAILABLE (dead socket, refused connection, a shedding supervisor) and
+# CANCELLED, which is what a replica stopping without grace hands the calls
+# it had in flight. Scoring is pure on the request, so the next owner's
+# answer is the same answer.
+_FAILOVER_CODES = (grpc.StatusCode.UNAVAILABLE, grpc.StatusCode.CANCELLED)
+
+
 def _pushback_ms_from(exc: grpc.RpcError) -> int | None:
     """The server's standard retry hint, off the trailing metadata."""
     try:
@@ -802,7 +810,7 @@ class ScoringRouter:
                         payload, timeout=timeout_s,
                         metadata=self._outbound_metadata(metadata, ddl))
             except grpc.RpcError as exc:
-                if exc.code() != grpc.StatusCode.UNAVAILABLE:
+                if exc.code() not in _FAILOVER_CODES:
                     raise  # the replica answered; its status is the answer
                 tried.add(target)
                 last_exc = exc
@@ -853,7 +861,7 @@ class ScoringRouter:
             pass  # straggler: hedge below
         except grpc.RpcError as exc:
             # A FAST failure is the retry path's job, not the hedge's.
-            if exc.code() != grpc.StatusCode.UNAVAILABLE:
+            if exc.code() not in _FAILOVER_CODES:
                 raise
             if _pushback_ms_from(exc) is None:
                 self.watcher.note_forward_failure(primary.id, exc)

@@ -30,6 +30,7 @@ from igaming_platform_tpu.serve.grpc_server import (
     graceful_stop,
     serve_risk,
 )
+from igaming_platform_tpu.serve.native_store import DEFAULT_MAX_ACCOUNTS
 from igaming_platform_tpu.serve.scorer import ScoreRequest, TPUScoringEngine
 
 logger = logging.getLogger(__name__)
@@ -98,6 +99,7 @@ class RiskServer:
         grpc_port: int | None = None,
         http_port: int | None = None,
         engine_factory=None,
+        store_max_accounts: int = DEFAULT_MAX_ACCOUNTS,
     ):
         self.config = config or RiskServiceConfig.from_env()
         self.metrics = ServiceMetrics("risk")
@@ -148,7 +150,7 @@ class RiskServer:
             if native_available():
                 from igaming_platform_tpu.serve.native_store import NativeFeatureStore
 
-                feature_store = NativeFeatureStore()
+                feature_store = NativeFeatureStore(max_accounts=store_max_accounts)
                 store_name = "native"
             elif self.config.feature_store == "native" or backend != "cpu":
                 raise RuntimeError(
@@ -970,7 +972,7 @@ def _multihost_mesh():
     return global_mesh(MeshSpec(data=-1))
 
 
-def main() -> None:
+def main(store_max_accounts: int = DEFAULT_MAX_ACCOUNTS) -> None:
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(levelname)s %(message)s")
 
     # Multi-host serving roles (serve/multihost.py): one FRONT process
@@ -1045,13 +1047,14 @@ def main() -> None:
             logger.warning("MULTIHOST_ROLE=front ignores MESH_DEVICES/MESH_SEQ/"
                            "MESH_EXPERT — the multihost mesh owns the devices")
             config = dataclasses.replace(config, mesh_devices=0)
-        server = RiskServer(config, engine_factory=factory)
+        server = RiskServer(config, engine_factory=factory,
+                            store_max_accounts=store_max_accounts)
         server.wait_for_signal()
         return
     if role:
         raise RuntimeError(f"MULTIHOST_ROLE={role!r} not recognized (front|follower)")
 
-    server = RiskServer()
+    server = RiskServer(store_max_accounts=store_max_accounts)
     server.wait_for_signal()
 
 

@@ -81,7 +81,7 @@ def test_ticker_runs_periodically(tmp_path):
     fs = InMemoryFeatureStore()
     job = BatchFeatureRefreshJob(fs, wallet_store_source(path), interval_s=0.01)
     job.start()
-    deadline = time.time() + 2.0
+    deadline = time.time() + 60.0
     while job.last_refresh_count == 0 and time.time() < deadline:
         time.sleep(0.01)
     job.stop()

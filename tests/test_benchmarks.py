@@ -23,13 +23,14 @@ def test_config1_runs():
 
 
 def test_config2_runs():
-    r = config2_replay_throughput(n_events=300, batch_size=64)
+    r = config2_replay_throughput(n_events=300, batch_size=64,
+                                  store_max_accounts=4096)
     assert r["events"] == 300
     assert r["value"] > 0
 
 
 def test_config3_runs():
-    r = config3_sequence_throughput(batch=4, seq_len=32, iters=2)
+    r = config3_sequence_throughput(batch=4, seq_len=32, iters=2, long_s=128)
     assert r["value"] > 0
 
 

@@ -125,7 +125,7 @@ def test_risk_server_assembled():
         scoring=ScoringConfig(),
         batcher=BatcherConfig(batch_size=32, max_wait_ms=1),
     )
-    server = RiskServer(cfg, grpc_port=0, http_port=0)
+    server = RiskServer(cfg, grpc_port=0, http_port=0, store_max_accounts=4096)
     try:
         base = f"http://localhost:{server.http_port}"
         with urllib.request.urlopen(f"{base}/health") as r:
@@ -150,7 +150,7 @@ def test_risk_server_assembled():
         pub.publish(EXCHANGE_WALLET, tx_event("srv-acct", 4000, "deposit"))
         import time
 
-        deadline = time.time() + 5
+        deadline = time.time() + 60
         while time.time() < deadline and server.bridge.events_processed < 1:
             time.sleep(0.05)
         assert server.bridge.events_processed >= 1
@@ -176,7 +176,7 @@ def test_risk_server_with_multi_device_mesh(monkeypatch):
     monkeypatch.setenv("BATCH_SIZE", "64")
     monkeypatch.setenv("GRPC_PORT", "0")
     monkeypatch.setenv("HTTP_PORT", "0")
-    server = RiskServer(RiskServiceConfig.from_env())
+    server = RiskServer(RiskServiceConfig.from_env(), store_max_accounts=4096)
     try:
         import jax
         assert server.engine._mesh is not None
@@ -202,16 +202,16 @@ def test_ready_reflects_device_liveness(monkeypatch):
     monkeypatch.setenv("GRPC_PORT", "0")
     monkeypatch.setenv("HTTP_PORT", "0")
     monkeypatch.delenv("MESH_DEVICES", raising=False)
-    server = RiskServer(RiskServiceConfig.from_env())
+    server = RiskServer(RiskServiceConfig.from_env(), store_max_accounts=4096)
     try:
         body = json.load(urllib.request.urlopen(
-            f"http://localhost:{server.http_port}/ready", timeout=5))
+            f"http://localhost:{server.http_port}/ready", timeout=30))
         assert body == {"ready": True, "device": True}
 
         # Device probe failing -> 503, not a hang.
         server.device_alive = lambda timeout_s=2.0: False
         try:
-            urllib.request.urlopen(f"http://localhost:{server.http_port}/ready", timeout=5)
+            urllib.request.urlopen(f"http://localhost:{server.http_port}/ready", timeout=30)
             raise AssertionError("expected 503")
         except urllib.error.HTTPError as e:
             assert e.code == 503
@@ -236,7 +236,7 @@ def test_risk_server_with_sequence_parallel_abuse(monkeypatch):
     monkeypatch.setenv("BATCH_SIZE", "64")
     monkeypatch.setenv("GRPC_PORT", "0")
     monkeypatch.setenv("HTTP_PORT", "0")
-    server = RiskServer(RiskServiceConfig.from_env())
+    server = RiskServer(RiskServiceConfig.from_env(), store_max_accounts=4096)
     try:
         import jax
         assert server.engine._mesh.shape["seq"] == 2

@@ -134,7 +134,7 @@ def test_wallet_events_reach_risk_bridge_over_amqp(monkeypatch):
                 rabbitmq_url=broker.url,
                 batcher=BatcherConfig(batch_size=16, max_wait_ms=1.0),
             ),
-            grpc_port=0, http_port=0,
+            grpc_port=0, http_port=0, store_max_accounts=4096,
         )
         wallet = WalletServer(
             WalletServiceConfig(

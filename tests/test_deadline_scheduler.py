@@ -107,9 +107,12 @@ def test_monotonic_clock_discipline():
     ddl = Deadline.after_ms(100.0)
     # The anchor IS a monotonic reading: remaining is consistent with
     # monotonic elapsed regardless of what the wall clock does.
-    assert abs(
-        (ddl.remaining_ms()) -
-        (100.0 - (time.monotonic() - ddl.born_at) * 1000.0)) < 5.0
+    before = time.monotonic()
+    remaining = ddl.remaining_ms()
+    after = time.monotonic()
+    assert (100.0 - (after - ddl.born_at) * 1000.0 - 1e-6
+            <= remaining
+            <= 100.0 - (before - ddl.born_at) * 1000.0 + 1e-6)
     repo = pathlib.Path(__file__).resolve().parent.parent
     for rel in ("igaming_platform_tpu/serve/deadline.py",
                 "igaming_platform_tpu/serve/batcher.py"):
@@ -169,7 +172,7 @@ def test_expired_in_queue_is_shed_not_returned():
     time.sleep(0.02)  # first item expires while queued
     assert s.poll(0.1).payload == "live"
     with pytest.raises(DeadlineExpired) as ei:
-        fut.result(timeout=1)
+        fut.result(timeout=60)
     assert ei.value.stage == "dispatch"
     assert expired_counts == [(1, "dispatch", LANE_BULK)]
 
@@ -262,9 +265,9 @@ def test_batcher_sheds_expired_and_scores_live():
     time.sleep(0.02)
     b.start()
     live = b.submit(21, deadline=Deadline.after_ms(5000))
-    assert live.result(timeout=5) == 42
+    assert live.result(timeout=60) == 42
     with pytest.raises(DeadlineExpired):
-        dead.result(timeout=1)
+        dead.result(timeout=60)
     assert b.dead_dispatched == 0
     b.stop()
 
@@ -321,7 +324,7 @@ def test_batcher_plan_hook_reports_chosen_shape():
     b.on_plan = shapes_seen.append
     b.start()
     try:
-        b.submit(1, deadline=Deadline.after_ms(1000)).result(timeout=5)
+        b.submit(1, deadline=Deadline.after_ms(1000)).result(timeout=60)
         assert shapes_seen and all(s in (8, 64) for s in shapes_seen)
     finally:
         b.stop()

@@ -32,8 +32,10 @@ def test_parity_harness_self_consistent_on_cpu():
     reason="device run: set TPU_PARITY_TEST=1 on a TPU host",
 )
 def test_trained_models_match_cpu_on_device():
+    # The device run sees none of what tests/conftest.py sets for the CPU.
     env = {k: v for k, v in os.environ.items()
-           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR",
+                        "JAX_DISABLE_MOST_OPTIMIZATIONS")}
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (repo_root, env.get("PYTHONPATH", "")) if p)

@@ -105,7 +105,20 @@ def test_chip_smoke_main_refuses_to_run_without_a_tpu():
     assert '"ok"' not in proc.stdout
 
 
-def test_smoke_environment_native_and_cache_phases():
+@pytest.fixture
+def _no_compile_cache(monkeypatch, _restore_cache_dir):
+    """The suite's own cache (conftest) taken away: what a CPU boot with
+    no JAX_COMPILATION_CACHE_DIR sees."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()  # JAX decides once per process
+    yield
+    compilation_cache.reset_cache()
+
+
+def test_smoke_environment_native_and_cache_phases(_no_compile_cache):
     env = chip_smoke.phase_environment(require_tpu=False)
     assert env["device"]["platform"] == "cpu" and env["backend"] == "cpu"
     assert env["cache_dir"] is None and env["cache_dir_from_env"] is False
@@ -125,7 +138,8 @@ def test_smoke_environment_native_and_cache_phases():
 
 
 _TINY_SERVER = dict(batch_size=32, small_rows=(8,), abuse_events=66,
-                    steady_passes=4, singles=1, train_batch=32)
+                    steady_passes=4, singles=1, train_batch=32,
+                    store_max_accounts=4096)
 
 
 @pytest.fixture
@@ -142,7 +156,8 @@ def test_smoke_server_phase_tiny(_tiny_server_env):
     # server ride the slow-marked test below: tier-1 is near its budget.
     report = chip_smoke.phase_server(train_steps=0, **_TINY_SERVER)
     assert report["steady"]["compiles"] == 0
-    assert report["steady"]["dispatches"] == report["steady"]["chunks_sent"]
+    assert (report["steady"]["dispatches"]
+            == report["steady"]["chunks_sent"] + report["steady"]["hedged"])
     assert report["vs_cpu"]["max_score_delta"] == 0  # CPU vs CPU
     assert report["single_tier"] == "device"         # no host tier on a CPU boot
     assert report["ports_released"] and report["session_rows"]["warm"] > 0

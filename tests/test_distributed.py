@@ -2,7 +2,6 @@
 + REAL two-OS-process DCN runs (bootstrap, collectives, DP training)."""
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -33,14 +32,10 @@ sys.path.insert(0, os.environ["REPO_ROOT"])
 """
 
 
-def _run_two_workers(tmp_path, body: str, timeout: float = 240.0) -> list[str]:
+def _run_two_workers(tmp_path, port: int, body: str, timeout: float = 240.0) -> list[str]:
     """Spawn two worker processes running PREAMBLE+body with the
     COORDINATOR_ADDRESS/NUM_PROCESSES/PROCESS_ID env contract; returns
-    their outputs, asserting both exited 0."""
-    with socket.socket() as s:  # free coordinator port
-        s.bind(("localhost", 0))
-        port = s.getsockname()[1]
-
+    their outputs, asserting both exited 0. ``port`` is the coordinator's."""
     worker = tmp_path / "worker.py"
     worker.write_text(_WORKER_PREAMBLE + textwrap.dedent(body))
 
@@ -116,14 +111,14 @@ def test_engine_with_mesh_shards_batches():
         eng_single.close()
 
 
-def test_two_process_dcn_bootstrap_and_collectives(tmp_path):
+def test_two_process_dcn_bootstrap_and_collectives(tmp_path, free_port):
     """REAL multi-process run: two OS processes bootstrap through
     initialize_from_env (the production env contract), build the global
     mesh spanning both processes' devices, and run a cross-process
     gradient-style reduction plus process_batch_slice sharding — the
     DCN scale-out story executed for real (gloo-backed CPU collectives),
     not simulated on one process."""
-    outs = _run_two_workers(tmp_path, """
+    outs = _run_two_workers(tmp_path, free_port(), """
         from igaming_platform_tpu.parallel.distributed import (
             global_mesh, initialize_from_env, is_primary, process_batch_slice,
         )
@@ -156,7 +151,7 @@ def test_two_process_dcn_bootstrap_and_collectives(tmp_path):
         assert f"OK process={i}" in out, out[-500:]
 
 
-def test_two_process_dp_training_matches_single_process(tmp_path):
+def test_two_process_dp_training_matches_single_process(tmp_path, free_port):
     """DP gradient sync over REAL process boundaries: two OS processes
     train the multitask net on complementary halves of one global batch
     (psum over gloo), and their per-step losses must match a
@@ -173,7 +168,7 @@ def test_two_process_dp_training_matches_single_process(tmp_path):
     stream = make_stream(global_batch, seed=seed)
     ref_losses = [ref.train_step(next(stream))["loss"] for _ in range(steps)]
 
-    outs = _run_two_workers(tmp_path, f"""
+    outs = _run_two_workers(tmp_path, free_port(), f"""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from igaming_platform_tpu.parallel.distributed import (
@@ -214,7 +209,7 @@ def test_two_process_dp_training_matches_single_process(tmp_path):
         np.testing.assert_allclose(got, ref_losses, rtol=2e-4, atol=2e-5)
 
 
-def test_two_process_scoring_matches_single_process(tmp_path):
+def test_two_process_scoring_matches_single_process(tmp_path, free_port):
     """The SERVING ensemble across REAL process boundaries: two OS
     processes execute one jitted score step over a global [B,30] batch
     (rows sharded over DCN, outputs replicated back via gloo
@@ -237,7 +232,7 @@ def test_two_process_scoring_matches_single_process(tmp_path):
     ref_scores = np.asarray(ref["score"]).tolist()
     ref_actions = np.asarray(ref["action"]).tolist()
 
-    outs = _run_two_workers(tmp_path, f"""
+    outs = _run_two_workers(tmp_path, free_port(), f"""
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -132,9 +132,9 @@ def test_fleetz_serves_merged_drift_with_dead_replica_stale_stamped():
                         metrics=ServiceMetrics("risk"))
     try:
         view.scrape_once()
-        t0 = time.monotonic()
+        scrapes = view.scrapes_total
         snap = view.snapshot()
-        assert time.monotonic() - t0 < 0.5, "snapshot must not scrape"
+        assert view.scrapes_total == scrapes, "snapshot must not scrape"
         fd = snap["fleet_drift"]
         assert fd["rows"] == 200  # both live replicas merged exactly
         assert fd["merge_errors"] == []
@@ -190,7 +190,7 @@ def drift_server(tmp_path_factory):
         scoring=ScoringConfig(),
         batcher=BatcherConfig(batch_size=32, max_wait_ms=1),
     )
-    server = RiskServer(cfg, grpc_port=0, http_port=0)
+    server = RiskServer(cfg, grpc_port=0, http_port=0, store_max_accounts=4096)
     try:
         yield server
     finally:

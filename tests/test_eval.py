@@ -1,6 +1,8 @@
 """Model-quality eval: labeled fraud generator + metric math + ordering."""
 
+import jax
 import numpy as np
+import pytest
 
 from igaming_platform_tpu.train.eval import (
     average_precision,
@@ -9,6 +11,18 @@ from igaming_platform_tpu.train.eval import (
     run_eval,
 )
 from igaming_platform_tpu.train.fraudgen import generate_labeled
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _optimized_programs():
+    """These tests run hundreds of training steps over thousands of rows:
+    here XLA's optimizer pays for itself (55 against 79 CPU-seconds for the
+    file), unlike in the rest of the suite (tests/conftest.py)."""
+    name = "jax_disable_most_optimizations"
+    before = jax.config.values[name]
+    jax.config.update(name, False)
+    yield
+    jax.config.update(name, before)
 
 
 def test_metric_math_known_values():
@@ -59,8 +73,6 @@ def test_routed_training_improves_over_untrained_bundle():
     """Joint router+experts training beats the fresh bundle by a wide
     margin, the trained router spreads load, and the bundle drops into
     the serving engine's routed backend."""
-    import jax
-
     from igaming_platform_tpu.parallel.ep import gate_probs
     from igaming_platform_tpu.train.routed import (
         RoutedTrainConfig,

@@ -152,6 +152,15 @@ def test_gc_pause_attributed_to_inflight_rpc(profiler):
     assert metrics.gc_pause_ms.calls
 
 
+def test_gc_hook_takes_no_lock(profiler):
+    """A collection can start on a thread that already holds the
+    profiler's lock (any allocation under it): the hook must queue the
+    pause, not wait for a lock its own thread holds."""
+    with profiler._lock:
+        gc.collect()
+    assert sum(profiler.gc_snapshot()["collections"].values()) >= 1
+
+
 def test_heap_block_gauges(profiler):
     heap = profiler.snapshot()["heap"]
     assert heap["allocated_blocks"] > 0
@@ -186,7 +195,7 @@ def test_sampler_folds_registered_thread_by_active_span(profiler):
         summary = profiler.sampler.stop()
     finally:
         stop.set()
-        worker.join(timeout=5.0)
+        worker.join(timeout=60.0)
         hostprof.unregister_scoring_thread(worker.ident)
     assert summary["samples_total"] > 0
     assert "stage_worker" in summary["roles_seen"]
@@ -215,7 +224,7 @@ def test_speedscope_export_shape(profiler):
         profiler.sampler.stop()
     finally:
         stop.set()
-        worker.join(timeout=5.0)
+        worker.join(timeout=60.0)
         hostprof.unregister_scoring_thread(worker.ident)
     prof = profiler.sampler.to_speedscope()
     assert prof["$schema"].startswith("https://www.speedscope.app")
@@ -246,7 +255,7 @@ def test_sampler_never_touches_unregistered_threads(profiler):
         profiler.sampler.stop()
     finally:
         stop.set()
-        worker.join(timeout=5.0)
+        worker.join(timeout=60.0)
     assert not any("span:score.anon" in k
                    for k in profiler.sampler.folded())
 
@@ -327,7 +336,7 @@ def risk_server():
         scoring=ScoringConfig(),
         batcher=BatcherConfig(batch_size=32, max_wait_ms=1),
     )
-    server = RiskServer(cfg, grpc_port=0, http_port=0)
+    server = RiskServer(cfg, grpc_port=0, http_port=0, store_max_accounts=4096)
     try:
         yield server
     finally:

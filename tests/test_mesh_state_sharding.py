@@ -163,7 +163,7 @@ def test_fused_sketch_variant_parity_on_sharded_mesh():
     def sketched(d):
         import time
 
-        deadline = time.monotonic() + 5.0
+        deadline = time.monotonic() + 60.0
         while time.monotonic() < deadline:
             if d.rows_sketched >= 48:  # 2 rounds x 24 rows
                 return d.rows_sketched

@@ -16,7 +16,6 @@ and past the session TTL, one shared seed timestamp, calls back-to-back.
 """
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -92,14 +91,8 @@ print("FRONT_CLEAN_EXIT", flush=True)
 """
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("localhost", 0))
-        return s.getsockname()[1]
-
-
-def test_two_process_full_server_parity(tmp_path):
-    coord, follower_port = _free_port(), _free_port()
+def test_two_process_full_server_parity(tmp_path, free_port):
+    coord, follower_port = free_port(), free_port()
     seed_now = time.time()
     done_path = str(tmp_path / "done")
     worker = tmp_path / "worker.py"
@@ -277,7 +270,7 @@ def _start_follower_stub(tmp_path, port: int, mode: str = "ack"):
     return proc
 
 
-def test_follower_death_degrades_loudly_not_wedged(tmp_path):
+def test_follower_death_degrades_loudly_not_wedged(tmp_path, free_port):
     """Kill the follower under load: the next broadcast must raise a
     typed MultihostChannelError within the io timeout — BEFORE the front
     would enter the dead collective — and every later call must fail
@@ -287,7 +280,7 @@ def test_follower_death_degrades_loudly_not_wedged(tmp_path):
         WorkChannel,
     )
 
-    port = _free_port()
+    port = free_port()
     proc = _start_follower_stub(tmp_path, port)
     chan = WorkChannel([port], io_timeout_s=5.0, ack_window=4)
     try:
@@ -308,23 +301,26 @@ def test_follower_death_degrades_loudly_not_wedged(tmp_path):
             for _ in range(10):
                 chan.broadcast(xp, blp, thr)
                 time.sleep(0.05)
+        # timing-ok: a wedge never returns; 20x the loop's own 0.5 s of sleeps
         assert time.monotonic() - t0 < 10.0, "detection must not wedge"
 
-        # Dead channel fails FAST from now on — no timeout, no retry.
+        # Dead channel fails FAST from now on — no timeout, no retry:
+        # well inside the io_timeout_s=5.0 a wait on the socket would cost.
         t0 = time.monotonic()
         try:
             chan.broadcast(xp, blp, thr)
             raise AssertionError("dead channel must keep failing")
         except MultihostChannelError:
             pass
-        assert time.monotonic() - t0 < 0.5
+        # timing-ok: half the channel's own 5 s timer; the raise itself does no I/O
+        assert time.monotonic() - t0 < 2.5
     finally:
         chan.close()
         if proc.poll() is None:
             proc.kill()
 
 
-def test_wedged_follower_ack_timeout(tmp_path):
+def test_wedged_follower_ack_timeout(tmp_path, free_port):
     """A follower that stays CONNECTED but stops completing steps (no
     ACKs) must trip the ACK timeout once the un-ACKed window fills —
     bounded detection instead of running unboundedly ahead of a wedged
@@ -334,7 +330,7 @@ def test_wedged_follower_ack_timeout(tmp_path):
         WorkChannel,
     )
 
-    port = _free_port()
+    port = free_port()
     proc = _start_follower_stub(tmp_path, port, mode="wedge")
     chan = WorkChannel([port], io_timeout_s=1.0, ack_window=2)
     try:
@@ -347,6 +343,7 @@ def test_wedged_follower_ack_timeout(tmp_path):
             for _ in range(20):
                 chan.broadcast(xp, blp, thr)
         elapsed = time.monotonic() - t0
+        # timing-ok: bounds the channel's own io_timeout_s=1.0 ACK timer, 15x over
         assert elapsed < 15.0, f"ACK timeout must bound detection, took {elapsed}"
     finally:
         chan.close()
@@ -354,11 +351,11 @@ def test_wedged_follower_ack_timeout(tmp_path):
             proc.kill()
 
 
-def test_model_mismatch_fails_handshake(tmp_path):
+def test_model_mismatch_fails_handshake(tmp_path, free_port):
     """A follower that resolved DIFFERENT params (e.g. its checkpoint
     silently degraded to mock) must die loudly at the boot handshake —
     never execute a divergent SPMD program on the shared mesh."""
-    coord, follower_port = _free_port(), _free_port()
+    coord, follower_port = free_port(), free_port()
     worker = tmp_path / "worker.py"
     worker.write_text(textwrap.dedent(_PREAMBLE + """
 import numpy as np

@@ -160,7 +160,7 @@ def test_native_gather_faster_than_python():
 
 
 def test_best_feature_store_returns_native():
-    store = best_feature_store()
+    store = best_feature_store(max_accounts=16)
     assert isinstance(store, NativeFeatureStore)
 
 

@@ -244,7 +244,7 @@ def test_wire_batch_feeds_score_distribution():
         _pytest.skip("native feature store unavailable")
     engine = TPUScoringEngine(
         ScoringConfig(), batcher_config=BatcherConfig(batch_size=32, max_wait_ms=1.0),
-        feature_store=native_store.NativeFeatureStore(),
+        feature_store=native_store.NativeFeatureStore(max_accounts=4096),
     )
     service = RiskGrpcService(engine)
     server, health, port = serve_risk(service, 0)

@@ -144,12 +144,18 @@ def test_rig_survives_adversarial_bytes(server):
 
     rng = __import__("numpy").random.default_rng(0)
 
-    def blast(payload: bytes) -> None:
+    def blast(payload: bytes, hang_up: bool = False) -> None:
         s = socket.socket()
         s.settimeout(2.0)
         try:
             s.connect(("127.0.0.1", server.port))
             s.sendall(payload)
+            if hang_up:
+                # A client that sends its bytes and closes its side: the
+                # server answers or drops it at once, where a silent
+                # client costs the full 2 s wait (60 mutants of it were
+                # 30 s of this test asleep).
+                s.shutdown(socket.SHUT_WR)
             try:
                 s.recv(4096)
             except OSError:
@@ -166,7 +172,7 @@ def test_rig_survives_adversarial_bytes(server):
         mutant = bytearray(valid_startup + b"user\x00tester\x00\x00")
         for _ in range(int(rng.integers(1, 4))):
             mutant[int(rng.integers(0, len(mutant)))] = int(rng.integers(0, 256))
-        blast(bytes(mutant))
+        blast(bytes(mutant), hang_up=True)
 
     # After all of that, a real client must still work end-to-end.
     conn = _connect(server)

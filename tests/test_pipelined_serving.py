@@ -138,7 +138,7 @@ class TestTwoPhaseBatcher:
         ).start()
         try:
             with pytest.raises(RuntimeError, match="boom-dispatch"):
-                b.submit(1).result(timeout=5)
+                b.submit(1).result(timeout=60)
         finally:
             b.stop()
 
@@ -150,7 +150,7 @@ class TestTwoPhaseBatcher:
         ).start()
         try:
             with pytest.raises(RuntimeError, match="boom-collect"):
-                b.submit(1).result(timeout=5)
+                b.submit(1).result(timeout=60)
         finally:
             b.stop()
 
@@ -159,7 +159,7 @@ class TestTwoPhaseBatcher:
         release = threading.Event()
 
         def collect(handle):
-            release.wait(timeout=5)
+            release.wait(timeout=60)
             return handle
 
         b = ContinuousBatcher(
@@ -171,7 +171,7 @@ class TestTwoPhaseBatcher:
         time.sleep(0.1)  # let the launcher dispatch
         release.set()
         b.stop()
-        assert [f.result(timeout=1) for f in futs] == [0, 1, 2, 3]
+        assert [f.result(timeout=60) for f in futs] == [0, 1, 2, 3]
 
     def test_requires_some_runner(self):
         with pytest.raises(ValueError):

@@ -64,7 +64,7 @@ def test_background_job_runs_and_stops(tmp_path):
     job = ReconciliationJob(rec, interval_s=0.01)
     job.start()
     import time
-    deadline = time.time() + 2.0
+    deadline = time.time() + 60.0
     while rec.last_report is None and time.time() < deadline:
         time.sleep(0.01)
     job.stop()

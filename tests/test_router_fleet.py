@@ -192,6 +192,44 @@ def test_non_unavailable_statuses_pass_through(fleet3):
         server.stop(0)
 
 
+def test_cancelled_by_a_stopping_replica_fails_over(fleet3, monkeypatch):
+    """A replica that stops without grace hands the calls it had in flight
+    CANCELLED, not UNAVAILABLE. The router walks the ring on it just the
+    same; surfaced, it reached the caller as UNKNOWN (the kill test below
+    met it on a loaded machine)."""
+
+    class _Cancelled(grpc.RpcError):
+        def code(self):
+            return grpc.StatusCode.CANCELLED
+
+        def details(self):
+            return "CANCELLED"
+
+        def trailing_metadata(self):
+            return ()
+
+    router, server, addr = _router_over(fleet3, hedge=False)
+    ch, txn, _ = _stubs(addr)
+    try:
+        acct = next(f"cx-{i}" for i in range(200)
+                    if router.ring.owner(f"cx-{i}") == "r1")
+        calls = []
+
+        def cancelled(payload, timeout=None, metadata=None):
+            calls.append(payload)
+            raise _Cancelled()
+
+        monkeypatch.setattr(router.replicas["r1"], "score_txn", cancelled)
+        resp = txn(risk_pb2.ScoreTransactionRequest(
+            account_id=acct, amount=4200, transaction_type="deposit"), timeout=30)
+        assert 0 <= resp.score <= 100
+        assert len(calls) == 1 and router.stats["retries"] == 1
+    finally:
+        ch.close()
+        router.close()
+        server.stop(0)
+
+
 def test_replica_kill_mid_load_only_ok_or_unavailable():
     """SIGKILL-shaped failover: one replica dies under load. Every client
     outcome is OK (router retried onto the next ring owner) or
@@ -226,7 +264,7 @@ def test_replica_kill_mid_load_only_ok_or_unavailable():
                 try:
                     txn(risk_pb2.ScoreTransactionRequest(
                         account_id=acct, amount=4200,
-                        transaction_type="deposit"), timeout=5)
+                        transaction_type="deposit"), timeout=30)
                     out = "OK"
                 except grpc.RpcError as exc:
                     out = exc.code().name
@@ -253,6 +291,7 @@ def test_replica_kill_mid_load_only_ok_or_unavailable():
         evicted_at = next(
             t for (t, rid, _o, new) in router.watcher.events
             if rid == victim.rid and new == "dead")
+        # timing-ok: 10x the watcher's own 0.1 s x 2 detection timer
         assert evicted_at - t_kill < 2.0
         # Post-kill: stranded accounts answer from the secondary owner,
         # bit-exact (identical params + empty history — a wrong-replica
@@ -282,7 +321,7 @@ def test_health_not_serving_evicts_and_recovery_readmits(fleet3):
         assert target.rid in router.ring.active
         # Supervisor BROWNOUT shape: health flips NOT_SERVING.
         target.health.set("", NOT_SERVING)
-        deadline = time.monotonic() + 3.0
+        deadline = time.monotonic() + 60.0
         while target.rid in router.ring.active and time.monotonic() < deadline:
             time.sleep(0.02)
         assert target.rid not in router.ring.active
@@ -290,7 +329,7 @@ def test_health_not_serving_evicts_and_recovery_readmits(fleet3):
         assert router.metrics.ring_replicas.value(state="brownout") == 1
         # Recovery: SERVING again -> readmitted.
         target.health.set("", SERVING)
-        deadline = time.monotonic() + 3.0
+        deadline = time.monotonic() + 60.0
         while target.rid not in router.ring.active and time.monotonic() < deadline:
             time.sleep(0.02)
         assert target.rid in router.ring.active
@@ -316,13 +355,11 @@ def test_hedge_straggler_secondary_wins_loser_cancelled():
         acct = next(f"hedge-{i}" for i in range(200)
                     if router.ring.owner(f"hedge-{i}") == "r2")
         secondary = router.ring.owners(acct, 2)[1]
-        t0 = time.monotonic()
         resp = txn(risk_pb2.ScoreTransactionRequest(
-            account_id=acct, amount=900, transaction_type="bet"), timeout=10)
-        elapsed = time.monotonic() - t0
+            account_id=acct, amount=900, transaction_type="bet"), timeout=30)
         assert 0 <= resp.score <= 100
-        # The hedge answered well before the 1 s straggler would have.
-        assert elapsed < 0.9
+        # The hedge answered before the 1 s straggler did: it won the
+        # race, and the primary never did.
         assert router.stats["hedges_launched"] == 1
         assert router.stats["hedge_wins"] == 1
         assert router.stats["primary_wins"] == 0
@@ -378,7 +415,7 @@ def test_hedge_primary_still_wins_when_it_finishes_first():
             r.close()
 
 
-def test_load_gen_retry_helper_honors_pushback():
+def test_load_gen_retry_helper_honors_pushback(monkeypatch):
     """The satellite fix: the client retry path consumes the server's
     grpc-retry-pushback-ms hint (PR 5 emitted it; no in-tree client
     respected it) with a jittered bounded sleep, counted in the stats."""
@@ -417,18 +454,27 @@ def test_load_gen_retry_helper_honors_pushback():
                           request_serializer=lambda b: b,
                           response_deserializer=lambda b: b)
     try:
+        import load_gen
+
+        slept: list[float] = []
+        real_sleep = time.sleep
+
+        def recording_sleep(seconds: float) -> None:
+            if threading.current_thread() is threading.main_thread():
+                slept.append(seconds)
+            real_sleep(seconds)
+
+        monkeypatch.setattr(load_gen.time, "sleep", recording_sleep)
         stats = _RetryStats()
-        t0 = time.monotonic()
         out = _call_with_retry([call], b"payload", (), stats,
                                np.random.default_rng(0))
-        elapsed = time.monotonic() - t0
+        monkeypatch.undo()
         assert out == b"payload"
         assert calls["n"] == 3
         assert stats.retries == 2
         assert stats.pushback_honored == 2
-        # Two honored 30 ms hints, jittered 0.5x-1.5x: the sleep really
-        # happened (>= 2 * 15 ms) and stayed bounded (< 2 * 45 ms + slack).
-        assert 0.03 <= elapsed < 0.5
+        # Two honored 30 ms hints, each slept once, jittered 0.5x-1.5x.
+        assert len(slept) == 2 and all(0.015 <= s < 0.045 for s in slept), slept
     finally:
         ch.close()
         server.stop(0)

@@ -299,11 +299,12 @@ def test_fleetz_survives_dead_and_hung_replica():
         scrape_wall = time.monotonic() - t0
         # Bounded: ~4 endpoints x 0.3 s for the hung replica, concurrent
         # across replicas — never a 30 s hang.
-        assert scrape_wall < 4.0, f"scrape blocked for {scrape_wall:.1f}s"
+        # timing-ok: the view's own 0.3 s timeouts, against a 30 s hang
+        assert scrape_wall < 15.0, f"scrape blocked for {scrape_wall:.1f}s"
 
-        t0 = time.monotonic()
+        scrapes = view.scrapes_total
         snap = view.snapshot()
-        assert time.monotonic() - t0 < 0.5, "snapshot must not scrape"
+        assert view.scrapes_total == scrapes, "snapshot must not scrape"
 
         by_rid = {r["replica"]: r for r in snap["replicas"]}
         assert by_rid["r0"]["stale"] is False
@@ -342,7 +343,7 @@ def test_fleetz_merges_stage_histograms_across_replicas():
         stage = view.snapshot()["fleet_stage_latency_ms"]["score.gather"]
         assert stage["count"] == 5
         # 4/5 <= 10ms -> p50 bucket bound well below the p99 bound.
-        assert stage["p50_ms"] <= 10.0
+        assert stage["p50_ms"] <= 10.0  # timing-ok: a bucket bound of seeded samples
         assert stage["p99_ms"] == 100.0
     finally:
         view.stop()

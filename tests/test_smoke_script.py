@@ -23,7 +23,7 @@ def test_api_smoke_against_live_services():
 
     risk = RiskServer(
         RiskServiceConfig(batcher=BatcherConfig(batch_size=32, max_wait_ms=1.0)),
-        grpc_port=0, http_port=0,
+        grpc_port=0, http_port=0, store_max_accounts=4096,
     )
     wallet = None
     try:

@@ -19,7 +19,6 @@ The acceptance bar of the supervisor PR, as tests:
 """
 
 import os
-import socket
 import subprocess
 import sys
 import threading
@@ -69,12 +68,6 @@ def _engine_factory(batch: int = 16):
             batcher_config=BatcherConfig(batch_size=batch, max_wait_ms=1.0),
         )
     return factory
-
-
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("localhost", 0))
-        return s.getsockname()[1]
 
 
 def _start_stub(port: int, mode: str = "ack", wedge_after: int = 0):
@@ -243,7 +236,7 @@ def test_feature_store_outage_serves_degraded_heuristic():
 
         # Store recovers -> real scores + SERVING again.
         chaos.clear()
-        deadline = time.monotonic() + 5
+        deadline = time.monotonic() + 60
         while sup.state != SERVING and time.monotonic() < deadline:
             stub.ScoreTransaction(risk_pb2.ScoreTransactionRequest(
                 account_id="rec", amount=1000, transaction_type="deposit"))
@@ -276,14 +269,15 @@ def test_wedge_trips_watchdog_then_rpcs_recover():
         # count=2: the batcher's stall hedge (serve/batcher.py) would
         # recover a SINGLE wedged readback by re-dispatching the batch —
         # to demonstrate the watchdog, the hedged collect must wedge too.
-        chaos.install("seed=5;device.readback=wedge:p=1.0:ms=2500:count=2")
+        chaos.install("seed=5;device.readback=wedge:p=1.0:ms=6000:count=2")
         t0 = time.monotonic()
         with pytest.raises(grpc.RpcError) as exc_info:
             stub.ScoreTransaction(req)
         err = exc_info.value
         # Loud UNAVAILABLE within ~the watchdog deadline, never a wedge.
         assert err.code() == grpc.StatusCode.UNAVAILABLE
-        assert time.monotonic() - t0 < 2.4
+        # timing-ok: the engine's own watchdog_s=1.0, against the 6 s wedge
+        assert time.monotonic() - t0 < 5.0
         trailing = dict(err.trailing_metadata() or ())
         assert trailing.get("grpc-retry-pushback-ms"), trailing
         assert service.metrics.watchdog_trips_total.value() == 1
@@ -300,7 +294,7 @@ def test_wedge_trips_watchdog_then_rpcs_recover():
             time.sleep(0.1)
         assert engine.rebuilds == 1
         chaos.clear()
-        deadline = time.monotonic() + 10
+        deadline = time.monotonic() + 60
         ok = None
         while time.monotonic() < deadline:
             ok = stub.ScoreTransaction(req)
@@ -368,11 +362,11 @@ def test_brownout_sheds_unavailable_with_pushback():
 # WorkChannel: broadcast thread-safety regression (satellite 1)
 
 
-def test_broadcast_concurrent_threads_ack_accounting(tmp_path):
+def test_broadcast_concurrent_threads_ack_accounting(tmp_path, free_port):
     """Two threads hammering broadcast must not race the per-socket mode
     transitions in the ACK reap: no spurious dead-marking, consistent
     un-ACKed accounting, channel alive at the end."""
-    port = _free_port()
+    port = free_port()
     proc = _start_stub(port)
     chan = multihost.WorkChannel([port], io_timeout_s=10.0, ack_window=4)
     errors: list[BaseException] = []
@@ -407,8 +401,8 @@ def test_broadcast_concurrent_threads_ack_accounting(tmp_path):
 # Follower kill -> single-host degraded -> resurrection, bit-exact
 
 
-def test_follower_kill_resurrection_bit_exact(tmp_path):
-    port = _free_port()
+def test_follower_kill_resurrection_bit_exact(tmp_path, free_port):
+    port = free_port()
     stub = _start_stub(port)
     sup = ServingSupervisor(failure_threshold=2, open_s=0.5)
     engine = multihost.multihost_engine(
@@ -432,7 +426,8 @@ def test_follower_kill_resurrection_bit_exact(tmp_path):
         # bit-exact to the full-mesh ones while the follower is down.
         t0 = time.monotonic()
         during = [(r.score, r.ml_score) for r in engine.score_batch(reqs)]
-        assert time.monotonic() - t0 < 5.0, "outage scoring must not wedge"
+        # timing-ok: a wedge never returns; the channel's own io_timeout_s is 2.0
+        assert time.monotonic() - t0 < 30.0, "outage scoring must not wedge"
         assert during == baseline
         assert not engine._chan.alive
         assert sup.state == DEGRADED
@@ -443,7 +438,7 @@ def test_follower_kill_resurrection_bit_exact(tmp_path):
         # SERVING with bit-exact scores.
         stub2 = _start_stub(port)
         t_restart = time.monotonic()
-        budget_s = 8.0
+        budget_s = 60.0
         while not engine._chan.alive and time.monotonic() - t_restart < budget_s:
             time.sleep(0.05)
         assert engine._chan.alive, "follower never resurrected in budget"
@@ -464,10 +459,10 @@ def test_follower_kill_resurrection_bit_exact(tmp_path):
                 p.kill()
 
 
-def test_resurrection_replays_param_hot_swap(tmp_path):
+def test_resurrection_replays_param_hot_swap(tmp_path, free_port):
     """A param hot-swap during the outage reaches the follower at
     resurrection via the provider replay (MAGIC_PARAMS before alive)."""
-    port = _free_port()
+    port = free_port()
     stub = _start_stub(port)
     chan = multihost.WorkChannel([port], io_timeout_s=2.0, ack_window=4,
                                  reconnect=True,
@@ -494,7 +489,7 @@ def test_resurrection_replays_param_hot_swap(tmp_path):
         leaves_served[0] = np.ones((4,), np.float32)
 
         stub2 = _start_stub(port)
-        deadline = time.monotonic() + 8
+        deadline = time.monotonic() + 60
         while not chan.alive and time.monotonic() < deadline:
             time.sleep(0.05)
         assert chan.alive
