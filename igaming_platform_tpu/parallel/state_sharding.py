@@ -137,21 +137,36 @@ def local_slot_index(local_rows: int, slots):
     return jnp.where(owned, li, local_rows), owned
 
 
+def select_owner(contrib, slots, local_rows: int):
+    """all_gather every shard's contribution over ``data`` and select the
+    owner's copy of each row (never summed)."""
+    import jax
+    import jax.numpy as jnp
+
+    allc = jax.lax.all_gather(contrib, AXIS_DATA)  # [K, B, ...]
+    owner = jnp.clip(slots // local_rows, 0, allc.shape[0] - 1)
+    return allc[owner, jnp.arange(slots.shape[0])]
+
+
 def gather_slots(local, slots):
     """Exact sharded gather: ``local`` is this shard's row block of a
     slot-sharded array; ``slots`` are GLOBAL slot ids (replicated).
     Returns the full gathered rows, identical on every shard — each
-    shard contributes its owned rows, the contributions all_gather over
-    ``data`` and the owner's copy is selected (never summed)."""
-    import jax
-    import jax.numpy as jnp
-
+    shard contributes its owned rows (others read as zero fill) and
+    :func:`select_owner` picks the owner's copy."""
     local_rows = local.shape[0]
     li, _ = local_slot_index(local_rows, slots)
     contrib = local.at[li].get(mode="fill", fill_value=0)
-    allc = jax.lax.all_gather(contrib, AXIS_DATA)  # [K, B, ...]
-    owner = jnp.clip(slots // local_rows, 0, allc.shape[0] - 1)
-    return allc[owner, jnp.arange(slots.shape[0])]
+    return select_owner(contrib, slots, local_rows)
+
+
+def gather_ring_slots(ring_l, slots, local_rows: int, n_events: int):
+    """:func:`gather_slots` for the session ring, whose at-rest layout
+    is flat (serve/session_state.ring_rows owns it): [B, N, D] rows."""
+    from igaming_platform_tpu.serve.session_state import ring_rows
+
+    li, _ = local_slot_index(local_rows, slots)
+    return select_owner(ring_rows(ring_l, li, n_events), slots, local_rows)
 
 
 def scatter_slots(local, slots, rows):
@@ -188,26 +203,29 @@ def make_sharded_scatter(plan: SlotShardingPlan, ndim: int):
 def make_sharded_ring_sync(plan: SlotShardingPlan):
     """jit(shard_map) twin of the session admission sync: scatter window
     rows + cursors + lengths for freshly admitted slots into the
-    slot-sharded ring state."""
+    slot-sharded ring state (donated: outputs alias inputs)."""
     import jax
     from jax.sharding import PartitionSpec as P
 
     from jax import shard_map
 
+    from igaming_platform_tpu.serve.session_state import ring_put
+
     def sync(ring_l, cur_l, len_l, slots, w, c, l):  # noqa: E741
-        return (scatter_slots(ring_l, slots, w),
+        li, _ = local_slot_index(cur_l.shape[0], slots)
+        return (ring_put(ring_l, li, w),
                 scatter_slots(cur_l, slots, c),
                 scatter_slots(len_l, slots, l))
 
     sm = shard_map(
         sync,
         mesh=plan.mesh,
-        in_specs=(plan.spec(3), plan.spec(1), plan.spec(1), P(), P(), P(),
+        in_specs=(plan.spec(1), plan.spec(1), plan.spec(1), P(), P(), P(),
                   P()),
-        out_specs=(plan.spec(3), plan.spec(1), plan.spec(1)),
+        out_specs=(plan.spec(1), plan.spec(1), plan.spec(1)),
         check_vma=False,
     )
-    return jax.jit(sm)
+    return jax.jit(sm, donate_argnums=(0, 1, 2))
 
 
 # ---------------------------------------------------------------------------

@@ -8,9 +8,12 @@ module keeps a KV-cache-style per-account ring buffer in HBM **beside
 the PR 1 device feature cache** (the SNIPPETS mesh helpers come from a
 KV-cache serving codebase — same shape, same discipline):
 
-- ``session_ring``  [capacity+1, N_EVENTS, EVENT_WIDTH] float32 — per-slot
-  event windows, slot-aligned with the feature table (row ``capacity`` is
-  the scratch slot batch padding writes into, never read for a decision);
+- ``session_ring``  float32, FLAT: [(capacity+1) * N_EVENTS * EVENT_WIDTH]
+  — per-slot event windows, slot-aligned with the feature table, slot
+  ``s`` owning elements [s*N*D, (s+1)*N*D) (slot ``capacity`` is the
+  scratch slot batch padding writes into, never read for a decision).
+  Flat because that is the one at-rest layout the TPU both leaves
+  unpadded and writes in place: see "the ring's at-rest layout" below;
 - ``session_cursor`` / ``session_length`` [capacity+1] int32 — per-slot
   write cursor and saturating event count.
 
@@ -210,11 +213,10 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
     """Post-append window construction from PRE-GATHERED per-row ring
     state (``ring_rows`` [B, N, D], ``cur``/``ln`` [B]): the last
     ``min(length, N-1)`` stored events in chronological order, then the
-    new event, zero-padded to [B, N, D]. Split out of
-    :func:`build_windows` so the slot-sharded fused step
-    (parallel/state_sharding.py gathers the rows with an exact
-    owner-select collective) reuses the identical window math — one
-    implementation, bitwise-shared by the replicated and sharded
+    new event, zero-padded to [B, N, D]. Shared so the slot-sharded
+    fused step (parallel/state_sharding.py gathers the rows with an
+    exact owner-select collective) reuses the identical window math —
+    one implementation, bitwise-shared by the replicated and sharded
     programs."""
     import jax.numpy as jnp
 
@@ -230,13 +232,87 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
     return win, lp
 
 
-def build_windows(ring, cursor, length, sidx, events, n_events: int):
-    """Gather each row's POST-APPEND window from the ring. Duplicate
-    accounts within one batch see the BATCH-START state (batch-snapshot
-    semantics — the host index and replay apply the same rule), while
-    their appends land at distinct cursor offsets."""
-    return windows_from_state(
-        ring[sidx], cursor[sidx], length[sidx], events, n_events)
+# -- the ring's at-rest layout (ONE place) -----------------------------------
+#
+# The device ring is FLAT: float32[rows * N_EVENTS * EVENT_WIDTH], slot
+# ``s`` owning the contiguous elements [s*N*D, (s+1)*N*D). A 1-D array
+# pads nothing (the chip keeps [rows, 16, 12] as {0,1,2:T(8,128)} with
+# the slot axis minor-most precisely to avoid padding 16 and 12 to a
+# tile) and, unlike every shaped layout tried, its windowed gather and
+# scatter compile IN PLACE on the TPU: a step moves O(batch) bytes, not
+# two re-layout copies of the whole ring (docs/performance.md "Session
+# ring layout" has the table of compiles). Everything that reads or
+# writes the ring goes through the three functions below; an index past
+# the end reads zeros and writes nothing (the sharded bodies point
+# non-owned rows there).
+
+
+def ring_size(rows: int, n_events: int, shards: int = 1) -> int:
+    """Elements of a flat ring of ``rows`` slots. The int32 window
+    starts address one device's block of it, so that block — not a
+    slot-sharded ring's total — has to stay under 2**31 elements."""
+    size = int(rows) * n_events * EVENT_WIDTH
+    if size // shards >= 2**31:
+        raise ValueError(
+            f"session ring of {rows} slots x {n_events} events over "
+            f"{shards} device(s): {size // shards} elements on one device "
+            "is past int32 indexing (shard the state further)")
+    return size
+
+
+def ring_rows(ring, slots, n_events: int):
+    """Gather the stored [B, N, D] event rows of ``slots`` ([B] int32)."""
+    import jax
+
+    row = n_events * EVENT_WIDTH
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,))
+    flat = jax.lax.gather(ring, (slots * row)[:, None], dn, (row,),
+                          mode="fill", fill_value=0)
+    return flat.reshape(slots.shape[0], n_events, EVENT_WIDTH)
+
+
+def _ring_scatter(ring, starts, updates):
+    import jax
+
+    dn = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(0,))
+    return jax.lax.scatter(ring, starts[:, None], updates, dn, mode="drop")
+
+
+def ring_append(ring, slots, wpos, events, n_events: int):
+    """Write one [D] event per row at ``(slot, wpos)``."""
+    return _ring_scatter(
+        ring, (slots * n_events + wpos) * EVENT_WIDTH, events)
+
+
+def ring_put(ring, slots, windows):
+    """Overwrite the whole window of each of ``slots`` ([K, N, D])."""
+    k, n_events, _ = windows.shape
+    return _ring_scatter(
+        ring, slots * (n_events * EVENT_WIDTH),
+        windows.reshape(k, n_events * EVENT_WIDTH))
+
+
+def advance_counters(cursor, length, slots, ln, occ, real, n_events: int):
+    """Advance the write cursor and the saturating length of the TOUCHED
+    slots only (the arrays are capacity-sized; a batch is not). ``ln``
+    is the gathered batch-start length, ``occ`` the within-batch
+    occurrence rank: duplicates of one account advance its cursor once
+    each, and the max over them of ``min(ln + occ + 1, N)`` is the
+    saturated new length. Rows with ``real`` False (padding into the
+    scratch slot) add nothing, so the scratch counters stay 0 and a pad
+    row can never look warm."""
+    import jax.numpy as jnp
+
+    inc = real.astype(jnp.int32)
+    cursor = cursor.at[slots].add(inc, mode="drop")
+    total = cursor.at[slots].get(mode="fill", fill_value=0)
+    cursor = cursor.at[slots].set(jnp.mod(total, n_events), mode="drop")
+    length = length.at[slots].max(
+        jnp.minimum(ln + occ + 1, n_events) * inc, mode="drop")
+    return cursor, length
 
 
 def occurrence_rank_host(uidx: np.ndarray) -> np.ndarray:
@@ -316,6 +392,14 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
     stateful program that would serve. With both flags False the
     original signature and outputs are returned unchanged.
 
+    The ring is FLAT at rest (``ring_rows`` / ``ring_append`` /
+    ``ring_put`` own the layout): the gather of a batch's windows and
+    the donated append compile to in-place loops over the batch, so a
+    step moves O(batch) bytes whatever the capacity, and ``cursor`` /
+    ``length`` advance on the touched slots only (``advance_counters``).
+    With the ring shaped [slots, N, D] the same step re-laid the whole
+    ring out twice per call (28 ms at 5.2M slots; PERF.md, PR 26).
+
     ``idxs`` indexes the feature table (pad rows -> slot 0, scored and
     discarded, as on the plain cached path); ``sidx`` indexes the ring
     (pad rows -> the scratch slot ``capacity``, so padding never touches
@@ -390,7 +474,13 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
         out = score_fn(params, x, blv, thr)
 
         # -- session head over the post-append window ---------------------
-        win, lp = build_windows(ring, cursor, length, sidx, events, n_events)
+        #    Duplicate accounts within one batch see the BATCH-START state
+        #    (batch-snapshot semantics — the host index and replay apply
+        #    the same rule); their appends land at distinct cursor offsets.
+        cur = cursor[sidx]
+        ln = length[sidx]
+        win, lp = windows_from_state(
+            ring_rows(ring, sidx, n_events), cur, ln, events, n_events)
         sprob = head_fn(sparams, win, lp).astype(jnp.float32)
         real = sidx < capacity
         warm = jnp.logical_and(lp >= min_events, real)
@@ -400,14 +490,10 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
 
         # -- in-place append (donated buffers: ring'/cursor'/length' alias
         #    their inputs; the scratch slot soaks up padding rows) --------
-        wpos = jnp.mod(cursor[sidx] + occ, n_events)
-        ring2 = ring.at[sidx, wpos].set(events)
-        adds = jnp.zeros((capacity + 1,), jnp.int32).at[sidx].add(1)
-        cursor2 = jnp.mod(cursor + adds, n_events)
-        length2 = jnp.minimum(length + adds, n_events)
-        # The scratch slot stays empty so a pad row can never look warm.
-        cursor2 = cursor2.at[capacity].set(0)
-        length2 = length2.at[capacity].set(0)
+        ring2 = ring_append(ring, sidx, jnp.mod(cur + occ, n_events), events,
+                            n_events)
+        cursor2, length2 = advance_counters(cursor, length, sidx, ln, occ,
+                                            real, n_events)
         res = [packed, ring2, cursor2, length2]
         if sketch:
             from igaming_platform_tpu.obs.drift import sketch_kernel
@@ -434,7 +520,7 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
         out = score_fn(params, x, blv, thr)
 
         # -- sharded window gather + the SAME fold math -------------------
-        rows = ss.gather_slots(ring_l, sidx)
+        rows = ss.gather_ring_slots(ring_l, sidx, cur_l.shape[0], n_events)
         cur = ss.gather_slots(cur_l, sidx)
         ln = ss.gather_slots(len_l, sidx)
         win, lp = windows_from_state(rows, cur, ln, events, n_events)
@@ -446,13 +532,11 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
         packed = _session_fold(out, sprob, fold, cold, thr)
 
         # -- owned-only donated append (padding drops: no scratch row) ----
-        li, _ = ss.local_slot_index(ring_l.shape[0], sidx)
-        wpos = jnp.mod(cur + occ, n_events)
-        ring2 = ring_l.at[li, wpos].set(events, mode="drop")
-        adds = jnp.zeros((ring_l.shape[0],), jnp.int32).at[li].add(
-            1, mode="drop")
-        cursor2 = jnp.mod(cur_l + adds, n_events)
-        length2 = jnp.minimum(len_l + adds, n_events)
+        li, owned = ss.local_slot_index(cur_l.shape[0], sidx)
+        ring2 = ring_append(ring_l, li, jnp.mod(cur + occ, n_events), events,
+                            n_events)
+        cursor2, length2 = advance_counters(cur_l, len_l, li, ln, occ, owned,
+                                            n_events)
         res = [packed, ring2, cursor2, length2]
         if sketch:
             from igaming_platform_tpu.obs.drift import sketch_kernel
@@ -468,12 +552,12 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
 
         from jax import shard_map
 
-        outs = ([P(), plan.spec(3), plan.spec(1), plan.spec(1)]
+        outs = ([P(), plan.spec(1), plan.spec(1), plan.spec(1)]
                 + ([P()] if sketch else []) + ([P()] if shadow else []))
         sharded = shard_map(
             _sharded_body,
             mesh=plan.mesh,
-            in_specs=(P(), P(), plan.spec(2), plan.spec(1), plan.spec(3),
+            in_specs=(P(), P(), plan.spec(2), plan.spec(1), plan.spec(1),
                       plan.spec(1), plan.spec(1), P(), P(), P(), P(), P(),
                       P(), P(), P(), P(), P()),
             out_specs=tuple(outs),
@@ -501,6 +585,43 @@ def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
                      None, 0)[:4]
 
     return step
+
+
+def session_head(head: str):
+    """``SESSION_HEAD`` name -> (head_fn(sparams, window, lengths), params)."""
+    if head == "pattern":
+        return (lambda sparams, win, lp: pattern_scores(win, lp)), None
+    if head == "transformer":
+        return transformer_scores, init_session_head_params()
+    raise ValueError(
+        f"SESSION_HEAD={head!r} not supported "
+        "(use 'pattern' or 'transformer')")
+
+
+def make_ring_sync(mesh=None, plan=None):
+    """The jitted admission sync ``(ring, cursor, length, slots [K],
+    windows [K, N, D], cursors [K], lengths [K]) -> (ring', cursor',
+    length')``. The ring state is DONATED (outputs alias inputs, as in
+    the fused step): an admission writes K windows, it does not copy the
+    ring; ``on_admit`` rebinds the results under the manager's lock."""
+    import jax
+
+    if plan is not None:
+        from igaming_platform_tpu.parallel import state_sharding
+
+        return state_sharding.make_sharded_ring_sync(plan)
+
+    def sync(ring, cur, ln, slots, w, c, l):  # noqa: E741
+        return (ring_put(ring, slots, w), cur.at[slots].set(c),
+                ln.at[slots].set(l))
+
+    if mesh is None:
+        return jax.jit(sync, donate_argnums=(0, 1, 2))
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    repl = NamedSharding(mesh, P())
+    return jax.jit(sync, in_shardings=(repl,) * 7, out_shardings=(repl,) * 3,
+                   donate_argnums=(0, 1, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -553,8 +674,12 @@ class SessionStateManager:
 
     The host index (``_session_twin``) is authoritative — the device
     ring is its slot-resident projection, synced on admission and
-    advanced by the fused step's donated append. Everything that
-    mutates either lives behind ``lock`` and a
+    advanced by the fused step's donated append. The ring is allocated
+    flat ([slots * N * D] float32, under its sharding from birth) and
+    every program that touches it — the step, the admission sync, their
+    slot-sharded twins — donates it and goes through ``ring_rows`` /
+    ``ring_append`` / ``ring_put``: no program copies the ring.
+    Everything that mutates either lives behind ``lock`` and a
     ``# analysis: session-append-seam`` function (rule CC08).
     """
 
@@ -577,15 +702,7 @@ class SessionStateManager:
             flag_threshold if flag_threshold is not None
             else default_flag_threshold())
         self.head = (head or os.environ.get("SESSION_HEAD", "pattern")).lower()
-        if self.head not in ("pattern", "transformer"):
-            raise ValueError(
-                f"SESSION_HEAD={self.head!r} not supported "
-                "(use 'pattern' or 'transformer')")
-        self.head_params = (
-            init_session_head_params() if self.head == "transformer" else None)
-        self.head_fn = (
-            transformer_scores if self.head == "transformer" else
-            (lambda sparams, win, lp: pattern_scores(win, lp)))
+        self.head_fn, self.head_params = session_head(self.head)
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}
@@ -612,31 +729,23 @@ class SessionStateManager:
         self.n_shards = 1 if self.plan is None else self.plan.n_shards
         ring_rows = self.capacity if self.plan is not None else self.capacity + 1
         self._ring_rows = ring_rows
-        ring = jnp.zeros((ring_rows, self.n_events, EVENT_WIDTH),
-                         dtype=jnp.float32)
-        cursor = jnp.zeros((ring_rows,), dtype=jnp.int32)
-        length = jnp.zeros((ring_rows,), dtype=jnp.int32)
-
-        def sync(ring, cur, ln, slots, w, c, l):  # noqa: E741
-            return (ring.at[slots].set(w), cur.at[slots].set(c),
-                    ln.at[slots].set(l))
-
+        sharding = None
         if self.plan is not None:
-            ring = self.plan.place(ring)
-            cursor = self.plan.place(cursor)
-            length = self.plan.place(length)
-            self._sync = state_sharding.make_sharded_ring_sync(self.plan)
+            sharding = self.plan.named(1)
         elif mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            repl = NamedSharding(mesh, P())
-            ring = jax.device_put(ring, repl)
-            cursor = jax.device_put(cursor, repl)
-            length = jax.device_put(length, repl)
-            self._sync = jax.jit(
-                sync, in_shardings=(repl,) * 7, out_shardings=(repl,) * 3)
-        else:
-            self._sync = jax.jit(sync)
+            sharding = NamedSharding(mesh, P())
+
+        n_ring = ring_size(ring_rows, self.n_events, self.n_shards)
+        # Born under its sharding: a slot-sharded ring never exists whole
+        # on one device.
+        ring, cursor, length = jax.jit(
+            lambda: (jnp.zeros((n_ring,), jnp.float32),
+                     jnp.zeros((ring_rows,), jnp.int32),
+                     jnp.zeros((ring_rows,), jnp.int32)),
+            out_shardings=sharding)()
+        self._sync = make_ring_sync(mesh, self.plan)
         self.session_ring = ring
         self.session_cursor = cursor
         self.session_length = length

@@ -1,0 +1,190 @@
+"""AOT compiles of the session ring's programs for a DESCRIBED TPU v5e.
+
+The sandbox has no chip, but the TPU compiler is installed: these tests
+compile the fused session step and the admission sync at a deployment's
+size for a v5e that is described, not attached, and hold the compiled
+module to what the ring's flat at-rest layout promises
+(serve/session_state.py "the ring's at-rest layout"): the donated ring
+is written in place — no ring-sized ``copy``, no ring-sized temporary,
+outputs aliased onto the arguments. Nothing runs, so nothing here is a
+time; the chip numbers are in PERF.md.
+
+The topology is described inside a fixture of this file (never at
+import): only one process may load libtpu, and under xdist only the
+worker that is handed this file does.
+"""
+
+import os
+import re
+
+import numpy as np
+import pytest
+
+CAPACITY = 6_291_456          # ISSUE 24's size: 6 x 2^20 resident accounts
+SHARDED_CAPACITY = 20_971_520  # PERF.md Open question 1: 5,242,880 a shard
+BATCH = 256
+SYNC_SLOTS = 4096
+TEMP_LIMIT = 64 * 2**20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe means skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """models/sequence picks its attention core from
+    ``jax.default_backend()`` while tracing: steer it to the core the
+    chip runs (the Pallas flash kernel), here in the test."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _spec(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _step_args(head_params, capacity, ring_rows, shards, state, repl):
+    """Abstract arguments of the fused step (sketch variant), the ring
+    state under ``state`` and everything else under ``repl``."""
+    import jax
+
+    from igaming_platform_tpu.core.features import NUM_FEATURES
+    from igaming_platform_tpu.models.multitask import init_multitask
+    from igaming_platform_tpu.serve import session_state as ss
+
+    def abstract(make):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, repl),
+                            jax.eval_shape(make))
+
+    params = {"multitask": abstract(lambda: init_multitask(jax.random.key(0)))}
+    sparams = None if head_params is None else abstract(lambda: head_params)
+    n = ss.default_events()
+    f32, i32 = np.float32, np.int32
+    b = BATCH
+    return (
+        params, sparams,
+        _spec((capacity, NUM_FEATURES), f32, state),      # table
+        _spec((capacity,), np.bool_, state),              # flags
+        _spec((ss.ring_size(ring_rows, n, shards),), f32, state),  # ring
+        _spec((ring_rows,), i32, state),                  # cursor
+        _spec((ring_rows,), i32, state),                  # length
+        _spec((b,), i32, repl), _spec((b,), i32, repl), _spec((b,), i32, repl),
+        _spec((b,), f32, repl), _spec((b,), i32, repl),
+        _spec((b, ss.EVENT_WIDTH), f32, repl), _spec((b,), np.bool_, repl),
+        _spec((2,), i32, repl), None, _spec((), i32, repl),
+    )
+
+
+def _compile_step(head, capacity, ring_rows, state, repl, *, mesh=None,
+                  plan=None):
+    """The fused session step as serve/scorer._build_fused jits it (the
+    drift-sketch variant the server runs: ``jit(_body)``, ring donated)."""
+    import jax
+
+    from igaming_platform_tpu.core.config import ScoringConfig
+    from igaming_platform_tpu.models.ensemble import make_score_fn
+    from igaming_platform_tpu.serve import session_state as ss
+
+    cfg = ScoringConfig()
+    head_fn, head_params = ss.session_head(head)
+    step = ss.make_session_step(
+        make_score_fn(cfg, "multitask", mesh=mesh), cfg, head_fn,
+        capacity=capacity, n_events=ss.default_events(),
+        min_events=ss.default_min_events(),
+        flag_threshold=ss.default_flag_threshold(),
+        sketch=True, shadow=False, plan=plan)
+    args = _step_args(head_params, capacity, ring_rows,
+                      1 if plan is None else plan.n_shards, state, repl)
+    return jax.jit(step, donate_argnums=(4, 5, 6)).lower(*args).compile()
+
+
+def _ring_sized_copies(compiled, ring_elems: int) -> list[str]:
+    """HLO ``copy`` instructions whose result holds a ring's worth of
+    elements (whatever its shape or layout)."""
+    hits = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?[\w.-]+ = \w+\[([\d,]*)\][^ ]* copy\(",
+                     line)
+        if m and m.group(1):
+            elems = int(np.prod([int(d) for d in m.group(1).split(",")]))
+            if elems >= ring_elems:
+                hits.append(line.strip()[:160])
+    return hits
+
+
+def _assert_in_place(compiled, ring_elems: int) -> None:
+    mem = compiled.memory_analysis()
+    assert _ring_sized_copies(compiled, ring_elems) == []
+    assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
+    assert mem.alias_size_in_bytes >= 4 * ring_elems, mem
+
+
+@pytest.mark.parametrize("head", ["pattern", "transformer"])
+def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.serve import session_state as ss
+
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step(head, CAPACITY, CAPACITY + 1, one, one)
+    _assert_in_place(compiled, ss.ring_size(CAPACITY + 1, ss.default_events()))
+    if head == "transformer":
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_admission_sync_writes_the_ring_in_place(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.serve import session_state as ss
+
+    one = SingleDeviceSharding(topo.devices[0])
+    n, rows = ss.default_events(), CAPACITY + 1
+    f32, i32, k = np.float32, np.int32, SYNC_SLOTS
+    compiled = ss.make_ring_sync().lower(
+        _spec((ss.ring_size(rows, n),), f32, one),
+        _spec((rows,), i32, one), _spec((rows,), i32, one),
+        _spec((k,), i32, one), _spec((k, n, ss.EVENT_WIDTH), f32, one),
+        _spec((k,), i32, one), _spec((k,), i32, one)).compile()
+    _assert_in_place(compiled, ss.ring_size(rows, n))
+
+
+def test_slot_sharded_step_writes_each_shard_in_place(topo):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from igaming_platform_tpu.parallel.mesh import AXIS_DATA
+    from igaming_platform_tpu.parallel.state_sharding import SlotShardingPlan
+    from igaming_platform_tpu.serve import session_state as ss
+
+    devices = np.array(topo.devices)
+    mesh = Mesh(devices, (AXIS_DATA,))
+    plan = SlotShardingPlan(mesh, devices.size)
+    compiled = _compile_step(
+        "pattern", SHARDED_CAPACITY, SHARDED_CAPACITY,
+        NamedSharding(mesh, P(AXIS_DATA)), NamedSharding(mesh, P()),
+        mesh=mesh, plan=plan)
+    # memory_analysis is per device: one shard's ring.
+    _assert_in_place(compiled, ss.ring_size(
+        SHARDED_CAPACITY // devices.size, ss.default_events()))
+    assert "all-gather" in compiled.as_text()

@@ -66,12 +66,13 @@ def close_engine(eng):
 def ring_rows(eng, account_id):
     """Device-resident window for one account (chronological), read back."""
     slot = eng.cache._slots[account_id]
-    ring = jax.device_get(eng.session.session_ring)
+    n = eng.session.n_events
+    rows = np.asarray(session_mod.ring_rows(
+        eng.session.session_ring, np.asarray([slot], np.int32), n))[0]
     cur = int(jax.device_get(eng.session.session_cursor)[slot])
     ln = int(jax.device_get(eng.session.session_length)[slot])
-    n = eng.session.n_events
     pos = [(cur - ln + k) % n for k in range(ln)]
-    return ring[slot][pos]
+    return rows[pos]
 
 
 # ---------------------------------------------------------------------------
@@ -127,6 +128,87 @@ def test_duplicate_accounts_in_one_chunk_batch_snapshot():
     meta = eng.session.twin_meta("dup")
     assert meta["seq"] == 3
     close_engine(eng)
+
+
+# ---------------------------------------------------------------------------
+# The flat ring vs a numpy model of it (replicated and slot-sharded)
+
+
+@pytest.mark.parametrize("k", [None, 2, 4])
+@pytest.mark.parametrize("seed", [3, 2_271_560_481])
+def test_ring_state_matches_numpy_model(k, seed):
+    """Seeded batches with duplicates, pad rows, wrap-around and a cold
+    admit, straight through the jitted step and the admission sync:
+    windows, cursors and lengths equal a plain [slots, N, D] numpy ring."""
+    from igaming_platform_tpu.core.features import NUM_FEATURES
+    from igaming_platform_tpu.models.ensemble import make_score_fn
+    from igaming_platform_tpu.parallel.mesh import MeshSpec, create_mesh
+
+    cap, shape, n_steps = 8, 16, 30
+    mesh = None if k is None else create_mesh(
+        MeshSpec(data=k), devices=jax.devices()[:k])
+    mgr = session_mod.SessionStateManager(cap, mesh=mesh)
+    n, d = mgr.n_events, session_mod.EVENT_WIDTH
+    cfg = ScoringConfig()
+    step = jax.jit(session_mod.make_session_step(
+        make_score_fn(cfg, "mock"), cfg, mgr.head_fn, capacity=cap,
+        n_events=n, min_events=mgr.min_events,
+        flag_threshold=mgr.flag_threshold, plan=mgr.plan),
+        donate_argnums=(4, 5, 6))
+    table = np.zeros((cap, NUM_FEATURES), np.float32)
+    flags = np.zeros((cap,), bool)
+    if mgr.plan is not None:
+        table, flags = mgr.plan.place(table), mgr.plan.place(flags)
+    thr = np.array([cfg.block_threshold, cfg.review_threshold], np.int32)
+
+    rng = np.random.default_rng(seed)
+    ring = np.zeros((cap, n, d), np.float32)
+    cur = np.zeros((cap,), np.int64)
+    ln = np.zeros((cap,), np.int64)
+    for t in range(n_steps):
+        if t == n_steps - 3:
+            # Cold admit of two slots late in the run: their windows start over.
+            cold = rng.choice(cap, 2, replace=False).astype(np.int32)
+            mgr.on_admit([f"new-{s}" for s in cold], cold)
+            ring[cold], cur[cold], ln[cold] = 0.0, 0, 0
+        # 5..13 real rows over 8 slots (duplicates certain from 9 up;
+        # 30 steps of ~9 rows wrap the 16-event windows), the rest pad.
+        b = int(rng.integers(5, 14))
+        slots = rng.integers(0, cap, b).astype(np.int32)
+        sidx = np.full((shape,), cap, np.int32)
+        sidx[:b] = slots
+        occ = np.arange(shape, dtype=np.int32)
+        occ[:b] = session_mod.occurrence_rank_host(slots.astype(np.int64))
+        occ[b:] = np.arange(shape - b)
+        events = rng.random((shape, d), dtype=np.float32)
+        res = step(None, mgr.head_params, table, flags, mgr.session_ring,
+                   mgr.session_cursor, mgr.session_length,
+                   np.where(sidx < cap, sidx, 0).astype(np.int32), sidx, occ,
+                   np.zeros((shape,), np.float32),
+                   np.full((shape,), 4, np.int32), events,
+                   np.zeros((shape,), bool), thr)
+        mgr.adopt(*res[1:4])
+        cur0 = cur.copy()
+        for i in range(b):
+            ring[slots[i], (cur0[slots[i]] + occ[i]) % n] = events[i]
+        counts = np.bincount(slots, minlength=cap)
+        cur = (cur + counts) % n
+        ln = np.minimum(ln + counts, n)
+
+    rows = np.asarray(session_mod.ring_rows(
+        mgr.session_ring, np.arange(cap, dtype=np.int32), n))
+    np.testing.assert_array_equal(rows, ring)
+    got_cur = np.asarray(mgr.session_cursor)
+    got_len = np.asarray(mgr.session_length)
+    np.testing.assert_array_equal(got_cur[:cap], cur)
+    np.testing.assert_array_equal(got_len[:cap], ln)
+    assert ln.max() == n and ln.min() < n  # wrapped slots and a cold one
+    if mgr.plan is None:
+        # The scratch slot soaked up every pad row and still reads empty.
+        assert got_cur[cap] == 0 and got_len[cap] == 0
+        assert mgr.session_ring.shape == ((cap + 1) * n * d,)
+    else:
+        assert mgr.session_ring.shape == (cap * n * d,)
 
 
 # ---------------------------------------------------------------------------
