@@ -1,9 +1,12 @@
 """Operations and bytes a kernel needs for one call, from its shapes.
 
-Each function takes the deployment's configuration and the padded batch
-of one execution and returns ``{"flops": ..., "bytes": ...}``: what the
-algorithm has to do, not what a compiler's cost analysis says it did.
-A roofline reader names one of these by its key in ``COSTS``.
+One file a kernel under ``chipbench/costs/``, each with one function
+named like the file. It takes the deployment's configuration and the
+padded batch of one execution and returns ``{"flops": ..., "bytes":
+...}``: what the algorithm has to do, not what a compiler's cost
+analysis says it did. A roofline metric's file names it under ``cost``
+and ``validate.load_code`` finds it; a new kernel, or the fused step of a
+new session head, is a new file here.
 """
 
 from __future__ import annotations
@@ -46,5 +49,3 @@ def fused_step(config: dict, batch: int, *, index_mode: bool) -> dict:
     return {"flops": flops,
             "bytes": state_bytes + wire_bytes + out_bytes + param_bytes}
 
-
-COSTS = {"fused_step": fused_step}

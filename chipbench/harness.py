@@ -7,12 +7,14 @@ The program is driven through its normal entry points only
 harness edits nothing in it. Two seams are used from outside:
 ``serve/ledger.wall_clock`` (the program's injected clock seam) is held
 to a seeded time during the output check, so that inter-event gaps come
-from the seed; and the transformer session head's parameters are
-replaced by a seeded tree of the same shapes before any traffic.
+from the seed; and where the session head has parameters they are
+replaced, before any traffic, by the seeded tree that the head's file
+under ``chipbench/heads/`` makes.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -20,7 +22,7 @@ import time
 
 import numpy as np
 
-from chipbench import reference, traffic, trace_reduce
+from chipbench import reference, traffic, trace_reduce, validate
 from chipbench.readers import READERS, Readings, roofline_bound
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -29,8 +31,9 @@ FILL_NOW = 1_800_000_000.0  # the fixed "now" of every admission gather
 SCORE_BATCH = "/risk.v1.RiskService/ScoreBatch"
 # The rehearsal (CPU, --rehearse) runs every phase at this size.
 REHEARSAL = {"resident_accounts": 4096, "store_loaded_accounts": 1024,
-             "fill_chunk": 1024, "pool_frames": 64}
+             "fill_chunk": 1024, "pool_frames": 64, "warm_up_s": 0.5}
 TRACE_SLICE_S = 2.5
+WARM_UP_S = 1.0  # of the pool's own traffic from every client, before t0
 RPC_TIMEOUT_S = 120.0
 
 
@@ -44,7 +47,12 @@ def process_age_s() -> float:
     return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
 
 
+COMPARED: list[str] = []  # every number compared, beside its limit
+
+
 def log(msg: str) -> None:
+    if msg.startswith("check "):
+        COMPARED.append(msg)
     print(f"[chipbench {process_age_s():7.2f}s] {msg}", flush=True)
 
 
@@ -117,10 +125,13 @@ class Run:
                              "session plane")
         if backend != "cpu" and type(inner.features).__name__ != "NativeFeatureStore":
             raise SystemExit("a TPU boot must serve from the native feature store")
-        self.head = inner.session.head
-        self.head_params = None
-        if self.head == "transformer":
-            self.head_params = reference.make_head_params(self.seed)
+        # the session head's reference is a file of its own, found by the
+        # name the configuration gives; a head with parameters has them
+        # replaced by that file's seeded tree
+        self.head = validate.load_code(
+            "heads", validate.head_name(self.config), self.spec["root"])
+        self.head_params = self.head.make_params(self.seed, self.config)
+        if self.head_params is not None:
             inner.session.head_params = jax.device_put(self.head_params, self.device)
         self.channel = grpc.insecure_channel(
             f"localhost:{self.server.grpc_port}",
@@ -131,11 +142,13 @@ class Run:
             response_deserializer=lambda b: b)
         self.phase_s["boot"] = time.perf_counter() - t0
         log(f"boot {self.phase_s['boot']:.2f} s on {self.device.device_kind} "
-            f"(head={self.head})")
+            f"(head={inner.session.head}, reference "
+            f"heads/{validate.head_name(self.config)}.py)")
 
     def shutdown(self) -> None:
         self.channel.close()
         self.server.shutdown(grace=5.0)
+        gc.unfreeze()  # what window() froze; a test process lives on
 
     # -- resident state ------------------------------------------------------
 
@@ -328,40 +341,20 @@ class Run:
 
     # -- the window ----------------------------------------------------------
 
-    def warm_up(self, pool) -> None:
-        """One frame of every size the window will send (the check has sent
-        them once already; this also starts every client thread's path)."""
-        t0 = time.perf_counter()
-        seen = set()
-        for payload, rows in pool:
-            if rows not in seen:
-                seen.add(rows)
-                for _ in range(3):
-                    self.call(payload, timeout=300)
-        self.phase_s["warm_up"] = time.perf_counter() - t0
-
-    def window(self) -> dict:
+    def drive(self, pool, cursor: list[int], t_end: float) -> tuple[list, list]:
+        """Starts the closed loop until ``t_end``: client ``c`` of ``k``
+        sends frames ``c, c+k, ...`` of the pool from where ``cursor[c]``
+        says it stopped, each when its previous reply has arrived. Returns
+        the list that holds ``(due, done, rows, ok)`` of every RPC once the
+        threads, returned beside it, have been joined."""
         import grpc
 
-        if self.pool is None:
-            self.pool = traffic.build_pool(self.mix, self.pop, self.seed)
-            # the population (a list of every account id) has done its work:
-            # a real client holds no such list, and the collector would
-            # walk it
-            self.pop = None
-        pool = self.pool
-        self.warm_up(pool)
-        mix, seconds = self.mix, self.seconds
-        records: list[tuple] = []  # (due, done, rows, ok)
+        records: list[tuple] = []
         lock = threading.Lock()
-        c0, s0 = self.counters(), self.stage_totals()
-        tracer = Tracer(self) if self.trace else None
-        self.setup_s = process_age_s()
-        t0 = time.perf_counter()
-        t_end = t0 + seconds
+        k = len(cursor)
 
-        def closed_client(c: int, k: int) -> None:
-            mine, i = pool[c::k], 0
+        def closed_client(c: int) -> None:
+            mine, i = pool[c::k], cursor[c]
             local, due = [], time.perf_counter()
             while due < t_end:
                 payload, rows = mine[i % len(mine)]
@@ -374,24 +367,66 @@ class Run:
                 done = time.perf_counter()
                 local.append((due, done, rows, ok))
                 due = done
+            cursor[c] = i
             with lock:
                 records.extend(local)
 
-        k = int(mix["clients"])
-        threads = [threading.Thread(target=closed_client, args=(c, k),
+        threads = [threading.Thread(target=closed_client, args=(c,),
                                     name=f"chipbench-client-{c}")
                    for c in range(k)]
         for t in threads:
             t.start()
+        return records, threads
+
+    def warm_up(self, pool, cursor: list[int]) -> None:
+        """The pool's own traffic from every client for ``WARM_UP_S``
+        seconds: every frame size, every client thread's path and the
+        process's first second of serving, unlike the rest in every run
+        (PERF.md, PR 28), are behind it when the window starts. The
+        window goes on where this stops in the pool, so it scores the same
+        kind of accounts (mostly never seen) as it would without."""
+        t0 = time.perf_counter()
+        records, threads = self.drive(
+            pool, cursor,
+            t0 + (REHEARSAL["warm_up_s"] if self.rehearse else WARM_UP_S))
+        for t in threads:
+            t.join()
+        failed = sum(1 for r in records if not r[3])
+        if failed:
+            raise SystemExit(f"{failed} of {len(records)} warm-up RPCs failed")
+        self.phase_s["warm_up"] = time.perf_counter() - t0
+
+    def window(self) -> dict:
+        if self.pool is None:
+            self.pool = traffic.build_pool(self.mix, self.pop, self.seed)
+            # the population (a list of every account id) has done its work:
+            # a real client holds no such list, and the collector would
+            # walk it
+            self.pop = None
+            # and everything that lives on (the server's structures, the
+            # pool) leaves the collector's generations: a full collection
+            # in the window then walks what the window made, ~30 ms where
+            # it was ~140 (PERF.md, PR 28)
+            gc.collect()
+            gc.freeze()
+        pool = self.pool
+        cursor = [0] * int(self.mix["clients"])
+        self.warm_up(pool, cursor)
+        c0, s0 = self.counters(), self.stage_totals()
+        tracer = Tracer(self) if self.trace else None
+        self.setup_s = process_age_s()
+        t0 = time.perf_counter()
+        records, threads = self.drive(pool, cursor, t0 + self.seconds)
         if tracer:
-            tracer.slice(t0, seconds)
+            tracer.slice(t0, self.seconds)
         for t in threads:
             t.join()
         if tracer:
             tracer.load()
         t_last = max(r[1] for r in records)
         c1, s1 = self.counters(), self.stage_totals()
-        log(f"window: {len(records)} RPCs in {t_last - t0:.3f} s")
+        log(f"window: {len(records)} RPCs in {t_last - t0:.3f} s after "
+            f"{self.phase_s['warm_up']:.2f} s of warm-up")
         return self.reduce(records, t0, t_last, c0, c1, s0, s1, tracer)
 
     # -- reduction -----------------------------------------------------------
@@ -442,7 +477,7 @@ class Run:
             counters=counters,
             latency_ms=latency_ms,
             index_mode=self.index_mode, device_kind=self.device.device_kind,
-            pad_rows=pads)
+            pad_rows=pads, root=self.spec["root"])
         breakdown = None
         device_extra = {}
         if tracer is not None and tracer.result is not None:

@@ -14,7 +14,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from chipbench import costs, peaks, trace_reduce
+from chipbench import peaks, trace_reduce, validate
 
 
 @dataclass
@@ -30,6 +30,7 @@ class Readings:
     pad_rows: dict = field(default_factory=dict)  # padded batch -> executions
     trace: trace_reduce.Trace | None = None
     trace_window: tuple[int, int] | None = None   # ns, on the trace's clock
+    root: str = validate.ROOT         # where files named by a metric are found
 
 
 def _counter(r: Readings, name) -> float | None:
@@ -94,10 +95,15 @@ def trace_program_ms(m: dict, r: Readings):
     return sum(e[2] for e in runs) / len(runs) / 1e6
 
 
+def _cost(m: dict, r: Readings):
+    return getattr(validate.load_code("costs", m["cost"], r.root), m["cost"])
+
+
 def trace_roofline_share(m: dict, r: Readings):
     """The least time the chip could take for the executions seen (the
     larger of operations over peak FLOP/s and bytes over peak bytes/s,
-    from ``chipbench/costs.py``), over their measured device time. The
+    from the file under ``chipbench/costs/`` that the metric names), over
+    their measured device time. The
     padded batch of each execution is read from its name's row count
     where the harness recorded how many executions each padded shape
     had; the shares are weighted by those counts."""
@@ -105,7 +111,7 @@ def trace_roofline_share(m: dict, r: Readings):
     if not runs or not r.pad_rows:
         return None
     peak = peaks.peaks_for(r.device_kind)
-    cost = costs.COSTS[m["cost"]]
+    cost = _cost(m, r)
     least = 0.0
     for batch, count in r.pad_rows.items():
         c = cost(r.config, int(batch), index_mode=r.index_mode)
@@ -123,7 +129,7 @@ def roofline_bound(m: dict, r: Readings) -> str | None:
         return None
     peak = peaks.peaks_for(r.device_kind)
     batch = max(r.pad_rows, key=r.pad_rows.get)
-    c = costs.COSTS[m["cost"]](r.config, int(batch), index_mode=r.index_mode)
+    c = _cost(m, r)(r.config, int(batch), index_mode=r.index_mode)
     return ("flops" if c["flops"] / peak["flops_per_s"]
             > c["bytes"] / peak["bytes_per_s"] else "bytes")
 

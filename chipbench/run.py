@@ -84,6 +84,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.rehearse:
         line["rehearsal"] = True
     sys.stdout.flush()
+    # every number compared beside its limit, as the last lines of
+    # standard error too: the driver keeps the end of that
+    print("\n".join(harness.COMPARED), file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -3,7 +3,6 @@ a new cell, configuration, traffic mix or counter metric is data only."""
 
 import json
 import os
-import shutil
 
 import pytest
 
@@ -14,16 +13,6 @@ ROOT = validate.ROOT
 
 def test_manifest_as_committed_passes():
     assert validate.check_manifest() == []
-
-
-@pytest.fixture
-def copy(tmp_path):
-    """A copy of BENCHMARK.json and the data directories to break."""
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    for kind in ("configs", "traffic", "layer_metrics"):
-        shutil.copytree(os.path.join(ROOT, "chipbench", kind),
-                        tmp_path / "chipbench" / kind)
-    return tmp_path
 
 
 def _edit(root, fn):

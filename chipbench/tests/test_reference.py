@@ -72,3 +72,49 @@ def test_every_counter_a_metric_file_names_reads_a_number(run):
             names = m.get(key, [])
             for name in [names] if isinstance(names, str) else names:
                 assert name.startswith("client.") or name in counters, (path, name)
+
+
+def test_an_answer_altered_where_it_is_produced_is_not_correct(run, monkeypatch):
+    """The rest of a run with the timed path broken underneath: the drain
+    of the device's packed result adds 7 to one row's score."""
+    import numpy as np
+
+    from igaming_platform_tpu.serve import scorer
+
+    if not run.index_mode:
+        pytest.skip("no cell sends proto rows; their drain is another seam")
+    real = scorer._device_readback
+
+    def altered(out):
+        packed = np.array(real(out))
+        packed[0, 0] += 7
+        return packed
+
+    monkeypatch.setattr(scorer, "_device_readback", altered)
+    ok, numbers = run.check()
+    assert not ok and numbers["score_max_err"] >= 6, numbers
+
+
+def test_the_window_goes_on_where_the_warm_up_stopped(run):
+    """Warm-up and window are one closed loop over the pool, cut at t0:
+    every client sends its share of the pool in order from the first
+    frame, through the warm-up and on through the window, and the
+    window's identities hold."""
+    sent = []
+    call = run.call
+    run.call = lambda payload, timeout=None: (sent.append(payload),
+                                              call(payload, timeout=timeout))[1]
+    try:
+        result = run.window()
+    finally:
+        run.call = call
+    assert result["correct"] and result["failed"] == 0
+    assert run.phase_s["warm_up"] >= 0.5
+    warm = len(sent) - result["attempted"]
+    assert warm >= int(run.mix["clients"])
+    k = int(run.mix["clients"])
+    owner = {p: (i % k, i // k) for i, (p, _) in enumerate(run.pool)}
+    for c in range(k):
+        order = [owner[p][1] for p in sent if owner[p][0] == c]
+        laps = len(run.pool[c::k])
+        assert order == [i % laps for i in range(len(order))]

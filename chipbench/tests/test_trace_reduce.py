@@ -40,13 +40,14 @@ def test_idle_share_and_program_time_through_the_readers(small):
 
 
 def test_roofline_share_from_costs_and_peaks(small):
-    from chipbench import costs, peaks, validate
+    from chipbench import peaks, validate
     config = validate.load_data("configs", "risk-stateful-5m-pattern")
     r = Readings(config=config, rows_ok=1, stages={}, counters={},
                  device_kind="TPU v5 lite", pad_rows={256: 10},
                  trace=small, trace_window=WINDOW)
     m = {"pattern": "fused_session", "cost": "fused_step"}
-    c = costs.fused_step(config, 256, index_mode=True)
+    c = validate.load_code("costs", "fused_step").fused_step(
+        config, 256, index_mode=True)
     p = peaks.peaks_for("TPU v5 lite")
     least = max(c["flops"] / p["flops_per_s"], c["bytes"] / p["bytes_per_s"])
     assert READERS["trace_roofline_share"](m, r) == pytest.approx(

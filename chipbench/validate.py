@@ -3,8 +3,11 @@
 ``BENCHMARK.json`` names cells, configurations and metrics; everything
 that belongs to one of them sits in a file of its own under
 ``chipbench/`` (``configs/<config>.json``, ``traffic/<mix>.json``,
-``layer_metrics/<metric>.json``) which this module finds by name. A
-later PR adds a cell by adding files and entries, never by editing one.
+``layer_metrics/<metric>.json``) which this module finds by name. So
+does the code a configuration or a metric brings: the plain reference of
+a session head (``heads/<name>.py``) and the operations and bytes of a
+kernel (``costs/<name>.py``). A later PR adds a cell by adding files and
+entries, never by editing one.
 
 ``check_manifest`` returns every breach as one line; ``python -m
 chipbench.run --validate`` prints them and exits non-zero on any.
@@ -12,6 +15,7 @@ chipbench.run --validate`` prints them and exits non-zero on any.
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import re
@@ -38,6 +42,13 @@ _CONFIG_KEYS = {"name", "source", "chips", "resident_accounts", "ml_backend",
                 "store_loaded_accounts", "session_events_preloaded",
                 "precision", "guarantees", "reduced", "reduced_why", "assumed",
                 "limits"}
+# A configuration may bring its session head: ``{"reference": <name of a
+# file under heads/>, ...}``. The other keys are that head's own (its
+# sizes, what was published, how it was cut) and are not listed here.
+_CONFIG_OPTIONAL = {"head"}
+# What a code file found by name has to define (``load_code``).
+CODE_DEFINES = {"heads": lambda name: ("make_params", "forward"),
+                "costs": lambda name: (name,)}
 _TRAFFIC_KEYS = {"name", "loop", "clients", "rpc", "rows", "pool_frames",
                  "accounts", "tx_types", "amounts", "check"}
 _LAYER_KEYS = {"name", "layer", "unit", "better", "source", "moves", "reader"}
@@ -62,15 +73,50 @@ def load_manifest(root: str = ROOT) -> dict:
     return _load(os.path.join(root, "BENCHMARK.json"))
 
 
-def data_path(kind: str, name: str, root: str = ROOT) -> str:
-    """``kind`` is ``configs``, ``traffic`` or ``layer_metrics``."""
+def data_path(kind: str, name: str, root: str = ROOT, ext: str = ".json") -> str:
+    """``kind`` is ``configs``, ``traffic`` or ``layer_metrics``, or with
+    ``ext=".py"`` ``heads`` or ``costs``."""
     if not NAME_RE.match(name):
         raise ValueError(f"{kind} name {name!r} breaks the character rules")
-    return os.path.join(root, "chipbench", kind, name + ".json")
+    return os.path.join(root, "chipbench", kind, name + ext)
 
 
 def load_data(kind: str, name: str, root: str = ROOT) -> dict:
     return _load(data_path(kind, name, root))
+
+
+_code: dict[str, object] = {}
+
+
+def load_code(kind: str, name: str, root: str = ROOT):
+    """The module ``chipbench/<kind>/<name>.py`` under ``root``, loaded by
+    its path: a file a later PR adds is found with no import line
+    anywhere. ``kind`` is ``heads`` (``make_params(seed, config)`` and
+    ``forward(params, windows, lengths, rounder)``) or ``costs`` (one
+    function named like the file)."""
+    path = data_path(kind, name, root, ".py")
+    if path not in _code:
+        if not os.path.isfile(path):
+            raise FileNotFoundError(f"no file chipbench/{kind}/{name}.py")
+        spec = importlib.util.spec_from_file_location(
+            f"chipbench_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        missing = [f for f in CODE_DEFINES[kind](name)
+                   if not callable(getattr(module, f, None))]
+        if missing:
+            raise AttributeError(
+                f"chipbench/{kind}/{name}.py does not define {missing}")
+        _code[path] = module
+    return _code[path]
+
+
+def head_name(config: dict) -> str:
+    """The file under ``heads/`` that holds the reference of this
+    deployment's session head: the configuration's ``head.reference``,
+    and without one the value of ``SESSION_HEAD`` (default ``pattern``)."""
+    return (config.get("head", {}).get("reference")
+            or config.get("env", {}).get("SESSION_HEAD", "pattern"))
 
 
 def load_cell(workload: str, root: str = ROOT) -> dict:
@@ -94,6 +140,7 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
         "end_to_end": e2e,
         "per_layer": layer,
         "run_seconds": manifest["run_seconds"],
+        "root": root,
     }
 
 
@@ -198,7 +245,17 @@ def check_manifest(root: str = ROOT) -> list[str]:
         except (OSError, ValueError) as exc:
             errors.append(f"{what}: {exc}")
             continue
-        _check_keys(errors, f"{path}", data, _CONFIG_KEYS)
+        _check_keys(errors, f"{path}", data, _CONFIG_KEYS, _CONFIG_OPTIONAL)
+        head = data.get("head")
+        if head is not None and not (isinstance(head, dict) and isinstance(
+                head.get("reference"), str)):
+            errors.append(f"{path}: head is an object that names its "
+                          "'reference', a file under chipbench/heads/")
+        else:
+            try:
+                load_code("heads", head_name(data), root)
+            except (OSError, ValueError, AttributeError, SyntaxError) as exc:
+                errors.append(f"{path}: session head: {exc}")
         if data.get("name") != name:
             errors.append(f"{path}: name {data.get('name')!r} != {name!r}")
         if data.get("source") != c.get("source"):
@@ -311,6 +368,11 @@ def check_manifest(root: str = ROOT) -> list[str]:
                           f"{sorted(READER_PARAMS)}")
         else:
             _check_keys(errors, lpath, d, _LAYER_KEYS, READER_PARAMS[reader])
+        if "cost" in d:
+            try:
+                load_code("costs", str(d["cost"]), root)
+            except (OSError, ValueError, AttributeError, SyntaxError) as exc:
+                errors.append(f"{lpath}: cost: {exc}")
         for key in ("name", "layer", "unit", "better", "source", "moves"):
             if d.get(key) != x.get(key):
                 errors.append(f"{lpath}: {key} {d.get(key)!r} differs from "
