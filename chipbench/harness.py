@@ -23,7 +23,7 @@ import time
 import numpy as np
 
 from chipbench import reference, traffic, trace_reduce, validate
-from chipbench.readers import READERS, Readings, roofline_bound
+from chipbench.readers import Readings, read_all
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "out")
@@ -491,19 +491,7 @@ class Run:
                 "idle_gaps": trace_reduce.idle_gaps(
                     readings.trace, readings.trace_window, spans),
             }
-        per_layer = {}
-        for m in self.spec["per_layer"]:
-            value = READERS[m["reader"]](m, readings)
-            if value is not None:
-                per_layer[m["name"]] = {"value": float(value), "unit": m["unit"]}
-            if m["reader"] == "trace_roofline_share" and value is not None:
-                log(f"{m['name']} is bound by {roofline_bound(m, readings)}")
-        if readings.trace is not None and readings.trace.device_ops:
-            # a traced line that lacks a metric this cell owes is refused
-            for m in self.spec["per_layer"]:
-                if m["name"] not in per_layer:
-                    log(f"MISSING per-layer metric {m['name']}: its reader "
-                        f"{m['reader']} found nothing to read in this cell")
+        per_layer = read_all(self.spec["per_layer"], readings, log)
         finite = latency_ms[np.isfinite(latency_ms)]
         log("latency ms: " + json.dumps({
             f"p{q}": round(float(np.percentile(finite, q)), 3)

@@ -99,15 +99,14 @@ def _cost(m: dict, r: Readings):
     return getattr(validate.load_code("costs", m["cost"], r.root), m["cost"])
 
 
-def trace_roofline_share(m: dict, r: Readings):
-    """The least time the chip could take for the executions seen (the
+def _roofline_share(m: dict, r: Readings, runs: int, measured_ns: float):
+    """The least time the chip could take for ``runs`` executions (the
     larger of operations over peak FLOP/s and bytes over peak bytes/s,
     from the file under ``chipbench/costs/`` that the metric names), over
-    their measured device time. The
-    padded batch of each execution is read from its name's row count
-    where the harness recorded how many executions each padded shape
-    had; the shares are weighted by those counts."""
-    runs = _executions(m, r)
+    their measured device time. The padded batch of each execution is
+    read from its name's row count where the harness recorded how many
+    executions each padded shape had; the shares are weighted by those
+    counts."""
     if not runs or not r.pad_rows:
         return None
     peak = peaks.peaks_for(r.device_kind)
@@ -117,10 +116,39 @@ def trace_roofline_share(m: dict, r: Readings):
         c = cost(r.config, int(batch), index_mode=r.index_mode)
         least += count * max(c["flops"] / peak["flops_per_s"],
                              c["bytes"] / peak["bytes_per_s"])
-    measured = sum(e[2] for e in runs) / 1e9
     # the counts cover the whole window, the trace a slice of it
-    least *= len(runs) / sum(r.pad_rows.values())
-    return 100.0 * least / measured
+    least *= runs / sum(r.pad_rows.values())
+    return 100.0 * least / (measured_ns / 1e9)
+
+
+def trace_roofline_share(m: dict, r: Readings):
+    """A whole program's share of its roofline (``_roofline_share``)."""
+    runs = _executions(m, r)
+    return _roofline_share(m, r, len(runs), sum(e[2] for e in runs))
+
+
+def _operation(m: dict, r: Readings) -> tuple[int, int]:
+    if r.trace is None:
+        return 0, 0
+    return trace_reduce.operation_executions(
+        r.trace, m["pattern"], r.trace_window, m.get("program"))
+
+
+def trace_op_ms(m: dict, r: Readings):
+    """Mean device time, per execution of the enclosing program, of the
+    operations on the XLA Ops line whose name or ``jax.named_scope`` path
+    matches ``pattern`` (``program``, optional, anchors the enclosing
+    program as ``trace_program_ms``'s pattern does)."""
+    ns, runs = _operation(m, r)
+    return ns / runs / 1e6 if runs else None
+
+
+def trace_op_roofline_share(m: dict, r: Readings):
+    """One operation's share of its roofline: ``trace_op_ms``'s time
+    against the cost the metric names, per padded batch of the enclosing
+    program as ``trace_roofline_share`` weighs them."""
+    ns, runs = _operation(m, r)
+    return _roofline_share(m, r, runs, ns)
 
 
 def roofline_bound(m: dict, r: Readings) -> str | None:
@@ -142,4 +170,24 @@ READERS = {
     "trace_device_idle": trace_device_idle,
     "trace_program_ms": trace_program_ms,
     "trace_roofline_share": trace_roofline_share,
+    "trace_op_ms": trace_op_ms,
+    "trace_op_roofline_share": trace_op_roofline_share,
 }
+
+
+def read_all(metrics: list[dict], r: Readings, log) -> dict:
+    """Every per-layer metric of a cell through its reader: ``{name:
+    {"value", "unit"}}``. A reader that finds nothing leaves its metric
+    out, and in a traced run that is said: a traced line that lacks a
+    metric its cell owes is refused."""
+    per_layer = {}
+    for m in metrics:
+        value = READERS[m["reader"]](m, r)
+        if value is not None:
+            per_layer[m["name"]] = {"value": float(value), "unit": m["unit"]}
+            if "cost" in m:
+                log(f"{m['name']} is bound by {roofline_bound(m, r)}")
+        elif r.trace is not None and r.trace.device_ops:
+            log(f"MISSING per-layer metric {m['name']}: its reader "
+                f"{m['reader']} found nothing to read in this cell")
+    return per_layer

@@ -256,6 +256,15 @@ def compare(got: dict, want: dict, exact: dict) -> dict:
     float32. Sums and maxima, to be merged over RPCs."""
     ml_err = np.abs(got["ml_score"].astype(np.float64) - want["ml_score"])
     score_err = np.abs(got["score"].astype(np.int64) - want["score"])
+    # What the stated rounding itself costs on the probability that is
+    # judged: the reference at the stated precision against the reference
+    # with float32 operands, session head included. Where the two fold
+    # differently (a fold moves the probability by tenths) the stateless
+    # probability stands in, so a flip between the references cannot swamp it.
+    base_rounding = want["ml_base"].astype(np.float64) - exact["ml_base"]
+    rounding = np.where(want["fold"] == exact["fold"],
+                        want["ml_score"].astype(np.float64) - exact["ml_score"],
+                        base_rounding)
     got_cold = np.array(["SESSION_COLD" in r for r in got["reasons"]])
     got_fold = np.array(["SESSION_PATTERN" in r for r in got["reasons"]])
     near = (np.abs(want["sprob"] - FLAG_THRESHOLD) < FOLD_BAND) & ~want["cold"]
@@ -273,10 +282,8 @@ def compare(got: dict, want: dict, exact: dict) -> dict:
     return {
         "rows": len(ml_err),
         "fraud_prob_sq_sum": float((ml_err ** 2).sum()),
-        # on the stateless probability: a fold that flips between the two
-        # references would swamp it
-        "rounding_sq_sum": float(((want["ml_base"].astype(np.float64)
-                                   - exact["ml_base"]) ** 2).sum()),
+        "rounding_sq_sum": float((rounding ** 2).sum()),
+        "stateless_rounding_sq_sum": float((base_rounding ** 2).sum()),
         "fraud_prob_max_err": float(ml_err.max()),
         "score_max_err": int(score_err.max()),
         "rule_score_mismatch": int((got["rule_score"] != want["rule_score"]).sum()),
@@ -292,15 +299,23 @@ def compare(got: dict, want: dict, exact: dict) -> dict:
 def merge(parts: list[dict]) -> dict:
     rows = sum(p["rows"] for p in parts)
     err = sum(p["fraud_prob_sq_sum"] for p in parts)
-    rounding = sum(p["rounding_sq_sum"] for p in parts)
+    def in_roundings(key: str) -> float:
+        rounding = sum(p[key] for p in parts)
+        return math.sqrt(err / rounding) if rounding > 0 else math.inf
+
     out = {"rows": rows,
            "fraud_prob_rms_err": math.sqrt(err / max(rows, 1)),
            # The same error in units of what the stated rounding itself costs
            # (reference at the stated precision against float32 operands):
-           # how sensitive a seed's parameters are cancels out, so this is
-           # the number that is steady from seed to seed.
-           "fraud_prob_err_in_roundings": math.sqrt(err / rounding)
-           if rounding > 0 else math.inf}
+           # how sensitive a seed's parameters are cancels out, in the trunk
+           # and in the session head, so this is the number that is steady
+           # from seed to seed.
+           "fraud_prob_err_in_roundings": in_roundings("rounding_sq_sum"),
+           # In units of the rounding's cost on the stateless probability
+           # alone, which was judged until PR 30: a seed whose head is far
+           # more sensitive than its trunk reads high here (PERF.md).
+           "fraud_prob_err_in_stateless_roundings": in_roundings(
+               "stateless_rounding_sq_sum")}
     for key in ("fraud_prob_max_err", "score_max_err"):
         out[key] = max(p[key] for p in parts)
     for key in ("rule_score_mismatch", "action_mismatch_same_score",

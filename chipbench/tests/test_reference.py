@@ -118,3 +118,43 @@ def test_the_window_goes_on_where_the_warm_up_stopped(run):
         order = [owner[p][1] for p in sent if owner[p][0] == c]
         laps = len(run.pool[c::k])
         assert order == [i % laps for i in range(len(order))]
+
+
+def test_the_error_is_counted_in_roundings_of_what_is_judged():
+    """A seed whose session head is far more sensitive to the stated
+    rounding than its trunk (seed 3000002006 on the chip, PR 30): the error
+    of a folded row is held against what the rounding costs on the folded
+    probability, not on the stateless one alone; where the two references
+    fold differently the stateless probability stands in."""
+    import numpy as np
+
+    from chipbench import reference
+
+    f = np.float32
+    n = 4
+    want = {"ml_score": np.array([0.90, 0.91, 0.10, 0.80], f),
+            "ml_base": np.array([0.10, 0.10, 0.10, 0.10], f),
+            "sprob": np.array([0.90, 0.91, 0.20, 0.80], f),
+            "fold": np.array([True, True, False, True]),
+            "cold": np.zeros(n, bool), "warm": np.ones(n, bool),
+            "score": np.full(n, 50), "action": np.ones(n, int),
+            "rule_score": np.zeros(n, int)}
+    exact = dict(want, ml_score=np.array([0.89, 0.90, 0.1001, 0.1001], f),
+                 ml_base=np.array([0.1001] * n, f),
+                 fold=np.array([True, True, False, False]))
+    got = {"ml_score": want["ml_score"] + np.array([0.002, 0, 0, 0], f),
+           "score": want["score"], "action": want["action"],
+           "rule_score": want["rule_score"],
+           "reasons": [frozenset(["SESSION_PATTERN"] if x else [])
+                       for x in want["fold"]]}
+    part = reference.compare(got, want, exact)
+    # rows 0, 1: the folded probability's rounding (0.01 each); row 2: the
+    # stateless one; row 3: the references fold differently, stateless too
+    assert part["rounding_sq_sum"] == pytest.approx(2 * 0.01 ** 2 + 2 * 1e-4 ** 2, rel=1e-3)
+    assert part["stateless_rounding_sq_sum"] == pytest.approx(4 * 1e-4 ** 2, rel=1e-2)
+    numbers = reference.merge([part])
+    assert numbers["fraud_prob_err_in_roundings"] == pytest.approx(
+        0.002 / (0.01 * 2 ** 0.5), rel=1e-2)
+    assert numbers["fraud_prob_err_in_stateless_roundings"] == pytest.approx(10.0, rel=1e-2)
+    ok, lines = reference.judge(numbers, {"fraud_prob_err_in_roundings": 4.0})
+    assert ok and any("in_stateless_roundings" in x and "not judged" in x for x in lines)

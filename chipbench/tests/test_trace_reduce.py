@@ -98,3 +98,131 @@ def test_recorded_v5e_slice_reduces():
     gaps = trace_reduce.idle_gaps(trace, window, [])
     assert sum(s for _, s in gaps) == pytest.approx(
         (window[1] - window[0]) / 1e9 - busy)
+
+
+# -- one operation inside a program -------------------------------------------
+
+FUSED = r"^jit__body\("
+XSPACE = '''
+planes {
+  name: "/device:TPU:0"
+  lines { name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 900000 } }
+  lines { name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 2 offset_ps: 100000 duration_ps: 300000 }
+    events { metadata_id: 3 offset_ps: 500000 duration_ps: 200000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit_step(7)" } }
+  event_metadata { key: 2 value { id: 2
+    name: "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kOutput"
+    stats { metadata_id: 1 str_value: "jit(step)/experts/dot_general:" } } }
+  event_metadata { key: 3 value { id: 3 name: "%copy.2 = f32[8]{0} copy(f32[8]{0} %q)"
+    stats { metadata_id: 3 int64_value: 5 } stats { metadata_id: 1 ref_value: 2 } } }
+  stat_metadata { key: 1 value { id: 1 name: "tf_op" } }
+  stat_metadata { key: 2 value { id: 2 name: "jit(step)/router/copy" } }
+  stat_metadata { key: 3 value { id: 3 name: "flops" } }
+}
+planes {
+  name: "/host:CPU"
+  lines { name: "python3" events { metadata_id: 1 offset_ps: 0 duration_ps: 1000 } }
+  event_metadata { key: 1 value { id: 1 name: "chipbench_mark_lo"
+    stats { metadata_id: 1 str_value: "not a device plane" } } }
+  stat_metadata { key: 1 value { id: 1 name: "tf_op" } }
+}
+'''
+
+
+@pytest.fixture(scope="module")
+def step():
+    """Two executions of the fused session step of ``seqhead-index-flatout``
+    at 5,242,880 slots, every operation with its named-scope path (my chip
+    run, PR 30, seed 3000000001)."""
+    return trace_reduce.load_json(os.path.join(DATA, "recorded_v5e_seqhead_step.json"))
+
+
+def _readings(trace, **kw):
+    window = (0, max(s + d for _, s, d in trace.programs[0]) + 1)
+    return Readings(config=kw.pop("config", {}), rows_ok=1, stages={},
+                    counters={}, trace=trace, trace_window=window, **kw)
+
+
+def test_the_scope_of_an_operation_is_read_from_the_profilers_file(tmp_path):
+    from jax.profiler import ProfileData
+    raw = ProfileData.text_proto_to_serialized_xspace(XSPACE)
+    assert trace_reduce.op_scopes(raw) == {
+        "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kOutput":
+            "jit(step)/experts/dot_general",
+        "%copy.2 = f32[8]{0} copy(f32[8]{0} %q)": "jit(step)/router/copy"}
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(raw)
+    trace, summary = trace_reduce.load_xplane(str(path))
+    assert trace.device_ops[0] == [("%fusion.1 f32[8] fusion", 1100, 300),
+                                   ("%copy.2 f32[8] copy", 1500, 200)]
+    assert trace.op_scopes[0] == ["jit(step)/experts/dot_general",
+                                  "jit(step)/router/copy"]
+    assert summary["/device:TPU:0"] == {"XLA Modules": 1, "XLA Ops": 2}
+    r = _readings(trace)
+    # by the operation's name, and by the scope it was traced under
+    assert READERS["trace_op_ms"]({"pattern": r"^%copy\."}, r) == pytest.approx(200e-6)
+    assert READERS["trace_op_ms"]({"pattern": "/experts/"}, r) == pytest.approx(300e-6)
+    assert READERS["trace_op_ms"]({"pattern": r"jit\(step\)"}, r) == pytest.approx(500e-6)
+    # and through a dump, as the harness keeps its cut
+    trace_reduce.dump_json(trace, str(tmp_path / "cut.json"))
+    again = trace_reduce.load_json(str(tmp_path / "cut.json"))
+    assert again.op_scopes == trace.op_scopes and again.device_ops == trace.device_ops
+
+
+def test_one_operations_time_on_the_recorded_step(step):
+    r = _readings(step)
+    assert len(trace_reduce.program_executions(step, FUSED, r.trace_window)) == 2
+    whole = READERS["trace_program_ms"]({"pattern": FUSED}, r)
+    assert whole == pytest.approx(1.367, abs=0.002)
+    # the flash kernel of the transformer head: one custom call a step
+    flash = READERS["trace_op_ms"]({"pattern": r"^%_run_resident", "program": FUSED}, r)
+    assert flash == pytest.approx(0.308, abs=0.002)
+    assert READERS["trace_op_ms"]({"pattern": r"jit\(_run_resident\)/pallas_call"},
+                                  r) == flash
+    # a loop and the operations of its body are both on the line: the
+    # union of what matches, so nothing is counted twice
+    gather = READERS["trace_op_ms"]({"pattern": r"^jit\(_body\)/gather$"}, r)
+    loop = READERS["trace_op_ms"]({"pattern": r"^%while\.6 "}, r)
+    both = READERS["trace_op_ms"](
+        {"pattern": r"^%while\.6 |^jit\(_body\)/gather$"}, r)
+    assert 0.29 < loop < 0.30 and loop < both < loop + 0.04 < loop + gather
+    scatter = READERS["trace_op_ms"]({"pattern": r"^%while\.7 "}, r)
+    assert flash + loop + scatter < whole
+    # anchored to another program, or an operation that is not there
+    assert READERS["trace_op_ms"]({"pattern": "_run_resident", "program": "^jit_sync"}, r) is None
+    assert READERS["trace_op_ms"]({"pattern": "ragged-dot"}, r) is None
+    untraced = Readings(config={}, rows_ok=1, stages={}, counters={})
+    assert READERS["trace_op_ms"]({"pattern": "_run_resident"}, untraced) is None
+
+
+def test_one_operations_roofline_share_on_the_recorded_step(step):
+    from chipbench import peaks, validate
+    from chipbench.readers import read_all
+    config = validate.load_data("configs", "risk-stateful-5m-seqhead")
+    r = _readings(step, config=config, device_kind="TPU v5 lite",
+                  pad_rows={256: 70, 2048: 0})
+    m = {"name": "flash_roofline", "unit": "%", "reader": "trace_op_roofline_share",
+         "pattern": "_run_resident", "program": FUSED, "cost": "fused_step"}
+    c = validate.load_code("costs", "fused_step").fused_step(config, 256, index_mode=True)
+    p = peaks.peaks_for("TPU v5 lite")
+    least = max(c["flops"] / p["flops_per_s"], c["bytes"] / p["bytes_per_s"])
+    ms = READERS["trace_op_ms"](m, r)
+    share = READERS["trace_op_roofline_share"](m, r)
+    assert share == pytest.approx(100.0 * least / (ms / 1e3))
+    assert 0.0 < share <= 100.0, "operations or bytes counted too high"
+    # no padded batches recorded, or no such operation: nothing, never 0
+    absent = dict(m, name="grouped_roofline", pattern="ragged-dot")
+    assert READERS["trace_op_roofline_share"](absent, r) is None
+    r.pad_rows = {}
+    assert READERS["trace_op_roofline_share"](m, r) is None
+    r.pad_rows = {256: 70}
+    said = []
+    out = read_all([m, absent, dict(absent, name="grouped_ms", reader="trace_op_ms")],
+                   r, said.append)
+    assert list(out) == ["flash_roofline"] and out["flash_roofline"]["unit"] == "%"
+    assert said[0] == "flash_roofline is bound by bytes"
+    assert [s.split(":")[0] for s in said[1:]] == [
+        "MISSING per-layer metric grouped_roofline",
+        "MISSING per-layer metric grouped_ms"]

@@ -5,11 +5,16 @@ longest idle gaps named by what the host was doing.
 The reduction works on a plain ``Trace`` (lists of ``(name, start_ns,
 dur_ns)``), so it is checked on a small recorded trace kept as JSON in
 ``chipbench/tests/data/``. ``load_xplane`` turns the ``.xplane.pb`` the
-JAX profiler writes into one.
+JAX profiler writes into one. One operation inside a program is found by
+its name or by its ``jax.named_scope`` path (``operation_executions``);
+the profiler keeps that path in the operation's metadata, which
+``jax.profiler.ProfileData`` does not show, so ``op_scopes`` reads it from
+the file's bytes.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import os
@@ -32,6 +37,9 @@ class Trace:
     programs: dict[int, list[Event]] = field(default_factory=dict)
     # host annotations (TraceAnnotation), any thread
     host_marks: list[Event] = field(default_factory=list)
+    # device ordinal -> the named-scope path of each operation, entry for
+    # entry beside ``device_ops`` ("" where the profiler kept none)
+    op_scopes: dict[int, list[str]] = field(default_factory=dict)
 
 
 def load_json(path: str) -> Trace:
@@ -42,7 +50,8 @@ def load_json(path: str) -> Trace:
                     for k, v in raw["device_ops"].items()},
         programs={int(k): [tuple(e) for e in v]
                   for k, v in raw["programs"].items()},
-        host_marks=[tuple(e) for e in raw.get("host_marks", [])])
+        host_marks=[tuple(e) for e in raw.get("host_marks", [])],
+        op_scopes={int(k): v for k, v in raw.get("op_scopes", {}).items()})
 
 
 def dump_json(trace: Trace, path: str, limit: int | None = None) -> None:
@@ -50,7 +59,9 @@ def dump_json(trace: Trace, path: str, limit: int | None = None) -> None:
     with open(path, "w", encoding="utf-8") as f:
         json.dump({"device_ops": {k: cut(v) for k, v in trace.device_ops.items()},
                    "programs": {k: cut(v) for k, v in trace.programs.items()},
-                   "host_marks": cut(trace.host_marks)}, f)
+                   "host_marks": cut(trace.host_marks),
+                   "op_scopes": {k: cut(v) for k, v in trace.op_scopes.items()}},
+                  f)
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -75,12 +86,87 @@ def short_name(name: str, limit: int = 96) -> str:
     return name[:limit]
 
 
+def _varint(buf: memoryview, at: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[at]
+        at += 1
+        value |= (byte & 0x7F) << shift
+        shift += 7
+        if byte < 0x80:
+            return value, at
+
+
+def _fields(buf: memoryview):
+    """The fields of one protobuf message: ``(number, value)``, a varint
+    as an int and a length-delimited field as a view of its bytes."""
+    at, end = 0, len(buf)
+    while at < end:
+        key, at = _varint(buf, at)
+        number, wire = key >> 3, key & 7
+        if wire == 0:
+            value, at = _varint(buf, at)
+            yield number, value
+        elif wire == 2:
+            size, at = _varint(buf, at)
+            yield number, buf[at:at + size]
+            at += size
+        elif wire in (1, 5):
+            at += 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"protobuf wire type {wire} at byte {at}")
+
+
+def op_scopes(serialized: bytes) -> dict[str, str]:
+    """Full name of each device operation (its HLO line) -> the
+    ``op_name`` the compiler kept for it, which is the path of
+    ``jax.named_scope`` and jitted-function names it was traced under
+    (``jit(_body)/jit(_run_resident)/pallas_call``). The profiler stores it
+    as the stat ``tf_op`` of the event's metadata in a device plane
+    (XSpace.planes=1; XPlane.name=2, event_metadata=4, stat_metadata=5;
+    XEventMetadata.name=2, stats=5; XStat.metadata_id=1, str_value=5,
+    ref_value=7; XStatMetadata.name=2)."""
+    found: dict[str, str] = {}
+    for number, plane in _fields(memoryview(serialized)):
+        if number != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for number, value in _fields(plane):
+            if number == 2:
+                name = bytes(value).decode()
+            elif number in (4, 5):
+                entry = dict(_fields(value))  # a map entry: key 1, value 2
+                if number == 4:
+                    events.append(entry[2])
+                else:
+                    stat_names[entry[1]] = bytes(
+                        dict(_fields(entry[2])).get(2, b"")).decode()
+        if not DEVICE_PLANE.match(name):
+            continue
+        for event in events:
+            op, scope = "", ""
+            for number, value in _fields(event):
+                if number == 2:
+                    op = bytes(value).decode()
+                elif number == 5:
+                    stat = dict(_fields(value))
+                    if stat_names.get(stat.get(1)) == "tf_op":
+                        scope = (bytes(stat[5]).decode() if 5 in stat
+                                 else stat_names.get(stat.get(7), ""))
+            if op and scope:
+                found[op] = scope.rstrip(":")
+    return found
+
+
 def load_xplane(path: str, mark_prefix: str = "chipbench") -> tuple[Trace, dict]:
     """The trace, and a summary of what the file held (plane and line names
     with event counts) for the run's log."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    with open(path, "rb") as f:
+        serialized = f.read()
+    data = ProfileData.from_serialized_xspace(serialized)
+    scopes = op_scopes(serialized)
     trace, summary = Trace(), {}
     for plane in data.planes:
         lines = {}
@@ -91,6 +177,8 @@ def load_xplane(path: str, mark_prefix: str = "chipbench") -> tuple[Trace, dict]
             lines[line.name] = len(events)
             if m and line.name == OPS_LINE:
                 trace.device_ops[int(m.group(1))] = events
+                trace.op_scopes[int(m.group(1))] = [
+                    scopes.get(e.name, "") for e in line.events]
             elif m and line.name == MODULES_LINE:
                 trace.programs[int(m.group(1))] = events
             elif not m:
@@ -140,6 +228,39 @@ def program_executions(trace: Trace, pattern: str,
     events = trace.programs[min(trace.programs)]
     return [e for e in events if rx.search(e[0])
             and e[1] >= window[0] and e[1] + e[2] <= window[1]]
+
+
+def operation_executions(trace: Trace, pattern: str, window: tuple[int, int],
+                         program: str | None = None) -> tuple[int, int]:
+    """Device time of one operation inside its program: ``(ns, runs)``.
+
+    ``pattern`` is searched in each operation's name and in its
+    named-scope path, on the first device. A matching operation belongs to
+    the program execution (XLA Modules line, wholly inside ``window``, and
+    matching ``program`` where one is given) that it ran inside; ``ns`` is
+    the union of their intervals (a loop and the operations of its body
+    are both on the line, and may both match) and ``runs`` counts the
+    executions of every program that held one, so an operation that runs
+    many times an execution is summed and one that a branch skips still
+    divides by every execution. ``(0, 0)`` where nothing matches."""
+    if not trace.programs or not trace.device_ops:
+        return 0, 0
+    device = min(trace.programs)
+    runs = sorted(program_executions(trace, program or "", window),
+                  key=lambda e: e[1])
+    starts = [e[1] for e in runs]
+    rx = re.compile(pattern)
+    scopes = trace.op_scopes.get(device, [])
+    inside, holders = [], set()
+    for i, (name, start, dur) in enumerate(trace.device_ops.get(device, [])):
+        if not (rx.search(name) or (i < len(scopes) and rx.search(scopes[i]))):
+            continue
+        at = bisect.bisect_right(starts, start) - 1
+        if at >= 0 and start + dur <= runs[at][1] + runs[at][2]:
+            inside.append((start, start + dur))
+            holders.add(runs[at][0])
+    return (sum(hi - lo for lo, hi in _union(inside)),
+            sum(1 for e in runs if e[0] in holders))
 
 
 def top_device_ops(trace: Trace, window: tuple[int, int], n: int = 10) -> list:

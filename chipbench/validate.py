@@ -9,6 +9,13 @@ a session head (``heads/<name>.py``) and the operations and bytes of a
 kernel (``costs/<name>.py``). A later PR adds a cell by adding files and
 entries, never by editing one.
 
+A configuration taken from a published source holds that source's keys
+at its own top level, under the source's names (``source_keys`` lists
+them), and a copy of the source sits beside it as
+``sources/<config>.json``; ``check_source`` holds the one to the other by
+the rule in ``source_rules.json``: a count may differ where ``reduced``
+names it, a width never.
+
 ``check_manifest`` returns every breach as one line; ``python -m
 chipbench.run --validate`` prints them and exits non-zero on any.
 """
@@ -43,9 +50,14 @@ _CONFIG_KEYS = {"name", "source", "chips", "resident_accounts", "ml_backend",
                 "precision", "guarantees", "reduced", "reduced_why", "assumed",
                 "limits"}
 # A configuration may bring its session head: ``{"reference": <name of a
-# file under heads/>, ...}``. The other keys are that head's own (its
-# sizes, what was published, how it was cut) and are not listed here.
-_CONFIG_OPTIONAL = {"head"}
+# file under heads/>, ...}``. The other keys are that head's own (the
+# source's value of each reduced key under ``published``, the
+# ``deployment``, what was ``assumed``, sizes of the program's own) and
+# are not listed here. ``source_keys`` lists the top-level keys that are
+# its published source's, under the source's names: they are admitted
+# beside these, and ``sources/<config>.json`` is what they are held to.
+_CONFIG_OPTIONAL = {"head", "source_keys"}
+_SOURCE_KEYS = {"name", "source_url", "config"}
 # What a code file found by name has to define (``load_code``).
 CODE_DEFINES = {"heads": lambda name: ("make_params", "forward"),
                 "costs": lambda name: (name,)}
@@ -60,6 +72,8 @@ READER_PARAMS = {
     "trace_device_idle": set(),
     "trace_program_ms": {"pattern"},
     "trace_roofline_share": {"pattern", "cost"},
+    "trace_op_ms": {"pattern", "program"},
+    "trace_op_roofline_share": {"pattern", "cost", "program"},
     "client_latency": {"percentile"},
 }
 
@@ -74,8 +88,8 @@ def load_manifest(root: str = ROOT) -> dict:
 
 
 def data_path(kind: str, name: str, root: str = ROOT, ext: str = ".json") -> str:
-    """``kind`` is ``configs``, ``traffic`` or ``layer_metrics``, or with
-    ``ext=".py"`` ``heads`` or ``costs``."""
+    """``kind`` is ``configs``, ``sources``, ``traffic`` or
+    ``layer_metrics``, or with ``ext=".py"`` ``heads`` or ``costs``."""
     if not NAME_RE.match(name):
         raise ValueError(f"{kind} name {name!r} breaks the character rules")
     return os.path.join(root, "chipbench", kind, name + ext)
@@ -173,6 +187,174 @@ def _check_keys(errors: list, what: str, entry: dict, required: set,
         errors.append(f"{what}: unknown keys {sorted(unknown)}")
 
 
+_rules: dict = {}
+
+
+def source_rules() -> dict:
+    """``source_rules.json``: which keys of a source count something (the
+    rest are widths) and the floors a cut keeps to, with the guide's
+    sentences they come from. The yardstick's own file, so it is read
+    from beside this module whatever ``root`` is checked."""
+    if not _rules:
+        _rules.update(_load(os.path.join(HERE, "source_rules.json")))
+    return _rules
+
+
+def load_source(name: str, root: str = ROOT) -> dict | None:
+    """``chipbench/sources/<config>.json``, the published entry a
+    configuration was taken from (``{"name", "source_url", "config"}``),
+    or ``None`` where the configuration has no such file."""
+    path = data_path("sources", name, root)
+    return _load(path) if os.path.isfile(path) else None
+
+
+def _same(a, b) -> bool:
+    """Equal as JSON: a boolean is not the number 1, ``null`` is only
+    ``null``, lists and groups compare entry by entry."""
+    if isinstance(a, bool) or isinstance(b, bool) or a is None or b is None:
+        return a is b
+    if isinstance(a, (int, float)) and isinstance(b, (int, float)):
+        return a == b
+    if isinstance(a, list) and isinstance(b, list):
+        return len(a) == len(b) and all(map(_same, a, b))
+    if isinstance(a, dict) and isinstance(b, dict):
+        return a.keys() == b.keys() and all(_same(a[k], b[k]) for k in a)
+    return type(a) is type(b) and a == b
+
+
+def _show(value, limit: int = 60) -> str:
+    text = json.dumps(value, separators=(",", ":"))
+    return text if len(text) <= limit else text[:limit - 3] + "..."
+
+
+def check_source(where: str, data: dict, source: dict, reduced) -> list[str]:
+    """Hold a configuration file to the copy of its source: every key of
+    the source's ``config`` is a top-level key of the file under the same
+    name; a value equals the source's unless ``reduced`` names the key; a
+    nested group equals the source's key for key unless ``reduced`` names
+    the group or the key; and a width never differs, named or not. One
+    line a breach, naming the key, the file's value and the source's."""
+    rules, errors = source_rules(), []
+    counts = {k for keys in rules["counts"].values() for k in keys}
+    reduced = set(reduced if isinstance(reduced, list) else [])
+    published = data.get("head", {})
+    published = published.get("published", {}) if isinstance(published, dict) else {}
+
+    def breach(path: str, mine, theirs, why: str) -> None:
+        errors.append(f"{where}: {path} is {_show(mine)} and its source "
+                      f"gives {_show(theirs)}: {why}")
+
+    def compare(path: str, key: str, mine, theirs, named: bool) -> None:
+        if isinstance(mine, dict) and isinstance(theirs, dict):
+            for k in sorted(theirs.keys() - mine.keys()):
+                errors.append(f"{where}: {path}.{k} is missing and its source "
+                              f"gives {_show(theirs[k])}: a nested group is "
+                              "copied whole")
+            for k in sorted(mine.keys() - theirs.keys()):
+                errors.append(f"{where}: {path}.{k} is {_show(mine[k])} and "
+                              "its source has no such key: a nested group is "
+                              "copied whole")
+            for k in sorted(mine.keys() & theirs.keys()):
+                compare(f"{path}.{k}", k, mine[k], theirs[k],
+                        named or k in reduced)
+        elif _same(mine, theirs):
+            return
+        elif key not in counts:
+            breach(path, mine, theirs, "a width may not differ, whether or "
+                   "not reduced names it or the group that holds it")
+        elif not named:
+            breach(path, mine, theirs, "reduced does not name it")
+
+    theirs = source["config"]
+    for key, value in theirs.items():
+        if key not in data:
+            hint = (" (it is under head.published, where nothing looks for it)"
+                    if key in published else "")
+            errors.append(f"{where}: {key} is missing at the top level and its "
+                          f"source gives {_show(value)}{hint}")
+        else:
+            compare(key, key, data[key], value, key in reduced)
+
+    # -- what a cut keeps to (the guide's floors), where the keys exist -----
+    floors = rules["floors"]
+
+    def first(group: str):
+        return next(((k, data[k]) for k in rules["counts"][group]
+                     if k in theirs and isinstance(data.get(k), int)
+                     and isinstance(theirs[k], int)), None)
+
+    layers, dense = first("layers"), first("leading_dense_layers")
+    if layers and (layers[1] != theirs[layers[0]]
+                   or (dense and dense[1] != theirs[dense[0]])):
+        left = layers[1] - (dense[1] if dense else 0)
+        if left < floors["layers_after_leading_dense"]:
+            breach(layers[0], layers[1], theirs[layers[0]],
+                   f"{left} layers follow the leading dense ones; a cut keeps "
+                   f"at least {floors['layers_after_leading_dense']}")
+    for key in rules["counts"]["one_entry_per_layer"]:
+        if layers and isinstance(data.get(key), list) and key in theirs:
+            if len(data[key]) != layers[1]:
+                breach(key, data[key], theirs[key], f"{len(data[key])} "
+                       f"entries for {layers[0]} {layers[1]}")
+    for group, least, why in (
+            ("experts_held", lambda n: floors["routed_experts"],
+             f"at least {floors['routed_experts']} routed experts"),
+            ("vocabulary_rows", lambda n: floors["vocabulary_share"] * n,
+             "at least an eighth of the vocabulary")):
+        for key in rules["counts"][group]:
+            mine, src = data.get(key), theirs.get(key)
+            if (isinstance(mine, int) and isinstance(src, int) and mine != src
+                    and not least(src) <= mine <= src):
+                breach(key, mine, src, f"a cut holds {why} and no more than "
+                       "the source has")
+    return errors
+
+
+def _check_config_source(errors: list, path: str, name: str, data: dict,
+                         root: str) -> set:
+    """The configuration's ``source_keys`` and its file under
+    ``sources/``; returns the top-level names that ``source_keys``
+    admits."""
+    listed = data.get("source_keys", [])
+    if not (isinstance(listed, list)
+            and all(isinstance(k, str) and k for k in listed)):
+        errors.append(f"{path}: source_keys is a list of the top-level keys "
+                      "that are the published source's")
+        return set()
+    for key in listed:
+        if key in _CONFIG_KEYS | _CONFIG_OPTIONAL:
+            errors.append(f"{path}: source_keys names {key!r}, which is a key "
+                          "of the benchmark's own")
+        elif key not in data:
+            errors.append(f"{path}: source_keys names {key!r}, which is not a "
+                          "top-level key of the file")
+    spath = f"chipbench/sources/{name}.json"
+    try:
+        source = load_source(name, root)
+    except (OSError, ValueError) as exc:
+        errors.append(f"{spath}: {exc}")
+        return set(listed)
+    if source is None:
+        if listed:
+            errors.append(f"{path}: source_keys without {spath}, the copy of "
+                          "the source that they are held to")
+        return set(listed)
+    if not (isinstance(source, dict) and _SOURCE_KEYS <= source.keys()
+            and isinstance(source["config"], dict)):
+        errors.append(f"{spath}: an object with {sorted(_SOURCE_KEYS)}, the "
+                      "source's entry copied whole")
+        return set(listed)
+    if data.get("source") != source["source_url"]:
+        errors.append(f"{path}: source {data.get('source')!r} is not its "
+                      f"source's source_url {source['source_url']!r}")
+    for key in sorted((set(listed) & data.keys()) - source["config"].keys()
+                      - _CONFIG_KEYS - _CONFIG_OPTIONAL):
+        errors.append(f"{path}: source_keys names {key!r}, which {spath} does "
+                      "not have")
+    errors.extend(check_source(path, data, source, data.get("reduced")))
+    return set(listed)
+
+
 def check_manifest(root: str = ROOT) -> list[str]:
     """Every breach of the rules in ``BENCHMARK.json`` and the data files
     it names, one line each; empty when all hold."""
@@ -245,7 +427,8 @@ def check_manifest(root: str = ROOT) -> list[str]:
         except (OSError, ValueError) as exc:
             errors.append(f"{what}: {exc}")
             continue
-        _check_keys(errors, f"{path}", data, _CONFIG_KEYS, _CONFIG_OPTIONAL)
+        admitted = _check_config_source(errors, path, name, data, root)
+        _check_keys(errors, path, data, _CONFIG_KEYS, _CONFIG_OPTIONAL | admitted)
         head = data.get("head")
         if head is not None and not (isinstance(head, dict) and isinstance(
                 head.get("reference"), str)):
