@@ -747,6 +747,21 @@ class ServiceMetrics:
             "scoring step's donated in-place ring scatter (one per "
             "session-scored row)",
         )
+        self.session_twin_bytes = self.registry.gauge(
+            f"{service}_session_twin_bytes",
+            "Host bytes held by the per-account session buffers (sum of "
+            "their capacities; a buffer grows with the events its "
+            "account holds, up to 4 * SESSION_EVENTS rows) - over "
+            "accounts_tracked of /debug/sessionz it is what an account "
+            "costs the host",
+        )
+        self.session_twin_regrows_total = self.registry.counter(
+            f"{service}_session_twin_regrows_total",
+            "Session appends that had to reallocate an account's host "
+            "buffer after its first allocation (growth or compaction) - "
+            "over session_appends_total it is the share of events that "
+            "pay a copy",
+        )
         self.session_rehydrations_total = self.registry.counter(
             f"{service}_session_rehydrations_total",
             "Session windows restored into HBM from the host session "

@@ -629,22 +629,40 @@ def make_ring_sync(mesh=None, plan=None):
 
 
 _EMPTY_WINDOW = np.zeros((0, EVENT_WIDTH), np.float32)
+_EMPTY_WINDOW.setflags(write=False)
+
+# The host twin's first buffer and how it grows, in rows (measured on
+# the chip's host, PERF.md section 6, PR 31). Capacity stops growing at
+# the steady ``4 * n_events`` rows.
+_TWIN_FIRST_ROWS = 2
+_TWIN_GROWTH = 2
 
 
 class _AcctSession:
     """Host-authoritative window for one account.
 
-    Events live in an APPEND-ONLY buffer (compacted only when full, by
-    reallocating — never by shifting in place), so window snapshots can
-    be handed out as stable numpy VIEWS: the lazy hash audit
-    (SessionChunkAudit) reads them on the ledger writer thread while
-    later chunks keep appending. ``seq`` is the monotone total event
-    count; the live window is the last ``min(seq, N)`` buffer rows."""
+    Events live in an APPEND-ONLY buffer that holds bytes in proportion
+    to the events it has been given: a never-seen account shares the
+    read-only ``_EMPTY_WINDOW`` and allocates nothing; the first append
+    allocates ``_TWIN_FIRST_ROWS`` rows (or the rows it is given), later
+    ones grow the capacity by ``_TWIN_GROWTH`` up to the steady
+    ``4 * n_events`` rows, and from there the buffer is compacted when
+    full. Growth and compaction both REALLOCATE (``np.empty``: every row
+    below ``count`` is written before it is read) and copy the last
+    ``min(count, n_events)`` rows - never a shift in place, never a view
+    of a chunk's array - and rows ``[0:count]`` of an array are never
+    written again, so window snapshots can be handed out as stable numpy
+    VIEWS: the lazy hash audit (SessionChunkAudit) reads them on the
+    ledger writer thread while later chunks keep appending. An account
+    that has received ``k`` events holds at most ``2 * max(k, 2)`` rows
+    and never more than the steady size (plus one oversized chunk).
+    ``seq`` is the monotone total event count; the live window is the
+    last ``min(seq, N)`` buffer rows."""
 
     __slots__ = ("buf", "count", "seq", "last_ts")
 
-    def __init__(self, n_events: int):
-        self.buf = np.zeros((4 * n_events, EVENT_WIDTH), dtype=np.float32)
+    def __init__(self):
+        self.buf = _EMPTY_WINDOW
         self.count = 0  # rows currently stored in buf
         self.seq = 0    # total events ever appended
         self.last_ts = 0.0
@@ -654,12 +672,21 @@ class _AcctSession:
         return self.buf[self.count - k:self.count]
 
     def append_rows(self, rows: np.ndarray, n_events: int,
-                    now: float) -> None:
+                    now: float) -> int:
+        """Appends ``rows``; returns the row capacity of the buffer it
+        left behind where it had to allocate, else -1."""
         k = rows.shape[0]
+        old = -1
         if self.count + k > self.buf.shape[0]:
+            old = self.buf.shape[0]
             keep = min(self.count, n_events)
-            nb = np.empty((max(4 * n_events, k + n_events), EVENT_WIDTH),
-                          dtype=np.float32)
+            steady = 4 * n_events
+            if old >= steady or keep + k > steady:
+                cap = max(steady, k + n_events)
+            else:
+                cap = min(steady, max(_TWIN_FIRST_ROWS, _TWIN_GROWTH * old,
+                                      keep + k))
+            nb = np.empty((cap, EVENT_WIDTH), dtype=np.float32)
             nb[:keep] = self.buf[self.count - keep:self.count]
             self.buf = nb  # old views (audit snapshots) keep the old buf
             self.count = keep
@@ -667,6 +694,7 @@ class _AcctSession:
         self.count += k
         self.seq += k
         self.last_ts = now
+        return old
 
 
 class SessionStateManager:
@@ -674,7 +702,12 @@ class SessionStateManager:
 
     The host index (``_session_twin``) is authoritative — the device
     ring is its slot-resident projection, synced on admission and
-    advanced by the fused step's donated append. The ring is allocated
+    advanced by the fused step's donated append. An account's host
+    buffer grows with the events it holds (``_AcctSession``): a first
+    event costs a 2-row buffer, not a window; ``twin_bytes`` is the sum
+    of the live buffers' capacities (kept at allocation, never by a
+    walk) and ``twin_regrows`` counts the appends that had to allocate
+    again after an account's first allocation. The ring is allocated
     flat ([slots * N * D] float32, under its sharding from birth) and
     every program that touches it — the step, the admission sync, their
     slot-sharded twins — donates it and goes through ``ring_rows`` /
@@ -716,6 +749,8 @@ class SessionStateManager:
         self.warm_rows = 0
         self.cold_rows = 0
         self.bypass_rows = 0
+        self.twin_bytes = 0
+        self.twin_regrows = 0
 
         from igaming_platform_tpu.parallel import state_sharding
 
@@ -758,10 +793,10 @@ class SessionStateManager:
         self._metrics = metrics
         with self.lock:
             self._export(self.warm_rows, self.cold_rows, self.bypass_rows,
-                         self.appends, self.rehydrations)
+                         self.appends, self.rehydrations, self.twin_regrows)
 
     def _export(self, warm: int, cold: int, bypass: int, appends: int,
-                rehydrations: int) -> None:
+                rehydrations: int, regrows: int = 0) -> None:
         m = self._metrics
         if m is None:
             return
@@ -775,6 +810,9 @@ class SessionStateManager:
             m.session_appends_total.inc(appends)
         if rehydrations:
             m.session_rehydrations_total.inc(rehydrations)
+        if regrows:
+            m.session_twin_regrows_total.inc(regrows)
+        m.session_twin_bytes.set(self.twin_bytes)
         m.session_hbm_bytes.set(self.hbm_bytes())
         for s, b in enumerate(self.hbm_bytes_per_shard()):
             m.hbm_bytes.set(b, shard=str(s), table="session_ring")
@@ -810,6 +848,8 @@ class SessionStateManager:
                 "accounts_tracked": len(self._twin),
                 "hbm_bytes": self.hbm_bytes(),
                 "appends": self.appends,
+                "twin_bytes": self.twin_bytes,
+                "twin_regrows": self.twin_regrows,
                 "rehydrations": self.rehydrations,
                 "admissions": self.admissions,
                 "rows": {"warm": self.warm_rows, "cold": self.cold_rows,
@@ -909,7 +949,7 @@ class SessionStateManager:
                 uniq[a] = u
                 tw = twin.get(a)
                 if tw is None:
-                    tw = _AcctSession(n_ev)
+                    tw = _AcctSession()
                     twin[a] = tw
                 utw.append(tw)
                 snaps.append((tw.buf, tw.count))
@@ -929,24 +969,30 @@ class SessionStateManager:
         # device append scatters the same rows at cursor+occ). The
         # common all-unique chunk skips the argsort/grouping machinery.
         if len(utw) == b:
-            for i in range(b):
-                utw[i].append_rows(events[i:i + 1], n_ev, now)
+            groups = ((utw[i], events[i:i + 1]) for i in range(b))
         else:
             order = np.argsort(uidx, kind="stable")
             sorted_u = uidx[order]
             starts = np.flatnonzero(np.concatenate(
                 ([True], sorted_u[1:] != sorted_u[:-1])))
             bounds = np.append(starts, b)
-            for r in range(len(starts)):
-                rows = order[bounds[r]:bounds[r + 1]]
-                utw[int(sorted_u[bounds[r]])].append_rows(
-                    events[rows], n_ev, now)
+            groups = ((utw[int(sorted_u[bounds[r]])],
+                       events[order[bounds[r]:bounds[r + 1]]])
+                      for r in range(len(starts)))
+        grown = regrows = 0  # rows of capacity added; reallocations
+        for tw, rows in groups:
+            old = tw.append_rows(rows, n_ev, now)
+            if old >= 0:
+                grown += tw.buf.shape[0] - old
+                regrows += old > 0
+        self.twin_bytes += grown * EVENT_WIDTH * 4
+        self.twin_regrows += regrows
         warm = int(np.count_nonzero(post_len >= self.min_events))
         cold = b - warm
         self.appends += b
         self.warm_rows += warm
         self.cold_rows += cold
-        self._export(warm, cold, 0, b, 0)
+        self._export(warm, cold, 0, b, 0, regrows)
         return events, occ, post_len, seqs, audit
 
     def adopt(self, ring, cursor, length) -> None:  # analysis: session-append-seam

@@ -548,3 +548,51 @@ def test_replay_detects_tampered_score(tmp_path):
     assert not verdict["ok"]
     sample = verdict["mismatch_samples"][0]
     assert sample["recorded"]["score"] == sample["recomputed"]["score"] + 7
+
+
+@pytest.mark.parametrize("rounds", [3, 2 * 16 + 1, 4 * 16 + 6])
+def test_replay_session_hashes_held_across_twin_growth(tmp_path, monkeypatch,
+                                                       rounds):
+    """Stateful decisions whose accounts' host session buffers grow (3
+    rounds: 2 -> 4 -> 8 rows; 33: every step up to the steady size; 70:
+    a compaction too) between the score that recorded a window snapshot
+    and the writer thread that hashes it: the writer is held until every
+    append has happened, then replay rebuilds each window from ledger
+    event order and every session_state_hash is bit-exact."""
+    from tools.replay import replay_directory
+
+    engine = _mock_engine(batch=16, feature_cache=8, session_state=True)
+    d = str(tmp_path / "growth")
+    led = DecisionLedger(d)
+    engine.ledger = led
+    engine.ensure_cache()
+    gate = threading.Event()
+    write_batches = led._write_batches
+
+    def held_write(batches):
+        assert gate.wait(60.0)
+        return write_batches(batches)
+
+    monkeypatch.setattr(led, "_write_batches", held_write)
+    try:
+        for r in range(rounds):
+            ids = ["ga", "gb", "ga", "gc"][:3 + r % 2]  # ga twice a chunk
+            engine.score_columns_cached(
+                ids, [400 + 7 * r + i for i in range(len(ids))],
+                [("bet", "deposit", "withdraw")[(r + i) % 3]
+                 for i in range(len(ids))],
+                now=1_700_000_000.0 + 20.0 * r)
+        snap = engine.session.snapshot()
+        assert snap["twin_regrows"] >= 3
+        gate.set()
+        assert led.flush(30.0)
+    finally:
+        gate.set()
+        led.close()
+        engine.close()
+    v = replay_directory(d, batch=16)
+    assert v["session_records"] == 3 * rounds + rounds // 2
+    assert v["session_verified"] == v["session_records"]
+    assert v["session_hash_mismatch"] == 0
+    assert v["session_chain_gaps"] == 0 and v["session_reordered"] == 0
+    assert v["session_ok"] and v["ok"]

@@ -18,7 +18,11 @@ Covers the session plane's contracts end to end on the CPU control rig:
 
 from __future__ import annotations
 
+import hashlib
+import queue
+import sys
 import tempfile
+import threading
 
 import numpy as np
 import pytest
@@ -41,6 +45,7 @@ from igaming_platform_tpu.serve.wire import TX_TYPE_CODES
 from igaming_platform_tpu.train.fraudgen import FraudRing
 
 NOW0 = 1_700_000_000.0
+_N = 16  # SESSION_EVENTS default; the growth cases are named in its terms
 
 
 def make_engine(batch_size=16, capacity=8, session=True, tiers=(8,),
@@ -97,22 +102,32 @@ def test_event_codec_deterministic_and_hash_stable():
 # Ring parity vs the numpy twin (append, wrap, eviction)
 
 
-def test_ring_append_wrap_parity_vs_twin():
+# Rounds of one event an account: 1-5 stay in the first buffers of the
+# host twin (2, 4, 8 rows), N + 5 is the device ring's wrap, 2N + 1 and
+# 4N + 2 cross the twin's last growth step and its first compaction.
+@pytest.mark.parametrize("rounds", [1, 2, 3, 5, _N + 5, 2 * _N + 1, 4 * _N + 2])
+def test_ring_append_wrap_parity_vs_twin(rounds):
     eng = make_engine(capacity=4)
     n_events = eng.session.n_events
+    assert n_events == _N
     accts = [f"tw{i}" for i in range(3)]
-    rounds = n_events + 5  # force wrap-around past N events per account
     for r in range(rounds):
+        # tw0 twice in one chunk on odd rounds: it crosses every growth
+        # step of its buffer a round or two before the others, some of
+        # them with a two-row append
+        ids = accts + accts[:r % 2]
         eng.score_columns_cached(
-            accts, [500 + 13 * r + i for i in range(3)],
-            [("bet", "deposit", "withdraw")[(r + i) % 3] for i in range(3)],
+            ids, [500 + 13 * r + i for i in range(len(ids))],
+            [("bet", "deposit", "withdraw")[(r + i) % 3]
+             for i in range(len(ids))],
             now=NOW0 + 30.0 * r)
+        for a in accts:
+            assert np.array_equal(ring_rows(eng, a),
+                                  eng.session.twin_window(a)), (a, r)
     for a in accts:
-        twin = eng.session.twin_window(a)
-        dev = ring_rows(eng, a)
-        assert twin.shape[0] == n_events  # saturated
-        assert np.array_equal(dev, twin), a
-        assert eng.session.twin_meta(a)["seq"] == rounds
+        seq = rounds + (rounds // 2 if a == "tw0" else 0)
+        assert eng.session.twin_window(a).shape[0] == min(seq, n_events)
+        assert eng.session.twin_meta(a)["seq"] == seq
     close_engine(eng)
 
 
@@ -128,6 +143,200 @@ def test_duplicate_accounts_in_one_chunk_batch_snapshot():
     meta = eng.session.twin_meta("dup")
     assert meta["seq"] == 3
     close_engine(eng)
+
+
+# ---------------------------------------------------------------------------
+# The host twin grows with the events it holds (ISSUE 31): stable views,
+# the same windows and hashes, bytes in proportion
+
+
+_GROWTH_COUNTS = {"1": 1, "2": 2, "3": 3, "5": 5, "N": _N, "N+1": _N + 1,
+                  "4N": 4 * _N, "4N+1": 4 * _N + 1, "5N": 5 * _N}
+
+
+class _TwinModel:
+    """Plain numpy model of one account's session chain, rebuilt from the
+    order sent: batch-snapshot windows, blake2b-8 over history + event."""
+
+    def __init__(self, n_events):
+        self.n = n_events
+        self.events = []
+        self.last_ts = 0.0
+
+    def chunk(self, amounts, codes, now):
+        """Hashes of this chunk's rows for the account, then commits."""
+        seq0 = len(self.events)
+        dt = max(0.0, now - self.last_ts) if seq0 else 0.0
+        rows = session_mod.encode_events_host(
+            amounts, codes, [dt] * len(amounts))
+        hist = self.events[seq0 - min(seq0, self.n - 1):]
+        out = [hashlib.blake2b(
+            b"".join(e.tobytes() for e in hist) + row.tobytes(),
+            digest_size=8).digest() for row in rows]
+        self.events.extend(rows)
+        self.last_ts = now
+        return out
+
+    def window(self):
+        if not self.events:
+            return np.zeros((0, session_mod.EVENT_WIDTH), np.float32)
+        return np.stack(self.events[-self.n:])
+
+
+def _twin_walk_bytes(mgr):
+    return sum(tw.buf.nbytes for tw in mgr._twin.values())
+
+
+@pytest.mark.parametrize("how", ["singly", "one_chunk"])
+@pytest.mark.parametrize("count", list(_GROWTH_COUNTS))
+def test_twin_growth_keeps_views_hashes_and_windows(count, how):
+    """``count`` events to one account, one a chunk or all as duplicates
+    inside one chunk (between two other accounts), then three chunks
+    more: every audit hash read after all later appends equals the hash
+    read at once and the numpy model's, across growth and compaction."""
+    mgr = session_mod.SessionStateManager(4)
+    n = mgr.n_events
+    assert n == _N
+    total = _GROWTH_COUNTS[count]
+    model = _TwinModel(n)
+    sizes = [1] * total if how == "singly" else [total]
+    sizes += [2, 1, 3]  # the later appends
+    held = []  # (audit, rows of the account, hashes read at once, expected)
+    sent = 0
+    for c, k in enumerate(sizes):
+        now = NOW0 + 7.0 * c
+        amounts = [100 + 3 * (sent + j) for j in range(k)]
+        codes = [(sent + j) % 5 for j in range(k)]
+        sent += k
+        ids = ["g"] * k
+        if how == "one_chunk":
+            ids = [f"pre{c}"] + ids + [f"post{c}"]
+            amounts = [11] + amounts + [13]
+            codes = [0] + codes + [1]
+        events, occ, post_len, seqs, audit = mgr.prepare_chunk(
+            ids, amounts, codes, now)
+        rows = [i for i, a in enumerate(ids) if a == "g"]
+        want = model.chunk([amounts[i] for i in rows],
+                           [codes[i] for i in rows], now)
+        at_once = [audit[i] for i in range(len(audit))]
+        assert [at_once[i] for i in rows] == want
+        assert list(seqs[rows]) == list(range(sent - k + 1, sent + 1))
+        held.append((audit, rows, at_once, want))
+        assert np.array_equal(mgr.twin_window("g"), model.window())
+        tw = mgr._twin["g"]
+        # steady size, or one oversized chunk as before this PR
+        assert tw.buf.shape[0] <= max(4 * n, max(sizes[:c + 1]) + n)
+        assert tw.buf.shape[0] <= 2 * max(tw.seq, 2)
+        assert tw.buf.base is None  # its own array, never a view of a chunk's
+    for audit, rows, at_once, want in held:
+        later = [audit[i] for i in range(len(audit))]
+        assert later == at_once
+        assert [later[i] for i in rows] == want
+    snap = mgr.snapshot()
+    assert snap["twin_bytes"] == _twin_walk_bytes(mgr)
+    assert snap["appends"] == sum(len(h[2]) for h in held)
+
+
+@pytest.mark.parametrize("before", [1, 3, 5, _N + 1, 2 * _N + 1])
+def test_grown_twin_rehydrates_the_same_window(before):
+    """An account whose host buffer has grown (3 events: 2 -> 4 rows; the
+    other counts cross the later steps) is evicted and re-admitted: the
+    ring is rehydrated with the window the numpy model holds."""
+    eng = make_engine(capacity=4)
+    model = _TwinModel(eng.session.n_events)
+    for r in range(before):
+        now = NOW0 + 11.0 * r
+        eng.score_columns_cached(["grown"], [700 + r], ["bet"], now=now)
+        model.chunk([700 + r], [TX_TYPE_CODES["bet"]], now)
+    assert np.array_equal(ring_rows(eng, "grown"), model.window())
+    others = [f"ot{i}" for i in range(8)]
+    for lo in (0, 4):  # 2x capacity of other accounts: CLOCK evicts
+        eng.score_columns_cached(others[lo:lo + 4], [50] * 4, ["deposit"] * 4,
+                                 now=NOW0 + 900.0 + lo)
+    assert "grown" not in eng.cache._slots
+    assert np.array_equal(eng.session.twin_window("grown"), model.window())
+    rehydrations = eng.session.rehydrations
+    eng.score_columns_cached(["grown"], [999], ["withdraw"], now=NOW0 + 2000.0)
+    model.chunk([999], [TX_TYPE_CODES["withdraw"]], NOW0 + 2000.0)
+    assert eng.session.rehydrations == rehydrations + 1
+    assert np.array_equal(ring_rows(eng, "grown"), model.window())
+    assert np.array_equal(eng.session.twin_window("grown"), model.window())
+    close_engine(eng)
+
+
+def test_audit_hashes_read_on_another_thread_while_appends_go_on():
+    """The ledger writer's side of the contract: a second thread hashes
+    every chunk's snapshots, without the session lock, while the first
+    keeps appending to the same accounts through every growth step and
+    two compactions; each hash equals the numpy model's."""
+    mgr = session_mod.SessionStateManager(4)
+    accounts = [f"c{i}" for i in range(6)]
+    models = {a: _TwinModel(mgr.n_events) for a in accounts}
+    handoff: queue.Queue = queue.Queue()
+    wrong = []
+
+    def reader():
+        while True:
+            item = handoff.get()
+            if item is None:
+                return
+            audit, want = item
+            got = [audit[i] for i in range(len(audit))]
+            if got != want:
+                wrong.append((got, want))
+
+    t = threading.Thread(target=reader, name="audit-reader")
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t.start()
+        for r in range(8 * _N):
+            now = NOW0 + 3.0 * r
+            ids = accounts + accounts[:1 + r % 3]  # duplicates in the chunk
+            amounts = [200 + 5 * r + i for i in range(len(ids))]
+            codes = [(r + i) % 5 for i in range(len(ids))]
+            with mgr.lock:
+                audit = mgr.prepare_chunk(ids, amounts, codes, now)[4]
+            want = [None] * len(ids)
+            for a in accounts:
+                rows = [i for i, x in enumerate(ids) if x == a]
+                for i, h in zip(rows, models[a].chunk(
+                        [amounts[i] for i in rows],
+                        [codes[i] for i in rows], now)):
+                    want[i] = h
+            handoff.put((audit, want))
+    finally:
+        handoff.put(None)
+        t.join(60.0)
+        sys.setswitchinterval(interval)
+    assert not t.is_alive()
+    assert not wrong, wrong[:1]
+    assert mgr.snapshot()["twin_bytes"] == _twin_walk_bytes(mgr)
+
+
+def test_twin_bytes_follow_the_events_held():
+    """A count, not a timing: one event to each of 10,000 never-seen
+    accounts holds at most 300 B an account and regrows nothing; a
+    second event to each regrows at most once an account."""
+    mgr = session_mod.SessionStateManager(4)
+    accounts = [f"n{i}" for i in range(10_000)]
+    for event in range(2):
+        for lo in range(0, len(accounts), 256):
+            ids = accounts[lo:lo + 256]
+            mgr.prepare_chunk(ids, [500 + event] * len(ids), [2] * len(ids),
+                              NOW0 + 60.0 * event)
+        snap = mgr.snapshot()
+        assert snap["accounts_tracked"] == 10_000
+        assert snap["appends"] == 10_000 * (event + 1)
+        assert snap["twin_bytes"] == _twin_walk_bytes(mgr)
+        assert snap["twin_bytes"] <= 300 * 10_000
+        assert snap["twin_regrows"] <= 10_000 * event
+    # and an account that fills its window ends at today's steady size
+    for r in range(5 * _N):
+        mgr.prepare_chunk(["n0"], [900 + r], [0], NOW0 + 500.0 + r)
+    steady = 4 * _N * session_mod.EVENT_WIDTH * 4
+    assert mgr._twin["n0"].buf.nbytes == steady
+    assert mgr.snapshot()["twin_bytes"] == _twin_walk_bytes(mgr)
 
 
 # ---------------------------------------------------------------------------
@@ -531,6 +740,8 @@ def test_session_rows_metric_exposition():
     text = m.registry.render_text()
     assert 'risk_session_rows_total{outcome="cold"}' in text
     assert "risk_session_appends_total" in text
+    assert "risk_session_twin_bytes" in text
+    assert "risk_session_twin_regrows_total" in text
     assert "risk_session_hbm_bytes" in text
     close_engine(eng)
 
