@@ -1,0 +1,115 @@
+"""Session heads: the models the fused session step runs over an
+account's post-append event window.
+
+``HEADS`` maps a ``SESSION_HEAD`` name to ``head_fn(params, window
+[B, N, D], lengths [B]) -> [B] prob`` (jittable) and ``init_params()``,
+the pinned seeded tree replay rebuilds without a checkpoint. The program
+(serve/index_program.py) takes ``head_fn`` as an argument and the
+parameters as a traced tree: a new head is a function, an init and a row.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from igaming_platform_tpu.models.sequence import (
+    EVENT_DIM,
+    SeqConfig,
+    init_sequence_model,
+    sequence_forward,
+)
+
+# One-hot sub-columns of the event vector (models/sequence.encode_event:
+# [log-amount, log-dt, 8-way tx-type one-hot, ...]) the pattern head reads.
+_COL_DEPOSIT = 2 + 0
+_COL_BET = 2 + 2
+
+
+def pattern_scores(window, lengths):
+    """Deterministic coordinated-cycling detector (the ``pattern`` head,
+    the session analog of models.mock_model: hand-tuned, paramless,
+    replay-exact by construction).
+
+    High iff the window shows bet/deposit CYCLING at a regular cadence
+    with consistent amounts — the coordinated-ring shape
+    (train/fraudgen.FraudRing) — each factor in [0, 1]:
+
+    - ``bd_frac``   fraction of events that are bets or deposits;
+    - ``alt_frac``  fraction of adjacent pairs alternating bet<->deposit;
+    - ``reg``       exp(-4 * var(log-dt)) over events 1.. — machine-paced
+                    cycles have near-constant gaps, humans don't;
+    - ``acons``     exp(-2 * var(log-amount)) — ring members push
+                    near-identical amounts.
+    """
+    n = window.shape[1]
+    k = jnp.arange(n)[None, :]
+    m = (k < lengths[:, None]).astype(jnp.float32)  # [B, N] valid-event mask
+    cnt = jnp.maximum(jnp.sum(m, axis=1), 1.0)
+
+    log_amt = window[..., 0]
+    log_dt = window[..., 1]
+    is_dep = window[..., _COL_DEPOSIT]
+    is_bet = window[..., _COL_BET]
+
+    bd_frac = jnp.sum((is_bet + is_dep) * m, axis=1) / cnt
+
+    pair_m = m[:, 1:] * m[:, :-1]
+    pairs = jnp.maximum(jnp.sum(pair_m, axis=1), 1.0)
+    alt = (is_bet[:, 1:] * is_dep[:, :-1] + is_dep[:, 1:] * is_bet[:, :-1])
+    alt_frac = jnp.sum(alt * pair_m, axis=1) / pairs
+
+    # dt regularity: skip event 0 (its gap points outside the window).
+    dt_m = m[:, 1:]
+    dt_cnt = jnp.maximum(jnp.sum(dt_m, axis=1), 1.0)
+    dt_mu = jnp.sum(log_dt[:, 1:] * dt_m, axis=1) / dt_cnt
+    dt_var = jnp.sum(((log_dt[:, 1:] - dt_mu[:, None]) ** 2) * dt_m, axis=1) / dt_cnt
+    reg = jnp.exp(-4.0 * dt_var)
+
+    a_mu = jnp.sum(log_amt * m, axis=1) / cnt
+    a_var = jnp.sum(((log_amt - a_mu[:, None]) ** 2) * m, axis=1) / cnt
+    acons = jnp.exp(-2.0 * a_var)
+
+    return jnp.clip(bd_frac * alt_frac * reg * acons, 0.0, 1.0)
+
+
+# SESSION_HEAD=transformer: the stock sequence model (models/sequence.py)
+# over the N-event window, params from the pinned seeded convention below.
+SESSION_SEQ_CONFIG = SeqConfig(d_model=32, n_heads=4, n_layers=1, d_ff=64,
+                               in_dim=EVENT_DIM, max_len=256)
+_SESSION_HEAD_SEED = 11
+
+
+def init_session_head_params(seed: int = _SESSION_HEAD_SEED):
+    """The pinned seeded init for the transformer session head (the same
+    convention tools/replay.py uses for serving params)."""
+    return init_sequence_model(jax.random.key(seed), SESSION_SEQ_CONFIG)
+
+
+def transformer_scores(sparams, window, lengths):
+    """The ``transformer`` head: the existing sequence model
+    (models/sequence.sequence_forward, dense attention) over the padded
+    window. Padding rows are zeroed by the window builder; positions
+    beyond ``lengths`` still contribute bias/positional terms — that is
+    deterministic and pinned, which is what replay needs."""
+    del lengths  # deterministic padded forward; mask lives in the zeros
+    return sequence_forward(sparams, window, SESSION_SEQ_CONFIG)["abuse"]
+
+
+# SESSION_HEAD name -> (head_fn(sparams, window, lengths), init_params()).
+HEADS = {
+    "pattern": (lambda sparams, win, lp: pattern_scores(win, lp),
+                lambda: None),
+    "transformer": (transformer_scores, init_session_head_params),
+}
+
+
+def session_head(name: str):
+    """``SESSION_HEAD`` name -> (head_fn, params)."""
+    try:
+        head_fn, init = HEADS[name]
+    except KeyError:
+        raise ValueError(
+            f"SESSION_HEAD={name!r} not supported "
+            f"(use one of {sorted(HEADS)})") from None
+    return head_fn, init()

@@ -62,7 +62,7 @@ from typing import Callable
 
 import numpy as np
 
-from igaming_platform_tpu.core.features import F, FEATURE_NAMES, NUM_FEATURES
+from igaming_platform_tpu.core.features import FEATURE_NAMES, NUM_FEATURES
 
 logger = logging.getLogger(__name__)
 
@@ -151,21 +151,14 @@ def sketch_kernel(x, packed, n):
 
 def cached_sketch_kernel(table, idxs, amounts, types, packed, n):
     """Index-mode sketch: re-compose the scored rows from the
-    device-resident feature table (the same gather + tx-context writes
-    as the cached score step — the rows never exist on the host) and
-    reduce. Device-to-device; the host only ever sees the tiny vector."""
-    import jax.numpy as jnp
+    device-resident feature table (the composition the cached score
+    step runs, serve/index_program.compose_rows — the rows never exist
+    on the host) and reduce. Device-to-device; the host only ever sees
+    the tiny vector."""
+    from igaming_platform_tpu.serve.index_program import compose_rows, slot_access
 
-    txa, td, tw, tb = (
-        int(F.TX_AMOUNT), int(F.TX_TYPE_DEPOSIT),
-        int(F.TX_TYPE_WITHDRAW), int(F.TX_TYPE_BET),
-    )
-    x = table[idxs]
-    f32 = x.dtype
-    x = x.at[:, txa].set(amounts)
-    x = x.at[:, td].set((types == 0).astype(f32))
-    x = x.at[:, tw].set((types == 1).astype(f32))
-    x = x.at[:, tb].set((types == 2).astype(f32))
+    x, _ = compose_rows(slot_access(None).take, table, None, idxs, amounts,
+                        types, None)
     return sketch_kernel(x, packed, n)
 
 

@@ -25,15 +25,15 @@ from dataclasses import dataclass
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from igaming_platform_tpu.core.config import BatcherConfig, ScoringConfig
-from igaming_platform_tpu.serve import chaos
+from igaming_platform_tpu.serve import chaos, index_program
 from igaming_platform_tpu.serve import ledger as ledger_mod
 from igaming_platform_tpu.core.enums import ReasonCode, action_from_code, decode_reason_mask
-from igaming_platform_tpu.core.features import F, NUM_FEATURES, FeatureVector
+from igaming_platform_tpu.core.features import NUM_FEATURES, FeatureVector
 from igaming_platform_tpu.models.ensemble import make_score_fn
+from igaming_platform_tpu.obs import drift as drift_mod
 from igaming_platform_tpu.obs.tracing import annotate, span
 from igaming_platform_tpu.parallel.mesh import AXIS_DATA, validate_batch_for_mesh
 from igaming_platform_tpu.serve.batcher import ContinuousBatcher, pad_batch
@@ -91,21 +91,6 @@ def _row_divisor(mesh, ml_backend: str) -> int:
     return max(1, d)
 
 
-def _stack_packed(out: dict):
-    """Canonical dict-output -> packed int32 [5, B] (score, action,
-    reason_mask, rule_score, ml_score as IEEE-754 bits) — one D2H
-    transfer instead of five."""
-    return jnp.stack([
-        out["score"].astype(jnp.int32),
-        out["action"].astype(jnp.int32),
-        out["reason_mask"].astype(jnp.int32),
-        out["rule_score"].astype(jnp.int32),
-        jax.lax.bitcast_convert_type(
-            out["ml_score"].astype(jnp.float32), jnp.int32
-        ),
-    ])
-
-
 def _pack_outputs(fn, echo_batch: bool = False):
     """Wrap a dict-output score fn into one int32 [5, B] output (one D2H
     transfer). Row order: score, action, reason_mask, rule_score,
@@ -121,7 +106,7 @@ def _pack_outputs(fn, echo_batch: bool = False):
     the donated buffer and the staging slot is recycled in place."""
 
     def packed(params, x, blacklisted, thresholds):
-        stacked = _stack_packed(fn(params, x, blacklisted, thresholds))
+        stacked = index_program.stack_packed(fn(params, x, blacklisted, thresholds))
         return (stacked, x) if echo_batch else stacked
 
     return packed
@@ -206,17 +191,15 @@ class TPUScoringEngine:
         self._drift_cached_fn = None
         self._drift_lock = threading.Lock()
         # Fused mega-step (one graph, one dispatch): per path family
-        # (packed / host / cached / session) a single pjit'd program
-        # folds the drift sketch and — when a candidate sits in shadow —
-        # the candidate re-score into the SAME dispatch, sharing the
-        # feature gather and elementwise prologue. Variants are keyed
-        # (family, sketch, shadow), built+AOT-warmed OFF the request
-        # path (bind_drift at boot; _on_shadow_candidate on a daemon
+        # (packed / host; cached / session: serve/index_program.py) one
+        # jitted program folds the drift sketch and — when a candidate
+        # sits in shadow — the candidate re-score into the SAME dispatch.
+        # Variants are keyed (family, sketch, shadow), built+AOT-warmed
+        # OFF the request path (boot; _on_shadow_candidate's daemon
         # thread), and a launch only selects a variant already in
-        # `_fused_ready` — until then it falls back to the split path,
-        # so neither bind_drift nor set_candidate ever stalls serving.
-        # FUSED=0 keeps the split paths entirely; SHADOW_FUSED=0 keeps
-        # the shadow on its fallback (echo-fed) path.
+        # `_fused_ready` — until then it rides the plain program and the
+        # split sketch / shadow. FUSED=0 keeps the split paths entirely;
+        # SHADOW_FUSED=0 keeps the shadow on its fallback (echo-fed) path.
         self._fused_enabled = os.environ.get("FUSED", "1") not in ("0", "false")
         self._shadow_fused_enabled = (
             os.environ.get("SHADOW_FUSED", "1") not in ("0", "false"))
@@ -316,8 +299,11 @@ class TPUScoringEngine:
                 "(use 'bf16', 'int8' or 'float32')")
 
         fn_f32 = make_score_fn(self.config, ml_backend, mesh=mesh)
-        # Raw dict-output graph, kept for the fused session step
-        # (serve/session_state.py composes the session head around it).
+        # Raw dict-output graph, kept for the index-mode programs
+        # (serve/index_program.py composes resident rows, the session
+        # head, the sketch and the shadow branch around it): they gather
+        # f32 rows already resident in HBM, so they wrap the raw-f32
+        # graph whatever WIRE_DTYPE says.
         self._score_fn_f32 = fn_f32
         fn = fn_f32
         if self._wire_dtype is np.int8:
@@ -341,12 +327,6 @@ class TPUScoringEngine:
         # inputs may be caller-owned arrays, and on the CPU backend jax
         # can alias host memory zero-copy.
         packed_fn_host = _pack_outputs(fn_f32, echo_batch=True)
-        # Kept unjitted for the device-cache path (ensure_cache): the
-        # cached step gathers f32 rows already resident in HBM, so it
-        # always wraps the raw-f32 graph regardless of WIRE_DTYPE — and
-        # WITHOUT the batch echo (the cached step composes its x on
-        # device; there is no host staging buffer to donate).
-        self._packed_fn_f32 = _pack_outputs(fn_f32)
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -441,7 +421,6 @@ class TPUScoringEngine:
         # traffic. `wire_mode=index` (WIRE_MODE env) additionally routes
         # the columnar score_batch_wire path through the cached step.
         self.cache = None
-        self._cached_fn = None
         self._cache_supported = True
         self._cache_metrics_sink = None
         self._cache_lock = threading.Lock()
@@ -467,7 +446,6 @@ class TPUScoringEngine:
         from igaming_platform_tpu.serve import session_state as session_mod
 
         self.session = None
-        self._session_fn = None
         self._session_metrics_sink = None
         self._session_enabled = (
             session_mod.session_enabled_env() if session_state is None
@@ -563,8 +541,6 @@ class TPUScoringEngine:
         if drift_engine is None:
             self.drift = None
             return
-        from igaming_platform_tpu.obs import drift as drift_mod
-
         sk = jax.jit(drift_mod.sketch_kernel)
         # Warm with the dtypes the launch paths actually ship: the wire
         # dtype on the device path (f32 default, bf16 opt-in — int8 is
@@ -583,19 +559,14 @@ class TPUScoringEngine:
         if self.cache is not None:
             self._ensure_drift_cached_fn()
         if self._fused_enabled:
-            # Fold the sketch into the scoring program itself: one
-            # dispatch carries score + sketch (+ the shadow branch once
-            # a candidate warms). bind_drift runs at boot / engine
-            # rebuild, so this compile is off the request path; the
-            # split kernels above stay compiled as the FUSED=0 /
-            # warmup-window fallback.
+            # Fold the sketch into the scoring program itself (boot /
+            # engine rebuild: off the request path); the split kernels
+            # above stay compiled as the FUSED=0 / warm-window fallback.
             self._warm_fused("packed", True, False)
             if self._fn_host is not None:
                 self._warm_fused("host", True, False)
             if self.cache is not None:
-                self._warm_fused(
-                    "session" if self.session is not None else "cached",
-                    True, False)
+                self._warm_fused(self._index_family(), True, False)
             shadow = self.shadow
             if shadow is not None:
                 # Drift bound after a candidate was already in shadow:
@@ -610,18 +581,13 @@ class TPUScoringEngine:
             return self._drift_cached_fn
         with self._drift_lock:
             if self._drift_cached_fn is None:
-                from igaming_platform_tpu.obs import drift as drift_mod
-
                 fn = jax.jit(drift_mod.cached_sketch_kernel)
                 # AOT-warm every ladder shape against the live table.
                 for shape in self._shapes:
-                    idxs = np.zeros((shape,), dtype=np.int32)
-                    amounts = np.zeros((shape,), dtype=np.float32)
-                    types = np.full((shape,), 4, dtype=np.int32)
-                    packed = np.zeros((5, shape), dtype=np.int32)
+                    c = index_program.warm_columns(shape, self.cache.capacity)
                     jax.device_get(fn(
-                        self.cache.table, idxs, amounts, types, packed,
-                        np.int32(0)))
+                        self.cache.table, c["idxs"], c["amounts"], c["types"],
+                        np.zeros((5, shape), dtype=np.int32), np.int32(0)))
                 self._drift_cached_fn = fn
         return self._drift_cached_fn
 
@@ -656,10 +622,9 @@ class TPUScoringEngine:
 
     def _note_drift_cached(self, idxsp, amtp, typp, packed, n: int,
                            sketch=None) -> None:
-        """Index-mode twin of ``_note_drift``: the fused cached/session
-        program computes the sketch in-graph; the split fallback
-        re-gathers the device-resident feature table rows (host never
-        materializes them) with one extra, honestly-counted launch."""
+        """Index-mode twin of ``_note_drift``: a fused cached/session
+        variant computes the sketch in-graph; the split fallback re-gathers
+        the HBM-resident rows with one extra, honestly-counted launch."""
         drift = self.drift
         if drift is None or n <= 0:
             return
@@ -676,24 +641,15 @@ class TPUScoringEngine:
         except Exception:  # noqa: CC04 — drift observability must never fail scoring; the engine counts its errors
             drift.note_error()
 
-    # -- fused mega-step (one graph, one dispatch) ----------------------------
-    #
-    # Per Hummingbird, classical-model serving wins by compiling the whole
-    # prediction pipeline into one tensor program. These variants fold the
-    # drift sketch and the shadow-candidate re-score into the scoring
-    # dispatch itself: the XLA scheduler shares the feature gather and
-    # elementwise prologue between production and candidate, the sketch
-    # consumes the batch in-graph (no echo round-trip), and the sketch /
-    # shadow outputs ride the dispatch's own output handles into the same
-    # bounded queues — the drift worker and ShadowScorer._worker become
-    # pure host-side consumers.
+    # -- fused program variants (one graph, one dispatch; see __init__) -------
 
     def _build_fused(self, family: str, sketch: bool, shadow: bool):
-        """Construct + jit one fused-program variant. Outputs are a
-        variable-length tuple: (packed, echo[, ring,cursor,length]
+        """Construct + jit one program variant. The index families
+        (``cached`` / ``session``) come from serve/index_program.build;
+        the row families (``packed`` / ``host``) are built here. Outputs
+        are a variable-length tuple: (packed[, echo | ring,cursor,length]
         [, sketch][, shadow_packed]) — the launch site knows the layout
         from the (sketch, shadow) key it selected."""
-        from igaming_platform_tpu.obs import drift as drift_mod
         from igaming_platform_tpu.ops.quantize import wire_dequantize_int8
 
         core = self._score_fn_f32
@@ -703,15 +659,11 @@ class TPUScoringEngine:
 
             def fused(params, cand, x, bl, thr, n):
                 xr = wire_dequantize_int8(x) if int8_wire else x
-                out = core(params, xr, bl, thr)
-                packed = _stack_packed(out)
-                res = [packed, x]
-                if sketch:
-                    res.append(drift_mod.sketch_kernel(
-                        jnp.asarray(xr, jnp.float32), packed, n))
-                if shadow:
-                    res.append(_stack_packed(core(cand, xr, bl, thr)))
-                return tuple(res)
+                packed = index_program.stack_packed(core(params, xr, bl, thr))
+                return index_program.epilogue(
+                    [packed, x], xr, packed, n, sketch,
+                    (lambda: index_program.stack_packed(
+                        core(cand, xr, bl, thr))) if shadow else None)
 
             donate = (2,) if family == "packed" else ()
             if self._mesh is not None:
@@ -730,114 +682,10 @@ class TPUScoringEngine:
                     donate_argnums=donate)
             return jax.jit(fused, donate_argnums=donate)
 
-        if family == "cached":
-            txa, td, tw, tb = (
-                int(F.TX_AMOUNT), int(F.TX_TYPE_DEPOSIT),
-                int(F.TX_TYPE_WITHDRAW), int(F.TX_TYPE_BET),
-            )
-            plan = self._state_plan
-            if plan is not None:
-                # Slot-sharded fused step: the sharded gather feeds the
-                # same score + in-graph sketch + shadow composition —
-                # one shard_map body, one jit dispatch.
-                from jax.sharding import PartitionSpec as P
-
-                from jax import shard_map
-                from igaming_platform_tpu.parallel import state_sharding as ss
-
-                def fused_cached_sharded(params, cand, table_l, flags_l,
-                                         idxs, amounts, types, bl, thr, n):
-                    x = ss.gather_slots(table_l, idxs)
-                    f32 = x.dtype
-                    x = x.at[:, txa].set(amounts)
-                    x = x.at[:, td].set((types == 0).astype(f32))
-                    x = x.at[:, tw].set((types == 1).astype(f32))
-                    x = x.at[:, tb].set((types == 2).astype(f32))
-                    blv = jnp.logical_or(bl, ss.gather_slots(flags_l, idxs))
-                    out = core(params, x, blv, thr)
-                    packed = _stack_packed(out)
-                    res = [packed]
-                    if sketch:
-                        res.append(drift_mod.sketch_kernel(x, packed, n))
-                    if shadow:
-                        res.append(_stack_packed(core(cand, x, blv, thr)))
-                    return tuple(res)
-
-                outs = [P()] + ([P()] if sketch else []) \
-                    + ([P()] if shadow else [])
-                return jax.jit(shard_map(
-                    fused_cached_sharded,
-                    mesh=self._mesh,
-                    in_specs=(P(), P(), plan.spec(2), plan.spec(1), P(),
-                              P(), P(), P(), P(), P()),
-                    out_specs=tuple(outs),
-                    check_vma=False,
-                ))
-
-            def fused_cached(params, cand, table, flags, idxs, amounts,
-                             types, bl, thr, n):
-                x = table[idxs]
-                f32 = x.dtype
-                x = x.at[:, txa].set(amounts)
-                x = x.at[:, td].set((types == 0).astype(f32))
-                x = x.at[:, tw].set((types == 1).astype(f32))
-                x = x.at[:, tb].set((types == 2).astype(f32))
-                blv = jnp.logical_or(bl, flags[idxs])
-                out = core(params, x, blv, thr)
-                packed = _stack_packed(out)
-                res = [packed]
-                if sketch:
-                    res.append(drift_mod.sketch_kernel(x, packed, n))
-                if shadow:
-                    res.append(_stack_packed(core(cand, x, blv, thr)))
-                return tuple(res)
-
-            if self._mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                vec = NamedSharding(self._mesh, P(AXIS_DATA))
-                repl = NamedSharding(self._mesh, P())
-                pk = NamedSharding(self._mesh, P(None, AXIS_DATA))
-                outs = [pk] + ([repl] if sketch else []) \
-                    + ([pk] if shadow else [])
-                return jax.jit(
-                    fused_cached,
-                    in_shardings=(None, None, repl, repl, vec, vec, vec,
-                                  vec, repl, repl),
-                    out_shardings=tuple(outs))
-            return jax.jit(fused_cached)
-
-        if family == "session":
-            from igaming_platform_tpu.serve import session_state as session_mod
-
-            mgr = self.session
-            step = session_mod.make_session_step(
-                core, self.config, mgr.head_fn,
-                capacity=self.cache.capacity, n_events=mgr.n_events,
-                min_events=mgr.min_events,
-                flag_threshold=mgr.flag_threshold,
-                sketch=sketch, shadow=shadow, plan=self._state_plan)
-            if self._state_plan is not None:
-                return jax.jit(step, donate_argnums=(4, 5, 6))
-            if self._mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                repl = NamedSharding(self._mesh, P())
-                vec = NamedSharding(self._mesh, P(AXIS_DATA))
-                row = NamedSharding(self._mesh, P(AXIS_DATA, None))
-                pk = NamedSharding(self._mesh, P(None, AXIS_DATA))
-                outs = [pk, repl, repl, repl] + ([repl] if sketch else []) \
-                    + ([pk] if shadow else [])
-                return jax.jit(
-                    step,
-                    in_shardings=(None, None, repl, repl, repl, repl, repl,
-                                  vec, vec, vec, vec, vec, row, vec, repl,
-                                  None, repl),
-                    out_shardings=tuple(outs),
-                    donate_argnums=(4, 5, 6))
-            return jax.jit(step, donate_argnums=(4, 5, 6))
-
-        raise ValueError(f"unknown fused family {family!r}")
+        return index_program.build(
+            core, self.config, family=family, sketch=sketch, shadow=shadow,
+            mesh=self._mesh, plan=self._state_plan,
+            session=self.session if family == "session" else None)
 
     def _ensure_fused(self, family: str, sketch: bool, shadow: bool):
         """Build (once) the jitted fused variant. The memo key is
@@ -857,12 +705,13 @@ class TPUScoringEngine:
         return ffn
 
     def _warm_fused(self, family: str, sketch: bool, shadow: bool,
-                    cand=None):
+                    cand=None, cache=None):
         """AOT-compile every ladder shape of one fused variant (always
         OFF the request path: bind_drift at boot, ensure_cache's build
         window, or the shadow-candidate warm thread), then mark it
         launchable. A launch only ever selects a key in
-        ``_fused_ready``, so serving never blocks on these compiles."""
+        ``_fused_ready``, so serving never blocks on these compiles.
+        ``cache``: the table ensure_cache has not yet published."""
         ffn = self._ensure_fused(family, sketch, shadow)
         with self._params_lock:
             params = self._params
@@ -878,50 +727,36 @@ class TPUScoringEngine:
                 bl = np.zeros((shape,), dtype=bool)
                 jax.block_until_ready(
                     ffn(p, cand, x, bl, thr, np.int32(0)))
-        elif family == "cached":
-            cache = self.cache
-            for shape in self._shapes:
-                idxs = np.zeros((shape,), dtype=np.int32)
-                amounts = np.zeros((shape,), dtype=np.float32)
-                types = np.full((shape,), 4, dtype=np.int32)
-                bl = np.zeros((shape,), dtype=bool)
-                jax.block_until_ready(ffn(
-                    params, cand, cache.table, cache.flags, idxs, amounts,
-                    types, bl, self._thresholds, np.int32(0)))
-        elif family == "session":
-            from igaming_platform_tpu.serve import session_state as session_mod
-
+        else:
+            cache = cache if cache is not None else self.cache
             mgr = self.session
-            cache = self.cache
-            with mgr.lock:
-                for shape in self._shapes:
-                    idxs = np.zeros((shape,), dtype=np.int32)
-                    sidx = np.full((shape,), cache.capacity, dtype=np.int32)
-                    occ = np.arange(shape, dtype=np.int32)
-                    amounts = np.zeros((shape,), dtype=np.float32)
-                    types = np.full((shape,), 4, dtype=np.int32)
-                    events = np.zeros((shape, session_mod.EVENT_WIDTH),
-                                      dtype=np.float32)
-                    bl = np.zeros((shape,), dtype=bool)
+            for shape in self._shapes:
+                c = index_program.warm_columns(shape, cache.capacity)
+                if family == "cached":
                     res = ffn(
-                        params, mgr.head_params, cache.table, cache.flags,
-                        mgr.session_ring, mgr.session_cursor,
-                        mgr.session_length, idxs, sidx, occ, amounts,
-                        types, events, bl, self._thresholds, cand,
+                        params, cand, cache.table, cache.flags, c["idxs"],
+                        c["amounts"], c["types"], c["bl"], self._thresholds,
                         np.int32(0))
-                    jax.block_until_ready(res[0])
-                    mgr.adopt(res[1], res[2], res[3])
+                else:
+                    with mgr.lock:
+                        res = ffn(
+                            params, mgr.head_params, cache.table, cache.flags,
+                            mgr.session_ring, mgr.session_cursor,
+                            mgr.session_length, c["idxs"], c["sidx"],
+                            c["occ"], c["amounts"], c["types"], c["events"],
+                            c["bl"], self._thresholds, cand, np.int32(0))
+                        mgr.adopt(res[1], res[2], res[3])
+                jax.device_get(res[0])
         self._fused_ready.add((family, sketch, shadow))  # noqa: CC10 — publish-once GIL-atomic set: each key added by exactly one warm thread, after every shape compiled
         return ffn
 
     def _select_fused(self, family: str):
         """Pick the best READY fused variant for a launch: (fn,
         sketch_in_graph, (generation, candidate_params) | None), or None
-        for the split path. Preference order: sketch+shadow when a
-        candidate is active and its variant warmed; sketch-only (built
-        at bind_drift); else split. Reads of ``_fused_ready`` are
-        lock-free (GIL-atomic membership; a key is added only after all
-        its ladder shapes compiled)."""
+        for the split path. Preference: sketch+shadow when a candidate
+        is active and its variant warmed; sketch-only; else split.
+        ``_fused_ready`` is read lock-free (GIL-atomic membership; a key
+        is added only after all its ladder shapes compiled)."""
         if not self._fused_enabled:
             return None
         sketch = self.drift is not None
@@ -939,23 +774,24 @@ class TPUScoringEngine:
         """ShadowScorer hook (constructor / set_candidate / supervisor
         rebind): AOT-build and warm the shadow-branch fused variants on
         a daemon thread so installing a candidate NEVER stalls serving.
-        Until the warm completes, dispatches ride the sketch-only
-        program and the candidate scores on the echo-fed split path —
-        same numbers, one extra launch."""
+        Until then dispatches ride the sketch-only program and the
+        candidate scores on the echo-fed split path: one extra launch."""
         if not (self._fused_enabled and self._shadow_fused_enabled):
             return
         if shadow.active_state() is None:
             return
-        t = self._shadow_warm_thread
-        if t is not None and t.is_alive():
-            return
-        t = threading.Thread(target=self._warm_shadow_fused, args=(shadow,),
+        # Chained behind a warm that still runs, never dropped: bind_drift
+        # after a candidate wants the sketch+shadow variants as well.
+        t = threading.Thread(target=self._warm_shadow_fused,
+                             args=(shadow, self._shadow_warm_thread),
                              name="fused-shadow-warm", daemon=True)
         self._shadow_warm_thread = t
         t.start()
 
-    def _warm_shadow_fused(self, shadow) -> None:
+    def _warm_shadow_fused(self, shadow, after=None) -> None:
         try:
+            if after is not None:
+                after.join()
             state = shadow.active_state()
             if state is None:
                 return
@@ -963,8 +799,7 @@ class TPUScoringEngine:
             sketch = self.drift is not None
             fams = ["packed"]
             if self.cache is not None:
-                fams.append("session" if self.session is not None
-                            else "cached")
+                fams.append(self._index_family())
             for fam in fams:
                 if (fam, sketch, True) not in self._fused_ready:
                     self._warm_fused(fam, sketch, True, cand=cand)
@@ -1222,9 +1057,10 @@ class TPUScoringEngine:
             self.session.note_bypass(n)
 
     def ensure_cache(self):
-        """Build (once) the HBM feature table + the jitted cached score
-        step, and AOT-warm every ladder shape — called lazily on the
-        first index-mode request or eagerly from warmup()."""
+        """Build (once) the HBM feature table (and the session plane),
+        and AOT-warm the plain index programs at every ladder shape —
+        called lazily on the first index-mode request or eagerly from
+        warmup()."""
         if self.cache is not None:
             return self.cache
         if not self._cache_supported:
@@ -1249,171 +1085,49 @@ class TPUScoringEngine:
             if hasattr(self.features, "delta_listener"):
                 self.features.delta_listener = cache.note_update
 
-            packed = self._packed_fn_f32
-            txa, td, tw, tb = (
-                int(F.TX_AMOUNT), int(F.TX_TYPE_DEPOSIT),
-                int(F.TX_TYPE_WITHDRAW), int(F.TX_TYPE_BET),
-            )
+            # The plain programs (serve/index_program.py), AOT-warmed at
+            # every ladder shape; the cache is published only after they
+            # ran. The session plane (serve/session_state.py: event ring +
+            # host index) is built beside the table and hooked into its
+            # admissions: one CLOCK decision governs both. The builder
+            # reads head and sizes from the manager, so it is bound first.
+            self._warm_fused("cached", False, False, cache=cache)
+            if self._session_enabled:
+                from igaming_platform_tpu.serve import session_state as session_mod
 
-            def cached_step(params, table, flags, idxs, amounts, types, bl, thr):
-                x = table[idxs]
-                f32 = x.dtype
-                x = x.at[:, txa].set(amounts)
-                x = x.at[:, td].set((types == 0).astype(f32))
-                x = x.at[:, tw].set((types == 1).astype(f32))
-                x = x.at[:, tb].set((types == 2).astype(f32))
-                return packed(params, x, jnp.logical_or(bl, flags[idxs]), thr)
-
-            plan = self._state_plan
-            if plan is not None:
-                # Slot-sharded table: the gather becomes an exact
-                # owner-select collective inside a shard_map body —
-                # still one jit dispatch, identical outputs, per-chip
-                # table bytes ~1/K.
-                from jax.sharding import PartitionSpec as P
-
-                from jax import shard_map
-                from igaming_platform_tpu.parallel import state_sharding as ss
-
-                def cached_step_sharded(params, table_l, flags_l, idxs,
-                                        amounts, types, bl, thr):
-                    x = ss.gather_slots(table_l, idxs)
-                    f32 = x.dtype
-                    x = x.at[:, txa].set(amounts)
-                    x = x.at[:, td].set((types == 0).astype(f32))
-                    x = x.at[:, tw].set((types == 1).astype(f32))
-                    x = x.at[:, tb].set((types == 2).astype(f32))
-                    blv = jnp.logical_or(bl, ss.gather_slots(flags_l, idxs))
-                    return packed(params, x, blv, thr)
-
-                self._cached_fn = jax.jit(shard_map(
-                    cached_step_sharded,
-                    mesh=self._mesh,
-                    in_specs=(P(), plan.spec(2), plan.spec(1), P(), P(),
-                              P(), P(), P()),
-                    out_specs=P(),
-                    check_vma=False,
-                ))
-            elif self._mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                repl = NamedSharding(self._mesh, P())
-                vec = NamedSharding(self._mesh, P(AXIS_DATA))
-                self._cached_fn = jax.jit(
-                    cached_step,
-                    in_shardings=(None, repl, repl, vec, vec, vec, vec, repl),
-                    out_shardings=NamedSharding(self._mesh, P(None, AXIS_DATA)),
-                )
-            else:
-                self._cached_fn = jax.jit(cached_step)
-            # AOT-warm every ladder shape before the first live index RPC.
-            for shape in self._shapes:
-                idxs = np.zeros((shape,), dtype=np.int32)
-                amounts = np.zeros((shape,), dtype=np.float32)
-                types = np.full((shape,), 4, dtype=np.int32)
-                bl = np.zeros((shape,), dtype=bool)
-                with self._params_lock:
-                    params = self._params
-                out = self._cached_fn(
-                    params, cache.table, cache.flags, idxs, amounts, types,
-                    bl, self._thresholds)
-                jax.device_get(out)
+                self.session = session_mod.SessionStateManager(
+                    cache.capacity, mesh=self._mesh,
+                    metrics=self._session_metrics_sink)
+                self._warm_fused("session", False, False, cache=cache)
+                cache.session_hook = self.session.on_admit
             self.cache = cache
-            self._ensure_session(cache)
         if self.drift is not None:
             # A drift engine bound before the cache existed: compile +
             # warm the index-mode sketch now, off the live request path.
             self._ensure_drift_cached_fn()
             if self._fused_enabled:
-                self._warm_fused(
-                    "session" if self.session is not None else "cached",
-                    True, False)
+                self._warm_fused(self._index_family(), True, False)
         if self.shadow is not None and self._fused_enabled:
             # A candidate already in shadow gets its cached/session
             # fused variant warmed off-path too.
             self._on_shadow_candidate(self.shadow)
         return cache
 
-    def _ensure_session(self, cache) -> None:
-        """Build (once) the session plane beside a freshly built cache:
-        the HBM event ring + host index (serve/session_state.py), the
-        FUSED session scoring step (feature gather + ensemble + session
-        head + donated in-place append — still ONE dispatch per chunk),
-        AOT-warmed at every ladder shape, and the cache admission hook
-        that keeps both tables under one CLOCK decision. Caller holds
-        ``_cache_lock``."""
-        if not self._session_enabled or self.session is not None:
-            return
-        from igaming_platform_tpu.serve import session_state as session_mod
-
-        mgr = session_mod.SessionStateManager(
-            cache.capacity, mesh=self._mesh,
-            metrics=self._session_metrics_sink)
-        step = session_mod.make_session_step(
-            self._score_fn_f32, self.config, mgr.head_fn,
-            capacity=cache.capacity, n_events=mgr.n_events,
-            min_events=mgr.min_events, flag_threshold=mgr.flag_threshold,
-            plan=self._state_plan)
-        if self._state_plan is not None:
-            # shard_map specs already constrain the layout; the ring
-            # state donates shard-for-shard (outputs alias inputs).
-            self._session_fn = jax.jit(step, donate_argnums=(4, 5, 6))
-        elif self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            repl = NamedSharding(self._mesh, P())
-            vec = NamedSharding(self._mesh, P(AXIS_DATA))
-            row = NamedSharding(self._mesh, P(AXIS_DATA, None))
-            self._session_fn = jax.jit(
-                step,
-                in_shardings=(None, None, repl, repl, repl, repl, repl,
-                              vec, vec, vec, vec, vec, row, vec, repl),
-                out_shardings=(NamedSharding(self._mesh, P(None, AXIS_DATA)),
-                               repl, repl, repl),
-                donate_argnums=(4, 5, 6),
-            )
-        else:
-            self._session_fn = jax.jit(step, donate_argnums=(4, 5, 6))
-        # AOT-warm every ladder shape. Warm rows target the scratch slot
-        # (sidx=capacity), so no real account's window moves; the step
-        # leaves the scratch counters zeroed.
-        with mgr.lock:
-            for shape in self._shapes:
-                idxs = np.zeros((shape,), dtype=np.int32)
-                sidx = np.full((shape,), cache.capacity, dtype=np.int32)
-                occ = np.arange(shape, dtype=np.int32)
-                amounts = np.zeros((shape,), dtype=np.float32)
-                types = np.full((shape,), 4, dtype=np.int32)
-                events = np.zeros((shape, session_mod.EVENT_WIDTH),
-                                  dtype=np.float32)
-                bl = np.zeros((shape,), dtype=bool)
-                with self._params_lock:
-                    params = self._params
-                out, ring2, cur2, len2 = self._session_fn(
-                    params, mgr.head_params, cache.table, cache.flags,
-                    mgr.session_ring, mgr.session_cursor,
-                    mgr.session_length, idxs, sidx, occ, amounts, types,
-                    events, bl, self._thresholds)
-                jax.device_get(out)
-                mgr.adopt(ring2, cur2, len2)
-        cache.session_hook = mgr.on_admit
-        self.session = mgr
+    def _index_family(self) -> str:
+        return "session" if self.session is not None else "cached"
 
     def _launch_cached(self, idxs: np.ndarray, amounts: np.ndarray,
                        types: np.ndarray, bl: np.ndarray,
                        snap: tuple | None = None,
                        account_ids=None, now: float | None = None):
-        """Dispatch the cached score step: the device gathers rows from
-        the HBM-resident table; only int32 indices + per-txn context
-        cross the link. Pad rows index slot 0 — scored and discarded,
-        same as zero-row padding on the full-row path.
-
-        With session state enabled (and ``account_ids`` provided) the
-        FUSED session step runs instead: same dispatch count, plus the
-        ring-window gather + session head + donated in-place append.
-        Returns (packed out, n, session_meta) where ``session_meta``
-        carries the per-row post-append lengths, sequence numbers and
-        session hashes for the ledger (None on the plain path)."""
+        """Dispatch one chunk's index program (serve/index_program.py):
+        the device gathers rows from the HBM-resident table; only int32
+        indices + per-txn context cross the link. With session state on
+        (and ``account_ids`` given) it is the ``session`` family: same
+        dispatch count, plus the window gather, session head and donated
+        in-place append. Returns (packed out, n, session_meta):
+        per-row post-append lengths, sequence numbers and session hashes
+        for the ledger (None on the ``cached`` family)."""
         n = idxs.shape[0]
         shape = self._pick_shape(n)
         with span("score.pad", batch=n):
@@ -1425,17 +1139,26 @@ class TPUScoringEngine:
             snap = self.params_snapshot()
         params = snap[0]
         mgr = self.session
-        if mgr is not None and account_ids is not None:
-            fsel = self._select_fused("session")
+        family = ("session" if mgr is not None and account_ids is not None
+                  else "cached")
+        # FUSED / the ready set decide only WHICH (sketch, shadow) variant
+        # of the family's one program is launched; without a fused
+        # variant the plain one runs and sketch / shadow ride their own
+        # dispatches after (_note_drift_cached / _note_shadow).
+        ffn, has_sketch, sstate = self._select_fused(family) or (
+            self._ensure_fused(family, False, False), False, None)
+        cand = sstate[1] if sstate is not None else None
+        label = (("fused_" if has_sketch or sstate is not None else "")
+                 + family + "_step")
+        smeta = None
+        if family == "session":
             # Host-index commit + device dispatch under the session lock:
             # device append order must match host (and therefore ledger /
             # replay) order, and the donated ring buffers are rebound
             # before anyone else can dispatch against them.
             with mgr.lock:
                 ts = now if now is not None else ledger_mod.wall_clock()
-                # Session bookkeeping seam: the ~µs/row host cost that
-                # drove the SESSION_r13 0.67 A/B rides its own span so
-                # the hostprof µs/row table can name it.
+                # Session bookkeeping rides its own span (hostprof us/row).
                 with span("score.session", batch=n):
                     events, occ, post_len, seqs, audit = mgr.prepare_chunk(
                         account_ids, amounts, types, ts)
@@ -1451,67 +1174,32 @@ class TPUScoringEngine:
                     # Pad rows all target the scratch slot: distinct
                     # occurrence ranks keep their appends off each other.
                     occp[n:] = np.arange(shape - n, dtype=np.int32)
-                sk = sh = sstate = None
-                if fsel is not None:
-                    ffn, has_sketch, sstate = fsel
-                    cand = sstate[1] if sstate is not None else None
-                    _device_dispatch("fused_session_step", idxsp.shape,
-                                     idxsp.dtype)
-                    res = ffn(
-                        params, mgr.head_params, self.cache.table,
-                        self.cache.flags, mgr.session_ring,
-                        mgr.session_cursor, mgr.session_length, idxsp,
-                        sidxp, occp, amtp, typp, evp, blp,
-                        self._thresholds, cand, np.int32(n))
-                    out, ring2, cur2, len2 = res[0], res[1], res[2], res[3]
-                    sk = res[4] if has_sketch else None
-                    sh = (res[4 + int(has_sketch)]
-                          if sstate is not None else None)
-                else:
-                    _device_dispatch("session_step", idxsp.shape,
-                                     idxsp.dtype)
-                    out, ring2, cur2, len2 = self._session_fn(
-                        params, mgr.head_params, self.cache.table,
-                        self.cache.flags, mgr.session_ring,
-                        mgr.session_cursor, mgr.session_length, idxsp,
-                        sidxp, occp, amtp, typp, evp, blp,
-                        self._thresholds)
+                _device_dispatch(label, idxsp.shape, idxsp.dtype)
+                out, ring2, cur2, len2, *extra = ffn(
+                    params, mgr.head_params, self.cache.table,
+                    self.cache.flags, mgr.session_ring, mgr.session_cursor,
+                    mgr.session_length, idxsp, sidxp, occp, amtp, typp, evp,
+                    blp, self._thresholds, cand, np.int32(n))
                 mgr.adopt(ring2, cur2, len2)
-            self._note_drift_cached(idxsp, amtp, typp, out, n, sketch=sk)
-            self._note_shadow(out, None, blp, n, self._thresholds,
-                              shadow_out=sh,
-                              gen=sstate[0] if sstate is not None else None)
-            if hasattr(out, "copy_to_host_async"):
-                out.copy_to_host_async()
-            return out, n, {"ts": ts, "lens": post_len, "seqs": seqs,
-                            "hashes": audit}
-        fsel = self._select_fused("cached")
-        sk = sh = sstate = None
-        if fsel is not None:
-            ffn, has_sketch, sstate = fsel
-            cand = sstate[1] if sstate is not None else None
-            _device_dispatch("fused_cached_step", idxsp.shape, idxsp.dtype)
-            res = ffn(params, cand, self.cache.table, self.cache.flags,
-                      idxsp, amtp, typp, blp, self._thresholds, np.int32(n))
-            out = res[0]
-            sk = res[1] if has_sketch else None
-            sh = res[1 + int(has_sketch)] if sstate is not None else None
+            smeta = {"ts": ts, "lens": post_len, "seqs": seqs,
+                     "hashes": audit}
         else:
-            _device_dispatch("cached_step", idxsp.shape, idxsp.dtype)
-            out = self._cached_fn(
-                params, self.cache.table, self.cache.flags,
-                idxsp, amtp, typp, blp, self._thresholds)
-        # Index-mode drift sketch: computed in-graph on the fused path;
-        # the split fallback re-gathers the scored rows from the HBM
-        # table and reduces on device — the rows never exist on the
-        # host, and neither does any new sync (obs/drift.py).
+            _device_dispatch(label, idxsp.shape, idxsp.dtype)
+            out, *extra = ffn(
+                params, cand, self.cache.table, self.cache.flags, idxsp,
+                amtp, typp, blp, self._thresholds, np.int32(n))
+        # After the family's outputs: [sketch][, shadow_packed]. Without
+        # an in-graph sketch the split kernel re-gathers the scored rows
+        # on device: they never exist on the host (obs/drift.py).
+        sk = extra[0] if has_sketch else None
+        sh = extra[-1] if sstate is not None else None
         self._note_drift_cached(idxsp, amtp, typp, out, n, sketch=sk)
         self._note_shadow(out, None, blp, n, self._thresholds,
                           shadow_out=sh,
                           gen=sstate[0] if sstate is not None else None)
         if hasattr(out, "copy_to_host_async"):
             out.copy_to_host_async()
-        return out, n, None
+        return out, n, smeta
 
     def _blacklist_flags(self, n: int, ips, devices, fingerprints) -> np.ndarray:
         """Per-request blacklist vector from the host sets — the cheap

@@ -24,12 +24,11 @@ synced (rehydrated) from the host-side session index in the same
 between-steps scatter window as the feature delta fold — an evicted or
 restarted slot rehydrates without any new wire surface.
 
-The scoring step itself is FUSED (serve/scorer.py builds it via
-:func:`make_session_step`): the same dispatch that gathers feature rows
-gathers each account's ring window, runs the session head over the
-POST-APPEND window (history + the event being scored), folds the result
-into the ensemble, and appends the event in place through donated ring
-buffers — zero extra device dispatches per RPC, zero added host syncs.
+The scoring step itself is FUSED (serve/index_program.py owns and builds
+it; this module owns the ring it reads and writes, the host index and
+the admission sync): the dispatch that gathers feature rows also runs the
+session head over each account's POST-APPEND window and appends the event
+in place through donated ring buffers — no extra dispatch, no host sync.
 
 Auditability ("Rethinking LLMOps for Fraud and AML", PAPERS.md): every
 stateful decision carries a ``session_state_hash`` — blake2b over the
@@ -58,8 +57,8 @@ from typing import Any
 
 import numpy as np
 
-from igaming_platform_tpu.core.enums import SESSION_COLD_BIT, SESSION_PATTERN_BIT
-from igaming_platform_tpu.models.sequence import EVENT_DIM, SeqConfig
+from igaming_platform_tpu.models.sequence import EVENT_DIM
+from igaming_platform_tpu.models.session_heads import session_head
 
 # Per-event layout: models/sequence.encode_event — [log-amount, log-dt,
 # 8-way tx-type one-hot, game-weight, balance-ratio].
@@ -70,10 +69,6 @@ EVENT_WIDTH = EVENT_DIM
 # first four match models/sequence.TX_TYPE_INDEX; "other" lands on the
 # adjustment column (index 7), same as encode_event's fallback.
 _TX_EVENT_COL = np.array([0, 1, 2, 3, 7], dtype=np.int64)
-
-# One-hot sub-columns of the event vector the pattern head reads.
-_COL_DEPOSIT = 2 + 0
-_COL_BET = 2 + 2
 
 
 def default_events() -> int:
@@ -122,102 +117,15 @@ def window_hash(window: np.ndarray) -> bytes:
 
 
 # ---------------------------------------------------------------------------
-# Session heads (jittable: [B, N, D] window + [B] lengths -> [B] prob)
-
-
-def pattern_scores(window, lengths):
-    """Deterministic coordinated-cycling detector (the ``pattern`` head,
-    the session analog of models.mock_model: hand-tuned, paramless,
-    replay-exact by construction).
-
-    High iff the window shows bet/deposit CYCLING at a regular cadence
-    with consistent amounts — the coordinated-ring shape
-    (train/fraudgen.FraudRing) — each factor in [0, 1]:
-
-    - ``bd_frac``   fraction of events that are bets or deposits;
-    - ``alt_frac``  fraction of adjacent pairs alternating bet<->deposit;
-    - ``reg``       exp(-4 * var(log-dt)) over events 1.. — machine-paced
-                    cycles have near-constant gaps, humans don't;
-    - ``acons``     exp(-2 * var(log-amount)) — ring members push
-                    near-identical amounts.
-    """
-    import jax.numpy as jnp
-
-    n = window.shape[1]
-    k = jnp.arange(n)[None, :]
-    m = (k < lengths[:, None]).astype(jnp.float32)  # [B, N] valid-event mask
-    cnt = jnp.maximum(jnp.sum(m, axis=1), 1.0)
-
-    log_amt = window[..., 0]
-    log_dt = window[..., 1]
-    is_dep = window[..., _COL_DEPOSIT]
-    is_bet = window[..., _COL_BET]
-
-    bd_frac = jnp.sum((is_bet + is_dep) * m, axis=1) / cnt
-
-    pair_m = m[:, 1:] * m[:, :-1]
-    pairs = jnp.maximum(jnp.sum(pair_m, axis=1), 1.0)
-    alt = (is_bet[:, 1:] * is_dep[:, :-1] + is_dep[:, 1:] * is_bet[:, :-1])
-    alt_frac = jnp.sum(alt * pair_m, axis=1) / pairs
-
-    # dt regularity: skip event 0 (its gap points outside the window).
-    dt_m = m[:, 1:]
-    dt_cnt = jnp.maximum(jnp.sum(dt_m, axis=1), 1.0)
-    dt_mu = jnp.sum(log_dt[:, 1:] * dt_m, axis=1) / dt_cnt
-    dt_var = jnp.sum(((log_dt[:, 1:] - dt_mu[:, None]) ** 2) * dt_m, axis=1) / dt_cnt
-    reg = jnp.exp(-4.0 * dt_var)
-
-    a_mu = jnp.sum(log_amt * m, axis=1) / cnt
-    a_var = jnp.sum(((log_amt - a_mu[:, None]) ** 2) * m, axis=1) / cnt
-    acons = jnp.exp(-2.0 * a_var)
-
-    return jnp.clip(bd_frac * alt_frac * reg * acons, 0.0, 1.0)
-
-
-# Small transformer config for the per-window head (SESSION_HEAD=
-# transformer): the stock sequence model (models/sequence.py) over the
-# N-event window. Params come from the pinned seeded convention below so
-# replay rebuilds the identical tree without a checkpoint.
-SESSION_SEQ_CONFIG = SeqConfig(d_model=32, n_heads=4, n_layers=1, d_ff=64,
-                               in_dim=EVENT_DIM, max_len=256)
-_SESSION_HEAD_SEED = 11
-
-
-def init_session_head_params(seed: int = _SESSION_HEAD_SEED):
-    """The pinned seeded init for the transformer session head (the same
-    convention tools/replay.py uses for serving params)."""
-    import jax
-
-    from igaming_platform_tpu.models.sequence import init_sequence_model
-
-    return init_sequence_model(jax.random.key(seed), SESSION_SEQ_CONFIG)
-
-
-def transformer_scores(sparams, window, lengths):
-    """The ``transformer`` head: the existing sequence model
-    (models/sequence.sequence_forward, dense attention) over the padded
-    window. Padding rows are zeroed by the window builder; positions
-    beyond ``lengths`` still contribute bias/positional terms — that is
-    deterministic and pinned, which is what replay needs."""
-    from igaming_platform_tpu.models.sequence import sequence_forward
-
-    del lengths  # deterministic padded forward; mask lives in the zeros
-    return sequence_forward(sparams, window, SESSION_SEQ_CONFIG)["abuse"]
-
-
-# ---------------------------------------------------------------------------
-# The fused step: feature gather + score + session head + in-place append
+# What the fused step (serve/index_program.py) reads and writes the ring with
 
 
 def windows_from_state(ring_rows, cur, ln, events, n_events: int):
     """Post-append window construction from PRE-GATHERED per-row ring
     state (``ring_rows`` [B, N, D], ``cur``/``ln`` [B]): the last
     ``min(length, N-1)`` stored events in chronological order, then the
-    new event, zero-padded to [B, N, D]. Shared so the slot-sharded
-    fused step (parallel/state_sharding.py gathers the rows with an
-    exact owner-select collective) reuses the identical window math —
-    one implementation, bitwise-shared by the replicated and sharded
-    programs."""
+    new event, zero-padded to [B, N, D]. However the rows were gathered
+    (one device or an owner-select collective), the window math is this."""
     import jax.numpy as jnp
 
     lp = jnp.minimum(ln + 1, n_events)  # post-append window length
@@ -243,7 +151,7 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
 # two re-layout copies of the whole ring (docs/performance.md "Session
 # ring layout" has the table of compiles). Everything that reads or
 # writes the ring goes through the three functions below; an index past
-# the end reads zeros and writes nothing (the sharded bodies point
+# the end reads zeros and writes nothing (a slot-sharded body points
 # non-owned rows there).
 
 
@@ -366,236 +274,6 @@ class SessionChunkAudit:
                 buf[count - hist:count], dtype=np.float32).tobytes())
         h.update(self.events[i].tobytes())
         return h.digest()
-
-
-def make_session_step(score_fn, cfg, head_fn, *, capacity: int,
-                      n_events: int, min_events: int,
-                      flag_threshold: float,
-                      sketch: bool = False, shadow: bool = False,
-                      plan=None):
-    """Build the jittable fused session scoring step.
-
-    Signature (scorer jits it with the ring state donated)::
-
-        step(params, sparams, table, flags, ring, cursor, length,
-             idxs, sidx, occ, amounts, types, events, bl, thr)
-          -> (packed [5, B] int32, ring', cursor', length')
-
-    ``sketch``/``shadow`` select the PR 14 fused-variant layout: the
-    signature gains trailing ``(..., cand, n)`` arguments and the
-    outputs extend to ``(packed, ring', cursor', length'[, sketch]
-    [, shadow_packed])`` — the drift sketch reduces the composed rows
-    in-graph (obs/drift.sketch_kernel over the same gather) and the
-    shadow branch re-scores the identical composition with the
-    CANDIDATE param tree, INCLUDING the session fold (same ``sprob``,
-    same warm/cold semantics): promotion evidence is about exactly the
-    stateful program that would serve. With both flags False the
-    original signature and outputs are returned unchanged.
-
-    The ring is FLAT at rest (``ring_rows`` / ``ring_append`` /
-    ``ring_put`` own the layout): the gather of a batch's windows and
-    the donated append compile to in-place loops over the batch, so a
-    step moves O(batch) bytes whatever the capacity, and ``cursor`` /
-    ``length`` advance on the touched slots only (``advance_counters``).
-    With the ring shaped [slots, N, D] the same step re-laid the whole
-    ring out twice per call (28 ms at 5.2M slots; PERF.md, PR 26).
-
-    ``idxs`` indexes the feature table (pad rows -> slot 0, scored and
-    discarded, as on the plain cached path); ``sidx`` indexes the ring
-    (pad rows -> the scratch slot ``capacity``, so padding never touches
-    a real account's window); ``occ`` is the host-computed
-    within-batch occurrence rank (occurrence_rank_host) so duplicate
-    accounts append at distinct cursor offsets. Scoring semantics: the ensemble runs
-    unchanged; for rows whose post-append window is WARM
-    (>= ``min_events`` events) and whose session-head probability
-    reaches ``flag_threshold``, the ML component is raised to that
-    probability (``SESSION_PATTERN`` reason bit set) and the
-    score/action recombine through the same ensemble rule — below the
-    threshold a warm row's outputs are bit-identical to the session-off
-    path. COLD rows never fold (honest stateless fallback): they carry
-    the ``SESSION_COLD`` reason bit instead.
-
-    ``plan`` (parallel/state_sharding.SlotShardingPlan) selects the
-    SLOT-SHARDED twin: the feature table and the ring state arrive as
-    per-shard row blocks inside a ``shard_map`` body — gathers become
-    exact owner-select collectives, the donated append lands only on
-    the owning shard (``mode='drop'``; padding rows at
-    ``sidx == capacity`` are owned by nobody, replacing the scratch
-    row), and the window/fold math is the SAME code
-    (:func:`windows_from_state` / ``_session_fold``), so sharded
-    outputs are bit-identical to the replicated program. The returned
-    callable is the shard_map-wrapped program with the same external
-    signature — still ONE jit dispatch once the scorer jits it.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from igaming_platform_tpu.core.features import F
-    from igaming_platform_tpu.models.ensemble import ML_HIGH_RISK_BIT, combine
-
-    txa, td, tw, tb = (
-        int(F.TX_AMOUNT), int(F.TX_TYPE_DEPOSIT),
-        int(F.TX_TYPE_WITHDRAW), int(F.TX_TYPE_BET),
-    )
-
-    def _session_fold(out, sprob, fold, cold, thr):
-        """Fold one param tree's base outputs through the session head
-        result — shared bit-for-bit by the production and the shadow
-        branch (``sprob``/``fold``/``cold`` are params-independent)."""
-        ml = out["ml_score"].astype(jnp.float32)
-        ml2 = jnp.where(fold, jnp.maximum(ml, sprob), ml)
-        # Recombine exactly as the base graph did (combine() is pure in
-        # (rule, ml, mask)): strip the ML bit the base pass derived from
-        # the un-folded ml, then let combine re-derive it from ml2 — a
-        # non-folded row reproduces the base outputs bit-for-bit.
-        mask_base = out["reason_mask"] & ~(1 << ML_HIGH_RISK_BIT)
-        final, action, mask = combine(out["rule_score"], ml2, mask_base,
-                                      cfg, thr)
-        mask = mask | jnp.where(fold, 1 << SESSION_PATTERN_BIT, 0)
-        mask = mask | jnp.where(cold, 1 << SESSION_COLD_BIT, 0)
-        return jnp.stack([
-            final.astype(jnp.int32),
-            action.astype(jnp.int32),
-            mask.astype(jnp.int32),
-            out["rule_score"].astype(jnp.int32),
-            jax.lax.bitcast_convert_type(ml2, jnp.int32),
-        ])
-
-    def _body(params, sparams, table, flags, ring, cursor, length,
-              idxs, sidx, occ, amounts, types, events, bl, thr, cand, n):
-        # -- feature gather + context columns (the cached step, inlined) --
-        x = table[idxs]
-        f32 = x.dtype
-        x = x.at[:, txa].set(amounts)
-        x = x.at[:, td].set((types == 0).astype(f32))
-        x = x.at[:, tw].set((types == 1).astype(f32))
-        x = x.at[:, tb].set((types == 2).astype(f32))
-        blv = jnp.logical_or(bl, flags[idxs])
-        out = score_fn(params, x, blv, thr)
-
-        # -- session head over the post-append window ---------------------
-        #    Duplicate accounts within one batch see the BATCH-START state
-        #    (batch-snapshot semantics — the host index and replay apply
-        #    the same rule); their appends land at distinct cursor offsets.
-        cur = cursor[sidx]
-        ln = length[sidx]
-        win, lp = windows_from_state(
-            ring_rows(ring, sidx, n_events), cur, ln, events, n_events)
-        sprob = head_fn(sparams, win, lp).astype(jnp.float32)
-        real = sidx < capacity
-        warm = jnp.logical_and(lp >= min_events, real)
-        fold = jnp.logical_and(warm, sprob >= flag_threshold)
-        cold = jnp.logical_and(jnp.logical_not(warm), real)
-        packed = _session_fold(out, sprob, fold, cold, thr)
-
-        # -- in-place append (donated buffers: ring'/cursor'/length' alias
-        #    their inputs; the scratch slot soaks up padding rows) --------
-        ring2 = ring_append(ring, sidx, jnp.mod(cur + occ, n_events), events,
-                            n_events)
-        cursor2, length2 = advance_counters(cursor, length, sidx, ln, occ,
-                                            real, n_events)
-        res = [packed, ring2, cursor2, length2]
-        if sketch:
-            from igaming_platform_tpu.obs.drift import sketch_kernel
-
-            res.append(sketch_kernel(x, packed, n))
-        if shadow:
-            out_c = score_fn(cand, x, blv, thr)
-            res.append(_session_fold(out_c, sprob, fold, cold, thr))
-        return tuple(res)
-
-    def _sharded_body(params, sparams, table_l, flags_l, ring_l, cur_l,
-                      len_l, idxs, sidx, occ, amounts, types, events, bl,
-                      thr, cand, n):
-        from igaming_platform_tpu.parallel import state_sharding as ss
-
-        # -- sharded feature gather (exact owner-select) ------------------
-        x = ss.gather_slots(table_l, idxs)
-        f32 = x.dtype
-        x = x.at[:, txa].set(amounts)
-        x = x.at[:, td].set((types == 0).astype(f32))
-        x = x.at[:, tw].set((types == 1).astype(f32))
-        x = x.at[:, tb].set((types == 2).astype(f32))
-        blv = jnp.logical_or(bl, ss.gather_slots(flags_l, idxs))
-        out = score_fn(params, x, blv, thr)
-
-        # -- sharded window gather + the SAME fold math -------------------
-        rows = ss.gather_ring_slots(ring_l, sidx, cur_l.shape[0], n_events)
-        cur = ss.gather_slots(cur_l, sidx)
-        ln = ss.gather_slots(len_l, sidx)
-        win, lp = windows_from_state(rows, cur, ln, events, n_events)
-        sprob = head_fn(sparams, win, lp).astype(jnp.float32)
-        real = sidx < capacity
-        warm = jnp.logical_and(lp >= min_events, real)
-        fold = jnp.logical_and(warm, sprob >= flag_threshold)
-        cold = jnp.logical_and(jnp.logical_not(warm), real)
-        packed = _session_fold(out, sprob, fold, cold, thr)
-
-        # -- owned-only donated append (padding drops: no scratch row) ----
-        li, owned = ss.local_slot_index(cur_l.shape[0], sidx)
-        ring2 = ring_append(ring_l, li, jnp.mod(cur + occ, n_events), events,
-                            n_events)
-        cursor2, length2 = advance_counters(cur_l, len_l, li, ln, occ, owned,
-                                            n_events)
-        res = [packed, ring2, cursor2, length2]
-        if sketch:
-            from igaming_platform_tpu.obs.drift import sketch_kernel
-
-            res.append(sketch_kernel(x, packed, n))
-        if shadow:
-            out_c = score_fn(cand, x, blv, thr)
-            res.append(_session_fold(out_c, sprob, fold, cold, thr))
-        return tuple(res)
-
-    if plan is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from jax import shard_map
-
-        outs = ([P(), plan.spec(1), plan.spec(1), plan.spec(1)]
-                + ([P()] if sketch else []) + ([P()] if shadow else []))
-        sharded = shard_map(
-            _sharded_body,
-            mesh=plan.mesh,
-            in_specs=(P(), P(), plan.spec(2), plan.spec(1), plan.spec(1),
-                      plan.spec(1), plan.spec(1), P(), P(), P(), P(), P(),
-                      P(), P(), P(), P(), P()),
-            out_specs=tuple(outs),
-            check_vma=False,
-        )
-        if sketch or shadow:
-            return sharded
-
-        def sharded_step(params, sparams, table, flags, ring, cursor,
-                         length, idxs, sidx, occ, amounts, types, events,
-                         bl, thr):
-            return sharded(params, sparams, table, flags, ring, cursor,
-                           length, idxs, sidx, occ, amounts, types, events,
-                           bl, thr, None, 0)[:4]
-
-        return sharded_step
-
-    if sketch or shadow:
-        return _body
-
-    def step(params, sparams, table, flags, ring, cursor, length,
-             idxs, sidx, occ, amounts, types, events, bl, thr):
-        return _body(params, sparams, table, flags, ring, cursor, length,
-                     idxs, sidx, occ, amounts, types, events, bl, thr,
-                     None, 0)[:4]
-
-    return step
-
-
-def session_head(head: str):
-    """``SESSION_HEAD`` name -> (head_fn(sparams, window, lengths), params)."""
-    if head == "pattern":
-        return (lambda sparams, win, lp: pattern_scores(win, lp)), None
-    if head == "transformer":
-        return transformer_scores, init_session_head_params()
-    raise ValueError(
-        f"SESSION_HEAD={head!r} not supported "
-        "(use 'pattern' or 'transformer')")
 
 
 def make_ring_sync(mesh=None, plan=None):

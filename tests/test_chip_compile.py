@@ -99,25 +99,26 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl):
 
 def _compile_step(head, capacity, ring_rows, state, repl, *, mesh=None,
                   plan=None):
-    """The fused session step as serve/scorer._build_fused jits it (the
-    drift-sketch variant the server runs: ``jit(_body)``, ring donated)."""
-    import jax
-
+    """The fused session step the server runs, from the builder the
+    server builds it with (serve/index_program.build; the drift-sketch
+    variant: ``jit(_body)``, ring donated)."""
     from igaming_platform_tpu.core.config import ScoringConfig
     from igaming_platform_tpu.models.ensemble import make_score_fn
+    from igaming_platform_tpu.models.session_heads import session_head
+    from igaming_platform_tpu.serve import index_program
     from igaming_platform_tpu.serve import session_state as ss
 
     cfg = ScoringConfig()
-    head_fn, head_params = ss.session_head(head)
-    step = ss.make_session_step(
-        make_score_fn(cfg, "multitask", mesh=mesh), cfg, head_fn,
-        capacity=capacity, n_events=ss.default_events(),
-        min_events=ss.default_min_events(),
-        flag_threshold=ss.default_flag_threshold(),
-        sketch=True, shadow=False, plan=plan)
+    head_fn, head_params = session_head(head)
+    step = index_program.build(
+        make_score_fn(cfg, "multitask", mesh=mesh), cfg, family="session",
+        sketch=True, shadow=False, mesh=mesh, plan=plan,
+        session=index_program.SessionSpec(
+            head_fn, capacity, ss.default_events(), ss.default_min_events(),
+            ss.default_flag_threshold()))
     args = _step_args(head_params, capacity, ring_rows,
                       1 if plan is None else plan.n_shards, state, repl)
-    return jax.jit(step, donate_argnums=(4, 5, 6)).lower(*args).compile()
+    return step.lower(*args).compile()
 
 
 def _ring_sized_copies(compiled, ring_elems: int) -> list[str]:

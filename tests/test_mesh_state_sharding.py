@@ -49,11 +49,11 @@ def _seed(store, n=24):
 
 
 def make_engine(k=None, *, capacity=64, session=True, batch_size=16,
-                tiers=(8,), ledger_dir=None, **kw):
+                tiers=(8,), ledger_dir=None, ml_backend="mock", **kw):
     store = InMemoryFeatureStore()
     _seed(store)
     eng = TPUScoringEngine(
-        ScoringConfig(), ml_backend="mock", feature_store=store,
+        ScoringConfig(), ml_backend=ml_backend, feature_store=store,
         batcher_config=BatcherConfig(batch_size=batch_size,
                                      latency_tiers=tiers, max_wait_ms=1.0),
         mesh=None if k is None else _mesh(k),
@@ -172,6 +172,60 @@ def test_fused_sketch_variant_parity_on_sharded_mesh():
 
     assert sketched(d1) == sketched(d0) == 48
     close_engine(eng)
+
+
+@pytest.mark.parametrize("sketch", [False, True])
+def test_fused_shadow_variant_parity_on_sharded_mesh(sketch):
+    """The shadow branch of the one session body under slot sharding
+    (K=2; with a drift engine bound, the sketch+shadow variant): the
+    production AND the candidate outputs of the sharded program are
+    bit-equal to the replicated program's over warm and cold windows."""
+    import time
+
+    from igaming_platform_tpu.models.mlp import init_mlp
+    from igaming_platform_tpu.obs import drift as dm
+    from igaming_platform_tpu.serve.shadow import ShadowScorer
+
+    def params(seed):
+        import jax
+
+        return {"mlp": init_mlp(jax.random.key(seed), hidden=(16, 16))}
+
+    ids, amounts, txs = _traffic(24)
+
+    def run(k):
+        eng = make_engine(k, ml_backend="mlp", params=params(0))
+        drift = dm.DriftEngine() if sketch else None
+        if drift is not None:
+            eng.bind_drift(drift)
+        cands = []
+        sh = ShadowScorer(eng, params(1),
+                          on_result=lambda c, p, n: cands.append(c))
+        eng.shadow = sh
+        try:
+            deadline = time.monotonic() + 240.0
+            while ("session", sketch, True) not in eng._fused_ready:
+                assert time.monotonic() < deadline
+                time.sleep(0.02)
+            prods = [eng.score_columns_cached(ids, amounts, txs, now=NOW0 + r)
+                     for r in range(3)]
+            assert sh.drain(300.0)
+            rep = sh.report()
+            assert rep["errors"] == 0 and rep["fused_batches"] > 0
+            return prods, cands
+        finally:
+            sh.close()
+            close_engine(eng)
+            if drift is not None:
+                drift.close()
+
+    ref_prods, ref_cands = run(None)
+    got_prods, got_cands = run(2)
+    assert len(got_cands) == len(ref_cands) > 0
+    for r, (got, ref) in enumerate(zip(got_prods, ref_prods)):
+        _assert_bits(got, ref, f"production round={r}")
+    for i, (got, ref) in enumerate(zip(got_cands, ref_cands)):
+        _assert_bits(got, ref, f"candidate batch={i}")
 
 
 # ---------------------------------------------------------------------------

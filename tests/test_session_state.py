@@ -37,6 +37,8 @@ from igaming_platform_tpu.core.enums import (
     SESSION_PATTERN_BIT,
     decode_reason_mask,
 )
+from igaming_platform_tpu.models import session_heads
+from igaming_platform_tpu.serve import index_program
 from igaming_platform_tpu.serve import ledger as ledger_mod
 from igaming_platform_tpu.serve import session_state as session_mod
 from igaming_platform_tpu.serve.feature_store import TransactionEvent
@@ -359,11 +361,9 @@ def test_ring_state_matches_numpy_model(k, seed):
     mgr = session_mod.SessionStateManager(cap, mesh=mesh)
     n, d = mgr.n_events, session_mod.EVENT_WIDTH
     cfg = ScoringConfig()
-    step = jax.jit(session_mod.make_session_step(
-        make_score_fn(cfg, "mock"), cfg, mgr.head_fn, capacity=cap,
-        n_events=n, min_events=mgr.min_events,
-        flag_threshold=mgr.flag_threshold, plan=mgr.plan),
-        donate_argnums=(4, 5, 6))
+    step = index_program.build(
+        make_score_fn(cfg, "mock"), cfg, family="session", sketch=False,
+        shadow=False, mesh=mesh, plan=mgr.plan, session=mgr)
     table = np.zeros((cap, NUM_FEATURES), np.float32)
     flags = np.zeros((cap,), bool)
     if mgr.plan is not None:
@@ -395,7 +395,7 @@ def test_ring_state_matches_numpy_model(k, seed):
                    np.where(sidx < cap, sidx, 0).astype(np.int32), sidx, occ,
                    np.zeros((shape,), np.float32),
                    np.full((shape,), 4, np.int32), events,
-                   np.zeros((shape,), bool), thr)
+                   np.zeros((shape,), bool), thr, None, np.int32(b))
         mgr.adopt(*res[1:4])
         cur0 = cur.copy()
         for i in range(b):
@@ -461,17 +461,17 @@ def test_sequence_head_bit_exact_vs_host_reference(n_rows):
         if lp > 1:
             windows[i, :lp - 1] = hist_all[hist_all.shape[0] - (lp - 1):]
         windows[i, lp - 1] = events[i]
-    head = jax.jit(lambda w, l: session_mod.pattern_scores(w, l))
+    head = jax.jit(lambda w, l: session_heads.pattern_scores(w, l))
     sprob = np.asarray(jax.device_get(head(windows, lps)), np.float32)
 
     # Base (aggregate-only) outputs through the PLAIN cached step.
     idxs = eng.cache.lookup(accts, now=now)
     bl = np.zeros((n_rows,), bool)
-    base = eng._cached_fn(
-        eng.get_params(), eng.cache.table, eng.cache.flags,
+    base, = eng._ensure_fused("cached", False, False)(
+        eng.get_params(), None, eng.cache.table, eng.cache.flags,
         jnp.asarray(idxs), jnp.asarray(np.asarray(amounts, np.float32)),
         jnp.asarray(np.asarray(codes, np.int32)), jnp.asarray(bl),
-        eng._thresholds)
+        eng._thresholds, np.int32(n_rows))
     base = np.asarray(jax.device_get(base))
     base_ml = base[4].view(np.float32)
     warm = lps >= mgr.min_events
@@ -514,7 +514,7 @@ def test_transformer_head_available_and_deterministic():
     assert np.array_equal(a, b)
     assert np.all((a >= 0.0) & (a <= 1.0))
     # The pinned seeded convention rebuilds the identical tree.
-    p2 = session_mod.init_session_head_params()
+    p2 = session_heads.init_session_head_params()
     assert (ledger_mod.params_fingerprint(mgr.head_params)
             == ledger_mod.params_fingerprint(p2))
 
