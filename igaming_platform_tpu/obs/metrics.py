@@ -762,6 +762,19 @@ class ServiceMetrics:
             "over session_appends_total it is the share of events that "
             "pay a copy",
         )
+        self.session_lock_wait_seconds_total = self.registry.counter(
+            f"{service}_session_lock_wait_seconds_total",
+            "Seconds index-mode chunks waited for the session lock "
+            "before their host-index commit and device dispatch - over "
+            "session_appends_total it is what the serial section costs a "
+            "row of the other clients",
+        )
+        self.session_lock_held_seconds_total = self.registry.counter(
+            f"{service}_session_lock_held_seconds_total",
+            "Seconds index-mode chunks held the session lock (host-index "
+            "commit, pad, dispatch of the fused step, adoption of the "
+            "donated ring): the serial section itself",
+        )
         self.session_rehydrations_total = self.registry.counter(
             f"{service}_session_rehydrations_total",
             "Session windows restored into HBM from the host session "

@@ -30,6 +30,7 @@ import numpy as np
 from igaming_platform_tpu.core.config import BatcherConfig, ScoringConfig
 from igaming_platform_tpu.serve import chaos, index_program
 from igaming_platform_tpu.serve import ledger as ledger_mod
+from igaming_platform_tpu.serve import session_state as session_mod
 from igaming_platform_tpu.core.enums import ReasonCode, action_from_code, decode_reason_mask
 from igaming_platform_tpu.core.features import NUM_FEATURES, FeatureVector
 from igaming_platform_tpu.models.ensemble import make_score_fn
@@ -443,8 +444,6 @@ class TPUScoringEngine:
         # cached step (one dispatch, ring appended via donated buffers).
         # Built with the cache (ensure_cache) — the tables share one
         # host index and one CLOCK admission decision.
-        from igaming_platform_tpu.serve import session_state as session_mod
-
         self.session = None
         self._session_metrics_sink = None
         self._session_enabled = (
@@ -1093,8 +1092,6 @@ class TPUScoringEngine:
             # reads head and sizes from the manager, so it is bound first.
             self._warm_fused("cached", False, False, cache=cache)
             if self._session_enabled:
-                from igaming_platform_tpu.serve import session_state as session_mod
-
                 self.session = session_mod.SessionStateManager(
                     cache.capacity, mesh=self._mesh,
                     metrics=self._session_metrics_sink)
@@ -1130,17 +1127,34 @@ class TPUScoringEngine:
         for the ledger (None on the ``cached`` family)."""
         n = idxs.shape[0]
         shape = self._pick_shape(n)
+        mgr = self.session
+        family = ("session" if mgr is not None and account_ids is not None
+                  else "cached")
+        if family == "session":
+            # What the chunk's ids decide alone is settled before the
+            # session lock is asked for: the grouping by account, and
+            # from it the occurrence ranks (session_state.group_chunk).
+            with span("score.session", batch=n):
+                groups = session_mod.group_chunk(account_ids)
         with span("score.pad", batch=n):
             idxsp, _ = pad_batch(idxs, shape)
             amtp, _ = pad_batch(amounts, shape)
             typp, _ = pad_batch(types, shape)
             blp, _ = pad_batch(bl, shape)
+            if family == "session":
+                occp, _ = pad_batch(groups.occ, shape)
+                # Fresh per-chunk buffer by design: jax may alias host
+                # memory zero-copy on the CPU backend, so a pooled
+                # buffer could be read by an in-flight dispatch.
+                sidxp = np.full((shape,), mgr.capacity, dtype=np.int32)  # noqa: MX04 — scratch-slot pad template must be fresh per dispatch (zero-copy aliasing)
+                sidxp[:n] = idxs
+                if n < shape:
+                    # Pad rows all target the scratch slot: distinct
+                    # occurrence ranks keep their appends off each other.
+                    occp[n:] = np.arange(shape - n, dtype=np.int32)
         if snap is None:
             snap = self.params_snapshot()
         params = snap[0]
-        mgr = self.session
-        family = ("session" if mgr is not None and account_ids is not None
-                  else "cached")
         # FUSED / the ready set decide only WHICH (sketch, shadow) variant
         # of the family's one program is launched; without a fused
         # variant the plain one runs and sketch / shadow ride their own
@@ -1156,24 +1170,16 @@ class TPUScoringEngine:
             # device append order must match host (and therefore ledger /
             # replay) order, and the donated ring buffers are rebound
             # before anyone else can dispatch against them.
+            asked = time.perf_counter()
             with mgr.lock:
+                got = time.perf_counter()
                 ts = now if now is not None else ledger_mod.wall_clock()
                 # Session bookkeeping rides its own span (hostprof us/row).
                 with span("score.session", batch=n):
-                    events, occ, post_len, seqs, audit = mgr.prepare_chunk(
-                        account_ids, amounts, types, ts)
+                    events, _, post_len, seqs, audit = mgr.prepare_chunk(
+                        groups, amounts, types, ts)
                 with span("score.pad", batch=n):
                     evp, _ = pad_batch(events, shape)
-                    occp, _ = pad_batch(occ, shape)
-                # Fresh per-chunk buffer by design: jax may alias host
-                # memory zero-copy on the CPU backend, so a pooled
-                # buffer could be read by an in-flight dispatch.
-                sidxp = np.full((shape,), mgr.capacity, dtype=np.int32)  # noqa: MX04 — scratch-slot pad template must be fresh per dispatch (zero-copy aliasing)
-                sidxp[:n] = idxs
-                if n < shape:
-                    # Pad rows all target the scratch slot: distinct
-                    # occurrence ranks keep their appends off each other.
-                    occp[n:] = np.arange(shape - n, dtype=np.int32)
                 _device_dispatch(label, idxsp.shape, idxsp.dtype)
                 out, ring2, cur2, len2, *extra = ffn(
                     params, mgr.head_params, self.cache.table,
@@ -1181,6 +1187,7 @@ class TPUScoringEngine:
                     mgr.session_length, idxsp, sidxp, occp, amtp, typp, evp,
                     blp, self._thresholds, cand, np.int32(n))
                 mgr.adopt(ring2, cur2, len2)
+                mgr.note_lock(got - asked, time.perf_counter() - got)
             smeta = {"ts": ts, "lens": post_len, "seqs": seqs,
                      "hashes": audit}
         else:
@@ -1268,13 +1275,15 @@ class TPUScoringEngine:
         for lo in range(0, total, self.batch_size):
             hi = min(lo + self.batch_size, total)
             with span("score.cache_lookup", batch=hi - lo):
-                idxs = self.cache.lookup(account_ids[lo:hi], now=now)
+                # decoded once a chunk: the cache and the session plane
+                # key their host indexes by the same ``str``
+                ids = session_mod.decoded_ids(account_ids[lo:hi])
+                idxs = self.cache.lookup(ids, now=now)
             self.lane_gate.acquire(LANE_BULK)
             with span("score.dispatch", batch=hi - lo), annotate("score_step"):
                 out, n, smeta = self._launch_cached(
                     idxs, amounts32[lo:hi], types32[lo:hi], bl[lo:hi], snap,
-                    account_ids=account_ids[lo:hi] if session_on else None,
-                    now=now)
+                    account_ids=ids if session_on else None, now=now)
             inflight.append((out, lo, n, smeta))
             if len(inflight) > self._pipeline_depth:
                 read_one()

@@ -51,6 +51,7 @@ silently breaking replay for every later decision on that slot.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import os
 import threading
 from typing import Any
@@ -223,6 +224,17 @@ def advance_counters(cursor, length, slots, ln, occ, real, n_events: int):
     return cursor, length
 
 
+def _stable_runs(uidx: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Rows of a non-empty batch sorted by account, chunk order kept
+    inside an account: (order [B], starts [B] bool, run_start [U])."""
+    order = np.argsort(uidx, kind="stable")
+    sorted_u = uidx[order]
+    starts = np.empty(uidx.shape, dtype=bool)
+    starts[0] = True
+    np.not_equal(sorted_u[1:], sorted_u[:-1], out=starts[1:])
+    return order, starts, np.flatnonzero(starts)
+
+
 def occurrence_rank_host(uidx: np.ndarray) -> np.ndarray:
     """occ[i] = how many earlier rows of this batch target the same
     account — duplicate appends land at cursor+occ instead of
@@ -232,16 +244,63 @@ def occurrence_rank_host(uidx: np.ndarray) -> np.ndarray:
     b = uidx.shape[0]
     if b == 0:
         return np.zeros((0,), np.int32)
-    order = np.argsort(uidx, kind="stable")
-    sorted_u = uidx[order]
-    starts = np.empty((b,), dtype=bool)
-    starts[0] = True
-    np.not_equal(sorted_u[1:], sorted_u[:-1], out=starts[1:])
-    run_id = np.cumsum(starts) - 1
-    run_start = np.flatnonzero(starts)
+    order, starts, run_start = _stable_runs(uidx)
     occ = np.empty((b,), np.int32)
-    occ[order] = (np.arange(b) - run_start[run_id]).astype(np.int32)
+    occ[order] = np.arange(b) - run_start[np.cumsum(starts) - 1]
     return occ
+
+
+def decoded_ids(account_ids) -> list[str]:
+    """Account ids as the host index keys them: ``str``. A wire frame
+    brings ``bytes``; decode a chunk once and hand the list on."""
+    return [a if isinstance(a, str) else str(a, "utf-8") for a in account_ids]
+
+
+class ChunkGroups:
+    """What a chunk's account ids decide on their own, computed BEFORE
+    the session lock is taken (:func:`group_chunk`): the unique accounts
+    in order of first appearance, each row's account (``uidx``) and
+    occurrence rank (``occ``), per unique account its number of rows
+    (``counts``) and first row (``first``), and the stable ``order`` of
+    the rows by account with the ``bounds`` of each account's run in it.
+    What is indexed per account in Python (``counts``, ``first``,
+    ``bounds``) is a plain sequence, not an array."""
+
+    __slots__ = ("ids", "uidx", "occ", "counts", "first", "order", "bounds")
+
+    def __init__(self, ids, uidx, occ, counts, first, order, bounds):
+        self.ids = ids
+        self.uidx = uidx
+        self.occ = occ
+        self.counts = counts
+        self.first = first
+        self.order = order
+        self.bounds = bounds
+
+    def rows_of(self, u: int) -> np.ndarray:
+        """Rows of unique account ``u``, in chunk order."""
+        return self.order[self.bounds[u]:self.bounds[u + 1]]
+
+
+def group_chunk(account_ids) -> ChunkGroups:
+    """Group a chunk's rows by account. Reads the ids and nothing else
+    (no host index, no clock), so it runs outside the session lock; what
+    needs the index is ``SessionStateManager.prepare_chunk``."""
+    index: dict[str, int] = {}
+    rows = [index.setdefault(a, len(index)) for a in decoded_ids(account_ids)]
+    b = len(rows)
+    if len(index) == b:  # distinct accounts: every row is its own run
+        uidx = np.arange(b)
+        return ChunkGroups(list(index), uidx, np.zeros((b,), np.int32),
+                           [1] * b, range(b), uidx, range(b + 1))
+    uidx = np.array(rows)
+    order, starts, run_start = _stable_runs(uidx)
+    bounds = np.append(run_start, b)
+    # uidx is dense and numbered by first appearance: run r is account r
+    occ = np.empty((b,), np.int32)
+    occ[order] = np.arange(b) - run_start[uidx[order]]
+    return ChunkGroups(list(index), uidx, occ, np.diff(bounds).tolist(),
+                       order[run_start].tolist(), order, bounds.tolist())
 
 
 class SessionChunkAudit:
@@ -308,6 +367,7 @@ def make_ring_sync(mesh=None, plan=None):
 
 _EMPTY_WINDOW = np.zeros((0, EVENT_WIDTH), np.float32)
 _EMPTY_WINDOW.setflags(write=False)
+_NO_HISTORY = (_EMPTY_WINDOW, 0)  # a never-seen account's snapshot
 
 # The host twin's first buffer and how it grows, in rows (measured on
 # the chip's host, PERF.md section 6, PR 31). Capacity stops growing at
@@ -339,11 +399,12 @@ class _AcctSession:
 
     __slots__ = ("buf", "count", "seq", "last_ts")
 
-    def __init__(self):
-        self.buf = _EMPTY_WINDOW
-        self.count = 0  # rows currently stored in buf
-        self.seq = 0    # total events ever appended
-        self.last_ts = 0.0
+    def __init__(self, buf: np.ndarray = _EMPTY_WINDOW, count: int = 0,
+                 seq: int = 0, last_ts: float = 0.0):
+        self.buf = buf
+        self.count = count  # rows currently stored in buf
+        self.seq = seq      # total events ever appended
+        self.last_ts = last_ts
 
     def window_view(self, n_events: int) -> np.ndarray:
         k = min(self.seq, n_events)
@@ -429,6 +490,8 @@ class SessionStateManager:
         self.bypass_rows = 0
         self.twin_bytes = 0
         self.twin_regrows = 0
+        self.lock_wait_s = 0.0
+        self.lock_held_s = 0.0
 
         from igaming_platform_tpu.parallel import state_sharding
 
@@ -472,6 +535,8 @@ class SessionStateManager:
         with self.lock:
             self._export(self.warm_rows, self.cold_rows, self.bypass_rows,
                          self.appends, self.rehydrations, self.twin_regrows)
+            metrics.session_lock_wait_seconds_total.inc(self.lock_wait_s)
+            metrics.session_lock_held_seconds_total.inc(self.lock_held_s)
 
     def _export(self, warm: int, cold: int, bypass: int, appends: int,
                 rehydrations: int, regrows: int = 0) -> None:
@@ -528,6 +593,8 @@ class SessionStateManager:
                 "appends": self.appends,
                 "twin_bytes": self.twin_bytes,
                 "twin_regrows": self.twin_regrows,
+                "lock_wait_s": self.lock_wait_s,
+                "lock_held_s": self.lock_held_s,
                 "rehydrations": self.rehydrations,
                 "admissions": self.admissions,
                 "rows": {"warm": self.warm_rows, "cold": self.cold_rows,
@@ -561,8 +628,7 @@ class SessionStateManager:
             w = np.zeros((k, self.n_events, EVENT_WIDTH), dtype=np.float32)
             lens = np.zeros((k,), dtype=np.int32)
             rehydrated = 0
-            for i, raw in enumerate(account_ids):
-                a = raw if isinstance(raw, str) else bytes(raw).decode()
+            for i, a in enumerate(decoded_ids(account_ids)):
                 tw = self._twin.get(a)
                 if tw is not None and tw.seq > 0:
                     win = tw.window_view(self.n_events)
@@ -589,12 +655,10 @@ class SessionStateManager:
 
     # -- the append path (fused step prepare/adopt) ---------------------------
 
-    def prepare_chunk(self, account_ids, amounts, tx_codes,
-                      now: float) -> tuple[np.ndarray, np.ndarray,
-                                           np.ndarray, np.ndarray,
-                                           "SessionChunkAudit"]:  # analysis: session-append-seam
-        """Under ``lock``: encode this chunk's events, compute every row's
-        post-append window length, within-batch occurrence rank and
+    def prepare_chunk(self, groups: ChunkGroups, amounts, tx_codes, now: float):  # analysis: session-append-seam
+        """Under ``lock``, with the chunk already grouped by account
+        (:func:`group_chunk`, before the lock): encode this chunk's
+        events, compute every row's post-append window length and
         per-account event sequence number from the HOST index
         (batch-snapshot semantics: duplicate accounts in one chunk all
         see the chunk-start state), then commit the events to the index
@@ -604,65 +668,98 @@ class SessionStateManager:
         returned :class:`SessionChunkAudit` carries the snapshots and
         hashes lazily on the ledger writer thread.
 
+        What the lock pays for is per chunk and per account that needs
+        it, not per row: one index lookup per unique account; first
+        events (a never-seen account, up to ``_TWIN_FIRST_ROWS`` rows of
+        it in the chunk) share ONE allocation and ONE indexed copy, each
+        account keeping its own block of it as its buffer; a seen
+        account with room takes its rows in place; only an account that
+        has to regrow or compact goes through ``append_rows``.
+
         Returns (events [B, EVENT_WIDTH] f32, occ [B] i32,
         post_len [B] i32, seqs [B] i64, audit)."""
-        b = len(account_ids)
+        g = groups
+        ids, uidx, occ, counts, first = g.ids, g.uidx, g.occ, g.counts, g.first
+        b = uidx.shape[0]
+        nu = len(ids)
         n_ev = self.n_events
         twin = self._twin
-        # Unique-account scan: ONE dict lookup per row plus a constant
-        # handful of appends per unique account (snapshot = a stable
-        # (buffer, count) reference into the append-only twin buffer —
-        # no copy, no slicing); everything per-row is vectorized below.
-        uniq: dict[str, int] = {}
-        uidx = np.empty((b,), np.int64)
-        utw: list[_AcctSession] = []
-        snaps: list[tuple[np.ndarray, int]] = []
-        useq: list[int] = []
-        ulast: list[float] = []
-        for i, raw in enumerate(account_ids):
-            a = raw if isinstance(raw, str) else bytes(raw).decode()
-            u = uniq.get(a)
-            if u is None:
-                u = len(uniq)
-                uniq[a] = u
-                tw = twin.get(a)
-                if tw is None:
-                    tw = _AcctSession()
-                    twin[a] = tw
-                utw.append(tw)
-                snaps.append((tw.buf, tw.count))
-                useq.append(tw.seq)
-                ulast.append(tw.last_ts)
-            uidx[i] = u
-        seq0 = np.asarray(useq, np.int64)[uidx]
-        last0 = np.asarray(ulast, np.float64)[uidx]
-        occ = occurrence_rank_host(uidx)
+        # Chunk-start snapshot per unique account, read in columns: a
+        # stable (buffer, count) reference into the append-only twin
+        # buffer — no copy. A never-seen account reads as all zeros.
+        utw = list(map(twin.get, ids))
+        snaps = [_NO_HISTORY] * nu
+        if utw.count(None) == nu:
+            fresh, seen = range(nu), ()
+            seq0 = np.zeros((b,), np.int64)
+            dts = np.zeros((b,), np.float64)
+        else:
+            seen = [u for u, tw in enumerate(utw) if tw is not None]
+            fresh = [u for u, tw in enumerate(utw) if tw is None]
+            stw = [utw[u] for u in seen]
+            bufs = [tw.buf for tw in stw]
+            held = [tw.count for tw in stw]
+            for u, snap in zip(seen, zip(bufs, held)):
+                snaps[u] = snap
+            useq = np.zeros((nu,), np.int64)
+            ulast = np.zeros((nu,), np.float64)
+            useq[seen] = [tw.seq for tw in stw]
+            ulast[seen] = [tw.last_ts for tw in stw]
+            seq0 = useq[uidx]
+            dts = np.where(seq0 > 0, np.maximum(0.0, now - ulast[uidx]), 0.0)
         seqs = seq0 + occ + 1
         post_len = (np.minimum(seq0, n_ev - 1) + 1).astype(np.int32)
-        dts = np.where(seq0 > 0, np.maximum(0.0, now - last0), 0.0)
         events = encode_events_host(amounts, tx_codes, dts)
         audit = SessionChunkAudit(events, post_len, uidx, snaps)
 
-        # Commit per unique account, rows grouped in chunk order (the
-        # device append scatters the same rows at cursor+occ). The
-        # common all-unique chunk skips the argsort/grouping machinery.
-        if len(utw) == b:
-            groups = ((utw[i], events[i:i + 1]) for i in range(b))
-        else:
-            order = np.argsort(uidx, kind="stable")
-            sorted_u = uidx[order]
-            starts = np.flatnonzero(np.concatenate(
-                ([True], sorted_u[1:] != sorted_u[:-1])))
-            bounds = np.append(starts, b)
-            groups = ((utw[int(sorted_u[bounds[r]])],
-                       events[order[bounds[r]:bounds[r + 1]]])
-                      for r in range(len(starts)))
+        # Commit, rows of an account in chunk order (the device append
+        # scatters the same rows at cursor+occ).
         grown = regrows = 0  # rows of capacity added; reallocations
-        for tw, rows in groups:
+        slow: list[tuple[_AcctSession, int]] = []
+        if fresh:
+            # First events in columns: block[j] is the first buffer of
+            # the j-th never-seen account, never the chunk's ``events``
+            # (which goes to the device and the audit).
+            small = [u for u in fresh if counts[u] <= _TWIN_FIRST_ROWS]
+            if small:
+                slot = np.full((nu,), -1)
+                slot[small] = np.arange(len(small))
+                at = slot[uidx]
+                rows = np.flatnonzero(at >= 0)
+                block = np.empty((len(small), _TWIN_FIRST_ROWS, EVENT_WIDTH),
+                                 dtype=np.float32)
+                block[at[rows], occ[rows]] = events[rows]
+                k = [counts[u] for u in small]
+                twin.update(zip([ids[u] for u in small],
+                                map(_AcctSession, block, k, k,
+                                    itertools.repeat(now))))
+                grown += len(small) * _TWIN_FIRST_ROWS
+            if len(small) < len(fresh):
+                for u in fresh:
+                    if counts[u] > _TWIN_FIRST_ROWS:
+                        tw = twin[ids[u]] = _AcctSession()
+                        slow.append((tw, u))
+        if seen:
+            for u, tw, buf, c in zip(seen, stw, bufs, held):
+                k = counts[u]
+                end = c + k
+                if end > len(buf):
+                    slow.append((tw, u))
+                    continue
+                if k == 1:
+                    buf[c] = events[first[u]]
+                else:
+                    buf[c:end] = events[g.rows_of(u)]
+                tw.count = end
+                tw.seq += k
+                tw.last_ts = now
+        for tw, u in slow:
+            i = first[u]
+            rows = (events[i:i + 1] if counts[u] == 1
+                    else events[g.rows_of(u)])
             old = tw.append_rows(rows, n_ev, now)
-            if old >= 0:
-                grown += tw.buf.shape[0] - old
-                regrows += old > 0
+            grown += len(tw.buf) - old
+            regrows += old > 0
         self.twin_bytes += grown * EVENT_WIDTH * 4
         self.twin_regrows += regrows
         warm = int(np.count_nonzero(post_len >= self.min_events))
@@ -672,6 +769,17 @@ class SessionStateManager:
         self.cold_rows += cold
         self._export(warm, cold, 0, b, 0, regrows)
         return events, occ, post_len, seqs, audit
+
+    def note_lock(self, waited_s: float, held_s: float) -> None:
+        """What one chunk's dispatch waited for ``lock`` and then held it
+        (the caller still holds it): the serial section of index-mode
+        scoring, per chunk."""
+        self.lock_wait_s += waited_s
+        self.lock_held_s += held_s
+        m = self._metrics
+        if m is not None:
+            m.session_lock_wait_seconds_total.inc(waited_s)
+            m.session_lock_held_seconds_total.inc(held_s)
 
     def adopt(self, ring, cursor, length) -> None:  # analysis: session-append-seam
         """Rebind the donated-step outputs as the live ring state (the

@@ -9,6 +9,8 @@ class BadManager:
         self.session_ring = ring
         self.session_cursor = cursor
         self.session_length = length
+        self._twin = {}
+        self.lock = None
 
     def adopt(self, ring, cursor, length):  # analysis: session-append-seam
         """The legitimate seam: device state, host index and ledger hash
@@ -16,6 +18,20 @@ class BadManager:
         self.session_ring = ring
         self.session_cursor = cursor
         self.session_length = length
+
+    def prepare_chunk(self, groups, amounts,
+                      now):  # analysis: session-append-seam
+        """The tag may close a signature of several lines; the caller
+        holds the lock, so the host index is this function's to write."""
+        for a in groups:
+            self._twin[a] = (amounts, now)
+
+    def locked_reader(self, a):
+        with self.lock:
+            return self._twin.get(a)
+
+    def unlocked_reader(self, a):
+        return self._twin.get(a)  # expect: CC08
 
     def sneaky_rebind(self, ring):
         self.session_ring = ring  # expect: CC08
@@ -31,6 +47,16 @@ def bad_external_rebind(mgr, ring):
 
 def bad_tuple_rebind(mgr, a, b):
     mgr.session_ring, mgr.session_cursor = a, b  # expect: CC08
+
+
+def bad_grouping_reads_the_index(mgr, ids):
+    # what runs before the lock reads the chunk's ids alone
+    return [mgr._twin.get(a) for a in ids]  # expect: CC08
+
+
+def good_grouping(ids):
+    index = {}
+    return [index.setdefault(a, len(index)) for a in ids]
 
 
 def good_other_attrs(mgr, ring):

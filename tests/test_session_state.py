@@ -216,7 +216,7 @@ def test_twin_growth_keeps_views_hashes_and_windows(count, how):
             amounts = [11] + amounts + [13]
             codes = [0] + codes + [1]
         events, occ, post_len, seqs, audit = mgr.prepare_chunk(
-            ids, amounts, codes, now)
+            session_mod.group_chunk(ids), amounts, codes, now)
         rows = [i for i, a in enumerate(ids) if a == "g"]
         want = model.chunk([amounts[i] for i in rows],
                            [codes[i] for i in rows], now)
@@ -229,7 +229,9 @@ def test_twin_growth_keeps_views_hashes_and_windows(count, how):
         # steady size, or one oversized chunk as before this PR
         assert tw.buf.shape[0] <= max(4 * n, max(sizes[:c + 1]) + n)
         assert tw.buf.shape[0] <= 2 * max(tw.seq, 2)
-        assert tw.buf.base is None  # its own array, never a view of a chunk's
+        # its own rows (of its own array, or of the block a chunk's first
+        # events share), never a view of a chunk's ``events``
+        assert not np.shares_memory(tw.buf, events)
     for audit, rows, at_once, want in held:
         later = [audit[i] for i in range(len(audit))]
         assert later == at_once
@@ -298,7 +300,8 @@ def test_audit_hashes_read_on_another_thread_while_appends_go_on():
             amounts = [200 + 5 * r + i for i in range(len(ids))]
             codes = [(r + i) % 5 for i in range(len(ids))]
             with mgr.lock:
-                audit = mgr.prepare_chunk(ids, amounts, codes, now)[4]
+                audit = mgr.prepare_chunk(
+                    session_mod.group_chunk(ids), amounts, codes, now)[4]
             want = [None] * len(ids)
             for a in accounts:
                 rows = [i for i, x in enumerate(ids) if x == a]
@@ -325,7 +328,8 @@ def test_twin_bytes_follow_the_events_held():
     for event in range(2):
         for lo in range(0, len(accounts), 256):
             ids = accounts[lo:lo + 256]
-            mgr.prepare_chunk(ids, [500 + event] * len(ids), [2] * len(ids),
+            mgr.prepare_chunk(session_mod.group_chunk(ids),
+                              [500 + event] * len(ids), [2] * len(ids),
                               NOW0 + 60.0 * event)
         snap = mgr.snapshot()
         assert snap["accounts_tracked"] == 10_000
@@ -335,10 +339,326 @@ def test_twin_bytes_follow_the_events_held():
         assert snap["twin_regrows"] <= 10_000 * event
     # and an account that fills its window ends at today's steady size
     for r in range(5 * _N):
-        mgr.prepare_chunk(["n0"], [900 + r], [0], NOW0 + 500.0 + r)
+        mgr.prepare_chunk(session_mod.group_chunk(["n0"]), [900 + r], [0],
+                          NOW0 + 500.0 + r)
     steady = 4 * _N * session_mod.EVENT_WIDTH * 4
     assert mgr._twin["n0"].buf.nbytes == steady
     assert mgr.snapshot()["twin_bytes"] == _twin_walk_bytes(mgr)
+
+
+# ---------------------------------------------------------------------------
+# The columnar commit against the per-account commit it replaced (PR 33)
+
+
+def _reference_prepare_chunk(mgr, account_ids, amounts, tx_codes, now):
+    """The per-account commit ``prepare_chunk`` had until PR 33, kept as
+    the plain reference: a scan of the chunk row by row, then one
+    ``append_rows`` per unique account with a buffer of its own."""
+    b = len(account_ids)
+    n_ev = mgr.n_events
+    twin = mgr._twin
+    uniq = {}
+    uidx = np.empty((b,), np.int64)
+    utw, snaps, useq, ulast = [], [], [], []
+    for i, raw in enumerate(account_ids):
+        a = raw if isinstance(raw, str) else bytes(raw).decode()
+        u = uniq.get(a)
+        if u is None:
+            u = len(uniq)
+            uniq[a] = u
+            tw = twin.get(a)
+            if tw is None:
+                tw = session_mod._AcctSession()
+                twin[a] = tw
+            utw.append(tw)
+            snaps.append((tw.buf, tw.count))
+            useq.append(tw.seq)
+            ulast.append(tw.last_ts)
+        uidx[i] = u
+    seq0 = np.asarray(useq, np.int64)[uidx]
+    last0 = np.asarray(ulast, np.float64)[uidx]
+    occ = session_mod.occurrence_rank_host(uidx)
+    seqs = seq0 + occ + 1
+    post_len = (np.minimum(seq0, n_ev - 1) + 1).astype(np.int32)
+    dts = np.where(seq0 > 0, np.maximum(0.0, now - last0), 0.0)
+    events = session_mod.encode_events_host(amounts, tx_codes, dts)
+    audit = session_mod.SessionChunkAudit(events, post_len, uidx, snaps)
+    order = np.argsort(uidx, kind="stable")
+    sorted_u = uidx[order]
+    starts = np.flatnonzero(np.concatenate(
+        ([True], sorted_u[1:] != sorted_u[:-1])))
+    bounds = np.append(starts, b)
+    grown = regrows = 0
+    for r in range(len(starts)):
+        tw = utw[int(sorted_u[bounds[r]])]
+        old = tw.append_rows(events[order[bounds[r]:bounds[r + 1]]], n_ev, now)
+        if old >= 0:
+            grown += tw.buf.shape[0] - old
+            regrows += old > 0
+    mgr.twin_bytes += grown * session_mod.EVENT_WIDTH * 4
+    mgr.twin_regrows += regrows
+    warm = int(np.count_nonzero(post_len >= mgr.min_events))
+    mgr.appends += b
+    mgr.warm_rows += warm
+    mgr.cold_rows += b - warm
+    return events, occ, post_len, seqs, audit
+
+
+def _chunks_first_unique(rng):
+    lo = 0
+    for b in (64, 256, 1, 64):
+        yield [f"u{lo + i}" for i in range(b)]
+        lo += b
+
+
+def _chunks_repeats(rng):
+    # repeats of never-seen accounts (2, 3 and 5 rows of one in its first
+    # chunk) and of seen ones, between distinct accounts
+    for c in range(6):
+        ids = [f"r{c}-{i}" for i in range(20)]
+        ids += ["two", "two", "three", "three", "three"] + ["five"] * 5
+        ids += [f"late{c}"] * (1 + c % 3)
+        yield [ids[j] for j in rng.permutation(len(ids))]
+
+
+def _chunks_seen_room(rng):
+    accounts = [f"s{i}" for i in range(96)]
+    yield accounts                       # first events: 2-row blocks
+    yield accounts[::-1]                 # second events: room
+    for _ in range(3):                   # a seeded mix, some repeated
+        yield [accounts[j] for j in rng.integers(0, 96, 64)]
+
+
+def _chunks_regrow_at(cap):
+    def make(rng):
+        accounts = [f"g{i}" for i in range(5)]
+        # fill every buffer exactly to ``cap`` rows, one event a chunk
+        # (capacities double from 2, so ``cap`` events do it) ...
+        for _ in range(cap):
+            yield accounts
+        # ... then one append that has to regrow (or, at the steady 64
+        # rows, compact): singly, and as repeats inside one chunk
+        yield accounts[:3] + [accounts[3]] * 2 + [accounts[4]] * 3
+        yield accounts
+    return make
+
+
+def _chunks_compaction(rng):
+    accounts = [f"k{i}" for i in range(4)]
+    for c in range(4 * _N * 2 + 9):      # twice through the steady size
+        yield accounts[:1 + c % 4] + (["k0"] if c % 5 == 0 else [])
+
+
+def _chunks_oversized(rng):
+    # more rows of one account in a chunk than a window holds, and more
+    # than the steady buffer: never-seen and seen
+    yield ["big"] * (_N + 4) + ["x0"]
+    yield ["x1"] + ["big"] * (_N + 4)
+    yield ["huge"] * (4 * _N + 6)
+    yield ["x2", "huge", "big"] + ["huge"] * (4 * _N + 6)
+    yield ["big", "huge", "x0", "x1", "x2"]
+
+
+def _chunks_bytes_and_str(rng):
+    names = [f"b{i}" for i in range(40)]
+    yield [n.encode() for n in names]
+    yield names[::-1]
+    yield [n.encode() if j % 2 else n
+           for j, n in enumerate(names + names[:7])]
+    yield [memoryview(n.encode()) for n in names[5:25]]
+
+
+_COMMIT_CASES = {
+    "first_unique": _chunks_first_unique,
+    "repeats": _chunks_repeats,
+    "seen_room": _chunks_seen_room,
+    **{f"regrow_at_{cap}": _chunks_regrow_at(cap)
+       for cap in (2, 4, 8, 16, 32, 64)},
+    "compaction": _chunks_compaction,
+    "oversized": _chunks_oversized,
+    "bytes_and_str": _chunks_bytes_and_str,
+}
+
+
+def _as_key(a):
+    return a if isinstance(a, str) else bytes(a).decode()
+
+
+@pytest.mark.parametrize("seed", [7, 2_271_560_481])
+@pytest.mark.parametrize("case", list(_COMMIT_CASES))
+def test_prepare_chunk_equals_the_per_account_commit(case, seed):
+    """The grouped, columnar ``prepare_chunk`` against the per-account
+    commit it replaced, chunk by chunk over seeded traffic: the returned
+    columns, every audit hash, every account's window and meta, and the
+    manager's counters are equal."""
+    mgr = session_mod.SessionStateManager(4)
+    ref = session_mod.SessionStateManager(4)
+    rng = np.random.default_rng(seed)
+    chunks = list(_COMMIT_CASES[case](np.random.default_rng(seed)))
+    held = []
+    touched = set()
+    for c, ids in enumerate(chunks):
+        b = len(ids)
+        amounts = rng.integers(1, 90_000, b)
+        codes = rng.integers(0, 5, b).astype(np.uint8)
+        now = NOW0 + 13.0 * c + float(rng.random())
+        got = mgr.prepare_chunk(session_mod.group_chunk(ids), amounts, codes,
+                                now)
+        want = _reference_prepare_chunk(ref, ids, amounts, codes, now)
+        for g, w, name in zip(got, want, ("events", "occ", "post_len", "seqs")):
+            assert g.dtype == w.dtype and np.array_equal(g, w), (name, c)
+        hashes = [got[4][i] for i in range(b)]
+        assert hashes == [want[4][i] for i in range(b)], c
+        assert np.array_equal(got[4].uidx, want[4].uidx)
+        held.append((got[4], hashes))
+        touched.update(_as_key(a) for a in ids)
+        for a in {_as_key(a) for a in ids}:
+            assert np.array_equal(mgr.twin_window(a), ref.twin_window(a)), a
+            assert mgr.twin_meta(a) == ref.twin_meta(a), a
+            assert len(mgr._twin[a].buf) == len(ref._twin[a].buf), a
+            assert not np.shares_memory(mgr._twin[a].buf, got[0])
+        for key in ("appends", "twin_bytes", "twin_regrows", "rows",
+                    "accounts_tracked"):
+            assert mgr.snapshot()[key] == ref.snapshot()[key], (key, c)
+    assert mgr.snapshot()["twin_bytes"] == _twin_walk_bytes(mgr)
+    assert set(mgr._twin) == touched
+    # every audit still hashes to what it hashed when its chunk was taken
+    for audit, hashes in held:
+        assert [audit[i] for i in range(len(audit))] == hashes
+
+
+def test_group_chunk_reads_ids_alone():
+    """The grouping is a function of the chunk's ids: first-appearance
+    order, each row's account and occurrence rank, the rows of each
+    account in chunk order - with or without repeats, ``bytes`` or
+    ``str``."""
+    ids = ["a", b"b", "a", "c", memoryview(b"b"), "a", "d"]
+    g = session_mod.group_chunk(ids)
+    assert g.ids == ["a", "b", "c", "d"]
+    assert g.uidx.tolist() == [0, 1, 0, 2, 1, 0, 3]
+    assert g.occ.dtype == np.int32 and g.occ.tolist() == [0, 0, 1, 0, 1, 2, 0]
+    assert np.array_equal(g.occ, session_mod.occurrence_rank_host(g.uidx))
+    assert list(g.counts) == [3, 2, 1, 1] and list(g.first) == [0, 1, 3, 6]
+    assert [g.rows_of(u).tolist() for u in range(4)] == [
+        [0, 2, 5], [1, 4], [3], [6]]
+    u = session_mod.group_chunk([b"x", "y", "z"])
+    assert u.ids == ["x", "y", "z"] and u.uidx.tolist() == [0, 1, 2]
+    assert u.occ.dtype == np.int32 and not u.occ.any()
+    assert list(u.counts) == [1, 1, 1] and list(u.first) == [0, 1, 2]
+    assert [u.rows_of(i).tolist() for i in range(3)] == [[0], [1], [2]]
+    e = session_mod.group_chunk([])
+    assert e.ids == [] and e.uidx.shape == (0,) and e.occ.shape == (0,)
+
+
+@pytest.mark.parametrize("taken_at", [0, 1, 7, 33])
+def test_audit_of_a_shared_first_block_is_stable_under_later_appends(taken_at):
+    """An audit taken at chunk ``taken_at`` - first events that share one
+    block when it is 0 - hashes to the same value, and to the numpy
+    model's, after 80 more chunks have appended to, regrown and compacted
+    the same accounts: rows below ``count`` are never written again,
+    shared block or not."""
+    mgr = session_mod.SessionStateManager(4)
+    accounts = [f"sh{i}" for i in range(12)]
+    models = {a: _TwinModel(mgr.n_events) for a in accounts}
+    taken = None
+    for c in range(taken_at + 81):
+        now = NOW0 + 5.0 * c
+        ids = list(accounts)
+        if c % 3 == 1:
+            ids += accounts[:4]          # repeats: two rows of a block
+        if c % 7 == 2:
+            ids += [accounts[5]] * 3
+        amounts = [300 + 7 * c + i for i in range(len(ids))]
+        codes = [(c + i) % 5 for i in range(len(ids))]
+        audit = mgr.prepare_chunk(session_mod.group_chunk(ids), amounts, codes,
+                                  now)[4]
+        want = [None] * len(ids)
+        for a in accounts:
+            rows = [i for i, x in enumerate(ids) if x == a]
+            for i, h in zip(rows, models[a].chunk(
+                    [amounts[i] for i in rows], [codes[i] for i in rows], now)):
+                want[i] = h
+        if c == taken_at:
+            taken = (audit, [audit[i] for i in range(len(audit))], want)
+            if c == 0:
+                bases = {id(mgr._twin[a].buf.base) for a in accounts}
+                assert len(bases) == 1 and None not in bases  # one block
+    audit, at_once, want = taken
+    assert at_once == want
+    assert [audit[i] for i in range(len(audit))] == want
+    assert mgr.snapshot()["twin_regrows"] > 0
+    for a in accounts:
+        assert mgr._twin[a].seq > 4 * _N  # through the steady size
+        assert np.array_equal(mgr.twin_window(a), models[a].window())
+
+
+def test_two_threads_leave_twin_ring_and_ledger_in_agreement():
+    """Two threads score overlapping accounts through ``_launch_cached``
+    (the grouping of a chunk now runs before the session lock is taken):
+    afterwards the device ring holds every account's host window, and
+    the ledger's chunks, put in the order the lock was held, replay every
+    session hash bit-exact with no gap and no reordering."""
+    sys.path.insert(0, str(__import__("pathlib").Path(__file__).parent.parent))
+    from tools.replay import verify_session_chain
+
+    d = tempfile.mkdtemp(prefix="sess-two-threads-")
+    eng = make_engine(batch_size=16, capacity=64, tiers=(8, 16), ledger_dir=d)
+    mgr = eng.session
+    accounts = [f"tt{i}" for i in range(24)]
+    lock_order = []
+    inner = mgr.prepare_chunk
+
+    def recording(groups, amounts, codes, now):
+        lock_order.append(now)  # called with the session lock held
+        return inner(groups, amounts, codes, now)
+
+    mgr.prepare_chunk = recording
+    errors = []
+
+    def client(t):
+        rng = np.random.default_rng(100 + t)
+        try:
+            for r in range(40):
+                n = int(rng.integers(1, 17))
+                ids = [accounts[j] for j in rng.integers(0, 24, n)]
+                eng.score_columns_cached(
+                    ids, [int(x) for x in rng.integers(1, 9000, n)],
+                    [("bet", "deposit", "win")[j % 3] for j in range(n)],
+                    now=NOW0 + 1000.0 * t + 3.0 * r)
+        except Exception as exc:  # noqa: BLE001 — surfaced below
+            errors.append(exc)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(t,)) for t in (0, 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(240.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors[:1]
+    assert not any(t.is_alive() for t in threads)
+    assert len(lock_order) == 80 == len(set(lock_order))
+    snap = mgr.snapshot()
+    assert snap["lock_held_s"] > 0.0 and snap["lock_wait_s"] >= 0.0
+    for a in accounts:
+        assert np.array_equal(ring_rows(eng, a), mgr.twin_window(a)), a
+    total = snap["appends"]
+    close_engine(eng)
+    recs = [r for k, r in ledger_mod.iter_entries(d) if k == "decision"]
+    assert len(recs) == total
+    by_ts = {}
+    for r in recs:
+        by_ts.setdefault(r.ts_unix, []).append(r)
+    assert set(by_ts) == set(lock_order)
+    v = verify_session_chain([r for ts in lock_order for r in by_ts[ts]])
+    assert v["session_records"] == v["session_verified"] == total
+    assert v["session_hash_mismatch"] == v["session_chain_gaps"] == 0
+    assert v["session_reordered"] == v["session_resets"] == 0
+    for a in accounts:
+        assert mgr.twin_meta(a)["seq"] == sum(r.account_id == a for r in recs)
 
 
 # ---------------------------------------------------------------------------

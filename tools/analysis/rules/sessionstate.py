@@ -16,8 +16,17 @@ This rule flags assignments/rebinds of the session state attributes
 (``session_ring``, ``session_cursor``, ``session_length``) anywhere in
 the session-state scope EXCEPT:
 
-- inside a function marked ``# analysis: session-append-seam``;
+- inside a function marked ``# analysis: session-append-seam`` (on any
+  line of its signature);
 - ``self.<attr> = ...`` inside ``__init__`` (construction, not mutation).
+
+The host session index (``_twin``) is the other half of the same state,
+and it is held to more: any touch of it, a read too, has to sit in a
+seam function (its caller holds the lock), under a lexical
+``with <x>.lock:``, or in ``__init__``. What a chunk's ids decide on
+their own - ``group_chunk``, which runs BEFORE the lock is taken -
+therefore carries no seam tag and can read no twin state: the rule
+fires on the first ``._twin`` that finds its way into it.
 
 Same shape as CC07 (param-mutation discipline): the discipline is the
 point, the marker is the audit trail.
@@ -31,6 +40,7 @@ import re
 from tools.analysis.engine import FileContext, ProjectContext, rule
 
 _SESSION_ATTRS = {"session_ring", "session_cursor", "session_length"}
+_TWIN_ATTR = "_twin"
 _SEAM_MARKER = re.compile(r"#\s*analysis:\s*session-append-seam")
 
 
@@ -55,7 +65,10 @@ def _seam_ranges(ctx: FileContext) -> list[tuple[int, int]]:
     for node in ctx.walk():
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
-        marker_lines = {node.lineno} | {d.lineno for d in node.decorator_list}
+        # the marker may close a signature that runs over several lines
+        marker_lines = set(range(
+            node.lineno, max(node.lineno + 1, node.body[0].lineno)))
+        marker_lines |= {d.lineno for d in node.decorator_list}
         if marker_lines & seam_lines:
             ranges.append((node.lineno, node.end_lineno or node.lineno))
     return ranges
@@ -66,6 +79,17 @@ def _init_self_ranges(ctx: FileContext) -> list[tuple[int, int]]:
         (node.lineno, node.end_lineno or node.lineno)
         for node in ctx.walk()
         if isinstance(node, ast.FunctionDef) and node.name == "__init__"
+    ]
+
+
+def _lock_ranges(ctx: FileContext) -> list[tuple[int, int]]:
+    """Bodies of ``with <x>.lock:`` statements (the manager's lock)."""
+    return [
+        (node.lineno, node.end_lineno or node.lineno)
+        for node in ctx.walk()
+        if isinstance(node, ast.With) and any(
+            isinstance(i.context_expr, ast.Attribute)
+            and i.context_expr.attr == "lock" for i in node.items)
     ]
 
 
@@ -92,17 +116,32 @@ def _session_targets(node: ast.AST):
       "lock — a bare rebind desyncs them and every later decision on "
       "the slot becomes a silent replay mismatch. Route the write "
       "through the seam functions (prepare_chunk/adopt/on_admit), or "
-      "mark a genuine new seam with `# analysis: session-append-seam`.",
+      "mark a genuine new seam with `# analysis: session-append-seam`. "
+      "The host session index (`._twin`) is read and written only "
+      "there or under `with <x>.lock:` - the grouping of a chunk that "
+      "runs before the lock is taken (group_chunk) touches neither.",
       scope="project")
 def session_state_mutation_discipline(project: ProjectContext):
     for ctx in _scoped_files(project):
         seam = _seam_ranges(ctx)
         inits = _init_self_ranges(ctx)
+        locked = _lock_ranges(ctx)
 
         def _in(ranges: list[tuple[int, int]], lineno: int) -> bool:
             return any(lo <= lineno <= hi for lo, hi in ranges)
 
         for node in ctx.walk():
+            if isinstance(node, ast.Attribute) and node.attr == _TWIN_ATTR:
+                if not (_in(seam, node.lineno) or _in(locked, node.lineno)
+                        or _in(inits, node.lineno)):
+                    yield ctx, node.lineno, (
+                        "host session index `._twin` touched outside the "
+                        "append seam and outside `with <x>.lock:` - what "
+                        "runs before the session lock (group_chunk) reads "
+                        "the chunk's ids alone; move the access behind a "
+                        "`# analysis: session-append-seam` function or "
+                        "take the lock")
+                continue
             for attr, is_self in _session_targets(node):
                 if _in(seam, attr.lineno):
                     continue
