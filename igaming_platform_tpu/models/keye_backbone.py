@@ -1,0 +1,298 @@
+"""A sparse-expert decoder backbone over the session window (the
+``keye`` session head, models/session_heads.py).
+
+The block is the language model of Keye-VL-2.0-30B-A3B (a Qwen3-MoE
+decoder layer with a learned-sparse-attention indexer and M-RoPE), at
+the published widths by default: hidden 2048, 32 query / 4 key-value
+heads of 128, 128 experts of width 768 with 8 a token, an indexer of 16
+heads of 64 with one key head and ``topk`` 2048. Events enter it the way
+image patches enter that model, as ``inputs_embeds`` from a projector
+(``x @ W_in``, 12 -> hidden); the score is a sequence-classification
+head on the last real position. Each layer, over ``h`` [B, T, hidden]:
+
+1. ``a = RMSNorm(h)``; grouped-query attention with per-head RMSNorm on
+   q and k and M-RoPE (three position-id streams, ``mrope_section``
+   frequency pairs each); causal.
+2. The indexer scores every causal key for every query (``sum_h w[t,h]
+   * relu(qi[t,h] . ki[s]) / sqrt(d)``) and query ``t`` attends only to
+   its ``min(topk, t + 1)`` best keys.
+3. ``b = RMSNorm(h)``; a router over ALL experts, softmax in float32,
+   the ``top_k`` largest renormalised; every (position, expert) pair is
+   computed — the layer is DROPLESS: pairs are sorted by expert and the
+   three expert products are grouped products (``lax.ragged_dot``) over
+   the stacked expert weights, whatever the routing's skew.
+
+Precision: parameters bfloat16 at rest (norm gains and the scoring head
+float32); every product multiplies ``operand_dtype`` operands and
+accumulates in float32; residual stream, norms, softmax, router and
+indexer scores, top-k and the logit are float32.
+
+``jax.named_scope`` marks the parts (``head/embed``, ``head/attn`` with
+``head/attn/indexer`` inside, ``head/moe/route``, ``head/moe/experts``)
+so that a device trace can be read by part.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from functools import partial
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+Params = dict[str, Any]
+
+
+@dataclass(frozen=True)
+class BackboneConfig:
+    in_dim: int = 12
+    hidden: int = 2048
+    layers: int = 4
+    heads: int = 32
+    kv_heads: int = 4
+    head_dim: int = 128
+    experts: int = 128
+    top_k: int = 8
+    expert_width: int = 768
+    idx_heads: int = 16
+    idx_dim: int = 64
+    idx_topk: int = 2048
+    mrope_section: tuple[int, ...] = (16, 24, 24)
+    rope_theta: float = 1e7
+    eps: float = 1e-6
+    # the depth the seeded tree is initialised for: the two projections that
+    # write into the residual stream are scaled by 1 / sqrt(2 * init_depth)
+    # (the published 48 layers, of which ``layers`` are held)
+    init_depth: int = 48
+    operand_dtype: Any = jnp.bfloat16
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _matrix(key, shape: tuple[int, ...], fan_in: int):
+    """Seeded normals scaled by ``fan_in ** -0.5``, in bfloat16. A stacked
+    weight ([experts, ...]) is generated slice by slice (``lax.map``), so
+    the float32 normals never exceed one expert's matrix."""
+    def draw(k, shp):
+        return (jax.random.normal(k, shp, jnp.float32)
+                * (1.0 / math.sqrt(fan_in))).astype(jnp.bfloat16)
+
+    if len(shape) == 3:
+        return jax.lax.map(lambda k: draw(k, shape[1:]),
+                           jax.random.split(key, shape[0]))
+    return draw(key, shape)
+
+
+def init_backbone(key, cfg: BackboneConfig) -> Params:
+    """A seeded tree, built on the device one matrix at a time and held in
+    bfloat16: nothing of it ever exists in float32, so the build adds at
+    most one matrix (the largest is one layer's stacked expert weight) to
+    what the tree itself takes. Every matrix keeps its input's variance
+    (``fan_in ** -0.5``); ``wo`` and ``wd``, which write into the residual
+    stream, are scaled by ``1 / sqrt(2 * init_depth)`` besides, the scaled
+    initialisation deep decoders are trained from. (At unit scale a
+    flipped 8th expert moves the next router's input by 2% against a gap
+    of 3% to the 9th, and one flip breeds the next: PERF.md, PR 34.)"""
+    f32 = jnp.float32
+    d, hd, f = cfg.hidden, cfg.head_dim, cfg.expert_width
+    keys = iter(jax.random.split(key, 2 + 11 * cfg.layers))
+    out = 2 * cfg.init_depth  # a fan-in 2 * init_depth times as large
+
+    def matrix(shape, fan_in):
+        return _matrix(next(keys), shape, fan_in)
+
+    layers = []
+    for _ in range(cfg.layers):
+        layers.append({
+            "g1": jnp.ones((d,), f32), "g2": jnp.ones((d,), f32),
+            "wq": matrix((d, cfg.heads * hd), d),
+            "wk": matrix((d, cfg.kv_heads * hd), d),
+            "wv": matrix((d, cfg.kv_heads * hd), d),
+            "wo": matrix((cfg.heads * hd, d), cfg.heads * hd * out),
+            "qn": jnp.ones((hd,), f32), "kn": jnp.ones((hd,), f32),
+            "wqi": matrix((d, cfg.idx_heads * cfg.idx_dim), d),
+            "wki": matrix((d, cfg.idx_dim), d),
+            "ww": matrix((d, cfg.idx_heads), d),
+            "kin": {"scale": jnp.ones((cfg.idx_dim,), f32),
+                    "bias": jnp.zeros((cfg.idx_dim,), f32)},
+            "wr": matrix((d, cfg.experts), d),
+            "wg": matrix((cfg.experts, d, f), d),
+            "wu": matrix((cfg.experts, d, f), d),
+            "wd": matrix((cfg.experts, f, d), f * out),
+        })
+    return {
+        "embed": matrix((cfg.in_dim, d), cfg.in_dim),
+        "layers": layers,
+        "gf": jnp.ones((d,), f32),
+        "head": {"w": jax.random.normal(next(keys), (d, 1), f32)
+                 * (1.0 / math.sqrt(d)),
+                 "b": jnp.zeros((1,), f32)},
+    }
+
+
+def _mm(x, w, cfg: BackboneConfig):
+    """``x @ w`` over the last axis of ``x``: operands in the stated
+    dtype, accumulated in float32."""
+    dt = cfg.operand_dtype
+    return jax.lax.dot_general(
+        x.astype(dt), w.astype(dt), (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, gain, eps: float):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def layer_norm(x, p, eps: float):
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, axis=-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def mrope_angles(pos3, head_dim: int, sections, theta: float):
+    """M-RoPE: ``pos3`` [3, B, T] (temporal, height, width ids) ->
+    (cos, sin) [B, T, head_dim // 2]. Frequency pair ``i`` turns by
+    ``theta ** (-2 i / head_dim)`` a step of the stream its section
+    names: the first ``sections[0]`` pairs follow the temporal id, the
+    next ``sections[1]`` the height id, the rest the width id."""
+    half = head_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    stream = np.repeat(np.arange(len(sections)), sections)
+    assert len(stream) == half, (sections, head_dim)
+    pos = jnp.take(pos3.astype(jnp.float32), stream, axis=0)  # [half, B, T]
+    ang = jnp.moveaxis(pos, 0, -1) * inv
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def rotate(x, cos, sin):
+    """Rotary embedding on the leading ``2 * cos.shape[-1]`` channels of
+    ``x`` [B, T, H, D] (pair ``i`` is channels ``i`` and ``i + half``:
+    the rotate-half convention); the rest pass through."""
+    half = cos.shape[-1]
+    x1, x2, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
+
+
+def indexer_keep(a, layer: Params, cos, sin, cfg: BackboneConfig):
+    """The learned-sparse selection: [B, T, T] bool, ``keep[b, t, s]`` iff
+    key ``s`` is causal for query ``t`` and among its ``min(idx_topk,
+    t + 1)`` best by the indexer's score. Follows DeepSeek-V3.2's
+    indexer where the source's config is silent: LayerNorm on the one
+    key head, rotary on the first half of its channels (by the temporal
+    id), head weights scaled by ``idx_heads ** -0.5``."""
+    b, t, _ = a.shape
+    nh, dh = cfg.idx_heads, cfg.idx_dim
+    rot = dh // 4  # rotary pairs: half of the channels turn
+    qi = _mm(a, layer["wqi"], cfg).reshape(b, t, nh, dh)
+    ki = layer_norm(_mm(a, layer["wki"], cfg), layer["kin"], cfg.eps)
+    qi = rotate(qi, cos[..., :rot], sin[..., :rot])
+    ki = rotate(ki[:, :, None, :], cos[..., :rot], sin[..., :rot])[:, :, 0, :]
+    w = _mm(a, layer["ww"], cfg) * (nh ** -0.5)
+    dt = cfg.operand_dtype
+    dots = jnp.einsum("bthd,bsd->bths", qi.astype(dt), ki.astype(dt),
+                      preferred_element_type=jnp.float32)
+    # float32 multiply-reduce over the heads, not a product on the MXU: the
+    # scores decide a top-k and stay in float32
+    score = jnp.sum(w[..., None] * jax.nn.relu(dots), axis=2) * (dh ** -0.5)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    score = jnp.where(causal, score, -jnp.inf)
+    k = min(cfg.idx_topk, t)
+    _, best = jax.lax.top_k(score, k)                         # [B, T, k]
+    chosen = jnp.any(best[..., None] == jnp.arange(t), axis=-2)
+    return jnp.logical_and(chosen, causal)
+
+
+def attention(h, layer: Params, cos, sin, cfg: BackboneConfig):
+    b, t, _ = h.shape
+    nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
+    dt = cfg.operand_dtype
+    a = rms_norm(h, layer["g1"], cfg.eps)
+    q = _mm(a, layer["wq"], cfg).reshape(b, t, nh, hd)
+    k = _mm(a, layer["wk"], cfg).reshape(b, t, nkv, hd)
+    v = _mm(a, layer["wv"], cfg).reshape(b, t, nkv, hd)
+    q = rotate(rms_norm(q, layer["qn"], cfg.eps), cos, sin)
+    k = rotate(rms_norm(k, layer["kn"], cfg.eps), cos, sin)
+    with jax.named_scope("indexer"):
+        keep = indexer_keep(a, layer, cos, sin, cfg)
+    # query head j reads key-value head j // (nh // nkv)
+    q = q.reshape(b, t, nkv, nh // nkv, hd)
+    sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),
+                    preferred_element_type=jnp.float32) * (hd ** -0.5)
+    sc = jnp.where(keep[:, None, None], sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1)
+    o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
+                   preferred_element_type=jnp.float32)
+    return _mm(o.reshape(b, t, nh * hd), layer["wo"], cfg)
+
+
+def route(x, layer: Params, cfg: BackboneConfig):
+    """Router over all experts: ``(experts [P, top_k] int32, weights
+    [P, top_k] float32)``, the weights renormalised over the chosen."""
+    p = jax.nn.softmax(_mm(x, layer["wr"], cfg), axis=-1)
+    top_p, top_e = jax.lax.top_k(p, cfg.top_k)
+    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def grouped_experts(x, top_e, top_w, layer: Params, cfg: BackboneConfig):
+    """Dropless expert layer over positions ``x`` [P, hidden]: every
+    (position, expert) pair the router chose is computed. The pairs are
+    sorted by expert, so each expert's rows are contiguous, and the three
+    products run grouped over the stacked weights (``lax.ragged_dot``);
+    the results return to position order by the inverse permutation and
+    are summed over a position's experts in float32."""
+    n, k = top_e.shape
+    dt = cfg.operand_dtype
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    sizes = jnp.bincount(flat_e, length=cfg.experts).astype(jnp.int32)
+    xs = x.astype(dt)[order // k]
+
+    def grouped(lhs, w):
+        return jax.lax.ragged_dot(lhs, w.astype(dt), sizes,
+                                  preferred_element_type=jnp.float32)
+
+    mid = jax.nn.silu(grouped(xs, layer["wg"])) * grouped(xs, layer["wu"])
+    ys = grouped(mid.astype(dt), layer["wd"])
+    back = jnp.argsort(order)
+    y = ys[back].reshape(n, k, -1)
+    return jnp.sum(y * top_w[..., None], axis=1)
+
+
+def backbone_hidden(params: Params, x, pos3, cfg: BackboneConfig):
+    """[B, T, in_dim] events, [3, B, T] position ids -> final-normed
+    hidden states [B, T, hidden] (float32)."""
+    b, t, _ = x.shape
+    with jax.named_scope("head/embed"):
+        h = _mm(x, params["embed"], cfg)
+        cos, sin = mrope_angles(pos3, cfg.head_dim, cfg.mrope_section,
+                                cfg.rope_theta)
+    for layer in params["layers"]:
+        with jax.named_scope("head/attn"):
+            h = h + attention(h, layer, cos, sin, cfg)
+        flat = rms_norm(h, layer["g2"], cfg.eps).reshape(b * t, -1)
+        with jax.named_scope("head/moe/route"):
+            top_e, top_w = route(flat, layer, cfg)
+        with jax.named_scope("head/moe/experts"):
+            y = grouped_experts(flat, top_e, top_w, layer, cfg)
+        h = h + y.reshape(b, t, -1)
+    return rms_norm(h, params["gf"], cfg.eps)
+
+
+def backbone_scores(params: Params, window, lengths, cfg: BackboneConfig):
+    """The session head: window [B, T, in_dim] (real events first, zeros
+    after), lengths [B] -> [B] probability. Text-like position ids (the
+    three M-RoPE streams all equal the event's index); the score reads
+    the last real position, which under causal attention no padded
+    position can reach."""
+    b, t, _ = window.shape
+    pos3 = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (3, b, t))
+    hid = backbone_hidden(params, window, pos3, cfg)
+    last = jnp.clip(lengths.astype(jnp.int32) - 1, 0, t - 1)
+    hl = jnp.take_along_axis(hid, last[:, None, None], axis=1)[:, 0, :]
+    # one output column: a float32 multiply-reduce, never the MXU
+    logit = jnp.sum(hl * params["head"]["w"][:, 0], axis=-1) + params["head"]["b"][0]
+    return jax.nn.sigmoid(logit)

@@ -13,6 +13,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from igaming_platform_tpu.models.keye_backbone import (
+    BackboneConfig,
+    backbone_scores,
+    init_backbone,
+)
 from igaming_platform_tpu.models.sequence import (
     EVENT_DIM,
     SeqConfig,
@@ -96,11 +101,30 @@ def transformer_scores(sparams, window, lengths):
     return sequence_forward(sparams, window, SESSION_SEQ_CONFIG)["abuse"]
 
 
+# SESSION_HEAD=keye: four decoder layers of a sparse-expert backbone at its
+# published widths (models/keye_backbone.py): 2.50 G parameters, 5.0 GB in
+# bfloat16 beside the state. The server holds this tree once.
+KEYE_CONFIG = BackboneConfig()
+
+
+def init_keye_params(seed: int = _SESSION_HEAD_SEED):
+    """The pinned seeded tree of the ``keye`` head, built on the device
+    in bfloat16, a matrix at a time."""
+    return init_backbone(jax.random.key(seed), KEYE_CONFIG)
+
+
+def keye_scores(sparams, window, lengths):
+    """The ``keye`` head: the backbone over the window, scored at the
+    last real position."""
+    return backbone_scores(sparams, window, lengths, KEYE_CONFIG)
+
+
 # SESSION_HEAD name -> (head_fn(sparams, window, lengths), init_params()).
 HEADS = {
     "pattern": (lambda sparams, win, lp: pattern_scores(win, lp),
                 lambda: None),
     "transformer": (transformer_scores, init_session_head_params),
+    "keye": (keye_scores, init_keye_params),
 }
 
 

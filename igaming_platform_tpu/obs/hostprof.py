@@ -41,6 +41,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from collections import deque
 
 from igaming_platform_tpu.obs import tracing
@@ -63,7 +64,25 @@ _GC_PAUSE_RING = 256
 # Scoring-path thread registry (Tier B's sampling contract)
 
 _REGISTRY_LOCK = threading.Lock()
-_THREAD_ROLES: dict[int, str] = {}
+# ident -> (role, weak reference to the Thread registered under it). The
+# OS hands an ident out again once its thread has ended, so an entry is
+# only as good as its thread: one whose thread has ended is dropped
+# (``_live``), never inherited by the stranger that got the ident next.
+_THREAD_ROLES: dict[int, tuple[str, "weakref.ref | None"]] = {}
+
+
+def _thread_of(ident: int):
+    if ident == threading.get_ident():
+        return threading.current_thread()
+    return next((t for t in threading.enumerate() if t.ident == ident), None)
+
+
+def _live(entry) -> bool:
+    ref = entry[1]
+    if ref is None:  # a thread ``threading`` never saw: nothing to ask
+        return True
+    thread = ref()
+    return thread is not None and thread.is_alive()
 
 
 def register_scoring_thread(role: str, ident: int | None = None) -> int:
@@ -71,11 +90,15 @@ def register_scoring_thread(role: str, ident: int | None = None) -> int:
     the sampler may profile. ``role`` is a short bounded label
     (``grpc_handler``, ``pipeline_stage``, ``readback``, ``ledger``,
     ``drift``, ``shadow``, ...) that prefixes its folded stacks.
-    Idempotent; returns the registered ident."""
+    Idempotent; returns the registered ident. The registration ends
+    with the thread: a later thread that the OS gives the same ident is
+    not registered by it."""
     if ident is None:
         ident = threading.get_ident()
+    thread = _thread_of(ident)
+    ref = weakref.ref(thread) if thread is not None else None
     with _REGISTRY_LOCK:
-        _THREAD_ROLES[ident] = str(role)
+        _THREAD_ROLES[ident] = (str(role), ref)
     return ident
 
 
@@ -87,9 +110,12 @@ def unregister_scoring_thread(ident: int | None = None) -> None:
 
 
 def registered_threads() -> dict[int, str]:
-    """Snapshot of {thread ident: role}."""
+    """Snapshot of {thread ident: role} over the registered threads that
+    are still running; the others are dropped here."""
     with _REGISTRY_LOCK:
-        return dict(_THREAD_ROLES)
+        for ident in [i for i, e in _THREAD_ROLES.items() if not _live(e)]:
+            del _THREAD_ROLES[ident]
+        return {ident: e[0] for ident, e in _THREAD_ROLES.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +414,8 @@ class HostProfiler:
             # Auto-register the handler thread for the sampler: the span
             # completes on the thread that served the RPC.
             ident = threading.get_ident()
-            if ident not in _THREAD_ROLES:
+            entry = _THREAD_ROLES.get(ident)
+            if entry is None or not _live(entry):
                 register_scoring_thread("grpc_handler", ident)
             rows = span.attributes.get("rows")
             with self._lock:

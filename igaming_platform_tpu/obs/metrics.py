@@ -762,6 +762,19 @@ class ServiceMetrics:
             "over session_appends_total it is the share of events that "
             "pay a copy",
         )
+        self.session_head_positions_total = self.registry.counter(
+            f"{service}_session_head_positions_total",
+            "Window positions the session head computed for session-scored "
+            "rows: rows x SESSION_EVENTS (every row is one whole window, "
+            "whatever it holds)",
+        )
+        self.session_head_real_positions_total = self.registry.counter(
+            f"{service}_session_head_real_positions_total",
+            "Of those positions, the ones that held an event (the sum of "
+            "the rows' post-append window lengths): over "
+            "session_head_positions_total it is what the padding of short "
+            "windows costs the head",
+        )
         self.session_lock_wait_seconds_total = self.registry.counter(
             f"{service}_session_lock_wait_seconds_total",
             "Seconds index-mode chunks waited for the session lock "

@@ -490,6 +490,7 @@ class SessionStateManager:
         self.bypass_rows = 0
         self.twin_bytes = 0
         self.twin_regrows = 0
+        self.head_real_positions = 0
         self.lock_wait_s = 0.0
         self.lock_held_s = 0.0
 
@@ -534,12 +535,14 @@ class SessionStateManager:
         self._metrics = metrics
         with self.lock:
             self._export(self.warm_rows, self.cold_rows, self.bypass_rows,
-                         self.appends, self.rehydrations, self.twin_regrows)
+                         self.appends, self.rehydrations, self.twin_regrows,
+                         self.head_real_positions)
             metrics.session_lock_wait_seconds_total.inc(self.lock_wait_s)
             metrics.session_lock_held_seconds_total.inc(self.lock_held_s)
 
     def _export(self, warm: int, cold: int, bypass: int, appends: int,
-                rehydrations: int, regrows: int = 0) -> None:
+                rehydrations: int, regrows: int = 0,
+                real_positions: int = 0) -> None:
         m = self._metrics
         if m is None:
             return
@@ -551,6 +554,10 @@ class SessionStateManager:
             m.session_rows_total.inc(bypass, outcome="bypass")
         if appends:
             m.session_appends_total.inc(appends)
+            # every session-scored row is one window of n_events positions
+            # through the head; real_positions of them hold an event
+            m.session_head_positions_total.inc(appends * self.n_events)
+            m.session_head_real_positions_total.inc(real_positions)
         if rehydrations:
             m.session_rehydrations_total.inc(rehydrations)
         if regrows:
@@ -593,6 +600,8 @@ class SessionStateManager:
                 "appends": self.appends,
                 "twin_bytes": self.twin_bytes,
                 "twin_regrows": self.twin_regrows,
+                "head_positions": self.appends * self.n_events,
+                "head_real_positions": self.head_real_positions,
                 "lock_wait_s": self.lock_wait_s,
                 "lock_held_s": self.lock_held_s,
                 "rehydrations": self.rehydrations,
@@ -764,10 +773,12 @@ class SessionStateManager:
         self.twin_regrows += regrows
         warm = int(np.count_nonzero(post_len >= self.min_events))
         cold = b - warm
+        real = int(post_len.sum())
         self.appends += b
         self.warm_rows += warm
         self.cold_rows += cold
-        self._export(warm, cold, 0, b, 0, regrows)
+        self.head_real_positions += real
+        self._export(warm, cold, 0, b, 0, regrows, real)
         return events, occ, post_len, seqs, audit
 
     def note_lock(self, waited_s: float, held_s: float) -> None:

@@ -67,7 +67,9 @@ def _spec(shape, dtype, sharding):
 
 def _step_args(head_params, capacity, ring_rows, shards, state, repl):
     """Abstract arguments of the fused step (sketch variant), the ring
-    state under ``state`` and everything else under ``repl``."""
+    state under ``state`` and everything else under ``repl``;
+    ``head_params`` is the head's init, traced for its shapes and never
+    run (the ``keye`` tree is 5 GB)."""
     import jax
 
     from igaming_platform_tpu.core.features import NUM_FEATURES
@@ -79,7 +81,7 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl):
                             jax.eval_shape(make))
 
     params = {"multitask": abstract(lambda: init_multitask(jax.random.key(0)))}
-    sparams = None if head_params is None else abstract(lambda: head_params)
+    sparams = None if head_params is None else abstract(head_params)
     n = ss.default_events()
     f32, i32 = np.float32, np.int32
     b = BATCH
@@ -104,12 +106,13 @@ def _compile_step(head, capacity, ring_rows, state, repl, *, mesh=None,
     variant: ``jit(_body)``, ring donated)."""
     from igaming_platform_tpu.core.config import ScoringConfig
     from igaming_platform_tpu.models.ensemble import make_score_fn
-    from igaming_platform_tpu.models.session_heads import session_head
+    from igaming_platform_tpu.models.session_heads import HEADS
     from igaming_platform_tpu.serve import index_program
     from igaming_platform_tpu.serve import session_state as ss
 
     cfg = ScoringConfig()
-    head_fn, head_params = session_head(head)
+    head_fn, init = HEADS[head]
+    head_params = None if head == "pattern" else init
     step = index_program.build(
         make_score_fn(cfg, "multitask", mesh=mesh), cfg, family="session",
         sketch=True, shadow=False, mesh=mesh, plan=plan,
@@ -153,6 +156,29 @@ def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
     _assert_in_place(compiled, ss.ring_size(CAPACITY + 1, ss.default_events()))
     if head == "transformer":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_backbone_step_fits_beside_the_state_and_groups_its_experts(topo):
+    """The fused step with the ``keye`` backbone in it, at the cell's size
+    (5,242,880 accounts, one 256-row rung): still in place on the ring,
+    its arguments are the state plus 5.0 GB of bfloat16 weights, its
+    temporaries stay far under what is left of the chip's 16 GiB, and the
+    expert products are the TPU's grouped kernel, twelve of them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.serve import session_state as ss
+
+    capacity = 5_242_880
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step("keye", capacity, capacity + 1, one, one)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 9.7e9 < mem.argument_size_in_bytes < 9.9e9, mem
+    assert mem.temp_size_in_bytes < 2**30, mem
+    text = compiled.as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 12
 
 
 def test_admission_sync_writes_the_ring_in_place(topo):

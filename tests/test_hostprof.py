@@ -15,6 +15,7 @@ import threading
 import time
 import urllib.error
 import urllib.request
+import weakref
 
 import pytest
 
@@ -25,17 +26,21 @@ from igaming_platform_tpu.obs.flight import FlightRecorder
 
 @pytest.fixture()
 def profiler():
-    """A private HostProfiler riding the real tracing sink list;
-    uninstalled (and its auto-registered threads dropped) afterward so
-    no sink or registry entry leaks into other tests."""
-    before = set(hostprof.registered_threads())
+    """A private HostProfiler riding the real tracing sink list, on an
+    empty thread registry (what earlier files' servers registered in this
+    worker is set aside and put back); uninstalled afterward so no sink
+    or registry entry leaks into other tests."""
+    with hostprof._REGISTRY_LOCK:
+        before = dict(hostprof._THREAD_ROLES)
+        hostprof._THREAD_ROLES.clear()
     hp = hostprof.HostProfiler(enabled=True).install()
     try:
         yield hp
     finally:
         hp.uninstall()
-        for ident in set(hostprof.registered_threads()) - before:
-            hostprof.unregister_scoring_thread(ident)
+        with hostprof._REGISTRY_LOCK:
+            hostprof._THREAD_ROLES.clear()
+            hostprof._THREAD_ROLES.update(before)
 
 
 class _FakeHist:
@@ -258,6 +263,50 @@ def test_sampler_never_touches_unregistered_threads(profiler):
         worker.join(timeout=60.0)
     assert not any("span:score.anon" in k
                    for k in profiler.sampler.folded())
+
+
+def test_registration_ends_with_its_thread_not_with_its_ident(profiler):
+    """The OS hands an ident out again once its thread has ended: a
+    registration must not pass to the stranger that gets it next."""
+    gone = threading.Thread(target=hostprof.register_scoring_thread,
+                            args=("grpc_handler",))
+    gone.start()
+    gone.join(timeout=60.0)
+    ident = gone.ident
+    with hostprof._REGISTRY_LOCK:
+        assert ident in hostprof._THREAD_ROLES  # nobody dropped it yet
+    # the stranger: a live thread that holds the ended thread's ident
+    stop, ready = threading.Event(), threading.Event()
+
+    def anonymous():
+        with tracing.span("score.reused"):
+            ready.set()
+            stop.wait(60.0)
+
+    stranger = threading.Thread(target=anonymous, daemon=True)
+    stranger.start()
+    assert ready.wait(5.0)
+    try:
+        # whether or not the OS really re-used the ident, put the stale
+        # entry under the stranger's: that is the state a re-use leaves
+        with hostprof._REGISTRY_LOCK:
+            hostprof._THREAD_ROLES[stranger.ident] = (
+                hostprof._THREAD_ROLES.pop(ident))
+        assert stranger.ident not in hostprof.registered_threads()
+        profiler.sampler.start(hz=250.0)
+        time.sleep(0.1)
+        profiler.sampler.stop()
+    finally:
+        stop.set()
+        stranger.join(timeout=60.0)
+    assert not any("span:score.reused" in k for k in profiler.sampler.folded())
+    # and an RPC served on the re-used ident registers its own thread anew
+    with hostprof._REGISTRY_LOCK:
+        hostprof._THREAD_ROLES[threading.get_ident()] = ("grpc_handler",
+                                                         weakref.ref(gone))
+    with tracing.span("rpc.ScoreBatch"):
+        pass
+    assert threading.get_ident() in hostprof.registered_threads()
 
 
 # ---------------------------------------------------------------------------

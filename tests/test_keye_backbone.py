@@ -1,0 +1,315 @@
+"""The ``keye`` session head (models/keye_backbone.py) against its plain
+reference (chipbench/heads/keye_vl2.py) at a small size on the CPU, part
+by part, and through the served session path.
+
+The small size keeps every mechanism: 2 layers, hidden 64, 8 experts with
+2 a token, 4 query / 2 key-value heads of 16, an indexer of 2 heads of 8
+whose ``topk`` 4 is UNDER the window's 16 keys, so the selection prunes.
+"""
+
+from __future__ import annotations
+
+import copy
+import json
+import os
+import tempfile
+import types
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from chipbench import harness, reference, validate  # noqa: E402
+from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
+from igaming_platform_tpu.models import session_heads  # noqa: E402
+
+SMALL_SOURCE = {
+    "hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "num_experts": 8,
+    "num_local_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32,
+    "sa_config": {"indexer_num_heads": 2, "indexer_head_dim": 8, "topk": 4},
+    "rope_scaling": {"mrope_section": [2, 3, 3]},
+    "rope_theta": 1e7, "rms_norm_eps": 1e-6,
+}
+
+
+def small_config(**over) -> kb.BackboneConfig:
+    kw = dict(hidden=64, layers=2, heads=4, kv_heads=2, head_dim=16, experts=8,
+              top_k=2, expert_width=32, idx_heads=2, idx_dim=8, idx_topk=4,
+              mrope_section=(2, 3, 3))
+    kw.update(over)
+    return kb.BackboneConfig(**kw)
+
+
+@pytest.fixture(scope="module")
+def head():
+    return validate.load_code("heads", "keye_vl2")
+
+
+def windows(n: int, lengths, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    lengths = np.resize(np.asarray(lengths), n)
+    x = rng.normal(0, 1, (n, 16, 12)).astype(np.float32)
+    x *= (np.arange(16)[None, :] < lengths[:, None])[..., None]
+    return x, lengths
+
+
+def program_scores(cfg, params, x, lengths):
+    return np.asarray(jax.jit(
+        lambda p, w, l: kb.backbone_scores(p, w, l, cfg))(
+            params, jnp.asarray(x), jnp.asarray(lengths, jnp.int32)))
+
+
+# -- the whole head ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("lengths", [(1,), (4,), (16,), (1, 4, 16, 7, 9, 2)],
+                         ids=["len1", "len4", "len16", "mixed"])
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_head_equals_the_reference(head, operands, lengths):
+    cfg = small_config(operand_dtype=jnp.dtype(operands))
+    params = head.make_params(7, SMALL_SOURCE)
+    x, lens = windows(24, lengths, seed=len(lengths))
+    got = program_scores(cfg, params, x, lens)
+    want = head.forward(params, x, lens, reference.rounder(operands))
+    assert got.shape == want.shape == (24,)
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=0)
+    if max(lengths) > 4:
+        # the selection prunes here: keeping every key reads differently
+        loose = program_scores(small_config(
+            operand_dtype=jnp.dtype(operands), idx_topk=2048), params, x, lens)
+        assert np.abs(loose - got).max() > 1e-4
+
+
+def test_rounding_is_where_the_reference_puts_it(head):
+    """bfloat16 operands read differently from float32 ones (so the case
+    above compares two roundings, not one arithmetic twice)."""
+    params = head.make_params(7, SMALL_SOURCE)
+    x, lens = windows(24, (16,))
+    a = program_scores(small_config(operand_dtype=jnp.float32), params, x, lens)
+    b = program_scores(small_config(operand_dtype=jnp.bfloat16), params, x, lens)
+    diff = np.abs(a - b)
+    # one row may flip an expert or a kept key and read far off; most do not
+    assert diff.max() > 1e-5 and np.median(diff) < 0.01
+
+
+@pytest.mark.parametrize("lengths", [1, 4, 9])
+def test_positions_after_the_last_real_one_change_nothing(head, lengths):
+    cfg = small_config()
+    params = head.make_params(3, SMALL_SOURCE)
+    x, lens = windows(8, (lengths,))
+    junk = x.copy()
+    junk[:, lengths:] = np.random.default_rng(1).normal(0, 3, junk[:, lengths:].shape)
+    np.testing.assert_array_equal(program_scores(cfg, params, x, lens),
+                                  program_scores(cfg, params, junk, lens))
+    # the reference skips them outright
+    np.testing.assert_array_equal(
+        head.forward(params, x, lens, reference.rounder("bfloat16")),
+        head.forward(params, junk, lens, reference.rounder("bfloat16")))
+
+
+def test_tree_of_the_reference_is_the_programs(head):
+    """The harness replaces the program's tree by the reference's: the
+    two have one structure, shapes and dtypes, so the compiled step is
+    reused. And the program's sizes are the configuration file's."""
+    cfg = small_config()
+    mine = jax.eval_shape(lambda: kb.init_backbone(jax.random.key(0), cfg))
+    theirs = head.make_params(1, SMALL_SOURCE)
+    assert jax.tree.structure(mine) == jax.tree.structure(theirs)
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+    published = validate.load_data("configs", "risk-seqhead-keye-vl2-30b-a3b")
+    d, c = head.dims_of(published), session_heads.KEYE_CONFIG
+    assert (d.hidden, d.layers, d.heads, d.kv_heads, d.head_dim, d.experts,
+            d.top_k, d.expert_width, d.idx_heads, d.idx_dim, d.idx_topk,
+            d.sections, d.theta, d.eps) == (
+        c.hidden, c.layers, c.heads, c.kv_heads, c.head_dim, c.experts,
+        c.top_k, c.expert_width, c.idx_heads, c.idx_dim, c.idx_topk,
+        c.mrope_section, c.rope_theta, c.eps)
+    full = jax.eval_shape(session_heads.init_keye_params)
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(full))
+    assert 2.50e9 < n < 2.51e9
+    assert sum(a.dtype.itemsize * int(np.prod(a.shape))
+               for a in jax.tree.leaves(full)) < 5.01e9
+
+
+# -- M-RoPE ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("sections,head_dim", [((2, 3, 3), 16),
+                                               ((16, 24, 24), 128)])
+def test_mrope_with_three_unequal_streams(head, sections, head_dim):
+    rng = np.random.default_rng(5)
+    b, t, h = 3, 16, 2
+    pos3 = np.stack([np.arange(t) + 1, rng.integers(0, 40, t),
+                     rng.integers(0, 900, t)])[:, None, :].repeat(b, 1)
+    pos3[:, 1] += 3
+    x = rng.normal(0, 1, (b, t, h, head_dim)).astype(np.float32)
+    cos, sin = kb.mrope_angles(jnp.asarray(pos3, jnp.int32), head_dim, sections, 1e7)
+    got = np.asarray(kb.rotate(jnp.asarray(x), cos, sin))
+    d = head.dims_of(SMALL_SOURCE)._replace(head_dim=head_dim, sections=sections)
+    want = np.asarray(head._mrope(jnp.asarray(x), jnp.asarray(pos3, jnp.int32), d))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    # the streams matter: equal ids turn the later sections differently
+    same = np.broadcast_to(pos3[:1], pos3.shape)
+    cos1, sin1 = kb.mrope_angles(jnp.asarray(same, jnp.int32), head_dim, sections, 1e7)
+    assert np.abs(np.asarray(kb.rotate(jnp.asarray(x), cos1, sin1)) - got).max() > 0.1
+    # pair i is channels i and i + half, and each section follows its stream
+    half = head_dim // 2
+    lo = sections[0]
+    np.testing.assert_allclose(np.asarray(cos)[..., :lo], np.asarray(cos1)[..., :lo])
+    assert not np.allclose(np.asarray(cos)[..., lo:half], np.asarray(cos1)[..., lo:half])
+
+
+# -- the dropless expert layer --------------------------------------------------
+
+
+def _expert_loop(x, top_e, top_w, layer):
+    """Every (position, expert) pair, one expert at a time, in float64."""
+    x = np.asarray(x, np.float64)
+    y = np.zeros_like(x)
+    wg, wu, wd = (np.asarray(layer[k].astype(jnp.float32), np.float64)
+                  for k in ("wg", "wu", "wd"))
+    for e in range(wg.shape[0]):
+        pos, slot = np.nonzero(np.asarray(top_e) == e)
+        if len(pos):
+            g = x[pos] @ wg[e]
+            out = (g / (1 + np.exp(-g)) * (x[pos] @ wu[e])) @ wd[e]
+            np.add.at(y, pos, out * np.asarray(top_w)[pos, slot][:, None])
+    return y
+
+
+@pytest.mark.parametrize("experts,top_k,hot", [(8, 2, (3, 5)), (16, 8, (2, 11)),
+                                               (8, 2, None)],
+                         ids=["top2-all-on-two", "top8-two-in-every-set", "free"])
+def test_grouped_experts_drop_nothing_under_skew(experts, top_k, hot):
+    cfg = small_config(experts=experts, top_k=top_k, operand_dtype=jnp.float32)
+    params = kb.init_backbone(jax.random.key(2), cfg)
+    layer = dict(params["layers"][0])
+    n = 96
+    x = jax.random.normal(jax.random.key(3), (n, cfg.hidden), jnp.float32)
+    if hot is not None:
+        # a router that puts two experts into every position's set: one
+        # constant channel, and a large weight from it to the two
+        x = x.at[:, -1].set(10.0)
+        layer["wr"] = layer["wr"].astype(jnp.float32).at[-1, jnp.asarray(hot)].set(5.0)
+    top_e, top_w = jax.jit(lambda x: kb.route(x, layer, cfg))(x)
+    counts = np.bincount(np.asarray(top_e).ravel(), minlength=cfg.experts)
+    if hot is not None:
+        assert counts[list(hot)].tolist() == [n, n]  # every position, both
+    assert counts.sum() == n * cfg.top_k  # a pair a slot: none dropped
+    np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, atol=1e-6)
+    got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
+        x, e, w, layer, cfg))(x, top_e, top_w))
+    want = _expert_loop(x, top_e, top_w, layer)
+    np.testing.assert_allclose(got, want, atol=2e-4 * np.abs(want).max(), rtol=0)
+    # every position got all of its experts: leaving any pair out shows
+    pos, slot = 17, 0
+    less_w = np.asarray(top_w).copy()
+    less_w[pos, slot] = 0.0
+    less = _expert_loop(x, top_e, less_w, layer)
+    assert np.abs(less[pos] - got[pos]).max() > 10 * np.abs(want - got).max()
+
+
+# -- the served path ------------------------------------------------------------
+
+
+@pytest.fixture
+def small_keye(monkeypatch):
+    """``SESSION_HEAD=keye`` at the small size: the row of ``HEADS`` is
+    steered here, in the test; the program has no option for it."""
+    cfg = small_config()
+    monkeypatch.setitem(session_heads.HEADS, "keye", (
+        lambda sp, win, lp: kb.backbone_scores(sp, win, lp, cfg),
+        lambda: kb.init_backbone(jax.random.key(11), cfg)))
+    return cfg
+
+
+@pytest.fixture
+def environment():
+    saved = dict(os.environ)
+    yield
+    os.environ.clear()
+    os.environ.update(saved)
+
+
+def test_score_batch_on_the_session_path_equals_the_reference(
+        small_keye, environment):
+    """The new cell's own files, the source's sizes cut to the small one:
+    one server, the head through ``serve/index_program.build``, index-mode
+    ``ScoreBatch`` over a real socket, every reply (score, rule score,
+    action, session bits) against ``chipbench/reference.py``."""
+    spec = copy.deepcopy(validate.load_cell("keye-backbone-insession"))
+    spec["config"].update(copy.deepcopy(SMALL_SOURCE))
+    spec["config"]["env"]["FEATURE_STORE"] = "python"
+    run = harness.Run(spec, seed=3_400_000_007, seconds=1.0, trace=False,
+                      rehearse=True)
+    run.boot()
+    try:
+        assert run.inner.session.head == "keye"
+        run.fill()
+        # the harness judges a CPU run's head at float32 operands, because
+        # the transformer head leaves its rounding to the MXU; this head
+        # casts its operands itself, on every backend, so it is judged
+        # at the stated precision as on the chip
+        run.device = types.SimpleNamespace(platform="as-on-the-chip")
+        ok, numbers = run.check()
+        built = run.inner._fused_fns
+        counters = run.counters()
+        snap = run.inner.session.snapshot()
+    finally:
+        run.shutdown()
+    assert any(k[0] == "session" for k in built)
+    assert ok, numbers
+    assert numbers["session_bit_mismatch"] == 0 and numbers["score_max_err"] <= 1
+    assert numbers["warm_rows"] > numbers["rows"] // 2
+    assert numbers["folded_rows"] > 0
+    # the two counters of the head's positions
+    assert counters["risk_session_head_positions_total"] == 16 * numbers["rows"]
+    real = counters["risk_session_head_real_positions_total"]
+    assert numbers["rows"] < real < 16 * numbers["rows"]
+    assert snap["head_positions"] == 16 * numbers["rows"]
+    assert snap["head_real_positions"] == real
+
+
+def test_replay_verifies_a_ledger_written_under_the_head(small_keye, monkeypatch):
+    monkeypatch.setenv("SESSION_HEAD", "keye")
+    from igaming_platform_tpu.core.config import BatcherConfig, ScoringConfig
+    from igaming_platform_tpu.serve import ledger as ledger_mod
+    from igaming_platform_tpu.serve.scorer import TPUScoringEngine
+    from tools.replay import replay_directory
+
+    d = tempfile.mkdtemp(prefix="keye-replay-test-")
+    eng = TPUScoringEngine(
+        ScoringConfig(), ml_backend="mock",
+        batcher_config=BatcherConfig(batch_size=16, latency_tiers=(8,),
+                                     max_wait_ms=1.0),
+        feature_cache=8, session_state=True)
+    eng.ledger = ledger_mod.DecisionLedger(d)
+    eng.ensure_cache()
+    try:
+        accts = [f"k{i}" for i in range(5)]
+        for r in range(6):
+            ids = accts + [accts[r % 5]]
+            out = eng.score_columns_cached(
+                ids, [700 + 13 * i + r for i in range(len(ids))],
+                ["bet" if r % 2 == 0 else "deposit"] * len(ids),
+                now=1_700_000_000.0 + 30.0 * r)
+        assert eng.session.head == "keye"
+        assert np.all((out["ml_score"] >= 0) & (out["ml_score"] <= 1))
+    finally:
+        eng.ledger.close()
+        eng.close()
+    v = replay_directory(d, batch=16)
+    assert v["session_records"] == 36
+    assert v["session_verified"] == 36 and v["session_hash_mismatch"] == 0
+    assert v["session_ok"] and v["ok"], json.dumps(v)[:400]
+
+
+def test_unknown_head_lists_the_new_name():
+    with pytest.raises(ValueError) as err:
+        session_heads.session_head("mamba")
+    assert "'keye'" in str(err.value) and "'pattern'" in str(err.value)
