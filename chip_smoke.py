@@ -61,6 +61,10 @@ PROB_BOUND = 1e-2
 ATTN_FWD_TOL = 2e-2
 ATTN_BWD_TOL = 4e-2
 GBDT_TOL = 1e-5
+# Grouped expert products against lax.ragged_dot, as a share of the largest
+# value: one unit in the last place of bfloat16 (``mid`` is rounded once;
+# only the order of float32 accumulation may differ before it).
+EXPERTS_TOL = 2.0 ** -7
 
 
 def check(cond: bool, message: str) -> None:
@@ -662,11 +666,13 @@ def phase_kernels(interpret: bool = False, *,
                   attention_shapes: tuple = ((1, 2, 64, 32), (8, 2, 256, 32),
                                              (2, 8, 2048, 16), (1, 8, 8192, 16)),
                   backward_shape: tuple = (2, 8, 2048, 16),
-                  gbdt_batch: int = 8192, gbdt_tile: int = 256) -> dict:
+                  gbdt_batch: int = 8192, gbdt_tile: int = 256,
+                  expert_shape: tuple = (32768, 2048, 768, 128)) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
-    [8192, 30] — each against its XLA reference."""
+    [8192, 30], the grouped expert products at the ``keye`` head's
+    (rows, hidden, width, experts) — each against its XLA reference."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -675,6 +681,7 @@ def phase_kernels(interpret: bool = False, *,
     from igaming_platform_tpu.models.gbdt import gbdt_raw, init_gbdt
     from igaming_platform_tpu.ops.gbdt_matmul import gbdt_raw_matmul, precompute_selector
     from igaming_platform_tpu.ops.pallas import flash_attention as fa
+    from igaming_platform_tpu.ops.pallas import grouped_experts as ge
     from igaming_platform_tpu.ops.pallas.gbdt_kernel import gbdt_raw_pallas
 
     report: dict = {"device": device_stamp(), "interpret": interpret}
@@ -723,6 +730,43 @@ def phase_kernels(interpret: bool = False, *,
         err = float(np.max(np.abs(got - np.asarray(jax.jit(fn)()))))
         report[f"gbdt_vs_{name}"] = err
         check(err <= GBDT_TOL, f"GBDT kernel vs {name} form: {err} > {GBDT_TOL}")
+
+    rows, hidden, width, experts = expert_shape
+    ks = jax.random.split(jax.random.key(rows), 5)
+
+    def stacked(key, fan_in, fan_out):
+        return jax.lax.map(
+            lambda k: (jax.random.normal(k, (fan_in, fan_out), jnp.float32)
+                       * fan_in ** -0.5).astype(jnp.bfloat16),
+            jax.random.split(key, experts))
+
+    xs = jax.random.normal(ks[0], (rows, hidden), jnp.float32).astype(jnp.bfloat16)
+    wg, wu = stacked(ks[1], hidden, width), stacked(ks[2], hidden, width)
+    wd = stacked(ks[3], width, hidden)
+    # a seeded routing with its skew: every row one expert, some none
+    sizes = jnp.bincount(jax.random.categorical(
+        ks[4], jnp.linspace(0.0, 3.0, experts).at[1].set(-jnp.inf), shape=(rows,)),
+        length=experts).astype(jnp.int32)
+    check(ge.supports(xs, wg), f"grouped experts: {expert_shape} not supported")
+
+    def ragged(lhs, w):
+        return jax.lax.ragged_dot(lhs, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    # arguments, not closures: a captured 0.4 GB weight is a constant of
+    # the executable
+    mid_ref = jax.jit(lambda xs, wg, wu: (
+        jax.nn.silu(ragged(xs, wg)) * ragged(xs, wu)).astype(jnp.bfloat16))(
+            xs, wg, wu)
+    mid = ge.gate_up(xs, wg, wu, sizes, interpret=interpret)
+    ys = ge.down(mid_ref, wd, sizes, interpret=interpret)
+    ys_ref = jax.jit(ragged)(mid_ref, wd)
+    mid32, mid_ref32 = mid.astype(jnp.float32), mid_ref.astype(jnp.float32)
+    errs = [float(jnp.max(jnp.abs(mid32 - mid_ref32)) / jnp.max(jnp.abs(mid_ref32))),
+            float(jnp.max(jnp.abs(ys - ys_ref)) / jnp.max(jnp.abs(ys_ref)))]
+    report[f"grouped_experts_M{rows}_E{experts}"] = errs
+    check(max(errs) <= EXPERTS_TOL,
+          f"grouped experts {expert_shape}: max err {errs} > {EXPERTS_TOL}")
     return report
 
 

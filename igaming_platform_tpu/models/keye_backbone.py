@@ -19,8 +19,14 @@ head on the last real position. Each layer, over ``h`` [B, T, hidden]:
 3. ``b = RMSNorm(h)``; a router over ALL experts, softmax in float32,
    the ``top_k`` largest renormalised; every (position, expert) pair is
    computed — the layer is DROPLESS: pairs are sorted by expert and the
-   three expert products are grouped products (``lax.ragged_dot``) over
-   the stacked expert weights, whatever the routing's skew.
+   three expert products are grouped products over the stacked expert
+   weights, whatever the routing's skew. On a TPU, at lane-aligned widths
+   and bfloat16, they run as two Pallas kernels (ops/pallas/
+   grouped_experts.py: gate and up share one read of the sorted rows,
+   silu and their product in the epilogue; then down), chosen while
+   tracing and announced once (``expert core: ...``); anywhere else as
+   three ``lax.ragged_dot`` calls, which stay the kernels' reference and
+   what the CPU tests and replay run. Same arithmetic on both.
 
 Precision: parameters bfloat16 at rest (norm gains and the scoring head
 float32); every product multiplies ``operand_dtype`` operands and
@@ -34,9 +40,10 @@ so that a device trace can be read by part.
 
 from __future__ import annotations
 
+import logging
 import math
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any
 
 import jax
@@ -44,6 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 Params = dict[str, Any]
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass(frozen=True)
@@ -237,26 +246,53 @@ def route(x, layer: Params, cfg: BackboneConfig):
     return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
 
-def grouped_experts(x, top_e, top_w, layer: Params, cfg: BackboneConfig):
-    """Dropless expert layer over positions ``x`` [P, hidden]: every
-    (position, expert) pair the router chose is computed. The pairs are
-    sorted by expert, so each expert's rows are contiguous, and the three
-    products run grouped over the stacked weights (``lax.ragged_dot``);
-    the results return to position order by the inverse permutation and
-    are summed over a position's experts in float32."""
-    n, k = top_e.shape
+@lru_cache(maxsize=None)
+def _announce_core(core: str, backend: str) -> None:
+    """Log, once per (core, backend), which core runs the expert layer's
+    grouped products: the choice is made at trace time and is otherwise
+    invisible."""
+    logger.info("expert core: %s (backend=%s)", core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
+
+
+def _expert_products(xs, sizes, layer: Params, cfg: BackboneConfig):
+    """Rows ``xs`` [M, hidden] sorted by expert, ``sizes`` [E] -> float32
+    [M, hidden]: ``(silu(xs @ wg[e]) * (xs @ wu[e])) @ wd[e]`` for each
+    row's expert ``e``. On a TPU, at shapes the kernels support, two
+    Pallas grouped kernels (ops/pallas/grouped_experts.py: gate and up
+    share one read of the rows, silu and the product in the epilogue);
+    elsewhere three ``lax.ragged_dot`` products, which are also the
+    kernels' golden reference."""
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+
+    backend = jax.default_backend()
+    if backend == "tpu" and kernels.supports(xs, layer["wg"]):
+        _announce_core("pallas-grouped", backend)
+        mid = kernels.gate_up(xs, layer["wg"], layer["wu"], sizes)
+        return kernels.down(mid, layer["wd"], sizes)
+    _announce_core("xla-ragged-dot", backend)
     dt = cfg.operand_dtype
-    flat_e = top_e.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    sizes = jnp.bincount(flat_e, length=cfg.experts).astype(jnp.int32)
-    xs = x.astype(dt)[order // k]
 
     def grouped(lhs, w):
         return jax.lax.ragged_dot(lhs, w.astype(dt), sizes,
                                   preferred_element_type=jnp.float32)
 
     mid = jax.nn.silu(grouped(xs, layer["wg"])) * grouped(xs, layer["wu"])
-    ys = grouped(mid.astype(dt), layer["wd"])
+    return grouped(mid.astype(dt), layer["wd"])
+
+
+def grouped_experts(x, top_e, top_w, layer: Params, cfg: BackboneConfig):
+    """Dropless expert layer over positions ``x`` [P, hidden]: every
+    (position, expert) pair the router chose is computed. The pairs are
+    sorted by expert, so each expert's rows are contiguous, and the three
+    products run grouped over the stacked weights (``_expert_products``);
+    the results return to position order by the inverse permutation and
+    are summed over a position's experts in float32."""
+    n, k = top_e.shape
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    sizes = jnp.bincount(flat_e, length=cfg.experts).astype(jnp.int32)
+    xs = x.astype(cfg.operand_dtype)[order // k]
+    ys = _expert_products(xs, sizes, layer, cfg)
     back = jnp.argsort(order)
     y = ys[back].reshape(n, k, -1)
     return jnp.sum(y * top_w[..., None], axis=1)

@@ -10,6 +10,7 @@ commented-out wiring (main.go:98-130) is implemented, not stubbed.
 
 from __future__ import annotations
 
+import importlib
 import json
 import logging
 import os
@@ -85,6 +86,20 @@ def resolve_model_boot(config, ml_backend: str = "mock", params=None):
     return ml_backend, params
 
 
+def import_pallas_in_background() -> threading.Thread:
+    """Every TPU boot traces Pallas kernels sooner or later (a session
+    head's at warm-up, the abuse detector's attention at its first RPC),
+    and importing Pallas is ~2 s of Python (measured on a v5e host,
+    PERF.md, PR 35). Started just before the native store's allocation,
+    seconds of C++ that hold no GIL, the import costs the boot nothing;
+    whoever needs the modules first waits on their import locks."""
+    thread = threading.Thread(
+        target=importlib.import_module, args=("jax.experimental.pallas.tpu",),
+        name="import-pallas", daemon=True)
+    thread.start()
+    return thread
+
+
 class RiskServer:
     """Assembled risk service: TPU engine + gRPC + HTTP sidecar + bridge."""
 
@@ -150,6 +165,8 @@ class RiskServer:
             if native_available():
                 from igaming_platform_tpu.serve.native_store import NativeFeatureStore
 
+                if backend == "tpu":
+                    import_pallas_in_background()
                 feature_store = NativeFeatureStore(max_accounts=store_max_accounts)
                 store_name = "native"
             elif self.config.feature_store == "native" or backend != "cpu":

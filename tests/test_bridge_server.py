@@ -118,6 +118,30 @@ def test_abuse_detector_batch_scores():
     assert scores.shape == (2,)
 
 
+def test_pallas_is_imported_in_the_background_and_only_on_a_tpu_boot(monkeypatch):
+    """The thread a TPU boot starts before the native store allocates
+    imports the kernels' toolchain and ends; a CPU boot starts none."""
+    import sys
+
+    from igaming_platform_tpu.serve import server as server_mod
+
+    thread = server_mod.import_pallas_in_background()
+    assert thread.daemon and thread.name == "import-pallas"
+    thread.join(timeout=120)
+    assert not thread.is_alive()
+    assert "jax.experimental.pallas.tpu" in sys.modules
+
+    started = []
+    monkeypatch.setattr(server_mod, "import_pallas_in_background",
+                        lambda: started.append("started"))
+    cfg = RiskServiceConfig(scoring=ScoringConfig(),
+                            batcher=BatcherConfig(batch_size=32, max_wait_ms=1))
+    server = server_mod.RiskServer(cfg, grpc_port=0, http_port=0,
+                                   store_max_accounts=4096)
+    server.shutdown(grace=1)
+    assert started == []  # JAX_PLATFORMS=cpu: nothing here traces a kernel
+
+
 def test_risk_server_assembled():
     from igaming_platform_tpu.serve.server import RiskServer
 

@@ -158,12 +158,16 @@ def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
         assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_backbone_step_fits_beside_the_state_and_groups_its_experts(topo):
+def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
+        topo, tpu_backend):
     """The fused step with the ``keye`` backbone in it, at the cell's size
-    (5,242,880 accounts, one 256-row rung): still in place on the ring,
-    its arguments are the state plus 5.0 GB of bfloat16 weights, its
-    temporaries stay far under what is left of the chip's 16 GiB, and the
-    expert products are the TPU's grouped kernel, twelve of them."""
+    (5,242,880 accounts, one 256-row rung) and with the expert core the
+    chip picks: still in place on the ring, its arguments are the state
+    plus 5.0 GB of bfloat16 weights, its temporaries stay far under what
+    is left of the chip's 16 GiB, and the expert products are the two
+    Pallas grouped kernels, a pair a layer under the scope the trace
+    reads them by, with no XLA grouped product left. This is where the
+    kernels' tiles and VMEM limit are proven to compile without a chip."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.serve import session_state as ss
@@ -176,9 +180,15 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(topo):
     assert _ring_sized_copies(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.7e9 < mem.argument_size_in_bytes < 9.9e9, mem
+    # 615,414,272 B (614,769,152 B with the three XLA grouped products: the
+    # two float32 [32768, 768] arrays are gone, but the largest temporaries
+    # are the float32 [32768, 2048] result and its return to position order)
     assert mem.temp_size_in_bytes < 2**30, mem
     text = compiled.as_text()
-    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 12
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len([k for k in kernels if "head/moe/experts" in k]) == 8, kernels
+    assert "%ragged-dot-none" not in text
 
 
 def test_admission_sync_writes_the_ring_in_place(topo):

@@ -181,6 +181,8 @@ def test_smoke_trainer_and_mesh_phases_on_virtual_devices(_tiny_server_env):
 def test_smoke_kernels_phase_tiny_interpreted():
     report = chip_smoke.phase_kernels(
         interpret=True, attention_shapes=((1, 2, 64, 32),),
-        backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128)
+        backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
+        expert_shape=(512, 128, 128, 8))
     assert report["interpret"] is True
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
+    assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL

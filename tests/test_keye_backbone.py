@@ -10,6 +10,7 @@ whose ``topk`` 4 is UNDER the window's 16 keys, so the selection prunes.
 from __future__ import annotations
 
 import copy
+import functools
 import json
 import os
 import tempfile
@@ -182,11 +183,41 @@ def _expert_loop(x, top_e, top_w, layer):
     return y
 
 
+def _steer_to_the_kernels(monkeypatch):
+    """What a TPU would pick, on the CPU: the backend reports ``tpu`` and
+    the kernels run through the Pallas interpreter. Steered here, in the
+    test; the program has no option for it."""
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name in ("gate_up", "down"):
+        monkeypatch.setattr(kernels, name, functools.partial(
+            getattr(kernels, name), interpret=True))
+
+
+@pytest.fixture
+def expert_core(request, monkeypatch):
+    """The core ``grouped_experts`` picks while tracing: (sizes of the
+    layer, tolerance against the float64 loop, the core's name). ``xla``
+    is what a CPU picks by itself (float32 operands at the small size);
+    ``pallas`` sizes the layer so that ``supports`` takes it (lane-aligned
+    widths, bfloat16)."""
+    kb._announce_core.cache_clear()
+    if request.param == "xla":
+        return dict(operand_dtype=jnp.float32), 2e-4, "xla-ragged-dot"
+    _steer_to_the_kernels(monkeypatch)
+    # bfloat16 operands: x, the weights and ``mid`` are each rounded once
+    return dict(hidden=128, expert_width=128), 2e-2, "pallas-grouped"
+
+
+@pytest.mark.parametrize("expert_core", ["xla", "pallas"], indirect=True)
 @pytest.mark.parametrize("experts,top_k,hot", [(8, 2, (3, 5)), (16, 8, (2, 11)),
                                                (8, 2, None)],
                          ids=["top2-all-on-two", "top8-two-in-every-set", "free"])
-def test_grouped_experts_drop_nothing_under_skew(experts, top_k, hot):
-    cfg = small_config(experts=experts, top_k=top_k, operand_dtype=jnp.float32)
+def test_grouped_experts_drop_nothing_under_skew(experts, top_k, hot, expert_core,
+                                                 caplog):
+    sizes, tolerance, core = expert_core
+    cfg = small_config(experts=experts, top_k=top_k, **sizes)
     params = kb.init_backbone(jax.random.key(2), cfg)
     layer = dict(params["layers"][0])
     n = 96
@@ -202,16 +233,49 @@ def test_grouped_experts_drop_nothing_under_skew(experts, top_k, hot):
         assert counts[list(hot)].tolist() == [n, n]  # every position, both
     assert counts.sum() == n * cfg.top_k  # a pair a slot: none dropped
     np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, atol=1e-6)
-    got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
-        x, e, w, layer, cfg))(x, top_e, top_w))
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
+            x, e, w, layer, cfg))(x, top_e, top_w))
+    assert f"expert core: {core} (backend=" in caplog.text
     want = _expert_loop(x, top_e, top_w, layer)
-    np.testing.assert_allclose(got, want, atol=2e-4 * np.abs(want).max(), rtol=0)
+    np.testing.assert_allclose(got, want, atol=tolerance * np.abs(want).max(), rtol=0)
     # every position got all of its experts: leaving any pair out shows
     pos, slot = 17, 0
     less_w = np.asarray(top_w).copy()
     less_w[pos, slot] = 0.0
     less = _expert_loop(x, top_e, less_w, layer)
     assert np.abs(less[pos] - got[pos]).max() > 10 * np.abs(want - got).max()
+
+
+def test_expert_core_is_announced_and_falls_back_where_the_kernels_do_not_fit(
+        monkeypatch, caplog):
+    """Off the TPU the XLA branch runs and says so; on a (steered) TPU a
+    shape ``supports`` refuses (the small size: hidden 64, width 32) still
+    takes it, and a shape it accepts names the kernels."""
+    def announced(cfg):
+        kb._announce_core.cache_clear()
+        caplog.clear()
+        layer = jax.eval_shape(
+            lambda: kb.init_backbone(jax.random.key(0), cfg))["layers"][0]
+        xs = jax.ShapeDtypeStruct((64, cfg.hidden), cfg.operand_dtype)
+        sizes = jax.ShapeDtypeStruct((cfg.experts,), jnp.int32)
+        with caplog.at_level("INFO", logger=kb.logger.name):
+            out = jax.eval_shape(
+                lambda xs, sizes, layer: kb._expert_products(xs, sizes, layer, cfg),
+                xs, sizes, layer)
+        assert out.shape == (64, cfg.hidden) and out.dtype == jnp.float32
+        return [r.getMessage() for r in caplog.records]
+
+    small, aligned = small_config(), small_config(hidden=128, expert_width=128)
+    assert jax.default_backend() == "cpu"
+    assert announced(small) == ["expert core: xla-ragged-dot (backend=cpu)"]
+    assert announced(aligned) == ["expert core: xla-ragged-dot (backend=cpu)"]
+    _steer_to_the_kernels(monkeypatch)
+    assert announced(small) == ["expert core: xla-ragged-dot (backend=tpu)"]
+    assert announced(aligned) == ["expert core: pallas-grouped (backend=tpu)"]
+    assert announced(small_config(hidden=128, expert_width=128,
+                                  operand_dtype=jnp.float32)) == [
+        "expert core: xla-ragged-dot (backend=tpu)"]
 
 
 # -- the served path ------------------------------------------------------------
