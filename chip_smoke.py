@@ -65,6 +65,10 @@ GBDT_TOL = 1e-5
 # value: one unit in the last place of bfloat16 (``mid`` is rounded once;
 # only the order of float32 accumulation may differ before it).
 EXPERTS_TOL = 2.0 ** -7
+# The pangu head against its reference, on the probability: the limit the
+# cell's output check holds the worst single row to (fraud_prob_max_err
+# in chipbench/configs/risk-seqhead-openpangu-ultra-moe-718b.json).
+BACKBONE_TOL = 0.05
 
 
 def check(cond: bool, message: str) -> None:
@@ -771,6 +775,70 @@ def phase_kernels(interpret: bool = False, *,
 
 
 # ---------------------------------------------------------------------------
+# Phase: backbone
+
+
+def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
+                   seed: int = 36) -> dict:
+    """The ``pangu`` session head at its published widths (one dense and
+    four expert layers of latent attention, 8 of 256 routed experts held:
+    6.23 GB) against its plain reference (chipbench/heads/
+    openpangu_ultra.py, float32 at ``highest`` over bfloat16-rounded
+    operands) on one block of ``rows`` windows, and which core ran the
+    held experts' grouped products (chosen while tracing)."""
+    import gc
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import reference, validate
+    from igaming_platform_tpu.models import keye_backbone, pangu_backbone
+    from igaming_platform_tpu.models.session_heads import PANGU_CONFIG
+
+    cfg = cfg or PANGU_CONFIG
+    config = config or validate.load_data(
+        "configs", "risk-seqhead-openpangu-ultra-moe-718b")
+    head = validate.load_code("heads", "openpangu_ultra")
+    params = head.make_params(seed, config)
+    rng = np.random.default_rng(seed)
+    lengths = rng.integers(1, 17, rows).astype(np.int32)
+    win = rng.normal(0.0, 1.0, (rows, 16, cfg.in_dim)).astype(np.float32)
+    win *= (np.arange(16)[None, :] < lengths[:, None])[..., None]
+
+    said: list[str] = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    keye_backbone._announce_core.cache_clear()
+    keye_backbone.logger.addHandler(handler)
+    level = keye_backbone.logger.level
+    keye_backbone.logger.setLevel(logging.INFO)
+    try:
+        got = np.asarray(jax.jit(
+            lambda p, w, l: pangu_backbone.backbone_scores(p, w, l, cfg))(
+                params, jnp.asarray(win), jnp.asarray(lengths)))
+    finally:
+        keye_backbone.logger.removeHandler(handler)
+        keye_backbone.logger.setLevel(level)
+    want = head.forward(params, win, lengths, reference.rounder(
+        jnp.dtype(cfg.operand_dtype).name))
+    err = float(np.max(np.abs(got - want)))
+    cores = [m for m in said if m.startswith("expert core: ")]
+    report = {"device": device_stamp(), "rows": rows, "max_err": err,
+              "resident_bytes": sum(int(a.nbytes) for a in jax.tree.leaves(params)),
+              "expert_core": cores[0] if cores else None,
+              "scores_spread": float(np.std(want))}
+    del params
+    gc.collect()
+    check(bool(np.all(np.isfinite(got))) and err <= BACKBONE_TOL,
+          f"pangu head vs its reference on {rows} windows: max err {err} "
+          f"> {BACKBONE_TOL}")
+    check(bool(cores), "the expert layer announced no core")
+    return report
+
+
+# ---------------------------------------------------------------------------
 # Phase: mesh
 
 
@@ -851,6 +919,7 @@ def main() -> int:
     reports["trainer"] = one_chip.pop("trainer")  # ran beside the live server
     print(_summary_line("trainer", reports["trainer"]), flush=True)
     run("kernels", phase_kernels)
+    run("backbone", phase_backbone)
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

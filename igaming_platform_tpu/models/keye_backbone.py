@@ -79,11 +79,29 @@ class BackboneConfig:
     operand_dtype: Any = jnp.bfloat16
 
 
+# The most float32 normals one draw of ``_matrix`` makes (2^24: 64 MB).
+_DRAW_ELEMS = 1 << 24
+
+
+def row_blocks(rows: int, cols: int) -> int:
+    """In how many equal row blocks a [rows, cols] matrix is drawn so that
+    no draw passes ``_DRAW_ELEMS`` elements: the least divisor of ``rows``
+    that leaves blocks of whole bfloat16 tiles (16 rows), 1 where the
+    matrix is small or has no such divisor."""
+    need = -(-rows * cols // _DRAW_ELEMS)
+    if need <= 1:
+        return 1
+    return next((b for b in range(need, rows // 16 + 1)
+                 if rows % (16 * b) == 0), 1)
+
+
 @partial(jax.jit, static_argnums=(1, 2))
 def _matrix(key, shape: tuple[int, ...], fan_in: int):
     """Seeded normals scaled by ``fan_in ** -0.5``, in bfloat16. A stacked
-    weight ([experts, ...]) is generated slice by slice (``lax.map``), so
-    the float32 normals never exceed one expert's matrix."""
+    weight ([experts, ...]) is generated slice by slice (``lax.map``), and
+    a matrix of more than ``_DRAW_ELEMS`` elements row block by row block,
+    so the float32 normals never exceed one expert's matrix or one
+    block."""
     def draw(k, shp):
         return (jax.random.normal(k, shp, jnp.float32)
                 * (1.0 / math.sqrt(fan_in))).astype(jnp.bfloat16)
@@ -91,6 +109,10 @@ def _matrix(key, shape: tuple[int, ...], fan_in: int):
     if len(shape) == 3:
         return jax.lax.map(lambda k: draw(k, shape[1:]),
                            jax.random.split(key, shape[0]))
+    blocks = row_blocks(*shape)
+    if blocks > 1:
+        return jax.lax.map(lambda k: draw(k, (shape[0] // blocks, shape[1])),
+                           jax.random.split(key, blocks)).reshape(shape)
     return draw(key, shape)
 
 
@@ -280,22 +302,96 @@ def _expert_products(xs, sizes, layer: Params, cfg: BackboneConfig):
     return grouped(mid.astype(dt), layer["wd"])
 
 
-def grouped_experts(x, top_e, top_w, layer: Params, cfg: BackboneConfig):
-    """Dropless expert layer over positions ``x`` [P, hidden]: every
-    (position, expert) pair the router chose is computed. The pairs are
-    sorted by expert, so each expert's rows are contiguous, and the three
-    products run grouped over the stacked weights (``_expert_products``);
-    the results return to position order by the inverse permutation and
-    are summed over a position's experts in float32."""
+# Rows one pass of a share's pairs is rounded up to: the expert kernels'
+# row tile (ops/pallas/grouped_experts._tiles).
+_PASS_TILE = 256
+
+
+def pass_rows(pairs: int, held: int, experts: int) -> int:
+    """The static bound on the rows one pass over a share's pairs gathers
+    and multiplies: four times the share's expected pairs at uniform
+    routing, rounded up to the kernels' tile, and never more than all the
+    pairs (which it is where every expert is held)."""
+    share = -(-4 * pairs * held // experts)
+    return min(pairs, _PASS_TILE * -(-share // _PASS_TILE))
+
+
+def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
+                    live=None):
+    """Dropless expert layer over positions ``x`` [P, hidden] for the
+    experts HELD HERE: the stacked weights of ``layer`` are experts
+    ``first_expert ..`` of ``cfg.experts`` (all of them, or a chip's
+    share; how many is the weights' leading size). The router chose over
+    all experts and normalised its weights over all it chose; every
+    (position, expert) pair whose expert is held is computed, whatever
+    the routing, and a pair whose expert lies elsewhere is never gathered
+    or multiplied: what it would add is left out, and nothing stands in
+    for the chip that holds it. With ``live`` [P] bool (a share only) the
+    pairs of positions that are not live, a window's padding, are left
+    out the same way.
+
+    The pairs are sorted by local expert (absent ones take a key past the
+    last and sort behind), so each held expert's rows are contiguous and
+    the three products run grouped over the stacked weights
+    (``_expert_products``).
+
+    - Every expert held: one pass over all pairs; the results return to
+      position order by the inverse permutation and are summed over a
+      position's experts in float32.
+    - A share: the held pairs are worked ``pass_rows`` at a time by a loop
+      whose trip count is ``ceil(held pairs / pass_rows)`` (one pass at a
+      routing anywhere near uniform, more under skew, none where no pair
+      is held). Each pass gathers its rows, multiplies them, and every
+      position takes its own pairs' results back out of the pass, a row
+      gather a slot, times the router's weight, in float32: nothing is
+      scattered (XLA's scatter-add of the same rows took three times as
+      long on a v5e: PERF.md, PR 36). Temporaries are bounded by
+      ``pass_rows``, not by all pairs."""
     n, k = top_e.shape
-    flat_e = top_e.reshape(-1)
+    held = layer["wg"].shape[0]
+    everything = held == cfg.experts
+    if everything:
+        assert live is None, "every position is routed where every expert is held"
+        flat_e = top_e.reshape(-1)
+    else:
+        local = top_e - first_expert
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here = here & live[:, None]
+        flat_e = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(flat_e, stable=True)
-    sizes = jnp.bincount(flat_e, length=cfg.experts).astype(jnp.int32)
-    xs = x.astype(cfg.operand_dtype)[order // k]
-    ys = _expert_products(xs, sizes, layer, cfg)
-    back = jnp.argsort(order)
-    y = ys[back].reshape(n, k, -1)
-    return jnp.sum(y * top_w[..., None], axis=1)
+    # a key past the last held expert is counted by no bin
+    sizes = jnp.bincount(flat_e, length=held).astype(jnp.int32)
+    xb = x.astype(cfg.operand_dtype)
+    if everything:
+        xs = xb[order // k]
+        ys = _expert_products(xs, sizes, layer, cfg)
+        back = jnp.argsort(order)
+        y = ys[back].reshape(n, k, -1)
+        return jnp.sum(y * top_w[..., None], axis=1)
+
+    rows = pass_rows(n * k, held, cfg.experts)
+    rank = jnp.argsort(order).reshape(n, k)  # the sorted row of every pair
+    order = jnp.pad(order, (0, -(n * k) % rows))
+    ends = jnp.cumsum(sizes)
+    starts, n_held = ends - sizes, ends[-1]
+
+    def one_pass(i, y):
+        lo = i * rows
+        pair = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        # this pass's part of every expert's rows; rows past the held pairs
+        # (the last pass's tail) belong to no expert and are read by nobody
+        part = jnp.maximum(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0)
+        ys = _expert_products(xb[pair // k], part, layer, cfg)
+        mine = (rank >= lo) & (rank < jnp.minimum(lo + rows, n_held))
+        at = jnp.clip(rank - lo, 0, rows - 1)
+        for j in range(k):
+            y = y + (jnp.where(mine[:, j, None], ys[at[:, j]], 0.0)
+                     * top_w[:, j, None])
+        return y
+
+    return jax.lax.fori_loop(0, (n_held + rows - 1) // rows, one_pass,
+                             jnp.zeros((n, x.shape[-1]), jnp.float32))
 
 
 def backbone_hidden(params: Params, x, pos3, cfg: BackboneConfig):
@@ -327,6 +423,14 @@ def backbone_scores(params: Params, window, lengths, cfg: BackboneConfig):
     b, t, _ = window.shape
     pos3 = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (3, b, t))
     hid = backbone_hidden(params, window, pos3, cfg)
+    return score_last(params, hid, lengths)
+
+
+def score_last(params: Params, hid, lengths):
+    """The scoring head on final-normed hidden states ``hid`` [B, T,
+    hidden]: the sigmoid of one float32 output column at each window's
+    last real position."""
+    t = hid.shape[1]
     last = jnp.clip(lengths.astype(jnp.int32) - 1, 0, t - 1)
     hl = jnp.take_along_axis(hid, last[:, None, None], axis=1)[:, 0, :]
     # one output column: a float32 multiply-reduce, never the MXU

@@ -1,0 +1,252 @@
+"""A latent-attention decoder backbone over the session window, with a
+chip's share of its routed experts (the ``pangu`` session head,
+models/session_heads.py).
+
+The block is openPangu-Ultra-MoE-718B's decoder layer at the published
+widths by default: hidden 7680, 128 heads, a query latent of 1536 and a
+key-value latent of 512, query-key width 128 + 64 (the 64 rotary, one
+rotary key head shared by every head) against value width 128, sandwich
+norm, a leading dense layer of width 18,432, then layers with a shared
+expert and 256 routed experts of width 2,048, 8 a token. Events enter as
+``inputs_embeds`` through a projector (``x @ W_in``, 12 -> hidden); the
+score is a sequence-classification head on the last real position. Each
+layer ``l``, over the residual stream ``h`` [B, T, hidden] (float32; ``N``
+an RMSNorm):
+
+1. ``a = N1(h)``. Query: ``cq = Nq(a Wq_a)``; ``q = cq Wq_b`` -> heads of
+   ``[q_nope | q_rope]``. Key-value: ``a Wkv_a`` -> ``[ckv | k_rope]``;
+   ``ckv = Nkv(ckv)``; ``ckv Wkv_b`` -> heads of ``[k_nope | v]``. Rotary
+   (rotate-half) on ``q_rope`` per head and on the one ``k_rope``. Scores
+   ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)``, causal,
+   softmax in float32, times ``v``; ``o = concat(heads) Wo``.
+   ``h = h + N2(o)``: the sublayer's OUTPUT is normed before it joins the
+   residual (sandwich norm).
+2. ``b = N3(h)``; ``h = h + N4(MLP(b))``. For ``l < dense_layers`` the MLP
+   is a SwiGLU of width ``dense_width``. Else ``s = sigmoid(b Wr)`` over
+   ALL ``experts`` in float32, the ``top_k`` largest chosen, ``w =
+   s_chosen / (sum s_chosen + 1e-20) * routed_scale``; ``MLP(b) =
+   Shared(b) + sum over the chosen experts HELD HERE of w_e Expert_e(b)``.
+
+**A chip's share.** The deployment divides each layer's routed experts
+over ``experts / held_experts`` chips; this chip holds experts
+``first_expert ..`` (attention, the dense MLP, the shared expert and the
+router whole, as every chip does). The router keeps its published width
+and its experts per token, the weights are normalised over all chosen
+experts, held or not, and the expert layer (the one
+``keye_backbone.grouped_experts`` both heads call) computes the held
+experts' part for the pairs routed to them. What the absent experts would
+add is left out, and that partial result goes on to the next layer; no
+code stands in for the absent chips or their traffic. Positions past a
+window's last real event are not routed (the reference leaves them out
+too): nothing that is scored can read them.
+
+**No latent cache.** Per-slot state is the ``[T, in_dim]`` event window
+and the head recomputes its window every step, so the layer runs in its
+expanded form (keys and values of every head from the latent, every
+step); the compressed ``[ckv | k_rope]`` an account would cache in a
+decoder is not held.
+
+Precision as the ``keye`` head's: parameters bfloat16 at rest (norm gains
+and the scoring head float32); every product multiplies ``operand_dtype``
+operands and accumulates in float32; residual stream, norms, softmax,
+router scores, top-k and the logit are float32.
+
+``jax.named_scope`` marks the parts as the ``keye`` head's do:
+``head/embed``, ``head/attn`` (inside it ``q``, ``kv``, ``core``,
+``out``), ``head/mlp/dense``, ``head/moe/route``, ``head/moe/shared``,
+``head/moe/experts``.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from igaming_platform_tpu.models.keye_backbone import (
+    Params,
+    _matrix,
+    _mm,
+    grouped_experts,
+    mrope_angles,
+    rms_norm,
+    rotate,
+    score_last,
+)
+
+
+@dataclass(frozen=True)
+class PanguConfig:
+    in_dim: int = 12
+    hidden: int = 7680
+    layers: int = 5          # held here: ``dense_layers`` dense, the rest sparse
+    dense_layers: int = 1
+    heads: int = 128
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    dense_width: int = 18432
+    experts: int = 256       # the router's width: all of a layer's experts
+    held_experts: int = 8    # the chip's share, experts first_expert ..
+    first_expert: int = 0
+    top_k: int = 8
+    expert_width: int = 2048
+    routed_scale: float = 2.5
+    rope_theta: float = 25.6e6
+    eps: float = 1e-5
+    # the depth the seeded tree is initialised for: the two post-norm gains
+    # (N2, N4) start at 1 / sqrt(2 * init_depth), the published 61 layers
+    init_depth: int = 61
+    operand_dtype: Any = jnp.bfloat16
+
+
+def init_backbone(key, cfg: PanguConfig) -> Params:
+    """A seeded tree, built on the device one matrix at a time and held in
+    bfloat16 (``keye_backbone._matrix``: a stacked weight slice by slice, a
+    large matrix row block by row block, so nothing of it exists in
+    float32 beyond 64 MB). Every matrix keeps its input's variance
+    (``fan_in ** -0.5``); the two post-norm gains of a layer, which scale
+    what a sublayer adds to the residual stream, start at ``1 / sqrt(2 *
+    init_depth)``: the depth-scaled sandwich norm."""
+    f32 = jnp.float32
+    d, f = cfg.hidden, cfg.expert_width
+    qk = cfg.nope_dim + cfg.rope_dim
+    keys = iter(jax.random.split(key, 2 + 12 * cfg.layers))
+    post = 1.0 / math.sqrt(2.0 * cfg.init_depth)
+
+    def matrix(shape, fan_in):
+        return _matrix(next(keys), shape, fan_in)
+
+    def swiglu(width, stack=()):
+        return {"wg": matrix((*stack, d, width), d),
+                "wu": matrix((*stack, d, width), d),
+                "wd": matrix((*stack, width, d), width)}
+
+    layers = []
+    for i in range(cfg.layers):
+        layer = {
+            "g1": jnp.ones((d,), f32), "g2": jnp.full((d,), post, f32),
+            "g3": jnp.ones((d,), f32), "g4": jnp.full((d,), post, f32),
+            "wq_a": matrix((d, cfg.q_rank), d),
+            "qn": jnp.ones((cfg.q_rank,), f32),
+            "wq_b": matrix((cfg.q_rank, cfg.heads * qk), cfg.q_rank),
+            "wkv_a": matrix((d, cfg.kv_rank + cfg.rope_dim), d),
+            "kvn": jnp.ones((cfg.kv_rank,), f32),
+            "wkv_b": matrix((cfg.kv_rank, cfg.heads * (cfg.nope_dim + cfg.v_dim)),
+                            cfg.kv_rank),
+            "wo": matrix((cfg.heads * cfg.v_dim, d), cfg.heads * cfg.v_dim),
+        }
+        if i < cfg.dense_layers:
+            layer["dense"] = swiglu(cfg.dense_width)
+        else:
+            layer["wr"] = matrix((d, cfg.experts), d)
+            layer["shared"] = swiglu(f)
+            layer["routed"] = swiglu(f, (cfg.held_experts,))
+        layers.append(layer)
+    return {
+        "embed": matrix((cfg.in_dim, d), cfg.in_dim),
+        "layers": layers,
+        "gf": jnp.ones((d,), f32),
+        "head": {"w": jax.random.normal(next(keys), (d, 1), f32)
+                 * (1.0 / math.sqrt(d)),
+                 "b": jnp.zeros((1,), f32)},
+    }
+
+
+def latent_attention(a, layer: Params, cos, sin, cfg: PanguConfig):
+    """Multi-head latent attention over normed hidden states ``a`` [B, T,
+    hidden], in its expanded form -> [B, T, hidden] (before the
+    post-norm)."""
+    b, t, _ = a.shape
+    nh, nope, rope, dv = cfg.heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim
+    dt = cfg.operand_dtype
+    with jax.named_scope("q"):
+        cq = rms_norm(_mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
+        q = _mm(cq, layer["wq_b"], cfg).reshape(b, t, nh, nope + rope)
+        q_nope, q_rope = q[..., :nope], rotate(q[..., nope:], cos, sin)
+    with jax.named_scope("kv"):
+        kv = _mm(a, layer["wkv_a"], cfg)
+        ckv = rms_norm(kv[..., :cfg.kv_rank], layer["kvn"], cfg.eps)
+        # one rotary key head, shared by every query head
+        k_rope = rotate(kv[..., None, cfg.kv_rank:], cos, sin)[:, :, 0, :]
+        kvb = _mm(ckv, layer["wkv_b"], cfg).reshape(b, t, nh, nope + dv)
+        k_nope, v = kvb[..., :nope], kvb[..., nope:]
+    with jax.named_scope("core"):
+        sc = (jnp.einsum("bthd,bshd->bhts", q_nope.astype(dt), k_nope.astype(dt),
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt), k_rope.astype(dt),
+                           preferred_element_type=jnp.float32))
+        sc = sc * ((nope + rope) ** -0.5)
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+        o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), v.astype(dt),
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("out"):
+        return _mm(o.reshape(b, t, nh * dv), layer["wo"], cfg)
+
+
+def swiglu(x, w: Params, cfg: PanguConfig):
+    """``(silu(x Wg) * x Wu) Wd``; the product between is rounded once to
+    the operands' dtype, as the expert kernels round theirs."""
+    mid = jax.nn.silu(_mm(x, w["wg"], cfg)) * _mm(x, w["wu"], cfg)
+    return _mm(mid, w["wd"], cfg)
+
+
+def route(x, layer: Params, cfg: PanguConfig):
+    """Sigmoid router over ALL experts, no groups, no correction bias:
+    ``(experts [P, top_k] int32, weights [P, top_k] float32)``, the weights
+    the chosen scores over their sum (held or not), times
+    ``routed_scale``."""
+    s = jax.nn.sigmoid(_mm(x, layer["wr"], cfg))
+    top_s, top_e = jax.lax.top_k(s, cfg.top_k)
+    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return top_e, w * cfg.routed_scale
+
+
+def backbone_hidden(params: Params, x, lengths, cfg: PanguConfig):
+    """[B, T, in_dim] events, [B] real events a window -> final-normed
+    hidden states [B, T, hidden] (float32); position ``t`` of a window is
+    its rotary position. A window's padding (positions past its length,
+    which under causal attention no real position reads) goes through
+    attention and the dense and shared MLPs with the rest of the batch
+    but is not routed: it has no pair in the held experts."""
+    b, t, _ = x.shape
+    live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(b * t)
+    with jax.named_scope("head/embed"):
+        h = _mm(x, params["embed"], cfg)
+        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
+        cos, sin = mrope_angles(pos, cfg.rope_dim, (cfg.rope_dim // 2,),
+                                cfg.rope_theta)
+    for layer in params["layers"]:
+        with jax.named_scope("head/attn"):
+            a = rms_norm(h, layer["g1"], cfg.eps)
+            o = latent_attention(a, layer, cos, sin, cfg)
+            h = h + rms_norm(o, layer["g2"], cfg.eps)
+        flat = rms_norm(h, layer["g3"], cfg.eps).reshape(b * t, -1)
+        if "dense" in layer:
+            with jax.named_scope("head/mlp/dense"):
+                m = swiglu(flat, layer["dense"], cfg)
+        else:
+            with jax.named_scope("head/moe/route"):
+                top_e, top_w = route(flat, layer, cfg)
+            with jax.named_scope("head/moe/shared"):
+                m = swiglu(flat, layer["shared"], cfg)
+            with jax.named_scope("head/moe/experts"):
+                m = m + grouped_experts(flat, top_e, top_w, layer["routed"],
+                                        cfg, cfg.first_expert, live)
+        h = h + rms_norm(m, layer["g4"], cfg.eps).reshape(b, t, -1)
+    return rms_norm(h, params["gf"], cfg.eps)
+
+
+def backbone_scores(params: Params, window, lengths, cfg: PanguConfig):
+    """The session head: window [B, T, in_dim] (real events first, zeros
+    after), lengths [B] -> [B] probability, read at the last real
+    position, which under causal attention no padded position can
+    reach."""
+    lengths = lengths.astype(jnp.int32)
+    return score_last(params, backbone_hidden(params, window, lengths, cfg), lengths)

@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from igaming_platform_tpu.models import pangu_backbone
 from igaming_platform_tpu.models.keye_backbone import (
     BackboneConfig,
     backbone_scores,
@@ -119,12 +120,39 @@ def keye_scores(sparams, window, lengths):
     return backbone_scores(sparams, window, lengths, KEYE_CONFIG)
 
 
+# SESSION_HEAD=pangu: one dense and four expert layers of a latent-attention
+# backbone at its published widths, with a chip's share of the routed
+# experts (models/pangu_backbone.py: 8 of 256 held, all 256 routed over):
+# 3.11 G parameters, 6.23 GB in bfloat16 beside the state.
+PANGU_CONFIG = pangu_backbone.PanguConfig()
+
+
+def init_pangu_params(seed: int = _SESSION_HEAD_SEED):
+    """The pinned seeded tree of the ``pangu`` head, built on the device
+    in bfloat16, a matrix (or a block of one) at a time."""
+    return pangu_backbone.init_backbone(jax.random.key(seed), PANGU_CONFIG)
+
+
+def pangu_scores(sparams, window, lengths):
+    """The ``pangu`` head: the backbone over the window, scored at the
+    last real position."""
+    return pangu_backbone.backbone_scores(sparams, window, lengths, PANGU_CONFIG)
+
+
 # SESSION_HEAD name -> (head_fn(sparams, window, lengths), init_params()).
 HEADS = {
     "pattern": (lambda sparams, win, lp: pattern_scores(win, lp),
                 lambda: None),
     "transformer": (transformer_scores, init_session_head_params),
     "keye": (keye_scores, init_keye_params),
+    "pangu": (pangu_scores, init_pangu_params),
+}
+
+# SESSION_HEAD name -> (routed experts a layer held on this chip, experts
+# its router chooses among); a head without an expert layer has no row.
+HEAD_EXPERTS = {
+    "keye": (KEYE_CONFIG.experts, KEYE_CONFIG.experts),
+    "pangu": (PANGU_CONFIG.held_experts, PANGU_CONFIG.experts),
 }
 
 

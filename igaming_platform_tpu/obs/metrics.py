@@ -775,6 +775,24 @@ class ServiceMetrics:
             "session_head_positions_total it is what the padding of short "
             "windows costs the head",
         )
+        self.session_head_resident_bytes = self.registry.gauge(
+            f"{service}_session_head_resident_bytes",
+            "Device bytes of the session head's parameter tree (0 for a "
+            "head without parameters), set once at boot: beside "
+            "session_hbm_bytes and the feature table it is what the chip "
+            "holds at rest",
+        )
+        self.session_head_experts_held = self.registry.gauge(
+            f"{service}_session_head_experts_held",
+            "Routed experts a layer of the session head held on this chip "
+            "(0 for a head without an expert layer), set once at boot",
+        )
+        self.session_head_experts_routed = self.registry.gauge(
+            f"{service}_session_head_experts_routed",
+            "Experts the session head's router chooses among: with "
+            "session_head_experts_held under it the chip holds a share of "
+            "each layer and computes that share's part of the result",
+        )
         self.session_lock_wait_seconds_total = self.registry.counter(
             f"{service}_session_lock_wait_seconds_total",
             "Seconds index-mode chunks waited for the session lock "

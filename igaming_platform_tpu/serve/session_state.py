@@ -59,7 +59,7 @@ from typing import Any
 import numpy as np
 
 from igaming_platform_tpu.models.sequence import EVENT_DIM
-from igaming_platform_tpu.models.session_heads import session_head
+from igaming_platform_tpu.models.session_heads import HEAD_EXPERTS, session_head
 
 # Per-event layout: models/sequence.encode_event — [log-amount, log-dt,
 # 8-way tx-type one-hot, game-weight, balance-ratio].
@@ -475,6 +475,11 @@ class SessionStateManager:
             else default_flag_threshold())
         self.head = (head or os.environ.get("SESSION_HEAD", "pattern")).lower()
         self.head_fn, self.head_params = session_head(self.head)
+        # what the head holds, fixed at boot: its tree's device bytes and,
+        # where it has an expert layer, (experts held here, experts routed)
+        self.head_resident_bytes = sum(
+            int(a.nbytes) for a in jax.tree.leaves(self.head_params))
+        self.head_experts = HEAD_EXPERTS.get(self.head, (0, 0))
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}
@@ -526,6 +531,7 @@ class SessionStateManager:
         self.session_ring = ring
         self.session_cursor = cursor
         self.session_length = length
+        self._export_head()
 
     # -- metrics / surfaces ---------------------------------------------------
 
@@ -539,6 +545,15 @@ class SessionStateManager:
                          self.head_real_positions)
             metrics.session_lock_wait_seconds_total.inc(self.lock_wait_s)
             metrics.session_lock_held_seconds_total.inc(self.lock_held_s)
+        self._export_head()
+
+    def _export_head(self) -> None:
+        """What the head holds, fixed at boot: three gauges."""
+        m = self._metrics
+        if m is not None:
+            m.session_head_resident_bytes.set(self.head_resident_bytes)
+            m.session_head_experts_held.set(self.head_experts[0])
+            m.session_head_experts_routed.set(self.head_experts[1])
 
     def _export(self, warm: int, cold: int, bypass: int, appends: int,
                 rehydrations: int, regrows: int = 0,
@@ -602,6 +617,9 @@ class SessionStateManager:
                 "twin_regrows": self.twin_regrows,
                 "head_positions": self.appends * self.n_events,
                 "head_real_positions": self.head_real_positions,
+                "head_resident_bytes": self.head_resident_bytes,
+                "head_experts_held": self.head_experts[0],
+                "head_experts_routed": self.head_experts[1],
                 "lock_wait_s": self.lock_wait_s,
                 "lock_held_s": self.lock_held_s,
                 "rehydrations": self.rehydrations,

@@ -191,6 +191,52 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     assert "%ragged-dot-none" not in text
 
 
+def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
+        topo, tpu_backend, capsys):
+    """The fused step with the ``pangu`` backbone in it, at the cell's size
+    (3,145,728 accounts, one 256-row rung): in place on the ring, its
+    arguments are the state plus 6.23 GB of bfloat16 weights, and its
+    temporaries leave the chip room: the held experts' pass is bounded by
+    ``pass_rows`` (4,096 rows), not by the 32,768 pairs, so nothing of
+    [32768, 7680] exists. One expert's gate and up are 63 MB a slot, over
+    what the Pallas kernels hold in VMEM, so the held experts' products
+    are XLA's grouped product, inside the pass loop."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.keye_backbone import pass_rows
+    from igaming_platform_tpu.models.session_heads import PANGU_CONFIG as cfg
+    from igaming_platform_tpu.serve import session_state as ss
+
+    capacity = 3_145_728
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step("pangu", capacity, capacity + 1, one, one)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\npangu step for a described v5e: code "
+              f"{mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 9.0e9 < mem.argument_size_in_bytes < 9.1e9, mem
+    assert mem.temp_size_in_bytes < 4 * 2**30, mem
+    pairs = BATCH * ss.default_events() * cfg.top_k
+    assert pass_rows(pairs, cfg.held_experts, cfg.experts) == 4096
+    text = compiled.as_text()
+    assert f"[{pairs},{cfg.hidden}]" not in text
+    assert "ragged-dot" in text and "head/moe/experts" in text
+    # XLA's grouped product is a Mosaic custom call of its own
+    # (`%ragged-dot-none`); the in-tree kernels (ops/pallas/grouped_experts),
+    # whose calls carry `pallas_call` in their op_name, are not there.
+    # (Calls, not names: the module's table of function names is the
+    # process's, and holds `_gate_up` once the keye step was traced in it.)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len([c for c in calls if "%ragged-dot-none" in c]) == 12, calls
+    assert not [c for c in calls if "pallas_call" in c], calls
+
+
 def test_admission_sync_writes_the_ring_in_place(topo):
     from jax.sharding import SingleDeviceSharding
 

@@ -278,6 +278,105 @@ def test_expert_core_is_announced_and_falls_back_where_the_kernels_do_not_fit(
         "expert core: xla-ragged-dot (backend=tpu)"]
 
 
+# -- a chip's share of the experts (the layer both backbones call) --------------
+
+
+def _layer_as_before(x, top_e, top_w, layer, cfg):
+    """The expert layer as PR 35 left it, written out: every expert held,
+    one pass, the return to position order by the inverse permutation."""
+    n, k = top_e.shape
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    sizes = jnp.bincount(flat_e, length=cfg.experts).astype(jnp.int32)
+    xs = x.astype(cfg.operand_dtype)[order // k]
+    ys = kb._expert_products(xs, sizes, layer, cfg)
+    back = jnp.argsort(order)
+    y = ys[back].reshape(n, k, -1)
+    return jnp.sum(y * top_w[..., None], axis=1)
+
+
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_keye_head_is_unchanged_bit_for_bit_by_the_shared_layer(
+        head, operands, monkeypatch):
+    """Where every expert is held the layer that now also serves a share
+    computes what it computed: the same bits from the layer and from the
+    whole head."""
+    cfg = small_config(operand_dtype=jnp.dtype(operands))
+    params = head.make_params(5, SMALL_SOURCE)
+    layer = params["layers"][1]
+    x = jax.random.normal(jax.random.key(8), (160, cfg.hidden), jnp.float32)
+    top_e, top_w = kb.route(x, layer, cfg)
+    now = jax.jit(lambda x, e, w: kb.grouped_experts(x, e, w, layer, cfg))
+    before = jax.jit(lambda x, e, w: _layer_as_before(x, e, w, layer, cfg))
+    np.testing.assert_array_equal(np.asarray(now(x, top_e, top_w)),
+                                  np.asarray(before(x, top_e, top_w)))
+    win, lens = windows(24, (1, 4, 16, 7, 9, 2), seed=4)
+    got = program_scores(cfg, params, win, lens)
+    monkeypatch.setattr(kb, "grouped_experts", _layer_as_before)
+    np.testing.assert_array_equal(got, program_scores(cfg, params, win, lens))
+
+
+def test_pass_rows_bound_a_share_and_cover_a_whole_layer():
+    # the cell's share: 32,768 pairs, 8 of 256 held -> 4 x 1,024 expected
+    assert kb.pass_rows(32768, 8, 256) == 4096
+    # every expert held: one pass over all the pairs, as before
+    assert kb.pass_rows(32768, 128, 128) == 32768
+    assert kb.pass_rows(2048, 4, 32) == 1024
+    assert kb.pass_rows(192, 4, 16) == 192   # never more than all pairs
+    assert kb.pass_rows(4096, 1, 256) == 256  # rounded up to the kernels' tile
+
+
+@pytest.mark.parametrize("expert_core", ["xla", "pallas"], indirect=True)
+@pytest.mark.parametrize("routing", ["all-held", "none-held", "free", "ragged"])
+def test_a_share_is_dropless_at_any_routing(routing, expert_core):
+    """Experts 8-11 of 32 held, 512 positions x 4: ``pass_rows`` is 1,024
+    of 2,048 pairs. ``all-held``: every pair is on a held expert, two full
+    passes; ``ragged``: 1,500 held pairs, the second pass part empty and
+    experts split across the passes; ``none-held``: no pass at all, exact
+    zeros; ``free``: the router's own choice, one pass. Each against the
+    float64 loop over the held experts alone, weights as the router
+    normalised them over ALL chosen experts."""
+    sizes, tolerance, _ = expert_core
+    experts, held, first, k, n = 32, 4, 8, 4, 512
+    cfg = small_config(experts=experts, top_k=k, **sizes)
+    whole = kb.init_backbone(jax.random.key(2), cfg)["layers"][0]
+    share = {key: whole[key][first:first + held] for key in ("wg", "wu", "wd")}
+    x = jax.random.normal(jax.random.key(3), (n, cfg.hidden), jnp.float32)
+    rng = np.random.default_rng(6)
+    inside = first + np.stack([rng.permutation(held) for _ in range(n)])
+    outside = np.stack([rng.choice(np.r_[0:first, first + held:experts], k,
+                                   replace=False) for _ in range(n)])
+    if routing == "free":
+        top_e, top_w = kb.route(x, whole, cfg)
+    else:
+        top_e = {"all-held": inside, "none-held": outside,
+                 "ragged": np.where(np.arange(n * k).reshape(n, k) < 1500,
+                                    inside, outside)}[routing]
+        top_w = rng.dirichlet(np.ones(k), n).astype(np.float32)
+        top_e, top_w = jnp.asarray(top_e, jnp.int32), jnp.asarray(top_w)
+    here = (np.asarray(top_e) >= first) & (np.asarray(top_e) < first + held)
+    assert int(here.sum()) == {"all-held": 2048, "none-held": 0,
+                               "ragged": 1500}.get(routing, int(here.sum()))
+    assert kb.pass_rows(n * k, held, experts) == 1024
+    got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
+        x, e, w, share, cfg, first))(x, top_e, top_w))
+    local = np.where(here, np.asarray(top_e) - first, held)  # held: no expert
+    want = _expert_loop(x, local, np.where(here, np.asarray(top_w), 0.0), share)
+    if routing == "none-held":
+        assert not got.any()
+    else:
+        assert np.abs(want).max() > 1e-3
+    np.testing.assert_allclose(got, want, atol=tolerance * max(np.abs(want).max(), 1),
+                               rtol=0)
+    # a pair of the second pass is there: leaving it out shows
+    if routing in ("all-held", "ragged"):
+        pos = 300  # its pairs sort behind the first 1,024 held ones or among them
+        less_w = np.where(here, np.asarray(top_w), 0.0)
+        less_w[pos] = 0.0
+        less = _expert_loop(x, local, less_w, share)
+        assert np.abs(less[pos] - got[pos]).max() > 10 * np.abs(want - got).max()
+
+
 # -- the served path ------------------------------------------------------------
 
 
