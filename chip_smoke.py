@@ -69,6 +69,8 @@ EXPERTS_TOL = 2.0 ** -7
 # cell's output check holds the worst single row to (fraud_prob_max_err
 # in chipbench/configs/risk-seqhead-openpangu-ultra-moe-718b.json).
 BACKBONE_TOL = 0.05
+# combine against a gather and a weighted sum: float32 summation order alone
+COMBINE_TOL = 1e-5
 
 
 def check(cond: bool, message: str) -> None:
@@ -666,22 +668,52 @@ def phase_trainer(server, score_batch, before: dict, compiles, *,
 # Phase: kernels
 
 
+def _said_by_the_expert_layer(fn) -> list[str]:
+    """What ``models/keye_backbone`` announces while ``fn`` runs: the
+    cores it picks while tracing (``expert core: ...``, ``combine: ...``),
+    each once."""
+    import logging
+
+    from igaming_platform_tpu.models import keye_backbone
+
+    said: list[str] = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    keye_backbone._announce_core.cache_clear()
+    keye_backbone.logger.addHandler(handler)
+    level = keye_backbone.logger.level
+    keye_backbone.logger.setLevel(logging.INFO)
+    try:
+        fn()
+    finally:
+        keye_backbone.logger.removeHandler(handler)
+        keye_backbone.logger.setLevel(level)
+    return said
+
+
 def phase_kernels(interpret: bool = False, *,
                   attention_shapes: tuple = ((1, 2, 64, 32), (8, 2, 256, 32),
                                              (2, 8, 2048, 16), (1, 8, 8192, 16)),
                   backward_shape: tuple = (2, 8, 2048, 16),
                   gbdt_batch: int = 8192, gbdt_tile: int = 256,
-                  expert_shape: tuple = (32768, 2048, 768, 128)) -> dict:
+                  expert_shape: tuple = (32768, 2048, 768, 128),
+                  top_k: int = 8,
+                  share_shape: tuple = (2048, 7680, 4096, 1000)) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
     [8192, 30], the grouped expert products at the ``keye`` head's
-    (rows, hidden, width, experts) — each against its XLA reference."""
+    (rows, hidden, width, experts) and their way back to position order
+    (``combine``) at both backbones' shapes: every slot of the ``keye``
+    head's rows / ``top_k`` positions, and a pass of the ``pangu`` head's
+    share (rows, hidden, positions, slots taken) — each against its XLA
+    reference, with the way back a trace would pick there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from igaming_platform_tpu.core.features import NUM_FEATURES
+    from igaming_platform_tpu.models import keye_backbone
     from igaming_platform_tpu.models.gbdt import gbdt_raw, init_gbdt
     from igaming_platform_tpu.ops.gbdt_matmul import gbdt_raw_matmul, precompute_selector
     from igaming_platform_tpu.ops.pallas import flash_attention as fa
@@ -768,9 +800,54 @@ def phase_kernels(interpret: bool = False, *,
     mid32, mid_ref32 = mid.astype(jnp.float32), mid_ref.astype(jnp.float32)
     errs = [float(jnp.max(jnp.abs(mid32 - mid_ref32)) / jnp.max(jnp.abs(mid_ref32))),
             float(jnp.max(jnp.abs(ys - ys_ref)) / jnp.max(jnp.abs(ys_ref)))]
+    # the same numbers with every row one piece of memory, as ``combine`` reads them
+    whole = ge.down(mid_ref, wd, sizes, whole_rows=True, interpret=interpret)
+    errs.append(float(jnp.max(jnp.abs(whole.reshape(ys.shape) - ys))))
     report[f"grouped_experts_M{rows}_E{experts}"] = errs
     check(max(errs) <= EXPERTS_TOL,
           f"grouped experts {expert_shape}: max err {errs} > {EXPERTS_TOL}")
+
+    def way_back(label, ys, at, weights, take, want):
+        """``combine`` against the XLA expressions it replaces."""
+        picked = _said_by_the_expert_layer(
+            lambda: keye_backbone._combine_by_kernel(ys, at, take))
+        got = ge.combine(ys, at, weights, take, interpret=interpret)
+        err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+        report[label] = {"max_err": err, "way_back": picked[0]}
+        check(bool(jnp.all(jnp.isfinite(got))) and err <= COMBINE_TOL,
+              f"{label}: max err {err} > {COMBINE_TOL}")
+
+    ks = jax.random.split(jax.random.key(top_k), 4)
+    positions = rows // top_k
+    at = jax.random.permutation(ks[0], rows).astype(jnp.int32).reshape(
+        positions, top_k)
+    weights = jax.random.uniform(ks[1], at.shape, jnp.float32)
+    way_back(f"combine_M{rows}_H{hidden}", whole, at, weights, None, jax.jit(
+        lambda ys, at, w: jnp.sum(ys[at.reshape(-1)].reshape(*at.shape, -1)
+                                  * w[..., None], axis=1))(ys, at, weights))
+    del xs, wg, wu, wd, mid, mid_ref, ys, ys_ref, whole
+
+    rows, hidden, positions, taken = share_shape
+    slots = jax.random.permutation(ks[2], positions * top_k)[:taken]
+    take = jnp.zeros((positions * top_k,), bool).at[slots].set(True)
+    at = jnp.full((positions * top_k,), rows - 1, jnp.int32).at[slots].set(
+        jax.random.permutation(ks[3], rows)[:taken].astype(jnp.int32))
+    take, at = take.reshape(positions, top_k), at.reshape(positions, top_k)
+    ys = jax.random.normal(ks[0], (rows, hidden), jnp.float32)
+    weights = jax.random.uniform(ks[1], at.shape, jnp.float32)
+
+    def a_slot_a_gather(ys, at, take, w):
+        y = jnp.zeros((positions, hidden), jnp.float32)
+        for j in range(top_k):
+            y = y + jnp.where(take[:, j, None], ys[at[:, j]], 0.0) * w[:, j, None]
+        return y
+
+    want = jax.jit(a_slot_a_gather)(ys, at, take, weights)
+    # a row no taken slot names holds a NaN: it must reach nothing
+    owed = jnp.zeros((rows,), bool).at[jnp.where(take, at, rows).reshape(-1)].set(
+        True, mode="drop")
+    way_back(f"combine_share_M{rows}_H{hidden}",
+             jnp.where(owed[:, None], ys, jnp.nan), at, weights, take, want)
     return report
 
 
@@ -787,7 +864,6 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
     operands) on one block of ``rows`` windows, and which core ran the
     held experts' grouped products (chosen while tracing)."""
     import gc
-    import logging
 
     import jax
     import jax.numpy as jnp
@@ -807,34 +883,28 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
     win = rng.normal(0.0, 1.0, (rows, 16, cfg.in_dim)).astype(np.float32)
     win *= (np.arange(16)[None, :] < lengths[:, None])[..., None]
 
-    said: list[str] = []
-    handler = logging.Handler()
-    handler.emit = lambda record: said.append(record.getMessage())
-    keye_backbone._announce_core.cache_clear()
-    keye_backbone.logger.addHandler(handler)
-    level = keye_backbone.logger.level
-    keye_backbone.logger.setLevel(logging.INFO)
-    try:
-        got = np.asarray(jax.jit(
-            lambda p, w, l: pangu_backbone.backbone_scores(p, w, l, cfg))(
-                params, jnp.asarray(win), jnp.asarray(lengths)))
-    finally:
-        keye_backbone.logger.removeHandler(handler)
-        keye_backbone.logger.setLevel(level)
+    got = []
+    said = _said_by_the_expert_layer(lambda: got.append(np.asarray(jax.jit(
+        lambda p, w, l: pangu_backbone.backbone_scores(p, w, l, cfg))(
+            params, jnp.asarray(win), jnp.asarray(lengths)))))
+    got = got[0]
     want = head.forward(params, win, lengths, reference.rounder(
         jnp.dtype(cfg.operand_dtype).name))
     err = float(np.max(np.abs(got - want)))
     cores = [m for m in said if m.startswith("expert core: ")]
+    ways = [m for m in said if m.startswith("combine: ")]
     report = {"device": device_stamp(), "rows": rows, "max_err": err,
               "resident_bytes": sum(int(a.nbytes) for a in jax.tree.leaves(params)),
               "expert_core": cores[0] if cores else None,
+              "way_back": ways[0] if ways else None,
               "scores_spread": float(np.std(want))}
     del params
     gc.collect()
     check(bool(np.all(np.isfinite(got))) and err <= BACKBONE_TOL,
           f"pangu head vs its reference on {rows} windows: max err {err} "
           f"> {BACKBONE_TOL}")
-    check(bool(cores), "the expert layer announced no core")
+    check(bool(cores) and bool(ways),
+          "the expert layer announced no core or no way back")
     return report
 
 

@@ -26,7 +26,11 @@ head on the last real position. Each layer, over ``h`` [B, T, hidden]:
    silu and their product in the epilogue; then down), chosen while
    tracing and announced once (``expert core: ...``); anywhere else as
    three ``lax.ragged_dot`` calls, which stay the kernels' reference and
-   what the CPU tests and replay run. Same arithmetic on both.
+   what the CPU tests and replay run. The results' way back to position
+   order (each position's rows times the router's weights, summed in
+   float32) is chosen the same way (``combine: ...``): a third kernel of
+   that file, ``combine``, which reads each row that is owed once, or
+   XLA's row gather and sum. Same arithmetic on both.
 
 Precision: parameters bfloat16 at rest (norm gains and the scoring head
 float32); every product multiplies ``operand_dtype`` operands and
@@ -269,28 +273,32 @@ def route(x, layer: Params, cfg: BackboneConfig):
 
 
 @lru_cache(maxsize=None)
-def _announce_core(core: str, backend: str) -> None:
-    """Log, once per (core, backend), which core runs the expert layer's
-    grouped products: the choice is made at trace time and is otherwise
-    invisible."""
-    logger.info("expert core: %s (backend=%s)", core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
+def _announce_core(core: str, backend: str, part: str = "expert core") -> None:
+    """Log, once per (part, core, backend), which core runs a part of the
+    expert layer (``expert core``: its grouped products; ``combine``: the
+    results' way back to position order): the choice is made at trace time
+    and is otherwise invisible."""
+    logger.info("%s: %s (backend=%s)", part, core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
 
 
-def _expert_products(xs, sizes, layer: Params, cfg: BackboneConfig):
+def _expert_products(xs, sizes, layer: Params, cfg: BackboneConfig,
+                     whole_rows: bool = False):
     """Rows ``xs`` [M, hidden] sorted by expert, ``sizes`` [E] -> float32
     [M, hidden]: ``(silu(xs @ wg[e]) * (xs @ wu[e])) @ wd[e]`` for each
     row's expert ``e``. On a TPU, at shapes the kernels support, two
     Pallas grouped kernels (ops/pallas/grouped_experts.py: gate and up
-    share one read of the rows, silu and the product in the epilogue);
-    elsewhere three ``lax.ragged_dot`` products, which are also the
-    kernels' golden reference."""
+    share one read of the rows, silu and the product in the epilogue; with
+    ``whole_rows`` the second writes [M, hidden / 128, 128], each row one
+    piece of memory, for ``combine`` to copy row by row); elsewhere three
+    ``lax.ragged_dot`` products, which are also the kernels' golden
+    reference."""
     from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
 
     backend = jax.default_backend()
     if backend == "tpu" and kernels.supports(xs, layer["wg"]):
         _announce_core("pallas-grouped", backend)
         mid = kernels.gate_up(xs, layer["wg"], layer["wu"], sizes)
-        return kernels.down(mid, layer["wd"], sizes)
+        return kernels.down(mid, layer["wd"], sizes, whole_rows=whole_rows)
     _announce_core("xla-ragged-dot", backend)
     dt = cfg.operand_dtype
 
@@ -302,18 +310,53 @@ def _expert_products(xs, sizes, layer: Params, cfg: BackboneConfig):
     return grouped(mid.astype(dt), layer["wd"])
 
 
+def _combine_by_kernel(results, rows, take=None) -> bool:
+    """Whether the results' way back to position order runs as the Pallas
+    ``combine`` (ops/pallas/grouped_experts.py) or as the XLA expressions
+    that stand beside each call, which are its reference and what runs off
+    the TPU. ``results`` [M, hidden] float32 and ``rows`` [P, k], arrays or
+    shapes; ``take`` is given where only some slots are owed. Picked while
+    tracing, from backend and shapes, and announced once a compile."""
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+
+    backend = jax.default_backend()
+    by_kernel = backend == "tpu" and kernels.combine_supports(results, rows, take)
+    _announce_core("pallas-rows" if by_kernel else "xla-gather", backend,
+                   "combine")
+    return by_kernel
+
+
+def expert_sizes(keys, held: int):
+    """How many of ``keys`` [M] name each of the ``held`` experts: int32
+    [held], ``jnp.bincount``'s integers without its scatter of M ones (1.15
+    ms a step in the keye cell: PERF.md, PR 35), as a comparison of every
+    key with every bin, summed over the keys. A key past the last held
+    expert (an absent or a padded pair's) is counted by no bin."""
+    bins = jnp.arange(held, dtype=keys.dtype)
+    return jnp.sum((keys[:, None] == bins).astype(jnp.int32), axis=0)
+
+
 # Rows one pass of a share's pairs is rounded up to: the expert kernels'
 # row tile (ops/pallas/grouped_experts._tiles).
 _PASS_TILE = 256
 
 
-def pass_rows(pairs: int, held: int, experts: int) -> int:
+def pass_rows(pairs: int, held: int, experts: int, hidden: int = 0) -> int:
     """The static bound on the rows one pass over a share's pairs gathers
     and multiplies: four times the share's expected pairs at uniform
-    routing, rounded up to the kernels' tile, and never more than all the
-    pairs (which it is where every expert is held)."""
+    routing, rounded up to the kernels' tile; never more than all the
+    pairs (which it is where every expert is held); and, given the rows'
+    ``hidden`` size, no more tiles than leave a pass's float32 results
+    inside what ``combine`` keeps of them in VMEM for a whole call
+    (ops/pallas/grouped_experts.HELD_RESULTS_BYTES; one tile at least)."""
+    from igaming_platform_tpu.ops.pallas.grouped_experts import HELD_RESULTS_BYTES
+
     share = -(-4 * pairs * held // experts)
-    return min(pairs, _PASS_TILE * -(-share // _PASS_TILE))
+    rows = _PASS_TILE * -(-share // _PASS_TILE)
+    if hidden:
+        fit = HELD_RESULTS_BYTES // (4 * hidden * _PASS_TILE)
+        rows = min(rows, _PASS_TILE * max(fit, 1))
+    return min(pairs, rows)
 
 
 def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
@@ -342,11 +385,19 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
       whose trip count is ``ceil(held pairs / pass_rows)`` (one pass at a
       routing anywhere near uniform, more under skew, none where no pair
       is held). Each pass gathers its rows, multiplies them, and every
-      position takes its own pairs' results back out of the pass, a row
-      gather a slot, times the router's weight, in float32: nothing is
-      scattered (XLA's scatter-add of the same rows took three times as
-      long on a v5e: PERF.md, PR 36). Temporaries are bounded by
-      ``pass_rows``, not by all pairs."""
+      position takes its own pairs' results back out of the pass, times
+      the router's weight, in float32: nothing is scattered (XLA's
+      scatter-add of the same rows took three times as long on a v5e:
+      PERF.md, PR 36). Temporaries are bounded by ``pass_rows``, not by
+      all pairs.
+
+    The way back is ``_combine_by_kernel``'s choice, made while tracing:
+    the Pallas ``combine`` (each owed row read once; a slot that is not
+    taken reads nothing), or the XLA expressions written out below it (a
+    row gather and a sum; for a share a row gather a slot under a
+    ``where``), which are its reference and what runs off the TPU."""
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+
     n, k = top_e.shape
     held = layer["wg"].shape[0]
     everything = held == cfg.experts
@@ -360,18 +411,21 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
             here = here & live[:, None]
         flat_e = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(flat_e, stable=True)
-    # a key past the last held expert is counted by no bin
-    sizes = jnp.bincount(flat_e, length=held).astype(jnp.int32)
+    sizes = expert_sizes(flat_e, held)
     xb = x.astype(cfg.operand_dtype)
+    hidden = x.shape[-1]
+    rank = jnp.argsort(order).reshape(n, k)  # the sorted row of every pair
     if everything:
         xs = xb[order // k]
+        results = jax.ShapeDtypeStruct((n * k, hidden), jnp.float32)
+        if _combine_by_kernel(results, rank):
+            ys = _expert_products(xs, sizes, layer, cfg, whole_rows=True)
+            return kernels.combine(ys, rank, top_w)
         ys = _expert_products(xs, sizes, layer, cfg)
-        back = jnp.argsort(order)
-        y = ys[back].reshape(n, k, -1)
+        y = ys[rank.reshape(-1)].reshape(n, k, -1)
         return jnp.sum(y * top_w[..., None], axis=1)
 
-    rows = pass_rows(n * k, held, cfg.experts)
-    rank = jnp.argsort(order).reshape(n, k)  # the sorted row of every pair
+    rows = pass_rows(n * k, held, cfg.experts, hidden)
     order = jnp.pad(order, (0, -(n * k) % rows))
     ends = jnp.cumsum(sizes)
     starts, n_held = ends - sizes, ends[-1]
@@ -385,13 +439,15 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
         ys = _expert_products(xb[pair // k], part, layer, cfg)
         mine = (rank >= lo) & (rank < jnp.minimum(lo + rows, n_held))
         at = jnp.clip(rank - lo, 0, rows - 1)
+        if _combine_by_kernel(ys, at, mine):
+            return kernels.combine(ys, at, top_w, mine, onto=y)
         for j in range(k):
             y = y + (jnp.where(mine[:, j, None], ys[at[:, j]], 0.0)
                      * top_w[:, j, None])
         return y
 
     return jax.lax.fori_loop(0, (n_held + rows - 1) // rows, one_pass,
-                             jnp.zeros((n, x.shape[-1]), jnp.float32))
+                             jnp.zeros((n, hidden), jnp.float32))
 
 
 def backbone_hidden(params: Params, x, pos3, cfg: BackboneConfig):

@@ -1,11 +1,13 @@
-"""Pallas TPU kernels: the grouped products of a dropless expert layer.
+"""Pallas TPU kernels: the grouped products of a dropless expert layer,
+and its results' way back to position order.
 
 ``models/keye_backbone.grouped_experts`` sorts its (position, expert)
 pairs by expert, so each expert's rows are contiguous in ``xs`` [M,
 hidden] and ``sizes`` [E] says how many each holds. The XLA path runs the
 layer as three ``lax.ragged_dot`` products with two float32 [M, width]
-arrays and a separate ``silu(g) * u`` fusion between them; here it is two
-kernels:
+arrays and a separate ``silu(g) * u`` fusion between them, then a float32
+row gather back to position order and a weighted sum that reads the
+gathered copy again; here it is three kernels, the two products:
 
 - ``gate_up(xs, wg, wu, sizes) -> mid``: one read of a row tile is
   multiplied into two float32 accumulators (``x @ wg[e]``, ``x @ wu[e]``)
@@ -42,13 +44,31 @@ before ``down``. Only the order of float32 accumulation inside a product
 may differ. ``lax.ragged_dot`` stays the golden reference
 (tests/test_grouped_experts_kernel.py) and what runs off the TPU.
 
-``sizes`` must sum to M (the caller's ``bincount`` of M pairs does); rows
+``sizes`` must sum to M (the caller's count of M pairs does); rows
 past the sum would belong to no visit and are left unwritten.
+
+And the way back, ``combine(ys, rows, weights, take=None)``: ``y[p] = sum_j
+weights[p, j] * ys[rows[p, j]]`` in float32, slots in ascending ``j``. Each
+row that is owed is read once and nothing is gathered into a copy first.
+What it is given decides how it reads (PERF.md, PR 37, has the readings):
+
+- every slot taken (every expert held): 8 x positions rows, all of them
+  owed, so each is copied from HBM by a DMA of its own, a tile of
+  positions ahead of the sum. A row of the (8, 128)-tiled [M, hidden]
+  lies in hidden / 128 pieces of 512 B and Mosaic refuses to slice it;
+  as a leading index of [M, hidden / 128, 128] it is one piece, which is
+  what ``down(..., whole_rows=True)`` writes at the price of a plain store.
+- with ``take`` (a share's pass): few slots are owed (2-4% in the cell)
+  and the pass's results are small enough to stay in VMEM, so they are
+  copied there once, whole, and each taken slot adds its row from there;
+  a slot that is not taken reads nothing, so what its row holds, a NaN
+  too, reaches nothing.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +83,8 @@ _VMEM_CAP = 100 * 2**20
 # shapes (PERF.md, section 6, PR 35): 64 beats 128 (fewer masked rows) and
 # ties with 32; at 16 the MXU starves.
 _SUB_TILE = 64
+
+_LANES = 128
 
 
 def _tiles(m: int) -> tuple[int, int]:
@@ -128,7 +150,7 @@ def _schedule(sizes, m: int, tm: int):
 
 def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
             next_ref, n_ref, x_ref, *rest, tm: int, ts: int, nw: int,
-            epilogue):
+            epilogue, whole_rows: bool = False):
     w_hbm, o_ref = rest[:nw], rest[nw]
     slots, sem = rest[nw + 1:2 * nw + 1], rest[2 * nw + 1]
     v = pl.program_id(0)
@@ -167,8 +189,16 @@ def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
                    for buf in slots]
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (ts, 1), 0)
             own = jnp.logical_and(rows >= start, rows < end)
-            o_ref[window, :] = jnp.where(
-                own, epilogue(*acc).astype(o_ref.dtype), o_ref[window, :])
+            if whole_rows:
+                out = epilogue(*acc).astype(o_ref.dtype)
+                chunks = out.shape[1] // _LANES
+                for c in range(chunks):
+                    at = pl.ds(sub * (ts * chunks) + c, ts, stride=chunks)
+                    o_ref[at, :] = jnp.where(
+                        own, out[:, c * _LANES:(c + 1) * _LANES], o_ref[at, :])
+            else:
+                o_ref[window, :] = jnp.where(
+                    own, epilogue(*acc).astype(o_ref.dtype), o_ref[window, :])
 
         return carry
 
@@ -178,7 +208,7 @@ def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
 
 
 def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
-             interpret: bool):
+             interpret: bool, whole_rows: bool = False):
     """One grouped kernel over the visits of ``sizes``: ``lhs`` [M, K]
     against every matrix of ``weights`` (each [E, K, N]), their float32
     products through ``epilogue`` into [M, N]."""
@@ -192,15 +222,19 @@ def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
     # accumulators of one sub-tile, and room to spare
     vmem = (2 * tm * (k * lhs.dtype.itemsize + n * out_size)
             + 2 * nw * k * n * w_size + 4 * nw * ts * n * 4 + 8 * 2**20)
+    chunks = n // _LANES
+    out_shape, out_block = (((m * chunks, _LANES), (tm * chunks, _LANES))
+                            if whole_rows else ((m, n), (tm, n)))
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, ts=ts, nw=nw, epilogue=epilogue),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        functools.partial(_kernel, tm=tm, ts=ts, nw=nw, epilogue=epilogue,
+                          whole_rows=whole_rows),
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=8,
             grid=(pl.cdiv(m, tm) + e - 1,),
             in_specs=[pl.BlockSpec((tm, k), row_block)]
             + [pl.BlockSpec(memory_space=pl.ANY)] * nw,
-            out_specs=pl.BlockSpec((tm, n), row_block),
+            out_specs=pl.BlockSpec(out_block, row_block),
             scratch_shapes=[pltpu.VMEM((2, k, n), weights[0].dtype)] * nw
             + [pltpu.SemaphoreType.DMA((nw, 2))],
         ),
@@ -222,10 +256,12 @@ def _gate_up(xs, wg, wu, sizes, *, tm: int, ts: int, interpret: bool):
                     xs.dtype, tm=tm, ts=ts, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "ts", "interpret"))
-def _down(mid, wd, sizes, *, tm: int, ts: int, interpret: bool):
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "ts", "interpret", "whole_rows"))
+def _down(mid, wd, sizes, *, tm: int, ts: int, interpret: bool,
+          whole_rows: bool = False):
     return _grouped(lambda y: y, mid, (wd,), sizes, jnp.float32,
-                    tm=tm, ts=ts, interpret=interpret)
+                    tm=tm, ts=ts, interpret=interpret, whole_rows=whole_rows)
 
 
 def gate_up(xs, wg, wu, sizes, *, interpret: bool = False):
@@ -239,8 +275,248 @@ def gate_up(xs, wg, wu, sizes, *, interpret: bool = False):
     return _gate_up(xs, wg, wu, sizes, tm=tm, ts=ts, interpret=interpret)
 
 
-def down(mid, wd, sizes, *, interpret: bool = False):
+def down(mid, wd, sizes, *, whole_rows: bool = False, interpret: bool = False):
     """``mid @ wd[e]`` for the expert ``e`` of each row: ``mid`` [M, width]
-    x ``wd`` [E, width, hidden] -> float32 [M, hidden]."""
+    x ``wd`` [E, width, hidden] -> float32 [M, hidden]; with ``whole_rows``
+    the same numbers as [M, hidden / 128, 128], in which a row is one
+    piece of memory (what ``combine`` copies row by row): the kernel
+    stores each lane chunk of a sub-tile with a sublane stride, at the
+    price of a plain store (PERF.md, PR 37)."""
     tm, ts = _tiles(mid.shape[0])
-    return _down(mid, wd, sizes, tm=tm, ts=ts, interpret=interpret)
+    ys = _down(mid, wd, sizes, tm=tm, ts=ts, interpret=interpret,
+               whole_rows=whole_rows)
+    if whole_rows:
+        return ys.reshape(mid.shape[0], wd.shape[2] // _LANES, _LANES)
+    return ys
+
+
+# -- the way back to position order ---------------------------------------------
+
+# Positions one grid step of ``combine`` sums.
+_COMBINE_TILE = 64
+
+# What the results of a share's pass may take of VMEM, where they stay for
+# the whole call (``models/keye_backbone.pass_rows`` bounds a pass by it).
+HELD_RESULTS_BYTES = 64 * 2**20
+
+
+def _combine_rows_kernel(rows_ref, w_ref, ys_hbm, o_ref, buf, turn, sem, *,
+                         tile: int, k: int, chunks: int, n_tiles: int):
+    """Every slot taken. Grid step ``i`` starts the row copies of tile ``i``
+    into buffer ``i % 2`` and sums tile ``i - 1`` out of the other, eight
+    positions a loop turn. A row of ``ys_hbm`` [M, chunks, 128] lands as
+    [chunks, 128]; a position's sum is formed in that shape, with its
+    weights as scalars, and eight of them are turned into position-major
+    [8, 128] tiles through ``turn`` (stored row after row, read back with a
+    stride of a row).
+
+    The two ends run the same loop: step 0 sums a buffer nothing has
+    written into the block that step 1 then writes in full, and the last
+    step, with no tile left to fetch, fetches its own once more and waits
+    for it. The loops over a turn's positions are rolled: unrolled they
+    read 0.03 ms a call faster on a v5e and cost seven times the Python to
+    trace and lower, which a serving process pays in seconds (PERF.md, PR
+    37)."""
+    i = pl.program_id(0)
+    into = jax.lax.rem(i, 2)   # the buffer this step fetches into
+    outof = 1 - into           # and the one it sums out of
+    fetch0 = jnp.minimum(i, n_tiles - 1) * (tile * k)
+    sum0 = jnp.maximum(i - 1, 0) * (tile * k)
+
+    def landed(slot):
+        # one wait for all of the buffer's bytes: every row was started
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+
+    @pl.when(i > 0)
+    def _():
+        landed(outof)
+
+    def eight_positions(g, carry):
+        def start(u, c):
+            for j in range(k):
+                at = (g * 8 + u) * k + j
+                pltpu.make_async_copy(ys_hbm.at[rows_ref[fetch0 + at]],
+                                      buf.at[into, at], sem.at[into]).start()
+            return c
+
+        def add(u, c):
+            at = (g * 8 + u) * k
+            acc = buf[outof, at] * w_ref[sum0 + at]
+            for j in range(1, k):
+                acc = acc + buf[outof, at + j] * w_ref[sum0 + at + j]
+            turn[pl.ds(pl.multiple_of(u * chunks, chunks), chunks), :] = acc
+            return c
+
+        jax.lax.fori_loop(0, 8, start, 0)
+        jax.lax.fori_loop(0, 8, add, 0)
+        rows8 = pl.ds(pl.multiple_of(g * 8, 8), 8)
+        for c in range(chunks):
+            o_ref[rows8, c * _LANES:(c + 1) * _LANES] = turn[
+                pl.ds(c, 8, stride=chunks), :]
+        return carry
+
+    jax.lax.fori_loop(0, tile // 8, eight_positions, 0)
+
+    @pl.when(i == n_tiles)
+    def _():
+        landed(into)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _combine_rows(ys3, rows, weights, *, tile: int, interpret: bool):
+    m, chunks, _ = ys3.shape
+    p, k = rows.shape
+    n_tiles = p // tile
+    hidden = chunks * _LANES
+    return pl.pallas_call(
+        functools.partial(_combine_rows_kernel, tile=tile, k=k, chunks=chunks,
+                          n_tiles=n_tiles),
+        out_shape=jax.ShapeDtypeStruct((p, hidden), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles + 1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (tile, hidden), lambda i, *_: (jnp.maximum(i - 1, 0), 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, tile * k, chunks, _LANES), jnp.float32),
+                pltpu.VMEM((8 * chunks, _LANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,))],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(_VMEM_CAP, _combine_rows_vmem(tile, k, hidden))),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * p * k * hidden, transcendentals=0,
+            bytes_accessed=4 * (p * k * hidden + p * hidden + 2 * p * k)),
+        interpret=interpret,
+    )(rows.reshape(-1), weights.reshape(-1), ys3)
+
+
+def _combine_rows_vmem(tile: int, k: int, hidden: int) -> int:
+    """Two buffers of a tile's rows, the output block twice, and room to
+    spare."""
+    return 2 * k * tile * hidden * 4 + 2 * tile * hidden * 4 + 4 * 2**20
+
+
+def _combine_held_kernel(taken_ref, rows_ref, w_ref, ys_hbm, *rest, tile: int,
+                         k: int, n_pos: int, onto: bool):
+    """Some slots taken. The results stay in VMEM for the whole call;
+    ``taken_ref`` lists the taken slots (position x k + slot) in ascending
+    order, and grid step ``i`` walks the ones that fall into its tile of
+    positions: each adds its row, times its weight, to its position's row
+    of the output block, which starts from ``onto``'s or from zeros. A
+    slot that is not listed is never read."""
+    onto_ref = rest[0] if onto else None
+    o_ref, ys_vmem, sem, cursor = rest[-4:]
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _load():
+        whole = pltpu.make_async_copy(ys_hbm, ys_vmem, sem)
+        whole.start()
+        whole.wait()
+        cursor[0] = 0
+
+    o_ref[...] = (onto_ref[...] if onto
+                  else jnp.zeros(o_ref.shape, jnp.float32))
+    end = jnp.minimum((i + 1) * tile, n_pos) * k
+
+    def one(c):
+        slot = taken_ref[c]
+        at = pl.ds(slot // k - i * tile, 1)
+        o_ref[at, :] = (o_ref[at, :]
+                        + ys_vmem[pl.ds(rows_ref[slot], 1), :] * w_ref[slot])
+        return c + 1
+
+    # ``taken_ref`` has one entry more than ``ys`` has rows; a taken slot
+    # past that many would have no row of its own, and ends the walk too
+    last = taken_ref.shape[0] - 1
+    cursor[0] = jax.lax.while_loop(
+        lambda c: jnp.logical_and(c <= last,
+                                  taken_ref[jnp.minimum(c, last)] < end),
+        one, cursor[0])
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _combine_held(ys, rows, weights, take, onto=None, *, tile: int,
+                  interpret: bool):
+    m, hidden = ys.shape
+    p, k = rows.shape
+    # the n-th taken slot is the number of slots before which at most n
+    # are taken (a comparison summed over the slots: no sort, no scatter);
+    # past the last one that is every slot, p * k, which ends the walk
+    before = jnp.cumsum(take.reshape(-1).astype(jnp.int32))
+    nth = jnp.arange(m + 1, dtype=jnp.int32)
+    taken = jnp.sum((before[:, None] <= nth).astype(jnp.int32), axis=0)
+    block = pl.BlockSpec((tile, hidden), lambda i, *_: (i, 0))
+    carried = () if onto is None else (onto,)
+    return pl.pallas_call(
+        functools.partial(_combine_held_kernel, tile=tile, k=k, n_pos=p,
+                          onto=onto is not None),
+        out_shape=jax.ShapeDtypeStruct((p, hidden), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(pl.cdiv(p, tile),),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [block] * len(carried),
+            out_specs=block,
+            scratch_shapes=[pltpu.VMEM((m, hidden), jnp.float32),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SMEM((1,), jnp.int32)],
+        ),
+        # ``onto`` (operand 4, after the three prefetched and ``ys``) is
+        # the output's own memory
+        input_output_aliases={4: 0} if carried else {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(_VMEM_CAP, m * hidden * 4
+                                 + 4 * tile * hidden * 4 + 4 * 2**20)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * hidden, transcendentals=0,
+            bytes_accessed=4 * (m * hidden + (1 + len(carried)) * p * hidden
+                                + 3 * p * k)),
+        interpret=interpret,
+    )(taken, rows.reshape(-1), weights.reshape(-1), ys, *carried)
+
+
+def combine_supports(ys, rows, take=None) -> bool:
+    """Whether ``combine`` takes results ``ys`` ([M, hidden], or [M, hidden /
+    128, 128] as ``down(..., whole_rows=True)`` writes them) for ``rows`` [P,
+    k] (arrays or their shapes-and-dtypes): float32 rows of whole lane
+    tiles, and
+    - every slot taken: positions in whole tiles, a row a whole number of
+      sublane tiles, the two buffers inside the VMEM cap;
+    - with ``take``: the results themselves inside their share of VMEM.
+    Anything else takes the caller's XLA expressions."""
+    m = ys.shape[0]
+    hidden = math.prod(ys.shape[1:])
+    p, k = rows.shape
+    if ys.dtype != jnp.float32 or hidden % _LANES or m == 0 or p == 0:
+        return False
+    if take is not None:
+        return m * hidden * 4 <= HELD_RESULTS_BYTES
+    return (p % _COMBINE_TILE == 0 and hidden % (8 * _LANES) == 0
+            and _combine_rows_vmem(_COMBINE_TILE, k, hidden) <= _VMEM_CAP)
+
+
+def combine(ys, rows, weights, take=None, onto=None, *,
+            interpret: bool = False):
+    """The results' way back to position order: ``y[p] = sum_j weights[p, j]
+    * ys[rows[p, j]]`` over the slots taken (all of them without ``take``),
+    float32 throughout, slots added in ascending ``j``; with ``take`` and
+    ``onto`` [P, hidden] (a share's carry, whose memory the result takes)
+    the slots are added onto it, one after the other. ``ys`` float32 [M,
+    hidden] or [M, hidden / 128, 128], ``rows`` int32 [P, k], ``weights``
+    float32 [P, k], ``take`` bool [P, k] -> float32 [P, hidden]. Each row
+    that is owed is read once; a slot not taken is never read, so what
+    its row holds (a NaN too) reaches nothing."""
+    m, hidden = ys.shape[0], math.prod(ys.shape[1:])
+    if take is None:
+        # a row must be one piece to be copied alone: as a leading index
+        # of [M, hidden / 128, 128] it is, in the (8, 128)-tiled [M,
+        # hidden] it lies in hidden / 128 pieces, 4 KB apart
+        assert onto is None, "a carry comes with a share's passes (``take``)"
+        return _combine_rows(ys.reshape(m, hidden // _LANES, _LANES), rows,
+                             weights, tile=_COMBINE_TILE, interpret=interpret)
+    return _combine_held(ys.reshape(m, hidden), rows, weights, take, onto,
+                         tile=_COMBINE_TILE, interpret=interpret)

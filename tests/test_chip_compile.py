@@ -99,11 +99,11 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl):
     )
 
 
-def _compile_step(head, capacity, ring_rows, state, repl, *, mesh=None,
-                  plan=None):
+def _lower_step(head, capacity, ring_rows, state, repl, *, mesh=None,
+                plan=None, sketch=True):
     """The fused session step the server runs, from the builder the
-    server builds it with (serve/index_program.build; the drift-sketch
-    variant: ``jit(_body)``, ring donated)."""
+    server builds it with (serve/index_program.build; ``jit(_body)``, ring
+    donated; the drift-sketch variant unless told otherwise), lowered."""
     from igaming_platform_tpu.core.config import ScoringConfig
     from igaming_platform_tpu.models.ensemble import make_score_fn
     from igaming_platform_tpu.models.session_heads import HEADS
@@ -115,13 +115,17 @@ def _compile_step(head, capacity, ring_rows, state, repl, *, mesh=None,
     head_params = None if head == "pattern" else init
     step = index_program.build(
         make_score_fn(cfg, "multitask", mesh=mesh), cfg, family="session",
-        sketch=True, shadow=False, mesh=mesh, plan=plan,
+        sketch=sketch, shadow=False, mesh=mesh, plan=plan,
         session=index_program.SessionSpec(
             head_fn, capacity, ss.default_events(), ss.default_min_events(),
             ss.default_flag_threshold()))
     args = _step_args(head_params, capacity, ring_rows,
                       1 if plan is None else plan.n_shards, state, repl)
-    return step.lower(*args).compile()
+    return step.lower(*args)
+
+
+def _compile_step(*args, **kwargs):
+    return _lower_step(*args, **kwargs).compile()
 
 
 def _ring_sized_copies(compiled, ring_elems: int) -> list[str]:
@@ -163,11 +167,13 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     """The fused step with the ``keye`` backbone in it, at the cell's size
     (5,242,880 accounts, one 256-row rung) and with the expert core the
     chip picks: still in place on the ring, its arguments are the state
-    plus 5.0 GB of bfloat16 weights, its temporaries stay far under what
-    is left of the chip's 16 GiB, and the expert products are the two
-    Pallas grouped kernels, a pair a layer under the scope the trace
-    reads them by, with no XLA grouped product left. This is where the
-    kernels' tiles and VMEM limit are proven to compile without a chip."""
+    plus 5.0 GB of bfloat16 weights, its temporaries are not larger than
+    the parent's (PR 36: 615,414,272 B; the float32 [32768, 2048] copy the
+    return to position order gathered is gone), and the expert layer is
+    three Pallas kernels a layer under the scope the trace reads them by
+    (``_gate_up``, ``_down`` with its rows whole, ``_combine_rows``), with
+    no XLA grouped product left. This is where the kernels' tiles and VMEM
+    limits are proven to compile without a chip."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.serve import session_state as ss
@@ -180,14 +186,16 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     assert _ring_sized_copies(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.7e9 < mem.argument_size_in_bytes < 9.9e9, mem
-    # 615,414,272 B (614,769,152 B with the three XLA grouped products: the
-    # two float32 [32768, 768] arrays are gone, but the largest temporaries
-    # are the float32 [32768, 2048] result and its return to position order)
-    assert mem.temp_size_in_bytes < 2**30, mem
+    # 396,118,528 B (PR 37); 615,414,272 B with XLA's gather and sum (PR 35)
+    assert mem.temp_size_in_bytes <= 615_414_272, mem
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and "custom-call(" in line]
-    assert len([k for k in kernels if "head/moe/experts" in k]) == 8, kernels
+               if "tpu_custom_call" in line and "custom-call(" in line
+               and "head/moe/experts" in line]
+    for name in ("_gate_up", "_down", "_combine_rows"):
+        calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
+        assert len(calls) == 4, (name, kernels)
+    assert len(kernels) == 12, kernels
     assert "%ragged-dot-none" not in text
 
 
@@ -196,11 +204,14 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     """The fused step with the ``pangu`` backbone in it, at the cell's size
     (3,145,728 accounts, one 256-row rung): in place on the ring, its
     arguments are the state plus 6.23 GB of bfloat16 weights, and its
-    temporaries leave the chip room: the held experts' pass is bounded by
-    ``pass_rows`` (4,096 rows), not by the 32,768 pairs, so nothing of
-    [32768, 7680] exists. One expert's gate and up are 63 MB a slot, over
-    what the Pallas kernels hold in VMEM, so the held experts' products
-    are XLA's grouped product, inside the pass loop."""
+    temporaries are not larger than the parent's (PR 36: 1,623,082,496 B):
+    the held experts' pass is bounded by ``pass_rows`` (2,048 rows, as
+    many as ``combine`` keeps in VMEM), not by the 32,768 pairs, so nothing
+    of [32768, 7680] exists. One expert's gate and up are 63 MB a slot,
+    over what the Pallas product kernels hold in VMEM, so the held experts'
+    products are XLA's grouped product, inside the pass loop; the way back
+    to position order is the one Pallas kernel of the step,
+    ``_combine_held``, once a layer."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.models.keye_backbone import pass_rows
@@ -220,21 +231,45 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     assert _ring_sized_copies(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.0e9 < mem.argument_size_in_bytes < 9.1e9, mem
-    assert mem.temp_size_in_bytes < 4 * 2**30, mem
+    assert mem.temp_size_in_bytes <= 1_623_082_496, mem
     pairs = BATCH * ss.default_events() * cfg.top_k
-    assert pass_rows(pairs, cfg.held_experts, cfg.experts) == 4096
+    assert pass_rows(pairs, cfg.held_experts, cfg.experts, cfg.hidden) == 2048
     text = compiled.as_text()
     assert f"[{pairs},{cfg.hidden}]" not in text
     assert "ragged-dot" in text and "head/moe/experts" in text
     # XLA's grouped product is a Mosaic custom call of its own
-    # (`%ragged-dot-none`); the in-tree kernels (ops/pallas/grouped_experts),
-    # whose calls carry `pallas_call` in their op_name, are not there.
+    # (`%ragged-dot-none`); of the in-tree kernels (ops/pallas/
+    # grouped_experts), whose calls carry `pallas_call` in their op_name,
+    # only the way back is there, under the expert scope.
     # (Calls, not names: the module's table of function names is the
     # process's, and holds `_gate_up` once the keye step was traced in it.)
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "custom-call(" in line]
-    assert len([c for c in calls if "%ragged-dot-none" in c]) == 12, calls
-    assert not [c for c in calls if "pallas_call" in c], calls
+    assert len([c for c in calls
+                if re.match(r"\s*%ragged-dot-none(\.\d+)? = ", c)]) == 12, calls
+    in_tree = [c for c in calls if "pallas_call" in c]
+    assert len(in_tree) == 4, in_tree
+    assert all("_combine_held" in c and "head/moe/experts" in c
+               for c in in_tree), in_tree
+
+
+@pytest.mark.parametrize("head,sketch,sha256", [
+    ("pattern", False, "c51b7511f2159529"), ("pattern", True, "154ee60b366c28a6"),
+    ("transformer", False, "7037c7eb0e6f4958"),
+    ("transformer", True, "1e8ca471c94e4fe3")])
+def test_the_small_heads_step_is_the_one_the_ledger_measured(head, sketch, sha256):
+    """The two host-bound cells' fused step never traces the expert layer:
+    its StableHLO, lowered on this sandbox's CPU at 256 rows and 5,242,880
+    slots, is byte for byte what PR 34 to PR 37 read (PERF.md, section 6),
+    which is how a PR that works on a backbone shows that those cells'
+    device program did not move. A PR that changes that step on purpose
+    writes the new prefixes here, and says so in PERF.md."""
+    import hashlib
+
+    capacity = 5_242_880
+    text = _lower_step(head, capacity, capacity + 1, None, None,
+                       sketch=sketch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == sha256
 
 
 def test_admission_sync_writes_the_ring_in_place(topo):

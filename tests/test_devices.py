@@ -182,7 +182,11 @@ def test_smoke_kernels_phase_tiny_interpreted():
     report = chip_smoke.phase_kernels(
         interpret=True, attention_shapes=((1, 2, 64, 32),),
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
-        expert_shape=(512, 128, 128, 8))
+        expert_shape=(512, 1024, 128, 8), share_shape=(64, 128, 32, 20))
     assert report["interpret"] is True
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
+    for key in ("combine_M512_H1024", "combine_share_M64_H128"):
+        assert report[key]["max_err"] <= chip_smoke.COMBINE_TOL
+        # what a trace would pick here, off the TPU
+        assert report[key]["way_back"] == "combine: xla-gather (backend=cpu)"

@@ -3,8 +3,14 @@ against their golden reference, ``lax.ragged_dot`` + ``silu`` * up, on the
 CPU through the Pallas interpreter, at small shapes ``supports`` accepts
 and under every kind of routing skew the schedule has a case for.
 
-Nothing here is a time: the chip numbers are in PERF.md (PR 35), and
-tests/test_chip_compile.py compiles the kernels at the cell's size for a
+And ``combine``, the results' way back to position order, against the XLA
+expressions it replaces in ``models/keye_backbone.grouped_experts`` (a row
+gather and a weighted sum where every slot is taken; a row gather a slot
+under a ``where`` for a share), and the experts' sizes against
+``jnp.bincount``.
+
+Nothing here is a time: the chip numbers are in PERF.md (PR 35, PR 37), and
+tests/test_chip_compile.py compiles the kernels at the cells' sizes for a
 described v5e.
 """
 
@@ -16,6 +22,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
 from igaming_platform_tpu.ops.pallas import grouped_experts as ge  # noqa: E402
 
 EXPERTS, HIDDEN, WIDTH = 8, 128, 256
@@ -146,3 +153,224 @@ def test_supports_the_cells_shapes():
     assert ge.supports(xs, jax.ShapeDtypeStruct((128, 2048, 768), jnp.bfloat16))
     mid = jax.ShapeDtypeStruct((32768, 768), jnp.bfloat16)
     assert ge.supports(mid, jax.ShapeDtypeStruct((128, 768, 2048), jnp.bfloat16))
+
+
+# -- down, its rows whole ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("skew", ["uniform", "several-empty",
+                                  "not-multiples-of-the-tile",
+                                  "fewer-rows-than-a-sub-tile"])
+def test_down_writes_the_same_numbers_with_its_rows_whole(skew):
+    rows, sizes = SKEWS[skew]
+    xs, _, _, _ = operands(rows)
+    wd = operands(rows, seed=1)[1]  # [E, 128, 256]: hidden 256, two lane chunks
+    sizes = jnp.asarray(sizes, jnp.int32)
+    plain = ge.down(xs, wd, sizes, interpret=True)
+    whole = ge.down(xs, wd, sizes, whole_rows=True, interpret=True)
+    assert whole.shape == (rows, 2, 128) and whole.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(whole).reshape(rows, 256),
+                                  np.asarray(plain))
+
+
+# -- combine: the way back to position order --------------------------------------
+
+
+def xla_every_slot(ys, rows, weights):
+    """What ``grouped_experts`` does where every expert is held: a row
+    gather and a weighted sum over a position's slots."""
+    p, k = rows.shape
+    return jnp.sum(ys[rows.reshape(-1)].reshape(p, k, -1) * weights[..., None],
+                   axis=1)
+
+
+def xla_some_slots(ys, rows, weights, take, onto=None):
+    """What one pass of a share does: a row gather a slot, selected."""
+    p, k = rows.shape
+    y = jnp.zeros((p, ys.shape[1]), jnp.float32) if onto is None else onto
+    for j in range(k):
+        y = y + jnp.where(take[:, j, None], ys[rows[:, j]], 0.0) * weights[:, j, None]
+    return y
+
+
+def results(m: int, hidden: int, seed: int = 0):
+    return jax.random.normal(jax.random.key(seed), (m, hidden), jnp.float32)
+
+
+def uneven_weights(p: int, k: int, seed: int = 1):
+    """Weights over four decades, so a slot left out or added twice shows."""
+    return 10.0 ** jax.random.uniform(jax.random.key(seed), (p, k), jnp.float32,
+                                      -3.0, 1.0)
+
+
+@pytest.mark.parametrize("positions,k,hidden,whole_rows", [
+    (128, 8, 1024, False), (128, 8, 1024, True), (64, 2, 2048, True)],
+    ids=["k8", "k8-rows-whole", "k2-one-tile"])
+def test_combine_every_slot_equals_gather_and_weighted_sum(positions, k, hidden,
+                                                           whole_rows):
+    """(a) a full permutation with uneven weights, from ``ys`` as [M, hidden]
+    and as ``down(..., whole_rows=True)`` hands it over."""
+    m = positions * k
+    ys = results(m, hidden)
+    rows = jnp.asarray(np.random.default_rng(3).permutation(m).reshape(
+        positions, k).astype(np.int32))
+    w = uneven_weights(positions, k)
+    assert ge.combine_supports(ys, rows)
+    given = ys.reshape(m, hidden // 128, 128) if whole_rows else ys
+    got = ge.combine(given, rows, w, interpret=True)
+    want = xla_every_slot(ys, rows, w)
+    assert got.shape == (positions, hidden) and got.dtype == jnp.float32
+    # float32 summation order alone: eight terms of up to 10 x |ys|
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6 * float(jnp.abs(want).max()), rtol=0)
+    # a slot left out would show
+    less = xla_every_slot(ys, rows, w.at[5, 1].set(0.0))
+    assert float(jnp.abs(less[5] - got[5]).max()) > 1e-3
+
+
+def a_share(positions: int, k: int, m: int, taken: int, seed: int = 4):
+    """``taken`` slots at random, each naming a row of its own; the others
+    name the last row, as ``grouped_experts``'s clipped ranks do."""
+    rng = np.random.default_rng(seed)
+    slots = rng.permutation(positions * k)[:taken]
+    take = np.zeros(positions * k, bool)
+    take[slots] = True
+    rows = np.full(positions * k, m - 1, np.int32)
+    rows[slots] = rng.permutation(m)[:taken]
+    owed = np.zeros(m, bool)
+    owed[rows[slots]] = True
+    return (jnp.asarray(rows.reshape(positions, k)),
+            jnp.asarray(take.reshape(positions, k)), owed)
+
+
+@pytest.mark.parametrize("positions,taken", [(256, 61), (256, 0), (100, 30),
+                                             (192, 512)],
+                         ids=["3-percent", "none", "positions-not-a-tile",
+                              "as-many-as-rows"])
+def test_combine_some_slots_reads_only_the_rows_that_are_owed(positions, taken):
+    """(b) 3% of the slots taken and every row that no taken slot names
+    poisoned with NaN: the result is finite and equal; (c) tiles with no
+    slot taken (and a call with none at all) give zeros; (d) positions that
+    do not fill the last tile."""
+    k, m, hidden = 8, 512, 256
+    ys = results(m, hidden, seed=5)
+    rows, take, owed = a_share(positions, k, m, taken)
+    w = uneven_weights(positions, k, seed=6)
+    assert ge.combine_supports(ys, rows, take)
+    poisoned = jnp.where(jnp.asarray(owed)[:, None], ys, jnp.nan)
+    got = np.asarray(ge.combine(poisoned, rows, w, take, interpret=True))
+    want = np.asarray(xla_some_slots(ys, rows, w, take))
+    assert got.shape == (positions, hidden) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-6 * max(np.abs(want).max(), 1),
+                               rtol=0)
+    nothing = ~np.asarray(take).any(axis=1)
+    if taken < 100:
+        # whole tiles of 64 positions hold no taken slot, or nearly none
+        assert nothing.sum() >= positions // 2
+    assert not got[nothing].any()  # exact zeros
+    if taken:
+        assert np.abs(got).max() > 1e-3
+
+
+def test_combine_adds_onto_the_carry_slot_after_slot():
+    k, m, hidden, positions = 4, 256, 128, 96
+    ys = results(m, hidden, seed=7)
+    rows, take, _ = a_share(positions, k, m, 40, seed=8)
+    w = uneven_weights(positions, k, seed=9)
+    carry = results(positions, hidden, seed=10)
+    got = ge.combine(ys, rows, w, take, onto=carry, interpret=True)
+    want = xla_some_slots(ys, rows, w, take, onto=carry)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=0)
+    untouched = ~np.asarray(take).any(axis=1)
+    np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                  np.asarray(carry)[untouched])
+
+
+F32 = jnp.float32
+
+
+@pytest.mark.parametrize("why,m,hidden,positions,k,dtype,share", [
+    ("bfloat16 results", 1024, 1024, 128, 8, jnp.bfloat16, False),
+    ("hidden not lane-aligned", 1024, 1000, 128, 8, F32, False),
+    ("a row not whole sublane tiles", 1024, 640, 128, 8, F32, False),
+    ("positions not whole tiles", 800, 1024, 100, 8, F32, False),
+    ("no rows at all", 0, 1024, 128, 8, F32, False),
+    ("two buffers past the VMEM cap", 8192, 32768, 1024, 8, F32, False),
+    ("a share: bfloat16 results", 512, 256, 256, 8, jnp.bfloat16, True),
+    ("a share: hidden not lane-aligned", 512, 200, 256, 8, F32, True),
+    ("a share: results larger than their part of VMEM", 4096, 7680, 4096, 8, F32,
+     True),
+], ids=lambda v: v.replace(" ", "-") if isinstance(v, str) else "")
+def test_combine_supports_refuses(why, m, hidden, positions, k, dtype, share):
+    ys = jax.ShapeDtypeStruct((m, hidden), jnp.dtype(dtype))
+    rows = jax.ShapeDtypeStruct((positions, k), jnp.int32)
+    take = jax.ShapeDtypeStruct((positions, k), jnp.bool_) if share else None
+    assert not ge.combine_supports(ys, rows, take), why
+
+
+def test_combine_supports_the_cells_shapes():
+    rows = jax.ShapeDtypeStruct((4096, 8), jnp.int32)
+    take = jax.ShapeDtypeStruct((4096, 8), jnp.bool_)
+    # keye: every slot of 4,096 positions, results with their rows whole
+    assert ge.combine_supports(jax.ShapeDtypeStruct((32768, 16, 128), F32), rows)
+    # pangu: one pass of a share, as many rows as ``pass_rows`` allows
+    assert kb.pass_rows(32768, 8, 256, 7680) == 2048
+    assert ge.combine_supports(jax.ShapeDtypeStruct((2048, 7680), F32), rows, take)
+
+
+@pytest.mark.parametrize("case", ["refused-every-slot", "refused-share",
+                                  "taken-every-slot", "taken-share", "cpu"])
+def test_the_way_back_is_chosen_from_backend_and_shapes_and_announced(
+        case, monkeypatch, caplog):
+    """(e) the shapes ``combine_supports`` refuses take the XLA expressions,
+    on a (steered) TPU too, and a compile says once which way it took."""
+    kb._announce_core.cache_clear()
+    if case != "cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hidden = 1000 if case.startswith("refused") else 1024
+    share = case.endswith("share")
+    ys = jax.ShapeDtypeStruct((1024, hidden), F32)
+    rows = jax.ShapeDtypeStruct((128, 8), jnp.int32)
+    take = jax.ShapeDtypeStruct((128, 8), jnp.bool_) if share else None
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        first = kb._combine_by_kernel(ys, rows, take)
+        assert kb._combine_by_kernel(ys, rows, take) == first
+    assert first == case.startswith("taken")
+    way = "pallas-rows" if first else "xla-gather"
+    backend = "cpu" if case == "cpu" else "tpu"
+    assert [r.getMessage() for r in caplog.records] == [
+        f"combine: {way} (backend={backend})"]
+
+
+# -- the experts' sizes -------------------------------------------------------------
+
+
+def keys_of(kind: str, held: int, pairs: int = 4096):
+    rng = np.random.default_rng(11)
+    if kind == "uniform":
+        return rng.integers(0, held, pairs)
+    if kind == "zipf":
+        p = 1.0 / np.arange(1, held + 1) ** 1.2
+        return rng.choice(held, pairs, p=p / p.sum())
+    if kind == "empty-experts":
+        return rng.choice([1, 4, held - 1], pairs)
+    if kind == "sentinel-keys":  # absent and padded pairs carry ``held``
+        return np.where(rng.random(pairs) < 0.9, held, rng.integers(0, held, pairs))
+    if kind == "all-sentinel":
+        return np.full(pairs, held)
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("held", [8, 128])
+@pytest.mark.parametrize("kind", ["uniform", "zipf", "empty-experts",
+                                  "sentinel-keys", "all-sentinel"])
+def test_sizes_are_bincounts_integers_without_its_scatter(kind, held):
+    keys = jnp.asarray(keys_of(kind, held), jnp.int32)
+    sizes = jax.jit(lambda e: kb.expert_sizes(e, held))
+    got = sizes(keys)
+    want = jnp.bincount(keys, length=held).astype(jnp.int32)
+    assert got.dtype == jnp.int32 and got.shape == (held,)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if kind in ("sentinel-keys", "all-sentinel"):
+        assert int(got.sum()) < keys.shape[0]  # a key past the last bin counts nowhere
+    assert "scatter" not in sizes.lower(keys).as_text()

@@ -190,7 +190,7 @@ def _steer_to_the_kernels(monkeypatch):
     from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for name in ("gate_up", "down"):
+    for name in ("gate_up", "down", "combine"):
         monkeypatch.setattr(kernels, name, functools.partial(
             getattr(kernels, name), interpret=True))
 
@@ -278,6 +278,33 @@ def test_expert_core_is_announced_and_falls_back_where_the_kernels_do_not_fit(
         "expert core: xla-ragged-dot (backend=tpu)"]
 
 
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_head_with_the_kernels_on_equals_the_xla_path(operands, monkeypatch, caplog):
+    """The whole head at a size every kernel takes (hidden 1024, width 128,
+    128 positions), the kernels through the interpreter, against the XLA
+    expressions: float32 operands leave the products on ``lax.ragged_dot``
+    and only the way back on ``combine``; bfloat16 ones put all three
+    kernels into the step, ``down`` handing its rows over whole."""
+    cfg = small_config(hidden=1024, expert_width=128,
+                       operand_dtype=jnp.dtype(operands))
+    params = kb.init_backbone(jax.random.key(9), cfg)
+    x, lens = windows(8, (1, 4, 16, 7, 9, 2), seed=2)
+    by_xla = program_scores(cfg, params, x, lens)
+    kb._announce_core.cache_clear()
+    _steer_to_the_kernels(monkeypatch)
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        by_kernels = program_scores(cfg, params, x, lens)
+    said = {r.getMessage() for r in caplog.records}
+    core = "pallas-grouped" if operands == "bfloat16" else "xla-ragged-dot"
+    assert said == {f"expert core: {core} (backend=tpu)",
+                    "combine: pallas-rows (backend=tpu)"}
+    assert np.ptp(by_xla) > 1e-3
+    # float32 summation order in the way back (and, with bfloat16 operands,
+    # inside the products, before ``mid`` is rounded once)
+    atol = 2e-6 if operands == "float32" else 2e-4
+    np.testing.assert_allclose(by_kernels, by_xla, atol=atol, rtol=0)
+
+
 # -- a chip's share of the experts (the layer both backbones call) --------------
 
 
@@ -324,6 +351,13 @@ def test_pass_rows_bound_a_share_and_cover_a_whole_layer():
     assert kb.pass_rows(2048, 4, 32) == 1024
     assert kb.pass_rows(192, 4, 16) == 192   # never more than all pairs
     assert kb.pass_rows(4096, 1, 256) == 256  # rounded up to the kernels' tile
+    # and, told how wide a row is, no more rows than leave a pass's float32
+    # results in the 64 MiB that ``combine`` keeps them in: the pangu
+    # cell's pass is 2,048 rows of 7,680, not 4,096; small rows change nothing
+    assert kb.pass_rows(32768, 8, 256, 7680) == 2048
+    assert kb.pass_rows(32768, 8, 256, 2048) == 4096
+    assert kb.pass_rows(2048, 4, 32, 128) == 1024
+    assert kb.pass_rows(4096, 1, 256, 1 << 20) == 256  # one tile at least
 
 
 @pytest.mark.parametrize("expert_core", ["xla", "pallas"], indirect=True)

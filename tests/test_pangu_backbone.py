@@ -11,6 +11,7 @@ routed ones of which a chip holds 4, 4 chosen a token.
 from __future__ import annotations
 
 import copy
+import functools
 import types
 
 import numpy as np
@@ -77,9 +78,22 @@ def program_scores(cfg, params, x, lengths):
             params, jnp.asarray(x), jnp.asarray(lengths, jnp.int32)))
 
 
-def stream(rows: int = 6, seed: int = 0):
-    """A residual stream [rows, 16, 64] with some spread."""
-    return jax.random.normal(jax.random.key(seed), (rows, 16, 64), jnp.float32) * 2.0
+def stream(rows: int = 6, seed: int = 0, hidden: int = 64):
+    """A residual stream [rows, 16, hidden] with some spread."""
+    return jax.random.normal(jax.random.key(seed), (rows, 16, hidden),
+                             jnp.float32) * 2.0
+
+
+def steer_to_combine(monkeypatch):
+    """What a TPU would pick for the way back, on the CPU: the backend
+    reports ``tpu`` and ``combine`` runs through the Pallas interpreter.
+    Steered here, in the test; the program has no option for it."""
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+
+    kb._announce_core.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "combine", functools.partial(
+        kernels.combine, interpret=True))
 
 
 # -- the whole head, the stack and the score -------------------------------------
@@ -303,33 +317,45 @@ def test_router_is_sigmoid_top_k_renormalised_and_scaled():
 # -- the shares add up ------------------------------------------------------------
 
 
-def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(head):
+@pytest.mark.parametrize("way_back", ["xla-gather", "pallas-rows"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(
+        head, way_back, monkeypatch, caplog):
     """Model-configs guide, section 4: at 16 experts, the routed parts that
     4 shares of 4 give, with what every chip computes alike (the shared
     expert) counted once, equal what the UNCUT reference (all 16 held)
-    gives for the whole layer."""
+    gives for the whole layer. Both ways back to position order: the XLA
+    expressions at hidden 64, and ``combine`` (through the interpreter) at
+    hidden 128, whose rows are whole lane tiles."""
     dt = jnp.float32
-    uncut = small_source(first=0, held=EXPERTS)
+    size = {}
+    kb._announce_core.cache_clear()
+    if way_back == "pallas-rows":
+        size = {"hidden": 128}
+        steer_to_combine(monkeypatch)
+    uncut = small_source(first=0, held=EXPERTS,
+                         **{"hidden_size": v for v in size.values()})
     whole = head.make_params(11, uncut)["layers"][1]
     d_whole = head.dims_of(uncut)
-    x = stream(rows=8, seed=5)
+    x = stream(rows=8, seed=5, **size)
     b, t, hidden = x.shape
 
     flat = head._rms(x, whole["g3"], d_whole.eps).reshape(-1, hidden)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(_reference_mlp(head, whole, flat, d_whole, dt))
     shared_once = np.asarray(jax.jit(lambda f: pb.swiglu(
-        f, whole["shared"], small_config(operand_dtype=dt)))(flat))
+        f, whole["shared"], small_config(operand_dtype=dt, **size)))(flat))
     total = shared_once.copy()
     for first in range(0, EXPERTS, HELD):
-        cfg = small_config(first=first, operand_dtype=dt)
+        cfg = small_config(first=first, operand_dtype=dt, **size)
         share = {k: v[first:first + HELD] for k, v in whole["routed"].items()}
         top_e, top_w = pb.route(flat, whole, cfg)
-        part = np.asarray(jax.jit(lambda f, e, w: kb.grouped_experts(
-            f, e, w, share, cfg, first, jnp.ones((f.shape[0],), bool)))(
-                flat, top_e, top_w))
+        with caplog.at_level("INFO", logger=kb.logger.name):
+            part = np.asarray(jax.jit(lambda f, e, w: kb.grouped_experts(
+                f, e, w, share, cfg, first, jnp.ones((f.shape[0],), bool)))(
+                    flat, top_e, top_w))
         # a share's own reference gives the same part
-        d_share = head.dims_of(small_source(first=first))
+        d_share = head.dims_of(dict(uncut, n_routed_experts=HELD, head=dict(
+            uncut["head"], first_expert=first)))
         layer = dict(whole, routed=share)
         with jax.default_matmul_precision("highest"):
             ref_part = np.asarray(_reference_mlp(head, layer, flat, d_share, dt))
@@ -339,6 +365,26 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(head):
     np.testing.assert_allclose(total, want, atol=5e-5, rtol=0)
     # and every pair was somebody's: the weights of a position sum to 2.5
     assert np.abs(want - shared_once).max() > 1e-2
+    backend = "tpu" if way_back == "pallas-rows" else "cpu"
+    assert f"combine: {way_back} (backend={backend})" in caplog.text
+
+
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_head_with_combine_on_equals_the_xla_path(operands, monkeypatch, caplog):
+    """The whole head at hidden 128, where ``combine`` takes a share's
+    results (the products stay on ``lax.ragged_dot``: the cell's pairing),
+    against the XLA expressions on the same tree and windows."""
+    cfg = small_config(hidden=128, operand_dtype=jnp.dtype(operands))
+    params = pb.init_backbone(jax.random.key(4), cfg)
+    x, lens = windows(12, (1, 4, 16, 7, 9, 2), seed=3)
+    by_xla = program_scores(cfg, params, x, lens)
+    steer_to_combine(monkeypatch)
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        by_kernel = program_scores(cfg, params, x, lens)
+    assert "combine: pallas-rows (backend=tpu)" in caplog.text
+    assert "expert core: xla-ragged-dot (backend=tpu)" in caplog.text
+    assert np.ptp(by_xla) > 1e-3
+    np.testing.assert_allclose(by_kernel, by_xla, atol=2e-6, rtol=0)
 
 
 def _reference_mlp(head, layer, flat, d, dt):
@@ -458,4 +504,5 @@ def test_chip_smoke_phase_runs_the_head_against_its_reference():
                                        rows=8)
     assert report["max_err"] < 1e-4 and report["rows"] == 8
     assert report["expert_core"] == "expert core: xla-ragged-dot (backend=cpu)"
+    assert report["way_back"] == "combine: xla-gather (backend=cpu)"
     assert report["resident_bytes"] > 0
