@@ -9,8 +9,10 @@ a p99 breach fires, and the source the bench arms aggregate into their
 per-stage breakdown blocks.
 
 Wired by ``install()``: the tracing module's root-span sink records every
-completed ``rpc.*`` root here. Recording is O(1) per request (dict build
-+ deque append) — cheap enough to leave on in production.
+completed ``rpc.*`` root here. Recording is one deque append of the
+completed root; the summary (a dozen dicts and roundings per request) is
+made when the ring is READ, which is rare, not when a request ends,
+which is the hot path.
 """
 
 from __future__ import annotations
@@ -28,7 +30,8 @@ class FlightRecorder:
 
     def __init__(self, capacity: int = 256):
         self.capacity = max(1, capacity)
-        self._entries: deque[dict] = deque(maxlen=self.capacity)
+        # summaries (dict), or completed roots not summarized yet
+        self._entries: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
 
     def record(self, entry: dict) -> None:
@@ -37,7 +40,13 @@ class FlightRecorder:
 
     def record_root_span(self, span) -> None:
         """Root-span sink: only rpc.* roots are requests; batch-level
-        roots (batcher-thread stage spans) stay out of the ring.
+        roots (batcher-thread stage spans) stay out of the ring."""
+        if span.name.startswith("rpc."):
+            self.record(span)
+
+    @staticmethod
+    def summarize(span) -> dict:
+        """One completed rpc.* root as a flight entry.
 
         With the pipelined host engine, one request's stages run
         concurrently on stage-worker threads, so the busy-time sum
@@ -47,8 +56,10 @@ class FlightRecorder:
         is how much host-stage work ran concurrently."""
         if not span.name.startswith("rpc."):
             return
-        busy_ms = sum((span.stage_totals or {}).values())
-        wall_ms = tracing.union_duration_ms(span.stage_windows)
+        # copies: a pipeline worker's late stage may still be landing
+        stage_totals = dict(span.stage_totals or {})
+        busy_ms = sum(stage_totals.values())
+        wall_ms = tracing.union_duration_ms(list(span.stage_windows or ()))
         # Host-cost join (obs/hostprof.py's per-RPC face): the same
         # decomposition as stages_ms, but in µs and — when the handler
         # stamped a `rows` root attribute — per row, so one decision id
@@ -56,7 +67,7 @@ class FlightRecorder:
         rows = span.attributes.get("rows")
         rows = rows if isinstance(rows, int) and rows > 0 else None
         stage_us = {
-            k: round(v * 1000.0, 1) for k, v in (span.stage_totals or {}).items()
+            k: round(v * 1000.0, 1) for k, v in stage_totals.items()
         }
         host_cost = {
             "rows": rows,
@@ -65,27 +76,28 @@ class FlightRecorder:
                 {k: round(us / rows, 3) for k, us in stage_us.items()}
                 if rows else None),
         }
-        self.record({
+        return {
             "method": span.name[4:],
             "trace_id": span.trace_id,
             "span_id": span.span_id,
             "parent_id": span.parent_id,
             "start_unix_s": span.start,
             "duration_ms": round(span.duration_ms, 3),
-            "stages_ms": {
-                k: round(v, 3) for k, v in (span.stage_totals or {}).items()
-            },
+            "stages_ms": {k: round(v, 3) for k, v in stage_totals.items()},
             "stage_busy_ms": round(busy_ms, 3),
             "stage_wall_ms": round(wall_ms, 3),
             "stage_overlap_ratio": (
                 round(max(0.0, 1.0 - wall_ms / busy_ms), 4) if busy_ms > 0 else 0.0
             ),
             "host_cost": host_cost,
-            **{k: v for k, v in span.attributes.items()},
-        })
+            **span.attributes,
+        }
 
     def snapshot(self) -> list[dict]:
         with self._lock:
+            self._entries = deque(
+                (e if isinstance(e, dict) else self.summarize(e)
+                 for e in self._entries), maxlen=self.capacity)
             return list(self._entries)
 
     def clear(self) -> None:

@@ -10,7 +10,14 @@ readback, session, ledger_note, encode, ...) is folded — via a tracing
 span sink, so the serving code is untouched — into per-stage
 cost-per-row distributions: cumulative totals plus a bounded reservoir
 of recent per-span samples. Durations ride the spans' monotonic clock
-(``perf_counter``; MX06 enforces this in obs/). The same tier watches
+(``perf_counter``; MX06 enforces this in obs/). Beside each stage's
+inclusive ``total_us`` stand its EXCLUSIVE wall (``self_us``: the span's
+duration minus the same-thread child spans inside it) and exclusive
+thread CPU (``cpu_us``: read on one trace in ``tracing.CPU_SAMPLE_EVERY``
+and counted that many times, an estimate that settles over a few hundred
+requests); a stage that has had a child also gets a row
+``<stage>.self`` whose ``total_us`` is that exclusive wall, so a reader
+that sums rows by name can tile an envelope. The same tier watches
 the collector: a ``gc.callbacks`` hook records collection counts and
 pause-ms per generation, attributing each pause to the rpc.* roots in
 flight when it hit (read off the tracing thread-active table), plus
@@ -316,12 +323,25 @@ class StackSampler:
 
 
 class _StageAcc:
-    __slots__ = ("spans", "rows", "total_us", "samples")
+    __slots__ = ("key", "spans", "rows", "total_us", "self_us", "cpu_us",
+                 "has_children", "flushed_self_us", "flushed_cpu_us",
+                 "samples")
 
-    def __init__(self):
+    def __init__(self, stage: str = ""):
+        self.key = (("stage", stage),)  # its labelset on /metrics
         self.spans = 0
         self.rows = 0
         self.total_us = 0.0
+        # Exclusive both: what the stage's own code cost, its same-thread
+        # child spans taken out (tracing.Span.child_s / child_cpu_s).
+        self.self_us = 0.0
+        self.cpu_us = 0.0
+        # A span of this stage has had a child: the stage also shows as
+        # ``<stage>.self``.
+        self.has_children = False
+        # What flush_counters has already added to the /metrics counters.
+        self.flushed_self_us = 0.0
+        self.flushed_cpu_us = 0.0
         # Recent per-span µs/row samples (rolling window).
         self.samples: deque = deque(maxlen=_SAMPLE_RESERVOIR)
 
@@ -393,7 +413,31 @@ class HostProfiler:
     def bind_metrics(self, metrics) -> None:
         """Attach a ServiceMetrics so stage costs / GC pauses land on
         /metrics next to the rest of the serving series."""
+        old = self.metrics
+        if old is not None and old is not metrics and hasattr(old, "registry"):
+            old.registry.remove_refresher(self.flush_counters)
         self.metrics = metrics
+        if hasattr(metrics, "registry"):
+            metrics.registry.add_refresher(self.flush_counters)
+
+    def flush_counters(self) -> None:
+        """Registry refresher: the exclusive wall and CPU each stage has
+        gathered since the last render, onto
+        ``host_stage_{self,cpu}_seconds_total``. Off the request path."""
+        m = self.metrics
+        if m is None:
+            return
+        with self._lock:
+            grown = []
+            for stage, acc in self._stages.items():
+                grown.append((stage, acc.self_us - acc.flushed_self_us,
+                              acc.cpu_us - acc.flushed_cpu_us))
+                acc.flushed_self_us, acc.flushed_cpu_us = acc.self_us, acc.cpu_us
+        for stage, self_us, cpu_us in grown:
+            if self_us > 0:
+                m.host_stage_self_seconds_total.inc(self_us / 1e6, stage=stage)
+            if cpu_us > 0:
+                m.host_stage_cpu_seconds_total.inc(cpu_us / 1e6, stage=stage)
 
     def install_gc_watch(self) -> None:
         if self._gc_installed:
@@ -432,19 +476,30 @@ class HostProfiler:
         per_row = None
         if isinstance(rows, int) and rows > 0:
             per_row = us / rows
+        # exclusive both (tracing.Span.self_s / self_cpu_s, in us); thread
+        # CPU is read on one trace in CPU_SAMPLE_EVERY and stands for all
+        self_us = us - span.child_s * 1e6
+        cpu_us = ((span.cpu_s - span.child_cpu_s) * 1e6
+                  * tracing.CPU_SAMPLE_EVERY) if span.cpu_sampled else 0.0
         with self._lock:
             acc = self._stages.get(stage)
             if acc is None:
-                acc = self._stages[stage] = _StageAcc()
+                acc = self._stages[stage] = _StageAcc(stage)
             acc.spans += 1
             acc.total_us += us
+            if self_us > 0:
+                acc.self_us += self_us
+            if cpu_us > 0:
+                acc.cpu_us += cpu_us
+            if span.children:
+                acc.has_children = True
             if per_row is not None:
                 acc.rows += rows
                 acc.samples.append(per_row)
         m = self.metrics
         if m is not None and per_row is not None:
-            m.host_stage_us_per_row.observe(
-                per_row, exemplar=span.trace_id, stage=stage)
+            m.host_stage_us_per_row.observe_key(
+                acc.key, per_row, span.trace_id)
 
     # -- GC watch ------------------------------------------------------------
 
@@ -523,24 +578,38 @@ class HostProfiler:
     def _stage_block(self) -> dict:
         with self._lock:
             snap = {
-                stage: (acc.spans, acc.rows, acc.total_us, list(acc.samples))
+                stage: (acc.spans, acc.rows, acc.total_us, list(acc.samples),
+                        acc.self_us, acc.cpu_us, acc.has_children)
                 for stage, acc in self._stages.items()
             }
             rpc = (self._rpc.spans, self._rpc.rows, self._rpc.total_us,
                    list(self._rpc.samples))
         out: dict[str, dict] = {}
-        for stage, (spans, rows, total_us, samples) in sorted(snap.items()):
+        for stage, (spans, rows, total_us, samples, self_us, cpu_us,
+                    has_children) in snap.items():
             samples.sort()
             out[stage] = {
                 "spans": spans,
                 "rows": rows,
                 "total_us": round(total_us, 1),
+                "self_us": round(self_us, 1),
+                "cpu_us": round(cpu_us, 1),
                 "us_per_row": ({
                     "mean": round(total_us / rows, 4),
                     "p50": round(_percentile(samples, 0.50), 4),
                     "p99": round(_percentile(samples, 0.99), 4),
                 } if rows > 0 else None),
             }
+            if has_children:
+                # What the envelope's own code cost, as a row a reader can
+                # take by name: total_us IS the exclusive wall here.
+                out[f"{stage}.self"] = {
+                    **out[stage],
+                    "total_us": round(self_us, 1),
+                    "us_per_row": ({"mean": round(self_us / rows, 4)}
+                                   if rows > 0 else None),
+                }
+        out = dict(sorted(out.items()))
         spans, rows, total_us, samples = rpc
         samples.sort()
         rpc_block = {

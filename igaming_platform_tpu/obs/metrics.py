@@ -14,13 +14,15 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Iterable
+from bisect import bisect_left
+from typing import Callable, Iterable
 
 _DEFAULT_BUCKETS = (0.5, 1, 2.5, 5, 10, 25, 50, 100, 250, 500, 1000, 2500)
 
 
 def _label_key(labels: dict[str, str]) -> tuple:
-    return tuple(sorted(labels.items()))
+    # no labels or one (most series): nothing to sort
+    return tuple(labels.items()) if len(labels) < 2 else tuple(sorted(labels.items()))
 
 
 def _fmt_labels(key: tuple) -> str:
@@ -84,6 +86,9 @@ class Histogram:
         self.name = name
         self.help = help_text
         self.buckets = tuple(sorted(buckets))
+        # Per labelset, observations PER BUCKET (not cumulative; the last
+        # slot is +Inf): an observe touches one slot, and render /
+        # percentile cumulate. Spans feed two of these per completed span.
         self._counts: dict[tuple, list[int]] = {}
         self._sums: dict[tuple, float] = {}
         self._totals: dict[tuple, int] = {}
@@ -94,28 +99,31 @@ class Histogram:
         self._exemplars: dict[tuple, dict[int, tuple[str, float, float]]] = {}
         self._lock = threading.Lock()
 
-    def _bucket_index(self, value: float) -> int:
-        for i, bound in enumerate(self.buckets):
-            if value <= bound:
-                return i
-        return len(self.buckets)
-
-    def _note_exemplar(self, key: tuple, value: float, exemplar: str) -> None:
+    def _slots(self, key: tuple) -> list[int]:
         """Caller holds the lock."""
-        self._exemplars.setdefault(key, {})[self._bucket_index(value)] = (
-            str(exemplar), float(value), time.time())
+        counts = self._counts.get(key)
+        if counts is None:
+            counts = self._counts[key] = [0] * (len(self.buckets) + 1)
+            self._sums[key] = 0.0
+            self._totals[key] = 0
+            self._exemplars[key] = {}
+        return counts
 
     def observe(self, value: float, exemplar: str | None = None, **labels: str) -> None:
-        key = _label_key(labels)
+        self.observe_key(_label_key(labels), value, exemplar)
+
+    def observe_key(self, key: tuple, value: float,
+                    exemplar: str | None = None) -> None:
+        """``observe`` for a caller that keeps its labelset's key (the
+        sorted ``(name, value)`` pairs): the span sinks, once per span."""
+        index = bisect_left(self.buckets, value)  # first bound >= value
         with self._lock:
-            counts = self._counts.setdefault(key, [0] * len(self.buckets))
-            for i, bound in enumerate(self.buckets):
-                if value <= bound:
-                    counts[i] += 1
-            self._sums[key] = self._sums.get(key, 0.0) + value
-            self._totals[key] = self._totals.get(key, 0) + 1
+            counts = self._counts.get(key) or self._slots(key)
+            counts[index] += 1
+            self._sums[key] += value
+            self._totals[key] += 1
             if exemplar is not None:
-                self._note_exemplar(key, value, exemplar)
+                self._exemplars[key][index] = (exemplar, value, time.time())
 
     def observe_many(self, values, exemplar: str | None = None, **labels: str) -> None:
         """Vectorized observe for batch paths: one lock hold + one
@@ -127,19 +135,21 @@ class Histogram:
         if arr.size == 0:
             return
         key = _label_key(labels)
-        # counts[i] = how many values <= buckets[i] (cumulative, matching
-        # observe()'s per-bucket increments).
+        # how many values <= buckets[i], then per bucket; the rest is +Inf
         le_counts = np.searchsorted(np.sort(arr), self.buckets, side="right")
+        per_bucket = np.diff(le_counts, prepend=0, append=arr.size)
         with self._lock:
-            counts = self._counts.setdefault(key, [0] * len(self.buckets))
-            for i, c in enumerate(le_counts):
+            counts = self._slots(key)
+            for i, c in enumerate(per_bucket):
                 counts[i] += int(c)
-            self._sums[key] = self._sums.get(key, 0.0) + float(arr.sum())
-            self._totals[key] = self._totals.get(key, 0) + int(arr.size)
+            self._sums[key] += float(arr.sum())
+            self._totals[key] += int(arr.size)
             if exemplar is not None:
                 # One exemplar per batch: the worst value is the one a
                 # latency investigation wants to click through to.
-                self._note_exemplar(key, float(arr.max()), exemplar)
+                worst = float(arr.max())
+                self._exemplars[key][bisect_left(self.buckets, worst)] = (
+                    exemplar, worst, time.time())
 
     def percentile(self, q: float, **labels: str) -> float:
         """Approximate percentile from bucket boundaries (upper bound)."""
@@ -149,9 +159,10 @@ class Histogram:
             if total == 0:
                 return 0.0
             target = q * total
-            counts = self._counts[key]
-            for i, bound in enumerate(self.buckets):
-                if counts[i] >= target:
+            seen = 0
+            for bound, c in zip(self.buckets, self._counts[key]):
+                seen += c
+                if seen >= target:
                     return bound
             return float("inf")
 
@@ -179,9 +190,11 @@ class Histogram:
             }
         for key in sorted(snap):
             counts, _sum, _total, exemplars = snap[key]
+            seen = 0
             for i, (bound, c) in enumerate(zip(self.buckets, counts)):
+                seen += c
                 lk = key + (("le", str(bound)),)
-                yield (f"{self.name}_bucket{_fmt_labels(tuple(sorted(lk)))} {c}"
+                yield (f"{self.name}_bucket{_fmt_labels(tuple(sorted(lk)))} {seen}"
                        f"{self._exemplar_suffix(exemplars.get(i))}")
             lk = key + (("le", "+Inf"),)
             yield (f"{self.name}_bucket{_fmt_labels(tuple(sorted(lk)))} {_total}"
@@ -194,6 +207,20 @@ class Registry:
     def __init__(self):
         self._metrics: list = []
         self._lock = threading.Lock()
+        # Called before every render, off any request: series whose source
+        # is read rather than pushed (/proc counters, the host profiler's
+        # exclusive-time accumulators) are brought up to date here.
+        self._refreshers: list[Callable[[], None]] = []
+
+    def add_refresher(self, fn: Callable[[], None]) -> None:
+        with self._lock:
+            if fn not in self._refreshers:
+                self._refreshers.append(fn)
+
+    def remove_refresher(self, fn: Callable[[], None]) -> None:
+        with self._lock:
+            if fn in self._refreshers:
+                self._refreshers.remove(fn)
 
     def counter(self, name: str, help_text: str = "") -> Counter:
         m = Counter(name, help_text)
@@ -214,6 +241,13 @@ class Registry:
         return m
 
     def render_text(self) -> str:
+        with self._lock:
+            refreshers = list(self._refreshers)
+        for fn in refreshers:
+            try:
+                fn()
+            except Exception:  # noqa: BLE001 — a stale series must not fail the scrape
+                pass
         lines: list[str] = []
         with self._lock:
             for m in self._metrics:
@@ -226,6 +260,7 @@ class ServiceMetrics:
 
     def __init__(self, service: str, registry: Registry | None = None):
         self.registry = registry or Registry()
+        self._stage_keys: dict[str, tuple] = {}  # span name -> labelset key
         self.requests_total = self.registry.counter(
             f"{service}_grpc_requests_total", "gRPC requests by method and code"
         )
@@ -589,6 +624,43 @@ class ServiceMetrics:
             "dispatch-amplification ratio; per-request counts ride the "
             "flight entries' `dispatches` attribute",
         )
+        self.h2d_transfers_total = self.registry.counter(
+            f"{service}_h2d_transfers_total",
+            "Host (numpy) arguments handed to the index-mode scoring "
+            "program, one host-to-device transfer each: reckoned once per "
+            "(program, padded shape), added per launch",
+        )
+        self.h2d_bytes_total = self.registry.counter(
+            f"{service}_h2d_bytes_total",
+            "Bytes of those host arguments (their nbytes) — what crosses "
+            "the link on the way in, per launch",
+        )
+        self.host_cpu_steal_seconds_total = self.registry.counter(
+            f"{service}_host_cpu_steal_seconds_total",
+            "Steal column of /proc/stat's cpu line: seconds the "
+            "hypervisor ran somebody else while this machine had work; "
+            "refreshed at render, never on a request",
+        )
+        self.host_cpu_seconds_total = self.registry.counter(
+            f"{service}_host_cpu_seconds_total",
+            "All columns of /proc/stat's cpu line (every CPU's seconds, "
+            "idle included): the denominator of the steal share. A "
+            "sandboxed kernel shows that line as zeros: both counters then "
+            "stay 0 and the share cannot be read, which is not 'no steal'",
+        )
+        self.process_runqueue_wait_seconds_total = self.registry.counter(
+            f"{service}_process_runqueue_wait_seconds_total",
+            "Seconds this process's threads stood runnable on a run queue "
+            "without a CPU (second field of /proc/self/task/*/schedstat, "
+            "summed over the live threads); stays 0 where the kernel "
+            "keeps no schedstat, which is 'cannot be read', not 'no wait'",
+        )
+        self.process_cpu_seconds_total = self.registry.counter(
+            f"{service}_process_cpu_seconds_total",
+            "Seconds this process's threads ran on a CPU (first field of "
+            "schedstat, summed; time.process_time where the kernel keeps "
+            "no schedstat): the denominator of the run-queue share",
+        )
         self.step_anomalies_total = self.registry.counter(
             f"{service}_step_anomalies_total",
             "Device step-time EWMA anomalies by {stage}: a sample beyond "
@@ -832,6 +904,26 @@ class ServiceMetrics:
             "explains",
             buckets=(0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 25, 50, 100, 250),
         )
+        self.host_stage_self_seconds_total = self.registry.counter(
+            f"{service}_host_stage_self_seconds_total",
+            "Exclusive wall seconds by serving {stage}: each span's "
+            "duration minus the same-thread child spans inside it, so the "
+            "stages tile the attributed host time with nothing counted "
+            "twice; brought up to date at every render",
+        )
+        self.host_stage_cpu_seconds_total = self.registry.counter(
+            f"{service}_host_stage_cpu_seconds_total",
+            "Exclusive thread-CPU seconds by serving {stage}, an "
+            "ESTIMATE: time.thread_time is read at span entry and exit "
+            "(children subtracted) on one trace in seven "
+            "(tracing.CPU_SAMPLE_EVERY) and counted seven-fold, so read it "
+            "over windows of many requests (+-10% over 20 s; a tiny span "
+            "reads multiples of the clock's tick). Over "
+            "host_stage_self_seconds_total it is the share of the "
+            "attributed wall during which the thread was on a CPU; the "
+            "rest is the *_wait stages (device, lock, lane), GIL wait, "
+            "preemption, steal",
+        )
         self.gc_collections_total = self.registry.counter(
             f"{service}_gc_collections_total",
             "Python GC collections by {generation} — a hot gen-2 rate "
@@ -868,7 +960,10 @@ class ServiceMetrics:
         """Span-sink adapter (obs/tracing.set_span_sink): stage spans feed
         the per-stage histogram keyed by span name; rpc.* roots are the
         whole-request spans already covered by request_duration_ms."""
-        if span.name.startswith("rpc."):
+        name = span.name
+        if name.startswith("rpc."):
             return
-        self.stage_latency_ms.observe(
-            span.duration_ms, exemplar=span.trace_id, stage=span.name)
+        key = self._stage_keys.get(name)
+        if key is None:
+            key = self._stage_keys[name] = (("stage", name),)
+        self.stage_latency_ms.observe_key(key, span.duration_ms, span.trace_id)

@@ -36,10 +36,19 @@ stages, but three classes of device-runtime trouble never show up there:
 HBM-side occupancy gauges (arena pool buffers, device memory stats
 where the backend exposes them, device feature-cache occupancy is
 already covered by PR 1's gauges) refresh on every /metrics scrape.
+
+- **Who else had the CPU.** A run that is slow from boot to exit on a
+  shared-core machine looks like a slow program. :func:`read_host_cpu`
+  reads the ``cpu`` line of ``/proc/stat`` (steal, and all columns) and
+  this process's ``schedstat`` (seconds on a CPU, seconds runnable
+  without one); :meth:`RuntimeTelemetry.refresh_host_counters` folds
+  them into four counters whenever the registry renders — never on a
+  request.
 """
 
 from __future__ import annotations
 
+import glob
 import logging
 import os
 import threading
@@ -56,6 +65,41 @@ logger = logging.getLogger(__name__)
 # score.device is the fused dispatch+readback of the request paths.
 # (Dispatch COUNTING is launch-driven via note_dispatch, not span-driven.)
 _STEP_STAGES = ("score.dispatch", "score.readback", "score.device")
+
+
+def read_host_cpu(proc: str = "/proc") -> dict[str, float]:
+    """Seconds, cumulative: ``steal`` and ``all`` from the ``cpu`` line of
+    ``/proc/stat``; ``oncpu`` and ``runqueue`` from the first two fields
+    of every live thread's ``schedstat``. A sandboxed kernel shows a
+    ``cpu`` line of zeros and keeps no schedstat (the machines the chips
+    sit in: PERF.md, PR 38): ``steal``, ``all`` and ``runqueue`` then stay
+    0, which says "cannot be read" (a share over a zero denominator is
+    no number), and ``oncpu`` is ``time.process_time()``, the same
+    quantity from another clock."""
+    out = {"steal": 0.0, "all": 0.0, "oncpu": 0.0, "runqueue": 0.0}
+    try:
+        with open(f"{proc}/stat", encoding="ascii") as f:
+            ticks = [float(v) for v in f.readline().split()[1:]]
+        hz = os.sysconf("SC_CLK_TCK")
+        out["all"] = sum(ticks) / hz
+        if len(ticks) > 7:
+            out["steal"] = ticks[7] / hz
+    except (OSError, ValueError):  # noqa: CC04 — no /proc (not Linux): the columns read 0
+        pass
+    oncpu_ns = wait_ns = 0
+    for path in glob.glob(f"{proc}/self/task/*/schedstat"):
+        try:
+            with open(path, encoding="ascii") as f:
+                fields = f.read().split()
+            oncpu_ns += int(fields[0])
+            wait_ns += int(fields[1])
+        except (OSError, ValueError, IndexError):  # noqa: CC04 — the thread ended between the listing and the read
+            continue
+    if oncpu_ns:
+        out["oncpu"], out["runqueue"] = oncpu_ns / 1e9, wait_ns / 1e9
+    else:
+        out["oncpu"] = time.process_time()
+    return out
 
 
 class CompileWatcher:
@@ -236,6 +280,9 @@ class RuntimeTelemetry:
             warmup=int(os.environ.get("ANOMALY_WARMUP_STEPS", "30")),
         )
         self.dispatches_total = 0
+        # last reading of read_host_cpu, so each refresh adds the growth
+        self._host_cpu_seen = dict.fromkeys(
+            ("steal", "all", "oncpu", "runqueue"), 0.0)
         self.anomalies_total = 0
         self.anomalies: deque = deque(maxlen=64)
         self.profile_captures: list[dict] = []
@@ -271,6 +318,13 @@ class RuntimeTelemetry:
         span = tracing.current_span()
         if span is not None:
             tracing.bump_root_attribute_of(span, "dispatches", count)
+
+    def note_h2d(self, transfers: int, nbytes: int) -> None:
+        """The host arguments one launch of the index-mode program was
+        handed (``serve/scorer._launch_cached``): count and bytes."""
+        if self.metrics is not None:
+            self.metrics.h2d_transfers_total.inc(transfers)
+            self.metrics.h2d_bytes_total.inc(nbytes)
 
     def observe_span(self, span) -> None:
         name = getattr(span, "name", "")
@@ -341,6 +395,24 @@ class RuntimeTelemetry:
 
     # -- gauges + snapshot ---------------------------------------------------
 
+    def refresh_host_counters(self) -> None:
+        """Steal, run-queue wait and CPU seconds onto the bound registry;
+        a registry refresher (every render), so no request pays for the
+        reads. Threads that ended took their schedstat with them: a sum
+        that fell adds nothing."""
+        m = self.metrics
+        if m is None:
+            return
+        now = read_host_cpu()
+        with self._lock:
+            seen, self._host_cpu_seen = self._host_cpu_seen, now
+        for key, counter in (
+                ("steal", m.host_cpu_steal_seconds_total),
+                ("all", m.host_cpu_seconds_total),
+                ("runqueue", m.process_runqueue_wait_seconds_total),
+                ("oncpu", m.process_cpu_seconds_total)):
+            counter.inc(max(0.0, now[key] - seen[key]))
+
     def refresh_gauges(self) -> None:
         """Arena / HBM occupancy onto the bound metrics registry —
         called on each /metrics scrape so the gauges are scrape-fresh."""
@@ -399,10 +471,11 @@ def install(metrics=None) -> RuntimeTelemetry:
     process default (replacing the previous one — the most recently
     constructed risk service owns the sinks, same contract as metrics)."""
     global DEFAULT
-    if DEFAULT is not None:
-        tracing.remove_span_sink(DEFAULT.observe_span)
+    uninstall()
     DEFAULT = RuntimeTelemetry(metrics)
     tracing.add_span_sink(DEFAULT.observe_span)
+    if metrics is not None:
+        metrics.registry.add_refresher(DEFAULT.refresh_host_counters)
     return DEFAULT
 
 
@@ -410,6 +483,9 @@ def uninstall() -> None:
     global DEFAULT
     if DEFAULT is not None:
         tracing.remove_span_sink(DEFAULT.observe_span)
+        if DEFAULT.metrics is not None:
+            DEFAULT.metrics.registry.remove_refresher(
+                DEFAULT.refresh_host_counters)
         DEFAULT = None
 
 
@@ -432,3 +508,11 @@ def note_dispatch(count: int = 1) -> None:
     t = DEFAULT
     if t is not None:
         t.note_dispatch(count)
+
+
+def note_h2d(transfers: int, nbytes: int) -> None:
+    """Launch-seam helper (serve/scorer._launch_cached). No-op without a
+    process-default telemetry."""
+    t = DEFAULT
+    if t is not None:
+        t.note_h2d(transfers, nbytes)

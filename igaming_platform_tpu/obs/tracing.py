@@ -5,7 +5,7 @@ infrastructure-ready, not wired"); per-request latency is hand-measured.
 Here tracing is wired two ways:
 
 - device side: `jax.profiler` trace capture + named step annotations
-  (``annotate``/``step``) that show up on the TPU timeline;
+  (``annotate``) that show up on the TPU timeline;
 - host side: lightweight spans (``span``) collected into an in-process
   buffer exportable as JSON — the OTLP-shaped record without requiring an
   OTLP endpoint in the image.
@@ -24,52 +24,224 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
+import os
+import random
 import re
 import threading
 import time
-import uuid
-from dataclasses import dataclass, field
+from collections import deque
 from typing import Callable
 
 import jax
 
 
-@dataclass
+# Span ids: a process-random 64-bit base plus a counter (``next`` on an
+# ``itertools.count`` is one GIL-atomic step), formatted to 16 hex digits
+# only when somebody reads ``span_id``. Trace ids come from a generator
+# seeded once from the OS at import: no span pays a system call for an id.
+_SPAN_ID_MASK = (1 << 64) - 1
+_SPAN_SEQ = itertools.count(1)
+_TRACE_RNG = random.Random()
+_SPAN_ID_BASE = 0
+
+
+def _seed_ids() -> None:
+    global _SPAN_ID_BASE
+    seed = random.SystemRandom().getrandbits(192)
+    _SPAN_ID_BASE = seed & _SPAN_ID_MASK
+    _TRACE_RNG.seed(seed >> 64)
+
+
+_seed_ids()
+os.register_at_fork(after_in_child=_seed_ids)  # a forked child mints its own
+
+
+def _new_trace_id() -> str:
+    return "%032x" % (_TRACE_RNG.getrandbits(128) or 1)
+
+
+# Thread CPU is read on one trace in CPU_SAMPLE_EVERY (the root decides,
+# its spans follow) and counted CPU_SAMPLE_EVERY-fold by whoever sums it.
+# ``time.thread_time()`` is a real system call: 0.4 us on a bare kernel but
+# 6 us in the sandboxed machines the chips sit in (PERF.md, PR 38), twice a
+# span. Seven shares no period with frame sizes that come in pairs.
+CPU_SAMPLE_EVERY = 7
+_ROOT_SEQ = itertools.count()
+
+
 class Span:
-    name: str
-    start: float
-    end: float = 0.0
-    # Monotonic companion clock (time.perf_counter). ``start``/``end``
-    # are wall stamps for display ("when did this happen"); DURATIONS
-    # come from the monotonic pair — time.time() steps under NTP, and a
-    # slew mid-span would mint a negative or inflated stage cost that
-    # the µs/row accounting (obs/hostprof.py) would then publish as
-    # fact. MX06 (obs scope) enforces this split going forward.
-    mono_start: float = 0.0
-    mono_end: float = 0.0
-    trace_id: str = ""
-    span_id: str = ""
-    parent_id: str = ""
-    attributes: dict = field(default_factory=dict)
-    # Root spans only: summed child-stage durations (ms) by span name —
-    # the per-request decomposition the flight recorder snapshots.
-    stage_totals: dict | None = field(default=None, repr=False, compare=False)
-    # Root spans only: (start, end) of each completed descendant stage,
-    # on the MONOTONIC clock (same epoch as mono_start/mono_end).
-    # With pipelined serving, stages of one request run CONCURRENTLY on
-    # different worker threads, so the busy-time sum (stage_totals) can
-    # exceed the request's wall time; the interval union of these
-    # windows is the honest "time attributed to stages" figure, and
-    # 1 - union/sum is the request's host-stage overlap ratio.
-    stage_windows: list | None = field(default=None, repr=False, compare=False)
-    root: "Span | None" = field(default=None, repr=False, compare=False)
+    """One host span. ``with span(...)`` builds it, and it is its own
+    context manager (no generator frame per span).
+
+    ``start``/``end`` are wall stamps for display ("when did this
+    happen"), derived from the monotonic pair and the root's one
+    wall-clock reading; DURATIONS come from the monotonic pair alone —
+    time.time() steps under NTP, and a slew mid-span would mint a
+    negative or inflated stage cost that the µs/row accounting
+    (obs/hostprof.py) would then publish as fact. MX06 (obs scope)
+    enforces this split going forward.
+
+    Exclusive accounting: a span that ends on its parent's thread adds
+    its duration and its thread CPU to the parent's ``child_s`` /
+    ``child_cpu_s``, so ``self_s`` (duration minus children) and
+    ``self_cpu_s`` (thread CPU minus children's) are what THIS span's
+    own code cost. A child attached with ``parent=`` from another thread
+    runs beside its parent and is not subtracted. Thread CPU is read
+    only where ``cpu_sampled`` says so (``CPU_SAMPLE_EVERY``).
+
+    Root spans only: ``stage_totals`` holds summed child-stage durations
+    (ms) by span name — the per-request decomposition the flight
+    recorder snapshots — and ``stage_windows`` the (start, end) of each
+    completed descendant stage on the MONOTONIC clock. With pipelined
+    serving, stages of one request run CONCURRENTLY on different worker
+    threads, so the busy-time sum (stage_totals) can exceed the
+    request's wall time; the interval union of these windows is the
+    honest "time attributed to stages" figure, and 1 - union/sum is the
+    request's host-stage overlap ratio.
+    """
+
+    __slots__ = (
+        "name", "start", "end", "mono_start", "mono_end", "trace_id",
+        "attributes", "stage_totals", "stage_windows", "root",
+        "child_s", "child_cpu_s", "children", "cpu_s", "cpu_sampled",
+        "_sid", "_span_id", "_parent", "_parent_id", "_collector",
+        "_cpu_start", "_tid", "_token", "_wall_offset")
+
+    def __init__(self, name: str, start: float = 0.0, end: float = 0.0,
+                 mono_start: float = 0.0, mono_end: float = 0.0,
+                 trace_id: str = "", span_id: str = "", parent_id: str = "",
+                 attributes: dict | None = None):
+        self.name = name
+        self.start = start            # wall stamps (unix seconds)
+        self.end = end
+        self.mono_start = mono_start  # time.perf_counter()
+        self.mono_end = mono_end
+        self.trace_id = trace_id
+        self.attributes = {} if attributes is None else attributes
+        self.stage_totals = None
+        self.stage_windows = None
+        self.root = None
+        self.child_s = 0.0       # same-thread children's summed duration
+        self.child_cpu_s = 0.0   # ... and thread CPU
+        self.children = 0
+        self.cpu_s = 0.0         # this span's thread CPU, children included
+        self.cpu_sampled = False  # whether this trace reads thread CPU
+        self._sid = 0            # span id as a number; span_id formats it
+        self._span_id = span_id
+        self._parent = None
+        self._parent_id = parent_id  # a remote parent's (traceparent)
+        self._collector = None
+        self._tid = 0            # ident of the thread it was entered on
+        self._wall_offset = 0.0  # wall clock minus perf_counter, per trace
+
+    def __repr__(self) -> str:
+        return (f"Span(name={self.name!r}, trace_id={self.trace_id!r}, "
+                f"span_id={self.span_id!r}, parent_id={self.parent_id!r}, "
+                f"duration_ms={self.duration_ms:.3f})")
+
+    @property
+    def span_id(self) -> str:
+        sid = self._span_id
+        if not sid and self._sid:
+            sid = self._span_id = "%016x" % self._sid
+        return sid
+
+    @property
+    def parent_id(self) -> str:
+        parent = self._parent
+        return parent.span_id if parent is not None else self._parent_id
 
     @property
     def duration_ms(self) -> float:
         if self.mono_end:
             return (self.mono_end - self.mono_start) * 1000.0
         return (self.end - self.start) * 1000.0
+
+    @property
+    def self_s(self) -> float:
+        """Exclusive wall seconds: duration minus same-thread children."""
+        return max(0.0, self.mono_end - self.mono_start - self.child_s)
+
+    @property
+    def self_cpu_s(self) -> float:
+        """Exclusive thread-CPU seconds."""
+        return max(0.0, self.cpu_s - self.child_cpu_s)
+
+    # -- the context manager ------------------------------------------------
+
+    def __enter__(self) -> "Span":
+        self._token = _CURRENT.set(self)
+        tid = self._tid = threading.get_ident()
+        _ACTIVE_BY_THREAD[tid] = self
+        if self.cpu_sampled:
+            self._cpu_start = time.thread_time()
+        mono = self.mono_start = time.perf_counter()
+        self.start = mono + self._wall_offset
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        mono_end = self.mono_end = time.perf_counter()
+        if self.cpu_sampled:
+            self.cpu_s = time.thread_time() - self._cpu_start
+        self.end = mono_end + self._wall_offset
+        _CURRENT.reset(self._token)
+        # span() and carry() set the context and the thread's entry
+        # together, so what the context holds again is what was active
+        tid = self._tid
+        prior = _CURRENT.get()
+        if prior is not None:
+            _ACTIVE_BY_THREAD[tid] = prior
+        else:
+            _ACTIVE_BY_THREAD.pop(tid, None)
+        parent = self._parent
+        if parent is not None and parent._tid == tid:
+            # only this thread touches its own chain: no lock
+            parent.child_s += mono_end - self.mono_start
+            parent.child_cpu_s += self.cpu_s
+            parent.children += 1
+        self._complete()
+        return False
+
+    def _complete(self) -> None:
+        """Everything a finished span feeds: the ring, its root's stage
+        accounting, the span sinks, and for a root the root sinks. A
+        failing sink must never fail the traced request."""
+        collector = self._collector or DEFAULT_COLLECTOR
+        collector.add(self)
+        root = self.root
+        if root is not self and root is not None and root.stage_totals is not None:
+            name = self.name
+            with _STAGE_LOCK:
+                totals = root.stage_totals
+                totals[name] = totals.get(name, 0.0) + (
+                    self.mono_end - self.mono_start) * 1000.0
+                windows = root.stage_windows
+                if windows is not None and len(windows) < _MAX_STAGE_WINDOWS:
+                    windows.append((self.mono_start, self.mono_end))
+        if _SPAN_SINK is not None:
+            try:
+                _SPAN_SINK(self)
+            except Exception:  # noqa: BLE001 — sinks must not fail requests
+                pass
+        for sink in _EXTRA_SPAN_SINKS:
+            try:
+                sink(self)
+            except Exception:  # noqa: BLE001 — sinks must not fail requests
+                pass
+        if root is self:
+            collector.report_drops()
+            if _ROOT_SINK is not None:
+                try:
+                    _ROOT_SINK(self)
+                except Exception:  # noqa: BLE001 — sinks must not fail requests
+                    pass
+            for sink in _EXTRA_ROOT_SINKS:
+                try:
+                    sink(self)
+                except Exception:  # noqa: BLE001 — sinks must not fail requests
+                    pass
 
 
 # -- W3C trace context -------------------------------------------------------
@@ -157,8 +329,8 @@ def union_duration_ms(windows: list | None) -> float:
 # slot — add/remove are idempotent, and extras fire AFTER the primary.
 _SPAN_SINK: Callable[[Span], None] | None = None
 _ROOT_SINK: Callable[[Span], None] | None = None
-_EXTRA_SPAN_SINKS: list[Callable[[Span], None]] = []
-_EXTRA_ROOT_SINKS: list[Callable[[Span], None]] = []
+_EXTRA_SPAN_SINKS: tuple[Callable[[Span], None], ...] = ()
+_EXTRA_ROOT_SINKS: tuple[Callable[[Span], None], ...] = ()
 
 
 def set_span_sink(fn: Callable[[Span], None] | None) -> None:
@@ -172,23 +344,25 @@ def set_root_sink(fn: Callable[[Span], None] | None) -> None:
 
 
 def add_span_sink(fn: Callable[[Span], None]) -> None:
+    global _EXTRA_SPAN_SINKS
     if fn not in _EXTRA_SPAN_SINKS:
-        _EXTRA_SPAN_SINKS.append(fn)
+        _EXTRA_SPAN_SINKS += (fn,)
 
 
 def remove_span_sink(fn: Callable[[Span], None]) -> None:
-    if fn in _EXTRA_SPAN_SINKS:
-        _EXTRA_SPAN_SINKS.remove(fn)
+    global _EXTRA_SPAN_SINKS
+    _EXTRA_SPAN_SINKS = tuple(f for f in _EXTRA_SPAN_SINKS if f != fn)
 
 
 def add_root_sink(fn: Callable[[Span], None]) -> None:
+    global _EXTRA_ROOT_SINKS
     if fn not in _EXTRA_ROOT_SINKS:
-        _EXTRA_ROOT_SINKS.append(fn)
+        _EXTRA_ROOT_SINKS += (fn,)
 
 
 def remove_root_sink(fn: Callable[[Span], None]) -> None:
-    if fn in _EXTRA_ROOT_SINKS:
-        _EXTRA_ROOT_SINKS.remove(fn)
+    global _EXTRA_ROOT_SINKS
+    _EXTRA_ROOT_SINKS = tuple(f for f in _EXTRA_ROOT_SINKS if f != fn)
 
 
 def current_span() -> Span | None:
@@ -230,22 +404,32 @@ class SpanCollector:
 
     def __init__(self, capacity: int = 4096):
         self.capacity = capacity
-        self._spans: list[Span] = []
+        self._spans: deque[Span] = deque(maxlen=capacity)
         self._lock = threading.Lock()
-        # Past capacity the OLDEST spans are evicted; that loss is counted
-        # (and surfaced as <service>_spans_dropped_total via on_drop) so a
-        # sampling gap in /debug/spans or the OTLP export is visible.
+        # Past capacity the OLDEST span is evicted per add (O(1): the
+        # deque drops it); that loss is counted (and surfaced as
+        # <service>_spans_dropped_total via on_drop) so a sampling gap in
+        # /debug/spans or the OTLP export is visible.
         self.dropped_total = 0
         self.on_drop: Callable[[int], None] | None = None
+        self._unreported = 0  # drops on_drop has not been told of yet
 
     def add(self, span: Span) -> None:
-        dropped = 0
         with self._lock:
-            self._spans.append(span)
-            if len(self._spans) > self.capacity:
-                dropped = len(self._spans) - self.capacity
-                self._spans = self._spans[-self.capacity:]
-                self.dropped_total += dropped
+            spans = self._spans
+            if len(spans) == self.capacity:
+                self.dropped_total += 1
+                self._unreported += 1
+            spans.append(span)
+
+    def report_drops(self) -> None:
+        """Tell ``on_drop`` of the drops since it was last told: once per
+        completed root and whenever the ring is read, not once per span
+        (a full ring drops one span per add)."""
+        if not self._unreported:
+            return
+        with self._lock:
+            dropped, self._unreported = self._unreported, 0
             on_drop = self.on_drop
         if dropped and on_drop is not None:
             try:
@@ -254,34 +438,37 @@ class SpanCollector:
                 pass
 
     def drain(self) -> list[Span]:
+        self.report_drops()
         with self._lock:
-            out, self._spans = self._spans, []
+            out = list(self._spans)
+            self._spans.clear()
             return out
 
     def to_json(self) -> str:
+        self.report_drops()
         with self._lock:
-            return json.dumps([
-                {
-                    "name": s.name,
-                    "trace_id": s.trace_id,
-                    "span_id": s.span_id,
-                    "parent_id": s.parent_id,
-                    "start_unix_s": s.start,
-                    "duration_ms": s.duration_ms,
-                    "attributes": s.attributes,
-                }
-                for s in self._spans
-            ])
+            spans = list(self._spans)
+        return json.dumps([
+            {
+                "name": s.name,
+                "trace_id": s.trace_id,
+                "span_id": s.span_id,
+                "parent_id": s.parent_id,
+                "start_unix_s": s.start,
+                "duration_ms": s.duration_ms,
+                "attributes": s.attributes,
+            }
+            for s in spans
+        ])
 
 
 DEFAULT_COLLECTOR = SpanCollector()
 
 
-@contextlib.contextmanager
 def span(name: str, collector: SpanCollector | None = None, *,
          traceparent: str | None = None, parent: Span | None = None,
-         **attributes):
-    """Host-side span around a serving stage.
+         **attributes) -> Span:
+    """Host-side span around a serving stage: ``with span(...) as s``.
 
     Nested use on one thread links parent/child automatically; a root
     span may instead adopt a remote parent from a ``traceparent`` header
@@ -292,74 +479,32 @@ def span(name: str, collector: SpanCollector | None = None, *,
     durations into ``stage_totals`` (plus their (start, end) windows for
     overlap accounting) and fire the flight-recorder sink.
     """
-    collector = collector or DEFAULT_COLLECTOR
+    s = Span(name, attributes=attributes)
+    s._collector = collector
+    s._sid = (_SPAN_ID_BASE + next(_SPAN_SEQ)) & _SPAN_ID_MASK or 1
     ctx_parent = _CURRENT.get()
-    if ctx_parent is None and parent is not None:
+    if ctx_parent is None:
         ctx_parent = parent
-    parent = ctx_parent
-    trace_id = parent_id = ""
-    if parent is not None:
-        trace_id, parent_id = parent.trace_id, parent.span_id
-    elif traceparent is not None:
+    if ctx_parent is not None:
+        s._parent = ctx_parent
+        s.trace_id = ctx_parent.trace_id
+        root = s.root = ctx_parent.root or ctx_parent
+        s._wall_offset = root._wall_offset
+        s.cpu_sampled = root.cpu_sampled
+        return s
+    if traceparent is not None:
         parsed = parse_traceparent(traceparent)
         if parsed is not None:
-            trace_id, parent_id = parsed
-    if not trace_id:
-        trace_id = uuid.uuid4().hex
-    s = Span(name=name, start=time.time(), mono_start=time.perf_counter(),
-             trace_id=trace_id,
-             span_id=uuid.uuid4().hex[:16], parent_id=parent_id,
-             attributes=attributes)
-    if parent is None:
-        s.stage_totals = {}
-        s.stage_windows = []
-        s.root = s
-    else:
-        s.root = parent.root if parent.root is not None else parent
-    token = _CURRENT.set(s)
-    ident = threading.get_ident()
-    prior_active = _ACTIVE_BY_THREAD.get(ident)
-    _ACTIVE_BY_THREAD[ident] = s
-    try:
-        yield s
-    finally:
-        _CURRENT.reset(token)
-        if prior_active is not None:
-            _ACTIVE_BY_THREAD[ident] = prior_active
-        else:
-            _ACTIVE_BY_THREAD.pop(ident, None)
-        s.mono_end = time.perf_counter()
-        s.end = time.time()
-        collector.add(s)
-        root = s.root
-        if root is not None and root is not s and root.stage_totals is not None:
-            with _STAGE_LOCK:
-                root.stage_totals[s.name] = (
-                    root.stage_totals.get(s.name, 0.0) + s.duration_ms)
-                if (root.stage_windows is not None
-                        and len(root.stage_windows) < _MAX_STAGE_WINDOWS):
-                    root.stage_windows.append((s.mono_start, s.mono_end))
-        if _SPAN_SINK is not None:
-            try:
-                _SPAN_SINK(s)
-            except Exception:  # noqa: BLE001 — sinks must not fail requests
-                pass
-        for sink in tuple(_EXTRA_SPAN_SINKS):
-            try:
-                sink(s)
-            except Exception:  # noqa: BLE001 — sinks must not fail requests
-                pass
-        if root is s:
-            if _ROOT_SINK is not None:
-                try:
-                    _ROOT_SINK(s)
-                except Exception:  # noqa: BLE001 — sinks must not fail requests
-                    pass
-            for sink in tuple(_EXTRA_ROOT_SINKS):
-                try:
-                    sink(s)
-                except Exception:  # noqa: BLE001 — sinks must not fail requests
-                    pass
+            s.trace_id, s._parent_id = parsed
+    if not s.trace_id:
+        s.trace_id = _new_trace_id()
+    s.stage_totals = {}
+    s.stage_windows = []
+    s.root = s
+    s.cpu_sampled = next(_ROOT_SEQ) % CPU_SAMPLE_EVERY == 0
+    # the trace's one reading of the wall clock
+    s._wall_offset = time.time() - time.perf_counter()
+    return s
 
 
 @contextlib.contextmanager
@@ -387,16 +532,9 @@ def carry(parent: "Span | None"):
             _ACTIVE_BY_THREAD.pop(ident, None)
 
 
-@contextlib.contextmanager
 def annotate(name: str):
-    """Named region on the device profile timeline."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def step(name: str, step_num: int):
-    """Training-step marker (shows as steps in the profiler UI)."""
-    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+    """Named region on the device profile timeline (a context manager)."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager

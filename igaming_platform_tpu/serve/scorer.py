@@ -229,6 +229,9 @@ class TPUScoringEngine:
         self._thresholds = np.array(
             [self.config.block_threshold, self.config.review_threshold], dtype=np.int32
         )
+        # (program label, padded shape) -> (host arguments, their bytes):
+        # what one launch of the index program hands over the link.
+        self._h2d_cost: dict[tuple, tuple[int, int]] = {}
         self._mesh = mesh
         # Slot-sharded device state (parallel/state_sharding.py,
         # ROADMAP item 2): on a mesh with a >1 ``data`` axis the HBM
@@ -1170,9 +1173,16 @@ class TPUScoringEngine:
             # device append order must match host (and therefore ledger /
             # replay) order, and the donated ring buffers are rebound
             # before anyone else can dispatch against them.
-            asked = time.perf_counter()
-            with mgr.lock:
-                got = time.perf_counter()
+            held = False
+            try:
+                # asked / got stamp the acquire alone (the counter behind
+                # session_lock_wait_us_per_row); the span's own entry and
+                # exit are its cost, and its exit runs inside the try
+                with span("score.lock_wait", batch=n):
+                    asked = time.perf_counter()
+                    mgr.lock.acquire()
+                    held = True
+                    got = time.perf_counter()
                 ts = now if now is not None else ledger_mod.wall_clock()
                 # Session bookkeeping rides its own span (hostprof us/row).
                 with span("score.session", batch=n):
@@ -1180,33 +1190,58 @@ class TPUScoringEngine:
                         groups, amounts, types, ts)
                 with span("score.pad", batch=n):
                     evp, _ = pad_batch(events, shape)
-                _device_dispatch(label, idxsp.shape, idxsp.dtype)
-                out, ring2, cur2, len2, *extra = ffn(
-                    params, mgr.head_params, self.cache.table,
-                    self.cache.flags, mgr.session_ring, mgr.session_cursor,
-                    mgr.session_length, idxsp, sidxp, occp, amtp, typp, evp,
-                    blp, self._thresholds, cand, np.int32(n))
-                mgr.adopt(ring2, cur2, len2)
+                with span("score.launch", batch=n):
+                    args = (params, mgr.head_params, self.cache.table,
+                            self.cache.flags, mgr.session_ring,
+                            mgr.session_cursor, mgr.session_length, idxsp,
+                            sidxp, occp, amtp, typp, evp, blp,
+                            self._thresholds, cand, np.int32(n))
+                    self._note_launch(label, idxsp, args)
+                    out, ring2, cur2, len2, *extra = ffn(*args)
+                    mgr.adopt(ring2, cur2, len2)
                 mgr.note_lock(got - asked, time.perf_counter() - got)
+            finally:
+                if held:
+                    mgr.lock.release()
             smeta = {"ts": ts, "lens": post_len, "seqs": seqs,
                      "hashes": audit}
         else:
-            _device_dispatch(label, idxsp.shape, idxsp.dtype)
-            out, *extra = ffn(
-                params, cand, self.cache.table, self.cache.flags, idxsp,
-                amtp, typp, blp, self._thresholds, np.int32(n))
+            with span("score.launch", batch=n):
+                args = (params, cand, self.cache.table, self.cache.flags,
+                        idxsp, amtp, typp, blp, self._thresholds, np.int32(n))
+                self._note_launch(label, idxsp, args)
+                out, *extra = ffn(*args)
         # After the family's outputs: [sketch][, shadow_packed]. Without
         # an in-graph sketch the split kernel re-gathers the scored rows
         # on device: they never exist on the host (obs/drift.py).
         sk = extra[0] if has_sketch else None
         sh = extra[-1] if sstate is not None else None
-        self._note_drift_cached(idxsp, amtp, typp, out, n, sketch=sk)
-        self._note_shadow(out, None, blp, n, self._thresholds,
-                          shadow_out=sh,
-                          gen=sstate[0] if sstate is not None else None)
-        if hasattr(out, "copy_to_host_async"):
-            out.copy_to_host_async()
+        with span("score.post_launch", batch=n):
+            self._note_drift_cached(idxsp, amtp, typp, out, n, sketch=sk)
+            self._note_shadow(out, None, blp, n, self._thresholds,
+                              shadow_out=sh,
+                              gen=sstate[0] if sstate is not None else None)
+            if hasattr(out, "copy_to_host_async"):
+                out.copy_to_host_async()
         return out, n, smeta
+
+    def _note_launch(self, label: str, idxsp: np.ndarray, args: tuple) -> None:
+        """The launch seam of the index program (``_device_dispatch``), and
+        what the launch hands over the link: every host (numpy) leaf of
+        ``args`` is its own host-to-device transfer. Which leaves are host
+        arrays is a property of the program and its padded shape, so it is
+        reckoned once per (label, shape) and added per launch."""
+        from igaming_platform_tpu.obs import runtime_telemetry as _rt
+
+        _device_dispatch(label, idxsp.shape, idxsp.dtype)
+        key = (label, idxsp.shape)
+        cost = self._h2d_cost.get(key)
+        if cost is None:
+            host = [a for a in jax.tree_util.tree_leaves(args)
+                    if isinstance(a, (np.ndarray, np.generic))]
+            cost = self._h2d_cost[key] = (
+                len(host), sum(int(a.nbytes) for a in host))
+        _rt.note_h2d(*cost)
 
     def _blacklist_flags(self, n: int, ips, devices, fingerprints) -> np.ndarray:
         """Per-request blacklist vector from the host sets — the cheap
@@ -1251,6 +1286,10 @@ class TPUScoringEngine:
         def read_one() -> None:
             out, lo, n, smeta = inflight.popleft()
             with span("score.readback", batch=n):
+                # the wait for the step apart from the copy: what is left
+                # of readback is the link and the unpacking
+                with span("score.device_wait", batch=n):
+                    jax.block_until_ready(out)
                 host = _unpack_host(_device_readback(out))
             for k in keys:
                 parts[k].append(host[k][:n])
@@ -1279,7 +1318,8 @@ class TPUScoringEngine:
                 # key their host indexes by the same ``str``
                 ids = session_mod.decoded_ids(account_ids[lo:hi])
                 idxs = self.cache.lookup(ids, now=now)
-            self.lane_gate.acquire(LANE_BULK)
+            with span("score.lane_wait", batch=hi - lo):
+                self.lane_gate.acquire(LANE_BULK)
             with span("score.dispatch", batch=hi - lo), annotate("score_step"):
                 out, n, smeta = self._launch_cached(
                     idxs, amounts32[lo:hi], types32[lo:hi], bl[lo:hi], snap,

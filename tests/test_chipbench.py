@@ -22,6 +22,7 @@ from chipbench.tests.test_manifest import *  # noqa: F401,F403
 from chipbench.tests.test_reference import *  # noqa: F401,F403
 from chipbench.tests.test_seam import *  # noqa: F401,F403
 from chipbench.tests.test_seam import sourced as _seam_sourced
+from chipbench.tests.test_span_metrics import *  # noqa: F401,F403
 from chipbench.tests.test_trace_reduce import *  # noqa: F401,F403
 from chipbench.tests.test_traffic import *  # noqa: F401,F403
 

@@ -50,6 +50,9 @@ class _FakeHist:
     def observe(self, value, **labels):
         self.calls.append((value, labels))
 
+    def observe_key(self, key, value, exemplar=None):
+        self.observe(value, exemplar=exemplar, **dict(key))
+
 
 class _FakeCounter(_FakeHist):
     def inc(self, **labels):
@@ -133,6 +136,147 @@ def test_reset_zeroes_accounting(profiler):
     snap = profiler.snapshot()
     assert snap["stages"] == {} and snap["rpc"]["rpcs"] == 0
     assert snap["sampler"]["samples_total"] == 0
+
+
+def _burn(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.fixture
+def every_trace_reads_cpu(monkeypatch):
+    monkeypatch.setattr(tracing, "CPU_SAMPLE_EVERY", 1)
+
+
+def test_sampled_thread_cpu_stands_for_every_trace(profiler):
+    """Thread CPU is read on one trace in seven and counted seven-fold:
+    over 70 like requests the stage's ``cpu_us`` is the CPU of all 70."""
+    for _ in range(70):
+        with tracing.span("rpc.ScoreBatch"):
+            with tracing.span("score.session", batch=8):
+                _burn(0.0005)
+    row = profiler.snapshot()["stages"]["session"]
+    assert row["spans"] == 70
+    assert row["cpu_us"] == pytest.approx(70 * 500, rel=0.2)
+    assert row["cpu_us"] <= row["self_us"] * 1.1
+
+
+def test_exclusive_wall_and_cpu_per_stage(profiler, every_trace_reads_cpu):
+    """``self_us`` is a stage's duration minus its same-thread child
+    spans, ``cpu_us`` its thread CPU likewise; a ``<stage>.self`` row
+    (its ``total_us`` the exclusive wall, for readers that take rows by
+    name) appears only for a stage that has had a child."""
+    with tracing.span("rpc.ScoreBatch"):
+        for _ in range(3):
+            with tracing.span("score.dispatch", batch=64):
+                with tracing.span("score.session", batch=64):
+                    _burn(0.002)
+                with tracing.span("score.launch", batch=64):
+                    with tracing.span("score.inner", batch=64):
+                        _burn(0.001)
+                _burn(0.001)
+            with tracing.span("score.device_wait", batch=64):
+                time.sleep(0.02)
+    stages = profiler.snapshot()["stages"]
+    assert set(stages) == {"dispatch", "dispatch.self", "session", "launch",
+                           "launch.self", "inner", "device_wait"}
+    d, own = stages["dispatch"], stages["dispatch.self"]
+    assert d["spans"] == own["spans"] == 3 and own["rows"] == 192
+    # the envelope tiles: its own code + its children (a grandchild left
+    # with its parent, once)
+    tiled = d["self_us"] + stages["session"]["total_us"] + stages["launch"]["total_us"]
+    assert tiled == pytest.approx(d["total_us"], rel=1e-3, abs=1.0)
+    assert own["total_us"] == d["self_us"] and own["self_us"] == d["self_us"]
+    assert own["us_per_row"]["mean"] == pytest.approx(d["self_us"] / 192, rel=1e-3)
+    assert stages["launch.self"]["total_us"] == pytest.approx(
+        stages["launch"]["total_us"] - stages["inner"]["total_us"], abs=1.0)
+    # a leaf's exclusive time is all of it
+    for leaf in ("session", "inner", "device_wait"):
+        assert stages[leaf]["self_us"] == pytest.approx(
+            stages[leaf]["total_us"], abs=0.2)
+    for row in stages.values():
+        assert 0.0 <= row["cpu_us"] <= row["self_us"] * 1.05 + 100.0
+    assert stages["session"]["cpu_us"] >= 3 * 2000 * 0.95
+    # asleep, the thread was on no CPU: the span's two clocks, compared
+    assert stages["device_wait"]["cpu_us"] < 0.2 * stages["device_wait"]["self_us"]
+    assert d["self_us"] >= 3 * 1000 * 0.95
+
+
+def test_exclusive_seconds_reach_metrics_at_render_not_per_span(
+        profiler, every_trace_reads_cpu):
+    """The two /metrics counters are brought up to date by the registry's
+    refresher: a span adds to the profiler's accumulators only, and a
+    render adds what has grown since the last one, once."""
+    from igaming_platform_tpu.obs.metrics import ServiceMetrics
+
+    m = ServiceMetrics("risk")
+    profiler.bind_metrics(m)
+    with tracing.span("rpc.ScoreBatch"):
+        with tracing.span("score.dispatch", batch=8):
+            with tracing.span("score.launch", batch=8):
+                _burn(0.002)
+    assert m.host_stage_self_seconds_total.value(stage="launch") == 0.0
+    text = m.registry.render_text()
+    self_s = m.host_stage_self_seconds_total.value(stage="launch")
+    cpu_s = m.host_stage_cpu_seconds_total.value(stage="launch")
+    assert self_s >= 0.0019 and 0.0019 <= cpu_s <= self_s * 1.05 + 1e-4
+    assert 'risk_host_stage_self_seconds_total{stage="dispatch"}' in text
+    assert "dispatch.self" not in text  # a row of the table, not a label
+    m.registry.render_text()  # nothing grew: nothing is added twice
+    assert m.host_stage_self_seconds_total.value(stage="launch") == self_s
+    rows = profiler.snapshot()["stages"]
+    assert self_s == pytest.approx(rows["launch"]["self_us"] / 1e6, abs=1e-6)
+    # a profiler bound elsewhere no longer feeds this registry
+    profiler.bind_metrics(ServiceMetrics("risk"))
+    with tracing.span("score.launch", batch=8):
+        pass
+    m.registry.render_text()
+    assert m.host_stage_self_seconds_total.value(stage="launch") == self_s
+
+
+@pytest.mark.parametrize("kernel", ["bare", "sandboxed", "no_proc"])
+def test_who_else_had_the_cpu_reads_what_the_kernel_keeps(tmp_path, kernel):
+    """``read_host_cpu`` on a bare kernel (a ``cpu`` line, a schedstat a
+    thread), on a sandboxed one (the line all zeros, no schedstat: the
+    machines the chips sit in) and with no ``/proc``: what cannot be read
+    stays 0, so a share over it has no denominator and reads nothing;
+    only the process's CPU seconds fall back, to the same quantity from
+    ``time.process_time``."""
+    import os
+
+    from igaming_platform_tpu.obs.metrics import ServiceMetrics
+    from igaming_platform_tpu.obs.runtime_telemetry import (
+        RuntimeTelemetry, read_host_cpu)
+
+    hz = os.sysconf("SC_CLK_TCK")
+    if kernel == "bare":
+        (tmp_path / "stat").write_text(
+            f"cpu  {30 * hz} 0 {10 * hz} {50 * hz} 0 0 0 {10 * hz} 0 0\ncpu0 1 2\n")
+        for tid, (on, wait) in {"7": (4_000_000_000, 500_000_000),
+                                "8": (2_000_000_000, 250_000_000)}.items():
+            d = tmp_path / "self" / "task" / tid
+            d.mkdir(parents=True)
+            (d / "schedstat").write_text(f"{on} {wait} 12\n")
+    elif kernel == "sandboxed":
+        (tmp_path / "stat").write_text("cpu  0 0 0 0 0 0 0 0 0 0\n")
+        (tmp_path / "self" / "task" / "7").mkdir(parents=True)
+    got = read_host_cpu(str(tmp_path))
+    if kernel == "bare":
+        assert got == {"steal": 10.0, "all": 100.0, "oncpu": 6.0, "runqueue": 0.75}
+    else:
+        assert (got["steal"], got["all"], got["runqueue"]) == (0.0, 0.0, 0.0)
+        assert 0.0 < got["oncpu"] <= time.process_time()
+    # the live reading, folded into the counters at a render: each adds
+    # its growth, and none ever falls
+    m = ServiceMetrics("risk")
+    t = RuntimeTelemetry(m)
+    t.refresh_host_counters()
+    first = m.process_cpu_seconds_total.value()
+    _burn(0.002)
+    t.refresh_host_counters()
+    assert m.process_cpu_seconds_total.value() > first > 0.0
+    assert m.host_cpu_steal_seconds_total.value() <= m.host_cpu_seconds_total.value()
 
 
 # ---------------------------------------------------------------------------
@@ -319,6 +463,7 @@ def test_flight_entry_carries_host_cost_join():
         tracing.set_root_attribute("rows", 128)
         with tracing.span("score.decode") as dsp:
             dsp.attributes["batch"] = 128
+            _burn(0.001)  # an empty span is ~1 us: under the entry's rounding
         with tracing.span("score.dispatch"):
             pass
     rec.record_root_span(root)

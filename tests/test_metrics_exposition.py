@@ -17,8 +17,8 @@ from risk.v1 import risk_pb2
 _SAMPLE_RE = re.compile(
     r'^[a-zA-Z_:][a-zA-Z0-9_:]*'
     r'(\{[a-zA-Z_][a-zA-Z0-9_]*="[^"]*"(,[a-zA-Z_][a-zA-Z0-9_]*="[^"]*")*\})?'
-    r' -?[0-9eE+.infa]+'
-    r'( # \{trace_id="[0-9a-f]+"\} -?[0-9eE+.]+ [0-9.]+)?$')
+    r' -?[0-9eE+.infa-]+'  # a small float prints as 2.7e-05
+    r'( # \{trace_id="[0-9a-f]+"\} -?[0-9eE+.-]+ [0-9.]+)?$')
 _COMMENT_RE = re.compile(r"^# (HELP|TYPE) [a-zA-Z_:][a-zA-Z0-9_:]* .+$")
 
 

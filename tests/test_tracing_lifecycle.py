@@ -260,3 +260,384 @@ def test_workchannel_ships_traceparent_to_follower():
     fs = got["follower_span"]
     assert fs.trace_id == root.trace_id      # one trace across processes
     assert fs.parent_id == root.span_id      # front span is the parent
+
+
+# -- exclusive time, thread CPU, ids, the ring, the sinks (PR 38) ------------
+
+
+def _burn(seconds: float) -> None:
+    """Hold this thread on a CPU (a loop, not a sleep)."""
+    import time
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.fixture
+def every_trace_reads_cpu(monkeypatch):
+    """Thread CPU is read on one trace in ``CPU_SAMPLE_EVERY``; a test
+    that looks at one trace's CPU has it read on every trace."""
+    monkeypatch.setattr(tracing, "CPU_SAMPLE_EVERY", 1)
+
+
+def test_exclusive_time_of_nested_spans_on_one_thread(every_trace_reads_cpu):
+    """A parent's exclusive wall is its duration minus its same-thread
+    children; a grandchild is subtracted once (from its own parent, whose
+    whole duration the grandparent then takes out)."""
+    col = SpanCollector()
+    with span("rpc.T", col) as root:
+        with span("score.dispatch", col) as parent:
+            with span("score.session", col) as child:
+                with span("score.inner", col) as grand:
+                    _burn(0.002)
+                _burn(0.001)
+            with span("score.pad", col) as child2:
+                pass
+            _burn(0.001)
+
+    def dur(s):
+        return s.mono_end - s.mono_start
+
+    assert parent.children == 2 and child.children == 1
+    assert grand.children == 0 and root.children == 1
+    assert parent.child_s == pytest.approx(dur(child) + dur(child2))
+    assert child.child_s == pytest.approx(dur(grand))
+    assert parent.self_s == pytest.approx(dur(parent) - dur(child) - dur(child2))
+    # the grandchild sits inside `child`, so it left `parent` with it
+    assert parent.self_s + child.self_s + grand.self_s + child2.self_s == (
+        pytest.approx(dur(parent)))
+    assert root.self_s == pytest.approx(dur(root) - dur(parent))
+    # thread CPU nests the same way
+    assert parent.child_cpu_s == pytest.approx(child.cpu_s + child2.cpu_s)
+    assert grand.self_cpu_s == pytest.approx(grand.cpu_s)
+    assert 0.0 <= parent.self_cpu_s <= parent.cpu_s
+
+
+def test_child_attached_from_another_thread_is_not_subtracted():
+    """A ``parent=`` child on a pipeline worker runs BESIDE its parent:
+    it joins the trace and the root's stage totals, and leaves the
+    parent's exclusive time alone."""
+    col = SpanCollector()
+    done = threading.Event()
+    with span("rpc.T", col) as root:
+        with span("score.dispatch", col) as parent:
+            def worker():
+                with span("score.readback", col, parent=parent) as s:
+                    _burn(0.001)
+                worker.span = s
+                done.set()
+
+            t = threading.Thread(target=worker)
+            t.start()
+            assert done.wait(10.0)
+            t.join()
+    beside = worker.span
+    assert beside.trace_id == root.trace_id
+    assert beside.parent_id == parent.span_id
+    assert "score.readback" in root.stage_totals
+    assert parent.children == 0 and parent.child_s == 0.0
+    assert parent.self_s == pytest.approx(parent.mono_end - parent.mono_start)
+
+
+def test_thread_cpu_is_read_on_one_trace_in_seven_and_its_spans_follow():
+    """The root decides, deterministically by its number, and every span
+    of its trace follows it (a worker's ``parent=`` child too); a trace
+    that is not sampled makes no ``thread_time`` call and reads 0."""
+    assert tracing.CPU_SAMPLE_EVERY == 7
+    col = SpanCollector()
+    sampled = []
+    for _ in range(70):
+        with span("rpc.T", col) as root:
+            with span("score.a", col) as a:
+                with span("score.b", col) as b:
+                    _burn(0.0002)
+            got = {}
+
+            def worker(root=root, got=got):
+                with span("score.c", col, parent=root) as c:
+                    pass
+                got["c"] = c
+
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
+        flags = {root.cpu_sampled, a.cpu_sampled, b.cpu_sampled,
+                 got["c"].cpu_sampled}
+        assert len(flags) == 1
+        sampled.append(root.cpu_sampled)
+        if root.cpu_sampled:
+            assert b.cpu_s >= 0.0002 and a.child_cpu_s == b.cpu_s
+        else:
+            assert root.cpu_s == a.cpu_s == b.cpu_s == a.child_cpu_s == 0.0
+    assert sum(sampled) == 10
+    first = sampled.index(True)
+    assert all(sampled[i] == ((i - first) % 7 == 0) for i in range(70))
+
+
+def test_thread_cpu_is_under_wall_and_a_sleeping_span_reads_none(
+        every_trace_reads_cpu):
+    import time
+    col = SpanCollector()
+    with span("score.busy", col) as busy:
+        _burn(0.005)
+    with span("score.asleep", col) as asleep:
+        time.sleep(0.05)
+    for s in (busy, asleep):
+        wall = s.mono_end - s.mono_start
+        assert 0.0 <= s.cpu_s <= wall * 1.05 + 1e-4  # two clocks, read apart
+    assert busy.cpu_s >= 0.005
+    # asleep, the thread was on no CPU: a relation between the span's two
+    # clocks, not this machine's speed
+    assert asleep.cpu_s < 0.2 * (asleep.mono_end - asleep.mono_start)
+
+
+def test_ring_is_a_bounded_deque_and_counts_every_drop():
+    """O(1) by construction: a deque with a maxlen evicts on append and
+    nothing re-slices. At capacity every add drops exactly one."""
+    from collections import deque
+
+    col = SpanCollector(capacity=8)
+    assert isinstance(col._spans, deque) and col._spans.maxlen == 8
+    told = []
+    col.on_drop = told.append
+    ring = col._spans
+    for i in range(8 * 10):
+        with span(f"s{i}", col):
+            pass
+    assert col._spans is ring, "the ring is never rebuilt on add"
+    assert len(ring) == 8
+    assert col.dropped_total == 72 and sum(told) == 72
+    assert [s.name for s in col.drain()] == [f"s{i}" for i in range(72, 80)]
+    assert len(ring) == 0 and col.dropped_total == 72
+    # a child's drop is reported with its root, not lost
+    small = SpanCollector(capacity=1)
+    seen = []
+    small.on_drop = seen.append
+    with span("rpc.R", small):
+        with span("score.a", small):
+            pass
+        with span("score.b", small):
+            pass
+    assert small.dropped_total == 2 and sum(seen) == 2
+
+
+def test_ids_are_w3c_shaped_and_unique_over_100k_spans():
+    import re
+    col = SpanCollector(capacity=4)
+    hex32, hex16 = re.compile(r"^[0-9a-f]{32}$"), re.compile(r"^[0-9a-f]{16}$")
+    span_ids, trace_ids = set(), set()
+    for _ in range(10_000):
+        with span("rpc.T", col) as root:
+            for _ in range(9):
+                with span("score.x", col) as s:
+                    pass
+                span_ids.add(s.span_id)
+                assert s.trace_id is root.trace_id
+        span_ids.add(root.span_id)
+        trace_ids.add(root.trace_id)
+    assert len(span_ids) == 100_000 and len(trace_ids) == 10_000
+    assert all(hex16.match(i) for i in span_ids)
+    assert all(hex32.match(t) for t in trace_ids)
+    assert "0" * 16 not in span_ids and "0" * 32 not in trace_ids
+    # what leaves the process is still a W3C header that parses back
+    with span("rpc.T", col) as root:
+        with span("score.x", col) as s:
+            tp = current_traceparent()
+    assert parse_traceparent(tp) == (root.trace_id, s.span_id)
+    assert s.parent_id == root.span_id and s.span_id == s.span_id
+
+
+def test_no_urandom_inside_a_child_span(monkeypatch):
+    """A child span draws nothing from the OS: its trace id is its
+    root's, its span id a counter. Counted by patching, not timed."""
+    import os
+    import uuid
+
+    calls = []
+    real_urandom, real_uuid4 = os.urandom, uuid.uuid4
+
+    def counting_urandom(n):
+        calls.append(("urandom", n))
+        return real_urandom(n)
+
+    def counting_uuid4():
+        calls.append(("uuid4",))
+        return real_uuid4()
+
+    col = SpanCollector()
+    with span("rpc.T", col):
+        monkeypatch.setattr(os, "urandom", counting_urandom)
+        monkeypatch.setattr(uuid, "uuid4", counting_uuid4)
+        for _ in range(100):
+            with span("score.child", col) as s:
+                with span("score.grandchild", col):
+                    pass
+            assert s.span_id and s.trace_id and current_traceparent() is not None
+        monkeypatch.undo()
+    assert calls == []
+
+
+def test_every_sink_sees_every_span_once_and_a_raising_sink_fails_nothing():
+    seen_primary, seen_extra, seen_root = [], [], []
+
+    def boom(_span):
+        raise RuntimeError("a sink's own fault")
+
+    old_span_sink, old_root_sink = tracing._SPAN_SINK, tracing._ROOT_SINK
+    tracing.set_span_sink(seen_primary.append)
+    tracing.add_span_sink(boom)
+    tracing.add_span_sink(seen_extra.append)
+    tracing.add_span_sink(seen_extra.append)  # idempotent
+    tracing.set_root_sink(boom)
+    tracing.add_root_sink(seen_root.append)
+    try:
+        col = SpanCollector()
+        with span("rpc.T", col) as root:
+            with span("score.a", col) as a:
+                with span("score.b", col) as b:
+                    pass
+        with pytest.raises(ValueError):
+            with span("rpc.Fails", col) as failed:
+                raise ValueError("the request's own fault passes through")
+    finally:
+        tracing.set_span_sink(old_span_sink)
+        tracing.set_root_sink(old_root_sink)
+        tracing.remove_span_sink(boom)
+        tracing.remove_span_sink(seen_extra.append)
+        tracing.remove_root_sink(seen_root.append)
+    want = [b, a, root, failed]  # completion order
+    mine = lambda spans: [s for s in spans if s in want]  # noqa: E731
+    assert mine(seen_primary) == want and mine(seen_extra) == want
+    assert mine(seen_root) == [root, failed]
+    assert failed.mono_end > 0 and current_traceparent() is None
+    assert seen_extra.append not in tracing._EXTRA_SPAN_SINKS
+
+
+def test_span_budget_is_structural():
+    """What keeps a span cheap, held as structure and counts (the
+    microseconds are in PERF.md, from tools/span_cost.py): ``span`` is a
+    plain function returning an object that is its own context manager
+    (no generator frame), a span holds its ids as numbers until asked,
+    and one child span with the server's sinks wired makes a bounded
+    number of calls."""
+    import inspect
+    import sys
+
+    from tools import span_cost
+
+    assert not inspect.isgeneratorfunction(span)
+    assert hasattr(tracing.Span, "__slots__") and hasattr(tracing.Span, "__exit__")
+    col = SpanCollector()
+    with span("rpc.T", col):
+        with span("score.x", col) as s:
+            pass
+    assert s._span_id == "" and s._sid, "formatted only when read"
+    assert len(s.span_id) == 16 and s._span_id == s.span_id
+
+    old_span_sink, old_root_sink = tracing._SPAN_SINK, tracing._ROOT_SINK
+    old_extra = (tracing._EXTRA_SPAN_SINKS, tracing._EXTRA_ROOT_SINKS)
+    from igaming_platform_tpu.obs import hostprof, runtime_telemetry, slo
+    slo_before = slo.get_default()
+    try:
+        span_cost.wire()
+        for _ in range(3):
+            span_cost.one_rpc()  # every stage's accumulators exist
+        calls = [0]
+
+        def count(frame, event, arg):
+            if event in ("call", "c_call"):
+                calls[0] += 1
+
+        def one_root(children: int) -> int:
+            calls[0] = 0
+            sys.setprofile(count)
+            try:
+                with span("rpc.ScoreBatch"):
+                    for _ in range(children):
+                        with span("score.pad", batch=256):
+                            pass
+            finally:
+                sys.setprofile(None)
+            return calls[0]
+
+        per_child = (one_root(10) - one_root(0)) / 10
+    finally:
+        runtime_telemetry.uninstall()
+        hostprof._reset_default_for_tests()
+        slo.uninstall()
+        if slo_before is not None:
+            slo.install(slo_before)
+        tracing.set_span_sink(old_span_sink)
+        tracing.set_root_sink(old_root_sink)
+        tracing._EXTRA_SPAN_SINKS, tracing._EXTRA_ROOT_SINKS = old_extra
+        tracing.DEFAULT_COLLECTOR.on_drop = None
+    # 47 on the tree of PR 38, 85 on its parent: Python and C calls of
+    # one child span through the ring, the root's accounting and all three
+    # sinks.
+    assert per_child <= 60, per_child
+
+
+def test_a_finished_stage_shows_while_its_request_is_still_open():
+    """Each span is completed when it ends: ring, root stage accounting
+    and sinks see a finished stage of a request that is still in flight
+    (a request hung in a later stage shows where it has been)."""
+    seen = []
+    tracing.add_span_sink(seen.append)
+    try:
+        col = SpanCollector()
+        with span("rpc.T", col) as root:
+            with span("score.a", col) as a:
+                with span("score.b", col) as b:
+                    pass
+            assert [s for s in seen if s in (a, b)] == [b, a]
+            assert set(root.stage_totals) == {"score.a", "score.b"}
+            assert col.drain() == [b, a]
+        assert seen[-1] is root and col.drain() == [root]
+        with span("score.late", col, parent=root) as late:  # its root has ended
+            pass
+        assert seen[-1] is late and "score.late" in root.stage_totals
+    finally:
+        tracing.remove_span_sink(seen.append)
+
+
+def test_every_span_is_completed_once_when_workers_end_around_the_root():
+    """Workers attach stages with ``parent=`` and end them while the root
+    itself ends: each span reaches the sinks exactly once."""
+    seen = []
+    lock = threading.Lock()
+
+    def sink(s):
+        with lock:
+            seen.append(s)
+
+    tracing.add_span_sink(sink)
+    try:
+        col = SpanCollector(capacity=100_000)
+        made = []
+        for _ in range(50):
+            go = threading.Event()
+            mine = []
+
+            def worker(root_box=mine):
+                go.wait(5.0)
+                for _ in range(20):
+                    with span("score.w", col, parent=root_box[0]) as s:
+                        pass
+                    with lock:
+                        made.append(s)
+
+            threads = [threading.Thread(target=worker) for _ in range(4)]
+            with span("rpc.T", col) as root:
+                mine.append(root)
+                for t in threads:
+                    t.start()
+                go.set()
+            with lock:
+                made.append(root)
+            for t in threads:
+                t.join()
+    finally:
+        tracing.remove_span_sink(sink)
+    ours = [s for s in seen if s.name in ("score.w", "rpc.T")]
+    assert len(made) == 50 * 81
+    assert len(ours) == len(made) and set(map(id, ours)) == set(map(id, made))
