@@ -669,9 +669,9 @@ def phase_trainer(server, score_batch, before: dict, compiles, *,
 
 
 def _said_by_the_expert_layer(fn) -> list[str]:
-    """What ``models/keye_backbone`` announces while ``fn`` runs: the
-    cores it picks while tracing (``expert core: ...``, ``combine: ...``),
-    each once."""
+    """What ``models/keye_backbone._announce_core`` says while ``fn`` runs:
+    the cores picked while tracing (``expert core: ...``, ``combine: ...``,
+    and ``models/pangu_backbone``'s ``attention core: ...``), each once."""
     import logging
 
     from igaming_platform_tpu.models import keye_backbone
@@ -861,8 +861,9 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
     four expert layers of latent attention, 8 of 256 routed experts held:
     6.23 GB) against its plain reference (chipbench/heads/
     openpangu_ultra.py, float32 at ``highest`` over bfloat16-rounded
-    operands) on one block of ``rows`` windows, and which core ran the
-    held experts' grouped products (chosen while tracing)."""
+    operands) on one block of ``rows`` windows, and which cores ran the
+    held experts' grouped products, their way back and the core of
+    attention (each chosen while tracing)."""
     import gc
 
     import jax
@@ -893,10 +894,12 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
     err = float(np.max(np.abs(got - want)))
     cores = [m for m in said if m.startswith("expert core: ")]
     ways = [m for m in said if m.startswith("combine: ")]
+    attends = [m for m in said if m.startswith("attention core: ")]
     report = {"device": device_stamp(), "rows": rows, "max_err": err,
               "resident_bytes": sum(int(a.nbytes) for a in jax.tree.leaves(params)),
               "expert_core": cores[0] if cores else None,
               "way_back": ways[0] if ways else None,
+              "attention_core": attends[0] if attends else None,
               "scores_spread": float(np.std(want))}
     del params
     gc.collect()
@@ -905,6 +908,7 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
           f"> {BACKBONE_TOL}")
     check(bool(cores) and bool(ways),
           "the expert layer announced no core or no way back")
+    check(bool(attends), "latent attention announced no core")
     return report
 
 

@@ -46,6 +46,21 @@ expanded form (keys and values of every head from the latent, every
 step); the compressed ``[ckv | k_rope]`` an account would cache in a
 decoder is not held.
 
+**Which core runs where.** The residual stream and every product of a
+layer are position-major, ``[P, channels]`` with ``P = B x T``. On a TPU,
+where ``ops/pallas/window_attention.supports`` holds (``T`` divides 128,
+head widths whole 64-lane halves: the published widths do), the core of
+attention (the rotary part of ``q``, scores, mask, softmax, ``p v``) is
+one Pallas kernel that reads ``Wq_b``'s and ``Wkv_b``'s results where the
+products wrote them and writes ``Wo``'s operand where ``Wo`` reads it,
+eight windows to a 128-row tile; elsewhere, and on the CPU, it is three
+einsums over ``[b, t, h, d]``, which are also the kernel's reference. The
+choice is made while tracing and logged once a compile (``attention core:
+pallas-windows`` or ``xla-einsum``, as the expert layer logs ``expert
+core`` and ``combine``). Either way it is the expanded form at the
+precision stated below: no absorbed product, no cached latent, every
+position of every window computed.
+
 Precision as the ``keye`` head's: parameters bfloat16 at rest (norm gains
 and the scoring head float32); every product multiplies ``operand_dtype``
 operands and accumulates in float32; residual stream, norms, softmax,
@@ -59,6 +74,7 @@ router scores, top-k and the logit are float32.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any
@@ -68,6 +84,7 @@ import jax.numpy as jnp
 
 from igaming_platform_tpu.models.keye_backbone import (
     Params,
+    _announce_core,
     _matrix,
     _mm,
     grouped_experts,
@@ -158,36 +175,79 @@ def init_backbone(key, cfg: PanguConfig) -> Params:
     }
 
 
+def _core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
+                     rope: int, dv: int, window: int):
+    """The core of attention as three einsums over ``[b, t, h, d]``, under
+    the kernel's signature (ops/pallas/window_attention.window_attention):
+    its reference, and what runs off the TPU. -> float32 [P, heads x dv],
+    which ``Wo``'s product rounds."""
+    dt, t = kvb.dtype, window
+    b = q.shape[0] // t
+    q = q.reshape(b, t, heads, nope + rope)
+    q_rope = rotate(q[..., nope:], cos.reshape(b, t, -1), sin.reshape(b, t, -1))
+    kvb = kvb.reshape(b, t, heads, nope + dv)
+    sc = (jnp.einsum("bthd,bshd->bhts", q[..., :nope].astype(dt),
+                     kvb[..., :nope], preferred_element_type=jnp.float32)
+          + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt),
+                       k_rope.reshape(b, t, rope),
+                       preferred_element_type=jnp.float32))
+    sc = sc * ((nope + rope) ** -0.5)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+    o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), kvb[..., nope:],
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b * t, heads * dv)
+
+
+def _attention_core(q, kvb, cfg: PanguConfig, window: int):
+    """What runs the core of attention over ``q`` [P, heads x (nope +
+    rope)] and ``kvb`` [P, heads x (nope + v)] (arrays or shapes): the
+    Pallas kernel (ops/pallas/window_attention.py) on a TPU where its
+    ``supports`` holds, else ``_core_by_einsums``; either way a function of
+    ``(q, kvb, k_rope, cos, sin)``. Picked while tracing, from backend and
+    shapes, and announced once a compile."""
+    from igaming_platform_tpu.ops.pallas import window_attention as kernel
+
+    backend = jax.default_backend()
+    widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
+                  dv=cfg.v_dim, window=window)
+    by_kernel = backend == "tpu" and kernel.supports(q, kvb, **widths)
+    _announce_core("pallas-windows" if by_kernel else "xla-einsum", backend,
+                   "attention core")
+    return functools.partial(
+        kernel.window_attention if by_kernel else _core_by_einsums, **widths)
+
+
 def latent_attention(a, layer: Params, cos, sin, cfg: PanguConfig):
     """Multi-head latent attention over normed hidden states ``a`` [B, T,
     hidden], in its expanded form -> [B, T, hidden] (before the
-    post-norm)."""
+    post-norm). The core (the rotary part of ``q``, scores, mask, softmax,
+    ``p v``) is one Pallas kernel over the projections' results as they
+    lie where ``window_attention.supports`` holds on a TPU, else three
+    einsums over ``[b, t, h, d]``: the same expanded form at the same
+    precision either way."""
     b, t, _ = a.shape
-    nh, nope, rope, dv = cfg.heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim
     dt = cfg.operand_dtype
+    # position-major from here to the last product: [P, channels], P = B x T
+    a = a.reshape(b * t, -1)
     with jax.named_scope("q"):
         cq = rms_norm(_mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
-        q = _mm(cq, layer["wq_b"], cfg).reshape(b, t, nh, nope + rope)
-        q_nope, q_rope = q[..., :nope], rotate(q[..., nope:], cos, sin)
+        # heads of [q_nope | q_rope] as the product leaves them: float32,
+        # since the rotary part turns before it is rounded
+        q = _mm(cq, layer["wq_b"], cfg)
     with jax.named_scope("kv"):
         kv = _mm(a, layer["wkv_a"], cfg)
-        ckv = rms_norm(kv[..., :cfg.kv_rank], layer["kvn"], cfg.eps)
+        ckv = rms_norm(kv[:, :cfg.kv_rank], layer["kvn"], cfg.eps)
         # one rotary key head, shared by every query head
-        k_rope = rotate(kv[..., None, cfg.kv_rank:], cos, sin)[:, :, 0, :]
-        kvb = _mm(ckv, layer["wkv_b"], cfg).reshape(b, t, nh, nope + dv)
-        k_nope, v = kvb[..., :nope], kvb[..., nope:]
+        k_rope = rotate(kv[:, cfg.kv_rank:].reshape(b, t, 1, -1), cos, sin)
+        k_rope = k_rope.astype(dt).reshape(b * t, -1)
+        # heads of [k_nope | v]: rounded before any other use
+        kvb = _mm(ckv, layer["wkv_b"], cfg).astype(dt)
+    core = _attention_core(q, kvb, cfg, t)
     with jax.named_scope("core"):
-        sc = (jnp.einsum("bthd,bshd->bhts", q_nope.astype(dt), k_nope.astype(dt),
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt), k_rope.astype(dt),
-                           preferred_element_type=jnp.float32))
-        sc = sc * ((nope + rope) ** -0.5)
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-        o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), v.astype(dt),
-                       preferred_element_type=jnp.float32)
+        o = core(q, kvb, k_rope, cos.reshape(b * t, -1), sin.reshape(b * t, -1))
     with jax.named_scope("out"):
-        return _mm(o.reshape(b, t, nh * dv), layer["wo"], cfg)
+        return _mm(o, layer["wo"], cfg).reshape(b, t, -1)
 
 
 def swiglu(x, w: Params, cfg: PanguConfig):
@@ -218,16 +278,18 @@ def backbone_hidden(params: Params, x, lengths, cfg: PanguConfig):
     b, t, _ = x.shape
     live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(b * t)
     with jax.named_scope("head/embed"):
-        h = _mm(x, params["embed"], cfg)
+        # the residual stream position-major, [P, hidden] with P = B x T,
+        # from here to the final norm: every product reads and writes it so
+        h = _mm(x.reshape(b * t, -1), params["embed"], cfg)
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
         cos, sin = mrope_angles(pos, cfg.rope_dim, (cfg.rope_dim // 2,),
                                 cfg.rope_theta)
     for layer in params["layers"]:
         with jax.named_scope("head/attn"):
-            a = rms_norm(h, layer["g1"], cfg.eps)
-            o = latent_attention(a, layer, cos, sin, cfg)
+            a = rms_norm(h, layer["g1"], cfg.eps).reshape(b, t, -1)
+            o = latent_attention(a, layer, cos, sin, cfg).reshape(b * t, -1)
             h = h + rms_norm(o, layer["g2"], cfg.eps)
-        flat = rms_norm(h, layer["g3"], cfg.eps).reshape(b * t, -1)
+        flat = rms_norm(h, layer["g3"], cfg.eps)
         if "dense" in layer:
             with jax.named_scope("head/mlp/dense"):
                 m = swiglu(flat, layer["dense"], cfg)
@@ -239,8 +301,8 @@ def backbone_hidden(params: Params, x, lengths, cfg: PanguConfig):
             with jax.named_scope("head/moe/experts"):
                 m = m + grouped_experts(flat, top_e, top_w, layer["routed"],
                                         cfg, cfg.first_expert, live)
-        h = h + rms_norm(m, layer["g4"], cfg.eps).reshape(b, t, -1)
-    return rms_norm(h, params["gf"], cfg.eps)
+        h = h + rms_norm(m, layer["g4"], cfg.eps)
+    return rms_norm(h, params["gf"], cfg.eps).reshape(b, t, -1)
 
 
 def backbone_scores(params: Params, window, lengths, cfg: PanguConfig):

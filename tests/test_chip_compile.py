@@ -210,8 +210,10 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     of [32768, 7680] exists. One expert's gate and up are 63 MB a slot,
     over what the Pallas product kernels hold in VMEM, so the held experts'
     products are XLA's grouped product, inside the pass loop; the way back
-    to position order is the one Pallas kernel of the step,
-    ``_combine_held``, once a layer."""
+    to position order is ``_combine_held``, once an expert layer. The core
+    of attention is the other in-tree kernel, ``_window_attention``
+    (ops/pallas/window_attention.py), once a layer, and no score array
+    ``[256, 128, 16, 16]`` is left in the step."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.models.keye_backbone import pass_rows
@@ -238,9 +240,10 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     assert f"[{pairs},{cfg.hidden}]" not in text
     assert "ragged-dot" in text and "head/moe/experts" in text
     # XLA's grouped product is a Mosaic custom call of its own
-    # (`%ragged-dot-none`); of the in-tree kernels (ops/pallas/
-    # grouped_experts), whose calls carry `pallas_call` in their op_name,
-    # only the way back is there, under the expert scope.
+    # (`%ragged-dot-none`); the in-tree kernels' calls carry `pallas_call`
+    # in their op_name: of ops/pallas/grouped_experts only the way back is
+    # there, under the expert scope, and the core of attention
+    # (ops/pallas/window_attention) under `head/attn/core`.
     # (Calls, not names: the module's table of function names is the
     # process's, and holds `_gate_up` once the keye step was traced in it.)
     calls = [line for line in text.splitlines()
@@ -248,9 +251,15 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     assert len([c for c in calls
                 if re.match(r"\s*%ragged-dot-none(\.\d+)? = ", c)]) == 12, calls
     in_tree = [c for c in calls if "pallas_call" in c]
-    assert len(in_tree) == 4, in_tree
-    assert all("_combine_held" in c and "head/moe/experts" in c
-               for c in in_tree), in_tree
+    back = [c for c in in_tree if "_combine_held" in c]
+    core = [c for c in in_tree if "_window_attention" in c]
+    assert len(back) == cfg.layers - cfg.dense_layers == 4, in_tree
+    assert all("head/moe/experts" in c for c in back), back
+    assert len(core) == cfg.layers == 5, in_tree
+    assert all("head/attn/core" in c for c in core), core
+    assert len(in_tree) == 9, in_tree
+    scores = f"[{BATCH},{cfg.heads},{ss.default_events()},{ss.default_events()}]"
+    assert scores == "[256,128,16,16]" and scores not in text
 
 
 @pytest.mark.parametrize("head,sketch,sha256", [

@@ -96,6 +96,26 @@ def steer_to_combine(monkeypatch):
         kernels.combine, interpret=True))
 
 
+# Head widths the attention kernel takes (whole 64-lane halves), at two
+# heads: what the small size's 16 + 8 against 16 becomes where the kernel
+# is to run in a CPU test.
+KERNEL_WIDTHS = dict(heads=2, nope_dim=64, rope_dim=64, v_dim=64)
+KERNEL_SOURCE = dict(num_attention_heads=2, qk_nope_head_dim=64,
+                     qk_rope_head_dim=64, v_head_dim=64)
+
+
+def steer_to_attention_kernel(monkeypatch):
+    """What a TPU would pick for the core of attention, on the CPU: the
+    backend reports ``tpu`` and the kernel runs through the Pallas
+    interpreter (``combine`` too, where an expert layer follows). Steered
+    here, in the test; the program has no option for it."""
+    from igaming_platform_tpu.ops.pallas import window_attention as kernel
+
+    steer_to_combine(monkeypatch)
+    monkeypatch.setattr(kernel, "window_attention", functools.partial(
+        kernel.window_attention, interpret=True))
+
+
 # -- the whole head, the stack and the score -------------------------------------
 
 
@@ -230,25 +250,37 @@ def test_large_matrices_are_drawn_in_row_blocks():
 # -- the parts --------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("core", ["xla-einsum", "pallas-windows"])
 @pytest.mark.parametrize("operands", ["float32", "bfloat16"])
-def test_latent_attention_alone(head, operands):
+def test_latent_attention_alone(head, operands, core, monkeypatch, caplog):
     """``x + N2(attention(N1(x)))`` of one layer against the reference's,
-    and the one rotary key is shared: turning it turns every head."""
+    and the one rotary key is shared: turning it turns every head. With
+    the three einsums at the small size's widths, and with the kernel
+    (ops/pallas/window_attention.py, interpreted) at widths it takes, as a
+    TPU would pick it: the same expanded form at the same precision."""
     dt = jnp.dtype(operands)
-    cfg, d = small_config(operand_dtype=dt), head.dims_of(small_source())
-    layer = head.make_params(5, small_source())["layers"][1]
+    by_kernel = core == "pallas-windows"
+    source = small_source(**(KERNEL_SOURCE if by_kernel else {}))
+    cfg = small_config(operand_dtype=dt, **(KERNEL_WIDTHS if by_kernel else {}))
+    d = head.dims_of(source)
+    layer = head.make_params(5, source)["layers"][1]
     x = stream()
     t = x.shape[1]
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, x.shape[0], t))
     cos, sin = kb.mrope_angles(pos, cfg.rope_dim, (cfg.rope_dim // 2,),
                                cfg.rope_theta)
+    if by_kernel:
+        steer_to_attention_kernel(monkeypatch)
 
     def sublayer(x, cos, sin):
         o = pb.latent_attention(kb.rms_norm(x, layer["g1"], cfg.eps), layer,
                                 cos, sin, cfg)
         return x + kb.rms_norm(o, layer["g2"], cfg.eps)
 
-    got = np.asarray(jax.jit(sublayer)(x, cos, sin))
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        got = np.asarray(jax.jit(sublayer)(x, cos, sin))
+    if by_kernel:
+        assert "attention core: pallas-windows (backend=tpu)" in caplog.text
     with jax.default_matmul_precision("highest"):
         want = np.asarray(head._attend(layer, x, d, dt))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
@@ -387,6 +419,33 @@ def test_head_with_combine_on_equals_the_xla_path(operands, monkeypatch, caplog)
     np.testing.assert_allclose(by_kernel, by_xla, atol=2e-6, rtol=0)
 
 
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_head_with_attention_kernel_on_equals_the_xla_path(
+        operands, monkeypatch, caplog):
+    """The whole head at hidden 128 and head widths the attention kernel
+    takes, every layer's core through the kernel (and ``combine`` for the
+    way back, as a TPU pairs them), against the einsums on the same tree
+    and windows: windows of every length, padding going through attention
+    as it does in the cell."""
+    cfg = small_config(hidden=128, operand_dtype=jnp.dtype(operands),
+                       **KERNEL_WIDTHS)
+    params = pb.init_backbone(jax.random.key(4), cfg)
+    x, lens = windows(12, (1, 4, 16, 7, 9, 2), seed=3)
+    by_xla = program_scores(cfg, params, x, lens)
+    steer_to_attention_kernel(monkeypatch)
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        by_kernel = program_scores(cfg, params, x, lens)
+    assert "attention core: pallas-windows (backend=tpu)" in caplog.text
+    assert "combine: pallas-rows (backend=tpu)" in caplog.text
+    assert "attention core: xla-einsum" not in caplog.text
+    assert np.ptp(by_xla) > 1e-3
+    # float32 operands: the order of float32 sums alone; bfloat16: a
+    # probability on a rounding boundary may fall to either side (0.4% of
+    # itself), as in test_head_equals_the_reference
+    atol = 2e-6 if operands == "float32" else 2e-4
+    np.testing.assert_allclose(by_kernel, by_xla, atol=atol, rtol=0)
+
+
 def _reference_mlp(head, layer, flat, d, dt):
     """What the reference's ``_moe`` hands to N4 (shared expert + the held
     experts' part), from its own parts, every position live."""
@@ -497,7 +556,8 @@ def test_unknown_head_lists_the_new_name():
 
 def test_chip_smoke_phase_runs_the_head_against_its_reference():
     """``chip_smoke.phase_backbone`` at the small size on the CPU: the head
-    against its reference, and the core that ran the held experts."""
+    against its reference, and the cores that ran the held experts, their
+    way back and the core of attention."""
     import chip_smoke
 
     report = chip_smoke.phase_backbone(cfg=small_config(), config=small_source(),
@@ -505,4 +565,5 @@ def test_chip_smoke_phase_runs_the_head_against_its_reference():
     assert report["max_err"] < 1e-4 and report["rows"] == 8
     assert report["expert_core"] == "expert core: xla-ragged-dot (backend=cpu)"
     assert report["way_back"] == "combine: xla-gather (backend=cpu)"
+    assert report["attention_core"] == "attention core: xla-einsum (backend=cpu)"
     assert report["resident_bytes"] > 0
