@@ -855,15 +855,40 @@ def phase_kernels(interpret: bool = False, *,
 # Phase: backbone
 
 
-def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
+# SESSION_HEAD name -> (its configuration file, its reference under
+# chipbench/heads/, its module and its sizes in models/session_heads, and
+# the parts of it that pick a core while tracing, each with the core it
+# must pick on a TPU at the published widths): the backbones
+# ``phase_backbone`` runs. ``pangu``'s share of wide experts is past the
+# grouped kernels' VMEM (one expert's gate and up are 63 MB), so its
+# products are XLA's; ``lfm2``'s attention is einsums and picks nothing.
+BACKBONES = {
+    "pangu": ("risk-seqhead-openpangu-ultra-moe-718b", "openpangu_ultra",
+              "pangu_backbone", "PANGU_CONFIG",
+              {"expert_core": "xla-ragged-dot", "way_back": "pallas-rows",
+               "attention_core": "pallas-windows"}),
+    "lfm2": ("risk-seqhead-lfm2-24b-a2b", "lfm2_24b_a2b",
+             "lfm2_backbone", "LFM2_CONFIG",
+             {"expert_core": "pallas-grouped", "way_back": "pallas-rows"}),
+}
+CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
+              "attention_core": "attention core"}
+
+
+def phase_backbone(*, head_name: str = "pangu", cfg=None,
+                   config: dict | None = None, rows: int = 32,
                    seed: int = 36) -> dict:
-    """The ``pangu`` session head at its published widths (one dense and
-    four expert layers of latent attention, 8 of 256 routed experts held:
-    6.23 GB) against its plain reference (chipbench/heads/
-    openpangu_ultra.py, float32 at ``highest`` over bfloat16-rounded
-    operands) on one block of ``rows`` windows, and which cores ran the
-    held experts' grouped products, their way back and the core of
-    attention (each chosen while tracing)."""
+    """A backbone session head at its published widths against its plain
+    reference (float32 at ``highest`` over bfloat16-rounded operands) on
+    one block of ``rows`` windows, and which cores ran its expert layer's
+    grouped products, their way back and, where the head has one to pick,
+    the core of attention (each chosen while tracing). ``pangu``: one
+    dense and four expert layers of latent attention, 8 of 256 routed
+    experts held, 6.23 GB (chipbench/heads/openpangu_ultra.py); ``lfm2``:
+    four gated short convolutions and one grouped-query attention layer,
+    a dense MLP and four layers of 64 experts, every one held, 5.13 GB
+    (chipbench/heads/lfm2_24b_a2b.py), whose expert layer is the three
+    Pallas kernels at their second shape."""
     import gc
 
     import jax
@@ -871,13 +896,14 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
     import numpy as np
 
     from chipbench import reference, validate
-    from igaming_platform_tpu.models import keye_backbone, pangu_backbone
-    from igaming_platform_tpu.models.session_heads import PANGU_CONFIG
+    from igaming_platform_tpu.models import session_heads
 
-    cfg = cfg or PANGU_CONFIG
-    config = config or validate.load_data(
-        "configs", "risk-seqhead-openpangu-ultra-moe-718b")
-    head = validate.load_code("heads", "openpangu_ultra")
+    config_name, reference_name, module, sizes, on_tpu = BACKBONES[head_name]
+    scores = getattr(session_heads, module).backbone_scores
+    published = cfg is None
+    cfg = cfg or getattr(session_heads, sizes)
+    config = config or validate.load_data("configs", config_name)
+    head = validate.load_code("heads", reference_name)
     params = head.make_params(seed, config)
     rng = np.random.default_rng(seed)
     lengths = rng.integers(1, 17, rows).astype(np.int32)
@@ -886,29 +912,31 @@ def phase_backbone(*, cfg=None, config: dict | None = None, rows: int = 32,
 
     got = []
     said = _said_by_the_expert_layer(lambda: got.append(np.asarray(jax.jit(
-        lambda p, w, l: pangu_backbone.backbone_scores(p, w, l, cfg))(
+        lambda p, w, l: scores(p, w, l, cfg))(
             params, jnp.asarray(win), jnp.asarray(lengths)))))
     got = got[0]
     want = head.forward(params, win, lengths, reference.rounder(
         jnp.dtype(cfg.operand_dtype).name))
     err = float(np.max(np.abs(got - want)))
-    cores = [m for m in said if m.startswith("expert core: ")]
-    ways = [m for m in said if m.startswith("combine: ")]
-    attends = [m for m in said if m.startswith("attention core: ")]
-    report = {"device": device_stamp(), "rows": rows, "max_err": err,
+    picked = {part: next((m for m in said if m.startswith(line + ": ")), None)
+              for part, line in CORE_LINES.items()}
+    report = {"device": device_stamp(), "head": head_name, "rows": rows,
+              "max_err": err,
               "resident_bytes": sum(int(a.nbytes) for a in jax.tree.leaves(params)),
-              "expert_core": cores[0] if cores else None,
-              "way_back": ways[0] if ways else None,
-              "attention_core": attends[0] if attends else None,
-              "scores_spread": float(np.std(want))}
+              **picked, "scores_spread": float(np.std(want))}
     del params
     gc.collect()
     check(bool(np.all(np.isfinite(got))) and err <= BACKBONE_TOL,
-          f"pangu head vs its reference on {rows} windows: max err {err} "
+          f"{head_name} head vs its reference on {rows} windows: max err {err} "
           f"> {BACKBONE_TOL}")
-    check(bool(cores) and bool(ways),
-          "the expert layer announced no core or no way back")
-    check(bool(attends), "latent attention announced no core")
+    for part, line in CORE_LINES.items():
+        check((picked[part] is not None) == (part in on_tpu),
+              f"{head_name}: {line} announced {picked[part]!r}")
+    if published and jax.default_backend() == "tpu":
+        for part, core in on_tpu.items():
+            want_line = f"{CORE_LINES[part]}: {core} (backend=tpu)"
+            check(picked[part] == want_line,
+                  f"{head_name}: {picked[part]!r} where {want_line!r} was expected")
     return report
 
 
@@ -994,6 +1022,7 @@ def main() -> int:
     print(_summary_line("trainer", reports["trainer"]), flush=True)
     run("kernels", phase_kernels)
     run("backbone", phase_backbone)
+    run("backbone_lfm2", phase_backbone, head_name="lfm2")
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

@@ -114,6 +114,7 @@ class PanguConfig:
     top_k: int = 8
     expert_width: int = 2048
     routed_scale: float = 2.5
+    renorm_eps: float = 1e-20  # beside the sum the chosen scores are divided by
     rope_theta: float = 25.6e6
     eps: float = 1e-5
     # the depth the seeded tree is initialised for: the two post-norm gains
@@ -257,14 +258,21 @@ def swiglu(x, w: Params, cfg: PanguConfig):
     return _mm(mid, w["wd"], cfg)
 
 
-def route(x, layer: Params, cfg: PanguConfig):
-    """Sigmoid router over ALL experts, no groups, no correction bias:
-    ``(experts [P, top_k] int32, weights [P, top_k] float32)``, the weights
-    the chosen scores over their sum (held or not), times
-    ``routed_scale``."""
+def route(x, layer: Params, cfg):
+    """Sigmoid router over ALL experts, no groups: ``(experts [P, top_k]
+    int32, weights [P, top_k] float32)``, the weights the chosen scores
+    over their sum (held or not) plus ``cfg.renorm_eps``, times
+    ``cfg.routed_scale``. Where the layer has an expert bias (``rb``
+    [experts] float32: the ``lfm2`` head's, models/lfm2_backbone.py) it is
+    added to the scores that ``top_k`` reads and to nothing else: the bias
+    chooses and does not weigh. This head's layers have none."""
     s = jax.nn.sigmoid(_mm(x, layer["wr"], cfg))
-    top_s, top_e = jax.lax.top_k(s, cfg.top_k)
-    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    if "rb" in layer:
+        _, top_e = jax.lax.top_k(s + layer["rb"], cfg.top_k)
+        top_s = jnp.take_along_axis(s, top_e, axis=-1)
+    else:
+        top_s, top_e = jax.lax.top_k(s, cfg.top_k)
+    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
     return top_e, w * cfg.routed_scale
 
 

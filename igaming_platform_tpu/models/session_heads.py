@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from igaming_platform_tpu.models import pangu_backbone
+from igaming_platform_tpu.models import lfm2_backbone, pangu_backbone
 from igaming_platform_tpu.models.keye_backbone import (
     BackboneConfig,
     backbone_scores,
@@ -139,6 +139,27 @@ def pangu_scores(sparams, window, lengths):
     return pangu_backbone.backbone_scores(sparams, window, lengths, PANGU_CONFIG)
 
 
+# SESSION_HEAD=lfm2: a short-convolution hybrid at its published widths
+# (models/lfm2_backbone.py): the source's layer 0 and one whole period after
+# its leading dense layers (four gated short convolutions and one
+# grouped-query attention layer by ``layer_types``; one dense MLP, four
+# expert layers of 64 sigmoid-routed experts chosen with an expert bias,
+# every one held): 2.57 G parameters, 5.13 GB in bfloat16 beside the state.
+LFM2_CONFIG = lfm2_backbone.Lfm2Config()
+
+
+def init_lfm2_params(seed: int = _SESSION_HEAD_SEED):
+    """The pinned seeded tree of the ``lfm2`` head, built on the device in
+    bfloat16, a matrix (or a block of one) at a time."""
+    return lfm2_backbone.init_backbone(jax.random.key(seed), LFM2_CONFIG)
+
+
+def lfm2_scores(sparams, window, lengths):
+    """The ``lfm2`` head: the backbone over the window, scored at the last
+    real position."""
+    return lfm2_backbone.backbone_scores(sparams, window, lengths, LFM2_CONFIG)
+
+
 # SESSION_HEAD name -> (head_fn(sparams, window, lengths), init_params()).
 HEADS = {
     "pattern": (lambda sparams, win, lp: pattern_scores(win, lp),
@@ -146,6 +167,7 @@ HEADS = {
     "transformer": (transformer_scores, init_session_head_params),
     "keye": (keye_scores, init_keye_params),
     "pangu": (pangu_scores, init_pangu_params),
+    "lfm2": (lfm2_scores, init_lfm2_params),
 }
 
 # SESSION_HEAD name -> (routed experts a layer held on this chip, experts
@@ -153,6 +175,24 @@ HEADS = {
 HEAD_EXPERTS = {
     "keye": (KEYE_CONFIG.experts, KEYE_CONFIG.experts),
     "pangu": (PANGU_CONFIG.held_experts, PANGU_CONFIG.experts),
+    "lfm2": (LFM2_CONFIG.experts, LFM2_CONFIG.experts),
+}
+
+# What a layer's operator (``conv``, ``attention``) and its feed-forward
+# (``dense``, ``moe``) may be: the kinds ``HEAD_LAYERS`` counts.
+LAYER_KINDS = ("conv", "attention", "dense", "moe")
+
+# SESSION_HEAD name -> layers of each kind in its stack (a kind that is
+# left out has none; the ``pattern`` head has no layer at all).
+HEAD_LAYERS = {
+    "pattern": {},
+    "transformer": {"attention": SESSION_SEQ_CONFIG.n_layers,
+                    "dense": SESSION_SEQ_CONFIG.n_layers},
+    "keye": {"attention": KEYE_CONFIG.layers, "moe": KEYE_CONFIG.layers},
+    "pangu": {"attention": PANGU_CONFIG.layers,
+              "dense": PANGU_CONFIG.dense_layers,
+              "moe": PANGU_CONFIG.layers - PANGU_CONFIG.dense_layers},
+    "lfm2": lfm2_backbone.layer_kinds(LFM2_CONFIG),
 }
 
 

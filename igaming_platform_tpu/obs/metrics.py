@@ -865,6 +865,12 @@ class ServiceMetrics:
             "session_head_experts_held under it the chip holds a share of "
             "each layer and computes that share's part of the result",
         )
+        self.session_head_layers = self.registry.gauge(
+            f"{service}_session_head_layers",
+            "Layers of the session head's stack by kind, set once at boot: "
+            "kind=conv|attention is a layer's operator, kind=dense|moe its "
+            "feed-forward (a head without layers reads 0 for all four)",
+        )
         self.session_lock_wait_seconds_total = self.registry.counter(
             f"{service}_session_lock_wait_seconds_total",
             "Seconds index-mode chunks waited for the session lock "

@@ -59,7 +59,12 @@ from typing import Any
 import numpy as np
 
 from igaming_platform_tpu.models.sequence import EVENT_DIM
-from igaming_platform_tpu.models.session_heads import HEAD_EXPERTS, session_head
+from igaming_platform_tpu.models.session_heads import (
+    HEAD_EXPERTS,
+    HEAD_LAYERS,
+    LAYER_KINDS,
+    session_head,
+)
 
 # Per-event layout: models/sequence.encode_event — [log-amount, log-dt,
 # 8-way tx-type one-hot, game-weight, balance-ratio].
@@ -480,6 +485,9 @@ class SessionStateManager:
         self.head_resident_bytes = sum(
             int(a.nbytes) for a in jax.tree.leaves(self.head_params))
         self.head_experts = HEAD_EXPERTS.get(self.head, (0, 0))
+        # what the stack is made of: layers by kind, a kind it lacks at 0
+        layers = HEAD_LAYERS.get(self.head, {})
+        self.head_layers = {kind: layers.get(kind, 0) for kind in LAYER_KINDS}
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}
@@ -548,12 +556,15 @@ class SessionStateManager:
         self._export_head()
 
     def _export_head(self) -> None:
-        """What the head holds, fixed at boot: three gauges."""
+        """What the head holds and is made of, fixed at boot: three gauges
+        and one a kind of layer."""
         m = self._metrics
         if m is not None:
             m.session_head_resident_bytes.set(self.head_resident_bytes)
             m.session_head_experts_held.set(self.head_experts[0])
             m.session_head_experts_routed.set(self.head_experts[1])
+            for kind, count in self.head_layers.items():
+                m.session_head_layers.set(count, kind=kind)
 
     def _export(self, warm: int, cold: int, bypass: int, appends: int,
                 rehydrations: int, regrows: int = 0,
@@ -620,6 +631,7 @@ class SessionStateManager:
                 "head_resident_bytes": self.head_resident_bytes,
                 "head_experts_held": self.head_experts[0],
                 "head_experts_routed": self.head_experts[1],
+                "head_layers": dict(self.head_layers),
                 "lock_wait_s": self.lock_wait_s,
                 "lock_held_s": self.lock_held_s,
                 "rehydrations": self.rehydrations,

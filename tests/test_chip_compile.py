@@ -262,6 +262,54 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     assert scores == "[256,128,16,16]" and scores not in text
 
 
+def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
+        topo, tpu_backend, capsys):
+    """The fused step with the ``lfm2`` backbone in it, at the cell's size
+    (5,242,880 accounts, one 256-row rung): in place on the ring, its
+    arguments are the state plus 5.13 GB of weights, and the expert layer
+    is the three Pallas kernels the ``keye`` head runs, at a second shape:
+    64 experts of width 1,536 and 4 a position where they were written for
+    128 of 768 and 8 (``_gate_up``'s and ``_down``'s column tiles,
+    ``_schedule``'s visits and ``_combine_rows``'s ``k`` all take other
+    values), once a layer under the scope the trace reads them by, with no
+    XLA grouped product left. The convolution's taps are no kernel and no
+    product on the MXU: three shifted elementwise products a layer. Code size
+    and temporaries are printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.session_heads import LFM2_CONFIG as cfg
+    from igaming_platform_tpu.serve import session_state as ss
+
+    capacity = 5_242_880
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step("lfm2", capacity, capacity + 1, one, one)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nlfm2 step for a described v5e: code "
+              f"{mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 9.8e9 < mem.argument_size_in_bytes < 10.0e9, mem
+    assert mem.temp_size_in_bytes <= 615_414_272, mem  # keye's bound
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line
+               and "head/moe/experts" in line]
+    moe = len(cfg.layer_types) - cfg.dense_layers
+    for name in ("_gate_up", "_down", "_combine_rows"):
+        calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
+        assert len(calls) == moe == 4, (name, kernels)
+    assert len(kernels) == 12, kernels
+    assert "%ragged-dot-none" not in text
+    # the taps are elementwise work under their scope, never a product
+    assert "head/conv/taps" in text and "head/attn" in text
+    assert not [line for line in text.splitlines()
+                if " convolution(" in line and "head/conv/taps" in line]
+
+
 @pytest.mark.parametrize("head,sketch,sha256", [
     ("pattern", False, "c51b7511f2159529"), ("pattern", True, "154ee60b366c28a6"),
     ("transformer", False, "7037c7eb0e6f4958"),

@@ -42,7 +42,38 @@ SKEWS = {
 }
 
 
-def operands(rows: int, seed: int = 0):
+# The second shape the kernels run (the ``lfm2`` head's kind: PERF.md, PR
+# 43): 64 experts, a width that is a multiple of 128 and not 768, 4 a
+# position; its column tiles, its schedule's visits and ``combine``'s ``k``
+# take other values than the shape the kernels were written for.
+MANY = (64, 128, 384)  # experts, hidden, width
+
+
+def _ragged_sizes(experts: int, rows: int, seed: int = 12) -> list[int]:
+    """``rows`` dealt over ``experts`` unevenly, a third of them empty."""
+    rng = np.random.default_rng(seed)
+    p = rng.random(experts) ** 3 * (rng.random(experts) > 1 / 3)
+    sizes = rng.multinomial(rows, p / p.sum())
+    assert (sizes == 0).sum() >= experts // 4
+    return sizes.tolist()
+
+
+MANY_SKEWS = {
+    "64-experts-uniform": (1024, [16] * 64),
+    "64-experts-ragged": (768, _ragged_sizes(64, 768)),
+    "64-experts-all-on-one": (512, [0] * 41 + [512] + [0] * 22),
+    "64-experts-fewer-rows-than-experts": (40, ([1, 0, 2, 0] * 16)[:64][:-1] + [
+        40 - sum(([1, 0, 2, 0] * 16)[:63])]),
+}
+SKEWS.update(MANY_SKEWS)
+
+
+def shape_of(skew: str) -> tuple[int, int, int]:
+    return MANY if skew in MANY_SKEWS else (EXPERTS, HIDDEN, WIDTH)
+
+
+def operands(rows: int, seed: int = 0, shape=(EXPERTS, HIDDEN, WIDTH)):
+    experts, hidden, width = shape
     k = jax.random.split(jax.random.key(seed), 4)
     bf16 = jnp.bfloat16
 
@@ -50,10 +81,10 @@ def operands(rows: int, seed: int = 0):
         return (jax.random.normal(key, shape, jnp.float32)
                 * fan_in ** -0.5).astype(bf16)
 
-    return (matrix(k[0], (rows, HIDDEN), 1.0),
-            matrix(k[1], (EXPERTS, HIDDEN, WIDTH), HIDDEN),
-            matrix(k[2], (EXPERTS, HIDDEN, WIDTH), HIDDEN),
-            matrix(k[3], (EXPERTS, WIDTH, HIDDEN), WIDTH))
+    return (matrix(k[0], (rows, hidden), 1.0),
+            matrix(k[1], (experts, hidden, width), hidden),
+            matrix(k[2], (experts, hidden, width), hidden),
+            matrix(k[3], (experts, width, hidden), width))
 
 
 def ragged(lhs, w, sizes):
@@ -67,12 +98,13 @@ def f32(a):
 @pytest.mark.parametrize("skew", list(SKEWS))
 def test_gate_up_equals_two_ragged_dots_and_silu(skew):
     rows, sizes = SKEWS[skew]
-    xs, wg, wu, _ = operands(rows)
+    xs, wg, wu, _ = operands(rows, shape=shape_of(skew))
     sizes = jnp.asarray(sizes, jnp.int32)
     assert ge.supports(xs, wg) and int(sizes.sum()) == rows
+    assert sizes.shape == (wg.shape[0],)
     got = ge.gate_up(xs, wg, wu, sizes, interpret=True)
     want32 = jax.nn.silu(ragged(xs, wg, sizes)) * ragged(xs, wu, sizes)
-    assert got.shape == (rows, WIDTH) and got.dtype == jnp.bfloat16
+    assert got.shape == (rows, wg.shape[2]) and got.dtype == jnp.bfloat16
     # float32 accumulation may differ in order, and one rounding to
     # bfloat16 follows: at most one unit in the last place of bfloat16
     np.testing.assert_allclose(f32(got), np.asarray(want32),
@@ -82,26 +114,26 @@ def test_gate_up_equals_two_ragged_dots_and_silu(skew):
 @pytest.mark.parametrize("skew", list(SKEWS))
 def test_down_equals_a_ragged_dot(skew):
     rows, sizes = SKEWS[skew]
-    xs, wg, wu, wd = operands(rows, seed=1)
+    xs, wg, wu, wd = operands(rows, seed=1, shape=shape_of(skew))
     sizes = jnp.asarray(sizes, jnp.int32)
     mid = (jax.nn.silu(ragged(xs, wg, sizes))
            * ragged(xs, wu, sizes)).astype(jnp.bfloat16)
     got = ge.down(mid, wd, sizes, interpret=True)
     want = ragged(mid, wd, sizes)
-    assert got.shape == (rows, HIDDEN) and got.dtype == jnp.float32
+    assert got.shape == (rows, wd.shape[2]) and got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5 * float(jnp.abs(want).max()), rtol=0)
 
 
 @pytest.mark.parametrize("skew", ["several-empty", "not-multiples-of-the-tile",
-                                  "all-on-one"])
+                                  "all-on-one", "64-experts-ragged"])
 def test_a_row_is_multiplied_by_its_own_expert_alone(skew):
     """Every expert's weights differ, so a row computed with a neighbour's
     (a mask off by one at a group boundary) shows at once: each row against
     a plain product with the expert the sizes give it."""
     rows, sizes = SKEWS[skew]
-    xs, wg, wu, wd = operands(rows, seed=2)
-    expert = np.repeat(np.arange(EXPERTS), sizes)
+    xs, wg, wu, wd = operands(rows, seed=2, shape=shape_of(skew))
+    expert = np.repeat(np.arange(len(sizes)), sizes)
     sizes = jnp.asarray(sizes, jnp.int32)
     mid = ge.gate_up(xs, wg, wu, sizes, interpret=True)
     ys = ge.down(mid, wd, sizes, interpret=True)
@@ -115,8 +147,11 @@ def test_a_row_is_multiplied_by_its_own_expert_alone(skew):
                                rtol=0)
 
 
-def test_schedule_visits_every_tile_an_expert_touches_and_no_other():
-    rows, sizes = SKEWS["not-multiples-of-the-tile"]
+@pytest.mark.parametrize("skew", ["not-multiples-of-the-tile",
+                                  "64-experts-ragged", "64-experts-uniform"])
+def test_schedule_visits_every_tile_an_expert_touches_and_no_other(skew):
+    rows, sizes = SKEWS[skew]
+    EXPERTS = len(sizes)
     tm = 256
     starts, ends, group, tile, first, slot, following, n = (
         np.asarray(a) for a in ge._schedule(jnp.asarray(sizes, jnp.int32),
@@ -148,11 +183,18 @@ def test_supports_refuses(why, rows, hidden, width, dtype):
     assert not ge.supports(xs, w), why
 
 
-def test_supports_the_cells_shapes():
-    xs = jax.ShapeDtypeStruct((32768, 2048), jnp.bfloat16)
-    assert ge.supports(xs, jax.ShapeDtypeStruct((128, 2048, 768), jnp.bfloat16))
-    mid = jax.ShapeDtypeStruct((32768, 768), jnp.bfloat16)
-    assert ge.supports(mid, jax.ShapeDtypeStruct((128, 768, 2048), jnp.bfloat16))
+@pytest.mark.parametrize("pairs,experts,width", [(32768, 128, 768),
+                                                 (16384, 64, 1536)],
+                         ids=["keye-128x768-top8", "lfm2-64x1536-top4"])
+def test_supports_the_cells_shapes(pairs, experts, width):
+    xs = jax.ShapeDtypeStruct((pairs, 2048), jnp.bfloat16)
+    assert ge.supports(xs, jax.ShapeDtypeStruct((experts, 2048, width),
+                                                jnp.bfloat16))
+    mid = jax.ShapeDtypeStruct((pairs, width), jnp.bfloat16)
+    assert ge.supports(mid, jax.ShapeDtypeStruct((experts, width, 2048),
+                                                 jnp.bfloat16))
+    # two slots of an expert's gate and up: 12.6 and 25.2 MB of the 52.4
+    assert 2 * 2 * 2048 * width * 2 <= ge._VMEM_CAP // 2
 
 
 # -- down, its rows whole ---------------------------------------------------------
@@ -204,8 +246,9 @@ def uneven_weights(p: int, k: int, seed: int = 1):
 
 
 @pytest.mark.parametrize("positions,k,hidden,whole_rows", [
-    (128, 8, 1024, False), (128, 8, 1024, True), (64, 2, 2048, True)],
-    ids=["k8", "k8-rows-whole", "k2-one-tile"])
+    (128, 8, 1024, False), (128, 8, 1024, True), (64, 2, 2048, True),
+    (192, 4, 2048, True)],
+    ids=["k8", "k8-rows-whole", "k2-one-tile", "k4-rows-whole"])
 def test_combine_every_slot_equals_gather_and_weighted_sum(positions, k, hidden,
                                                            whole_rows):
     """(a) a full permutation with uneven weights, from ``ys`` as [M, hidden]
@@ -306,6 +349,12 @@ def test_combine_supports_refuses(why, m, hidden, positions, k, dtype, share):
     rows = jax.ShapeDtypeStruct((positions, k), jnp.int32)
     take = jax.ShapeDtypeStruct((positions, k), jnp.bool_) if share else None
     assert not ge.combine_supports(ys, rows, take), why
+
+
+def test_combine_supports_the_lfm2_cells_shape():
+    # every slot of 4,096 positions at 4 a position, rows whole
+    rows = jax.ShapeDtypeStruct((4096, 4), jnp.int32)
+    assert ge.combine_supports(jax.ShapeDtypeStruct((16384, 16, 128), F32), rows)
 
 
 def test_combine_supports_the_cells_shapes():
