@@ -698,12 +698,16 @@ def phase_kernels(interpret: bool = False, *,
                   gbdt_batch: int = 8192, gbdt_tile: int = 256,
                   expert_shape: tuple = (32768, 2048, 768, 128),
                   top_k: int = 8,
+                  second_shape: tuple = (16384, 2048, 1536, 64, 4),
                   share_shape: tuple = (2048, 7680, 4096, 1000)) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
     [8192, 30], the grouped expert products at the ``keye`` head's
-    (rows, hidden, width, experts) and their way back to position order
+    (rows, hidden, width, experts), how they are fed there and at the
+    ``lfm2`` head's (``second_shape``, with its pairs a position: the ring
+    of weight slots, the rows brought in by ``gate_up`` itself, a seeded
+    routing's first visits that wait), and their way back to position order
     (``combine``) at both backbones' shapes: every slot of the ``keye``
     head's rows / ``top_k`` positions, and a pass of the ``pangu`` head's
     share (rows, hidden, positions, slots taken) — each against its XLA
@@ -779,10 +783,13 @@ def phase_kernels(interpret: bool = False, *,
     xs = jax.random.normal(ks[0], (rows, hidden), jnp.float32).astype(jnp.bfloat16)
     wg, wu = stacked(ks[1], hidden, width), stacked(ks[2], hidden, width)
     wd = stacked(ks[3], width, hidden)
-    # a seeded routing with its skew: every row one expert, some none
-    sizes = jnp.bincount(jax.random.categorical(
-        ks[4], jnp.linspace(0.0, 3.0, experts).at[1].set(-jnp.inf), shape=(rows,)),
-        length=experts).astype(jnp.int32)
+    def seeded_sizes(key, rows, experts):
+        """A seeded routing with its skew: every row one expert, some none."""
+        return jnp.bincount(jax.random.categorical(
+            key, jnp.linspace(0.0, 3.0, experts).at[1].set(-jnp.inf),
+            shape=(rows,)), length=experts).astype(jnp.int32)
+
+    sizes = seeded_sizes(ks[4], rows, experts)
     check(ge.supports(xs, wg), f"grouped experts: {expert_shape} not supported")
 
     def ragged(lhs, w):
@@ -807,6 +814,40 @@ def phase_kernels(interpret: bool = False, *,
     check(max(errs) <= EXPERTS_TOL,
           f"grouped experts {expert_shape}: max err {errs} > {EXPERTS_TOL}")
 
+    def fed(xs, wg, wu, sizes, top_k):
+        """How the kernels are fed at this shape (``ge.feed``); where
+        ``gate_up`` brings its rows in itself, that it gives the bits it
+        gives on a sorted copy; and step 0's count for this seeded routing
+        (PERF.md, PR 44): the experts whose first visit finds their gate
+        and up not landed, with two weight slots and with the ring's, by
+        the kernel's own rule at a v5e's published peaks."""
+        from chipbench.peaks import PEAKS
+
+        m, hidden = xs.shape
+        e, _, width = wg.shape
+        positions = m // top_k
+        x = xs[:positions]
+        at = (jax.random.permutation(ks[4], m) // top_k).astype(jnp.int32)
+        out = {"feed": ge.feed(m, hidden, e, width, positions)}
+        if ge.takes_rows(x, at, wg):
+            same = bool(jnp.all(
+                ge.gate_up(x, wg, wu, sizes, rows=at, interpret=interpret)
+                == ge.gate_up(x[at], wg, wu, sizes, interpret=interpret)))
+            out["rows_in_kernel_same_bits"] = same
+            check(same, f"gate_up {xs.shape}: rows in-kernel and gathered differ")
+        peaks = PEAKS["TPU v5 lite"]
+        fetch_us = 2 * hidden * width * 2 / peaks["bytes_per_s"] * 1e6
+        row_us = 2 * 2 * hidden * width / peaks["flops_per_s"] * 1e6
+        ring = ge._slots(wg, wu)
+        out["first_visits_that_wait"] = {
+            f"{n}_slots": ge.first_visits_that_wait(np.asarray(sizes), n,
+                                                    fetch_us, row_us)[0]
+            for n in sorted({2, ring})}
+        out["experts_with_rows"] = int(jnp.sum(sizes > 0))
+        return out
+
+    report[f"grouped_experts_fed_M{rows}_E{experts}"] = fed(xs, wg, wu, sizes, top_k)
+
     def way_back(label, ys, at, weights, take, want):
         """``combine`` against the XLA expressions it replaces."""
         picked = _said_by_the_expert_layer(
@@ -826,6 +867,12 @@ def phase_kernels(interpret: bool = False, *,
         lambda ys, at, w: jnp.sum(ys[at.reshape(-1)].reshape(*at.shape, -1)
                                   * w[..., None], axis=1))(ys, at, weights))
     del xs, wg, wu, wd, mid, mid_ref, ys, ys_ref, whole
+
+    rows2, hidden2, width2, experts, k2 = second_shape
+    report[f"grouped_experts_fed_M{rows2}_E{experts}"] = fed(
+        jax.random.normal(ks[0], (rows2, hidden2), jnp.float32).astype(jnp.bfloat16),
+        stacked(ks[1], hidden2, width2), stacked(ks[2], hidden2, width2),
+        seeded_sizes(ks[3], rows2, experts), k2)
 
     rows, hidden, positions, taken = share_shape
     slots = jax.random.permutation(ks[2], positions * top_k)[:taken]
@@ -861,7 +908,9 @@ def phase_kernels(interpret: bool = False, *,
 # must pick on a TPU at the published widths): the backbones
 # ``phase_backbone`` runs. ``pangu``'s share of wide experts is past the
 # grouped kernels' VMEM (one expert's gate and up are 63 MB), so its
-# products are XLA's; ``lfm2``'s attention is einsums and picks nothing.
+# products are XLA's; ``lfm2``'s attention is einsums and picks nothing,
+# and its expert kernels say how they are fed (the ring's slots of
+# ``gate_up`` / ``down``, the rows brought in by ``gate_up`` itself).
 BACKBONES = {
     "pangu": ("risk-seqhead-openpangu-ultra-moe-718b", "openpangu_ultra",
               "pangu_backbone", "PANGU_CONFIG",
@@ -869,7 +918,8 @@ BACKBONES = {
                "attention_core": "pallas-windows"}),
     "lfm2": ("risk-seqhead-lfm2-24b-a2b", "lfm2_24b_a2b",
              "lfm2_backbone", "LFM2_CONFIG",
-             {"expert_core": "pallas-grouped", "way_back": "pallas-rows"}),
+             {"expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
+                             "rows=in-kernel)", "way_back": "pallas-rows"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
               "attention_core": "attention core"}

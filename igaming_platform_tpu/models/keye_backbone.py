@@ -272,35 +272,62 @@ def route(x, layer: Params, cfg: BackboneConfig):
     return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
 
+# What each part last said it runs as (``/debug/sessionz``'s ``head_cores``).
+_ANNOUNCED: dict[str, str] = {}
+
+
 @lru_cache(maxsize=None)
 def _announce_core(core: str, backend: str, part: str = "expert core") -> None:
     """Log, once per (part, core, backend), which core runs a part of the
-    expert layer (``expert core``: its grouped products; ``combine``: the
-    results' way back to position order): the choice is made at trace time
-    and is otherwise invisible."""
+    expert layer (``expert core``: its grouped products, and where they
+    are the kernels how those are fed; ``combine``: the results' way back
+    to position order): the choice is made at trace time and is otherwise
+    invisible. ``announced_cores`` keeps the last word of each part."""
+    _ANNOUNCED[part] = f"{core} (backend={backend})"
     logger.info("%s: %s (backend=%s)", part, core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
 
 
+def announced_cores() -> dict[str, str]:
+    """Part -> the core it last announced, for the steps traced so far in
+    this process (empty before the first trace, and for a head that has
+    no such part)."""
+    return dict(_ANNOUNCED)
+
+
 def _expert_products(xs, sizes, layer: Params, cfg: BackboneConfig,
-                     whole_rows: bool = False):
+                     whole_rows: bool = False, rows=None):
     """Rows ``xs`` [M, hidden] sorted by expert, ``sizes`` [E] -> float32
     [M, hidden]: ``(silu(xs @ wg[e]) * (xs @ wu[e])) @ wd[e]`` for each
-    row's expert ``e``. On a TPU, at shapes the kernels support, two
-    Pallas grouped kernels (ops/pallas/grouped_experts.py: gate and up
-    share one read of the rows, silu and the product in the epilogue; with
-    ``whole_rows`` the second writes [M, hidden / 128, 128], each row one
-    piece of memory, for ``combine`` to copy row by row); elsewhere three
-    ``lax.ragged_dot`` products, which are also the kernels' golden
-    reference."""
+    row's expert ``e``; with ``rows`` [M], ``xs`` is the positions [P,
+    hidden] still unsorted and sorted row ``i`` is ``xs[rows[i]]``. On a
+    TPU, at shapes the kernels support, two Pallas grouped kernels
+    (ops/pallas/grouped_experts.py: gate and up share one read of the
+    rows, silu and the product in the epilogue; with ``whole_rows`` the
+    second writes [M, hidden / 128, 128], each row one piece of memory, for
+    ``combine`` to copy row by row). How they are fed is read from the
+    shapes and announced with the core: the weights through a ring of VMEM
+    slots, the rows brought together inside ``gate_up`` out of the
+    positions it holds in VMEM (``takes_rows``), or gathered here into a
+    sorted copy. Elsewhere that gather and three ``lax.ragged_dot``
+    products, which are also the kernels' golden reference."""
     from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
 
     backend = jax.default_backend()
-    if backend == "tpu" and kernels.supports(xs, layer["wg"]):
-        _announce_core("pallas-grouped", backend)
-        mid = kernels.gate_up(xs, layer["wg"], layer["wu"], sizes)
+    m, hidden = xs.shape[0] if rows is None else rows.shape[0], xs.shape[1]
+    e, _, width = layer["wg"].shape
+    if backend == "tpu" and kernels.supports(
+            jax.ShapeDtypeStruct((m, hidden), xs.dtype), layer["wg"]):
+        if rows is not None and not kernels.takes_rows(xs, rows, layer["wg"]):
+            xs, rows = xs[rows], None
+        fed = kernels.feed(m, hidden, e, width,
+                           None if rows is None else xs.shape[0])
+        _announce_core(f"pallas-grouped ({fed})", backend)
+        mid = kernels.gate_up(xs, layer["wg"], layer["wu"], sizes, rows=rows)
         return kernels.down(mid, layer["wd"], sizes, whole_rows=whole_rows)
     _announce_core("xla-ragged-dot", backend)
     dt = cfg.operand_dtype
+    if rows is not None:
+        xs = xs[rows]
 
     def grouped(lhs, w):
         return jax.lax.ragged_dot(lhs, w.astype(dt), sizes,
@@ -416,12 +443,12 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
     hidden = x.shape[-1]
     rank = jnp.argsort(order).reshape(n, k)  # the sorted row of every pair
     if everything:
-        xs = xb[order // k]
         results = jax.ShapeDtypeStruct((n * k, hidden), jnp.float32)
         if _combine_by_kernel(results, rank):
-            ys = _expert_products(xs, sizes, layer, cfg, whole_rows=True)
+            ys = _expert_products(xb, sizes, layer, cfg, whole_rows=True,
+                                  rows=order // k)
             return kernels.combine(ys, rank, top_w)
-        ys = _expert_products(xs, sizes, layer, cfg)
+        ys = _expert_products(xb, sizes, layer, cfg, rows=order // k)
         y = ys[rank.reshape(-1)].reshape(n, k, -1)
         return jnp.sum(y * top_w[..., None], axis=1)
 
@@ -436,7 +463,7 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
         # this pass's part of every expert's rows; rows past the held pairs
         # (the last pass's tail) belong to no expert and are read by nobody
         part = jnp.maximum(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0)
-        ys = _expert_products(xb[pair // k], part, layer, cfg)
+        ys = _expert_products(xb, part, layer, cfg, rows=pair // k)
         mine = (rank >= lo) & (rank < jnp.minimum(lo + rows, n_held))
         at = jnp.clip(rank - lo, 0, rows - 1)
         if _combine_by_kernel(ys, at, mine):

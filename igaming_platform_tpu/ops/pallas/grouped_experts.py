@@ -31,12 +31,39 @@ repeat its block indices and do nothing.
 The weights never ride the grid's own pipeline, which looks one step
 ahead: at ~256 rows an expert a visit is shorter than the fetch of the
 next expert's matrices. They stay in HBM and the kernel copies them into
-two VMEM slots itself: an expert's first visit waits for its matrices and
-starts the copy of the next expert that has rows, which then has all of
-this expert's visits to land. The whole contraction dimension is one
-block, so an expert's weights are fetched once a call however many tiles
-it spans; an expert with no rows has no visit and no fetch. Every pair is
-computed, whatever the skew: dropless stays dropless.
+a ring of VMEM slots itself, as far ahead as slots are free: the first
+grid step starts the copies of the first ``slots - 1`` experts that have
+rows; an expert's first visit waits for its own matrices and starts the
+copy of the ``slots - 1``-th expert with rows after it into the slot the
+expert before it has just left. How many slots is read from the shapes
+(``_slots``: as many experts' matrices as fit in the half of the VMEM cap
+that ``supports`` grants the weights, two at least, four at most), because
+loads are lumpy: with two slots a small expert behind a large one waits
+for its matrices while the DMA stood idle through the large one's
+products, and the call costs ``sum(max(fetch, products))`` where a deeper
+ring comes toward ``max(sum(fetch), sum(products))`` (PERF.md, PR 44). The
+whole contraction dimension is one block, so an expert's weights are
+fetched once a call however many tiles it spans; an expert with no rows
+has no visit and no fetch. Every pair is computed, whatever the skew:
+dropless stays dropless.
+
+The rows come one of two ways, by the shapes (``takes_rows``).
+*Gathered*: the caller sorts a copy ``xs`` [M, hidden] into expert order
+(an XLA gather that writes M rows to HBM) and the row tiles ride the
+grid's pipeline back in. *In-kernel*: ``gate_up`` is given the unsorted
+positions ``x`` [P, hidden] and ``rows`` [M] (sorted row ``i`` is
+``x[rows[i]]``), holds ``x`` whole in VMEM (16 MB at 4,096 positions of
+2,048) and brings each sub-tile's rows together itself, so no sorted copy
+is written or read back and nothing depends on where XLA's memory
+assignment put the gather's source (in ``lfm2``'s step it was HBM, 33 ns
+a row; in ``keye``'s VMEM, 6.4: PERF.md, PR 44). A bfloat16 row cannot be
+addressed alone in a tiled array, so ``x`` travels as 32-bit words, the
+row's two halves packed lane for lane (``_packed``): a row is then
+``hidden / 256`` sublanes of 128 words, one (8, 128) tile at 2,048; a
+sub-tile's rows are stored one under the other in a scratch and read back
+with a sublane stride (what ``combine`` does to turn its sums), which
+yields [rows, 128] pieces that unpack exactly into the [rows, hidden]
+operand the products read.
 
 Same arithmetic as the reference: operands as given (bfloat16),
 accumulation, ``silu`` and the gate-up product in float32, one rounding
@@ -77,6 +104,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # What a kernel may ask of the v5e's 128 MiB of VMEM: the default scoped
 # limit is 16 MiB, under what two slots of full-contraction weights need.
+# ``supports`` grants the weights half of it (two slots at least must fit);
+# ``_slots`` fills that half.
 _VMEM_CAP = 100 * 2**20
 
 # Rows one product multiplies at once. Measured on a v5e at the cell's
@@ -85,6 +114,10 @@ _VMEM_CAP = 100 * 2**20
 _SUB_TILE = 64
 
 _LANES = 128
+
+# Rows one turn of the loop that brings a sub-tile's rows together copies
+# (written out: Mosaic unrolls a loop whole or not at all).
+_ROWS_A_TURN = 8
 
 
 def _tiles(m: int) -> tuple[int, int]:
@@ -114,13 +147,85 @@ def supports(xs, w) -> bool:
             and 2 * 2 * hidden * width * 2 <= _VMEM_CAP // 2)
 
 
-def _schedule(sizes, m: int, tm: int):
+# The deepest ring of weight slots. Measured on a v5e under both cells'
+# loads (PERF.md, PR 44): four hide every fetch that can be hidden (by the
+# kernel's own rule one first visit still waits, the call's first); more
+# buy nothing, and their copies share the DMA's bandwidth with the one the
+# next expert is waiting for.
+_MOST_SLOTS = 4
+
+
+def _slots(*weights) -> int:
+    """Weight slots of the ring, from the shapes: as many experts'
+    matrices (each [K, N] of ``weights``' shapes-and-dtypes, fetched
+    together) as fit in the half of the VMEM cap that ``supports`` grants
+    the weights; two at least, ``_MOST_SLOTS`` at most."""
+    each = sum(math.prod(w.shape[1:]) * jnp.dtype(w.dtype).itemsize
+               for w in weights)
+    return max(2, min(_MOST_SLOTS, (_VMEM_CAP // 2) // each))
+
+
+def _grouped_vmem(lhs_bytes: int, tm: int, ts: int, k: int, n: int, nw: int,
+                  slots: int, out_size: int, w_size: int) -> int:
+    """What one grouped kernel asks of VMEM: what it holds of its rows
+    (``lhs_bytes``), the output tile's double buffer, the ring of weight
+    slots, the float32 accumulators of one sub-tile (twice: the products
+    and the epilogue's), and room to spare."""
+    return (lhs_bytes + 2 * tm * n * out_size + slots * nw * k * n * w_size
+            + 4 * nw * ts * n * 4 + 8 * 2**20)
+
+
+def _held_rows_bytes(positions: int, ts: int, k: int) -> int:
+    """In-kernel rows: the positions whole, and one sub-tile's rows one
+    under the other, both as 32-bit words of two bfloat16."""
+    return positions * k * 2 + ts * k * 2
+
+
+# Sorted rows whose positions ``gate_up`` keeps in scalar memory: what
+# ``combine`` keeps there for the cells' 32,768 pairs, twice over.
+_ROWS_IN_SMEM = 65536
+
+
+def takes_rows(x, rows, w) -> bool:
+    """Whether ``gate_up`` brings its rows in itself: positions ``x`` [P,
+    hidden] and ``rows`` [M] against stacked weights ``w`` [E, hidden,
+    width] (arrays or their shapes-and-dtypes). A row must be whole (8,
+    128) tiles of 32-bit words (hidden a multiple of 2,048), the positions
+    must fit VMEM whole beside the ring of weight slots ``supports``
+    allowed, and ``rows`` must fit the scalar memory that ``combine``'s
+    two lists already take. Anything else has the caller gather a sorted
+    copy (``xs = x[rows]``)."""
+    p, hidden = x.shape
+    m = rows.shape[0]
+    tm, ts = _tiles(m)
+    asked = _grouped_vmem(_held_rows_bytes(p, ts, hidden), tm, ts, hidden,
+                          w.shape[2], 2, _slots(w, w), 2, 2)
+    return (supports(jax.ShapeDtypeStruct((m, hidden), x.dtype), w)
+            and hidden % (2 * 8 * _LANES) == 0
+            and m <= _ROWS_IN_SMEM and asked <= _VMEM_CAP)
+
+
+def _packed(x):
+    """Positions ``x`` [P, hidden] (bfloat16) as 32-bit words [P, hidden /
+    256, 128]: word ``(c, l)`` of a row holds its element ``128 c + l`` in
+    the low half and element ``hidden / 2 + 128 c + l`` in the high half,
+    so a row is whole sublanes and can be addressed alone."""
+    p, hidden = x.shape
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+    words = bits[:, :hidden // 2] | (bits[:, hidden // 2:] << 16)
+    return words.reshape(p, hidden // 2 // _LANES, _LANES)
+
+
+def _schedule(sizes, m: int, tm: int, slots: int):
     """``sizes`` [E] -> the visits, as scalar-prefetch operands. Per
     expert: its first row and the row past its last. Per grid step: the
     visit's expert and row tile, whether it is the expert's first visit,
-    the weight slot the expert's matrices sit in (experts with rows
-    alternate), and the next expert with rows (-1 after the last). And
-    the number of visits (one element)."""
+    the weight slot the expert's matrices sit in (experts with rows take
+    the ``slots`` of the ring in turn), and the expert whose matrices the
+    first visit starts fetching: the ``slots - 1``-th with rows after this
+    one (-1 past the last). The first ``slots - 1`` experts with rows,
+    which the first grid step fetches (-1 where there are fewer). And the
+    number of visits (one element)."""
     e = sizes.shape[0]
     i32 = jnp.int32
     sizes = sizes.astype(i32)
@@ -139,44 +244,90 @@ def _schedule(sizes, m: int, tm: int):
     tile = (starts[group] // tm + v - visit_starts[group]).astype(i32)
     first = jnp.logical_and(step == visit_starts[group],
                             step < n_visits).astype(i32)
-    slot = ((jnp.cumsum(some) - 1) % 2).astype(i32)
-    later = jax.lax.cummin(jnp.where(some, jnp.arange(e, dtype=i32), e),
-                           reverse=True)
-    following = jnp.concatenate([later[1:], jnp.full((1,), e, i32)])
-    following = jnp.where(following >= e, -1, following)
-    return (starts, ends, group, tile, first, slot[group], following[group],
-            n_visits.reshape(1))
+    # an expert's rank among those with rows, and the expert of each rank
+    # (a comparison summed over the experts: no sort); ``e`` past the last
+    upto = jnp.cumsum(some.astype(i32))
+    rank = upto - 1
+    of_rank = jnp.sum((upto[None, :] <= jnp.arange(e, dtype=i32)[:, None])
+                      .astype(i32), axis=1)
+    of_rank = jnp.concatenate([of_rank, jnp.full((slots,), e, i32)])
+    of_rank = jnp.where(of_rank >= e, -1, of_rank)
+    fetch = of_rank[rank + slots - 1]
+    return (starts, ends, group, tile, first, (rank % slots).astype(i32)[group],
+            fetch[group], of_rank[:slots - 1], n_visits.reshape(1))
 
 
 def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
-            next_ref, n_ref, x_ref, *rest, tm: int, ts: int, nw: int,
-            epilogue, whole_rows: bool = False):
+            fetch_ref, prime_ref, n_ref, *rest, tm: int, ts: int, nw: int,
+            slots: int, epilogue, whole_rows: bool = False,
+            in_kernel: bool = False):
+    if in_kernel:
+        # each sorted row's position and the positions' words in HBM; the
+        # positions whole in VMEM, a sub-tile's rows one under the other
+        rows_ref, x_hbm, *rest = rest
+        *rest, x_vmem, under, x_sem = rest
+    else:
+        x_ref, *rest = rest
     w_hbm, o_ref = rest[:nw], rest[nw]
-    slots, sem = rest[nw + 1:2 * nw + 1], rest[2 * nw + 1]
+    ring, sem = rest[nw + 1:2 * nw + 1], rest[2 * nw + 1]
     v = pl.program_id(0)
     group, slot = group_ref[v], slot_ref[v]
 
     def copies(g, s):
         return [pltpu.make_async_copy(w.at[g], buf.at[s], sem.at[i, s])
-                for i, (w, buf) in enumerate(zip(w_hbm, slots))]
+                for i, (w, buf) in enumerate(zip(w_hbm, ring))]
 
     @pl.when(v == 0)
     def _prime():
-        for c in copies(group, slot):
-            c.start()
+        if in_kernel:
+            whole = pltpu.make_async_copy(x_hbm, x_vmem, x_sem)
+            whole.start()
+        for ahead in range(slots - 1):
+            @pl.when(prime_ref[ahead] >= 0)
+            def _():
+                for c in copies(prime_ref[ahead], ahead):
+                    c.start()
+        if in_kernel:
+            whole.wait()
 
     @pl.when(first_ref[v] == 1)
     def _turn():
         for c in copies(group, slot):
             c.wait()
 
-        @pl.when(next_ref[v] >= 0)
+        @pl.when(fetch_ref[v] >= 0)
         def _ahead():
-            for c in copies(next_ref[v], 1 - slot):
+            # into the slot the expert before this one has just left
+            for c in copies(fetch_ref[v], jax.lax.rem(slot + slots - 1, slots)):
                 c.start()
 
     start, end = start_ref[group], end_ref[group]
     tile_row0 = tile_ref[v] * tm
+
+    def brought(row0):
+        """The sub-tile's rows [ts, hidden] out of the positions held in
+        VMEM: each row's ``words`` sublanes stored under the last one's,
+        piece ``c`` of every row read back with a stride of a row, its low
+        halves the columns ``128 c ..``, its high halves ``hidden / 2 + 128
+        c ..`` (``_packed``). A bfloat16 is the high half of the float32
+        of the same value, so both unpack exactly."""
+        words = x_vmem.shape[1]
+
+        def put(g, carry):
+            for j in range(_ROWS_A_TURN):
+                u = g * _ROWS_A_TURN + j
+                under[pl.ds(pl.multiple_of(u * words, words), words), :] = (
+                    x_vmem[rows_ref[row0 + u]])
+            return carry
+
+        jax.lax.fori_loop(0, ts // _ROWS_A_TURN, put, 0)
+        pieces = [under[pl.ds(c, ts, stride=words), :] for c in range(words)]
+        f32 = lambda w: jax.lax.bitcast_convert_type(w, jnp.float32)
+        dt = ring[0].dtype
+        return jnp.concatenate(
+            [f32(w << 16).astype(dt) for w in pieces]
+            + [f32(w & jnp.uint32(0xFFFF0000)).astype(dt) for w in pieces],
+            axis=1)
 
     def multiply(sub, carry):
         row0 = tile_row0 + sub * ts
@@ -184,9 +335,9 @@ def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
         @pl.when((row0 < end) & (row0 + ts > start))
         def _own():
             window = pl.ds(pl.multiple_of(sub * ts, ts), ts)
-            x = x_ref[window, :]
+            x = brought(row0) if in_kernel else x_ref[window, :]
             acc = [jnp.dot(x, buf[slot], preferred_element_type=jnp.float32)
-                   for buf in slots]
+                   for buf in ring]
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (ts, 1), 0)
             own = jnp.logical_and(rows >= start, rows < end)
             if whole_rows:
@@ -208,35 +359,53 @@ def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
 
 
 def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
-             interpret: bool, whole_rows: bool = False):
+             slots: int, interpret: bool, whole_rows: bool = False,
+             rows=None):
     """One grouped kernel over the visits of ``sizes``: ``lhs`` [M, K]
     against every matrix of ``weights`` (each [E, K, N]), their float32
-    products through ``epilogue`` into [M, N]."""
-    m, k = lhs.shape
+    products through ``epilogue`` into [M, N]. With ``rows`` [M], ``lhs``
+    is the unsorted [P, K] and sorted row ``i`` is ``lhs[rows[i]]``,
+    brought together inside the kernel."""
+    k = lhs.shape[1]
+    m = lhs.shape[0] if rows is None else rows.shape[0]
     e, _, n = weights[0].shape
     nw = len(weights)
+    tiles = pl.cdiv(m, tm)
     row_block = lambda v, start, end, group, tile, *_: (tile[v], 0)
     out_size = jnp.dtype(out_dtype).itemsize
     w_size = weights[0].dtype.itemsize
-    # the row tiles' double buffers, two slots of weights, the float32
-    # accumulators of one sub-tile, and room to spare
-    vmem = (2 * tm * (k * lhs.dtype.itemsize + n * out_size)
-            + 2 * nw * k * n * w_size + 4 * nw * ts * n * 4 + 8 * 2**20)
     chunks = n // _LANES
     out_shape, out_block = (((m * chunks, _LANES), (tm * chunks, _LANES))
                             if whole_rows else ((m, n), (tm, n)))
+    if rows is None:
+        prefetch, operands = (), (lhs,)
+        in_specs = [pl.BlockSpec((tm, k), row_block)]
+        scratch = []
+        lhs_bytes = 2 * tm * k * lhs.dtype.itemsize
+    else:
+        words = k // 2 // _LANES
+        # a tile's sub-tiles read whole: rows past the last name row 0
+        prefetch = (jnp.pad(rows.astype(jnp.int32), (0, tiles * tm - m)),)
+        operands = (_packed(lhs),)
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        scratch = [pltpu.VMEM((lhs.shape[0], words, _LANES), jnp.uint32),
+                   pltpu.VMEM((ts * words, _LANES), jnp.uint32),
+                   pltpu.SemaphoreType.DMA(())]
+        lhs_bytes = _held_rows_bytes(lhs.shape[0], ts, k)
+    vmem = _grouped_vmem(lhs_bytes, tm, ts, k, n, nw, slots, out_size, w_size)
+    schedule = _schedule(sizes, m, tm, slots)
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, ts=ts, nw=nw, epilogue=epilogue,
-                          whole_rows=whole_rows),
+        functools.partial(_kernel, tm=tm, ts=ts, nw=nw, slots=slots,
+                          epilogue=epilogue, whole_rows=whole_rows,
+                          in_kernel=rows is not None),
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,
-            grid=(pl.cdiv(m, tm) + e - 1,),
-            in_specs=[pl.BlockSpec((tm, k), row_block)]
-            + [pl.BlockSpec(memory_space=pl.ANY)] * nw,
+            num_scalar_prefetch=len(schedule) + len(prefetch),
+            grid=(tiles + e - 1,),
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * nw,
             out_specs=pl.BlockSpec(out_block, row_block),
-            scratch_shapes=[pltpu.VMEM((2, k, n), weights[0].dtype)] * nw
-            + [pltpu.SemaphoreType.DMA((nw, 2))],
+            scratch_shapes=[pltpu.VMEM((slots, k, n), weights[0].dtype)] * nw
+            + [pltpu.SemaphoreType.DMA((nw, slots))] + scratch,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -247,32 +416,39 @@ def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
             bytes_accessed=(m * k * lhs.dtype.itemsize + m * n * out_size
                             + nw * e * k * n * w_size)),
         interpret=interpret,
-    )(*_schedule(sizes, m, tm), lhs, *weights)
-
-
-@functools.partial(jax.jit, static_argnames=("tm", "ts", "interpret"))
-def _gate_up(xs, wg, wu, sizes, *, tm: int, ts: int, interpret: bool):
-    return _grouped(lambda g, u: jax.nn.silu(g) * u, xs, (wg, wu), sizes,
-                    xs.dtype, tm=tm, ts=ts, interpret=interpret)
+    )(*schedule, *prefetch, *operands, *weights)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("tm", "ts", "interpret", "whole_rows"))
-def _down(mid, wd, sizes, *, tm: int, ts: int, interpret: bool,
+                   static_argnames=("tm", "ts", "slots", "interpret"))
+def _gate_up(xs, wg, wu, sizes, rows=None, *, tm: int, ts: int, slots: int,
+             interpret: bool):
+    return _grouped(lambda g, u: jax.nn.silu(g) * u, xs, (wg, wu), sizes,
+                    xs.dtype, tm=tm, ts=ts, slots=slots, interpret=interpret,
+                    rows=rows)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "ts", "slots", "interpret",
+                                             "whole_rows"))
+def _down(mid, wd, sizes, *, tm: int, ts: int, slots: int, interpret: bool,
           whole_rows: bool = False):
-    return _grouped(lambda y: y, mid, (wd,), sizes, jnp.float32,
-                    tm=tm, ts=ts, interpret=interpret, whole_rows=whole_rows)
+    return _grouped(lambda y: y, mid, (wd,), sizes, jnp.float32, tm=tm, ts=ts,
+                    slots=slots, interpret=interpret, whole_rows=whole_rows)
 
 
-def gate_up(xs, wg, wu, sizes, *, interpret: bool = False):
+def gate_up(xs, wg, wu, sizes, *, rows=None, interpret: bool = False):
     """``silu(xs @ wg[e]) * (xs @ wu[e])`` for the expert ``e`` of each
     row: ``xs`` [M, hidden] sorted by expert, ``wg`` and ``wu`` [E, hidden,
     width], ``sizes`` int32 [E] summing to M -> ``mid`` [M, width] in
-    ``xs``'s dtype (products and silu in float32, rounded once).
+    ``xs``'s dtype (products and silu in float32, rounded once). With
+    ``rows`` int32 [M] (where ``takes_rows`` holds) ``xs`` is the unsorted
+    positions [P, hidden] and sorted row ``i`` is ``xs[rows[i]]``: the
+    kernel brings the rows together itself and no sorted copy exists.
     ``interpret=True`` runs the Pallas interpreter, the only way to run
     the kernel off the TPU, and always the caller's explicit choice."""
-    tm, ts = _tiles(xs.shape[0])
-    return _gate_up(xs, wg, wu, sizes, tm=tm, ts=ts, interpret=interpret)
+    tm, ts = _tiles(xs.shape[0] if rows is None else rows.shape[0])
+    return _gate_up(xs, wg, wu, sizes, rows, tm=tm, ts=ts,
+                    slots=_slots(wg, wu), interpret=interpret)
 
 
 def down(mid, wd, sizes, *, whole_rows: bool = False, interpret: bool = False):
@@ -283,11 +459,53 @@ def down(mid, wd, sizes, *, whole_rows: bool = False, interpret: bool = False):
     stores each lane chunk of a sub-tile with a sublane stride, at the
     price of a plain store (PERF.md, PR 37)."""
     tm, ts = _tiles(mid.shape[0])
-    ys = _down(mid, wd, sizes, tm=tm, ts=ts, interpret=interpret,
-               whole_rows=whole_rows)
+    ys = _down(mid, wd, sizes, tm=tm, ts=ts, slots=_slots(wd),
+               interpret=interpret, whole_rows=whole_rows)
     if whole_rows:
         return ys.reshape(mid.shape[0], wd.shape[2] // _LANES, _LANES)
     return ys
+
+
+def feed(pairs: int, hidden: int, experts: int, width: int,
+         positions: int | None = None, dtype=jnp.bfloat16) -> str:
+    """How the kernels are fed at a shape, for a log line: ``tm=.., ts=..,
+    slots=<gate_up's>/<down's>, rows=in-kernel|gathered`` (``positions``:
+    how many unsorted positions ``gate_up`` would be given with ``rows``;
+    None where it is given a sorted copy)."""
+    shape = jax.ShapeDtypeStruct
+    wg = shape((experts, hidden, width), dtype)
+    wd = shape((experts, width, hidden), dtype)
+    tm, ts = _tiles(pairs)
+    inside = positions is not None and takes_rows(
+        shape((positions, hidden), dtype), shape((pairs,), jnp.int32), wg)
+    return (f"tm={tm}, ts={ts}, slots={_slots(wg, wg)}/{_slots(wd)}, "
+            f"rows={'in-kernel' if inside else 'gathered'}")
+
+
+def first_visits_that_wait(sizes, slots: int, fetch_us: float,
+                           row_us: float) -> tuple[int, float]:
+    """A count for a log line, made on the host by the kernel's own rule:
+    of the experts with rows in ``sizes``, how many find at their first
+    visit that their matrices have not landed, and the microseconds the
+    call takes, if one expert's matrices take ``fetch_us`` to fetch (one
+    copy after the other, each started ``slots - 1`` first visits ahead,
+    the first ``slots - 1`` at the call's start) and a row ``row_us`` to
+    multiply (an expert's rows and one masked sub-tile). The first expert
+    always waits. A model: the chip's numbers are in PERF.md (PR 44)."""
+    rows = [int(n) for n in sizes if n > 0]
+    landed, free_at = [], 0.0  # when each expert's matrices land; the DMA's turn
+    for _ in rows[:slots - 1]:
+        free_at += fetch_us
+        landed.append(free_at)
+    waits, now = 0, 0.0
+    for r, n in enumerate(rows):
+        waits += landed[r] > now
+        now = max(now, landed[r])
+        if r + slots - 1 < len(rows):
+            free_at = max(free_at, now) + fetch_us
+            landed.append(free_at)
+        now += (n + _SUB_TILE) * row_us
+    return waits, now
 
 
 # -- the way back to position order ---------------------------------------------

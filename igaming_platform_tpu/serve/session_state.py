@@ -58,6 +58,7 @@ from typing import Any
 
 import numpy as np
 
+from igaming_platform_tpu.models.keye_backbone import announced_cores
 from igaming_platform_tpu.models.sequence import EVENT_DIM
 from igaming_platform_tpu.models.session_heads import (
     HEAD_EXPERTS,
@@ -632,6 +633,7 @@ class SessionStateManager:
                 "head_experts_held": self.head_experts[0],
                 "head_experts_routed": self.head_experts[1],
                 "head_layers": dict(self.head_layers),
+                "head_cores": announced_cores(),
                 "lock_wait_s": self.lock_wait_s,
                 "lock_held_s": self.lock_held_s,
                 "rehydrations": self.rehydrations,

@@ -149,6 +149,47 @@ def _assert_in_place(compiled, ring_elems: int) -> None:
     assert mem.alias_size_in_bytes >= 4 * ring_elems, mem
 
 
+def _expert_kernels(text: str, capsys, head: str) -> list[str]:
+    """The Pallas calls under ``head/moe/experts`` of a compiled step, the
+    VMEM each asks for printed (its scoped region: what XLA keeps of its
+    own in VMEM across the call lies under ``offset``)."""
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line
+               and "head/moe/experts" in line]
+    asks = {}
+    for line in kernels:
+        name = re.match(r"\s*%(\w+?)(\.\d+)? = ", line).group(1)
+        asked = re.search(r'scoped_memory_configs":\[\{"memory_space":"1",'
+                          r'"offset":"(\d+)","size":"(\d+)"', line)
+        offset, size = (int(g) for g in asked.groups()) if asked else (0, 0)
+        assert offset + size <= 128 * 2**20, line[:200]  # the chip's VMEM
+        asks.setdefault((name, size, offset), []).append(line)
+    with capsys.disabled():
+        for (name, size, offset), calls in asks.items():
+            print(f"{head} {name} x {len(calls)}: {size} B of VMEM asked, "
+                  f"above {offset} B that XLA holds")
+    return kernels
+
+
+def _assert_rows_come_in_by_the_kernel(text: str, pairs: int) -> None:
+    """What ISSUE 44 found by reading this text: in the ``lfm2`` step the
+    normed positions, made in VMEM, were copied out to HBM for XLA's
+    gather into expert order (a ``copy-start`` of ``bf16[4096,2048]`` out
+    of ``S(1)``; 33 ns a row on the chip against 6.4 in ``keye``'s step,
+    whose source XLA happened to leave in VMEM). Now no sorted copy
+    exists in either step: ``gate_up`` is handed the positions as 32-bit
+    words, a row one tile, and brings its rows together itself."""
+    assert f"bf16[{pairs},2048]" not in text
+    assert not [line for line in text.splitlines()
+                if "copy-start" in line and "bf16[4096,2048]" in line]
+    gate_ups = [line for line in text.splitlines()
+                if re.match(r"\s*%_gate_up(\.\d+)? = ", line)]
+    assert len(gate_ups) == 4
+    for line in gate_ups:
+        assert "u32[4096,8,128]" in line and f"s32[{pairs}]" in line, line[:300]
+
+
+
 @pytest.mark.parametrize("head", ["pattern", "transformer"])
 def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
     from jax.sharding import SingleDeviceSharding
@@ -163,7 +204,7 @@ def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
 
 
 def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
-        topo, tpu_backend):
+        topo, tpu_backend, capsys):
     """The fused step with the ``keye`` backbone in it, at the cell's size
     (5,242,880 accounts, one 256-row rung) and with the expert core the
     chip picks: still in place on the ring, its arguments are the state
@@ -189,14 +230,13 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     # 396,118,528 B (PR 37); 615,414,272 B with XLA's gather and sum (PR 35)
     assert mem.temp_size_in_bytes <= 615_414_272, mem
     text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and "custom-call(" in line
-               and "head/moe/experts" in line]
+    kernels = _expert_kernels(text, capsys, "keye")
     for name in ("_gate_up", "_down", "_combine_rows"):
         calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
         assert len(calls) == 4, (name, kernels)
     assert len(kernels) == 12, kernels
     assert "%ragged-dot-none" not in text
+    _assert_rows_come_in_by_the_kernel(text, 32768)
 
 
 def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
@@ -295,15 +335,14 @@ def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
     assert 9.8e9 < mem.argument_size_in_bytes < 10.0e9, mem
     assert mem.temp_size_in_bytes <= 615_414_272, mem  # keye's bound
     text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and "custom-call(" in line
-               and "head/moe/experts" in line]
+    kernels = _expert_kernels(text, capsys, "lfm2")
     moe = len(cfg.layer_types) - cfg.dense_layers
     for name in ("_gate_up", "_down", "_combine_rows"):
         calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
         assert len(calls) == moe == 4, (name, kernels)
     assert len(kernels) == 12, kernels
     assert "%ragged-dot-none" not in text
+    _assert_rows_come_in_by_the_kernel(text, 16384)
     # the taps are elementwise work under their scope, never a product
     assert "head/conv/taps" in text and "head/attn" in text
     assert not [line for line in text.splitlines()

@@ -182,10 +182,21 @@ def test_smoke_kernels_phase_tiny_interpreted():
     report = chip_smoke.phase_kernels(
         interpret=True, attention_shapes=((1, 2, 64, 32),),
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
-        expert_shape=(512, 1024, 128, 8), share_shape=(64, 128, 32, 20))
+        expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
+        share_shape=(64, 128, 32, 20))
     assert report["interpret"] is True
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
+    # how the kernels are fed: hidden 1024 is gathered outside, hidden 2048
+    # brought in by ``gate_up`` itself, to the same bits; a ring never
+    # makes more first visits wait than two slots do
+    assert report["grouped_experts_fed_M512_E8"]["feed"] == (
+        "tm=256, ts=64, slots=4/4, rows=gathered")
+    fed = report["grouped_experts_fed_M256_E4"]
+    assert fed["feed"] == "tm=256, ts=64, slots=4/4, rows=in-kernel"
+    assert fed["rows_in_kernel_same_bits"] is True
+    waits = fed["first_visits_that_wait"]
+    assert 1 <= waits["4_slots"] <= waits["2_slots"] <= fed["experts_with_rows"]
     for key in ("combine_M512_H1024", "combine_share_M64_H128"):
         assert report[key]["max_err"] <= chip_smoke.COMBINE_TOL
         # what a trace would pick here, off the TPU

@@ -147,15 +147,16 @@ def test_a_row_is_multiplied_by_its_own_expert_alone(skew):
                                rtol=0)
 
 
+@pytest.mark.parametrize("slots", [2, 3, 8])
 @pytest.mark.parametrize("skew", ["not-multiples-of-the-tile",
                                   "64-experts-ragged", "64-experts-uniform"])
-def test_schedule_visits_every_tile_an_expert_touches_and_no_other(skew):
+def test_schedule_visits_every_tile_an_expert_touches_and_no_other(skew, slots):
     rows, sizes = SKEWS[skew]
     EXPERTS = len(sizes)
     tm = 256
-    starts, ends, group, tile, first, slot, following, n = (
+    starts, ends, group, tile, first, slot, fetch, prime, n = (
         np.asarray(a) for a in ge._schedule(jnp.asarray(sizes, jnp.int32),
-                                            rows, tm))
+                                            rows, tm, slots))
     want = [(e, t) for e in range(EXPERTS) if sizes[e]
             for t in range(starts[e] // tm, (ends[e] - 1) // tm + 1)]
     assert list(zip(group[:n[0]], tile[:n[0]])) == want
@@ -164,11 +165,18 @@ def test_schedule_visits_every_tile_an_expert_touches_and_no_other(skew):
     assert set(zip(group[n[0]:], tile[n[0]:])) <= {want[-1]}
     assert first[n[0]:].sum() == 0
     # an expert's first visit is where its weights are waited for; the
-    # expert after it (with rows) is fetched into the other slot
+    # experts with rows take the ring's slots in turn; the first grid step
+    # fetches the first ``slots - 1`` of them and each first visit the
+    # ``slots - 1``-th after its own, so every expert with rows is fetched
+    # once, ``slots - 1`` first visits before it is waited for
     turns = np.flatnonzero(first)
-    assert group[turns].tolist() == [e for e in range(EXPERTS) if sizes[e]]
-    assert following[turns].tolist() == group[turns].tolist()[1:] + [-1]
-    assert (slot[turns][1:] != slot[turns][:-1]).all()
+    with_rows = [e for e in range(EXPERTS) if sizes[e]]
+    assert group[turns].tolist() == with_rows
+    assert slot[turns].tolist() == [r % slots for r in range(len(with_rows))]
+    ahead = slots - 1
+    assert prime.tolist() == (with_rows[:ahead] + [-1] * ahead)[:ahead]
+    assert fetch[turns].tolist() == (with_rows[ahead:] + [-1] * ahead)[
+        :len(with_rows)]
 
 
 @pytest.mark.parametrize("why,rows,hidden,width,dtype", [
@@ -195,6 +203,156 @@ def test_supports_the_cells_shapes(pairs, experts, width):
                                                  jnp.bfloat16))
     # two slots of an expert's gate and up: 12.6 and 25.2 MB of the 52.4
     assert 2 * 2 * 2048 * width * 2 <= ge._VMEM_CAP // 2
+
+
+# -- the feed: a ring of weight slots, and the rows brought in by the kernel ---------
+
+# The two cells' shapes cut down, ratios kept (keye 128 x 768 at 8 a
+# position -> 8 x 256 at 2; lfm2 64 x 1,536 at 4 -> 4 x 512 at 1), at the
+# hidden size the rows' way in is written for: experts, hidden, width, pairs
+# a position.
+FED = {"keye-cut": (8, 2048, 256, 2), "lfm2-cut": (4, 2048, 512, 1)}
+
+# pairs, sizes: what the ring and the row tiles have cases for
+FED_LOADS = {
+    "keye-cut": {
+        "one-holds-most": (512, [3, 2, 490, 1, 4, 5, 6, 1]),
+        "runs-of-empty": (512, [0, 0, 200, 0, 0, 0, 300, 12]),
+        "ends-on-tile-boundaries": (768, [256, 0, 128, 128, 0, 64, 192, 0]),
+        "ends-off-tile-boundaries": (512, [130, 1, 61, 3, 127, 129, 60, 1]),
+        "under-one-tile": (40, [5, 0, 7, 8, 0, 10, 9, 1]),
+    },
+    "lfm2-cut": {
+        "one-holds-most": (320, [10, 300, 4, 6]),
+        "runs-of-empty": (320, [0, 0, 320, 0]),
+        "ends-on-tile-boundaries": (512, [256, 64, 0, 192]),
+        "ends-off-tile-boundaries": (320, [100, 1, 199, 20]),
+        "under-one-tile": (24, [1, 0, 20, 3]),
+    },
+}
+FED_CASES = [(shape, load) for shape in FED for load in FED_LOADS[shape]]
+
+
+def fed_operands(shape: str, load: str, seed: int = 3):
+    """Positions, each sorted row's position (a permutation of the pairs,
+    as ``order // k`` is), the three stacked weights and the sizes."""
+    experts, hidden, width, k = FED[shape]
+    pairs, sizes = FED_LOADS[shape][load]
+    x, wg, wu, wd = operands(pairs // k, seed, (experts, hidden, width))
+    rows = jnp.asarray(np.random.default_rng(seed).permutation(pairs) // k,
+                       jnp.int32)
+    return x, rows, wg, wu, wd, jnp.asarray(sizes, jnp.int32)
+
+
+@pytest.mark.parametrize("slots", [2, 3, "by-the-shapes"])
+@pytest.mark.parametrize("shape,load", FED_CASES)
+def test_the_feed_changes_no_bit_of_gate_up(shape, load, slots):
+    """Rows brought together inside the kernel out of the unsorted
+    positions, weights through a ring of 2, 3 or as many slots as the
+    shapes give (more slots than experts with rows, and fewer): the same
+    bits as the kernel fed a sorted copy through two slots, which is
+    ``lax.ragged_dot``'s result to a rounding."""
+    x, rows, wg, wu, _, sizes = fed_operands(shape, load)
+    assert ge.takes_rows(x, rows, wg) and int(sizes.sum()) == rows.shape[0]
+    tm, ts = ge._tiles(rows.shape[0])
+    if slots == "by-the-shapes":
+        got = ge.gate_up(x, wg, wu, sizes, rows=rows, interpret=True)
+    else:
+        got = ge._gate_up(x, wg, wu, sizes, rows, tm=tm, ts=ts, slots=slots,
+                          interpret=True)
+    xs = x[rows]
+    two = ge._gate_up(xs, wg, wu, sizes, tm=tm, ts=ts, slots=2, interpret=True)
+    np.testing.assert_array_equal(f32(got), f32(two))
+    want32 = jax.nn.silu(ragged(xs, wg, sizes)) * ragged(xs, wu, sizes)
+    np.testing.assert_allclose(f32(got), np.asarray(want32),
+                               atol=2e-5, rtol=2 ** -7)
+
+
+@pytest.mark.parametrize("slots", [2, 3, "by-the-shapes"])
+@pytest.mark.parametrize("shape,load", FED_CASES)
+def test_the_ring_changes_no_bit_of_down(shape, load, slots):
+    x, rows, wg, wu, wd, sizes = fed_operands(shape, load, seed=4)
+    tm, ts = ge._tiles(rows.shape[0])
+    mid = ge.gate_up(x[rows], wg, wu, sizes, interpret=True)
+    if slots == "by-the-shapes":
+        got = ge.down(mid, wd, sizes, interpret=True)
+    else:
+        got = ge._down(mid, wd, sizes, tm=tm, ts=ts, slots=slots,
+                       interpret=True)
+    want = ragged(mid, wd, sizes)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5 * float(jnp.abs(want).max()), rtol=0)
+    two = ge._down(mid, wd, sizes, tm=tm, ts=ts, slots=2, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(two))
+
+
+def test_packed_words_hold_a_rows_two_halves_lane_for_lane():
+    x = operands(16, 5, (1, 2048, 128))[0]
+    words = np.asarray(ge._packed(x))
+    assert words.shape == (16, 8, 128) and words.dtype == np.uint32
+    bits = np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16)).astype(np.uint32)
+    np.testing.assert_array_equal(words.reshape(16, 1024) & 0xFFFF, bits[:, :1024])
+    np.testing.assert_array_equal(words.reshape(16, 1024) >> 16, bits[:, 1024:])
+
+
+@pytest.mark.parametrize("why,positions,pairs,hidden,experts,width", [
+    ("a row is not whole tiles of words", 256, 512, 1024, 8, 256),
+    ("more sorted rows than scalar memory holds", 65536, 131072, 2048, 8, 256),
+    ("the positions do not fit beside the ring", 16384, 65536, 2048, 64, 1536),
+    ("a shape the kernels refuse", 256, 512, 2048, 8, 200),
+], ids=lambda v: v.replace(" ", "-") if isinstance(v, str) else "")
+def test_takes_rows_refuses(why, positions, pairs, hidden, experts, width):
+    x = jax.ShapeDtypeStruct((positions, hidden), jnp.bfloat16)
+    rows = jax.ShapeDtypeStruct((pairs,), jnp.int32)
+    w = jax.ShapeDtypeStruct((experts, hidden, width), jnp.bfloat16)
+    assert not ge.takes_rows(x, rows, w), why
+
+
+@pytest.mark.parametrize("pairs,experts,width,fed", [
+    (32768, 128, 768, "tm=256, ts=64, slots=4/4, rows=in-kernel"),
+    (16384, 64, 1536, "tm=256, ts=64, slots=4/4, rows=in-kernel"),
+], ids=["keye-128x768-top8", "lfm2-64x1536-top4"])
+def test_the_choices_at_the_published_shapes_are_pinned(pairs, experts, width, fed):
+    """What the feed resolves to at the two cells' shapes (4,096 positions
+    of 2,048), so that neither cell's kernel changes silently with the
+    next edit: the chip numbers in PERF.md (PR 44) were read with these."""
+    assert ge.feed(pairs, 2048, experts, width, positions=4096) == fed
+    shape = jax.ShapeDtypeStruct
+    wg = shape((experts, 2048, width), jnp.bfloat16)
+    wd = shape((experts, width, 2048), jnp.bfloat16)
+    assert ge._tiles(pairs) == (256, 64)
+    assert (ge._slots(wg, wg), ge._slots(wd)) == (4, 4)
+    assert ge.takes_rows(shape((4096, 2048), jnp.bfloat16),
+                         shape((pairs,), jnp.int32), wg)
+    # given a sorted copy (no positions) the rows are what the caller gathered
+    assert ge.feed(pairs, 2048, experts, width).endswith("rows=gathered")
+    # pangu's share stays off the kernels (63 MB a slot): PERF.md, Open question 14
+    assert not ge.supports(shape((2048, 7680), jnp.bfloat16),
+                           shape((8, 7680, 2048), jnp.bfloat16))
+
+
+def test_slots_are_read_from_the_shapes():
+    shape = jax.ShapeDtypeStruct
+    w = lambda k, n: shape((8, k, n), jnp.bfloat16)
+    assert ge._slots(w(128, 256)) == ge._MOST_SLOTS == 4  # small matrices: the cap
+    assert ge._slots(w(2048, 1536), w(2048, 1536)) == 4   # 12.6 MB a slot: 4 fit
+    assert ge._slots(w(2048, 2048), w(2048, 2048)) == 3   # 16.8 MB a slot
+    assert ge._slots(w(2048, 3072), w(2048, 3072)) == 2   # 25.2 MB: what supports allows
+    assert ge.supports(shape((512, 2048), jnp.bfloat16), w(2048, 3072))
+
+
+@pytest.mark.parametrize("sizes,slots,waits", [
+    ([256] * 8, 2, 1), ([256] * 8, 4, 1),          # products cover every fetch
+    ([2000, 10, 10, 10, 10, 10], 2, 5),   # one ahead: the small ones wait, but the first
+    ([2000, 10, 10, 10, 10, 10], 4, 3),   # three land under the large one's products
+    ([0, 0, 5, 0], 4, 1), ([0, 0, 0], 2, 0),
+], ids=["uniform-2", "uniform-4", "lumpy-2", "lumpy-4", "one", "none"])
+def test_first_visits_that_wait_by_the_kernels_own_rule(sizes, slots, waits):
+    # an expert's matrices 15 us to fetch, a row 0.064 us to multiply
+    got, took = ge.first_visits_that_wait(sizes, slots, 15.0, 0.064)
+    assert got == waits
+    rows = sum(n + 64 for n in sizes if n)
+    assert took >= max(rows * 0.064, 15.0 * (got > 0))
 
 
 # -- down, its rows whole ---------------------------------------------------------

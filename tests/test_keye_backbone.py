@@ -206,11 +206,17 @@ def expert_core(request, monkeypatch):
     if request.param == "xla":
         return dict(operand_dtype=jnp.float32), 2e-4, "xla-ragged-dot"
     _steer_to_the_kernels(monkeypatch)
-    # bfloat16 operands: x, the weights and ``mid`` are each rounded once
+    # bfloat16 operands: x, the weights and ``mid`` are each rounded once;
+    # at hidden 2048 a row is whole tiles of packed words and ``gate_up``
+    # brings its rows in itself (``takes_rows``), below that the caller
+    # gathers a sorted copy
+    if request.param == "pallas-rows-in-kernel":
+        return dict(hidden=2048, expert_width=128), 2e-2, "pallas-grouped"
     return dict(hidden=128, expert_width=128), 2e-2, "pallas-grouped"
 
 
-@pytest.mark.parametrize("expert_core", ["xla", "pallas"], indirect=True)
+@pytest.mark.parametrize("expert_core", ["xla", "pallas", "pallas-rows-in-kernel"],
+                         indirect=True)
 @pytest.mark.parametrize("experts,top_k,hot", [(8, 2, (3, 5)), (16, 8, (2, 11)),
                                                (8, 2, None)],
                          ids=["top2-all-on-two", "top8-two-in-every-set", "free"])
@@ -236,7 +242,10 @@ def test_grouped_experts_drop_nothing_under_skew(experts, top_k, hot, expert_cor
     with caplog.at_level("INFO", logger=kb.logger.name):
         got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
             x, e, w, layer, cfg))(x, top_e, top_w))
-    assert f"expert core: {core} (backend=" in caplog.text
+    assert f"expert core: {core} (" in caplog.text  # the kernels say how they are fed
+    if core == "pallas-grouped":
+        rows = "in-kernel" if cfg.hidden == 2048 else "gathered"
+        assert f"rows={rows}) (backend=tpu)" in caplog.text
     want = _expert_loop(x, top_e, top_w, layer)
     np.testing.assert_allclose(got, want, atol=tolerance * np.abs(want).max(), rtol=0)
     # every position got all of its experts: leaving any pair out shows
@@ -272,10 +281,17 @@ def test_expert_core_is_announced_and_falls_back_where_the_kernels_do_not_fit(
     assert announced(aligned) == ["expert core: xla-ragged-dot (backend=cpu)"]
     _steer_to_the_kernels(monkeypatch)
     assert announced(small) == ["expert core: xla-ragged-dot (backend=tpu)"]
-    assert announced(aligned) == ["expert core: pallas-grouped (backend=tpu)"]
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+    fed = kernels.feed(64, 128, aligned.experts, 128)  # a sorted copy: gathered
+    assert fed.endswith("rows=gathered")
+    assert announced(aligned) == [f"expert core: pallas-grouped ({fed}) (backend=tpu)"]
     assert announced(small_config(hidden=128, expert_width=128,
                                   operand_dtype=jnp.float32)) == [
         "expert core: xla-ragged-dot (backend=tpu)"]
+    # the last word of each part is kept for /debug/sessionz (``head_cores``)
+    assert kb.announced_cores()["expert core"] == "xla-ragged-dot (backend=tpu)"
+    announced(aligned)
+    assert kb.announced_cores()["expert core"] == f"pallas-grouped ({fed}) (backend=tpu)"
 
 
 @pytest.mark.parametrize("operands", ["float32", "bfloat16"])
@@ -295,7 +311,11 @@ def test_head_with_the_kernels_on_equals_the_xla_path(operands, monkeypatch, cap
     with caplog.at_level("INFO", logger=kb.logger.name):
         by_kernels = program_scores(cfg, params, x, lens)
     said = {r.getMessage() for r in caplog.records}
-    core = "pallas-grouped" if operands == "bfloat16" else "xla-ragged-dot"
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+    # hidden 1024 is not whole tiles of packed words: the rows are gathered
+    fed = kernels.feed(128 * cfg.top_k, 1024, cfg.experts, 128, positions=128)
+    assert fed.endswith("rows=gathered")
+    core = f"pallas-grouped ({fed})" if operands == "bfloat16" else "xla-ragged-dot"
     assert said == {f"expert core: {core} (backend=tpu)",
                     "combine: pallas-rows (backend=tpu)"}
     assert np.ptp(by_xla) > 1e-3
@@ -360,7 +380,8 @@ def test_pass_rows_bound_a_share_and_cover_a_whole_layer():
     assert kb.pass_rows(4096, 1, 256, 1 << 20) == 256  # one tile at least
 
 
-@pytest.mark.parametrize("expert_core", ["xla", "pallas"], indirect=True)
+@pytest.mark.parametrize("expert_core", ["xla", "pallas", "pallas-rows-in-kernel"],
+                         indirect=True)
 @pytest.mark.parametrize("routing", ["all-held", "none-held", "free", "ragged"])
 def test_a_share_is_dropless_at_any_routing(routing, expert_core):
     """Experts 8-11 of 32 held, 512 positions x 4: ``pass_rows`` is 1,024

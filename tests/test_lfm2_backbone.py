@@ -445,6 +445,17 @@ def _steer_to_the_kernels(monkeypatch):
             getattr(kernels, name), interpret=True))
 
 
+def _fed(cfg, positions: int) -> str:
+    """How the kernels say they are fed at this size: hidden 1024 is not
+    whole tiles of packed words, so the rows are gathered outside."""
+    from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
+
+    fed = kernels.feed(positions * cfg.top_k, cfg.hidden, cfg.experts,
+                       cfg.expert_width, positions=positions)
+    assert fed.endswith("rows=gathered")
+    return fed
+
+
 @pytest.mark.parametrize("core", ["xla", "pallas"])
 def test_expert_layer_equals_the_loop_over_experts(core, monkeypatch, caplog):
     """``grouped_experts`` with this head's router against the loop over
@@ -472,7 +483,7 @@ def test_expert_layer_equals_the_loop_over_experts(core, monkeypatch, caplog):
     said = {r.getMessage() for r in caplog.records}
     assert said == ({"expert core: xla-ragged-dot (backend=cpu)",
                      "combine: xla-gather (backend=cpu)"} if core == "xla" else
-                    {"expert core: pallas-grouped (backend=tpu)",
+                    {f"expert core: pallas-grouped ({_fed(cfg, n)}) (backend=tpu)",
                      "combine: pallas-rows (backend=tpu)"})
     want = _expert_loop(x, top_e, top_w, layer["routed"])
     np.testing.assert_allclose(got, want, atol=tolerance * np.abs(want).max(),
@@ -601,6 +612,10 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (
         c.experts, c.experts) == (64, 64)
     assert snap["head_layers"] == {"conv": 4, "attention": 1, "dense": 1, "moe": 4}
+    # what the step's expert layer said it runs as when it was traced (here
+    # the CPU's cores; on a TPU the kernels and how they are fed)
+    assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"
+    assert snap["head_cores"]["combine"] == "xla-gather (backend=cpu)"
     text = text.replace(".0\n", "\n")
     for name, value in (("resident_bytes", resident), ("experts_held", 64),
                         ("experts_routed", 64)):
