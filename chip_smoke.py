@@ -910,7 +910,9 @@ def phase_kernels(interpret: bool = False, *,
 # grouped kernels' VMEM (one expert's gate and up are 63 MB), so its
 # products are XLA's; ``lfm2``'s attention is einsums and picks nothing,
 # and its expert kernels say how they are fed (the ring's slots of
-# ``gate_up`` / ``down``, the rows brought in by ``gate_up`` itself).
+# ``gate_up`` / ``down``, the rows brought in by ``gate_up`` itself);
+# ``falconh1`` has no expert layer, and its state-space core says which form
+# it runs (the dual form over the one chunk a window is).
 BACKBONES = {
     "pangu": ("risk-seqhead-openpangu-ultra-moe-718b", "openpangu_ultra",
               "pangu_backbone", "PANGU_CONFIG",
@@ -920,9 +922,12 @@ BACKBONES = {
              "lfm2_backbone", "LFM2_CONFIG",
              {"expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
                              "rows=in-kernel)", "way_back": "pallas-rows"}),
+    "falconh1": ("risk-seqhead-falcon-h1-34b", "falcon_h1_34b",
+                 "falconh1_backbone", "FALCONH1_CONFIG",
+                 {"ssm_core": "dual form, one chunk, 16 <= 128"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
-              "attention_core": "attention core"}
+              "attention_core": "attention core", "ssm_core": "state-space core"}
 
 
 def phase_backbone(*, head_name: str = "pangu", cfg=None,
@@ -938,7 +943,11 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     four gated short convolutions and one grouped-query attention layer,
     a dense MLP and four layers of 64 experts, every one held, 5.13 GB
     (chipbench/heads/lfm2_24b_a2b.py), whose expert layer is the three
-    Pallas kernels at their second shape."""
+    Pallas kernels at their second shape; ``falconh1``: four layers of a
+    Mamba-2 mixer beside grouped-query attention and a dense SwiGLU of
+    21,504, no expert, 3.44 GB (chipbench/heads/falcon_h1_34b.py), whose
+    state-space core runs in its dual form against the reference's
+    recurrence."""
     import gc
 
     import jax
@@ -1073,6 +1082,7 @@ def main() -> int:
     run("kernels", phase_kernels)
     run("backbone", phase_backbone)
     run("backbone_lfm2", phase_backbone, head_name="lfm2")
+    run("backbone_falconh1", phase_backbone, head_name="falconh1")
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

@@ -509,13 +509,17 @@ def backbone_scores(params: Params, window, lengths, cfg: BackboneConfig):
     return score_last(params, hid, lengths)
 
 
-def score_last(params: Params, hid, lengths):
+def score_last(params: Params, hid, lengths, logit_scale=None):
     """The scoring head on final-normed hidden states ``hid`` [B, T,
     hidden]: the sigmoid of one float32 output column at each window's
-    last real position."""
+    last real position. With ``logit_scale`` (a float: the ``falconh1``
+    head's ``lm_head_multiplier``) the column's product is scaled before
+    the bias is added, as that model scales its output head's logits."""
     t = hid.shape[1]
     last = jnp.clip(lengths.astype(jnp.int32) - 1, 0, t - 1)
     hl = jnp.take_along_axis(hid, last[:, None, None], axis=1)[:, 0, :]
     # one output column: a float32 multiply-reduce, never the MXU
-    logit = jnp.sum(hl * params["head"]["w"][:, 0], axis=-1) + params["head"]["b"][0]
-    return jax.nn.sigmoid(logit)
+    logit = jnp.sum(hl * params["head"]["w"][:, 0], axis=-1)
+    if logit_scale is not None:
+        logit = logit * logit_scale
+    return jax.nn.sigmoid(logit + params["head"]["b"][0])

@@ -177,18 +177,21 @@ def init_backbone(key, cfg: Lfm2Config) -> Params:
     }
 
 
-def causal_taps(z, taps):
+def causal_taps(z, taps, bias=None):
     """The depthwise causal convolution over the positions of each window:
     ``z`` [B, T, C] float32, ``taps`` [C, L] -> ``c[b, t] = sum_k taps[:, k]
     * z[b, t - (L - 1 - k)]``, with ``z`` before a window's first position
-    zero. ``L`` shifted products, no product on the MXU."""
+    zero. ``L`` shifted products, no product on the MXU. With ``bias`` [C]
+    (the ``falconh1`` head's convolution has one, models/
+    falconh1_backbone.py) it is added at every position; this head's has
+    none."""
     n_taps = taps.shape[1]
     t = z.shape[1]
     c = z * taps[:, n_taps - 1]
     for back in range(1, n_taps):
         earlier = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
         c = c + earlier * taps[:, n_taps - 1 - back]
-    return c
+    return c if bias is None else c + bias
 
 
 def short_conv(u, layer: Params, cfg: Lfm2Config, window: int):
@@ -207,18 +210,26 @@ def short_conv(u, layer: Params, cfg: Lfm2Config, window: int):
         return _mm(y, layer["w_out"], cfg)
 
 
-def attention(u, layer: Params, cos, sin, cfg: Lfm2Config, window: int):
+def attention(u, layer: Params, cos, sin, cfg, window: int, key_scale=None):
     """Grouped-query attention over normed hidden states ``u`` [P, hidden]
-    -> [P, hidden]: per-head RMSNorm on q and k, one rotary stream on every
-    channel, causal. Its 16-key core runs as two einsums."""
+    -> [P, hidden]: per-head RMSNorm on q and k where the layer holds their
+    gains (``qn``, ``kn``: this head's layers do, the ``falconh1`` head's
+    have no head norm), one rotary stream on every channel, causal. With
+    ``key_scale`` (a float: that head's ``key_multiplier``) the keys are
+    ``(u Wk) * key_scale``, scaled in float32; this head passes none. Its
+    16-key core runs as two einsums."""
     nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
     dt, t = cfg.operand_dtype, window
     b = u.shape[0] // t
     q = _mm(u, layer["wq"], cfg).reshape(b, t, nh, hd)
     k = _mm(u, layer["wk"], cfg).reshape(b, t, nkv, hd)
     v = _mm(u, layer["wv"], cfg).reshape(b, t, nkv, hd)
-    q = rotate(rms_norm(q, layer["qn"], cfg.eps), cos, sin)
-    k = rotate(rms_norm(k, layer["kn"], cfg.eps), cos, sin)
+    if key_scale is not None:
+        k = k * key_scale
+    normed = ((lambda x, gain: rms_norm(x, layer[gain], cfg.eps))
+              if "qn" in layer else (lambda x, gain: x))
+    q = rotate(normed(q, "qn"), cos, sin)
+    k = rotate(normed(k, "kn"), cos, sin)
     # query head j reads key-value head j // (nh // nkv)
     q = q.reshape(b, t, nkv, nh // nkv, hd)
     sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),

@@ -251,10 +251,16 @@ def latent_attention(a, layer: Params, cos, sin, cfg: PanguConfig):
         return _mm(o, layer["wo"], cfg).reshape(b, t, -1)
 
 
-def swiglu(x, w: Params, cfg: PanguConfig):
+def swiglu(x, w: Params, cfg, gate_scale=None):
     """``(silu(x Wg) * x Wu) Wd``; the product between is rounded once to
-    the operands' dtype, as the expert kernels round theirs."""
-    mid = jax.nn.silu(_mm(x, w["wg"], cfg)) * _mm(x, w["wu"], cfg)
+    the operands' dtype, as the expert kernels round theirs. With
+    ``gate_scale`` (a float: the ``falconh1`` head's first MLP multiplier,
+    models/falconh1_backbone.py) the gate is ``silu((x Wg) * gate_scale)``,
+    scaled in float32 before the activation. This head's MLPs have none."""
+    gate = _mm(x, w["wg"], cfg)
+    if gate_scale is not None:
+        gate = gate * gate_scale
+    mid = jax.nn.silu(gate) * _mm(x, w["wu"], cfg)
     return _mm(mid, w["wd"], cfg)
 
 

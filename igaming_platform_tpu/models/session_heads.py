@@ -13,7 +13,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from igaming_platform_tpu.models import lfm2_backbone, pangu_backbone
+from igaming_platform_tpu.models import (
+    falconh1_backbone,
+    lfm2_backbone,
+    pangu_backbone,
+)
 from igaming_platform_tpu.models.keye_backbone import (
     BackboneConfig,
     backbone_scores,
@@ -160,6 +164,28 @@ def lfm2_scores(sparams, window, lengths):
     return lfm2_backbone.backbone_scores(sparams, window, lengths, LFM2_CONFIG)
 
 
+# SESSION_HEAD=falconh1: four layers of a state-space hybrid at its
+# published widths (models/falconh1_backbone.py): in every layer a Mamba-2
+# mixer (32 heads of 128, a state of 256) and grouped-query attention (20 /
+# 4 heads of 128) on one normed input, both added to the stream, then a
+# dense SwiGLU of 21,504; the model's muP multipliers on the branches. No
+# expert layer: 1.72 G parameters, 3.44 GB in bfloat16 beside the state.
+FALCONH1_CONFIG = falconh1_backbone.FalconH1Config()
+
+
+def init_falconh1_params(seed: int = _SESSION_HEAD_SEED):
+    """The pinned seeded tree of the ``falconh1`` head, built on the device
+    in bfloat16, a matrix (or a block of one) at a time."""
+    return falconh1_backbone.init_backbone(jax.random.key(seed), FALCONH1_CONFIG)
+
+
+def falconh1_scores(sparams, window, lengths):
+    """The ``falconh1`` head: the backbone over the window, scored at the
+    last real position."""
+    return falconh1_backbone.backbone_scores(sparams, window, lengths,
+                                             FALCONH1_CONFIG)
+
+
 # SESSION_HEAD name -> (head_fn(sparams, window, lengths), init_params()).
 HEADS = {
     "pattern": (lambda sparams, win, lp: pattern_scores(win, lp),
@@ -168,6 +194,7 @@ HEADS = {
     "keye": (keye_scores, init_keye_params),
     "pangu": (pangu_scores, init_pangu_params),
     "lfm2": (lfm2_scores, init_lfm2_params),
+    "falconh1": (falconh1_scores, init_falconh1_params),
 }
 
 # SESSION_HEAD name -> (routed experts a layer held on this chip, experts
@@ -178,9 +205,11 @@ HEAD_EXPERTS = {
     "lfm2": (LFM2_CONFIG.experts, LFM2_CONFIG.experts),
 }
 
-# What a layer's operator (``conv``, ``attention``) and its feed-forward
-# (``dense``, ``moe``) may be: the kinds ``HEAD_LAYERS`` counts.
-LAYER_KINDS = ("conv", "attention", "dense", "moe")
+# What a layer's operators (``conv``, ``attention``, ``ssm``) and its
+# feed-forward (``dense``, ``moe``) may be: the kinds ``HEAD_LAYERS`` counts.
+# A layer that runs two operators (``falconh1``: ``ssm`` beside
+# ``attention``) counts under both.
+LAYER_KINDS = ("conv", "attention", "ssm", "dense", "moe")
 
 # SESSION_HEAD name -> layers of each kind in its stack (a kind that is
 # left out has none; the ``pattern`` head has no layer at all).
@@ -193,6 +222,7 @@ HEAD_LAYERS = {
               "dense": PANGU_CONFIG.dense_layers,
               "moe": PANGU_CONFIG.layers - PANGU_CONFIG.dense_layers},
     "lfm2": lfm2_backbone.layer_kinds(LFM2_CONFIG),
+    "falconh1": falconh1_backbone.layer_kinds(FALCONH1_CONFIG),
 }
 
 

@@ -349,6 +349,48 @@ def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
                 if " convolution(" in line and "head/conv/taps" in line]
 
 
+def test_state_space_step_fits_beside_the_state_and_holds_no_state(
+        topo, tpu_backend, capsys):
+    """The fused step with the ``falconh1`` backbone in it, at the cell's
+    size (5,242,880 accounts, one 256-row rung): in place on the ring, its
+    arguments are the state plus 3.44 GB of weights. Every layer has both
+    mixers and the MLP under the scopes the trace reads them by; the
+    state-space core is the dual form over one chunk, so no array of a
+    state's size (32 x 128 x 256 a window) is in the module, and the
+    convolution's taps are no ``convolution``. There is no expert layer:
+    no Pallas kernel and no grouped product. Code size and temporaries are
+    printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.session_heads import FALCONH1_CONFIG as cfg
+    from igaming_platform_tpu.serve import session_state as ss
+
+    capacity = 5_242_880
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step("falconh1", capacity, capacity + 1, one, one)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nfalconh1 step for a described v5e: code "
+              f"{mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 8.1e9 < mem.argument_size_in_bytes < 8.3e9, mem
+    assert mem.temp_size_in_bytes <= 2 * 2**30, mem
+    text = compiled.as_text()
+    for scope in ("head/embed", "head/ssm/in", "head/ssm/conv", "head/ssm/scan",
+                  "head/ssm/gate", "head/ssm/out", "head/attn",
+                  "head/mlp/dense", "head/score"):
+        assert scope in text, scope
+    assert "tpu_custom_call" not in text and "%ragged-dot" not in text
+    assert not [line for line in text.splitlines() if " convolution(" in line
+                and "head/ssm/conv" in line]
+    state = f"{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]"
+    assert "32,128,256]" == state and state not in text
+
+
 @pytest.mark.parametrize("head,sketch,sha256", [
     ("pattern", False, "c51b7511f2159529"), ("pattern", True, "154ee60b366c28a6"),
     ("transformer", False, "7037c7eb0e6f4958"),

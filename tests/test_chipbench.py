@@ -20,6 +20,7 @@ from chipbench.conftest import add_sources
 from chipbench.tests import test_span_metrics as _span_metrics
 from chipbench.tests.conftest import copy as _bare_copy
 from chipbench.tests.test_bounds import *  # noqa: F401,F403
+from chipbench.tests.test_falconh1_cell import *  # noqa: F401,F403
 from chipbench.tests.test_lfm2_cell import *  # noqa: F401,F403
 from chipbench.tests.test_manifest import *  # noqa: F401,F403
 from chipbench.tests.test_reference import *  # noqa: F401,F403

@@ -611,7 +611,8 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     c = session_heads.LFM2_CONFIG
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (
         c.experts, c.experts) == (64, 64)
-    assert snap["head_layers"] == {"conv": 4, "attention": 1, "dense": 1, "moe": 4}
+    assert snap["head_layers"] == {"conv": 4, "attention": 1, "ssm": 0,
+                                   "dense": 1, "moe": 4}
     # what the step's expert layer said it runs as when it was traced (here
     # the CPU's cores; on a TPU the kernels and how they are fed)
     assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"
@@ -670,8 +671,8 @@ def test_replay_verifies_a_ledger_written_under_the_head(small_lfm2, monkeypatch
 
 
 @pytest.mark.parametrize("name,layers", [
-    ("pattern", {"conv": 0, "attention": 0, "dense": 0, "moe": 0}),
-    ("transformer", {"conv": 0, "attention": 1, "dense": 1, "moe": 0})])
+    ("pattern", {"conv": 0, "attention": 0, "ssm": 0, "dense": 0, "moe": 0}),
+    ("transformer", {"conv": 0, "attention": 1, "ssm": 0, "dense": 1, "moe": 0})])
 def test_layer_gauge_of_the_small_heads(name, layers):
     from igaming_platform_tpu.obs.metrics import ServiceMetrics
     from igaming_platform_tpu.serve.session_state import SessionStateManager
@@ -688,7 +689,8 @@ def test_layer_gauge_of_the_small_heads(name, layers):
     ("pattern", {}), ("transformer", {"attention": 1, "dense": 1}),
     ("keye", {"attention": 4, "moe": 4}),
     ("pangu", {"attention": 5, "dense": 1, "moe": 4}),
-    ("lfm2", {"conv": 4, "attention": 1, "dense": 1, "moe": 4})])
+    ("lfm2", {"conv": 4, "attention": 1, "dense": 1, "moe": 4}),
+    ("falconh1", {"ssm": 4, "attention": 4, "dense": 4})])
 def test_every_head_says_what_its_stack_is_made_of(name, layers):
     assert set(session_heads.HEAD_LAYERS) == set(session_heads.HEADS)
     assert session_heads.HEAD_LAYERS[name] == layers
