@@ -91,7 +91,10 @@ class BatcherConfig:
     # smallest tier >= its row count instead of the full throughput shape,
     # so one transaction never pays an H2D/step/readback sized for
     # ``batch_size`` rows. Tiers >= batch_size are ignored; () disables.
-    latency_tiers: tuple[int, ...] = (256, 2048)
+    # A factor of four apart: a rung is up to four loaded programs of
+    # code on the device (12-21 MB each for a backbone head; the scratch
+    # they run in is shared, as large as the largest asks for).
+    latency_tiers: tuple[int, ...] = (64, 256, 2048)
     # Batches whose padded shape is <= this ride a host-CPU executable of
     # the same score graph instead of the device: trickle traffic gets
     # sub-millisecond scoring with zero host<->device round-trips (the

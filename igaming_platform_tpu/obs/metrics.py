@@ -635,6 +635,13 @@ class ServiceMetrics:
             "Bytes of those host arguments (their nbytes) — what crosses "
             "the link on the way in, per launch",
         )
+        self.launch_padded_rows_total = self.registry.counter(
+            f"{service}_launch_padded_rows_total",
+            "Padded rows of every launch of the index-mode scoring "
+            "program (the ladder rung the chunk ran): over the rows "
+            "acknowledged it is what padding to a rung costs, 1.0 where "
+            "every frame fills its rung",
+        )
         self.host_cpu_steal_seconds_total = self.registry.counter(
             f"{service}_host_cpu_steal_seconds_total",
             "Steal column of /proc/stat's cpu line: seconds the "

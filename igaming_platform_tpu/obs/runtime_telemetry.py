@@ -326,6 +326,12 @@ class RuntimeTelemetry:
             self.metrics.h2d_transfers_total.inc(transfers)
             self.metrics.h2d_bytes_total.inc(nbytes)
 
+    def note_padded_rows(self, rows: int) -> None:
+        """The padded rows one launch of the index-mode program ran (its
+        ladder rung), at the same seam."""
+        if self.metrics is not None:
+            self.metrics.launch_padded_rows_total.inc(rows)
+
     def observe_span(self, span) -> None:
         name = getattr(span, "name", "")
         if name not in _STEP_STAGES:
@@ -516,3 +522,11 @@ def note_h2d(transfers: int, nbytes: int) -> None:
     t = DEFAULT
     if t is not None:
         t.note_h2d(transfers, nbytes)
+
+
+def note_padded_rows(rows: int) -> None:
+    """Launch-seam helper (serve/scorer._launch_cached). No-op without a
+    process-default telemetry."""
+    t = DEFAULT
+    if t is not None:
+        t.note_padded_rows(rows)

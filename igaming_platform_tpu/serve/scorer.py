@@ -1230,7 +1230,8 @@ class TPUScoringEngine:
         what the launch hands over the link: every host (numpy) leaf of
         ``args`` is its own host-to-device transfer. Which leaves are host
         arrays is a property of the program and its padded shape, so it is
-        reckoned once per (label, shape) and added per launch."""
+        reckoned once per (label, shape) and added per launch, beside the
+        padded rows themselves (the rung the chunk ran)."""
         from igaming_platform_tpu.obs import runtime_telemetry as _rt
 
         _device_dispatch(label, idxsp.shape, idxsp.dtype)
@@ -1242,6 +1243,7 @@ class TPUScoringEngine:
             cost = self._h2d_cost[key] = (
                 len(host), sum(int(a.nbytes) for a in host))
         _rt.note_h2d(*cost)
+        _rt.note_padded_rows(idxsp.shape[0])
 
     def _blacklist_flags(self, n: int, ips, devices, fingerprints) -> np.ndarray:
         """Per-request blacklist vector from the host sets — the cheap

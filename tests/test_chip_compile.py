@@ -65,11 +65,12 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _step_args(head_params, capacity, ring_rows, shards, state, repl):
-    """Abstract arguments of the fused step (sketch variant), the ring
-    state under ``state`` and everything else under ``repl``;
-    ``head_params`` is the head's init, traced for its shapes and never
-    run (the ``keye`` tree is 5 GB)."""
+def _step_args(head_params, capacity, ring_rows, shards, state, repl,
+               batch=BATCH):
+    """Abstract arguments of the fused step (sketch variant) at the ladder
+    rung of ``batch`` rows, the ring state under ``state`` and everything
+    else under ``repl``; ``head_params`` is the head's init, traced for
+    its shapes and never run (the ``keye`` tree is 5 GB)."""
     import jax
 
     from igaming_platform_tpu.core.features import NUM_FEATURES
@@ -84,7 +85,7 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl):
     sparams = None if head_params is None else abstract(head_params)
     n = ss.default_events()
     f32, i32 = np.float32, np.int32
-    b = BATCH
+    b = batch
     return (
         params, sparams,
         _spec((capacity, NUM_FEATURES), f32, state),      # table
@@ -100,7 +101,7 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl):
 
 
 def _lower_step(head, capacity, ring_rows, state, repl, *, mesh=None,
-                plan=None, sketch=True):
+                plan=None, sketch=True, batch=BATCH):
     """The fused session step the server runs, from the builder the
     server builds it with (serve/index_program.build; ``jit(_body)``, ring
     donated; the drift-sketch variant unless told otherwise), lowered."""
@@ -120,7 +121,7 @@ def _lower_step(head, capacity, ring_rows, state, repl, *, mesh=None,
             head_fn, capacity, ss.default_events(), ss.default_min_events(),
             ss.default_flag_threshold()))
     args = _step_args(head_params, capacity, ring_rows,
-                      1 if plan is None else plan.n_shards, state, repl)
+                      1 if plan is None else plan.n_shards, state, repl, batch)
     return step.lower(*args)
 
 
@@ -389,6 +390,57 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
                 and "head/ssm/conv" in line]
     state = f"{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]"
     assert "32,128,256]" == state and state not in text
+
+
+@pytest.mark.parametrize("head,capacity,temps_256,in_tree", [
+    ("pangu", 3_145_728, 955_600_896,
+     {"_window_attention": 5, "_combine_held": 4, "ragged-dot-none": 12}),
+    ("falconh1", 5_242_880, 631_744_000, {}),
+    ("keye", 5_242_880, 394_860_544,
+     {"_gate_up": 4, "_down": 4, "_combine_rows": 4}),
+    ("lfm2", 5_242_880, 308_153_856,
+     {"_gate_up": 4, "_down": 4, "_combine_rows": 4})])
+def test_the_64_row_rung_compiles_beside_the_256_one(
+        topo, tpu_backend, capsys, head, capacity, temps_256, in_tree):
+    """The ladder's 64-row rung of each backbone's step (serve/scorer.py:
+    a 64-row frame no longer runs the 256-row program), at its cell's
+    size: in place on the ring, the same kernel calls as the 256-row step
+    (every kernel's ``supports`` takes the quarter shape, so no fallback
+    path is in play), and its code and temporaries printed. The
+    temporaries are held under a quarter of the 256-row step's
+    (``temps_256``: what the tests above print): the chip keeps one
+    scratch region for all loaded programs, as large as the largest asks
+    for, so a rung whose temporaries fit inside the 256-row step's adds
+    nothing to ``bytes_reserved`` and costs its programs' code alone,
+    which is why the default ladder holds the rung in every cell. Read
+    here (PR 46): 211,406,336 B ``pangu``, 142,620,160 ``falconh1``,
+    20,904,960 ``keye``, 56,169,472 ``lfm2``; code 12.1-19.4 MB."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.serve import session_state as ss
+
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step(head, capacity, capacity + 1, one, one, batch=64)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n{head} 64-row step for a described v5e: code "
+              f"{mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B (a quarter of the 256-row "
+              f"step's: {temps_256 // 4} B)")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert mem.temp_size_in_bytes <= temps_256 // 4, mem
+    # the in-tree kernels' calls carry `pallas_call` in their op_name;
+    # XLA's grouped product is a Mosaic call of its own (`ragged-dot-none`)
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line
+             and ("pallas_call" in line or "%ragged-dot-none" in line)]
+    found = {}
+    for line in calls:
+        name = re.match(r"\s*%([\w-]+?)(\.\d+)? = ", line).group(1)
+        found[name] = found.get(name, 0) + 1
+    assert found == in_tree, found
 
 
 @pytest.mark.parametrize("head,sketch,sha256", [

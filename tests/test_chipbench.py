@@ -2,7 +2,8 @@
 under the tier-1 command so that the harness, every file a cell brings
 and the A/A data are guarded by the run the driver makes (PERF.md Open
 question 9). Thin on purpose: it holds one case of its own, beside the one
-case of that directory that this tree can no longer pass (``LAST_EIGHT``).
+case of that directory that this tree can no longer pass (``LAST_EIGHT``),
+and since PR 46 a second pair of the same kind (``LAST_NINE``).
 
 Every test module of that directory is imported here under its own
 names, so each case counts as one. Their fixtures come with them; the
@@ -17,6 +18,7 @@ import os
 import pytest
 
 from chipbench.conftest import add_sources
+from chipbench.tests import test_falconh1_cell as _falconh1
 from chipbench.tests import test_span_metrics as _span_metrics
 from chipbench.tests.conftest import copy as _bare_copy
 from chipbench.tests.test_bounds import *  # noqa: F401,F403
@@ -51,7 +53,8 @@ def test_the_manifest_lists_them_last_and_validates():  # noqa: F811
 def test_the_manifest_keeps_the_span_metrics_together_and_validates():
     """What the case above held that still holds: the eight follow everything
     the benchmark had before them, together and without a ``workloads`` key,
-    whatever came later names its cells, and every cell reads the eight."""
+    whatever came later names its cells (but for the one metric since that
+    every cell reads too), and every cell reads the eight."""
     from chipbench import run, validate
 
     expected = _span_metrics.EXPECTED
@@ -67,10 +70,42 @@ def test_the_manifest_keeps_the_span_metrics_together_and_validates():
         entry = by_name[name]
         assert (entry["unit"], entry["layer"]) == (unit, layer)
         assert "workloads" not in entry
-    assert all("workloads" in by_name[n] for n in names[first + len(expected):])
+    # PR 46's counter is read wherever the index program is launched
+    assert [n for n in names[first + len(expected):]
+            if "workloads" not in by_name[n]] == ["padded_rows_per_row"]
     for cell in manifest["workloads"]:
         got = {m["name"] for m in validate.load_cell(cell["name"])["per_layer"]}
         assert set(expected) <= got, cell["name"]
+
+
+LAST_NINE = (
+    "chipbench/tests/test_falconh1_cell.py holds PR 45's nine metrics to the "
+    "LAST nine places of per_layer; PR 46's padded_rows_per_row follows them, "
+    "for the reason LAST_EIGHT gives. A benchmark PR repairs the case: "
+    "PERF.md Open question 9")
+
+
+@pytest.mark.xfail(strict=True, raises=AssertionError, reason=LAST_NINE)
+def test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut():  # noqa: F811
+    """That file's case of this name, run as it stands and expected to fail
+    on its last assertion alone; strict, as above."""
+    _falconh1.test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut()
+
+
+def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypatch):
+    """The case above, every assertion as it stands, over the manifest cut
+    off after the nine metrics it expects last: what follows them is what
+    later PRs appended, named here."""
+    from chipbench import validate
+
+    manifest = validate.load_manifest()
+    names = [m["name"] for m in manifest["per_layer"]]
+    last = max(names.index(name) for name in _falconh1.METRICS)
+    assert names[last + 1:] == ["padded_rows_per_row"]  # PR 46
+    cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1])
+    monkeypatch.setattr(_falconh1.validate, "load_manifest",
+                        lambda *args, **kwargs: cut)
+    _falconh1.test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut()
 
 
 @pytest.fixture

@@ -3,6 +3,7 @@
 import time
 
 import numpy as np
+import pytest
 
 from igaming_platform_tpu.core.config import BatcherConfig, ScoringConfig
 from igaming_platform_tpu.core.enums import ReasonCode
@@ -313,6 +314,31 @@ def test_latency_tiers_disabled_and_oversize():
     try:
         assert engine._shapes == [128]
         assert engine._pick_shape(1) == 128
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("batch_size,ladder", [
+    (256, [64, 256]), (8192, [64, 256, 2048, 8192]), (1024, [64, 256, 1024]),
+    (64, [64])])
+def test_default_ladder_has_a_64_row_rung_under_256(batch_size, ladder):
+    """The default tiers are 64 / 256 / 2048, a factor of four apart: a
+    64-row frame no longer pads to 256 rows, whatever ``BATCH_SIZE``."""
+    from igaming_platform_tpu.core.config import BatcherConfig, ScoringConfig
+    from igaming_platform_tpu.serve.scorer import TPUScoringEngine
+
+    assert BatcherConfig().latency_tiers == (64, 256, 2048)
+    engine = TPUScoringEngine(
+        ScoringConfig(),
+        batcher_config=BatcherConfig(batch_size=batch_size, max_wait_ms=1.0),
+        warmup=False,
+    )
+    try:
+        assert engine._shapes == ladder
+        assert engine._batcher._shapes == tuple(ladder)
+        assert engine._pick_shape(1) == engine._pick_shape(64) == 64
+        assert engine._pick_shape(65) == ladder[min(1, len(ladder) - 1)]
+        assert engine._pick_shape(batch_size) == batch_size
     finally:
         engine.close()
 
