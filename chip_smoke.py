@@ -27,6 +27,7 @@ the working directory.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -699,7 +700,8 @@ def phase_kernels(interpret: bool = False, *,
                   expert_shape: tuple = (32768, 2048, 768, 128),
                   top_k: int = 8,
                   second_shape: tuple = (16384, 2048, 1536, 64, 4),
-                  share_shape: tuple = (2048, 7680, 4096, 1000)) -> dict:
+                  share_shape: tuple = (2048, 7680, 4096, 1000),
+                  grouped_windows: int = 32) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -711,7 +713,11 @@ def phase_kernels(interpret: bool = False, *,
     (``combine``) at both backbones' shapes: every slot of the ``keye``
     head's rows / ``top_k`` positions, and a pass of the ``pangu`` head's
     share (rows, hidden, positions, slots taken) — each against its XLA
-    reference, with the way back a trace would pick there."""
+    reference, with the way back a trace would pick there; and the window
+    kernel's grouped-query form at the ``keye`` head's attention (32 query
+    / 4 key heads of 128, a random mask with the diagonal kept) on
+    ``grouped_windows`` windows of 16 against the einsum core, with the
+    core a trace would pick there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -895,6 +901,37 @@ def phase_kernels(interpret: bool = False, *,
         True, mode="drop")
     way_back(f"combine_share_M{rows}_H{hidden}",
              jnp.where(owed[:, None], ys, jnp.nan), at, weights, take, want)
+
+    from igaming_platform_tpu.ops.pallas import window_attention as wa
+
+    cfg, t = keye_backbone.BackboneConfig(), 16
+    n = grouped_windows * t
+    ks = jax.random.split(jax.random.key(n), 5)
+    q = jax.random.normal(ks[0], (n, cfg.heads * cfg.head_dim), jnp.float32) * 3
+    k, v = (jax.random.normal(key, (n, cfg.kv_heads * cfg.head_dim),
+                              jnp.float32).astype(jnp.bfloat16) for key in ks[1:3])
+    cos, sin = (a.reshape(n, -1) for a in keye_backbone.mrope_angles(
+        jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (3, grouped_windows, t)),
+        cfg.head_dim, cfg.mrope_section, cfg.rope_theta))
+    gain = 1 + 0.1 * jax.random.normal(ks[3], (cfg.head_dim,), jnp.float32)
+    keep = ((jax.random.bernoulli(ks[4], 0.5, (grouped_windows, t, t))
+             | jnp.eye(t, dtype=bool)) & jnp.tril(jnp.ones((t, t), bool))
+            ).reshape(n, t)
+    widths = dict(heads=cfg.heads, kv_heads=cfg.kv_heads, window=t, eps=cfg.eps)
+    picked = _said_by_the_expert_layer(
+        lambda: keye_backbone._attention_core(n, keep, cfg, t))
+    # q and v channel-major, as ``wq^T a^T`` and ``wv^T a^T`` leave them
+    got = wa.grouped_window_attention(q.T, k, v.T, cos, sin, gain, keep,
+                                      **widths, interpret=interpret).T
+    want = jax.jit(functools.partial(keye_backbone._core_by_einsums, **widths))(
+        q, k, v, cos, sin, gain, keep)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                / jnp.max(jnp.abs(want)))
+    report[f"grouped_attention_W{grouped_windows}"] = {"max_err": err,
+                                                       "core": picked[0]}
+    check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
+          f"grouped attention on {grouped_windows} windows: max err {err} "
+          f"> {BACKBONE_TOL}")
     return report
 
 

@@ -8,14 +8,24 @@ heads of 128, 128 experts of width 768 with 8 a token, an indexer of 16
 heads of 64 with one key head and ``topk`` 2048. Events enter it the way
 image patches enter that model, as ``inputs_embeds`` from a projector
 (``x @ W_in``, 12 -> hidden); the score is a sequence-classification
-head on the last real position. Each layer, over ``h`` [B, T, hidden]:
+head on the last real position. Each layer, over the residual stream
+``h`` [P, hidden], position-major with ``P = B x T`` from the projector to
+the final norm (every product reads and writes it so):
 
 1. ``a = RMSNorm(h)``; grouped-query attention with per-head RMSNorm on
    q and k and M-RoPE (three position-id streams, ``mrope_section``
    frequency pairs each); causal.
 2. The indexer scores every causal key for every query (``sum_h w[t,h]
    * relu(qi[t,h] . ki[s]) / sqrt(d)``) and query ``t`` attends only to
-   its ``min(topk, t + 1)`` best keys.
+   its ``min(topk, t + 1)`` best keys. On a TPU, where
+   ``ops/pallas/window_attention``'s grouped-query form takes the layer
+   (windows that divide 128, heads of whole 128-lane vregs: the published
+   widths do), the core (q's head norm and rotary, scores, that mask,
+   softmax, ``p v``) is one Pallas kernel on ``wq``'s and ``wv``'s results
+   as the products wrote them; elsewhere two einsums over ``[b, t, h,
+   d]``, which are its reference and what the CPU tests and replay run.
+   Chosen while tracing and announced once (``attention core: ...``, with
+   the kernel's reason where it declines).
 3. ``b = RMSNorm(h)``; a router over ALL experts, softmax in float32,
    the ``top_k`` largest renormalised; every (position, expert) pair is
    computed — the layer is DROPLESS: pairs are sorted by expert and the
@@ -38,7 +48,8 @@ accumulates in float32; residual stream, norms, softmax, router and
 indexer scores, top-k and the logit are float32.
 
 ``jax.named_scope`` marks the parts (``head/embed``, ``head/attn`` with
-``head/attn/indexer`` inside, ``head/moe/route``, ``head/moe/experts``)
+``head/attn/indexer`` and ``head/attn/core`` inside, ``head/moe/route``,
+``head/moe/experts``)
 so that a device trace can be read by part.
 """
 
@@ -176,6 +187,16 @@ def _mm(x, w, cfg: BackboneConfig):
         preferred_element_type=jnp.float32)
 
 
+def _mm_t(w, x, cfg: BackboneConfig):
+    """``(x @ w)^T`` as the product ``w^T x^T``, [out, P] channel-major:
+    the same operands and the same float32 sums as ``_mm``, the result
+    written positions along the lanes."""
+    dt = cfg.operand_dtype
+    return jax.lax.dot_general(w.astype(dt), x.astype(dt),
+                               (((0,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def rms_norm(x, gain, eps: float):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
@@ -212,21 +233,25 @@ def rotate(x, cos, sin):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
 
 
-def indexer_keep(a, layer: Params, cos, sin, cfg: BackboneConfig):
-    """The learned-sparse selection: [B, T, T] bool, ``keep[b, t, s]`` iff
-    key ``s`` is causal for query ``t`` and among its ``min(idx_topk,
-    t + 1)`` best by the indexer's score. Follows DeepSeek-V3.2's
-    indexer where the source's config is silent: LayerNorm on the one
-    key head, rotary on the first half of its channels (by the temporal
-    id), head weights scaled by ``idx_heads ** -0.5``."""
-    b, t, _ = a.shape
+def indexer_keep(a, layer: Params, cos, sin, cfg: BackboneConfig, window: int):
+    """The learned-sparse selection over normed hidden states ``a`` [P,
+    hidden] in windows of ``window`` positions (``cos``, ``sin`` [P, half]):
+    [P, window] bool, ``keep[b * window + t, s]`` iff key ``s`` of window
+    ``b`` is causal for its query ``t`` and among that query's
+    ``min(idx_topk, t + 1)`` best by the indexer's score. Follows
+    DeepSeek-V3.2's indexer where the source's config is silent: LayerNorm
+    on the one key head, rotary on the first half of its channels (by the
+    temporal id), head weights scaled by ``idx_heads ** -0.5``."""
+    t = window
+    b = a.shape[0] // t
     nh, dh = cfg.idx_heads, cfg.idx_dim
     rot = dh // 4  # rotary pairs: half of the channels turn
+    cos, sin = (x.reshape(b, t, -1)[..., :rot] for x in (cos, sin))
     qi = _mm(a, layer["wqi"], cfg).reshape(b, t, nh, dh)
     ki = layer_norm(_mm(a, layer["wki"], cfg), layer["kin"], cfg.eps)
-    qi = rotate(qi, cos[..., :rot], sin[..., :rot])
-    ki = rotate(ki[:, :, None, :], cos[..., :rot], sin[..., :rot])[:, :, 0, :]
-    w = _mm(a, layer["ww"], cfg) * (nh ** -0.5)
+    qi = rotate(qi, cos, sin)
+    ki = rotate(ki.reshape(b, t, 1, dh), cos, sin)[:, :, 0, :]
+    w = _mm(a, layer["ww"], cfg).reshape(b, t, nh) * (nh ** -0.5)
     dt = cfg.operand_dtype
     dots = jnp.einsum("bthd,bsd->bths", qi.astype(dt), ki.astype(dt),
                       preferred_element_type=jnp.float32)
@@ -238,30 +263,96 @@ def indexer_keep(a, layer: Params, cos, sin, cfg: BackboneConfig):
     k = min(cfg.idx_topk, t)
     _, best = jax.lax.top_k(score, k)                         # [B, T, k]
     chosen = jnp.any(best[..., None] == jnp.arange(t), axis=-2)
-    return jnp.logical_and(chosen, causal)
+    return jnp.logical_and(chosen, causal).reshape(b * t, t)
 
 
-def attention(h, layer: Params, cos, sin, cfg: BackboneConfig):
-    b, t, _ = h.shape
+def _core_by_einsums(q, k, v, cos, sin, gain, keep, *, heads: int,
+                     kv_heads: int, window: int, eps: float):
+    """The core of attention as two einsums over ``[b, t, h, d]``: the
+    reference of the kernel's grouped form (ops/pallas/window_attention.
+    grouped_window_attention, which takes ``q`` and ``v`` channel-major),
+    and what runs off the TPU. ``q`` [P, heads x hd] float32 as ``wq`` left
+    it (its head norm and rotary happen here), ``k`` and ``v`` [P, kv_heads
+    x hd] ready and rounded, ``keep`` [P, window] -> float32 [P, heads x
+    hd], which ``wo``'s product rounds."""
+    dt, t = k.dtype, window
+    b, hd = q.shape[0] // t, q.shape[1] // heads
+    cos, sin = cos.reshape(b, t, -1), sin.reshape(b, t, -1)
+    q = rotate(rms_norm(q.reshape(b, t, heads, hd), gain, eps), cos, sin)
+    # query head j reads key-value head j // (heads // kv_heads)
+    q = q.reshape(b, t, kv_heads, heads // kv_heads, hd)
+    sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt),
+                    k.reshape(b, t, kv_heads, hd),
+                    preferred_element_type=jnp.float32) * (hd ** -0.5)
+    sc = jnp.where(keep.reshape(b, 1, 1, t, t), sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1)
+    o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt),
+                   v.reshape(b, t, kv_heads, hd),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b * t, heads * hd)
+
+
+def _attention_core(positions: int, keep, cfg: BackboneConfig, window: int) -> bool:
+    """Whether the core of attention over ``positions`` positions in
+    windows of ``window`` runs as the Pallas kernel's grouped-query form
+    (ops/pallas/window_attention.py: on a TPU, where it takes the
+    operands' shapes) or as ``_core_by_einsums``. Picked while tracing,
+    from backend and shapes, and announced once a compile, with the
+    kernel's reason where it declines."""
+    from igaming_platform_tpu.ops.pallas import window_attention as kernel
+
+    backend = jax.default_backend()
+    nh, nkv, hd, dt = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.operand_dtype
+    why = "not a TPU" if backend != "tpu" else kernel.grouped_declines(
+        jax.ShapeDtypeStruct((nh * hd, positions), jnp.float32),
+        jax.ShapeDtypeStruct((positions, nkv * hd), dt),
+        jax.ShapeDtypeStruct((nkv * hd, positions), dt),
+        heads=nh, kv_heads=nkv, window=window, keep=keep)
+    _announce_core(
+        f"einsum ({why})" if why else
+        f"pallas-windows (grouped {nh}/{nkv} of {hd}, window {window}, mask=keep)",
+        backend, "attention core")
+    return not why
+
+
+def attention(h, layer: Params, cos, sin, cfg: BackboneConfig, window: int):
+    """The attention sublayer over the residual stream ``h`` [P, hidden] in
+    windows of ``window`` positions (``cos``, ``sin`` [P, head_dim / 2]) ->
+    [P, hidden]. The core (the query's head norm and rotary, scores, the
+    indexer's mask, softmax, ``p v``) is one Pallas kernel where
+    ``_attention_core`` finds that it takes the layer: ``wq`` and ``wv``
+    then leave their results channel-major ([channels, P]: ``w^T a^T``,
+    the same products), the kernel reads them where they were written and
+    writes ``wo``'s operand as ``wo`` contracts it, and nothing of ``[b,
+    heads, t, s]`` and no copy of ``q`` reaches HBM. Elsewhere it is two
+    einsums over ``[b, t, h, d]`` on position-major products: the same
+    arithmetic at the same precision either way."""
+    from igaming_platform_tpu.ops.pallas import window_attention as kernel
+
+    p = h.shape[0]
     nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
     dt = cfg.operand_dtype
     a = rms_norm(h, layer["g1"], cfg.eps)
-    q = _mm(a, layer["wq"], cfg).reshape(b, t, nh, hd)
-    k = _mm(a, layer["wk"], cfg).reshape(b, t, nkv, hd)
-    v = _mm(a, layer["wv"], cfg).reshape(b, t, nkv, hd)
-    q = rotate(rms_norm(q, layer["qn"], cfg.eps), cos, sin)
-    k = rotate(rms_norm(k, layer["kn"], cfg.eps), cos, sin)
+    k = rms_norm(_mm(a, layer["wk"], cfg).reshape(1, p, nkv, hd), layer["kn"],
+                 cfg.eps)
+    k = rotate(k, cos[None], sin[None]).astype(dt).reshape(p, nkv * hd)
     with jax.named_scope("indexer"):
-        keep = indexer_keep(a, layer, cos, sin, cfg)
-    # query head j reads key-value head j // (nh // nkv)
-    q = q.reshape(b, t, nkv, nh // nkv, hd)
-    sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),
-                    preferred_element_type=jnp.float32) * (hd ** -0.5)
-    sc = jnp.where(keep[:, None, None], sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
-                   preferred_element_type=jnp.float32)
-    return _mm(o.reshape(b, t, nh * hd), layer["wo"], cfg)
+        keep = indexer_keep(a, layer, cos, sin, cfg, window)
+    widths = dict(heads=nh, kv_heads=nkv, window=window, eps=cfg.eps)
+    if _attention_core(p, keep, cfg, window):
+        # q as the product leaves it: float32, since the head norm and the
+        # rotary come before the rounding
+        q, v = _mm_t(layer["wq"], a, cfg), _mm_t(layer["wv"], a, cfg).astype(dt)
+        with jax.named_scope("core"):
+            o = kernel.grouped_window_attention(q, k, v, cos, sin, layer["qn"],
+                                                keep, **widths)
+        return jax.lax.dot_general(o, layer["wo"].astype(dt),
+                                   (((0,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+    q, v = _mm(a, layer["wq"], cfg), _mm(a, layer["wv"], cfg).astype(dt)
+    with jax.named_scope("core"):
+        o = _core_by_einsums(q, k, v, cos, sin, layer["qn"], keep, **widths)
+    return _mm(o, layer["wo"], cfg)
 
 
 def route(x, layer: Params, cfg: BackboneConfig):
@@ -279,10 +370,12 @@ _ANNOUNCED: dict[str, str] = {}
 @lru_cache(maxsize=None)
 def _announce_core(core: str, backend: str, part: str = "expert core") -> None:
     """Log, once per (part, core, backend), which core runs a part of the
-    expert layer (``expert core``: its grouped products, and where they
-    are the kernels how those are fed; ``combine``: the results' way back
-    to position order): the choice is made at trace time and is otherwise
-    invisible. ``announced_cores`` keeps the last word of each part."""
+    head (``expert core``: the expert layer's grouped products, and where
+    they are the kernels how those are fed; ``combine``: the results' way
+    back to position order; ``attention core``: the window kernel or the
+    einsums, with the kernel's reason where it declines): the choice is
+    made at trace time and is otherwise invisible. ``announced_cores``
+    keeps the last word of each part."""
     _ANNOUNCED[part] = f"{core} (backend={backend})"
     logger.info("%s: %s (backend=%s)", part, core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
 
@@ -482,19 +575,20 @@ def backbone_hidden(params: Params, x, pos3, cfg: BackboneConfig):
     hidden states [B, T, hidden] (float32)."""
     b, t, _ = x.shape
     with jax.named_scope("head/embed"):
-        h = _mm(x, params["embed"], cfg)
-        cos, sin = mrope_angles(pos3, cfg.head_dim, cfg.mrope_section,
-                                cfg.rope_theta)
+        # the residual stream position-major, [P, hidden] with P = B x T,
+        # from here to the final norm: every product reads and writes it so
+        h = _mm(x.reshape(b * t, -1), params["embed"], cfg)
+        cos, sin = (a.reshape(b * t, -1) for a in mrope_angles(
+            pos3, cfg.head_dim, cfg.mrope_section, cfg.rope_theta))
     for layer in params["layers"]:
         with jax.named_scope("head/attn"):
-            h = h + attention(h, layer, cos, sin, cfg)
-        flat = rms_norm(h, layer["g2"], cfg.eps).reshape(b * t, -1)
+            h = h + attention(h, layer, cos, sin, cfg, t)
+        flat = rms_norm(h, layer["g2"], cfg.eps)
         with jax.named_scope("head/moe/route"):
             top_e, top_w = route(flat, layer, cfg)
         with jax.named_scope("head/moe/experts"):
-            y = grouped_experts(flat, top_e, top_w, layer, cfg)
-        h = h + y.reshape(b, t, -1)
-    return rms_norm(h, params["gf"], cfg.eps)
+            h = h + grouped_experts(flat, top_e, top_w, layer, cfg)
+    return rms_norm(h, params["gf"], cfg.eps).reshape(b, t, -1)
 
 
 def backbone_scores(params: Params, window, lengths, cfg: BackboneConfig):

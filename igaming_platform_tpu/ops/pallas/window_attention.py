@@ -61,6 +61,15 @@ lanes, 128 - T of them exact zeros, against T) may differ.
 A value that is not finite does not stay in its window as it does in the
 einsum form: ``0 x NaN`` is ``NaN``, so a NaN in one window's values
 reaches the windows that share its tile.
+
+**Two forms, picked by the operands a caller has.** The latent form above
+(``window_attention``, ``supports``: a shared rotary key and per-head
+``[k_nope | v]``) and, further down, the grouped-query form
+(``grouped_window_attention``, ``grouped_declines``: shared key heads, the
+head norm and a rotary over the whole head inside, an optional second mask
+``keep``), which ``models/keye_backbone.attention`` calls. One tile, one
+window rule, the same order of roundings; the grouped form's header says
+where its layout differs and why.
 """
 
 from __future__ import annotations
@@ -80,6 +89,11 @@ _LANES = 128
 # Heads a grid step takes at most. Measured on a v5e at the cell's shapes
 # (PERF.md, section 6, PR 39).
 _HEADS_PER_STEP = 16
+
+# Query heads of one key head that a turn of the grouped form's loop takes
+# side by side (their chains are independent: the scheduler overlaps one
+# head's products with another's arithmetic).
+_HEADS_PER_TURN = 4
 
 # What the kernel may ask of the v5e's 128 MiB of VMEM.
 _VMEM_CAP = 64 * 2**20
@@ -256,3 +270,235 @@ def window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
                             rope=rope, dv=dv, window=window, group=group,
                             interpret=interpret)
     return out[:p] if pad else out
+
+
+# --- the grouped-query form: shared key heads, rotary over the whole head ---
+#
+# ``models/keye_backbone.attention``: 32 query heads of 128 over 4 key heads,
+# a head norm on q, M-RoPE over the whole head, and the indexer's mask. The
+# same tile (128 positions, eight 16-position windows a grid step), the same
+# order of roundings as the caller's einsums; what differs from the latent
+# form is how a head finds its keys, and which way its operands lie.
+#
+# **Channel-major.** ``q``, ``v`` and the result are [channels, P], positions
+# along the lanes, as ``wq^T a^T`` writes them and as ``wo`` contracts them
+# (XLA's products cost the same either way: PERF.md, PR 47). Down a head's
+# 128 rows the head norm's mean is a sum of vregs, rotate-half's two halves
+# are the upper and lower 64 rows, and a query's scores run down a lane: the
+# softmax's max and sum are vreg against vreg on the VPU, and nothing in the
+# kernel crosses lanes. (Position-major, with the reductions along lanes, the
+# same kernel read 0.66 ms a layer against the einsums' 0.73.)
+#
+# **A window's own rows.** ``k x`` gives every key of the tile (rows) against
+# every query of it (lanes); query lane ``p`` needs only the 16 rows of its
+# own window. Those are picked out by lane (window ``w``'s rows where the
+# lane is in window ``w``) into [window, 128]: two vregs a head in place of
+# sixteen, so scale, mask, ``exp`` and the division run on an eighth of the
+# tile and every lane of them is used. The probabilities go back over the
+# tile's keys with exact zeros outside the window for ``v p``. The mask rule
+# is the latent form's (*same window and key <= query*) in this shape: a
+# row is a key of the query's own window, kept where ``key <= query %
+# window`` and where the caller's ``keep`` has it.
+#
+# **Four heads a turn.** A head is a chain (norm, rotary, product, softmax,
+# product) whose two products each load a 128 x 128 operand of its own into
+# an MXU for 128 rows: one head a loop turn left three MXUs idle (0.43 ms a
+# layer); four independent heads side by side in one turn overlap (0.22).
+# The loops over key heads and turns stay rolled.
+
+
+def _kv_heads_per_step(heads: int, kv_heads: int) -> int:
+    """Key heads a grid step takes: the most that divide ``kv_heads`` and
+    bring at most ``_HEADS_PER_STEP`` query heads with them (one where a
+    single key head's queries are already more)."""
+    rep = heads // kv_heads
+    return max([g for g in range(1, kv_heads + 1)
+                if kv_heads % g == 0 and g * rep <= _HEADS_PER_STEP] or [1])
+
+
+def _grouped_vmem(group: int, rep: int, hd: int, q_size: int, size: int) -> int:
+    """Both buffers of a step's blocks (``group`` key heads with their
+    queries and results, the angles, the gain, the mask), a turn's heads'
+    float32 values and scores several times over, and room to spare."""
+    blocks = _TILE * (group * hd * (rep * (q_size + size) + 2 * size)
+                      + 2 * hd * 4 + _TILE * 4)
+    turn = _HEADS_PER_TURN * (6 * _TILE * hd * 4 + 8 * _TILE * _TILE * 4)
+    return 2 * blocks + turn + 4 * 2**20
+
+
+def grouped_declines(q, k, v, *, heads: int, kv_heads: int, window: int,
+                     keep=None) -> str:
+    """Why ``grouped_window_attention`` does not take these operands, ""
+    where it does: the grouped form's ``supports``, with the reason the
+    caller announces beside ``einsum``. ``q`` [heads x hd, P], ``k`` [P,
+    kv_heads x hd], ``v`` [kv_heads x hd, P], ``keep`` [P, window] or None
+    (arrays or their shapes-and-dtypes). It takes whole windows of whole
+    8-row vregs to a tile, heads of whole 128-lane vregs, every key head
+    shared by as many query heads, bfloat16 or float32 operands, a step's
+    blocks inside VMEM; anything else takes the caller's einsums."""
+    if window <= 0 or _TILE % window or window % 8:
+        return (f"windows of {window} are not whole 8-row vregs that "
+                f"divide a tile of {_TILE}")
+    if kv_heads <= 0 or heads <= 0 or heads % kv_heads:
+        return f"{heads} heads over {kv_heads} key heads"
+    if q.ndim != 2 or q.shape[0] % heads or (q.shape[0] // heads) % _LANES:
+        return f"head width {q.shape[0] / heads:g} is not whole {_LANES}-lane vregs"
+    hd = q.shape[0] // heads
+    p = q.shape[1]
+    if p == 0 or p % window:
+        return f"{p} positions are not whole windows of {window}"
+    if k.shape != (p, kv_heads * hd) or v.shape != (kv_heads * hd, p):
+        return f"k {k.shape}, v {v.shape} against q {q.shape}"
+    if k.dtype not in (jnp.bfloat16, jnp.float32) or v.dtype != k.dtype:
+        return f"operands {k.dtype} / {v.dtype}"
+    if not jnp.issubdtype(q.dtype, jnp.floating):
+        return f"q {q.dtype}"
+    if keep is not None and keep.shape != (p, window):
+        return f"keep {keep.shape} against [{p}, {window}]"
+    group = _kv_heads_per_step(heads, kv_heads)
+    need = _grouped_vmem(group, heads // kv_heads, hd, q.dtype.itemsize,
+                         k.dtype.itemsize)
+    if need > _VMEM_CAP:
+        return f"a step's blocks take {need} of {_VMEM_CAP} bytes of VMEM"
+    return ""
+
+
+def _grouped_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, *rest,
+                    window: int, hd: int, rep: int, eps: float, scale: float):
+    f32 = jnp.float32
+    dt = k_ref.dtype
+    *keep_ref, o_ref = rest  # the caller's mask, where it has one
+    half, windows = hd // 2, _TILE // window
+    turn = max(t for t in range(1, _HEADS_PER_TURN + 1) if rep % t == 0)
+    # a window's scores, keys down the rows and queries along the lanes
+    key = jax.lax.broadcasted_iota(jnp.int32, (window, _TILE), 0)
+    query = jax.lax.broadcasted_iota(jnp.int32, (window, _TILE), 1)
+    keep = key <= query % window  # the tile's rule: the key is not after
+    if keep_ref:
+        keep = jnp.logical_and(keep, keep_ref[0][...] != 0)
+    mine = [query // window == w for w in range(windows)]
+    cos, sin, gain = cos_ref[...], sin_ref[...], gain_ref[...]
+
+    def one_head(k, v, head):
+        rows = pl.ds(pl.multiple_of(head * hd, _LANES), hd)
+        x = q_ref[rows, :].astype(f32)
+        # the head's RMS norm, then rotate-half over the whole head: a
+        # pair's halves are the head's upper and lower rows
+        x = x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=0, keepdims=True) + eps) * gain
+        x1, x2 = x[:half], x[half:]
+        x = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                            axis=0).astype(dt)
+        # every key of the tile against every query of it, then each
+        # query's own window's rows: [window, _TILE], nothing wasted
+        s = jnp.dot(k, x, preferred_element_type=f32)
+        own = s[:window]
+        for w in range(1, windows):
+            own = jnp.where(mine[w], s[w * window:(w + 1) * window], own)
+        own = jnp.where(keep, own * scale, -jnp.inf)
+        e = jnp.exp(own - jnp.max(own, axis=0, keepdims=True))
+        p = e / jnp.sum(e, axis=0, keepdims=True)
+        # back over the tile's keys: exact zeros outside the window
+        p = jnp.concatenate([jnp.where(mine[w], p, 0.0)
+                             for w in range(windows)], axis=0).astype(dt)
+        o_ref[rows, :] = jnp.dot(
+            v, p, preferred_element_type=f32).astype(o_ref.dtype)
+
+    def one_key_head(g, carry):
+        k = k_ref[:, pl.ds(pl.multiple_of(g * hd, _LANES), hd)]
+        v = v_ref[pl.ds(pl.multiple_of(g * hd, _LANES), hd), :]
+
+        def one_turn(i, carry):
+            # independent heads side by side in one loop body: their
+            # products overlap on the MXUs
+            for j in range(turn):
+                one_head(k, v, g * rep + i * turn + j)
+            return carry
+
+        return jax.lax.fori_loop(0, rep // turn, one_turn, carry)
+
+    jax.lax.fori_loop(0, k_ref.shape[1] // hd, one_key_head, 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "kv_heads", "window", "eps", "group", "interpret"))
+def _grouped_window_attention(q, k, v, cos, sin, gain, keep, *, heads: int,
+                              kv_heads: int, window: int, eps: float,
+                              group: int, interpret: bool):
+    p = q.shape[1]
+    hd, rep = q.shape[0] // heads, heads // kv_heads
+    size = k.dtype.itemsize
+    by_group = lambda i, g: (g, i)
+    by_tile = lambda i, g: (0, i)
+    whole = lambda i, g: (0, 0)
+    # the angles and the mask channel-major as ``q`` is, the gain over a
+    # tile's lanes
+    operands = [q, k, v, cos.T, sin.T,
+                jnp.broadcast_to(gain.astype(jnp.float32)[:, None], (hd, _TILE))]
+    in_specs = [pl.BlockSpec((group * rep * hd, _TILE), by_group),
+                pl.BlockSpec((_TILE, group * hd), lambda i, g: (i, g)),
+                pl.BlockSpec((group * hd, _TILE), by_group),
+                pl.BlockSpec((hd // 2, _TILE), by_tile),
+                pl.BlockSpec((hd // 2, _TILE), by_tile),
+                pl.BlockSpec((hd, _TILE), whole)]
+    if keep is not None:
+        operands.append(keep.T.astype(jnp.int32))
+        in_specs.append(pl.BlockSpec((window, _TILE), by_tile))
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, window=window, hd=hd, rep=rep,
+                          eps=eps, scale=hd ** -0.5),
+        out_shape=jax.ShapeDtypeStruct((heads * hd, p), k.dtype),
+        grid=(p // _TILE, kv_heads // group),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((group * rep * hd, _TILE), by_group),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=min(_VMEM_CAP, _grouped_vmem(
+                group, rep, hd, q.dtype.itemsize, size))),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * p * _TILE * heads * hd,
+            transcendentals=p * window * heads,
+            bytes_accessed=(q.size * q.dtype.itemsize
+                            + (k.size + v.size + p * heads * hd) * size
+                            + p * (hd + window) * 4)),
+        interpret=interpret,
+    )(*operands)
+
+
+def grouped_window_attention(q, k, v, cos, sin, gain, keep=None, *,
+                             heads: int, kv_heads: int, window: int,
+                             eps: float, interpret: bool = False):
+    """Causal grouped-query attention inside windows of ``window``
+    consecutive positions: query head ``j`` against key head ``j // (heads
+    // kv_heads)``, the query's head norm and rotary applied here.
+
+    ``q`` [heads x hd, P], CHANNEL-MAJOR as its projection accumulated it
+    (``wq^T a^T``; any float dtype, float32), NOT yet normed or turned;
+    ``k`` [P, kv_heads x hd], position-major, normed, turned and rounded;
+    ``v`` [kv_heads x hd, P], channel-major; both in the operands' dtype.
+    ``cos``, ``sin`` [P, hd / 2] float32, a position's rotary angles over
+    the whole head (rotate-half: pair ``i`` is channels ``i`` and ``i + hd
+    / 2``); ``gain`` [hd] the head norm's; ``keep`` [P, window] bool or
+    None, a second mask: query ``p`` may read key ``s`` of its own window
+    only where ``keep[p, s]`` -> [heads x hd, P] in the operands' dtype,
+    channel-major as ``wo``'s product contracts it: per head
+    ``softmax(rot(norm(q)) k^T / sqrt(hd)) v`` over the keys of the query's
+    window at or before it that ``keep`` leaves. In float32 until the one
+    rounding before each product, as the einsum form (``keye_backbone``'s)
+    rounds. P is whole windows; a last tile that the windows do not fill is
+    padded here (zeros, every key kept) and cut from the result. A query
+    whose every key is masked reads NaN, as in the einsum form.
+    ``interpret=True`` runs the Pallas interpreter, always the caller's
+    explicit choice."""
+    p = q.shape[1]
+    pad = -p % _TILE
+    if pad:
+        q, v = (jnp.pad(x, ((0, 0), (0, pad))) for x in (q, v))
+        k, cos, sin = (jnp.pad(x, ((0, pad), (0, 0))) for x in (k, cos, sin))
+        if keep is not None:
+            keep = jnp.pad(keep, ((0, pad), (0, 0)), constant_values=True)
+    out = _grouped_window_attention(
+        q, k, v, cos, sin, gain, keep, heads=heads, kv_heads=kv_heads,
+        window=window, eps=eps, group=_kv_heads_per_step(heads, kv_heads),
+        interpret=interpret)
+    return out[:, :p] if pad else out

@@ -240,6 +240,51 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     _assert_rows_come_in_by_the_kernel(text, 32768)
 
 
+@pytest.mark.parametrize("batch,parents_temps", [(256, 394_860_544),
+                                                 (64, 20_904_960)])
+def test_backbone_step_runs_attention_where_the_projections_wrote(
+        topo, tpu_backend, capsys, batch, parents_temps):
+    """``keye``'s session step at both rungs of its cell (PR 47): the core
+    of attention is the window kernel's grouped form, one custom call a
+    layer under ``head/attn`` (the scope ``head_attention_ms`` reads), on
+    ``wq``'s float32 result as the product left it (channel-major:
+    ``[heads x 128, P]``). Nothing of ``[b, heads,
+    t, s]`` and no copy of ``q`` turned heads-first is left in the compiled
+    module (at the parent: ``f32[256,4,16,16,8]`` scores and a copy of
+    them, ``bf16[256,4,8,16,16]`` probabilities, ``f32[256,16,32,128]``
+    and ``bf16[256,16,32,128]`` copies of ``q``), and the step's
+    temporaries stay within 10% of the parent's compile read
+    (394,860,544 B at 256 rows, 20,904,960 at 64: the kernel's operands
+    are arrays the einsums' fusions never wrote whole; what is read here
+    is 373,297,152 and 22,832,640)."""
+    from jax.sharding import SingleDeviceSharding
+
+    capacity = 5_242_880
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step("keye", capacity, capacity + 1, one, one, batch=batch)
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    with capsys.disabled():
+        print(f"\nkeye {batch}-row step for a described v5e: temporaries "
+              f"{mem.temp_size_in_bytes} B (the parent's {parents_temps})")
+    assert mem.temp_size_in_bytes <= parents_temps * 1.1, mem
+    cores = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line
+             and "head/attn/core" in line]
+    assert len(cores) == 4, cores
+    for line in cores:
+        assert re.match(r"\s*%_grouped_window_attention(\.\d+)? = "
+                        rf"bf16\[4096,{batch * 16}\]", line), line[:200]
+        # q as ``wq`` accumulated it, k and v, the mask's rows over a tile
+        for operand in (f"f32[4096,{batch * 16}]", f"bf16[{batch * 16},512]",
+                        f"bf16[512,{batch * 16}]", f"s32[16,{batch * 16}]"):
+            assert operand in line, (operand, line[:400])
+    for gone in (f"[{batch},4,8,16,16]", f"[{batch},4,16,16,8]",
+                 f"[{batch},16,32,128]", f"[{batch},16,4,8,128]",
+                 f"f32[{batch},16,4096]"):
+        assert gone not in text, gone
+
+
 def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
         topo, tpu_backend, capsys):
     """The fused step with the ``pangu`` backbone in it, at the cell's size
@@ -396,8 +441,9 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
     ("pangu", 3_145_728, 955_600_896,
      {"_window_attention": 5, "_combine_held": 4, "ragged-dot-none": 12}),
     ("falconh1", 5_242_880, 631_744_000, {}),
-    ("keye", 5_242_880, 394_860_544,
-     {"_gate_up": 4, "_down": 4, "_combine_rows": 4}),
+    ("keye", 5_242_880, 373_297_152,
+     {"_gate_up": 4, "_down": 4, "_combine_rows": 4,
+      "_grouped_window_attention": 4}),
     ("lfm2", 5_242_880, 308_153_856,
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4})])
 def test_the_64_row_rung_compiles_beside_the_256_one(
@@ -414,7 +460,9 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
     nothing to ``bytes_reserved`` and costs its programs' code alone,
     which is why the default ladder holds the rung in every cell. Read
     here (PR 46): 211,406,336 B ``pangu``, 142,620,160 ``falconh1``,
-    20,904,960 ``keye``, 56,169,472 ``lfm2``; code 12.1-19.4 MB."""
+    20,904,960 ``keye``, 56,169,472 ``lfm2``; code 12.1-19.4 MB. Since PR
+    47 ``keye``'s steps hold the window kernel's grouped form once a layer
+    (22,832,640 B at this rung, 373,297,152 at the 256-row one)."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.serve import session_state as ss

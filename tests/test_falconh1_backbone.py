@@ -660,13 +660,16 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
 
 
 @pytest.mark.parametrize("name,sha256", [
-    ("keye", "ec4e13b382434280"), ("pangu", "3b1ad6dc001c49cb"), ("lfm2", "619bcfbac3323ea6")])
+    ("keye", "97b106f2039253a0"), ("pangu", "3b1ad6dc001c49cb"), ("lfm2", "619bcfbac3323ea6")])
 def test_the_other_backbones_lower_to_the_parents_stablehlo(name, sha256):
     """The three backbones that share the widened functions, each at a small
     size of its own kinds of layer: the StableHLO of ``backbone_scores`` is
     byte for byte what the parent commit (9b653c8) lowers, held by its
     sha256 (computed there; a later PR that means to change one of them
-    says so and replaces the digest)."""
+    says so and replaces the digest). PR 47 meant to change ``keye``'s: its
+    stream is [P, hidden] and its core the einsums of ``_core_by_einsums``
+    (ec4e13b382434280 before; the scores' bits are held to the 3-D stream's
+    in tests/test_keye_backbone.py); ``pangu``'s and ``lfm2``'s stand."""
     f32 = jnp.float32
     if name == "keye":
         cfg = kb.BackboneConfig(hidden=128, layers=2, heads=4, kv_heads=2,

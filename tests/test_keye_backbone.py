@@ -316,13 +316,192 @@ def test_head_with_the_kernels_on_equals_the_xla_path(operands, monkeypatch, cap
     fed = kernels.feed(128 * cfg.top_k, 1024, cfg.experts, 128, positions=128)
     assert fed.endswith("rows=gathered")
     core = f"pallas-grouped ({fed})" if operands == "bfloat16" else "xla-ragged-dot"
+    # heads of 16 at this size: attention's core declines and says why
     assert said == {f"expert core: {core} (backend=tpu)",
-                    "combine: pallas-rows (backend=tpu)"}
+                    "combine: pallas-rows (backend=tpu)",
+                    "attention core: einsum (head width 16 is not whole "
+                    "128-lane vregs) (backend=tpu)"}
     assert np.ptp(by_xla) > 1e-3
     # float32 summation order in the way back (and, with bfloat16 operands,
     # inside the products, before ``mid`` is rounded once)
     atol = 2e-6 if operands == "float32" else 2e-4
     np.testing.assert_allclose(by_kernels, by_xla, atol=atol, rtol=0)
+
+
+# -- attention's core: the window kernel's grouped form or two einsums ----------
+
+
+def wide_heads(**over) -> kb.BackboneConfig:
+    """The published attention (32 query / 4 key heads of 128, M-RoPE's
+    sections, the indexer's 16 heads of 64) over a small hidden size, so
+    that the kernel takes the layer and a CPU holds it; ``idx_topk`` 2048
+    keeps every causal key, as in the cell."""
+    kw = dict(hidden=256, layers=1, heads=32, kv_heads=4, head_dim=128,
+              experts=2, top_k=1, expert_width=16, idx_heads=16, idx_dim=64,
+              idx_topk=2048, mrope_section=(16, 24, 24))
+    kw.update(over)
+    return kb.BackboneConfig(**kw)
+
+
+def attention_layer(cfg, windows_n: int, seed: int = 0):
+    """One seeded layer with gains that are not 1, a residual stream [P,
+    hidden] and the angles of positions 0 .. 15 of every window."""
+    layer = dict(kb.init_backbone(jax.random.key(21 + seed), cfg)["layers"][0])
+    ks = jax.random.split(jax.random.key(22 + seed), 3)
+    for name, key in zip(("qn", "kn"), ks[:2], strict=True):
+        layer[name] = 1 + 0.2 * jax.random.normal(key, layer[name].shape, jnp.float32)
+    p = windows_n * 16
+    h = jax.random.normal(ks[2], (p, cfg.hidden), jnp.float32)
+    pos3 = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (3, windows_n, 16))
+    cos, sin = (a.reshape(p, -1) for a in kb.mrope_angles(
+        pos3, cfg.head_dim, cfg.mrope_section, cfg.rope_theta))
+    return layer, h, cos, sin
+
+
+def run_attention(cfg, layer, h, cos, sin):
+    return np.asarray(jax.jit(
+        lambda h, c, s: kb.attention(h, layer, c, s, cfg, 16))(h, cos, sin))
+
+
+@pytest.fixture
+def attention_by_kernel(monkeypatch, caplog):
+    """Runs ``attention`` as a TPU would trace it, the window kernel through
+    the Pallas interpreter (steered here, in the test), and returns what it
+    computed with what ``_announce_core`` said."""
+    from igaming_platform_tpu.ops.pallas import window_attention as wa
+
+    def run(*args):
+        kb._announce_core.cache_clear()
+        caplog.clear()
+        with monkeypatch.context() as m, caplog.at_level("INFO", logger=kb.logger.name):
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            m.setattr(wa, "grouped_window_attention", functools.partial(
+                wa.grouped_window_attention, interpret=True))
+            out = run_attention(*args)
+        return out, [r.getMessage() for r in caplog.records]
+    return run
+
+
+@pytest.mark.parametrize("windows_n", [64, 256])
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_attention_through_the_kernel_equals_the_einsum_path(
+        operands, windows_n, attention_by_kernel):
+    """The sublayer on the seeded tree at the cell's two rungs (1,024 and
+    4,096 positions): projections, indexer and ``wo`` are the same XLA
+    either way, the core is the kernel or the einsums. ``wo`` sums 4,096
+    products of the core's results, so a result that fell to the other
+    side of a bfloat16 boundary (one in a thousand, tests/
+    test_window_attention.py) moves an output by 2^-8 of one term."""
+    cfg = wide_heads(operand_dtype=jnp.dtype(operands))
+    args = attention_layer(cfg, windows_n)
+    by_einsum = run_attention(cfg, *args)
+    by_kernel, said = attention_by_kernel(cfg, *args)
+    assert said == ["attention core: pallas-windows (grouped 32/4 of 128, "
+                    "window 16, mask=keep) (backend=tpu)"]
+    assert kb.announced_cores()["attention core"] == said[0].split(": ", 1)[1]
+    assert by_kernel.shape == by_einsum.shape == (windows_n * 16, cfg.hidden)
+    scale = np.abs(by_einsum).max()
+    assert scale > 0.01
+    atol = 1e-6 if operands == "float32" else 2e-3
+    np.testing.assert_allclose(by_kernel, by_einsum, atol=atol * scale, rtol=0)
+
+
+@pytest.mark.parametrize("core", ["einsum", "kernel"])
+def test_the_indexer_is_computed_not_assumed(core, attention_by_kernel):
+    """``idx_topk`` 4 under the window's 16 keys: the keys the indexer
+    drops change the output, through the einsums and through the kernel
+    alike (the mask is the kernel's operand; it does not take the cell's
+    ``topk`` 2048 for granted), and the two paths agree on the pruned
+    layer."""
+    run = ((lambda *a: attention_by_kernel(*a)[0]) if core == "kernel"
+           else run_attention)
+    pruned, whole = wide_heads(idx_topk=4), wide_heads()
+    args = attention_layer(pruned, 16, seed=1)
+    got, kept_all = run(pruned, *args), run(whole, *args)
+    first = np.arange(len(got)) % 16 < 4   # up to 4 causal keys: none dropped
+    np.testing.assert_array_equal(got[first], kept_all[first])
+    rest = np.abs(got[~first] - kept_all[~first]).max(axis=1)
+    assert (rest > 1e-3 * np.abs(kept_all).max()).all()
+    np.testing.assert_allclose(got, run_attention(pruned, *args),
+                               atol=2e-3 * np.abs(got).max(), rtol=0)
+
+
+@pytest.mark.parametrize("backend,over,window,said", [
+    ("cpu", {}, 16, "einsum (not a TPU) (backend=cpu)"),
+    ("tpu", {}, 16, "pallas-windows (grouped 32/4 of 128, window 16, mask=keep) "
+                    "(backend=tpu)"),
+    ("tpu", {"heads": 4, "kv_heads": 2, "head_dim": 16}, 16,
+     "einsum (head width 16 is not whole 128-lane vregs) (backend=tpu)"),
+    ("tpu", {"heads": 20, "kv_heads": 3}, 16,
+     "einsum (20 heads over 3 key heads) (backend=tpu)"),
+    ("tpu", {}, 12, "einsum (windows of 12 are not whole 8-row vregs that "
+                    "divide a tile of 128) (backend=tpu)"),
+    ("tpu", {"operand_dtype": jnp.float16}, 16,
+     "einsum (operands float16 / float16) (backend=tpu)"),
+], ids=["off-the-tpu", "published", "small-heads", "uneven-sharing", "window12",
+        "float16"])
+def test_attention_core_is_announced_with_the_reason_it_declines(
+        backend, over, window, said, monkeypatch, caplog):
+    """The choice is made while tracing, from the backend and the layer's
+    shapes alone; the boot's log line and ``/debug/sessionz``'s
+    ``head_cores`` carry it, with the kernel's own reason beside ``einsum``."""
+    cfg = wide_heads(**over)
+    kb._announce_core.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    keep = jax.ShapeDtypeStruct((64 * window, window), jnp.bool_)
+    with caplog.at_level("INFO", logger=kb.logger.name):
+        by_kernel = kb._attention_core(64 * window, keep, cfg, window)
+    assert by_kernel is said.startswith("pallas-windows")
+    assert [r.getMessage() for r in caplog.records] == [f"attention core: {said}"]
+    assert kb.announced_cores()["attention core"] == said
+
+
+def _hidden_with_a_3d_stream(params, x, pos3, cfg):
+    """``backbone_hidden`` as it was before the stream went position-major
+    (PR 47's parent): ``h`` [B, T, hidden], every product over a 3-D
+    operand, attention's core the two einsums over ``[b, t, h, d]`` with
+    the indexer's [B, T, T] mask."""
+    b, t, _ = x.shape
+    nh, nkv, hd, dt = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.operand_dtype
+    h = kb._mm(x, params["embed"], cfg)
+    cos, sin = kb.mrope_angles(pos3, hd, cfg.mrope_section, cfg.rope_theta)
+    for layer in params["layers"]:
+        a = kb.rms_norm(h, layer["g1"], cfg.eps)
+        q = kb._mm(a, layer["wq"], cfg).reshape(b, t, nh, hd)
+        k = kb._mm(a, layer["wk"], cfg).reshape(b, t, nkv, hd)
+        v = kb._mm(a, layer["wv"], cfg).reshape(b, t, nkv, hd)
+        q = kb.rotate(kb.rms_norm(q, layer["qn"], cfg.eps), cos, sin)
+        k = kb.rotate(kb.rms_norm(k, layer["kn"], cfg.eps), cos, sin)
+        keep = kb.indexer_keep(a.reshape(b * t, -1), layer, cos.reshape(b * t, -1),
+                               sin.reshape(b * t, -1), cfg, t).reshape(b, t, t)
+        q = q.reshape(b, t, nkv, nh // nkv, hd)
+        sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),
+                        preferred_element_type=jnp.float32) * (hd ** -0.5)
+        p = jax.nn.softmax(jnp.where(keep[:, None, None], sc, -jnp.inf), axis=-1)
+        o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
+                       preferred_element_type=jnp.float32)
+        h = h + kb._mm(o.reshape(b, t, nh * hd), layer["wo"], cfg)
+        flat = kb.rms_norm(h, layer["g2"], cfg.eps).reshape(b * t, -1)
+        top_e, top_w = kb.route(flat, layer, cfg)
+        h = h + kb.grouped_experts(flat, top_e, top_w, layer, cfg).reshape(b, t, -1)
+    return kb.rms_norm(h, params["gf"], cfg.eps)
+
+
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_the_position_major_stream_scores_the_3d_streams_bits(operands):
+    """The head's stream is [P, hidden] from the projector to the final
+    norm; on the einsum path that is the same arithmetic on the same
+    numbers as the [B, T, hidden] stream it replaced, bit for bit."""
+    cfg = small_config(operand_dtype=jnp.dtype(operands))
+    params = kb.init_backbone(jax.random.key(13), cfg)
+    x, lens = windows(12, (1, 4, 16, 7, 9, 2), seed=4)
+    pos3 = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (3, 12, 16))
+    now = program_scores(cfg, params, x, lens)
+    was = np.asarray(jax.jit(lambda p, w, l: kb.score_last(
+        p, _hidden_with_a_3d_stream(p, w, pos3, cfg), l))(
+            params, jnp.asarray(x), jnp.asarray(lens, jnp.int32)))
+    assert np.ptp(now) > 1e-3
+    np.testing.assert_array_equal(now, was)
 
 
 # -- a chip's share of the experts (the layer both backbones call) --------------
