@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 import json
 import math
 import os
@@ -670,25 +671,25 @@ def phase_trainer(server, score_batch, before: dict, compiles, *,
 
 
 def _said_by_the_expert_layer(fn) -> list[str]:
-    """What ``models/keye_backbone._announce_core`` says while ``fn`` runs:
+    """What ``models/decoder_parts.announce_core`` says while ``fn`` runs:
     the cores picked while tracing (``expert core: ...``, ``combine: ...``,
-    and ``models/pangu_backbone``'s ``attention core: ...``), each once."""
+    ``attention core: ...``, ``state-space core: ...``), each once."""
     import logging
 
-    from igaming_platform_tpu.models import keye_backbone
+    from igaming_platform_tpu.models import decoder_parts
 
     said: list[str] = []
     handler = logging.Handler()
     handler.emit = lambda record: said.append(record.getMessage())
-    keye_backbone._announce_core.cache_clear()
-    keye_backbone.logger.addHandler(handler)
-    level = keye_backbone.logger.level
-    keye_backbone.logger.setLevel(logging.INFO)
+    decoder_parts.announce_core.cache_clear()
+    decoder_parts.logger.addHandler(handler)
+    level = decoder_parts.logger.level
+    decoder_parts.logger.setLevel(logging.INFO)
     try:
         fn()
     finally:
-        keye_backbone.logger.removeHandler(handler)
-        keye_backbone.logger.setLevel(level)
+        decoder_parts.logger.removeHandler(handler)
+        decoder_parts.logger.setLevel(level)
     return said
 
 
@@ -723,7 +724,7 @@ def phase_kernels(interpret: bool = False, *,
     import numpy as np
 
     from igaming_platform_tpu.core.features import NUM_FEATURES
-    from igaming_platform_tpu.models import keye_backbone
+    from igaming_platform_tpu.models import decoder_parts, expert_layer, keye_backbone
     from igaming_platform_tpu.models.gbdt import gbdt_raw, init_gbdt
     from igaming_platform_tpu.ops.gbdt_matmul import gbdt_raw_matmul, precompute_selector
     from igaming_platform_tpu.ops.pallas import flash_attention as fa
@@ -857,7 +858,7 @@ def phase_kernels(interpret: bool = False, *,
     def way_back(label, ys, at, weights, take, want):
         """``combine`` against the XLA expressions it replaces."""
         picked = _said_by_the_expert_layer(
-            lambda: keye_backbone._combine_by_kernel(ys, at, take))
+            lambda: expert_layer._combine_by_kernel(ys, at, take))
         got = ge.combine(ys, at, weights, take, interpret=interpret)
         err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
         report[label] = {"max_err": err, "way_back": picked[0]}
@@ -910,7 +911,7 @@ def phase_kernels(interpret: bool = False, *,
     q = jax.random.normal(ks[0], (n, cfg.heads * cfg.head_dim), jnp.float32) * 3
     k, v = (jax.random.normal(key, (n, cfg.kv_heads * cfg.head_dim),
                               jnp.float32).astype(jnp.bfloat16) for key in ks[1:3])
-    cos, sin = (a.reshape(n, -1) for a in keye_backbone.mrope_angles(
+    cos, sin = (a.reshape(n, -1) for a in decoder_parts.mrope_angles(
         jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (3, grouped_windows, t)),
         cfg.head_dim, cfg.mrope_section, cfg.rope_theta))
     gain = 1 + 0.1 * jax.random.normal(ks[3], (cfg.head_dim,), jnp.float32)
@@ -940,27 +941,27 @@ def phase_kernels(interpret: bool = False, *,
 
 
 # SESSION_HEAD name -> (its configuration file, its reference under
-# chipbench/heads/, its module and its sizes in models/session_heads, and
-# the parts of it that pick a core while tracing, each with the core it
-# must pick on a TPU at the published widths): the backbones
-# ``phase_backbone`` runs. ``pangu``'s share of wide experts is past the
-# grouped kernels' VMEM (one expert's gate and up are 63 MB), so its
-# products are XLA's; ``lfm2``'s attention is einsums and picks nothing,
+# chipbench/heads/, its module under models/, and the parts of it that pick
+# a core while tracing, each with the core it must pick on a TPU at the
+# published widths): the backbones ``phase_backbone`` runs, each at the
+# sizes of its row in models/session_heads.HEADS. ``pangu``'s share of wide
+# experts is past the grouped kernels' VMEM (one expert's gate and up are
+# 63 MB), so its products are XLA's; ``lfm2``'s attention is einsums and
+# picks nothing,
 # and its expert kernels say how they are fed (the ring's slots of
 # ``gate_up`` / ``down``, the rows brought in by ``gate_up`` itself);
 # ``falconh1`` has no expert layer, and its state-space core says which form
 # it runs (the dual form over the one chunk a window is).
 BACKBONES = {
     "pangu": ("risk-seqhead-openpangu-ultra-moe-718b", "openpangu_ultra",
-              "pangu_backbone", "PANGU_CONFIG",
+              "pangu_backbone",
               {"expert_core": "xla-ragged-dot", "way_back": "pallas-rows",
                "attention_core": "pallas-windows"}),
-    "lfm2": ("risk-seqhead-lfm2-24b-a2b", "lfm2_24b_a2b",
-             "lfm2_backbone", "LFM2_CONFIG",
+    "lfm2": ("risk-seqhead-lfm2-24b-a2b", "lfm2_24b_a2b", "lfm2_backbone",
              {"expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
                              "rows=in-kernel)", "way_back": "pallas-rows"}),
     "falconh1": ("risk-seqhead-falcon-h1-34b", "falcon_h1_34b",
-                 "falconh1_backbone", "FALCONH1_CONFIG",
+                 "falconh1_backbone",
                  {"ssm_core": "dual form, one chunk, 16 <= 128"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
@@ -994,10 +995,11 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     from chipbench import reference, validate
     from igaming_platform_tpu.models import session_heads
 
-    config_name, reference_name, module, sizes, on_tpu = BACKBONES[head_name]
-    scores = getattr(session_heads, module).backbone_scores
+    config_name, reference_name, module, on_tpu = BACKBONES[head_name]
+    scores = importlib.import_module(
+        f"igaming_platform_tpu.models.{module}").backbone_scores
     published = cfg is None
-    cfg = cfg or getattr(session_heads, sizes)
+    cfg = cfg or session_heads.HEADS[head_name].config
     config = config or validate.load_data("configs", config_name)
     head = validate.load_code("heads", reference_name)
     params = head.make_params(seed, config)

@@ -1,0 +1,269 @@
+"""The parts of a decoder layer that two or more backbone modules call,
+owned by no model: the backbones import from here (and from the dropless
+expert layer beside it, models/expert_layer.py), never from each other. A
+function one backbone alone calls stays in that backbone's module. ``cfg``
+is any configuration with an ``operand_dtype`` (the routers read their own
+fields off it besides).
+
+Precision: every product multiplies ``operand_dtype`` operands and
+accumulates in float32; norms, softmax, router scores, the convolution and
+the logit are float32.
+"""
+
+from __future__ import annotations
+
+import logging
+import math
+from functools import lru_cache, partial
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+Params = dict[str, Any]
+
+logger = logging.getLogger(__name__)
+
+# The most float32 normals one draw of ``_matrix`` makes (2^24: 64 MB).
+_DRAW_ELEMS = 1 << 24
+
+
+def row_blocks(rows: int, cols: int) -> int:
+    """In how many equal row blocks a [rows, cols] matrix is drawn so that
+    no draw passes ``_DRAW_ELEMS`` elements: the least divisor of ``rows``
+    that leaves blocks of whole bfloat16 tiles (16 rows), 1 where the
+    matrix is small or has no such divisor."""
+    need = -(-rows * cols // _DRAW_ELEMS)
+    if need <= 1:
+        return 1
+    return next((b for b in range(need, rows // 16 + 1)
+                 if rows % (16 * b) == 0), 1)
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _matrix(key, shape: tuple[int, ...], fan_in: int):
+    """Seeded normals scaled by ``fan_in ** -0.5``, in bfloat16. A stacked
+    weight ([experts, ...]) is generated slice by slice (``lax.map``), and
+    a matrix of more than ``_DRAW_ELEMS`` elements row block by row block,
+    so the float32 normals never exceed one expert's matrix or one
+    block."""
+    def draw(k, shp):
+        return (jax.random.normal(k, shp, jnp.float32)
+                * (1.0 / math.sqrt(fan_in))).astype(jnp.bfloat16)
+
+    if len(shape) == 3:
+        return jax.lax.map(lambda k: draw(k, shape[1:]),
+                           jax.random.split(key, shape[0]))
+    blocks = row_blocks(*shape)
+    if blocks > 1:
+        return jax.lax.map(lambda k: draw(k, (shape[0] // blocks, shape[1])),
+                           jax.random.split(key, blocks)).reshape(shape)
+    return draw(key, shape)
+
+
+def tree_around(layers: list, embed, key, hidden: int, head_scale=1.0) -> Params:
+    """The tree every backbone holds around its ``layers``: the projector
+    ``embed`` its caller drew, the final norm's gain and the scoring head,
+    one float32 column drawn at ``hidden ** -0.5`` (over ``head_scale``, the
+    ``falconh1`` head's ``lm_head_multiplier``) and a zero bias."""
+    f32 = jnp.float32
+    return {
+        "embed": embed,
+        "layers": layers,
+        "gf": jnp.ones((hidden,), f32),
+        "head": {"w": jax.random.normal(key, (hidden, 1), f32)
+                 * (1.0 / (math.sqrt(hidden) * head_scale)),
+                 "b": jnp.zeros((1,), f32)},
+    }
+
+
+def mm(x, w, cfg):
+    """``x @ w`` over the last axis of ``x``: operands in the stated
+    dtype, accumulated in float32."""
+    dt = cfg.operand_dtype
+    return jax.lax.dot_general(
+        x.astype(dt), w.astype(dt), (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def mm_t(w, x, cfg):
+    """``(x @ w)^T`` as the product ``w^T x^T``, [out, P] channel-major:
+    the same operands and the same float32 sums as ``mm``, the result
+    written positions along the lanes."""
+    dt = cfg.operand_dtype
+    return jax.lax.dot_general(w.astype(dt), x.astype(dt),
+                               (((0,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, gain, eps: float):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def mrope_angles(pos3, head_dim: int, sections, theta: float):
+    """M-RoPE: ``pos3`` [3, B, T] (temporal, height, width ids) ->
+    (cos, sin) [B, T, head_dim // 2]. Frequency pair ``i`` turns by
+    ``theta ** (-2 i / head_dim)`` a step of the stream its section
+    names: the first ``sections[0]`` pairs follow the temporal id, the
+    next ``sections[1]`` the height id, the rest the width id."""
+    half = head_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    stream = np.repeat(np.arange(len(sections)), sections)
+    assert len(stream) == half, (sections, head_dim)
+    pos = jnp.take(pos3.astype(jnp.float32), stream, axis=0)  # [half, B, T]
+    ang = jnp.moveaxis(pos, 0, -1) * inv
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def rope_angles(b: int, t: int, dim: int, theta: float):
+    """One rotary stream on all ``dim`` channels of ``b`` windows, position
+    = the event's index in its window: (cos, sin) [B, T, dim // 2]."""
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
+    return mrope_angles(pos, dim, (dim // 2,), theta)
+
+
+def rotate(x, cos, sin):
+    """Rotary embedding on the leading ``2 * cos.shape[-1]`` channels of
+    ``x`` [B, T, H, D] (pair ``i`` is channels ``i`` and ``i + half``:
+    the rotate-half convention); the rest pass through."""
+    half = cos.shape[-1]
+    x1, x2, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
+
+
+def swiglu(x, w: Params, cfg, gate_scale=None):
+    """``(silu(x Wg) * x Wu) Wd``; the product between is rounded once to
+    the operands' dtype, as the expert kernels round theirs. With
+    ``gate_scale`` (a float: the ``falconh1`` head's first MLP multiplier,
+    models/falconh1_backbone.py) the gate is ``silu((x Wg) * gate_scale)``,
+    scaled in float32 before the activation; ``pangu``'s and ``lfm2``'s
+    MLPs have none."""
+    gate = mm(x, w["wg"], cfg)
+    if gate_scale is not None:
+        gate = gate * gate_scale
+    mid = jax.nn.silu(gate) * mm(x, w["wu"], cfg)
+    return mm(mid, w["wd"], cfg)
+
+
+def route(x, layer: Params, cfg):
+    """Sigmoid router over ALL experts, no groups: ``(experts [P, top_k]
+    int32, weights [P, top_k] float32)``, the weights the chosen scores
+    over their sum (held or not) plus ``cfg.renorm_eps``, times
+    ``cfg.routed_scale``. Where the layer has an expert bias (``rb``
+    [experts] float32: the ``lfm2`` head's, models/lfm2_backbone.py) it is
+    added to the scores that ``top_k`` reads and to nothing else: the bias
+    chooses and does not weigh. The ``pangu`` head's layers have none."""
+    s = jax.nn.sigmoid(mm(x, layer["wr"], cfg))
+    if "rb" in layer:
+        _, top_e = jax.lax.top_k(s + layer["rb"], cfg.top_k)
+        top_s = jnp.take_along_axis(s, top_e, axis=-1)
+    else:
+        top_s, top_e = jax.lax.top_k(s, cfg.top_k)
+    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
+    return top_e, w * cfg.routed_scale
+
+
+def causal_taps(z, taps, bias=None):
+    """The depthwise causal convolution over the positions of each window:
+    ``z`` [B, T, C] float32, ``taps`` [C, L] -> ``c[b, t] = sum_k taps[:, k]
+    * z[b, t - (L - 1 - k)]``, with ``z`` before a window's first position
+    zero. ``L`` shifted products, no product on the MXU. With ``bias`` [C]
+    (the ``falconh1`` head's convolution has one, models/
+    falconh1_backbone.py) it is added at every position; the ``lfm2``
+    head's has none."""
+    n_taps = taps.shape[1]
+    t = z.shape[1]
+    c = z * taps[:, n_taps - 1]
+    for back in range(1, n_taps):
+        earlier = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
+        c = c + earlier * taps[:, n_taps - 1 - back]
+    return c if bias is None else c + bias
+
+
+def attention(u, layer: Params, cos, sin, cfg, window: int, key_scale=None):
+    """Grouped-query attention over normed hidden states ``u`` [P, hidden]
+    -> [P, hidden]: per-head RMSNorm on q and k where the layer holds their
+    gains (``qn``, ``kn``: the ``lfm2`` head's layers do, the ``falconh1``
+    head's have no head norm), one rotary stream on every channel, causal.
+    With ``key_scale`` (a float: the ``falconh1`` head's
+    ``key_multiplier``) the keys are ``(u Wk) * key_scale``, scaled in
+    float32; ``lfm2`` passes none. Its 16-key core runs as two einsums."""
+    nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
+    dt, t = cfg.operand_dtype, window
+    b = u.shape[0] // t
+    q = mm(u, layer["wq"], cfg).reshape(b, t, nh, hd)
+    k = mm(u, layer["wk"], cfg).reshape(b, t, nkv, hd)
+    v = mm(u, layer["wv"], cfg).reshape(b, t, nkv, hd)
+    if key_scale is not None:
+        k = k * key_scale
+    normed = ((lambda x, gain: rms_norm(x, layer[gain], cfg.eps))
+              if "qn" in layer else (lambda x, gain: x))
+    q = rotate(normed(q, "qn"), cos, sin)
+    k = rotate(normed(k, "kn"), cos, sin)
+    # query head j reads key-value head j // (nh // nkv)
+    q = q.reshape(b, t, nkv, nh // nkv, hd)
+    sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),
+                    preferred_element_type=jnp.float32) * (hd ** -0.5)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+    o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
+                   preferred_element_type=jnp.float32)
+    return mm(o.reshape(b * t, nh * hd), layer["wo"], cfg)
+
+
+def score_last(params: Params, hid, lengths, logit_scale=None):
+    """The scoring head on final-normed hidden states ``hid`` [B, T,
+    hidden]: the sigmoid of one float32 output column at each window's
+    last real position. With ``logit_scale`` (a float: the ``falconh1``
+    head's ``lm_head_multiplier``) the column's product is scaled before
+    the bias is added, as that model scales its output head's logits."""
+    t = hid.shape[1]
+    last = jnp.clip(lengths.astype(jnp.int32) - 1, 0, t - 1)
+    hl = jnp.take_along_axis(hid, last[:, None, None], axis=1)[:, 0, :]
+    # one output column: a float32 multiply-reduce, never the MXU
+    logit = jnp.sum(hl * params["head"]["w"][:, 0], axis=-1)
+    if logit_scale is not None:
+        logit = logit * logit_scale
+    return jax.nn.sigmoid(logit + params["head"]["b"][0])
+
+
+# -- which core runs a part, and how that is said ------------------------------
+
+# What each part last said it runs as (``/debug/sessionz``'s ``head_cores``).
+_ANNOUNCED: dict[str, str] = {}
+
+
+@lru_cache(maxsize=None)
+def announce_core(core: str, backend: str, part: str = "expert core") -> None:
+    """Log, once per (part, core, backend), which core runs a part of the
+    head (``expert core``: the expert layer's grouped products, and where
+    they are the kernels how those are fed; ``combine``: the results' way
+    back to position order; ``attention core``: the window kernel or the
+    einsums, with the kernel's reason where it declines; ``state-space
+    core``: the form the recurrence is computed in): the choice is made at
+    trace time and is otherwise invisible. ``announced_cores`` keeps the
+    last word of each part."""
+    _ANNOUNCED[part] = f"{core} (backend={backend})"
+    logger.info("%s: %s (backend=%s)", part, core, backend)  # noqa: JX01 — deliberately a trace-time log: the core is chosen while tracing, once per compile
+
+
+def announced_cores() -> dict[str, str]:
+    """Part -> the core it last announced, for the steps traced so far in
+    this process (empty before the first trace, and for a head that has
+    no such part)."""
+    return dict(_ANNOUNCED)
+
+
+def kernel_declines(declines=None):
+    """``(why, backend)`` for a part that picks its core while tracing: why
+    its Pallas kernel does not run here (``not a TPU`` off one, else what
+    ``declines()`` says, the kernel module's own predicate: nothing where
+    it takes the shapes at hand) and the backend, for the caller to hand
+    ``announce_core`` with the core it picks. A part with nothing to choose
+    passes no predicate. The only read of the backend among the backbones
+    and these two modules."""
+    backend = jax.default_backend()
+    return ("not a TPU" if backend != "tpu" else declines and declines()), backend

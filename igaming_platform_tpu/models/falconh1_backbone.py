@@ -24,11 +24,11 @@ position-major; ``N`` an RMSNorm with a learned gain):
      channel, position = the event's index; causal softmax of ``q k^T /
      sqrt(head_dim)``, ``heads / kv_heads`` query heads to a key-value
      head; ``A = concat(heads) Wo * attention_out_multiplier``
-     (``lfm2_backbone.attention`` without head norms, with a key scale).
+     (``decoder_parts.attention`` without head norms, with a key scale).
    - state space: ``p = ((u * ssm_in_multiplier) W_in) * m`` with ``m`` the
      five ``ssm_multipliers`` over the column segments ``[z | x | B | C |
      dt]``; ``[x | B | C]`` through a depthwise causal convolution of
-     ``conv_taps`` taps with a bias (``lfm2_backbone.causal_taps``; zero
+     ``conv_taps`` taps with a bias (``decoder_parts.causal_taps``; zero
      before the window's first event), then ``silu``; ``dt = softplus(p_dt
      + dt_bias)``, ``A = -exp(A_log)`` a head. The recurrence a head,
      ``H_t = exp(dt_t A) H_{t-1} + dt_t x_t (x) B_t``, ``y_t = H_t C_t + D
@@ -44,7 +44,7 @@ position-major; ``N`` an RMSNorm with a learned gain):
 
 2. ``r = h + (S + A)``.
 3. ``h' = r + (silu((f Wg) * mlp_multipliers[0]) * (f Wu)) Wd *
-   mlp_multipliers[1]`` with ``f = N_ff(r)`` (``pangu_backbone.swiglu``
+   mlp_multipliers[1]`` with ``f = N_ff(r)`` (``decoder_parts.swiglu``
    with a gate scale).
 
 After the last layer one more RMSNorm. A window's padding (positions past
@@ -60,7 +60,7 @@ step; the recurrent state an account would carry in a decoder is ``32 x
 Precision as the other backbones': parameters bfloat16 at rest (norm
 gains, the convolution's taps and bias, ``A_log``, ``D``, ``dt_bias`` and
 the scoring head float32); the projections and the MLP multiply
-``operand_dtype`` operands and accumulate in float32 (``_mm``), the
+``operand_dtype`` operands and accumulate in float32 (``decoder_parts.mm``), the
 attention core's two einsums too; the state-space core (``dt``, the decay,
 ``G``, the sum over ``s``, ``D``) is float32 at ``Precision.HIGHEST`` on
 operands that are NOT rounded, as the published code keeps it in float32;
@@ -84,17 +84,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from igaming_platform_tpu.models.keye_backbone import (
+from igaming_platform_tpu.models.decoder_parts import (
     Params,
-    _announce_core,
     _matrix,
-    _mm,
-    mrope_angles,
+    announce_core,
+    attention,
+    causal_taps,
+    kernel_declines,
+    mm,
     rms_norm,
+    rope_angles,
     score_last,
+    swiglu,
+    tree_around,
 )
-from igaming_platform_tpu.models.lfm2_backbone import attention, causal_taps
-from igaming_platform_tpu.models.pangu_backbone import swiglu
 
 
 @dataclass(frozen=True)
@@ -164,7 +167,7 @@ def mup_vector(cfg: FalconH1Config):
 
 def init_backbone(key, cfg: FalconH1Config) -> Params:
     """A seeded tree, built on the device one matrix at a time and held in
-    bfloat16 (``keye_backbone._matrix``: a large matrix row block by row
+    bfloat16 (``decoder_parts._matrix``: a large matrix row block by row
     block). The multipliers are applied as published, so every matrix is
     drawn for the multiplier that follows it: it keeps its input's
     variance THROUGH that multiplier (``fan_in ** -0.5`` over the
@@ -217,14 +220,9 @@ def init_backbone(key, cfg: FalconH1Config) -> Params:
                       "wu": matrix((d, w), d),
                       "wd": matrix((w, d), w * out, cfg.mlp_multipliers[1])},
         })
-    return {
-        "embed": matrix((cfg.in_dim, d), cfg.in_dim, cfg.embedding_multiplier),
-        "layers": layers,
-        "gf": jnp.ones((d,), f32),
-        "head": {"w": jax.random.normal(next(keys), (d, 1), f32)
-                 * (1.0 / (math.sqrt(d) * cfg.lm_head_multiplier)),
-                 "b": jnp.zeros((1,), f32)},
-    }
+    return tree_around(
+        layers, matrix((cfg.in_dim, d), cfg.in_dim, cfg.embedding_multiplier),
+        next(keys), d, cfg.lm_head_multiplier)
 
 
 def ssd_one_chunk(x, bm, cm, dt, layer: Params, cfg: FalconH1Config):
@@ -243,8 +241,8 @@ def ssd_one_chunk(x, bm, cm, dt, layer: Params, cfg: FalconH1Config):
             f"a window of {t} positions is longer than one chunk "
             f"(mamba_chunk_size {cfg.chunk}): the dual form over one chunk "
             "holds no state for a second one")
-    _announce_core(f"dual form, one chunk, {t} <= {cfg.chunk}",
-                   jax.default_backend(), "state-space core")
+    announce_core(f"dual form, one chunk, {t} <= {cfg.chunk}",
+                  kernel_declines()[1], "state-space core")
     highest = jax.lax.Precision.HIGHEST
     c = jnp.cumsum(dt * -jnp.exp(layer["a_log"]), axis=1)       # [B, T, H]
     causal = jnp.tril(jnp.ones((t, t), bool))[:, :, None]
@@ -263,7 +261,7 @@ def ssm_mixer(u, layer: Params, cfg: FalconH1Config, window: int):
     t = window
     b = u.shape[0] // t
     with jax.named_scope("in"):
-        p = _mm(u * cfg.ssm_in_multiplier, layer["w_in"], cfg) * mup_vector(cfg)
+        p = mm(u * cfg.ssm_in_multiplier, layer["w_in"], cfg) * mup_vector(cfg)
         z, xbc, dt = p[:, :width], p[:, width:2 * width + 2 * bc], p[:, -nh:]
     with jax.named_scope("conv"):
         xbc = jax.nn.silu(causal_taps(xbc.reshape(b, t, -1), layer["taps"],
@@ -279,7 +277,7 @@ def ssm_mixer(u, layer: Params, cfg: FalconH1Config, window: int):
         g = (y * jax.nn.silu(z)).reshape(b * t, cfg.ssm_groups, -1)
         g = rms_norm(g, layer["gn"].reshape(cfg.ssm_groups, -1), cfg.eps)
     with jax.named_scope("out"):
-        return _mm(g.reshape(b * t, width), layer["w_out"], cfg) * cfg.ssm_out_multiplier
+        return mm(g.reshape(b * t, width), layer["w_out"], cfg) * cfg.ssm_out_multiplier
 
 
 def backbone_hidden(params: Params, x, cfg: FalconH1Config):
@@ -289,10 +287,8 @@ def backbone_hidden(params: Params, x, cfg: FalconH1Config):
     b, t, _ = x.shape
     with jax.named_scope("head/embed"):
         # the residual stream position-major, [P, hidden] with P = B x T
-        h = _mm(x.reshape(b * t, -1), params["embed"], cfg) * cfg.embedding_multiplier
-        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
-        cos, sin = mrope_angles(pos, cfg.head_dim, (cfg.head_dim // 2,),
-                                cfg.rope_theta)
+        h = mm(x.reshape(b * t, -1), params["embed"], cfg) * cfg.embedding_multiplier
+        cos, sin = rope_angles(b, t, cfg.head_dim, cfg.rope_theta)
     for layer in params["layers"]:
         with jax.named_scope("head/ssm"):
             u = rms_norm(h, layer["g1"], cfg.eps)  # both mixers read it

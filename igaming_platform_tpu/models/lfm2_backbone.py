@@ -25,15 +25,16 @@ x T`` position-major; ``N`` an RMSNorm with a learned gain):
      Wk``, ``v = u Wv`` as ``kv_heads``; RMSNorm over each head of ``q``
      and ``k``; rotate-half rotary on every channel, position = the event's
      index; causal softmax of ``q k^T / sqrt(head_dim)``, ``heads /
-     kv_heads`` query heads to a key-value head; ``Op = concat(heads) Wo``.
+     kv_heads`` query heads to a key-value head; ``Op = concat(heads) Wo``
+     (``decoder_parts.attention``, which ``falconh1`` calls too).
 
 2. ``h' = r + FF_l(N_ffn(r))``. For ``l < dense_layers`` a SwiGLU of
-   ``dense_width`` (``pangu_backbone.swiglu``). Else the one sigmoid router
-   both latent-attention and this head call (``pangu_backbone.route``):
+   ``dense_width`` (``decoder_parts.swiglu``). Else the one sigmoid router
+   both ``pangu`` and this head call (``decoder_parts.route``):
    ``s = sigmoid(u Wr)``; ``top_k(s + b)`` CHOOSES, with ``b`` the expert
    bias, and ``s`` of the chosen WEIGHS: ``w = s_sel / (sum s_sel +
    renorm_eps) * routed_scale``; every (position, expert) pair goes through
-   the dropless expert layer (``keye_backbone.grouped_experts``, every
+   the dropless expert layer (``expert_layer.grouped_experts``, every
    expert held: one pass, on a TPU the three Pallas kernels of
    ops/pallas/grouped_experts.py).
 
@@ -67,17 +68,20 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from igaming_platform_tpu.models.keye_backbone import (
+from igaming_platform_tpu.models.decoder_parts import (
     Params,
     _matrix,
-    _mm,
-    grouped_experts,
-    mrope_angles,
+    attention,
+    causal_taps,
+    mm,
     rms_norm,
-    rotate,
+    rope_angles,
+    route,
     score_last,
+    swiglu,
+    tree_around,
 )
-from igaming_platform_tpu.models.pangu_backbone import route, swiglu
+from igaming_platform_tpu.models.expert_layer import grouped_experts
 
 CONV, ATTENTION = "conv", "full_attention"
 
@@ -125,7 +129,7 @@ def layer_kinds(cfg: Lfm2Config) -> dict[str, int]:
 
 def init_backbone(key, cfg: Lfm2Config) -> Params:
     """A seeded tree, built on the device one matrix at a time and held in
-    bfloat16 (``keye_backbone._matrix``: a stacked weight slice by slice, a
+    bfloat16 (``decoder_parts._matrix``: a stacked weight slice by slice, a
     large matrix row block by row block). Every matrix keeps its input's
     variance (``fan_in ** -0.5``; the three taps of a channel ``3 **
     -0.5``); ``w_out``, ``wo`` and the down matrices, which write into the
@@ -167,31 +171,7 @@ def init_backbone(key, cfg: Lfm2Config) -> Params:
             layer["rb"] = jnp.zeros((cfg.experts,), f32)
             layer["routed"] = mlp(f, (cfg.experts,))
         layers.append(layer)
-    return {
-        "embed": matrix((cfg.in_dim, d), cfg.in_dim),
-        "layers": layers,
-        "gf": jnp.ones((d,), f32),
-        "head": {"w": jax.random.normal(next(keys), (d, 1), f32)
-                 * (1.0 / math.sqrt(d)),
-                 "b": jnp.zeros((1,), f32)},
-    }
-
-
-def causal_taps(z, taps, bias=None):
-    """The depthwise causal convolution over the positions of each window:
-    ``z`` [B, T, C] float32, ``taps`` [C, L] -> ``c[b, t] = sum_k taps[:, k]
-    * z[b, t - (L - 1 - k)]``, with ``z`` before a window's first position
-    zero. ``L`` shifted products, no product on the MXU. With ``bias`` [C]
-    (the ``falconh1`` head's convolution has one, models/
-    falconh1_backbone.py) it is added at every position; this head's has
-    none."""
-    n_taps = taps.shape[1]
-    t = z.shape[1]
-    c = z * taps[:, n_taps - 1]
-    for back in range(1, n_taps):
-        earlier = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
-        c = c + earlier * taps[:, n_taps - 1 - back]
-    return c if bias is None else c + bias
+    return tree_around(layers, matrix((cfg.in_dim, d), cfg.in_dim), next(keys), d)
 
 
 def short_conv(u, layer: Params, cfg: Lfm2Config, window: int):
@@ -200,45 +180,14 @@ def short_conv(u, layer: Params, cfg: Lfm2Config, window: int):
     the three thirds of ``u W_in`` in that order."""
     d = cfg.hidden
     with jax.named_scope("in"):
-        bcx = _mm(u, layer["w_in"], cfg)
+        bcx = mm(u, layer["w_in"], cfg)
     with jax.named_scope("gate"):
         z = bcx[:, :d] * bcx[:, 2 * d:]
     with jax.named_scope("taps"):
         c = causal_taps(z.reshape(-1, window, d), layer["taps"]).reshape(-1, d)
         y = bcx[:, d:2 * d] * c
     with jax.named_scope("out"):
-        return _mm(y, layer["w_out"], cfg)
-
-
-def attention(u, layer: Params, cos, sin, cfg, window: int, key_scale=None):
-    """Grouped-query attention over normed hidden states ``u`` [P, hidden]
-    -> [P, hidden]: per-head RMSNorm on q and k where the layer holds their
-    gains (``qn``, ``kn``: this head's layers do, the ``falconh1`` head's
-    have no head norm), one rotary stream on every channel, causal. With
-    ``key_scale`` (a float: that head's ``key_multiplier``) the keys are
-    ``(u Wk) * key_scale``, scaled in float32; this head passes none. Its
-    16-key core runs as two einsums."""
-    nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
-    dt, t = cfg.operand_dtype, window
-    b = u.shape[0] // t
-    q = _mm(u, layer["wq"], cfg).reshape(b, t, nh, hd)
-    k = _mm(u, layer["wk"], cfg).reshape(b, t, nkv, hd)
-    v = _mm(u, layer["wv"], cfg).reshape(b, t, nkv, hd)
-    if key_scale is not None:
-        k = k * key_scale
-    normed = ((lambda x, gain: rms_norm(x, layer[gain], cfg.eps))
-              if "qn" in layer else (lambda x, gain: x))
-    q = rotate(normed(q, "qn"), cos, sin)
-    k = rotate(normed(k, "kn"), cos, sin)
-    # query head j reads key-value head j // (nh // nkv)
-    q = q.reshape(b, t, nkv, nh // nkv, hd)
-    sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),
-                    preferred_element_type=jnp.float32) * (hd ** -0.5)
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-    o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
-                   preferred_element_type=jnp.float32)
-    return _mm(o.reshape(b * t, nh * hd), layer["wo"], cfg)
+        return mm(y, layer["w_out"], cfg)
 
 
 def backbone_hidden(params: Params, x, cfg: Lfm2Config):
@@ -248,10 +197,8 @@ def backbone_hidden(params: Params, x, cfg: Lfm2Config):
     b, t, _ = x.shape
     with jax.named_scope("head/embed"):
         # the residual stream position-major, [P, hidden] with P = B x T
-        h = _mm(x.reshape(b * t, -1), params["embed"], cfg)
-        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
-        cos, sin = mrope_angles(pos, cfg.head_dim, (cfg.head_dim // 2,),
-                                cfg.rope_theta)
+        h = mm(x.reshape(b * t, -1), params["embed"], cfg)
+        cos, sin = rope_angles(b, t, cfg.head_dim, cfg.rope_theta)
     for kind, layer in zip(cfg.layer_types, params["layers"], strict=True):
         with jax.named_scope("head/conv" if kind == CONV else "head/attn"):
             u = rms_norm(h, layer["g1"], cfg.eps)

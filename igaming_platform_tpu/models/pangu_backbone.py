@@ -33,7 +33,7 @@ over ``experts / held_experts`` chips; this chip holds experts
 router whole, as every chip does). The router keeps its published width
 and its experts per token, the weights are normalised over all chosen
 experts, held or not, and the expert layer (the one
-``keye_backbone.grouped_experts`` both heads call) computes the held
+``expert_layer.grouped_experts`` every head with experts calls) computes the held
 experts' part for the pairs routed to them. What the absent experts would
 add is left out, and that partial result goes on to the next layer; no
 code stands in for the absent chips or their traffic. Positions past a
@@ -82,17 +82,21 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from igaming_platform_tpu.models.keye_backbone import (
+from igaming_platform_tpu.models.decoder_parts import (
     Params,
-    _announce_core,
     _matrix,
-    _mm,
-    grouped_experts,
-    mrope_angles,
+    announce_core,
+    kernel_declines,
+    mm,
     rms_norm,
+    rope_angles,
     rotate,
+    route,
     score_last,
+    swiglu,
+    tree_around,
 )
+from igaming_platform_tpu.models.expert_layer import grouped_experts
 
 
 @dataclass(frozen=True)
@@ -123,9 +127,16 @@ class PanguConfig:
     operand_dtype: Any = jnp.bfloat16
 
 
+def layer_kinds(cfg: PanguConfig) -> dict[str, int]:
+    """How many layers of each kind the stack holds: every layer is
+    ``attention``; the leading ones ``dense``, the rest ``moe``."""
+    return {"attention": cfg.layers, "dense": cfg.dense_layers,
+            "moe": cfg.layers - cfg.dense_layers}
+
+
 def init_backbone(key, cfg: PanguConfig) -> Params:
     """A seeded tree, built on the device one matrix at a time and held in
-    bfloat16 (``keye_backbone._matrix``: a stacked weight slice by slice, a
+    bfloat16 (``decoder_parts._matrix``: a stacked weight slice by slice, a
     large matrix row block by row block, so nothing of it exists in
     float32 beyond 64 MB). Every matrix keeps its input's variance
     (``fan_in ** -0.5``); the two post-norm gains of a layer, which scale
@@ -166,14 +177,7 @@ def init_backbone(key, cfg: PanguConfig) -> Params:
             layer["shared"] = swiglu(f)
             layer["routed"] = swiglu(f, (cfg.held_experts,))
         layers.append(layer)
-    return {
-        "embed": matrix((cfg.in_dim, d), cfg.in_dim),
-        "layers": layers,
-        "gf": jnp.ones((d,), f32),
-        "head": {"w": jax.random.normal(next(keys), (d, 1), f32)
-                 * (1.0 / math.sqrt(d)),
-                 "b": jnp.zeros((1,), f32)},
-    }
+    return tree_around(layers, matrix((cfg.in_dim, d), cfg.in_dim), next(keys), d)
 
 
 def _core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
@@ -209,14 +213,13 @@ def _attention_core(q, kvb, cfg: PanguConfig, window: int):
     shapes, and announced once a compile."""
     from igaming_platform_tpu.ops.pallas import window_attention as kernel
 
-    backend = jax.default_backend()
     widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
                   dv=cfg.v_dim, window=window)
-    by_kernel = backend == "tpu" and kernel.supports(q, kvb, **widths)
-    _announce_core("pallas-windows" if by_kernel else "xla-einsum", backend,
-                   "attention core")
+    why, backend = kernel_declines(lambda: not kernel.supports(q, kvb, **widths))
+    announce_core("xla-einsum" if why else "pallas-windows", backend,
+                  "attention core")
     return functools.partial(
-        kernel.window_attention if by_kernel else _core_by_einsums, **widths)
+        _core_by_einsums if why else kernel.window_attention, **widths)
 
 
 def latent_attention(a, layer: Params, cos, sin, cfg: PanguConfig):
@@ -232,54 +235,23 @@ def latent_attention(a, layer: Params, cos, sin, cfg: PanguConfig):
     # position-major from here to the last product: [P, channels], P = B x T
     a = a.reshape(b * t, -1)
     with jax.named_scope("q"):
-        cq = rms_norm(_mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
+        cq = rms_norm(mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
         # heads of [q_nope | q_rope] as the product leaves them: float32,
         # since the rotary part turns before it is rounded
-        q = _mm(cq, layer["wq_b"], cfg)
+        q = mm(cq, layer["wq_b"], cfg)
     with jax.named_scope("kv"):
-        kv = _mm(a, layer["wkv_a"], cfg)
+        kv = mm(a, layer["wkv_a"], cfg)
         ckv = rms_norm(kv[:, :cfg.kv_rank], layer["kvn"], cfg.eps)
         # one rotary key head, shared by every query head
         k_rope = rotate(kv[:, cfg.kv_rank:].reshape(b, t, 1, -1), cos, sin)
         k_rope = k_rope.astype(dt).reshape(b * t, -1)
         # heads of [k_nope | v]: rounded before any other use
-        kvb = _mm(ckv, layer["wkv_b"], cfg).astype(dt)
+        kvb = mm(ckv, layer["wkv_b"], cfg).astype(dt)
     core = _attention_core(q, kvb, cfg, t)
     with jax.named_scope("core"):
         o = core(q, kvb, k_rope, cos.reshape(b * t, -1), sin.reshape(b * t, -1))
     with jax.named_scope("out"):
-        return _mm(o, layer["wo"], cfg).reshape(b, t, -1)
-
-
-def swiglu(x, w: Params, cfg, gate_scale=None):
-    """``(silu(x Wg) * x Wu) Wd``; the product between is rounded once to
-    the operands' dtype, as the expert kernels round theirs. With
-    ``gate_scale`` (a float: the ``falconh1`` head's first MLP multiplier,
-    models/falconh1_backbone.py) the gate is ``silu((x Wg) * gate_scale)``,
-    scaled in float32 before the activation. This head's MLPs have none."""
-    gate = _mm(x, w["wg"], cfg)
-    if gate_scale is not None:
-        gate = gate * gate_scale
-    mid = jax.nn.silu(gate) * _mm(x, w["wu"], cfg)
-    return _mm(mid, w["wd"], cfg)
-
-
-def route(x, layer: Params, cfg):
-    """Sigmoid router over ALL experts, no groups: ``(experts [P, top_k]
-    int32, weights [P, top_k] float32)``, the weights the chosen scores
-    over their sum (held or not) plus ``cfg.renorm_eps``, times
-    ``cfg.routed_scale``. Where the layer has an expert bias (``rb``
-    [experts] float32: the ``lfm2`` head's, models/lfm2_backbone.py) it is
-    added to the scores that ``top_k`` reads and to nothing else: the bias
-    chooses and does not weigh. This head's layers have none."""
-    s = jax.nn.sigmoid(_mm(x, layer["wr"], cfg))
-    if "rb" in layer:
-        _, top_e = jax.lax.top_k(s + layer["rb"], cfg.top_k)
-        top_s = jnp.take_along_axis(s, top_e, axis=-1)
-    else:
-        top_s, top_e = jax.lax.top_k(s, cfg.top_k)
-    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
-    return top_e, w * cfg.routed_scale
+        return mm(o, layer["wo"], cfg).reshape(b, t, -1)
 
 
 def backbone_hidden(params: Params, x, lengths, cfg: PanguConfig):
@@ -294,10 +266,8 @@ def backbone_hidden(params: Params, x, lengths, cfg: PanguConfig):
     with jax.named_scope("head/embed"):
         # the residual stream position-major, [P, hidden] with P = B x T,
         # from here to the final norm: every product reads and writes it so
-        h = _mm(x.reshape(b * t, -1), params["embed"], cfg)
-        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
-        cos, sin = mrope_angles(pos, cfg.rope_dim, (cfg.rope_dim // 2,),
-                                cfg.rope_theta)
+        h = mm(x.reshape(b * t, -1), params["embed"], cfg)
+        cos, sin = rope_angles(b, t, cfg.rope_dim, cfg.rope_theta)
     for layer in params["layers"]:
         with jax.named_scope("head/attn"):
             a = rms_norm(h, layer["g1"], cfg.eps).reshape(b, t, -1)

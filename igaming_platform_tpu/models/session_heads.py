@@ -1,28 +1,25 @@
 """Session heads: the models the fused session step runs over an
 account's post-append event window.
 
-``HEADS`` maps a ``SESSION_HEAD`` name to ``head_fn(params, window
-[B, N, D], lengths [B]) -> [B] prob`` (jittable) and ``init_params()``,
-the pinned seeded tree replay rebuilds without a checkpoint. The program
-(serve/index_program.py) takes ``head_fn`` as an argument and the
-parameters as a traced tree: a new head is a function, an init and a row.
+``HEADS`` is the one table: a ``SESSION_HEAD`` name -> its ``Head`` row.
+The program (serve/index_program.py) takes the row's ``scores`` as an
+argument and the parameters as a traced tree; the server's gauges
+(serve/session_state.py) read the rest of the row. A new backbone is a
+module with the backbones' surface (``init_backbone(key, cfg)``,
+``backbone_scores(params, window, lengths, cfg)``, ``layer_kinds(cfg)``)
+and one row, ``_backbone(module, cfg)``.
 """
 
 from __future__ import annotations
 
+from collections.abc import Callable, Mapping
+from dataclasses import dataclass, field
+from typing import Any
+
 import jax
 import jax.numpy as jnp
 
-from igaming_platform_tpu.models import (
-    falconh1_backbone,
-    lfm2_backbone,
-    pangu_backbone,
-)
-from igaming_platform_tpu.models.keye_backbone import (
-    BackboneConfig,
-    backbone_scores,
-    init_backbone,
-)
+from igaming_platform_tpu.models import falconh1_backbone, keye_backbone, lfm2_backbone, pangu_backbone
 from igaming_platform_tpu.models.sequence import (
     EVENT_DIM,
     SeqConfig,
@@ -106,132 +103,82 @@ def transformer_scores(sparams, window, lengths):
     return sequence_forward(sparams, window, SESSION_SEQ_CONFIG)["abuse"]
 
 
-# SESSION_HEAD=keye: four decoder layers of a sparse-expert backbone at its
-# published widths (models/keye_backbone.py): 2.50 G parameters, 5.0 GB in
-# bfloat16 beside the state. The server holds this tree once.
-KEYE_CONFIG = BackboneConfig()
-
-
-def init_keye_params(seed: int = _SESSION_HEAD_SEED):
-    """The pinned seeded tree of the ``keye`` head, built on the device
-    in bfloat16, a matrix at a time."""
-    return init_backbone(jax.random.key(seed), KEYE_CONFIG)
-
-
-def keye_scores(sparams, window, lengths):
-    """The ``keye`` head: the backbone over the window, scored at the
-    last real position."""
-    return backbone_scores(sparams, window, lengths, KEYE_CONFIG)
-
-
-# SESSION_HEAD=pangu: one dense and four expert layers of a latent-attention
-# backbone at its published widths, with a chip's share of the routed
-# experts (models/pangu_backbone.py: 8 of 256 held, all 256 routed over):
-# 3.11 G parameters, 6.23 GB in bfloat16 beside the state.
-PANGU_CONFIG = pangu_backbone.PanguConfig()
-
-
-def init_pangu_params(seed: int = _SESSION_HEAD_SEED):
-    """The pinned seeded tree of the ``pangu`` head, built on the device
-    in bfloat16, a matrix (or a block of one) at a time."""
-    return pangu_backbone.init_backbone(jax.random.key(seed), PANGU_CONFIG)
-
-
-def pangu_scores(sparams, window, lengths):
-    """The ``pangu`` head: the backbone over the window, scored at the
-    last real position."""
-    return pangu_backbone.backbone_scores(sparams, window, lengths, PANGU_CONFIG)
-
-
-# SESSION_HEAD=lfm2: a short-convolution hybrid at its published widths
-# (models/lfm2_backbone.py): the source's layer 0 and one whole period after
-# its leading dense layers (four gated short convolutions and one
-# grouped-query attention layer by ``layer_types``; one dense MLP, four
-# expert layers of 64 sigmoid-routed experts chosen with an expert bias,
-# every one held): 2.57 G parameters, 5.13 GB in bfloat16 beside the state.
-LFM2_CONFIG = lfm2_backbone.Lfm2Config()
-
-
-def init_lfm2_params(seed: int = _SESSION_HEAD_SEED):
-    """The pinned seeded tree of the ``lfm2`` head, built on the device in
-    bfloat16, a matrix (or a block of one) at a time."""
-    return lfm2_backbone.init_backbone(jax.random.key(seed), LFM2_CONFIG)
-
-
-def lfm2_scores(sparams, window, lengths):
-    """The ``lfm2`` head: the backbone over the window, scored at the last
-    real position."""
-    return lfm2_backbone.backbone_scores(sparams, window, lengths, LFM2_CONFIG)
-
-
-# SESSION_HEAD=falconh1: four layers of a state-space hybrid at its
-# published widths (models/falconh1_backbone.py): in every layer a Mamba-2
-# mixer (32 heads of 128, a state of 256) and grouped-query attention (20 /
-# 4 heads of 128) on one normed input, both added to the stream, then a
-# dense SwiGLU of 21,504; the model's muP multipliers on the branches. No
-# expert layer: 1.72 G parameters, 3.44 GB in bfloat16 beside the state.
-FALCONH1_CONFIG = falconh1_backbone.FalconH1Config()
-
-
-def init_falconh1_params(seed: int = _SESSION_HEAD_SEED):
-    """The pinned seeded tree of the ``falconh1`` head, built on the device
-    in bfloat16, a matrix (or a block of one) at a time."""
-    return falconh1_backbone.init_backbone(jax.random.key(seed), FALCONH1_CONFIG)
-
-
-def falconh1_scores(sparams, window, lengths):
-    """The ``falconh1`` head: the backbone over the window, scored at the
-    last real position."""
-    return falconh1_backbone.backbone_scores(sparams, window, lengths,
-                                             FALCONH1_CONFIG)
-
-
-# SESSION_HEAD name -> (head_fn(sparams, window, lengths), init_params()).
-HEADS = {
-    "pattern": (lambda sparams, win, lp: pattern_scores(win, lp),
-                lambda: None),
-    "transformer": (transformer_scores, init_session_head_params),
-    "keye": (keye_scores, init_keye_params),
-    "pangu": (pangu_scores, init_pangu_params),
-    "lfm2": (lfm2_scores, init_lfm2_params),
-    "falconh1": (falconh1_scores, init_falconh1_params),
-}
-
-# SESSION_HEAD name -> (routed experts a layer held on this chip, experts
-# its router chooses among); a head without an expert layer has no row.
-HEAD_EXPERTS = {
-    "keye": (KEYE_CONFIG.experts, KEYE_CONFIG.experts),
-    "pangu": (PANGU_CONFIG.held_experts, PANGU_CONFIG.experts),
-    "lfm2": (LFM2_CONFIG.experts, LFM2_CONFIG.experts),
-}
-
 # What a layer's operators (``conv``, ``attention``, ``ssm``) and its
-# feed-forward (``dense``, ``moe``) may be: the kinds ``HEAD_LAYERS`` counts.
-# A layer that runs two operators (``falconh1``: ``ssm`` beside
+# feed-forward (``dense``, ``moe``) may be: the kinds a row's ``layers``
+# counts. A layer that runs two operators (``falconh1``: ``ssm`` beside
 # ``attention``) counts under both.
 LAYER_KINDS = ("conv", "attention", "ssm", "dense", "moe")
+_NO_LAYERS = dict.fromkeys(LAYER_KINDS, 0)
 
-# SESSION_HEAD name -> layers of each kind in its stack (a kind that is
-# left out has none; the ``pattern`` head has no layer at all).
-HEAD_LAYERS = {
-    "pattern": {},
-    "transformer": {"attention": SESSION_SEQ_CONFIG.n_layers,
-                    "dense": SESSION_SEQ_CONFIG.n_layers},
-    "keye": {"attention": KEYE_CONFIG.layers, "moe": KEYE_CONFIG.layers},
-    "pangu": {"attention": PANGU_CONFIG.layers,
-              "dense": PANGU_CONFIG.dense_layers,
-              "moe": PANGU_CONFIG.layers - PANGU_CONFIG.dense_layers},
-    "lfm2": lfm2_backbone.layer_kinds(LFM2_CONFIG),
-    "falconh1": falconh1_backbone.layer_kinds(FALCONH1_CONFIG),
+
+@dataclass(frozen=True)
+class Head:
+    """One session head: everything the program and the server's gauges
+    know of it."""
+
+    scores: Callable  # (sparams, window [B, N, D], lengths [B]) -> [B] prob
+    init: Callable[[], Any]  # the pinned seeded tree (nothing: paramless)
+    config: Any = None  # the sizes it is built at
+    # (routed experts a layer held on this chip, experts its router chooses
+    # among); a head without an expert layer holds and routes none
+    experts: tuple[int, int] = (0, 0)
+    # layers of each kind in its stack, over exactly LAYER_KINDS (the
+    # ``pattern`` head has no layer at all)
+    layers: Mapping[str, int] = field(default_factory=_NO_LAYERS.copy)
+
+
+def _backbone(module, cfg) -> Head:
+    """The row of a backbone at the sizes ``cfg``, off its module's surface:
+    scored at the last real position, its pinned seeded tree built on the
+    device in bfloat16, the experts it holds (``held_experts`` where that is
+    a chip's share, else all of ``experts``, none without) and routes over."""
+    routed = getattr(cfg, "experts", 0)
+    return Head(
+        scores=lambda sparams, window, lengths: module.backbone_scores(
+            sparams, window, lengths, cfg),
+        init=lambda: module.init_backbone(
+            jax.random.key(_SESSION_HEAD_SEED), cfg),
+        config=cfg,
+        experts=(getattr(cfg, "held_experts", routed), routed),
+        layers=_NO_LAYERS | module.layer_kinds(cfg))
+
+
+HEADS = {
+    "pattern": Head(lambda sparams, win, lp: pattern_scores(win, lp),
+                    lambda: None),
+    "transformer": Head(transformer_scores, init_session_head_params,
+                        SESSION_SEQ_CONFIG,
+                        layers=_NO_LAYERS | {
+                            "attention": SESSION_SEQ_CONFIG.n_layers,
+                            "dense": SESSION_SEQ_CONFIG.n_layers}),
+    # four decoder layers of a sparse-expert backbone at its published
+    # widths: 2.50 G parameters, 5.0 GB in bfloat16 beside the state (the
+    # server holds each tree once)
+    "keye": _backbone(keye_backbone, keye_backbone.BackboneConfig()),
+    # one dense and four expert layers of a latent-attention backbone at its
+    # published widths, with a chip's share of the routed experts (8 of 256
+    # held, all 256 routed over): 3.11 G parameters, 6.23 GB
+    "pangu": _backbone(pangu_backbone, pangu_backbone.PanguConfig()),
+    # a short-convolution hybrid at its published widths: the source's layer
+    # 0 and one whole period after its leading dense layers (four gated short
+    # convolutions and one grouped-query attention layer by ``layer_types``;
+    # one dense MLP, four expert layers of 64 sigmoid-routed experts chosen
+    # with an expert bias, every one held): 2.57 G parameters, 5.13 GB
+    "lfm2": _backbone(lfm2_backbone, lfm2_backbone.Lfm2Config()),
+    # four layers of a state-space hybrid at its published widths: in every
+    # layer a Mamba-2 mixer (32 heads of 128, a state of 256) and
+    # grouped-query attention (20 / 4 heads of 128) on one normed input, both
+    # added to the stream, then a dense SwiGLU of 21,504; the model's muP
+    # multipliers on the branches. No expert layer: 1.72 G parameters, 3.44 GB
+    "falconh1": _backbone(falconh1_backbone, falconh1_backbone.FalconH1Config()),
 }
 
 
-def session_head(name: str):
-    """``SESSION_HEAD`` name -> (head_fn, params)."""
+def session_head(name: str) -> Head:
+    """``SESSION_HEAD`` name -> its row of ``HEADS``."""
     try:
-        head_fn, init = HEADS[name]
+        return HEADS[name]
     except KeyError:
         raise ValueError(
             f"SESSION_HEAD={name!r} not supported "
             f"(use one of {sorted(HEADS)})") from None
-    return head_fn, init()

@@ -1,7 +1,7 @@
 """Pallas TPU kernels: the grouped products of a dropless expert layer,
 and its results' way back to position order.
 
-``models/keye_backbone.grouped_experts`` sorts its (position, expert)
+``models/expert_layer.grouped_experts`` sorts its (position, expert)
 pairs by expert, so each expert's rows are contiguous in ``xs`` [M,
 hidden] and ``sizes`` [E] says how many each holds. The XLA path runs the
 layer as three ``lax.ragged_dot`` products with two float32 [M, width]
@@ -514,7 +514,7 @@ def first_visits_that_wait(sizes, slots: int, fetch_us: float,
 _COMBINE_TILE = 64
 
 # What the results of a share's pass may take of VMEM, where they stay for
-# the whole call (``models/keye_backbone.pass_rows`` bounds a pass by it).
+# the whole call (``models/expert_layer.pass_rows`` bounds a pass by it).
 HELD_RESULTS_BYTES = 64 * 2**20
 
 

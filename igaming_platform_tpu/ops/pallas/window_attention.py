@@ -27,7 +27,7 @@ widths, 0.22 ms at a v5e's peak); the bytes are one read of ``q`` and
 ``kv`` and one write of the result.
 
 **The rotary part is applied here**, to each head's ``q_rope`` in float32
-before its one rounding (``keye_backbone.rotate``'s arithmetic: pair ``i``
+before its one rounding (``decoder_parts.rotate``'s arithmetic: pair ``i``
 is channels ``i`` and ``i + rope / 2``), so the float32 ``q`` is read once,
 by this kernel, and no rotated copy of it is written. ``k_rope`` comes
 rotated (it is [P, rope]: 0.5 MB).

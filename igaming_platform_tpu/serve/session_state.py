@@ -58,14 +58,9 @@ from typing import Any
 
 import numpy as np
 
-from igaming_platform_tpu.models.keye_backbone import announced_cores
+from igaming_platform_tpu.models.decoder_parts import announced_cores
 from igaming_platform_tpu.models.sequence import EVENT_DIM
-from igaming_platform_tpu.models.session_heads import (
-    HEAD_EXPERTS,
-    HEAD_LAYERS,
-    LAYER_KINDS,
-    session_head,
-)
+from igaming_platform_tpu.models.session_heads import session_head
 
 # Per-event layout: models/sequence.encode_event — [log-amount, log-dt,
 # 8-way tx-type one-hot, game-weight, balance-ratio].
@@ -480,15 +475,15 @@ class SessionStateManager:
             flag_threshold if flag_threshold is not None
             else default_flag_threshold())
         self.head = (head or os.environ.get("SESSION_HEAD", "pattern")).lower()
-        self.head_fn, self.head_params = session_head(self.head)
+        row = session_head(self.head)
+        self.head_fn, self.head_params = row.scores, row.init()
         # what the head holds, fixed at boot: its tree's device bytes and,
         # where it has an expert layer, (experts held here, experts routed)
         self.head_resident_bytes = sum(
             int(a.nbytes) for a in jax.tree.leaves(self.head_params))
-        self.head_experts = HEAD_EXPERTS.get(self.head, (0, 0))
+        self.head_experts = row.experts
         # what the stack is made of: layers by kind, a kind it lacks at 0
-        layers = HEAD_LAYERS.get(self.head, {})
-        self.head_layers = {kind: layers.get(kind, 0) for kind in LAYER_KINDS}
+        self.head_layers = dict(row.layers)
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}

@@ -112,7 +112,7 @@ def _lower_step(head, capacity, ring_rows, state, repl, *, mesh=None,
     from igaming_platform_tpu.serve import session_state as ss
 
     cfg = ScoringConfig()
-    head_fn, init = HEADS[head]
+    head_fn, init = HEADS[head].scores, HEADS[head].init
     head_params = None if head == "pattern" else init
     step = index_program.build(
         make_score_fn(cfg, "multitask", mesh=mesh), cfg, family="session",
@@ -302,12 +302,13 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     ``[256, 128, 16, 16]`` is left in the step."""
     from jax.sharding import SingleDeviceSharding
 
-    from igaming_platform_tpu.models.keye_backbone import pass_rows
-    from igaming_platform_tpu.models.session_heads import PANGU_CONFIG as cfg
+    from igaming_platform_tpu.models.expert_layer import pass_rows
+    from igaming_platform_tpu.models.session_heads import HEADS
     from igaming_platform_tpu.serve import session_state as ss
 
     capacity = 3_145_728
     one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["pangu"].config
     compiled = _compile_step("pangu", capacity, capacity + 1, one, one)
     ring = ss.ring_size(capacity + 1, ss.default_events())
     mem = compiled.memory_analysis()
@@ -363,11 +364,12 @@ def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
     and temporaries are printed."""
     from jax.sharding import SingleDeviceSharding
 
-    from igaming_platform_tpu.models.session_heads import LFM2_CONFIG as cfg
+    from igaming_platform_tpu.models.session_heads import HEADS
     from igaming_platform_tpu.serve import session_state as ss
 
     capacity = 5_242_880
     one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["lfm2"].config
     compiled = _compile_step("lfm2", capacity, capacity + 1, one, one)
     ring = ss.ring_size(capacity + 1, ss.default_events())
     mem = compiled.memory_analysis()
@@ -408,11 +410,12 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
     printed."""
     from jax.sharding import SingleDeviceSharding
 
-    from igaming_platform_tpu.models.session_heads import FALCONH1_CONFIG as cfg
+    from igaming_platform_tpu.models.session_heads import HEADS
     from igaming_platform_tpu.serve import session_state as ss
 
     capacity = 5_242_880
     one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["falconh1"].config
     compiled = _compile_step("falconh1", capacity, capacity + 1, one, one)
     ring = ss.ring_size(capacity + 1, ss.default_events())
     mem = compiled.memory_analysis()

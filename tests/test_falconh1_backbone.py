@@ -13,6 +13,7 @@ its dual form over one chunk, the reference runs the recurrence.
 
 from __future__ import annotations
 
+import dataclasses
 import copy
 import hashlib
 import json
@@ -27,6 +28,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from chipbench import harness, reference, validate  # noqa: E402
+from igaming_platform_tpu.models import decoder_parts as dp  # noqa: E402
 from igaming_platform_tpu.models import falconh1_backbone as fb  # noqa: E402
 from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
 from igaming_platform_tpu.models import lfm2_backbone as lb  # noqa: E402
@@ -229,13 +231,13 @@ def test_tree_of_the_reference_is_the_programs(head, tree):
             assert layer[name].dtype == jnp.bfloat16, name
         assert "qn" not in layer and "wr" not in layer  # no head norm, no router
     published = validate.load_data("configs", CONFIG)
-    assert program_config(published) == session_heads.FALCONH1_CONFIG
-    c = session_heads.FALCONH1_CONFIG
+    assert program_config(published) == session_heads.HEADS["falconh1"].config
+    c = session_heads.HEADS["falconh1"].config
     assert c.init_depth == published["head"]["published"]["num_hidden_layers"] == 72
     assert c.segments == (4096, 4096, 512, 512, 32) and sum(c.segments) == 9248
     d = head.dims_of(published)
     assert d.segments == c.segments and d.chunk == c.chunk == 128
-    full = jax.eval_shape(session_heads.init_falconh1_params)
+    full = jax.eval_shape(session_heads.HEADS["falconh1"].init)
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(full))
     assert n == 1_720_551_809
     nbytes = sum(a.dtype.itemsize * int(np.prod(a.shape))
@@ -277,7 +279,7 @@ def test_the_seeded_trees_keep_the_streams_scale_through_the_multipliers(tree):
         np.testing.assert_array_equal(np.asarray(layer["d_skip"]), 1.0)
     # the stream a layer adds to is of unit scale, not the multiplier's 5.66
     x, _ = windows(8, (16,))
-    h0 = np.asarray(kb._mm(jnp.asarray(x).reshape(-1, 12), mine["embed"], cfg)
+    h0 = np.asarray(dp.mm(jnp.asarray(x).reshape(-1, 12), mine["embed"], cfg)
                     * cfg.embedding_multiplier)
     assert 0.5 < h0.std() < 2.0
 
@@ -394,11 +396,11 @@ def test_convolution_with_bias_and_silu_equals_an_explicit_loop(taps):
     w = jax.random.normal(ks[1], (24, taps)) * 0.5
     bias = jax.random.normal(ks[2], (24,)) * 0.25
     got = np.asarray(jax.jit(
-        lambda z: jax.nn.silu(lb.causal_taps(z, w, bias)))(z))
+        lambda z: jax.nn.silu(dp.causal_taps(z, w, bias)))(z))
     want = _conv_loop(z, w, bias)
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
     # the bias is read, at every position
-    bare = np.asarray(jax.jit(lambda z: jax.nn.silu(lb.causal_taps(z, w)))(z))
+    bare = np.asarray(jax.jit(lambda z: jax.nn.silu(dp.causal_taps(z, w)))(z))
     assert (np.abs(bare - got).max(axis=(0, 2)) > 0.01).all()
 
 
@@ -443,24 +445,24 @@ def test_each_branch_and_the_mlp_alone(head, tree, operands, part):
     x = stream(seed=5)
     rows, t, hid = x.shape
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, rows, t))
-    cos, sin = kb.mrope_angles(pos, cfg.head_dim, (cfg.head_dim // 2,),
+    cos, sin = dp.mrope_angles(pos, cfg.head_dim, (cfg.head_dim // 2,),
                                cfg.rope_theta)
 
     def branch(kind, u, cos, sin):
         if kind == "ssm":
             return fb.ssm_mixer(u, layer, cfg, t)
-        return (lb.attention(u * cfg.attention_in_multiplier, layer, cos, sin,
+        return (dp.attention(u * cfg.attention_in_multiplier, layer, cos, sin,
                              cfg, t, key_scale=cfg.key_multiplier)
                 * cfg.attention_out_multiplier)
 
     def sublayer(x, cos, sin, kinds=(part,)):
         h = x.reshape(rows * t, hid)
         if part == "mlp":
-            f = kb.rms_norm(h, layer["g2"], cfg.eps)
-            o = (pb.swiglu(f, layer["dense"], cfg, cfg.mlp_multipliers[0])
+            f = dp.rms_norm(h, layer["g2"], cfg.eps)
+            o = (dp.swiglu(f, layer["dense"], cfg, cfg.mlp_multipliers[0])
                  * cfg.mlp_multipliers[1])
         else:
-            u = kb.rms_norm(h, layer["g1"], cfg.eps)
+            u = dp.rms_norm(h, layer["g1"], cfg.eps)
             o = sum(branch(kind, u, cos, sin) for kind in kinds)
         return (h + o).reshape(x.shape)
 
@@ -541,7 +543,7 @@ def test_a_multiplier_missing_from_the_configuration_is_refused(head, name):
 
 
 def test_the_multipliers_are_the_published_ones_and_a_short_list_is_refused(head):
-    c = session_heads.FALCONH1_CONFIG
+    c = session_heads.HEADS["falconh1"].config
     for name, index in MULTIPLIERS:
         mine = getattr(c, name)
         assert (mine if index is None else mine[index]) == (
@@ -567,8 +569,8 @@ def _body(fn, *args) -> str:
 
 
 def _swiglu_of_the_parent(x, w, cfg):
-    mid = jax.nn.silu(kb._mm(x, w["wg"], cfg)) * kb._mm(x, w["wu"], cfg)
-    return kb._mm(mid, w["wd"], cfg)
+    mid = jax.nn.silu(dp.mm(x, w["wg"], cfg)) * dp.mm(x, w["wu"], cfg)
+    return dp.mm(mid, w["wd"], cfg)
 
 
 def _taps_of_the_parent(z, taps):
@@ -585,11 +587,11 @@ def _attention_of_the_parent(u, layer, cos, sin, cfg, window):
     nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
     dt, t = cfg.operand_dtype, window
     b = u.shape[0] // t
-    q = kb._mm(u, layer["wq"], cfg).reshape(b, t, nh, hd)
-    k = kb._mm(u, layer["wk"], cfg).reshape(b, t, nkv, hd)
-    v = kb._mm(u, layer["wv"], cfg).reshape(b, t, nkv, hd)
-    q = kb.rotate(kb.rms_norm(q, layer["qn"], cfg.eps), cos, sin)
-    k = kb.rotate(kb.rms_norm(k, layer["kn"], cfg.eps), cos, sin)
+    q = dp.mm(u, layer["wq"], cfg).reshape(b, t, nh, hd)
+    k = dp.mm(u, layer["wk"], cfg).reshape(b, t, nkv, hd)
+    v = dp.mm(u, layer["wv"], cfg).reshape(b, t, nkv, hd)
+    q = dp.rotate(dp.rms_norm(q, layer["qn"], cfg.eps), cos, sin)
+    k = dp.rotate(dp.rms_norm(k, layer["kn"], cfg.eps), cos, sin)
     q = q.reshape(b, t, nkv, nh // nkv, hd)
     sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt), k.astype(dt),
                     preferred_element_type=jnp.float32) * (hd ** -0.5)
@@ -597,7 +599,7 @@ def _attention_of_the_parent(u, layer, cos, sin, cfg, window):
     p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
     o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
                    preferred_element_type=jnp.float32)
-    return kb._mm(o.reshape(b * t, nh * hd), layer["wo"], cfg)
+    return dp.mm(o.reshape(b * t, nh * hd), layer["wo"], cfg)
 
 
 def _score_of_the_parent(params, hid, lengths):
@@ -627,13 +629,13 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
                              operand_dtype=dt)
         w = pb.init_backbone(jax.random.key(1), cfg)["layers"][0]["dense"]
         args = (jax.random.normal(jax.random.key(2), (48, 64), jnp.float32),)
-        now = jax.jit(lambda x: pb.swiglu(x, w, cfg))
+        now = jax.jit(lambda x: dp.swiglu(x, w, cfg))
         then = jax.jit(lambda x: _swiglu_of_the_parent(x, w, cfg))
     elif shared == "causal_taps":
         taps = jax.random.normal(jax.random.key(1), (32, 3))
         args = (jax.random.normal(jax.random.key(2), (4, 16, 32)).astype(dt)
                 .astype(jnp.float32),)
-        now = jax.jit(lambda z: lb.causal_taps(z, taps))
+        now = jax.jit(lambda z: dp.causal_taps(z, taps))
         then = jax.jit(lambda z: _taps_of_the_parent(z, taps))
     elif shared == "attention":
         cfg = lb.Lfm2Config(hidden=128, layer_types=("full_attention",),
@@ -642,10 +644,10 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
         layer = lb.init_backbone(jax.random.key(1), cfg)["layers"][0]
         assert "qn" in layer
         pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (1, 3, 16))
-        cos, sin = kb.mrope_angles(pos, 32, (16,), cfg.rope_theta)
+        cos, sin = dp.mrope_angles(pos, 32, (16,), cfg.rope_theta)
         args = (jax.random.normal(jax.random.key(2), (48, 128), jnp.float32),
                 cos, sin)
-        now = jax.jit(lambda u, c, s: lb.attention(u, layer, c, s, cfg, 16))
+        now = jax.jit(lambda u, c, s: dp.attention(u, layer, c, s, cfg, 16))
         then = jax.jit(lambda u, c, s: _attention_of_the_parent(u, layer, c, s,
                                                                cfg, 16))
     else:
@@ -653,14 +655,40 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
                            "b": jnp.full((1,), 0.3)}}
         args = (jax.random.normal(jax.random.key(2), (5, 16, 64)).astype(dt)
                 .astype(jnp.float32), jnp.array([1, 4, 16, 9, 0]))
-        now = jax.jit(lambda h, l: kb.score_last(params, h, l))
+        now = jax.jit(lambda h, l: dp.score_last(params, h, l))
         then = jax.jit(lambda h, l: _score_of_the_parent(params, h, l))
     np.testing.assert_array_equal(np.asarray(now(*args)), np.asarray(then(*args)))
     assert _body(now, *args) == _body(then, *args)
 
 
+@pytest.mark.parametrize("head_scale", [None, fb.FalconH1Config().lm_head_multiplier])
+def test_a_trees_ends_and_angles_are_what_each_backbone_wrote_out(head_scale):
+    """``decoder_parts.tree_around`` and ``rope_angles`` (PR 48: what all
+    four ``init_backbone``s, and three ``backbone_hidden``s, wrote out alike
+    got one owner) give the parent commit's leaves and angles bit for bit:
+    the scoring column at ``hidden ** -0.5``, over ``falconh1``'s
+    ``lm_head_multiplier`` where it is given, from the same key."""
+    f32, d, key = jnp.float32, 64, jax.random.key(5)
+    embed, layers = jnp.ones((12, d), jnp.bfloat16), [{"g1": jnp.ones((d,), f32)}]
+    over = {} if head_scale is None else {"head_scale": head_scale}
+    then = {"embed": embed, "layers": layers, "gf": jnp.ones((d,), f32),
+            "head": {"w": jax.random.normal(key, (d, 1), f32)
+                     * (1.0 / (np.sqrt(d) * (head_scale or 1.0))),
+                     "b": jnp.zeros((1,), f32)}}
+    now = dp.tree_around(layers, embed, key, d, **over)
+    assert jax.tree.structure(now) == jax.tree.structure(then)
+    for a, b in zip(jax.tree.leaves(now), jax.tree.leaves(then), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(f32)), np.asarray(b.astype(f32)))
+    pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (1, 3, 16))
+    for a, b in zip(dp.rope_angles(3, 16, 32, 1e6),
+                    dp.mrope_angles(pos, 32, (16,), 1e6), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("name,sha256", [
-    ("keye", "97b106f2039253a0"), ("pangu", "3b1ad6dc001c49cb"), ("lfm2", "619bcfbac3323ea6")])
+    ("keye", "97b106f2039253a0"), ("pangu", "3b1ad6dc001c49cb"), ("lfm2", "619bcfbac3323ea6"),
+    ("falconh1", "512502d3a3848a1e")])
 def test_the_other_backbones_lower_to_the_parents_stablehlo(name, sha256):
     """The three backbones that share the widened functions, each at a small
     size of its own kinds of layer: the StableHLO of ``backbone_scores`` is
@@ -669,7 +697,10 @@ def test_the_other_backbones_lower_to_the_parents_stablehlo(name, sha256):
     says so and replaces the digest). PR 47 meant to change ``keye``'s: its
     stream is [P, hidden] and its core the einsums of ``_core_by_einsums``
     (ec4e13b382434280 before; the scores' bits are held to the 3-D stream's
-    in tests/test_keye_backbone.py); ``pangu``'s and ``lfm2``'s stand."""
+    in tests/test_keye_backbone.py); ``pangu``'s and ``lfm2``'s stand. PR 48,
+    which moved the shared parts into modules no model owns, meant to
+    change none: ``falconh1``'s was added at its parent (834d8d8), so all
+    four are pinned across the move."""
     f32 = jnp.float32
     if name == "keye":
         cfg = kb.BackboneConfig(hidden=128, layers=2, heads=4, kv_heads=2,
@@ -683,6 +714,9 @@ def test_the_other_backbones_lower_to_the_parents_stablehlo(name, sha256):
                              v_dim=16, dense_width=96, experts=16,
                              held_experts=4, top_k=4, expert_width=32)
         make, scores = pb.init_backbone, pb.backbone_scores
+    elif name == "falconh1":
+        cfg = small_config()
+        make, scores = fb.init_backbone, fb.backbone_scores
     else:
         cfg = lb.Lfm2Config(hidden=128, layer_types=("conv", "full_attention",
                                                      "conv"),
@@ -704,9 +738,10 @@ def small_falconh1(monkeypatch):
     """``SESSION_HEAD=falconh1`` at the small size: the row of ``HEADS`` is
     steered here, in the test; the program has no option for it."""
     cfg = small_config()
-    monkeypatch.setitem(session_heads.HEADS, "falconh1", (
-        lambda sp, win, lp: fb.backbone_scores(sp, win, lp, cfg),
-        lambda: fb.init_backbone(jax.random.key(11), cfg)))
+    monkeypatch.setitem(session_heads.HEADS, "falconh1", dataclasses.replace(
+        session_heads.HEADS["falconh1"],
+        scores=lambda sp, win, lp: fb.backbone_scores(sp, win, lp, cfg),
+        init=lambda: fb.init_backbone(jax.random.key(11), cfg)))
     return cfg
 
 
@@ -735,7 +770,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     spec["config"].update(small)
     spec["config"]["env"]["FEATURE_STORE"] = "python"
     # each core is announced once a process: let this step's trace say it anew
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     run = harness.Run(spec, seed=4_500_000_007, seconds=1.0, trace=False,
                       rehearse=True)
     run.boot()
@@ -768,7 +803,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert snap["head_resident_bytes"] == resident > 0
     # no expert layer: nothing held, nothing routed
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (0, 0)
-    assert "falconh1" not in session_heads.HEAD_EXPERTS
+    assert session_heads.HEADS["falconh1"].experts == (0, 0)
     assert snap["head_layers"] == LAYERS
     # which form the state-space core said it runs when the step was traced
     assert snap["head_cores"]["state-space core"] == (
@@ -835,11 +870,12 @@ def test_layer_gauge_takes_the_new_kind(name, layers, monkeypatch):
     from igaming_platform_tpu.obs.metrics import ServiceMetrics
     from igaming_platform_tpu.serve.session_state import SessionStateManager
 
-    assert session_heads.HEAD_LAYERS[name] == layers
+    assert session_heads.HEADS[name].layers == {
+        kind: layers.get(kind, 0) for kind in session_heads.LAYER_KINDS}
     assert "ssm" in session_heads.LAYER_KINDS
     if name == "falconh1":  # the gauge, not a 3.44 GB tree
-        monkeypatch.setitem(session_heads.HEADS, name,
-                            (session_heads.HEADS[name][0], lambda: None))
+        monkeypatch.setitem(session_heads.HEADS, name, dataclasses.replace(
+            session_heads.HEADS[name], init=lambda: None))
     metrics = ServiceMetrics("risk")
     mgr = SessionStateManager(8, head=name, metrics=metrics)
     want = {kind: layers.get(kind, 0) for kind in session_heads.LAYER_KINDS}
@@ -853,8 +889,9 @@ def test_unknown_head_lists_the_new_name():
     with pytest.raises(ValueError) as err:
         session_heads.session_head("mamba")
     assert "'falconh1'" in str(err.value) and "'lfm2'" in str(err.value)
-    assert set(session_heads.HEAD_LAYERS) == set(session_heads.HEADS)
-    assert fb.layer_kinds(session_heads.FALCONH1_CONFIG) == {
+    assert all(set(row.layers) == set(session_heads.LAYER_KINDS)
+               for row in session_heads.HEADS.values())
+    assert fb.layer_kinds(session_heads.HEADS["falconh1"].config) == {
         "ssm": 4, "attention": 4, "dense": 4}
 
 

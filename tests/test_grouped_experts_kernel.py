@@ -4,7 +4,7 @@ CPU through the Pallas interpreter, at small shapes ``supports`` accepts
 and under every kind of routing skew the schedule has a case for.
 
 And ``combine``, the results' way back to position order, against the XLA
-expressions it replaces in ``models/keye_backbone.grouped_experts`` (a row
+expressions it replaces in ``models/expert_layer.grouped_experts`` (a row
 gather and a weighted sum where every slot is taken; a row gather a slot
 under a ``where`` for a share), and the experts' sizes against
 ``jnp.bincount``.
@@ -22,7 +22,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
+from igaming_platform_tpu.models import decoder_parts as dp  # noqa: E402
+from igaming_platform_tpu.models import expert_layer as el  # noqa: E402
 from igaming_platform_tpu.ops.pallas import grouped_experts as ge  # noqa: E402
 
 EXPERTS, HIDDEN, WIDTH = 8, 128, 256
@@ -521,7 +522,7 @@ def test_combine_supports_the_cells_shapes():
     # keye: every slot of 4,096 positions, results with their rows whole
     assert ge.combine_supports(jax.ShapeDtypeStruct((32768, 16, 128), F32), rows)
     # pangu: one pass of a share, as many rows as ``pass_rows`` allows
-    assert kb.pass_rows(32768, 8, 256, 7680) == 2048
+    assert el.pass_rows(32768, 8, 256, 7680) == 2048
     assert ge.combine_supports(jax.ShapeDtypeStruct((2048, 7680), F32), rows, take)
 
 
@@ -531,7 +532,7 @@ def test_the_way_back_is_chosen_from_backend_and_shapes_and_announced(
         case, monkeypatch, caplog):
     """(e) the shapes ``combine_supports`` refuses take the XLA expressions,
     on a (steered) TPU too, and a compile says once which way it took."""
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     if case != "cpu":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     hidden = 1000 if case.startswith("refused") else 1024
@@ -539,9 +540,9 @@ def test_the_way_back_is_chosen_from_backend_and_shapes_and_announced(
     ys = jax.ShapeDtypeStruct((1024, hidden), F32)
     rows = jax.ShapeDtypeStruct((128, 8), jnp.int32)
     take = jax.ShapeDtypeStruct((128, 8), jnp.bool_) if share else None
-    with caplog.at_level("INFO", logger=kb.logger.name):
-        first = kb._combine_by_kernel(ys, rows, take)
-        assert kb._combine_by_kernel(ys, rows, take) == first
+    with caplog.at_level("INFO", logger=dp.logger.name):
+        first = el._combine_by_kernel(ys, rows, take)
+        assert el._combine_by_kernel(ys, rows, take) == first
     assert first == case.startswith("taken")
     way = "pallas-rows" if first else "xla-gather"
     backend = "cpu" if case == "cpu" else "tpu"
@@ -573,7 +574,7 @@ def keys_of(kind: str, held: int, pairs: int = 4096):
                                   "sentinel-keys", "all-sentinel"])
 def test_sizes_are_bincounts_integers_without_its_scatter(kind, held):
     keys = jnp.asarray(keys_of(kind, held), jnp.int32)
-    sizes = jax.jit(lambda e: kb.expert_sizes(e, held))
+    sizes = jax.jit(lambda e: el.expert_sizes(e, held))
     got = sizes(keys)
     want = jnp.bincount(keys, length=held).astype(jnp.int32)
     assert got.dtype == jnp.int32 and got.shape == (held,)

@@ -9,6 +9,7 @@ whose ``topk`` 4 is UNDER the window's 16 keys, so the selection prunes.
 
 from __future__ import annotations
 
+import dataclasses
 import copy
 import functools
 import json
@@ -23,6 +24,8 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from chipbench import harness, reference, validate  # noqa: E402
+from igaming_platform_tpu.models import decoder_parts as dp  # noqa: E402
+from igaming_platform_tpu.models import expert_layer as el  # noqa: E402
 from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
 from igaming_platform_tpu.models import session_heads  # noqa: E402
 
@@ -123,14 +126,14 @@ def test_tree_of_the_reference_is_the_programs(head):
     for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
     published = validate.load_data("configs", "risk-seqhead-keye-vl2-30b-a3b")
-    d, c = head.dims_of(published), session_heads.KEYE_CONFIG
+    d, c = head.dims_of(published), session_heads.HEADS["keye"].config
     assert (d.hidden, d.layers, d.heads, d.kv_heads, d.head_dim, d.experts,
             d.top_k, d.expert_width, d.idx_heads, d.idx_dim, d.idx_topk,
             d.sections, d.theta, d.eps) == (
         c.hidden, c.layers, c.heads, c.kv_heads, c.head_dim, c.experts,
         c.top_k, c.expert_width, c.idx_heads, c.idx_dim, c.idx_topk,
         c.mrope_section, c.rope_theta, c.eps)
-    full = jax.eval_shape(session_heads.init_keye_params)
+    full = jax.eval_shape(session_heads.HEADS["keye"].init)
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(full))
     assert 2.50e9 < n < 2.51e9
     assert sum(a.dtype.itemsize * int(np.prod(a.shape))
@@ -149,15 +152,15 @@ def test_mrope_with_three_unequal_streams(head, sections, head_dim):
                      rng.integers(0, 900, t)])[:, None, :].repeat(b, 1)
     pos3[:, 1] += 3
     x = rng.normal(0, 1, (b, t, h, head_dim)).astype(np.float32)
-    cos, sin = kb.mrope_angles(jnp.asarray(pos3, jnp.int32), head_dim, sections, 1e7)
-    got = np.asarray(kb.rotate(jnp.asarray(x), cos, sin))
+    cos, sin = dp.mrope_angles(jnp.asarray(pos3, jnp.int32), head_dim, sections, 1e7)
+    got = np.asarray(dp.rotate(jnp.asarray(x), cos, sin))
     d = head.dims_of(SMALL_SOURCE)._replace(head_dim=head_dim, sections=sections)
     want = np.asarray(head._mrope(jnp.asarray(x), jnp.asarray(pos3, jnp.int32), d))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
     # the streams matter: equal ids turn the later sections differently
     same = np.broadcast_to(pos3[:1], pos3.shape)
-    cos1, sin1 = kb.mrope_angles(jnp.asarray(same, jnp.int32), head_dim, sections, 1e7)
-    assert np.abs(np.asarray(kb.rotate(jnp.asarray(x), cos1, sin1)) - got).max() > 0.1
+    cos1, sin1 = dp.mrope_angles(jnp.asarray(same, jnp.int32), head_dim, sections, 1e7)
+    assert np.abs(np.asarray(dp.rotate(jnp.asarray(x), cos1, sin1)) - got).max() > 0.1
     # pair i is channels i and i + half, and each section follows its stream
     half = head_dim // 2
     lo = sections[0]
@@ -202,7 +205,7 @@ def expert_core(request, monkeypatch):
     is what a CPU picks by itself (float32 operands at the small size);
     ``pallas`` sizes the layer so that ``supports`` takes it (lane-aligned
     widths, bfloat16)."""
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     if request.param == "xla":
         return dict(operand_dtype=jnp.float32), 2e-4, "xla-ragged-dot"
     _steer_to_the_kernels(monkeypatch)
@@ -239,8 +242,8 @@ def test_grouped_experts_drop_nothing_under_skew(experts, top_k, hot, expert_cor
         assert counts[list(hot)].tolist() == [n, n]  # every position, both
     assert counts.sum() == n * cfg.top_k  # a pair a slot: none dropped
     np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, atol=1e-6)
-    with caplog.at_level("INFO", logger=kb.logger.name):
-        got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
+    with caplog.at_level("INFO", logger=dp.logger.name):
+        got = np.asarray(jax.jit(lambda x, e, w: el.grouped_experts(
             x, e, w, layer, cfg))(x, top_e, top_w))
     assert f"expert core: {core} (" in caplog.text  # the kernels say how they are fed
     if core == "pallas-grouped":
@@ -262,15 +265,15 @@ def test_expert_core_is_announced_and_falls_back_where_the_kernels_do_not_fit(
     shape ``supports`` refuses (the small size: hidden 64, width 32) still
     takes it, and a shape it accepts names the kernels."""
     def announced(cfg):
-        kb._announce_core.cache_clear()
+        dp.announce_core.cache_clear()
         caplog.clear()
         layer = jax.eval_shape(
             lambda: kb.init_backbone(jax.random.key(0), cfg))["layers"][0]
         xs = jax.ShapeDtypeStruct((64, cfg.hidden), cfg.operand_dtype)
         sizes = jax.ShapeDtypeStruct((cfg.experts,), jnp.int32)
-        with caplog.at_level("INFO", logger=kb.logger.name):
+        with caplog.at_level("INFO", logger=dp.logger.name):
             out = jax.eval_shape(
-                lambda xs, sizes, layer: kb._expert_products(xs, sizes, layer, cfg),
+                lambda xs, sizes, layer: el._expert_products(xs, sizes, layer, cfg),
                 xs, sizes, layer)
         assert out.shape == (64, cfg.hidden) and out.dtype == jnp.float32
         return [r.getMessage() for r in caplog.records]
@@ -289,9 +292,9 @@ def test_expert_core_is_announced_and_falls_back_where_the_kernels_do_not_fit(
                                   operand_dtype=jnp.float32)) == [
         "expert core: xla-ragged-dot (backend=tpu)"]
     # the last word of each part is kept for /debug/sessionz (``head_cores``)
-    assert kb.announced_cores()["expert core"] == "xla-ragged-dot (backend=tpu)"
+    assert dp.announced_cores()["expert core"] == "xla-ragged-dot (backend=tpu)"
     announced(aligned)
-    assert kb.announced_cores()["expert core"] == f"pallas-grouped ({fed}) (backend=tpu)"
+    assert dp.announced_cores()["expert core"] == f"pallas-grouped ({fed}) (backend=tpu)"
 
 
 @pytest.mark.parametrize("operands", ["float32", "bfloat16"])
@@ -306,9 +309,9 @@ def test_head_with_the_kernels_on_equals_the_xla_path(operands, monkeypatch, cap
     params = kb.init_backbone(jax.random.key(9), cfg)
     x, lens = windows(8, (1, 4, 16, 7, 9, 2), seed=2)
     by_xla = program_scores(cfg, params, x, lens)
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     _steer_to_the_kernels(monkeypatch)
-    with caplog.at_level("INFO", logger=kb.logger.name):
+    with caplog.at_level("INFO", logger=dp.logger.name):
         by_kernels = program_scores(cfg, params, x, lens)
     said = {r.getMessage() for r in caplog.records}
     from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
@@ -353,7 +356,7 @@ def attention_layer(cfg, windows_n: int, seed: int = 0):
     p = windows_n * 16
     h = jax.random.normal(ks[2], (p, cfg.hidden), jnp.float32)
     pos3 = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (3, windows_n, 16))
-    cos, sin = (a.reshape(p, -1) for a in kb.mrope_angles(
+    cos, sin = (a.reshape(p, -1) for a in dp.mrope_angles(
         pos3, cfg.head_dim, cfg.mrope_section, cfg.rope_theta))
     return layer, h, cos, sin
 
@@ -371,9 +374,9 @@ def attention_by_kernel(monkeypatch, caplog):
     from igaming_platform_tpu.ops.pallas import window_attention as wa
 
     def run(*args):
-        kb._announce_core.cache_clear()
+        dp.announce_core.cache_clear()
         caplog.clear()
-        with monkeypatch.context() as m, caplog.at_level("INFO", logger=kb.logger.name):
+        with monkeypatch.context() as m, caplog.at_level("INFO", logger=dp.logger.name):
             m.setattr(jax, "default_backend", lambda: "tpu")
             m.setattr(wa, "grouped_window_attention", functools.partial(
                 wa.grouped_window_attention, interpret=True))
@@ -398,7 +401,7 @@ def test_attention_through_the_kernel_equals_the_einsum_path(
     by_kernel, said = attention_by_kernel(cfg, *args)
     assert said == ["attention core: pallas-windows (grouped 32/4 of 128, "
                     "window 16, mask=keep) (backend=tpu)"]
-    assert kb.announced_cores()["attention core"] == said[0].split(": ", 1)[1]
+    assert dp.announced_cores()["attention core"] == said[0].split(": ", 1)[1]
     assert by_kernel.shape == by_einsum.shape == (windows_n * 16, cfg.hidden)
     scale = np.abs(by_einsum).max()
     assert scale > 0.01
@@ -446,14 +449,14 @@ def test_attention_core_is_announced_with_the_reason_it_declines(
     shapes alone; the boot's log line and ``/debug/sessionz``'s
     ``head_cores`` carry it, with the kernel's own reason beside ``einsum``."""
     cfg = wide_heads(**over)
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     keep = jax.ShapeDtypeStruct((64 * window, window), jnp.bool_)
-    with caplog.at_level("INFO", logger=kb.logger.name):
+    with caplog.at_level("INFO", logger=dp.logger.name):
         by_kernel = kb._attention_core(64 * window, keep, cfg, window)
     assert by_kernel is said.startswith("pallas-windows")
     assert [r.getMessage() for r in caplog.records] == [f"attention core: {said}"]
-    assert kb.announced_cores()["attention core"] == said
+    assert dp.announced_cores()["attention core"] == said
 
 
 def _hidden_with_a_3d_stream(params, x, pos3, cfg):
@@ -463,15 +466,15 @@ def _hidden_with_a_3d_stream(params, x, pos3, cfg):
     the indexer's [B, T, T] mask."""
     b, t, _ = x.shape
     nh, nkv, hd, dt = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.operand_dtype
-    h = kb._mm(x, params["embed"], cfg)
-    cos, sin = kb.mrope_angles(pos3, hd, cfg.mrope_section, cfg.rope_theta)
+    h = dp.mm(x, params["embed"], cfg)
+    cos, sin = dp.mrope_angles(pos3, hd, cfg.mrope_section, cfg.rope_theta)
     for layer in params["layers"]:
-        a = kb.rms_norm(h, layer["g1"], cfg.eps)
-        q = kb._mm(a, layer["wq"], cfg).reshape(b, t, nh, hd)
-        k = kb._mm(a, layer["wk"], cfg).reshape(b, t, nkv, hd)
-        v = kb._mm(a, layer["wv"], cfg).reshape(b, t, nkv, hd)
-        q = kb.rotate(kb.rms_norm(q, layer["qn"], cfg.eps), cos, sin)
-        k = kb.rotate(kb.rms_norm(k, layer["kn"], cfg.eps), cos, sin)
+        a = dp.rms_norm(h, layer["g1"], cfg.eps)
+        q = dp.mm(a, layer["wq"], cfg).reshape(b, t, nh, hd)
+        k = dp.mm(a, layer["wk"], cfg).reshape(b, t, nkv, hd)
+        v = dp.mm(a, layer["wv"], cfg).reshape(b, t, nkv, hd)
+        q = dp.rotate(dp.rms_norm(q, layer["qn"], cfg.eps), cos, sin)
+        k = dp.rotate(dp.rms_norm(k, layer["kn"], cfg.eps), cos, sin)
         keep = kb.indexer_keep(a.reshape(b * t, -1), layer, cos.reshape(b * t, -1),
                                sin.reshape(b * t, -1), cfg, t).reshape(b, t, t)
         q = q.reshape(b, t, nkv, nh // nkv, hd)
@@ -480,11 +483,11 @@ def _hidden_with_a_3d_stream(params, x, pos3, cfg):
         p = jax.nn.softmax(jnp.where(keep[:, None, None], sc, -jnp.inf), axis=-1)
         o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v.astype(dt),
                        preferred_element_type=jnp.float32)
-        h = h + kb._mm(o.reshape(b, t, nh * hd), layer["wo"], cfg)
-        flat = kb.rms_norm(h, layer["g2"], cfg.eps).reshape(b * t, -1)
+        h = h + dp.mm(o.reshape(b, t, nh * hd), layer["wo"], cfg)
+        flat = dp.rms_norm(h, layer["g2"], cfg.eps).reshape(b * t, -1)
         top_e, top_w = kb.route(flat, layer, cfg)
-        h = h + kb.grouped_experts(flat, top_e, top_w, layer, cfg).reshape(b, t, -1)
-    return kb.rms_norm(h, params["gf"], cfg.eps)
+        h = h + el.grouped_experts(flat, top_e, top_w, layer, cfg).reshape(b, t, -1)
+    return dp.rms_norm(h, params["gf"], cfg.eps)
 
 
 @pytest.mark.parametrize("operands", ["float32", "bfloat16"])
@@ -497,7 +500,7 @@ def test_the_position_major_stream_scores_the_3d_streams_bits(operands):
     x, lens = windows(12, (1, 4, 16, 7, 9, 2), seed=4)
     pos3 = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (3, 12, 16))
     now = program_scores(cfg, params, x, lens)
-    was = np.asarray(jax.jit(lambda p, w, l: kb.score_last(
+    was = np.asarray(jax.jit(lambda p, w, l: dp.score_last(
         p, _hidden_with_a_3d_stream(p, w, pos3, cfg), l))(
             params, jnp.asarray(x), jnp.asarray(lens, jnp.int32)))
     assert np.ptp(now) > 1e-3
@@ -515,7 +518,7 @@ def _layer_as_before(x, top_e, top_w, layer, cfg):
     order = jnp.argsort(flat_e, stable=True)
     sizes = jnp.bincount(flat_e, length=cfg.experts).astype(jnp.int32)
     xs = x.astype(cfg.operand_dtype)[order // k]
-    ys = kb._expert_products(xs, sizes, layer, cfg)
+    ys = el._expert_products(xs, sizes, layer, cfg)
     back = jnp.argsort(order)
     y = ys[back].reshape(n, k, -1)
     return jnp.sum(y * top_w[..., None], axis=1)
@@ -532,7 +535,7 @@ def test_keye_head_is_unchanged_bit_for_bit_by_the_shared_layer(
     layer = params["layers"][1]
     x = jax.random.normal(jax.random.key(8), (160, cfg.hidden), jnp.float32)
     top_e, top_w = kb.route(x, layer, cfg)
-    now = jax.jit(lambda x, e, w: kb.grouped_experts(x, e, w, layer, cfg))
+    now = jax.jit(lambda x, e, w: el.grouped_experts(x, e, w, layer, cfg))
     before = jax.jit(lambda x, e, w: _layer_as_before(x, e, w, layer, cfg))
     np.testing.assert_array_equal(np.asarray(now(x, top_e, top_w)),
                                   np.asarray(before(x, top_e, top_w)))
@@ -544,19 +547,19 @@ def test_keye_head_is_unchanged_bit_for_bit_by_the_shared_layer(
 
 def test_pass_rows_bound_a_share_and_cover_a_whole_layer():
     # the cell's share: 32,768 pairs, 8 of 256 held -> 4 x 1,024 expected
-    assert kb.pass_rows(32768, 8, 256) == 4096
+    assert el.pass_rows(32768, 8, 256) == 4096
     # every expert held: one pass over all the pairs, as before
-    assert kb.pass_rows(32768, 128, 128) == 32768
-    assert kb.pass_rows(2048, 4, 32) == 1024
-    assert kb.pass_rows(192, 4, 16) == 192   # never more than all pairs
-    assert kb.pass_rows(4096, 1, 256) == 256  # rounded up to the kernels' tile
+    assert el.pass_rows(32768, 128, 128) == 32768
+    assert el.pass_rows(2048, 4, 32) == 1024
+    assert el.pass_rows(192, 4, 16) == 192   # never more than all pairs
+    assert el.pass_rows(4096, 1, 256) == 256  # rounded up to the kernels' tile
     # and, told how wide a row is, no more rows than leave a pass's float32
     # results in the 64 MiB that ``combine`` keeps them in: the pangu
     # cell's pass is 2,048 rows of 7,680, not 4,096; small rows change nothing
-    assert kb.pass_rows(32768, 8, 256, 7680) == 2048
-    assert kb.pass_rows(32768, 8, 256, 2048) == 4096
-    assert kb.pass_rows(2048, 4, 32, 128) == 1024
-    assert kb.pass_rows(4096, 1, 256, 1 << 20) == 256  # one tile at least
+    assert el.pass_rows(32768, 8, 256, 7680) == 2048
+    assert el.pass_rows(32768, 8, 256, 2048) == 4096
+    assert el.pass_rows(2048, 4, 32, 128) == 1024
+    assert el.pass_rows(4096, 1, 256, 1 << 20) == 256  # one tile at least
 
 
 @pytest.mark.parametrize("expert_core", ["xla", "pallas", "pallas-rows-in-kernel"],
@@ -591,8 +594,8 @@ def test_a_share_is_dropless_at_any_routing(routing, expert_core):
     here = (np.asarray(top_e) >= first) & (np.asarray(top_e) < first + held)
     assert int(here.sum()) == {"all-held": 2048, "none-held": 0,
                                "ragged": 1500}.get(routing, int(here.sum()))
-    assert kb.pass_rows(n * k, held, experts) == 1024
-    got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
+    assert el.pass_rows(n * k, held, experts) == 1024
+    got = np.asarray(jax.jit(lambda x, e, w: el.grouped_experts(
         x, e, w, share, cfg, first))(x, top_e, top_w))
     local = np.where(here, np.asarray(top_e) - first, held)  # held: no expert
     want = _expert_loop(x, local, np.where(here, np.asarray(top_w), 0.0), share)
@@ -619,9 +622,10 @@ def small_keye(monkeypatch):
     """``SESSION_HEAD=keye`` at the small size: the row of ``HEADS`` is
     steered here, in the test; the program has no option for it."""
     cfg = small_config()
-    monkeypatch.setitem(session_heads.HEADS, "keye", (
-        lambda sp, win, lp: kb.backbone_scores(sp, win, lp, cfg),
-        lambda: kb.init_backbone(jax.random.key(11), cfg)))
+    monkeypatch.setitem(session_heads.HEADS, "keye", dataclasses.replace(
+        session_heads.HEADS["keye"],
+        scores=lambda sp, win, lp: kb.backbone_scores(sp, win, lp, cfg),
+        init=lambda: kb.init_backbone(jax.random.key(11), cfg)))
     return cfg
 
 

@@ -4,6 +4,7 @@ rung under the 256 one, and the counter that says which rung a launch ran.
 
 from __future__ import annotations
 
+import dataclasses
 import numpy as np
 import pytest
 
@@ -60,9 +61,10 @@ def small_keye(monkeypatch):
         hidden=64, layers=2, heads=4, kv_heads=2, head_dim=16, experts=8,
         top_k=2, expert_width=32, idx_heads=2, idx_dim=8, idx_topk=4,
         mrope_section=(2, 3, 3))
-    monkeypatch.setitem(session_heads.HEADS, "keye", (
-        lambda sp, win, lp: kb.backbone_scores(sp, win, lp, cfg),
-        lambda: kb.init_backbone(jax.random.key(11), cfg)))
+    monkeypatch.setitem(session_heads.HEADS, "keye", dataclasses.replace(
+        session_heads.HEADS["keye"],
+        scores=lambda sp, win, lp: kb.backbone_scores(sp, win, lp, cfg),
+        init=lambda: kb.init_backbone(jax.random.key(11), cfg)))
     monkeypatch.setenv("SESSION_HEAD", "keye")
 
 

@@ -11,6 +11,7 @@ of mixed lengths, seeded weights.
 
 from __future__ import annotations
 
+import dataclasses
 import copy
 import functools
 import json
@@ -25,7 +26,8 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from chipbench import harness, reference, validate  # noqa: E402
-from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
+from igaming_platform_tpu.models import decoder_parts as dp  # noqa: E402
+from igaming_platform_tpu.models import expert_layer as el  # noqa: E402
 from igaming_platform_tpu.models import lfm2_backbone as lb  # noqa: E402
 from igaming_platform_tpu.models import pangu_backbone as pb  # noqa: E402
 from igaming_platform_tpu.models import session_heads  # noqa: E402
@@ -191,7 +193,7 @@ def test_tree_of_the_reference_is_the_programs(head, tree):
         for name in ("w_in", "w_out", "wq", "wk", "wv", "wo"):
             assert name not in layer or layer[name].dtype == jnp.bfloat16, name
     published = validate.load_data("configs", CONFIG)
-    d, c = head.dims_of(published), session_heads.LFM2_CONFIG
+    d, c = head.dims_of(published), session_heads.HEADS["lfm2"].config
     assert (d.hidden, d.layer_types, d.dense_layers, d.taps, d.heads, d.kv_heads,
             d.head_dim, d.dense_width, d.experts, d.top_k, d.expert_width,
             d.scale, d.theta, d.eps) == (
@@ -200,7 +202,7 @@ def test_tree_of_the_reference_is_the_programs(head, tree):
         c.expert_width, c.routed_scale, c.rope_theta, c.eps)
     assert c.renorm_eps == head.RENORM_EPS == 1e-6
     assert c.init_depth == published["head"]["published"]["num_hidden_layers"]
-    full = jax.eval_shape(session_heads.init_lfm2_params)
+    full = jax.eval_shape(session_heads.HEADS["lfm2"].init)
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(full))
     assert n == 2_566_463_873
     assert sum(a.dtype.itemsize * int(np.prod(a.shape))
@@ -284,7 +286,7 @@ def test_short_convolution_is_causal_and_each_window_alone():
     # and the taps alone, as three shifted products
     z = np.asarray(stream(rows=2, seed=4))
     w = np.asarray(layer["taps"])
-    c = np.asarray(lb.causal_taps(jnp.asarray(z), layer["taps"]))
+    c = np.asarray(dp.causal_taps(jnp.asarray(z), layer["taps"]))
     want = w[:, 2] * z
     want[:, 1:] += w[:, 1] * z[:, :-1]
     want[:, 2:] += w[:, 0] * z[:, :-2]
@@ -302,14 +304,14 @@ def test_operator_sublayer_alone(head, tree, operands, kind):
     x = stream(seed=5)
     rows, t, hid = x.shape
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, rows, t))
-    cos, sin = kb.mrope_angles(pos, cfg.head_dim, (cfg.head_dim // 2,),
+    cos, sin = dp.mrope_angles(pos, cfg.head_dim, (cfg.head_dim // 2,),
                                cfg.rope_theta)
 
     def sublayer(x, cos, sin):
         h = x.reshape(rows * t, hid)
-        u = kb.rms_norm(h, layer["g1"], cfg.eps)
+        u = dp.rms_norm(h, layer["g1"], cfg.eps)
         o = (lb.short_conv(u, layer, cfg, t) if kind == "conv"
-             else lb.attention(u, layer, cos, sin, cfg, t))
+             else dp.attention(u, layer, cos, sin, cfg, t))
         return (h + o).reshape(x.shape)
 
     got = np.asarray(jax.jit(sublayer)(x, cos, sin))
@@ -333,7 +335,7 @@ def test_operator_sublayer_alone(head, tree, operands, kind):
 
 
 def _route(layer, x, cfg):
-    top_e, top_w = jax.jit(lambda x: pb.route(x, layer, cfg))(x)
+    top_e, top_w = jax.jit(lambda x: dp.route(x, layer, cfg))(x)
     return np.asarray(top_e), np.asarray(top_w)
 
 
@@ -387,7 +389,7 @@ def test_the_bias_chooses_and_never_weighs(head, tree):
 
 def _route_of_the_parent(x, layer, cfg):
     """``pangu_backbone.route`` as the parent commit has it, written out."""
-    s = jax.nn.sigmoid(kb._mm(x, layer["wr"], cfg))
+    s = jax.nn.sigmoid(dp.mm(x, layer["wr"], cfg))
     top_s, top_e = jax.lax.top_k(s, cfg.top_k)
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
     return top_e, w * cfg.routed_scale
@@ -403,7 +405,7 @@ def test_pangu_route_without_a_bias_is_the_parents_bit_for_bit(operands):
     layer = pb.init_backbone(jax.random.key(1), cfg)["layers"][1]
     assert "rb" not in layer
     x = jax.random.normal(jax.random.key(2), (300, 64), jnp.float32)
-    now = jax.jit(lambda x: pb.route(x, layer, cfg))
+    now = jax.jit(lambda x: dp.route(x, layer, cfg))
     then = jax.jit(lambda x: _route_of_the_parent(x, layer, cfg))
     for a, b in zip(now(x), then(x)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -438,7 +440,7 @@ def _steer_to_the_kernels(monkeypatch):
     test; the program has no option for it."""
     from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
 
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for name in ("gate_up", "down", "combine"):
         monkeypatch.setattr(kernels, name, functools.partial(
@@ -465,7 +467,7 @@ def test_expert_layer_equals_the_loop_over_experts(core, monkeypatch, caplog):
     of 128 and not 768."""
     if core == "xla":
         cfg, tolerance = small_config(operand_dtype=jnp.float32), 2e-4
-        kb._announce_core.cache_clear()
+        dp.announce_core.cache_clear()
     else:
         cfg = small_config(hidden=1024, experts=64, top_k=4, expert_width=384,
                            layer_types=("conv", "conv"))
@@ -475,10 +477,10 @@ def test_expert_layer_equals_the_loop_over_experts(core, monkeypatch, caplog):
     layer["rb"] = jax.random.normal(jax.random.key(5), (cfg.experts,)) * 0.1
     n = 128
     x = jax.random.normal(jax.random.key(3), (n, cfg.hidden), jnp.float32)
-    top_e, top_w = jax.jit(lambda x: pb.route(x, layer, cfg))(x)
+    top_e, top_w = jax.jit(lambda x: dp.route(x, layer, cfg))(x)
     assert np.bincount(np.asarray(top_e).ravel()).sum() == n * cfg.top_k
-    with caplog.at_level("INFO", logger=kb.logger.name):
-        got = np.asarray(jax.jit(lambda x, e, w: kb.grouped_experts(
+    with caplog.at_level("INFO", logger=dp.logger.name):
+        got = np.asarray(jax.jit(lambda x, e, w: el.grouped_experts(
             x, e, w, layer["routed"], cfg))(x, top_e, top_w))
     said = {r.getMessage() for r in caplog.records}
     assert said == ({"expert core: xla-ragged-dot (backend=cpu)",
@@ -505,12 +507,12 @@ def test_ffn_sublayer_alone(head, tree, operands):
         layer = tree["layers"][index]
 
         def sublayer(x):
-            flat = kb.rms_norm(x.reshape(-1, cfg.hidden), layer["g2"], cfg.eps)
+            flat = dp.rms_norm(x.reshape(-1, cfg.hidden), layer["g2"], cfg.eps)
             if "dense" in layer:
-                m = pb.swiglu(flat, layer["dense"], cfg)
+                m = dp.swiglu(flat, layer["dense"], cfg)
             else:
-                top_e, top_w = pb.route(flat, layer, cfg)
-                m = kb.grouped_experts(flat, top_e, top_w, layer["routed"], cfg)
+                top_e, top_w = dp.route(flat, layer, cfg)
+                m = el.grouped_experts(flat, top_e, top_w, layer["routed"], cfg)
             return x + m.reshape(x.shape)
 
         got = np.asarray(jax.jit(sublayer)(x))
@@ -551,9 +553,10 @@ def small_lfm2(monkeypatch):
     """``SESSION_HEAD=lfm2`` at the small size: the row of ``HEADS`` is
     steered here, in the test; the program has no option for it."""
     cfg = small_config()
-    monkeypatch.setitem(session_heads.HEADS, "lfm2", (
-        lambda sp, win, lp: lb.backbone_scores(sp, win, lp, cfg),
-        lambda: lb.init_backbone(jax.random.key(11), cfg)))
+    monkeypatch.setitem(session_heads.HEADS, "lfm2", dataclasses.replace(
+        session_heads.HEADS["lfm2"],
+        scores=lambda sp, win, lp: lb.backbone_scores(sp, win, lp, cfg),
+        init=lambda: lb.init_backbone(jax.random.key(11), cfg)))
     return cfg
 
 
@@ -608,7 +611,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert snap["head_positions"] == 16 * numbers["rows"]
     resident = sum(int(a.nbytes) for a in jax.tree.leaves(run.head_params))
     assert snap["head_resident_bytes"] == resident > 0
-    c = session_heads.LFM2_CONFIG
+    c = session_heads.HEADS["lfm2"].config
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (
         c.experts, c.experts) == (64, 64)
     assert snap["head_layers"] == {"conv": 4, "attention": 1, "ssm": 0,
@@ -692,8 +695,8 @@ def test_layer_gauge_of_the_small_heads(name, layers):
     ("lfm2", {"conv": 4, "attention": 1, "dense": 1, "moe": 4}),
     ("falconh1", {"ssm": 4, "attention": 4, "dense": 4})])
 def test_every_head_says_what_its_stack_is_made_of(name, layers):
-    assert set(session_heads.HEAD_LAYERS) == set(session_heads.HEADS)
-    assert session_heads.HEAD_LAYERS[name] == layers
+    assert session_heads.HEADS[name].layers == {
+        kind: layers.get(kind, 0) for kind in session_heads.LAYER_KINDS}
     assert set(layers) <= set(session_heads.LAYER_KINDS)
 
 
@@ -701,7 +704,7 @@ def test_unknown_head_lists_the_new_name():
     with pytest.raises(ValueError) as err:
         session_heads.session_head("mamba")
     assert "'lfm2'" in str(err.value) and "'pangu'" in str(err.value)
-    assert session_heads.HEAD_EXPERTS["lfm2"] == (64, 64)
+    assert session_heads.HEADS["lfm2"].experts == (64, 64)
 
 
 def test_chip_smoke_phase_runs_the_head_against_its_reference():

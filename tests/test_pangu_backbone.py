@@ -10,6 +10,7 @@ routed ones of which a chip holds 4, 4 chosen a token.
 
 from __future__ import annotations
 
+import dataclasses
 import copy
 import functools
 import types
@@ -21,7 +22,8 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from chipbench import harness, reference, validate  # noqa: E402
-from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
+from igaming_platform_tpu.models import decoder_parts as dp  # noqa: E402
+from igaming_platform_tpu.models import expert_layer as el  # noqa: E402
 from igaming_platform_tpu.models import pangu_backbone as pb  # noqa: E402
 from igaming_platform_tpu.models import session_heads  # noqa: E402
 
@@ -90,7 +92,7 @@ def steer_to_combine(monkeypatch):
     Steered here, in the test; the program has no option for it."""
     from igaming_platform_tpu.ops.pallas import grouped_experts as kernels
 
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(kernels, "combine", functools.partial(
         kernels.combine, interpret=True))
@@ -178,7 +180,7 @@ def test_tree_of_the_reference_is_the_programs(head):
             assert np.all(np.asarray(tree["g1"]) == 1) and np.all(
                 np.asarray(tree["g3"]) == 1)
     published = validate.load_data("configs", CONFIG)
-    d, c = head.dims_of(published), session_heads.PANGU_CONFIG
+    d, c = head.dims_of(published), session_heads.HEADS["pangu"].config
     assert (d.hidden, d.layers, d.dense_layers, d.heads, d.q_rank, d.kv_rank,
             d.nope, d.rope, d.v, d.dense_width, d.experts, d.held, d.first,
             d.top_k, d.expert_width, d.scale, d.theta, d.eps) == (
@@ -187,7 +189,7 @@ def test_tree_of_the_reference_is_the_programs(head):
         c.held_experts, c.first_expert, c.top_k, c.expert_width,
         c.routed_scale, c.rope_theta, c.eps)
     assert c.init_depth == published["head"]["published"]["num_hidden_layers"]
-    full = jax.eval_shape(session_heads.init_pangu_params)
+    full = jax.eval_shape(session_heads.HEADS["pangu"].init)
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(full))
     assert 3.11e9 < n < 3.12e9
     assert 6.22e9 < sum(a.dtype.itemsize * int(np.prod(a.shape))
@@ -234,16 +236,16 @@ def test_seeded_projector_reads_standardised_events(head, seed):
 def test_large_matrices_are_drawn_in_row_blocks():
     """No draw of the seeded tree passes 2^24 float32 normals: the dense
     MLP's matrices and ``wo`` come in row blocks of whole tiles."""
-    c = session_heads.PANGU_CONFIG
+    c = session_heads.HEADS["pangu"].config
     for rows, cols in ((c.hidden, c.dense_width), (c.dense_width, c.hidden),
                        (c.heads * c.v_dim, c.hidden),
                        (c.q_rank, c.heads * (c.nope_dim + c.rope_dim))):
-        blocks = kb.row_blocks(rows, cols)
+        blocks = dp.row_blocks(rows, cols)
         assert blocks > 1 and rows % (16 * blocks) == 0
-        assert rows // blocks * cols <= kb._DRAW_ELEMS
+        assert rows // blocks * cols <= dp._DRAW_ELEMS
     # every matrix of the keye head is one draw, as before
-    assert kb.row_blocks(2048, 4096) == kb.row_blocks(4096, 2048) == 1
-    got = kb._matrix(jax.random.key(0), (64, 8), 64)
+    assert dp.row_blocks(2048, 4096) == dp.row_blocks(4096, 2048) == 1
+    got = dp._matrix(jax.random.key(0), (64, 8), 64)
     assert got.shape == (64, 8) and got.dtype == jnp.bfloat16
 
 
@@ -267,17 +269,17 @@ def test_latent_attention_alone(head, operands, core, monkeypatch, caplog):
     x = stream()
     t = x.shape[1]
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, x.shape[0], t))
-    cos, sin = kb.mrope_angles(pos, cfg.rope_dim, (cfg.rope_dim // 2,),
+    cos, sin = dp.mrope_angles(pos, cfg.rope_dim, (cfg.rope_dim // 2,),
                                cfg.rope_theta)
     if by_kernel:
         steer_to_attention_kernel(monkeypatch)
 
     def sublayer(x, cos, sin):
-        o = pb.latent_attention(kb.rms_norm(x, layer["g1"], cfg.eps), layer,
+        o = pb.latent_attention(dp.rms_norm(x, layer["g1"], cfg.eps), layer,
                                 cos, sin, cfg)
-        return x + kb.rms_norm(o, layer["g2"], cfg.eps)
+        return x + dp.rms_norm(o, layer["g2"], cfg.eps)
 
-    with caplog.at_level("INFO", logger=kb.logger.name):
+    with caplog.at_level("INFO", logger=dp.logger.name):
         got = np.asarray(jax.jit(sublayer)(x, cos, sin))
     if by_kernel:
         assert "attention core: pallas-windows (backend=tpu)" in caplog.text
@@ -309,14 +311,14 @@ def test_mlp_sublayer_alone(head, operands, kind):
 
     def sublayer(x, live):
         b, t, _ = x.shape
-        flat = kb.rms_norm(x, layer["g3"], cfg.eps).reshape(b * t, -1)
+        flat = dp.rms_norm(x, layer["g3"], cfg.eps).reshape(b * t, -1)
         if kind == "dense":
-            m = pb.swiglu(flat, layer["dense"], cfg)
+            m = dp.swiglu(flat, layer["dense"], cfg)
         else:
-            top_e, top_w = pb.route(flat, layer, cfg)
-            m = pb.swiglu(flat, layer["shared"], cfg) + kb.grouped_experts(
+            top_e, top_w = dp.route(flat, layer, cfg)
+            m = dp.swiglu(flat, layer["shared"], cfg) + el.grouped_experts(
                 flat, top_e, top_w, layer["routed"], cfg, cfg.first_expert, live)
-        return x + kb.rms_norm(m, layer["g4"], cfg.eps).reshape(x.shape)
+        return x + dp.rms_norm(m, layer["g4"], cfg.eps).reshape(x.shape)
 
     got = np.asarray(jax.jit(sublayer)(x, live))
     with jax.default_matmul_precision("highest"):
@@ -335,7 +337,7 @@ def test_router_is_sigmoid_top_k_renormalised_and_scaled():
     cfg = small_config(operand_dtype=jnp.float32)
     layer = pb.init_backbone(jax.random.key(1), cfg)["layers"][1]
     x = jax.random.normal(jax.random.key(2), (40, 64), jnp.float32)
-    top_e, top_w = jax.jit(lambda x: pb.route(x, layer, cfg))(x)
+    top_e, top_w = jax.jit(lambda x: dp.route(x, layer, cfg))(x)
     s = 1 / (1 + np.exp(-(np.asarray(x, np.float64)
                           @ np.asarray(layer["wr"].astype(jnp.float32), np.float64))))
     best = np.argsort(-s, axis=1, kind="stable")[:, :cfg.top_k]
@@ -360,7 +362,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(
     hidden 128, whose rows are whole lane tiles."""
     dt = jnp.float32
     size = {}
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     if way_back == "pallas-rows":
         size = {"hidden": 128}
         steer_to_combine(monkeypatch)
@@ -374,15 +376,15 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(
     flat = head._rms(x, whole["g3"], d_whole.eps).reshape(-1, hidden)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(_reference_mlp(head, whole, flat, d_whole, dt))
-    shared_once = np.asarray(jax.jit(lambda f: pb.swiglu(
+    shared_once = np.asarray(jax.jit(lambda f: dp.swiglu(
         f, whole["shared"], small_config(operand_dtype=dt, **size)))(flat))
     total = shared_once.copy()
     for first in range(0, EXPERTS, HELD):
         cfg = small_config(first=first, operand_dtype=dt, **size)
         share = {k: v[first:first + HELD] for k, v in whole["routed"].items()}
-        top_e, top_w = pb.route(flat, whole, cfg)
-        with caplog.at_level("INFO", logger=kb.logger.name):
-            part = np.asarray(jax.jit(lambda f, e, w: kb.grouped_experts(
+        top_e, top_w = dp.route(flat, whole, cfg)
+        with caplog.at_level("INFO", logger=dp.logger.name):
+            part = np.asarray(jax.jit(lambda f, e, w: el.grouped_experts(
                 f, e, w, share, cfg, first, jnp.ones((f.shape[0],), bool)))(
                     flat, top_e, top_w))
         # a share's own reference gives the same part
@@ -411,7 +413,7 @@ def test_head_with_combine_on_equals_the_xla_path(operands, monkeypatch, caplog)
     x, lens = windows(12, (1, 4, 16, 7, 9, 2), seed=3)
     by_xla = program_scores(cfg, params, x, lens)
     steer_to_combine(monkeypatch)
-    with caplog.at_level("INFO", logger=kb.logger.name):
+    with caplog.at_level("INFO", logger=dp.logger.name):
         by_kernel = program_scores(cfg, params, x, lens)
     assert "combine: pallas-rows (backend=tpu)" in caplog.text
     assert "expert core: xla-ragged-dot (backend=tpu)" in caplog.text
@@ -433,7 +435,7 @@ def test_head_with_attention_kernel_on_equals_the_xla_path(
     x, lens = windows(12, (1, 4, 16, 7, 9, 2), seed=3)
     by_xla = program_scores(cfg, params, x, lens)
     steer_to_attention_kernel(monkeypatch)
-    with caplog.at_level("INFO", logger=kb.logger.name):
+    with caplog.at_level("INFO", logger=dp.logger.name):
         by_kernel = program_scores(cfg, params, x, lens)
     assert "attention core: pallas-windows (backend=tpu)" in caplog.text
     assert "combine: pallas-rows (backend=tpu)" in caplog.text
@@ -470,9 +472,10 @@ def small_pangu(monkeypatch):
     """``SESSION_HEAD=pangu`` at the small size: the row of ``HEADS`` is
     steered here, in the test; the program has no option for it."""
     cfg = small_config()
-    monkeypatch.setitem(session_heads.HEADS, "pangu", (
-        lambda sp, win, lp: pb.backbone_scores(sp, win, lp, cfg),
-        lambda: pb.init_backbone(jax.random.key(11), cfg)))
+    monkeypatch.setitem(session_heads.HEADS, "pangu", dataclasses.replace(
+        session_heads.HEADS["pangu"],
+        scores=lambda sp, win, lp: pb.backbone_scores(sp, win, lp, cfg),
+        init=lambda: pb.init_backbone(jax.random.key(11), cfg)))
     return cfg
 
 
@@ -522,7 +525,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert counters["risk_session_head_positions_total"] == 16 * numbers["rows"]
     resident = sum(int(a.nbytes) for a in jax.tree.leaves(run.head_params))
     assert snap["head_resident_bytes"] == resident > 0
-    c = session_heads.PANGU_CONFIG
+    c = session_heads.HEADS["pangu"].config
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (
         c.held_experts, c.experts) == (8, 256)
     for name, value in (("resident_bytes", resident), ("experts_held", 8),

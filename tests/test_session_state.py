@@ -18,7 +18,9 @@ Covers the session plane's contracts end to end on the CPU control rig:
 
 from __future__ import annotations
 
+import ast
 import hashlib
+import pathlib
 import queue
 import sys
 import tempfile
@@ -1101,3 +1103,48 @@ def test_session_reason_bits_appended_not_reordered():
     assert REASON_BIT_ORDER.index(ReasonCode.ML_HIGH_RISK) == 8
     assert SESSION_PATTERN_BIT == 9 and SESSION_COLD_BIT == 10
     assert decode_reason_mask(1 << 8) == [ReasonCode.ML_HIGH_RISK]
+
+
+# -- the shape of models/: a backbone is one module and one row ----------------
+
+_MODELS = pathlib.Path(session_heads.__file__).parent
+_BACKBONES = sorted(p.stem for p in _MODELS.glob("*_backbone.py"))
+
+
+def _imported(module: str) -> set[str]:
+    """Every module name ``models/<module>.py`` imports, anywhere in it, by
+    ``ast``: ``from a.b import c`` gives both ``a.b`` and ``a.b.c``."""
+    names: set[str] = set()
+    for node in ast.walk(ast.parse((_MODELS / f"{module}.py").read_text())):
+        if isinstance(node, ast.Import):
+            names.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            names.add(node.module or "")
+            names.update(f"{node.module}.{alias.name}" for alias in node.names)
+    return names
+
+
+@pytest.mark.parametrize("module", [*_BACKBONES, "decoder_parts", "expert_layer"])
+def test_no_backbone_is_imported_by_another_or_by_the_shared_parts(module):
+    """The arrows of ``models/``: a backbone takes its shared parts from
+    ``decoder_parts`` / ``expert_layer`` and never from another backbone,
+    and those two import no backbone; so a new backbone edits no other."""
+    assert len(_BACKBONES) >= 4
+    others = {b for b in _BACKBONES if b != module}
+    taken = {name.rsplit(".", 1)[-1] for name in _imported(module)} & others
+    assert not taken, f"models/{module}.py imports {sorted(taken)}"
+
+
+@pytest.mark.parametrize("name", sorted(session_heads.HEADS))
+def test_every_row_of_heads_is_whole(name):
+    """One row a head, nothing beside it: layer counts over exactly
+    ``LAYER_KINDS`` and an expert pair, which ``/debug/sessionz`` and the
+    boot gauges read without a default."""
+    row = session_heads.HEADS[name]
+    assert list(row.layers) == list(session_heads.LAYER_KINDS)
+    assert all(isinstance(n, int) and n >= 0 for n in row.layers.values())
+    held, routed = row.experts
+    assert 0 <= held <= routed
+    assert (routed > 0) == (row.layers["moe"] > 0)
+    assert callable(row.scores) and callable(row.init)
+    assert session_heads.session_head(name) is row

@@ -24,6 +24,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from igaming_platform_tpu.models import decoder_parts as dp  # noqa: E402
 from igaming_platform_tpu.models import keye_backbone as kb  # noqa: E402
 from igaming_platform_tpu.models import pangu_backbone as pb  # noqa: E402
 from igaming_platform_tpu.ops.pallas import window_attention as wa  # noqa: E402
@@ -46,7 +47,7 @@ def operands(windows: int, t: int, widths, dtype, seed: int = 0):
     kv = jax.random.normal(ks[1], (p, HEADS * (nope + dv)), jnp.float32)
     k_rope = jax.random.normal(ks[2], (p, rope), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, windows, t))
-    cos, sin = kb.mrope_angles(pos, rope, (rope // 2,), 25.6e6)
+    cos, sin = dp.mrope_angles(pos, rope, (rope // 2,), 25.6e6)
     return (q, kv.astype(dtype), k_rope.astype(dtype),
             cos.reshape(p, -1), sin.reshape(p, -1))
 
@@ -185,7 +186,7 @@ def test_where_supports_is_false_the_layer_takes_its_einsums(monkeypatch, caplog
     layer = pb.init_backbone(jax.random.key(2), cfg)["layers"][0]
     a = jax.random.normal(jax.random.key(3), (6, 16, 64), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (1, 6, 16))
-    cos, sin = kb.mrope_angles(pos, 8, (4,), cfg.rope_theta)
+    cos, sin = dp.mrope_angles(pos, 8, (4,), cfg.rope_theta)
     run = lambda: np.asarray(jax.jit(
         lambda a, c, s: pb.latent_attention(a, layer, c, s, cfg))(a, cos, sin))
     off_tpu = run()
@@ -193,10 +194,10 @@ def test_where_supports_is_false_the_layer_takes_its_einsums(monkeypatch, caplog
     def never(*args, **kwargs):
         raise AssertionError("the kernel was called at widths it does not take")
 
-    kb._announce_core.cache_clear()
+    dp.announce_core.cache_clear()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(wa, "window_attention", never)
-    with caplog.at_level("INFO", logger=kb.logger.name):
+    with caplog.at_level("INFO", logger=dp.logger.name):
         on_tpu = run()
     assert "attention core: xla-einsum (backend=tpu)" in caplog.text
     np.testing.assert_array_equal(on_tpu, off_tpu)
@@ -224,7 +225,7 @@ def grouped_operands(windows: int, dtype, keep: str, seed: int = 0):
     k = jax.random.normal(ks[1], (p, G_KV * G_HD), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (p, G_KV * G_HD), jnp.float32).astype(dtype)
     pos = jnp.broadcast_to(jnp.arange(G_T, dtype=jnp.int32), (3, windows, G_T))
-    cos, sin = kb.mrope_angles(pos, G_HD, (16, 24, 24), 1e7)
+    cos, sin = dp.mrope_angles(pos, G_HD, (16, 24, 24), 1e7)
     gain = 1 + 0.1 * jax.random.normal(ks[3], (G_HD,), jnp.float32)
     mask = {"all": jnp.ones((windows, G_T, G_T), bool),
             "random": (jax.random.bernoulli(ks[4], 0.5, (windows, G_T, G_T))
@@ -400,7 +401,7 @@ def test_the_latent_form_is_the_program_it_was(what, p, heads, dtype):
                                jnp.float32).astype(dtype)
         kr = jax.random.normal(ks[2], (p, rope), jnp.float32).astype(dtype)
         pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (1, p // 16, 16))
-        cos, sin = kb.mrope_angles(pos, rope, (rope // 2,), 25.6e6)
+        cos, sin = dp.mrope_angles(pos, rope, (rope // 2,), 25.6e6)
         out = wa.window_attention(q, kv, kr, cos.reshape(p, -1),
                                   sin.reshape(p, -1), **kw, interpret=True)
         got = np.asarray(out.astype(jnp.float32)).tobytes().hex()
