@@ -963,9 +963,16 @@ BACKBONES = {
     "falconh1": ("risk-seqhead-falcon-h1-34b", "falcon_h1_34b",
                  "falconh1_backbone",
                  {"ssm_core": "dual form, one chunk, 16 <= 128"}),
+    "ling": ("risk-seqhead-ling-3.0-flash", "ling_3_flash", "ling_backbone",
+             {"linear_core": "delta rule, one chunk, 16 <= 17",
+              "expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
+                             "rows=gathered)", "way_back": "pallas-rows",
+              "attention_core": "xla-einsum (interleaved rotary pairs: the "
+                                "window kernel turns by halves)"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
-              "attention_core": "attention core", "ssm_core": "state-space core"}
+              "attention_core": "attention core", "ssm_core": "state-space core",
+              "linear_core": "linear-attention core"}
 
 
 def phase_backbone(*, head_name: str = "pangu", cfg=None,
@@ -985,7 +992,11 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     Mamba-2 mixer beside grouped-query attention and a dense SwiGLU of
     21,504, no expert, 3.44 GB (chipbench/heads/falcon_h1_34b.py), whose
     state-space core runs in its dual form against the reference's
-    recurrence."""
+    recurrence; ``ling``: one dense and six expert layers, five Kimi Delta
+    Attention layers to one of latent attention, a shared expert beside 64
+    of 512 group-routed experts held, 5.53 GB
+    (chipbench/heads/ling_3_flash.py), whose delta rule runs in its
+    one-chunk form against the reference's recurrence."""
     import gc
 
     import jax
@@ -1122,6 +1133,7 @@ def main() -> int:
     run("backbone", phase_backbone)
     run("backbone_lfm2", phase_backbone, head_name="lfm2")
     run("backbone_falconh1", phase_backbone, head_name="falconh1")
+    run("backbone_ling", phase_backbone, head_name="ling")
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

@@ -124,11 +124,21 @@ def rope_angles(b: int, t: int, dim: int, theta: float):
     return mrope_angles(pos, dim, (dim // 2,), theta)
 
 
-def rotate(x, cos, sin):
+def rotate(x, cos, sin, interleave: bool = False):
     """Rotary embedding on the leading ``2 * cos.shape[-1]`` channels of
     ``x`` [B, T, H, D] (pair ``i`` is channels ``i`` and ``i + half``:
-    the rotate-half convention); the rest pass through."""
+    the rotate-half convention); the rest pass through. With
+    ``interleave`` (the ``ling`` head's latent attention:
+    ``rope_interleave``) pair ``i`` is channels ``2 i`` and ``2 i + 1``,
+    each turned where it lies."""
     half = cos.shape[-1]
+    if interleave:
+        pairs = x[..., :2 * half].reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        c, s = cos[:, :, None, :], sin[:, :, None, :]
+        turned = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+        return jnp.concatenate([turned.reshape(*x.shape[:-1], 2 * half),
+                                x[..., 2 * half:]], axis=-1)
     x1, x2, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
     c, s = cos[:, :, None, :], sin[:, :, None, :]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
@@ -148,22 +158,47 @@ def swiglu(x, w: Params, cfg, gate_scale=None):
     return mm(mid, w["wd"], cfg)
 
 
-def route(x, layer: Params, cfg):
-    """Sigmoid router over ALL experts, no groups: ``(experts [P, top_k]
-    int32, weights [P, top_k] float32)``, the weights the chosen scores
-    over their sum (held or not) plus ``cfg.renorm_eps``, times
-    ``cfg.routed_scale``. Where the layer has an expert bias (``rb``
-    [experts] float32: the ``lfm2`` head's, models/lfm2_backbone.py) it is
-    added to the scores that ``top_k`` reads and to nothing else: the bias
-    chooses and does not weigh. The ``pangu`` head's layers have none."""
+def route(x, layer: Params, cfg, groups: int | None = None,
+          kept_groups: int | None = None):
+    """Sigmoid router over ALL experts: ``(experts [P, top_k] int32,
+    weights [P, top_k] float32)``, the weights the chosen scores over their
+    sum (held or not) plus ``cfg.renorm_eps``, times ``cfg.routed_scale``.
+    Where the layer has an expert bias (``rb`` [experts] float32: the
+    ``lfm2`` and ``ling`` heads', models/lfm2_backbone.py) it is added to
+    the scores that ``top_k`` reads and to nothing else: the bias chooses
+    and does not weigh. The ``pangu`` head's layers have none. With
+    ``groups`` and ``kept_groups`` (the ``ling`` head's ``n_group`` and
+    ``topk_group``, models/ling_backbone.py) the experts lie in ``groups``
+    equal runs; a group's score is the sum of its two largest biased
+    scores, the ``kept_groups`` largest groups stay (equal sums: the lower
+    index), and every expert outside them is masked out of what ``top_k``
+    reads, however large its score; the weights are the unbiased scores as
+    without groups. ``pangu`` and ``lfm2`` pass none."""
     s = jax.nn.sigmoid(mm(x, layer["wr"], cfg))
-    if "rb" in layer:
+    if groups is not None:
+        chosen_by = within_kept_groups(s + layer["rb"] if "rb" in layer else s,
+                                       groups, kept_groups)
+        _, top_e = jax.lax.top_k(chosen_by, cfg.top_k)
+        top_s = jnp.take_along_axis(s, top_e, axis=-1)
+    elif "rb" in layer:
         _, top_e = jax.lax.top_k(s + layer["rb"], cfg.top_k)
         top_s = jnp.take_along_axis(s, top_e, axis=-1)
     else:
         top_s, top_e = jax.lax.top_k(s, cfg.top_k)
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
     return top_e, w * cfg.routed_scale
+
+
+def within_kept_groups(scores, groups: int, kept_groups: int):
+    """``scores`` [P, experts] with every expert outside its position's
+    ``kept_groups`` best groups at ``-inf``: the experts lie in ``groups``
+    equal runs, a group's score is the sum of its two largest scores."""
+    p, experts = scores.shape
+    by_group = scores.reshape(p, groups, experts // groups)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, kept_groups)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)  # [P, groups]
+    return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(p, experts)
 
 
 def causal_taps(z, taps, bias=None):
@@ -214,6 +249,109 @@ def attention(u, layer: Params, cos, sin, cfg, window: int, key_scale=None):
     return mm(o.reshape(b * t, nh * hd), layer["wo"], cfg)
 
 
+def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
+                           rope: int, dv: int, window: int,
+                           interleave: bool = False):
+    """The core of latent attention as three einsums over ``[b, t, h, d]``,
+    under the kernel's signature (ops/pallas/window_attention.
+    window_attention): its reference, and what runs off the TPU. ->
+    float32 [P, heads x dv], which ``Wo``'s product rounds. With
+    ``interleave`` the rotary part of ``q`` turns by interleaved pairs
+    (``rotate``); ``k_rope`` comes turned, by the same pairing."""
+    dt, t = kvb.dtype, window
+    b = q.shape[0] // t
+    q = q.reshape(b, t, heads, nope + rope)
+    q_rope = rotate(q[..., nope:], cos.reshape(b, t, -1), sin.reshape(b, t, -1),
+                    interleave)
+    kvb = kvb.reshape(b, t, heads, nope + dv)
+    sc = (jnp.einsum("bthd,bshd->bhts", q[..., :nope].astype(dt),
+                     kvb[..., :nope], preferred_element_type=jnp.float32)
+          + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt),
+                       k_rope.reshape(b, t, rope),
+                       preferred_element_type=jnp.float32))
+    sc = sc * ((nope + rope) ** -0.5)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+    o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), kvb[..., nope:],
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b * t, heads * dv)
+
+
+def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False):
+    """What runs the core of latent attention over ``q`` [P, heads x (nope
+    + rope)] and ``kvb`` [P, heads x (nope + v)] (arrays or shapes): the
+    Pallas kernel (ops/pallas/window_attention.py) on a TPU where its
+    ``supports`` holds, else ``latent_core_by_einsums``; either way a
+    function of ``(q, kvb, k_rope, cos, sin)``. Picked while tracing, from
+    backend and shapes, and announced once a compile. The kernel turns
+    rotate-half pairs, so with ``interleave`` the einsums run on every
+    backend and the announcement says why: the pairs are never re-paired
+    silently."""
+    from igaming_platform_tpu.ops.pallas import window_attention as kernel
+
+    widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
+                  dv=cfg.v_dim, window=window)
+    if interleave:
+        _, backend = kernel_declines()
+        announce_core("xla-einsum (interleaved rotary pairs: the window "
+                      "kernel turns by halves)", backend, "attention core")
+        return partial(latent_core_by_einsums, **widths, interleave=True)
+    why, backend = kernel_declines(lambda: not kernel.supports(q, kvb, **widths))
+    announce_core("xla-einsum" if why else "pallas-windows", backend,
+                  "attention core")
+    return partial(
+        latent_core_by_einsums if why else kernel.window_attention, **widths)
+
+
+def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False):
+    """Multi-head latent attention over normed hidden states ``a`` [B, T,
+    hidden], in its expanded form -> [B, T, hidden] (``pangu``: before its
+    post-norm). The core (the rotary part of ``q``, scores, mask, softmax,
+    ``p v``) is one Pallas kernel over the projections' results as they
+    lie where ``window_attention.supports`` holds on a TPU, else three
+    einsums over ``[b, t, h, d]``: the same expanded form at the same
+    precision either way. ``cfg`` gives ``heads``, ``kv_rank``,
+    ``nope_dim``, ``rope_dim``, ``v_dim`` and ``eps``.
+
+    What the ``ling`` head's layer differs by is read off the layer and one
+    argument (``pangu`` has and passes none of it): without a query latent
+    (no ``wq_a``) the queries are ``a Wq``; with ``interleave`` the rotary
+    pairs are interleaved on both sides; with a head-wise gate (``wgate``
+    [hidden, heads]) each head's output is multiplied by ``sigmoid(a
+    Wgate)`` of its head, in float32, before ``Wo`` rounds it."""
+    b, t, _ = a.shape
+    dt = cfg.operand_dtype
+    # position-major from here to the last product: [P, channels], P = B x T
+    a = a.reshape(b * t, -1)
+    with jax.named_scope("q"):
+        if "wq_a" in layer:
+            cq = rms_norm(mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
+            # heads of [q_nope | q_rope] as the product leaves them: float32,
+            # since the rotary part turns before it is rounded
+            q = mm(cq, layer["wq_b"], cfg)
+        else:
+            q = mm(a, layer["wq"], cfg)
+    with jax.named_scope("kv"):
+        kv = mm(a, layer["wkv_a"], cfg)
+        ckv = rms_norm(kv[:, :cfg.kv_rank], layer["kvn"], cfg.eps)
+        # one rotary key head, shared by every query head
+        k_rope = rotate(kv[:, cfg.kv_rank:].reshape(b, t, 1, -1), cos, sin,
+                        interleave)
+        k_rope = k_rope.astype(dt).reshape(b * t, -1)
+        # heads of [k_nope | v]: rounded before any other use
+        kvb = mm(ckv, layer["wkv_b"], cfg).astype(dt)
+    core = latent_attention_core(q, kvb, cfg, t, interleave)
+    with jax.named_scope("core"):
+        o = core(q, kvb, k_rope, cos.reshape(b * t, -1), sin.reshape(b * t, -1))
+    if "wgate" in layer:
+        with jax.named_scope("gate"):
+            gate = jax.nn.sigmoid(mm(a, layer["wgate"], cfg))  # [P, heads]
+            o = (o.astype(jnp.float32).reshape(b * t, cfg.heads, -1)
+                 * gate[:, :, None]).reshape(b * t, -1)
+    with jax.named_scope("out"):
+        return mm(o, layer["wo"], cfg).reshape(b, t, -1)
+
+
 def score_last(params: Params, hid, lengths, logit_scale=None):
     """The scoring head on final-normed hidden states ``hid`` [B, T,
     hidden]: the sigmoid of one float32 output column at each window's
@@ -243,7 +381,8 @@ def announce_core(core: str, backend: str, part: str = "expert core") -> None:
     they are the kernels how those are fed; ``combine``: the results' way
     back to position order; ``attention core``: the window kernel or the
     einsums, with the kernel's reason where it declines; ``state-space
-    core``: the form the recurrence is computed in): the choice is made at
+    core`` and ``linear-attention core``: the form the recurrence is
+    computed in): the choice is made at
     trace time and is otherwise invisible. ``announced_cores`` keeps the
     last word of each part."""
     _ANNOUNCED[part] = f"{core} (backend={backend})"

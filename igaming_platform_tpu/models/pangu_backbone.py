@@ -46,8 +46,9 @@ expanded form (keys and values of every head from the latent, every
 step); the compressed ``[ckv | k_rope]`` an account would cache in a
 decoder is not held.
 
-**Which core runs where.** The residual stream and every product of a
-layer are position-major, ``[P, channels]`` with ``P = B x T``. On a TPU,
+**Which core runs where** (``decoder_parts.latent_attention``, which the
+``ling`` head's one such layer calls too). The residual stream and every
+product of a layer are position-major, ``[P, channels]`` with ``P = B x T``. On a TPU,
 where ``ops/pallas/window_attention.supports`` holds (``T`` divides 128,
 head widths whole 64-lane halves: the published widths do), the core of
 attention (the rotary part of ``q``, scores, mask, softmax, ``p v``) is
@@ -74,7 +75,6 @@ router scores, top-k and the logit are float32.
 
 from __future__ import annotations
 
-import functools
 import math
 from dataclasses import dataclass
 from typing import Any
@@ -85,12 +85,10 @@ import jax.numpy as jnp
 from igaming_platform_tpu.models.decoder_parts import (
     Params,
     _matrix,
-    announce_core,
-    kernel_declines,
+    latent_attention,
     mm,
     rms_norm,
     rope_angles,
-    rotate,
     route,
     score_last,
     swiglu,
@@ -178,80 +176,6 @@ def init_backbone(key, cfg: PanguConfig) -> Params:
             layer["routed"] = swiglu(f, (cfg.held_experts,))
         layers.append(layer)
     return tree_around(layers, matrix((cfg.in_dim, d), cfg.in_dim), next(keys), d)
-
-
-def _core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
-                     rope: int, dv: int, window: int):
-    """The core of attention as three einsums over ``[b, t, h, d]``, under
-    the kernel's signature (ops/pallas/window_attention.window_attention):
-    its reference, and what runs off the TPU. -> float32 [P, heads x dv],
-    which ``Wo``'s product rounds."""
-    dt, t = kvb.dtype, window
-    b = q.shape[0] // t
-    q = q.reshape(b, t, heads, nope + rope)
-    q_rope = rotate(q[..., nope:], cos.reshape(b, t, -1), sin.reshape(b, t, -1))
-    kvb = kvb.reshape(b, t, heads, nope + dv)
-    sc = (jnp.einsum("bthd,bshd->bhts", q[..., :nope].astype(dt),
-                     kvb[..., :nope], preferred_element_type=jnp.float32)
-          + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt),
-                       k_rope.reshape(b, t, rope),
-                       preferred_element_type=jnp.float32))
-    sc = sc * ((nope + rope) ** -0.5)
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), kvb[..., nope:],
-                   preferred_element_type=jnp.float32)
-    return o.reshape(b * t, heads * dv)
-
-
-def _attention_core(q, kvb, cfg: PanguConfig, window: int):
-    """What runs the core of attention over ``q`` [P, heads x (nope +
-    rope)] and ``kvb`` [P, heads x (nope + v)] (arrays or shapes): the
-    Pallas kernel (ops/pallas/window_attention.py) on a TPU where its
-    ``supports`` holds, else ``_core_by_einsums``; either way a function of
-    ``(q, kvb, k_rope, cos, sin)``. Picked while tracing, from backend and
-    shapes, and announced once a compile."""
-    from igaming_platform_tpu.ops.pallas import window_attention as kernel
-
-    widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
-                  dv=cfg.v_dim, window=window)
-    why, backend = kernel_declines(lambda: not kernel.supports(q, kvb, **widths))
-    announce_core("xla-einsum" if why else "pallas-windows", backend,
-                  "attention core")
-    return functools.partial(
-        _core_by_einsums if why else kernel.window_attention, **widths)
-
-
-def latent_attention(a, layer: Params, cos, sin, cfg: PanguConfig):
-    """Multi-head latent attention over normed hidden states ``a`` [B, T,
-    hidden], in its expanded form -> [B, T, hidden] (before the
-    post-norm). The core (the rotary part of ``q``, scores, mask, softmax,
-    ``p v``) is one Pallas kernel over the projections' results as they
-    lie where ``window_attention.supports`` holds on a TPU, else three
-    einsums over ``[b, t, h, d]``: the same expanded form at the same
-    precision either way."""
-    b, t, _ = a.shape
-    dt = cfg.operand_dtype
-    # position-major from here to the last product: [P, channels], P = B x T
-    a = a.reshape(b * t, -1)
-    with jax.named_scope("q"):
-        cq = rms_norm(mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
-        # heads of [q_nope | q_rope] as the product leaves them: float32,
-        # since the rotary part turns before it is rounded
-        q = mm(cq, layer["wq_b"], cfg)
-    with jax.named_scope("kv"):
-        kv = mm(a, layer["wkv_a"], cfg)
-        ckv = rms_norm(kv[:, :cfg.kv_rank], layer["kvn"], cfg.eps)
-        # one rotary key head, shared by every query head
-        k_rope = rotate(kv[:, cfg.kv_rank:].reshape(b, t, 1, -1), cos, sin)
-        k_rope = k_rope.astype(dt).reshape(b * t, -1)
-        # heads of [k_nope | v]: rounded before any other use
-        kvb = mm(ckv, layer["wkv_b"], cfg).astype(dt)
-    core = _attention_core(q, kvb, cfg, t)
-    with jax.named_scope("core"):
-        o = core(q, kvb, k_rope, cos.reshape(b * t, -1), sin.reshape(b * t, -1))
-    with jax.named_scope("out"):
-        return mm(o, layer["wo"], cfg).reshape(b, t, -1)
 
 
 def backbone_hidden(params: Params, x, lengths, cfg: PanguConfig):

@@ -19,7 +19,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from igaming_platform_tpu.models import falconh1_backbone, keye_backbone, lfm2_backbone, pangu_backbone
+from igaming_platform_tpu.models import (
+    falconh1_backbone,
+    keye_backbone,
+    lfm2_backbone,
+    ling_backbone,
+    pangu_backbone,
+)
 from igaming_platform_tpu.models.sequence import (
     EVENT_DIM,
     SeqConfig,
@@ -103,11 +109,11 @@ def transformer_scores(sparams, window, lengths):
     return sequence_forward(sparams, window, SESSION_SEQ_CONFIG)["abuse"]
 
 
-# What a layer's operators (``conv``, ``attention``, ``ssm``) and its
-# feed-forward (``dense``, ``moe``) may be: the kinds a row's ``layers``
-# counts. A layer that runs two operators (``falconh1``: ``ssm`` beside
-# ``attention``) counts under both.
-LAYER_KINDS = ("conv", "attention", "ssm", "dense", "moe")
+# What a layer's operators (``conv``, ``attention``, ``ssm``, ``linear``:
+# linear attention) and its feed-forward (``dense``, ``moe``) may be: the
+# kinds a row's ``layers`` counts. A layer that runs two operators
+# (``falconh1``: ``ssm`` beside ``attention``) counts under both.
+LAYER_KINDS = ("conv", "attention", "ssm", "linear", "dense", "moe")
 _NO_LAYERS = dict.fromkeys(LAYER_KINDS, 0)
 
 
@@ -171,6 +177,14 @@ HEADS = {
     # added to the stream, then a dense SwiGLU of 21,504; the model's muP
     # multipliers on the branches. No expert layer: 1.72 G parameters, 3.44 GB
     "falconh1": _backbone(falconh1_backbone, falconh1_backbone.FalconH1Config()),
+    # a delta-rule linear-attention hybrid at its published widths: the
+    # source's layer 1 (a leading dense layer) and one whole period of six
+    # after it, five Kimi Delta Attention layers (32 heads of 128 keys and
+    # values, the one-chunk form) to one of latent attention by
+    # ``layer_group_size``; a dense SwiGLU of 6,144, then a shared expert
+    # beside 512 experts routed inside the 4 best of 8 groups, a chip's
+    # share of 64 held: 2.77 G parameters, 5.53 GB
+    "ling": _backbone(ling_backbone, ling_backbone.LingConfig()),
 }
 
 

@@ -875,10 +875,10 @@ class ServiceMetrics:
         self.session_head_layers = self.registry.gauge(
             f"{service}_session_head_layers",
             "Layers of the session head's stack by kind, set once at boot: "
-            "kind=conv|attention|ssm is a layer's operator (a layer that "
-            "runs two, a state-space mixer beside attention, counts under "
-            "both), kind=dense|moe its feed-forward (a head without layers "
-            "reads 0 for all five)",
+            "kind=conv|attention|ssm|linear is a layer's operator (linear: "
+            "linear attention; a layer that runs two, a state-space mixer "
+            "beside attention, counts under both), kind=dense|moe its "
+            "feed-forward (a head without layers reads 0 for all six)",
         )
         self.session_lock_wait_seconds_total = self.registry.counter(
             f"{service}_session_lock_wait_seconds_total",

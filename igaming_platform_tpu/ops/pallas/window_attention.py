@@ -1,7 +1,8 @@
 """Pallas TPU kernel: the core of attention over short windows, on the
 projections' own layouts, eight windows to an MXU tile.
 
-``models/pangu_backbone.latent_attention`` attends inside windows of ``T``
+``models/decoder_parts.latent_attention`` (the ``pangu`` head's layer)
+attends inside windows of ``T``
 positions (16 in the cell) with keys and values of every head expanded from
 a latent. Its projections leave position-major, lane-dense matrices: ``q``
 [P, heads x (nope + rope)] (float32, as the product accumulated it), ``kv``

@@ -440,6 +440,56 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
     assert "32,128,256]" == state and state not in text
 
 
+def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
+        topo, tpu_backend, capsys):
+    """The fused step with the ``ling`` backbone in it, at the cell's size
+    (3,145,728 accounts, the 256-row rung): in place on the ring, its
+    arguments are the state plus 5.53 GB of weights. Every part is under
+    the scope the trace reads it by; the delta rule is its one-chunk form,
+    so no array of a state's size (32 x 128 x 128 a window) is in the
+    module, and the taps are no ``convolution``. Hidden 2560 is not a
+    multiple of 2,048, so the held experts' rows are gathered for the
+    Pallas grouped kernels (``_gate_up``, ``_down``) inside the pass loop,
+    their way back ``_combine_held``, once an expert layer; the
+    latent-attention layer's core is the einsums (interleaved rotary
+    pairs), so no ``_window_attention``. Code, temporaries and arguments
+    are printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.session_heads import HEADS
+    from igaming_platform_tpu.serve import session_state as ss
+
+    capacity = 3_145_728
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["ling"].config
+    compiled = _compile_step("ling", capacity, capacity + 1, one, one)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nling step for a described v5e: code "
+              f"{mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 8.3e9 < mem.argument_size_in_bytes < 8.5e9, mem
+    assert mem.temp_size_in_bytes <= 2 * 2**30, mem
+    text = compiled.as_text()
+    for scope in ("head/embed", "head/kda/proj", "head/kda/conv",
+                  "head/kda/gate", "head/kda/core", "head/kda/out",
+                  "head/attn/core", "head/attn/gate", "head/mlp/dense",
+                  "head/moe/route", "head/moe/shared", "head/moe/experts",
+                  "head/score"):
+        assert scope in text, scope
+    assert "_window_attention" not in text
+    assert not [line for line in text.splitlines() if " convolution(" in line
+                and "head/kda/conv" in line]
+    state = f"{cfg.heads},{cfg.head_dim},{cfg.head_dim}]"
+    assert "32,128,128]" == state and state not in text
+    kernels = _expert_kernels(text, capsys, "ling")
+    assert kernels, "the held experts' products run as no kernel"
+
+
 @pytest.mark.parametrize("head,capacity,temps_256,in_tree", [
     ("pangu", 3_145_728, 955_600_896,
      {"_window_attention": 5, "_combine_held": 4, "ragged-dot-none": 12}),
@@ -448,7 +498,9 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4,
       "_grouped_window_attention": 4}),
     ("lfm2", 5_242_880, 308_153_856,
-     {"_gate_up": 4, "_down": 4, "_combine_rows": 4})])
+     {"_gate_up": 4, "_down": 4, "_combine_rows": 4}),
+    ("ling", 3_145_728, 984_582_656,
+     {"_gate_up": 6, "_down": 6, "_combine_held": 6})])
 def test_the_64_row_rung_compiles_beside_the_256_one(
         topo, tpu_backend, capsys, head, capacity, temps_256, in_tree):
     """The ladder's 64-row rung of each backbone's step (serve/scorer.py:

@@ -24,6 +24,7 @@ from chipbench.tests.conftest import copy as _bare_copy
 from chipbench.tests.test_bounds import *  # noqa: F401,F403
 from chipbench.tests.test_falconh1_cell import *  # noqa: F401,F403
 from chipbench.tests.test_lfm2_cell import *  # noqa: F401,F403
+from chipbench.tests.test_ling_cell import *  # noqa: F401,F403
 from chipbench.tests.test_manifest import *  # noqa: F401,F403
 from chipbench.tests.test_reference import *  # noqa: F401,F403
 from chipbench.tests.test_seam import *  # noqa: F401,F403
@@ -80,8 +81,9 @@ def test_the_manifest_keeps_the_span_metrics_together_and_validates():
 
 LAST_NINE = (
     "chipbench/tests/test_falconh1_cell.py holds PR 45's nine metrics to the "
-    "LAST nine places of per_layer; PR 46's padded_rows_per_row follows them, "
-    "for the reason LAST_EIGHT gives. A benchmark PR repairs the case: "
+    "LAST nine places of per_layer; PR 46's padded_rows_per_row follows them "
+    "(and PR 49's configuration, cell and ten metrics), for the reason "
+    "LAST_EIGHT gives. A benchmark PR repairs the case: "
     "PERF.md Open question 9")
 
 
@@ -94,15 +96,20 @@ def test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut(): 
 
 def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypatch):
     """The case above, every assertion as it stands, over the manifest cut
-    off after the nine metrics it expects last: what follows them is what
-    later PRs appended, named here."""
+    off after the nine metrics, the configuration and the cell it expects
+    last: what follows them is what later PRs appended, named here."""
     from chipbench import validate
 
     manifest = validate.load_manifest()
     names = [m["name"] for m in manifest["per_layer"]]
     last = max(names.index(name) for name in _falconh1.METRICS)
-    assert names[last + 1:] == ["padded_rows_per_row"]  # PR 46
-    cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1])
+    assert names[last + 1] == "padded_rows_per_row"  # PR 46
+    assert all(name.startswith(("ling_", "kda_")) for name in names[last + 2:])  # PR 49
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert cells[cells.index(_falconh1.CELL) + 1:] == ["ling-kda-insession"]  # PR 49
+    cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
+               configs=manifest["configs"][:cells.index(_falconh1.CELL) + 1],
+               workloads=manifest["workloads"][:cells.index(_falconh1.CELL) + 1])
     monkeypatch.setattr(_falconh1.validate, "load_manifest",
                         lambda *args, **kwargs: cut)
     _falconh1.test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut()

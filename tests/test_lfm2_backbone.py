@@ -615,7 +615,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (
         c.experts, c.experts) == (64, 64)
     assert snap["head_layers"] == {"conv": 4, "attention": 1, "ssm": 0,
-                                   "dense": 1, "moe": 4}
+                                   "linear": 0, "dense": 1, "moe": 4}
     # what the step's expert layer said it runs as when it was traced (here
     # the CPU's cores; on a TPU the kernels and how they are fed)
     assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"
@@ -674,8 +674,10 @@ def test_replay_verifies_a_ledger_written_under_the_head(small_lfm2, monkeypatch
 
 
 @pytest.mark.parametrize("name,layers", [
-    ("pattern", {"conv": 0, "attention": 0, "ssm": 0, "dense": 0, "moe": 0}),
-    ("transformer", {"conv": 0, "attention": 1, "ssm": 0, "dense": 1, "moe": 0})])
+    ("pattern", {"conv": 0, "attention": 0, "ssm": 0, "linear": 0, "dense": 0,
+                 "moe": 0}),
+    ("transformer", {"conv": 0, "attention": 1, "ssm": 0, "linear": 0,
+                     "dense": 1, "moe": 0})])
 def test_layer_gauge_of_the_small_heads(name, layers):
     from igaming_platform_tpu.obs.metrics import ServiceMetrics
     from igaming_platform_tpu.serve.session_state import SessionStateManager
@@ -693,7 +695,8 @@ def test_layer_gauge_of_the_small_heads(name, layers):
     ("keye", {"attention": 4, "moe": 4}),
     ("pangu", {"attention": 5, "dense": 1, "moe": 4}),
     ("lfm2", {"conv": 4, "attention": 1, "dense": 1, "moe": 4}),
-    ("falconh1", {"ssm": 4, "attention": 4, "dense": 4})])
+    ("falconh1", {"ssm": 4, "attention": 4, "dense": 4}),
+    ("ling", {"linear": 6, "attention": 1, "dense": 1, "moe": 6})])
 def test_every_head_says_what_its_stack_is_made_of(name, layers):
     assert session_heads.HEADS[name].layers == {
         kind: layers.get(kind, 0) for kind in session_heads.LAYER_KINDS}

@@ -275,7 +275,7 @@ def test_latent_attention_alone(head, operands, core, monkeypatch, caplog):
         steer_to_attention_kernel(monkeypatch)
 
     def sublayer(x, cos, sin):
-        o = pb.latent_attention(dp.rms_norm(x, layer["g1"], cfg.eps), layer,
+        o = dp.latent_attention(dp.rms_norm(x, layer["g1"], cfg.eps), layer,
                                 cos, sin, cfg)
         return x + dp.rms_norm(o, layer["g2"], cfg.eps)
 

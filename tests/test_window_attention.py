@@ -57,7 +57,7 @@ def einsum_form(q, kv, k_rope, cos, sin, *, widths, t):
     """The program's own einsum core (what ``latent_attention`` runs where
     the kernel does not), rounded as ``Wo``'s product rounds it."""
     nope, rope, dv = widths
-    return pb._core_by_einsums(q, kv, k_rope, cos, sin, heads=HEADS, nope=nope,
+    return dp.latent_core_by_einsums(q, kv, k_rope, cos, sin, heads=HEADS, nope=nope,
                                rope=rope, dv=dv, window=t).astype(kv.dtype)
 
 
@@ -188,7 +188,7 @@ def test_where_supports_is_false_the_layer_takes_its_einsums(monkeypatch, caplog
     pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (1, 6, 16))
     cos, sin = dp.mrope_angles(pos, 8, (4,), cfg.rope_theta)
     run = lambda: np.asarray(jax.jit(
-        lambda a, c, s: pb.latent_attention(a, layer, c, s, cfg))(a, cos, sin))
+        lambda a, c, s: dp.latent_attention(a, layer, c, s, cfg))(a, cos, sin))
     off_tpu = run()
 
     def never(*args, **kwargs):
