@@ -702,7 +702,7 @@ def phase_kernels(interpret: bool = False, *,
                   top_k: int = 8,
                   second_shape: tuple = (16384, 2048, 1536, 64, 4),
                   share_shape: tuple = (2048, 7680, 4096, 1000),
-                  grouped_windows: int = 32) -> dict:
+                  grouped_windows: int = 32, delta_windows: int = 32) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -718,7 +718,11 @@ def phase_kernels(interpret: bool = False, *,
     kernel's grouped-query form at the ``keye`` head's attention (32 query
     / 4 key heads of 128, a random mask with the diagonal kept) on
     ``grouped_windows`` windows of 16 against the einsum core, with the
-    core a trace would pick there."""
+    core a trace would pick there; and the delta-rule window kernel at the
+    ``ling`` head's mixer (32 heads of 128; the taps, the decay and the head
+    norm inside) on ``delta_windows`` windows of 16 against the mixer's XLA
+    path (``kda_one_chunk`` its core), with the core a trace would pick
+    there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -933,6 +937,34 @@ def phase_kernels(interpret: bool = False, *,
     check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
           f"grouped attention on {grouped_windows} windows: max err {err} "
           f"> {BACKBONE_TOL}")
+
+    from igaming_platform_tpu.models import ling_backbone
+    from igaming_platform_tpu.ops.pallas import delta_window as dw
+
+    cfg = ling_backbone.LingConfig()
+    n, c = delta_windows * t, cfg.heads * cfg.head_dim
+    ks = jax.random.split(jax.random.key(n + 1), 11)
+    q, k, v, f = (jax.random.normal(key, (n, c), jnp.float32) for key in ks[:4])
+    beta = jax.random.normal(ks[4], (n, cfg.heads), jnp.float32)
+    layer = {"gn": 1.0 + 0.1 * jax.random.normal(ks[5], (cfg.head_dim,), jnp.float32),
+             "a_log": jnp.log(jax.random.uniform(ks[6], (cfg.heads,), jnp.float32,
+                                                 0.5, 1.5)),
+             "dt_bias": jax.random.normal(ks[7], (c,), jnp.float32) - 1.0}
+    for name, key in zip(("tq", "tk", "tv"), ks[8:], strict=True):
+        layer[name] = jax.random.normal(key, (c, cfg.conv_taps), jnp.float32) * 0.5
+    picked = _said_by_the_expert_layer(
+        lambda: ling_backbone._core_is_the_kernel(n, cfg, t))
+    got = dw.delta_window(
+        q, k, v, f, beta, layer["a_log"], layer["dt_bias"],
+        (layer["tq"], layer["tk"], layer["tv"]), (layer["gn"], cfg.eps),
+        heads=cfg.heads, window=t, lower_bound=cfg.gate_lower_bound,
+        interpret=interpret)
+    want = jax.jit(lambda *a: ling_backbone._core_by_xla(*a, layer, cfg, t))(
+        q, k, v, f, beta)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    report[f"delta_window_W{delta_windows}"] = {"max_err": err, "core": picked[0]}
+    check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
+          f"delta rule on {delta_windows} windows: max err {err} > {BACKBONE_TOL}")
     return report
 
 
@@ -964,7 +996,8 @@ BACKBONES = {
                  "falconh1_backbone",
                  {"ssm_core": "dual form, one chunk, 16 <= 128"}),
     "ling": ("risk-seqhead-ling-3.0-flash", "ling_3_flash", "ling_backbone",
-             {"linear_core": "delta rule, one chunk, 16 <= 17",
+             {"linear_core": "pallas-windows (32 heads of 128, window 16, "
+                             "prologue=taps, norm=inside)",
               "expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
                              "rows=gathered)", "way_back": "pallas-rows",
               "attention_core": "xla-einsum (interleaved rotary pairs: the "
@@ -996,7 +1029,8 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     Attention layers to one of latent attention, a shared expert beside 64
     of 512 group-routed experts held, 5.53 GB
     (chipbench/heads/ling_3_flash.py), whose delta rule runs in its
-    one-chunk form against the reference's recurrence."""
+    one-chunk form (on a TPU inside the window kernel of
+    ops/pallas/delta_window.py) against the reference's recurrence."""
     import gc
 
     import jax

@@ -36,6 +36,17 @@ pre-norm: ``r = h + Mixer(N1(h))``, ``h' = r + FF(N2(r))``.
    (one gain of ``head_dim`` shared by the heads), gated a channel;
    ``Mixer = y Wo``.
 
+Steps 1 to 3 and the head norm of step 4 are ONE Pallas call a layer on a
+TPU (ops/pallas/delta_window.py, since PR 50) over the projections' results
+where the products wrote them, ``[P, heads x head_dim]``, wherever the
+kernel takes the shapes (``_core_is_the_kernel``: heads of whole 128-lane
+vregs, a window that divides 128 in whole 8-row vregs, whole tiles of 128
+positions, VMEM); off the TPU and where it declines they are XLA's over
+``[b, t, h, d]`` (``_core_by_xla``), ``kda_one_chunk`` the core and the
+reference the kernel is held to. Which one a step runs is said once a
+compile (``linear-attention core``: ``pallas-windows (32 heads of 128,
+window 16, prologue=taps, norm=inside)`` or ``one chunk by einsums (<why>)``).
+
 **MLA mixer** (``decoder_parts.latent_attention``, which ``pangu`` calls
 too): no query latent, a key-value latent of 512, one rotary key head of 64
 turned by INTERLEAVED pairs, 32 heads of 128 + 64 against values of 128, a
@@ -65,12 +76,17 @@ float32); the projections, the MLPs and the experts multiply
 the attention core's einsums too; everything of KDA between its projections
 (taps, ``silu``, the L2 norm, the decay, the one-chunk core, the gated norm)
 is float32, the core's products at ``Precision.HIGHEST`` on operands that
-are NOT rounded; residual stream, norms, softmax, router scores, top-k and
-the logit float32.
+are NOT rounded, in the kernel (Mosaic's float32 contraction, six bfloat16
+passes) as in the einsums: the two differ by the order of float32 sums
+alone; residual stream, norms, softmax, router scores, top-k and the logit
+float32.
 
 ``jax.named_scope`` marks the parts: ``head/embed``, ``head/kda`` (inside
-it ``proj`` with the norm, ``conv``, ``gate``, ``core``, ``out`` with the
-gated norm and the add), ``head/attn`` (inside it ``q``, ``kv``, ``core``,
+it ``proj`` with the norm, ``core``, ``out`` with the gate, ``Wo`` and the
+add; where the kernel runs, ``core`` is its one call and holds the taps,
+the decay and the head norm too; on the XLA path ``conv`` and ``gate`` are
+scopes of their own and the head norm lies under ``out``), ``head/attn``
+(inside it ``q``, ``kv``, ``core``,
 ``gate``, ``out``), ``head/mlp/dense``, ``head/moe/route`` (with the norm),
 ``head/moe/shared``, ``head/moe/experts`` (with the add), ``head/score``.
 """
@@ -177,6 +193,18 @@ def window_limit(lower_bound: float) -> int:
     """The longest window the one-chunk form takes: the running sum of a
     channel's decays stays inside what float32's ``exp`` holds."""
     return int(_EXP_ROOM // -lower_bound)
+
+
+def refuse_past_the_limit(window: int, lower_bound: float) -> None:
+    """One chunk holds a whole window or the delta rule is not computed:
+    both cores factor ``exp(G_s - G_r)`` and neither carries a state from
+    chunk to chunk."""
+    limit = window_limit(lower_bound)
+    if window > limit:
+        raise ValueError(
+            f"a window of {window} positions is longer than the one chunk the "
+            f"delta rule is computed in ({limit} positions at a gate bounded "
+            f"by {lower_bound}): exp(-G) would leave float32")
 
 
 def layer_kinds(cfg: LingConfig) -> dict[str, int]:
@@ -296,14 +324,7 @@ def kda_one_chunk(q, k, v, g, beta, *, lower_bound: float,
     fast end (e^-80) times a small key falls under float32's least normal
     value, which a TPU flushes to zero."""
     t = q.shape[1]
-    limit = window_limit(lower_bound)
-    if t > limit:
-        raise ValueError(
-            f"a window of {t} positions is longer than the one chunk the "
-            f"delta rule is computed in ({limit} positions at a gate bounded "
-            f"by {lower_bound}): exp(-G) would leave float32")
-    announce_core(f"delta rule, one chunk, {t} <= {limit}",
-                  kernel_declines()[1], "linear-attention core")
+    refuse_past_the_limit(t, lower_bound)
     highest = jax.lax.Precision.HIGHEST
     total = jnp.cumsum(g, axis=1)                       # G [B, T, H, dk]
     total = total - total[:, t // 2, None]
@@ -321,15 +342,62 @@ def kda_one_chunk(q, k, v, g, beta, *, lower_bound: float,
                       precision=highest)
 
 
+def _core_is_the_kernel(positions: int, cfg: LingConfig, window: int) -> bool:
+    """Whether everything of a KDA mixer between its projections and its
+    output gate over ``positions`` positions in windows of ``window`` runs
+    as the Pallas kernel (ops/pallas/delta_window.py: on a TPU, where it
+    takes the shapes) or as ``_core_by_xla``. Picked while tracing, from backend and
+    shapes, and announced once a compile, with the kernel's reason where
+    it declines. A window past ``window_limit`` is refused either way."""
+    from igaming_platform_tpu.ops.pallas import delta_window as kernel
+
+    refuse_past_the_limit(window, cfg.gate_lower_bound)
+    nh, hd = cfg.heads, cfg.head_dim
+    why, backend = kernel_declines(lambda: kernel.declines(
+        positions, heads=nh, head_dim=hd, window=window))
+    announce_core(
+        f"one chunk by einsums ({why})" if why else
+        f"pallas-windows ({nh} heads of {hd}, window {window}, prologue=taps, "
+        "norm=inside)",
+        backend, "linear-attention core")
+    return not why
+
+
 def kda_mixer(u, layer: Params, cfg: LingConfig, window: int):
     """Kimi Delta Attention over normed hidden states ``u`` [P, hidden] ->
-    [P, hidden]."""
-    nh, hd, t = cfg.heads, cfg.head_dim, window
-    b = u.shape[0] // t
+    [P, hidden]. Everything between the projections and the output gate
+    (the taps, ``silu``, the L2 norm, the decay, the one-chunk core, the
+    head norm) is one Pallas kernel over the projections' results as they
+    lie where ``_core_is_the_kernel`` finds that it takes the layer;
+    elsewhere the same arithmetic by XLA over ``[b, t, h, d]``,
+    ``kda_one_chunk`` its core: float32 with no operand rounded either
+    way."""
+    from igaming_platform_tpu.ops.pallas import delta_window as kernel
+
+    nh, t = cfg.heads, window
     with jax.named_scope("proj"):
         q, k, v, f, z = (mm(u, layer[name], cfg)
                          for name in ("wq", "wk", "wv", "wf", "wg"))
         beta = mm(u, layer["wb"], cfg)
+    if _core_is_the_kernel(u.shape[0], cfg, t):
+        with jax.named_scope("core"):
+            y = kernel.delta_window(
+                q, k, v, f, beta, layer["a_log"], layer["dt_bias"],
+                (layer["tq"], layer["tk"], layer["tv"]), (layer["gn"], cfg.eps),
+                heads=nh, window=t, lower_bound=cfg.gate_lower_bound)
+    else:
+        y = _core_by_xla(q, k, v, f, beta, layer, cfg, t)
+    with jax.named_scope("out"):
+        return mm(y * jax.nn.sigmoid(z), layer["wo"], cfg)
+
+
+def _core_by_xla(q, k, v, f, beta, layer: Params, cfg: LingConfig, t: int):
+    """The projections' results [P, heads x head_dim] (``beta`` [P, heads])
+    -> the head-normed core's result [P, heads x head_dim], as the kernel
+    returns it: the taps, the decay, ``kda_one_chunk`` and the head norm
+    over ``[b, t, h, d]``."""
+    nh, hd = cfg.heads, cfg.head_dim
+    b = q.shape[0] // t
     with jax.named_scope("conv"):
         def conv(x, taps):
             return jax.nn.silu(causal_taps(x.reshape(b, t, -1), layer[taps]))
@@ -349,9 +417,7 @@ def kda_mixer(u, layer: Params, cfg: LingConfig, window: int):
     with jax.named_scope("core"):
         o = kda_one_chunk(q, k, v, g, beta, lower_bound=cfg.gate_lower_bound)
     with jax.named_scope("out"):
-        y = (rms_norm(o, layer["gn"], cfg.eps).reshape(b * t, nh * hd)
-             * jax.nn.sigmoid(z))
-        return mm(y, layer["wo"], cfg)
+        return rms_norm(o, layer["gn"], cfg.eps).reshape(b * t, nh * hd)
 
 
 def backbone_hidden(params: Params, x, lengths, cfg: LingConfig):

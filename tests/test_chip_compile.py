@@ -447,7 +447,14 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
     arguments are the state plus 5.53 GB of weights. Every part is under
     the scope the trace reads it by; the delta rule is its one-chunk form,
     so no array of a state's size (32 x 128 x 128 a window) is in the
-    module, and the taps are no ``convolution``. Hidden 2560 is not a
+    module, and the taps are no ``convolution``. Since PR 50 everything of
+    a KDA mixer between its projections and its output gate (the taps,
+    ``silu``, the L2 norm, the decay, the delta core, the head norm) is ONE
+    Pallas call a layer, ``_delta_window`` (ops/pallas/delta_window.py)
+    under ``head/kda/core``, over the projections' own ``[4096, 4096]``
+    results: six calls, the scopes ``head/kda/conv`` and ``head/kda/gate``
+    inside them (``test_delta_core_is_one_call_a_layer`` holds a mixer
+    alone to the layouts that are gone). Hidden 2560 is not a
     multiple of 2,048, so the held experts' rows are gathered for the
     Pallas grouped kernels (``_gate_up``, ``_down``) inside the pass loop,
     their way back ``_combine_held``, once an expert layer; the
@@ -475,19 +482,69 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
     assert 8.3e9 < mem.argument_size_in_bytes < 8.5e9, mem
     assert mem.temp_size_in_bytes <= 2 * 2**30, mem
     text = compiled.as_text()
-    for scope in ("head/embed", "head/kda/proj", "head/kda/conv",
-                  "head/kda/gate", "head/kda/core", "head/kda/out",
+    for scope in ("head/embed", "head/kda/proj", "head/kda/core", "head/kda/out",
                   "head/attn/core", "head/attn/gate", "head/mlp/dense",
                   "head/moe/route", "head/moe/shared", "head/moe/experts",
                   "head/score"):
         assert scope in text, scope
     assert "_window_attention" not in text
     assert not [line for line in text.splitlines() if " convolution(" in line
-                and "head/kda/conv" in line]
+                and ("head/kda/conv" in line or "head/kda/core" in line)]
     state = f"{cfg.heads},{cfg.head_dim},{cfg.head_dim}]"
     assert "32,128,128]" == state and state not in text
     kernels = _expert_kernels(text, capsys, "ling")
     assert kernels, "the held experts' products run as no kernel"
+    delta = [line for line in text.splitlines() if "tpu_custom_call" in line
+             and "custom-call(" in line and "_delta_window" in line]
+    assert len(delta) == 6 and all("head/kda/core" in line for line in delta)
+    # the einsum core's float32 passes over [256, 16, 32, 128] and the
+    # substitution's row stacks are gone
+    assert mem.temp_size_in_bytes <= LING_TEMPS_256, (
+        f"{mem.temp_size_in_bytes} B of temporaries; {LING_TEMPS_256} with the "
+        f"delta kernel (PR 50), 984582656 with the einsum core (PR 49)")
+
+
+# What the ``ling`` step holds in temporaries at the 256-row rung since PR 50,
+# the delta kernel in it (984,582,656 B with the einsum core, PR 49); the
+# 64-row rung reads 54,972,928 B (132,454,912 at PR 49), and the step's
+# code 27.4 / 23.4 MB (34.3 MB at the 64-row rung at PR 49: the unrolled
+# substitution is out of the program).
+LING_TEMPS_256 = 479_243_264
+
+
+@pytest.mark.parametrize("batch", [256, 64])
+def test_delta_core_is_one_call_a_layer(topo, tpu_backend, batch):
+    """One KDA mixer compiled alone at each rung's positions (the step's
+    one latent-attention layer has score stacks of the same shapes; the
+    steps themselves are held to six ``_delta_window`` calls above and
+    below): one custom call, under the scope ``core`` where ``kda_core_ms``
+    reads it, and nothing of the einsum core's layouts in the compiled
+    text: no ``[b, 32, 16, 16]`` scores or stacks, no ``[512, 8, 32, 128]``
+    re-layout of the projections' results or of the core's, no ``[b, 16,
+    32, 128]`` or heads-first ``[b, 32, 16, 128]`` copy of q, k, v or g;
+    between the projections and ``Wo`` only ``[P, 4096]``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models import ling_backbone as lb
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = lb.LingConfig()
+    layer = jax.eval_shape(
+        lambda: lb.init_backbone(jax.random.key(0), cfg))["layers"][0]
+    layer = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one), layer)
+    u = _spec((batch * 16, cfg.hidden), jnp.float32, one)
+    text = jax.jit(lambda u, layer: lb.kda_mixer(u, layer, cfg, 16)).lower(
+        u, layer).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line
+             and "custom-call(" in line and "_delta_window" in line]
+    assert len(calls) == 1 and "/core/" in calls[0]
+    for layout in (f"[{batch},32,16,16]", f"[{batch},32,16,128]",
+                   f"[{2 * batch},8,32,128]", f"[{batch},16,32,128]",
+                   f"[{batch},16,4096]"):
+        assert layout not in text, layout
+    assert f"f32[{batch * 16},4096]" in text
 
 
 @pytest.mark.parametrize("head,capacity,temps_256,in_tree", [
@@ -499,8 +556,8 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
       "_grouped_window_attention": 4}),
     ("lfm2", 5_242_880, 308_153_856,
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4}),
-    ("ling", 3_145_728, 984_582_656,
-     {"_gate_up": 6, "_down": 6, "_combine_held": 6})])
+    ("ling", 3_145_728, LING_TEMPS_256,
+     {"_gate_up": 6, "_down": 6, "_combine_held": 6, "_delta_window": 6})])
 def test_the_64_row_rung_compiles_beside_the_256_one(
         topo, tpu_backend, capsys, head, capacity, temps_256, in_tree):
     """The ladder's 64-row rung of each backbone's step (serve/scorer.py:

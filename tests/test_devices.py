@@ -183,7 +183,7 @@ def test_smoke_kernels_phase_tiny_interpreted():
         interpret=True, attention_shapes=((1, 2, 64, 32),),
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
         expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
-        share_shape=(64, 128, 32, 20), grouped_windows=11)
+        share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8)
     assert report["interpret"] is True
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
@@ -206,3 +206,8 @@ def test_smoke_kernels_phase_tiny_interpreted():
     grouped = report["grouped_attention_W11"]
     assert grouped["max_err"] <= chip_smoke.BACKBONE_TOL
     assert grouped["core"] == "attention core: einsum (not a TPU) (backend=cpu)"
+    # the delta-rule window kernel (ling's mixer) on one tile of 8 windows
+    delta = report["delta_window_W8"]
+    assert delta["max_err"] <= chip_smoke.BACKBONE_TOL
+    assert delta["core"] == ("linear-attention core: one chunk by einsums "
+                             "(not a TPU) (backend=cpu)")

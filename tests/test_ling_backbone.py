@@ -689,6 +689,137 @@ def test_each_mixer_and_the_feed_forward_alone(head, tree, operands, part):
     assert np.abs(got - np.asarray(x)).max() > 1e-2  # the sublayer adds something
 
 
+# -- the delta core as the window kernel (ops/pallas/delta_window.py) ------------------
+
+
+def wide_source() -> dict:
+    """The small size with KDA heads as wide as the kernel takes them: 2
+    heads of 128 keys and values."""
+    return small_source(num_attention_heads=2, num_key_value_heads=2, head_dim=128)
+
+
+@pytest.fixture
+def linear_core_by_kernel(monkeypatch, caplog):
+    """Runs ``fn`` as a TPU would trace the KDA mixers, the delta kernel
+    through the Pallas interpreter, and returns what it computed with what
+    the linear-attention core said. Steered here, in the test, and for this
+    part alone (the expert layer and latent attention ask ``decoder_parts``
+    themselves and stay on the CPU's cores)."""
+    import functools
+
+    from igaming_platform_tpu.ops.pallas import delta_window as dw
+
+    def run(fn):
+        dp.announce_core.cache_clear()
+        caplog.clear()
+        with monkeypatch.context() as m, caplog.at_level("INFO", logger=dp.logger.name):
+            m.setattr(lb, "kernel_declines",
+                      lambda declines=None: (declines and declines(), "tpu"))
+            m.setattr(dw, "delta_window", functools.partial(
+                dw.delta_window, interpret=True))
+            out = fn()
+        said = [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("linear-attention core")]
+        dp.announce_core.cache_clear()
+        return out, said
+    return run
+
+
+KERNEL_SAYS = ("linear-attention core: pallas-windows (2 heads of 128, window "
+               "16, prologue=taps, norm=inside) (backend=tpu)")
+
+
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_head_through_the_delta_kernel_equals_the_reference(
+        head, operands, linear_core_by_kernel):
+    """The whole head with every KDA mixer's core the Pallas kernel (8
+    windows: one tile of 128 positions, mixed lengths) against the plain
+    reference's recurrence, under the limits the einsum path meets
+    (``test_head_equals_the_reference``), and against the einsum path
+    itself."""
+    dt = jnp.dtype(operands)
+    source = wide_source()
+    cfg = program_config(source, operand_dtype=dt)
+    params = head.make_params(7, source)
+    d = head.dims_of(source)
+    x, lens = windows(8, (1, 4, 16, 7, 9, 2), seed=7)
+    (logit, hidden), said = linear_core_by_kernel(
+        lambda: program_logits(cfg, params, x, lens))
+    assert said == [KERNEL_SAYS]
+    want_logit = head._logits(params, x, lens, d, dt)
+    want_hidden = head._logits(params, x, lens, d, dt, hidden=True)
+    atol = 5e-5 if operands == "float32" else ROUNDING
+    np.testing.assert_allclose(logit, want_logit, atol=atol, rtol=0)
+    np.testing.assert_allclose(hidden, want_hidden, atol=atol, rtol=0)
+    by_einsum, _ = program_logits(cfg, params, x, lens)
+    np.testing.assert_allclose(logit, by_einsum, atol=atol, rtol=0)
+    assert np.std(want_logit) > 0.01
+
+
+def test_mixer_through_the_delta_kernel_equals_the_einsum_path(
+        head, linear_core_by_kernel):
+    """One KDA sublayer on a stream with spread, float32 operands: the
+    projections, the gate and ``Wo`` are the same XLA either way, so what
+    differs is the order of float32 sums between them."""
+    source = wide_source()
+    cfg = program_config(source, operand_dtype=jnp.float32)
+    layer = head.make_params(7, source)["layers"][1]
+    u = stream(rows=8, seed=9).reshape(-1, 128)
+    mixer = lambda: np.asarray(jax.jit(
+        lambda x: lb.kda_mixer(x, layer, cfg, 16))(u))
+    by_kernel, said = linear_core_by_kernel(mixer)
+    by_einsum = mixer()
+    assert said == [KERNEL_SAYS]
+    assert dp.announced_cores()["linear-attention core"] == (
+        "one chunk by einsums (not a TPU) (backend=cpu)")
+    scale = np.abs(by_einsum).max()
+    assert scale > 1e-3
+    np.testing.assert_allclose(by_kernel, by_einsum, atol=2e-6 * max(scale, 1.0),
+                               rtol=0)
+
+
+@pytest.mark.parametrize("backend,over,window,said", [
+    ("cpu", {}, 16, "one chunk by einsums (not a TPU) (backend=cpu)"),
+    ("tpu", {}, 16, "pallas-windows (32 heads of 128, window 16, prologue=taps, "
+                    "norm=inside) (backend=tpu)"),
+    ("tpu", {"head_dim": 96}, 16, "one chunk by einsums (head width 96 is not "
+                                  "whole 128-lane vregs) (backend=tpu)"),
+    ("tpu", {}, 12, "one chunk by einsums (windows of 12 are not whole 8-row "
+                    "vregs that divide a tile of 128) (backend=tpu)"),
+    ("tpu", {}, 8, "pallas-windows (32 heads of 128, window 8, prologue=taps, "
+                   "norm=inside) (backend=tpu)"),
+], ids=["off-the-tpu", "published", "head96", "window12", "window8"])
+def test_linear_attention_core_is_announced_with_the_reason_it_declines(
+        backend, over, window, said, monkeypatch, caplog):
+    """The choice is made while tracing, from the backend and the layer's
+    shapes alone; the boot's log line and ``/debug/sessionz``'s
+    ``head_cores`` carry it, with the kernel's own reason beside ``one
+    chunk by einsums``."""
+    cfg = lb.LingConfig(**over)
+    dp.announce_core.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with caplog.at_level("INFO", logger=dp.logger.name):
+        by_kernel = lb._core_is_the_kernel(256 * window, cfg, window)
+    assert by_kernel is said.startswith("pallas-windows")
+    assert [r.getMessage() for r in caplog.records] == [
+        f"linear-attention core: {said}"]
+    assert dp.announced_cores()["linear-attention core"] == said
+    dp.announce_core.cache_clear()
+
+
+def test_a_part_filled_tile_takes_the_einsums(monkeypatch):
+    """The 64-row rung is 1,024 positions and the 256-row one 4,096: whole
+    tiles. A batch that is not (a test's, 6 windows) declines in the
+    kernel's words and runs ``kda_one_chunk``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dp.announce_core.cache_clear()
+    assert not lb._core_is_the_kernel(6 * 16, lb.LingConfig(), 16)
+    assert dp.announced_cores()["linear-attention core"] == (
+        "one chunk by einsums (96 positions are not whole tiles of 128) "
+        "(backend=tpu)")
+    dp.announce_core.cache_clear()
+
+
 def test_the_attention_core_says_why_it_is_the_einsums(tree, caplog, monkeypatch):
     """The window kernel turns rotate-half pairs: with interleaved pairs the
     core is the einsums on every backend, a TPU too, and the announcement
@@ -864,7 +995,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert snap["head_layers"] == LAYERS
     # which cores the step said it runs when it was traced
     assert snap["head_cores"]["linear-attention core"] == (
-        "delta rule, one chunk, 16 <= 17 (backend=cpu)")
+        "one chunk by einsums (not a TPU) (backend=cpu)")
     assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"
     assert snap["head_cores"]["combine"] == "xla-gather (backend=cpu)"
     assert snap["head_cores"]["attention core"].startswith(
@@ -967,7 +1098,7 @@ def test_chip_smoke_phase_runs_the_head_against_its_reference():
     assert report["max_err"] < 1e-4 and report["rows"] == 8
     assert report["head"] == "ling"
     assert report["linear_core"] == (
-        "linear-attention core: delta rule, one chunk, 16 <= 17 (backend=cpu)")
+        "linear-attention core: one chunk by einsums (not a TPU) (backend=cpu)")
     assert report["expert_core"] == "expert core: xla-ragged-dot (backend=cpu)"
     assert report["way_back"] == "combine: xla-gather (backend=cpu)"
     assert report["attention_core"].startswith(
