@@ -165,40 +165,82 @@ def route(x, layer: Params, cfg, groups: int | None = None,
     sum (held or not) plus ``cfg.renorm_eps``, times ``cfg.routed_scale``.
     Where the layer has an expert bias (``rb`` [experts] float32: the
     ``lfm2`` and ``ling`` heads', models/lfm2_backbone.py) it is added to
-    the scores that ``top_k`` reads and to nothing else: the bias chooses
+    the scores the choice reads and to nothing else: the bias chooses
     and does not weigh. The ``pangu`` head's layers have none. With
     ``groups`` and ``kept_groups`` (the ``ling`` head's ``n_group`` and
     ``topk_group``, models/ling_backbone.py) the experts lie in ``groups``
     equal runs; a group's score is the sum of its two largest biased
     scores, the ``kept_groups`` largest groups stay (equal sums: the lower
-    index), and every expert outside them is masked out of what ``top_k``
+    index), and every expert outside them is masked out of what the choice
     reads, however large its score; the weights are the unbiased scores as
-    without groups. ``pangu`` and ``lfm2`` pass none."""
-    s = jax.nn.sigmoid(mm(x, layer["wr"], cfg))
+    without groups. ``pangu`` and ``lfm2`` pass none.
+
+    The choice is ``cfg.top_k`` rounds of max-and-mask
+    (``largest_by_rounds``) over the scores laid experts-first (``mm_t``:
+    [experts, P], so a round reduces down the sublanes and never across
+    lanes): what ``lax.top_k`` would give, with no sort. A chosen expert's
+    unbiased score is read back as ``sum(where(iota == e, s, 0))`` over
+    the experts, the score itself plus zeros: no gather and no product
+    that could round it."""
+    s = jax.nn.sigmoid(mm_t(layer["wr"], x, cfg))           # [experts, P]
+    chosen_by = s + layer["rb"][:, None] if "rb" in layer else s
     if groups is not None:
-        chosen_by = within_kept_groups(s + layer["rb"] if "rb" in layer else s,
-                                       groups, kept_groups)
-        _, top_e = jax.lax.top_k(chosen_by, cfg.top_k)
-        top_s = jnp.take_along_axis(s, top_e, axis=-1)
-    elif "rb" in layer:
-        _, top_e = jax.lax.top_k(s + layer["rb"], cfg.top_k)
-        top_s = jnp.take_along_axis(s, top_e, axis=-1)
-    else:
-        top_s, top_e = jax.lax.top_k(s, cfg.top_k)
+        chosen_by = within_kept_groups(chosen_by, groups, kept_groups)
+    top_s, top_e = largest_by_rounds(chosen_by, cfg.top_k)  # [top_k, P]
+    if chosen_by is not s:
+        expert = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        top_s = jnp.stack([jnp.sum(jnp.where(expert == e, s, 0.0), axis=0)
+                           for e in top_e])
+    # the two small results position-major, as the expert layer reads them
+    top_e, top_s = top_e.T, top_s.T
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
     return top_e, w * cfg.routed_scale
 
 
+@partial(jax.jit, static_argnums=1)
+def largest_by_rounds(scores, k: int):
+    """``lax.top_k`` down the second-to-last axis of ``scores`` [..., n, P]
+    by ``k`` rounds of max-and-mask: ``(values, indices)`` [..., k, P],
+    largest first. A round takes the maximum of what is left, then the
+    lowest index that holds it; what is left for the next round is every
+    entry after that pick in ``top_k``'s order (a smaller value, or the
+    same value at a higher index), so no round writes a masked copy and
+    each reads ``scores`` alone. Round ``j`` therefore picks the ``j``-th
+    entry of the sort by (value descending, index ascending), which is
+    ``top_k``'s: equal values go to the lower index, and ``-inf`` entries
+    come last in index order, since an entry not left reads ``-inf`` to
+    the maximum and is kept out of the index by the same test. Jitted so
+    that a step traces each (shape, ``k``) once for all its layers: the
+    rounds are unrolled, and what a boot traces it pays for at every boot
+    (PERF.md section 6, PR 50 and PR 51)."""
+    index = jax.lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 2)
+    n = scores.shape[-2]
+    left = True                 # before the first round: every entry
+    values, indices = [], []
+    for _ in range(k):
+        v = jnp.max(jnp.where(left, scores, -jnp.inf), axis=-2, keepdims=True)
+        i = jnp.min(jnp.where(left & (scores == v), index, n), axis=-2,
+                    keepdims=True)
+        values.append(v)
+        indices.append(i)
+        left = (scores < v) | ((scores == v) & (index > i))
+    return (jnp.concatenate(values, axis=-2),
+            jnp.concatenate(indices, axis=-2))
+
+
 def within_kept_groups(scores, groups: int, kept_groups: int):
-    """``scores`` [P, experts] with every expert outside its position's
+    """``scores`` [experts, P] with every expert outside its position's
     ``kept_groups`` best groups at ``-inf``: the experts lie in ``groups``
-    equal runs, a group's score is the sum of its two largest scores."""
-    p, experts = scores.shape
-    by_group = scores.reshape(p, groups, experts // groups)
-    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
-    _, kept = jax.lax.top_k(group_score, kept_groups)
-    keep = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)  # [P, groups]
-    return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(p, experts)
+    equal runs, a group's score is the sum of its two largest scores. Both
+    selections are ``largest_by_rounds``: two rounds down each run, then
+    ``kept_groups`` rounds down the groups' scores."""
+    experts, p = scores.shape
+    by_group = scores.reshape(groups, experts // groups, p)
+    group_score = jnp.sum(largest_by_rounds(by_group, 2)[0], axis=-2)
+    _, kept = largest_by_rounds(group_score, kept_groups)   # [kept_groups, P]
+    keep = jnp.any(kept[:, None, :] == jnp.arange(groups)[None, :, None],
+                   axis=0)                                  # [groups, P]
+    return jnp.where(keep[:, None, :], by_group, -jnp.inf).reshape(experts, p)
 
 
 def causal_taps(z, taps, bias=None):

@@ -191,6 +191,22 @@ def _assert_rows_come_in_by_the_kernel(text: str, pairs: int) -> None:
 
 
 
+def _assert_the_router_sorts_and_gathers_nothing(text: str, positions: int,
+                                                 top_k: int) -> None:
+    """``decoder_parts.route`` chooses by rounds of max-and-mask since PR 51:
+    no ``sort`` of the compiled step carries its scope (each ``lax.top_k``
+    was one: three a layer in ``ling``'s step), and the chosen scores are
+    read back by compare-and-sum, so no gather yields ``f32[P, top_k]``
+    (``take_along_axis`` lowered to one under the bare op_name ``gather``,
+    without the scope, so it is told by its shape)."""
+    lines = text.splitlines()
+    assert "head/moe/route" in text
+    assert not [line for line in lines if "head/moe/route" in line
+                and (" sort(" in line or " gather(" in line)]
+    assert not [line for line in lines if re.search(
+        rf"= f32\[{positions},{top_k}\]\S* gather\(", line)]
+
+
 @pytest.mark.parametrize("head", ["pattern", "transformer"])
 def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
     from jax.sharding import SingleDeviceSharding
@@ -347,6 +363,8 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
     assert len(in_tree) == 9, in_tree
     scores = f"[{BATCH},{cfg.heads},{ss.default_events()},{ss.default_events()}]"
     assert scores == "[256,128,16,16]" and scores not in text
+    _assert_the_router_sorts_and_gathers_nothing(
+        text, BATCH * ss.default_events(), cfg.top_k)
 
 
 def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
@@ -395,6 +413,8 @@ def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
     assert "head/conv/taps" in text and "head/attn" in text
     assert not [line for line in text.splitlines()
                 if " convolution(" in line and "head/conv/taps" in line]
+    _assert_the_router_sorts_and_gathers_nothing(
+        text, BATCH * ss.default_events(), cfg.top_k)
 
 
 def test_state_space_step_fits_beside_the_state_and_holds_no_state(
@@ -497,19 +517,23 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
     delta = [line for line in text.splitlines() if "tpu_custom_call" in line
              and "custom-call(" in line and "_delta_window" in line]
     assert len(delta) == 6 and all("head/kda/core" in line for line in delta)
+    _assert_the_router_sorts_and_gathers_nothing(
+        text, BATCH * ss.default_events(), cfg.top_k)
     # the einsum core's float32 passes over [256, 16, 32, 128] and the
     # substitution's row stacks are gone
     assert mem.temp_size_in_bytes <= LING_TEMPS_256, (
-        f"{mem.temp_size_in_bytes} B of temporaries; {LING_TEMPS_256} with the "
-        f"delta kernel (PR 50), 984582656 with the einsum core (PR 49)")
+        f"{mem.temp_size_in_bytes} B of temporaries; {LING_TEMPS_256} since the "
+        f"router sorts nothing (PR 51), 479243264 with the delta kernel (PR "
+        f"50), 984582656 with the einsum core (PR 49)")
 
 
-# What the ``ling`` step holds in temporaries at the 256-row rung since PR 50,
-# the delta kernel in it (984,582,656 B with the einsum core, PR 49); the
-# 64-row rung reads 54,972,928 B (132,454,912 at PR 49), and the step's
-# code 27.4 / 23.4 MB (34.3 MB at the 64-row rung at PR 49: the unrolled
-# substitution is out of the program).
-LING_TEMPS_256 = 479_243_264
+# What the ``ling`` step holds in temporaries at the 256-row rung since PR 51,
+# the routers' sorts out of it (479,243,264 B at PR 50 with the delta kernel,
+# 984,582,656 with the einsum core, PR 49); the 64-row rung reads 52,811,776 B
+# (54,972,928 at PR 50, 132,454,912 at PR 49), and the step's code 26.4 / 22.8
+# MB (34.3 MB at the 64-row rung at PR 49: the unrolled substitution is out
+# of the program).
+LING_TEMPS_256 = 441_004_032
 
 
 @pytest.mark.parametrize("batch", [256, 64])
@@ -548,13 +572,13 @@ def test_delta_core_is_one_call_a_layer(topo, tpu_backend, batch):
 
 
 @pytest.mark.parametrize("head,capacity,temps_256,in_tree", [
-    ("pangu", 3_145_728, 955_600_896,
+    ("pangu", 3_145_728, 950_890_496,
      {"_window_attention": 5, "_combine_held": 4, "ragged-dot-none": 12}),
     ("falconh1", 5_242_880, 631_744_000, {}),
     ("keye", 5_242_880, 373_297_152,
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4,
       "_grouped_window_attention": 4}),
-    ("lfm2", 5_242_880, 308_153_856,
+    ("lfm2", 5_242_880, 302_540_288,
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4}),
     ("ling", 3_145_728, LING_TEMPS_256,
      {"_gate_up": 6, "_down": 6, "_combine_held": 6, "_delta_window": 6})])
@@ -574,7 +598,10 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
     here (PR 46): 211,406,336 B ``pangu``, 142,620,160 ``falconh1``,
     20,904,960 ``keye``, 56,169,472 ``lfm2``; code 12.1-19.4 MB. Since PR
     47 ``keye``'s steps hold the window kernel's grouped form once a layer
-    (22,832,640 B at this rung, 373,297,152 at the 256-row one)."""
+    (22,832,640 B at this rung, 373,297,152 at the 256-row one). Since PR
+    51 the three steps that route through ``decoder_parts.route`` sort and
+    gather nothing under it at this rung either (210,599,936 B ``pangu``,
+    56,169,472 ``lfm2``, 52,811,776 ``ling``)."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.serve import session_state as ss
@@ -601,6 +628,11 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
         name = re.match(r"\s*%([\w-]+?)(\.\d+)? = ", line).group(1)
         found[name] = found.get(name, 0) + 1
     assert found == in_tree, found
+    if head in ("pangu", "lfm2", "ling"):    # who routes by decoder_parts.route
+        from igaming_platform_tpu.models.session_heads import HEADS
+
+        _assert_the_router_sorts_and_gathers_nothing(
+            compiled.as_text(), 64 * ss.default_events(), HEADS[head].config.top_k)
 
 
 @pytest.mark.parametrize("head,sketch,sha256", [

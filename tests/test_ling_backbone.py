@@ -492,7 +492,8 @@ def test_an_expert_outside_the_kept_groups_is_never_chosen():
     scores[:, 0] = 0.99                     # the largest score of all
     scores[:, 8:16] += 0.1                  # two groups clearly ahead
     scores[:, 16:24] += 0.2
-    kept = np.asarray(dp.within_kept_groups(jnp.asarray(scores), 4, 2))
+    # the scores go in experts-first, [experts, P], and come back so
+    kept = np.asarray(dp.within_kept_groups(jnp.asarray(scores.T), 4, 2)).T
     assert np.all(np.isneginf(kept[:, :8])) and np.all(np.isneginf(kept[:, 24:]))
     np.testing.assert_array_equal(kept[:, 8:24], scores[:, 8:24])
     top = np.asarray(jax.lax.top_k(jnp.asarray(kept), 4)[1])
@@ -507,15 +508,98 @@ def _body(fn, *args) -> str:
                      if "func.func" not in line and "module @" not in line)
 
 
-def _route_of_the_parent(x, layer, cfg):
+def _route_of_the_parent(x, layer, cfg, groups=None, kept_groups=None):
+    """``decoder_parts.route`` and ``within_kept_groups`` as PR 50 had them:
+    three ``lax.top_k`` (each a sort) and ``take_along_axis`` (a gather),
+    over scores laid positions-first."""
     s = jax.nn.sigmoid(dp.mm(x, layer["wr"], cfg))
-    if "rb" in layer:
-        _, top_e = jax.lax.top_k(s + layer["rb"], cfg.top_k)
-        top_s = jnp.take_along_axis(s, top_e, axis=-1)
-    else:
+    chosen_by = s + layer["rb"] if "rb" in layer else s
+    if groups is not None:
+        p, experts = s.shape
+        by_group = chosen_by.reshape(p, groups, experts // groups)
+        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, kept_groups)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)
+        chosen_by = jnp.where(keep[:, :, None], by_group,
+                              -jnp.inf).reshape(p, experts)
+    if chosen_by is s:
         top_s, top_e = jax.lax.top_k(s, cfg.top_k)
+    else:
+        _, top_e = jax.lax.top_k(chosen_by, cfg.top_k)
+        top_s = jnp.take_along_axis(s, top_e, axis=-1)
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
     return top_e, w * cfg.routed_scale
+
+
+def _exact_router(kind: str, hidden: int, experts: int, positions: int, seed: int):
+    """``(x, wr, logits)`` whose products and sums are exact in float32 in
+    any order (small integers against multiples of 1/8, exact in bfloat16
+    too), so the router's product is the same number however it is laid
+    out and what is compared is the choice: ``rounded`` logits are
+    multiples of 1/8 with many equal pairs; ``saturated`` ones are
+    multiples of 4, so the sigmoid is 1.0 in many experts of a position;
+    ``few-values`` has three distinct columns in ``wr``, so every position
+    holds three distinct scores at most, fewer than any ``top_k`` here."""
+    rng = np.random.default_rng(seed)
+    x = rng.integers(-2, 3, (positions, hidden)).astype(np.float32)
+    wr = rng.integers(-2, 3, (hidden, experts)).astype(np.float32)
+    if kind == "few-values":
+        wr = wr[:, rng.integers(0, 3, experts)]
+    wr *= 4.0 if kind == "saturated" else 0.125
+    return x, wr, x @ wr
+
+
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["rounded", "saturated", "zero-bias",
+                                  "few-values"])
+@pytest.mark.parametrize("caller,experts,groups,kept_groups,top_k,biased", [
+    ("ling", 512, 8, 4, 8, True), ("lfm2", 64, None, None, 4, True),
+    ("pangu", 256, None, None, 8, False)])
+def test_route_by_rounds_is_the_sorts_and_the_gather_bit_for_bit(
+        caller, experts, groups, kept_groups, top_k, biased, kind, operands):
+    """``decoder_parts.route`` (PR 51: rounds of max-and-mask over scores
+    laid experts-first, the chosen scores read back by compare-and-sum)
+    against the formulation it replaced, at the three callers' routers
+    (512 experts in 8 groups of which 4 are kept, 8 chosen, a bias; 64, 4
+    chosen, a bias; 256, 8 chosen, bare): the same experts in the same
+    order and the same weights, bit for bit, where scores are equal in
+    pairs, where they saturate at 1.0, where the bias is zero (so equal
+    scores stay equal) and where a position holds fewer distinct scores
+    than ``top_k`` asks for: every tie goes to the lower index in both."""
+    hidden, positions = 64, 96
+    cfg = types.SimpleNamespace(operand_dtype=jnp.dtype(operands), top_k=top_k,
+                                renorm_eps=1e-20, routed_scale=2.5)
+    x, wr, logits = _exact_router(kind, hidden, experts, positions, seed=experts)
+    layer = {"wr": jnp.asarray(wr, jnp.bfloat16)}
+    if biased:
+        rng = np.random.default_rng(1)
+        layer["rb"] = jnp.asarray(
+            np.zeros(experts) if kind == "zero-bias"
+            else np.round(rng.normal(0, 0.3, experts) * 8) / 8, jnp.float32)
+    # the inputs are what their names say
+    distinct = [len(np.unique(row)) for row in logits]
+    if kind == "few-values":
+        assert max(distinct) <= 3 < top_k
+    elif kind == "saturated":
+        assert np.mean(logits >= 20) > 0.2       # sigmoid(20) rounds to 1.0
+    else:
+        assert min(distinct) < experts           # equal scores in a position
+    now = jax.jit(lambda x: dp.route(x, layer, cfg, groups, kept_groups))(x)
+    then = jax.jit(lambda x: _route_of_the_parent(
+        x, layer, cfg, groups, kept_groups))(x)
+    for a, b in zip(now, then, strict=True):
+        assert a.shape == b.shape == (positions, top_k) and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if groups is not None:
+        per = experts // groups
+        assert all(len(set(row // per)) <= kept_groups for row in np.asarray(now[0]))
+
+
+def _lowers_no_selection_by_sort(fn, *args) -> bool:
+    """Neither a sort (``lax.top_k`` lowers to ``chlo.top_k`` or a sort)
+    nor a gather is in the lowered text of ``fn``."""
+    text = fn.lower(*args).as_text()
+    return not any(op in text for op in ("top_k", "sort", "gather"))
 
 
 def _rotate_of_the_parent(x, cos, sin):
@@ -581,7 +665,8 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
     ``latent_attention`` with a query latent, rotate-half pairs and no gate
     (``pangu``; moved to models/decoder_parts.py): the same values and,
     names aside, the same StableHLO as the parent commit's, written out
-    above."""
+    above. Since PR 51 ``route`` keeps the values and not the text: it
+    lowers to no sort and no gather, where the parent's lowers to both."""
     dt = jnp.dtype(operands)
     if shared.startswith("route"):
         if shared == "route":
@@ -592,7 +677,12 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
             cfg = _small_lfm2(dt)
             layer = dict(lfm.init_backbone(jax.random.key(1), cfg)["layers"][1])
             layer["rb"] = jax.random.normal(jax.random.key(3), (cfg.experts,)) * 0.2
-        args = (jax.random.normal(jax.random.key(2), (48, cfg.hidden), jnp.float32),)
+        # since PR 51 ``route`` is the parent's in its values and no longer in
+        # its text (rounds where the sorts were, the product experts-first),
+        # so the router here has sums that are exact in any order
+        x, wr, _ = _exact_router("rounded", cfg.hidden, cfg.experts, 48, seed=2)
+        layer = dict(layer, wr=jnp.asarray(wr, jnp.bfloat16))
+        args = (jnp.asarray(x),)
         now = jax.jit(lambda x: dp.route(x, layer, cfg))
         then = jax.jit(lambda x: _route_of_the_parent(x, layer, cfg))
     elif shared == "rotate":
@@ -613,18 +703,23 @@ def test_a_widened_function_without_its_new_argument_is_the_parents_bit_for_bit(
     for a, b in zip(jax.tree.leaves(now(*args)), jax.tree.leaves(then(*args)),
                     strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert _body(now, *args) == _body(then, *args)
+    if shared.startswith("route"):
+        assert _lowers_no_selection_by_sort(now, *args)
+        assert not _lowers_no_selection_by_sort(then, *args)
+    else:
+        assert _body(now, *args) == _body(then, *args)
 
 
 @pytest.mark.parametrize("name,sha256", [
-    ("keye", "97b106f2039253a0"), ("pangu", "3b1ad6dc001c49cb"),
-    ("lfm2", "619bcfbac3323ea6")])
+    ("keye", "97b106f2039253a0"), ("pangu", "6f25d45683d24dfe"),
+    ("lfm2", "ce10c44bd93b8d8d")])
 def test_the_backbones_that_share_the_widened_functions_lower_as_before(name, sha256):
     """``pangu``, ``lfm2`` and ``keye`` at the small sizes and under the
     digests tests/test_falconh1_backbone.py pins (computed at PR 45's and PR
-    47's parents, held across PR 48's move): ``route`` without groups,
-    ``rotate`` by halves and ``latent_attention`` without its new arguments
-    leave their lowered ``backbone_scores`` byte for byte."""
+    47's parents, held across PR 48's move; ``pangu``'s and ``lfm2``'s
+    replaced at PR 51, whose ``route`` sorts nothing): ``rotate`` by halves
+    and ``latent_attention`` without its new arguments leave their lowered
+    ``backbone_scores`` byte for byte."""
     if name == "keye":
         cfg = kb.BackboneConfig(hidden=128, layers=2, heads=4, kv_heads=2,
                                 head_dim=32, experts=8, top_k=2, expert_width=64,
