@@ -1002,10 +1002,19 @@ BACKBONES = {
                              "rows=gathered)", "way_back": "pallas-rows",
               "attention_core": "xla-einsum (interleaved rotary pairs: the "
                                 "window kernel turns by halves)"}),
+    # every expert held and the padding left out: the share's passes at a
+    # third shape (hidden 3584: the rows gathered, 4,608 a pass)
+    "xing": ("risk-seqhead-xing4.0-29b-a4b", "xing4_29b_a4b", "xing_backbone",
+             {"residual_path": "hyper-connections, 4 streams, 20 Sinkhorn "
+                               "rounds",
+              "expert_core": "pallas-grouped (tm=256, ts=64, slots=3/4, "
+                             "rows=gathered)", "way_back": "pallas-rows",
+              "attention_core": "pallas-windows"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
               "attention_core": "attention core", "ssm_core": "state-space core",
-              "linear_core": "linear-attention core"}
+              "linear_core": "linear-attention core",
+              "residual_path": "residual path"}
 
 
 def phase_backbone(*, head_name: str = "pangu", cfg=None,
@@ -1030,7 +1039,12 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     of 512 group-routed experts held, 5.53 GB
     (chipbench/heads/ling_3_flash.py), whose delta rule runs in its
     one-chunk form (on a TPU inside the window kernel of
-    ops/pallas/delta_window.py) against the reference's recurrence."""
+    ops/pallas/delta_window.py) against the reference's recurrence;
+    ``xing``: one dense and four expert layers of latent attention with
+    YaRN under hyper-connections of four residual streams, a shared expert
+    beside 64 bias-chosen experts, every one held, 6.22 GB
+    (chipbench/heads/xing4_29b_a4b.py), whose maps the program computes
+    positions along the lanes against the reference's stream by stream."""
     import gc
 
     import jax
@@ -1168,6 +1182,7 @@ def main() -> int:
     run("backbone_lfm2", phase_backbone, head_name="lfm2")
     run("backbone_falconh1", phase_backbone, head_name="falconh1")
     run("backbone_ling", phase_backbone, head_name="ling")
+    run("backbone_xing", phase_backbone, head_name="xing")
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

@@ -117,11 +117,52 @@ def mrope_angles(pos3, head_dim: int, sections, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def rope_angles(b: int, t: int, dim: int, theta: float):
+def rope_angles(b: int, t: int, dim: int, theta: float, scaling=None):
     """One rotary stream on all ``dim`` channels of ``b`` windows, position
-    = the event's index in its window: (cos, sin) [B, T, dim // 2]."""
+    = the event's index in its window: (cos, sin) [B, T, dim // 2]. With
+    ``scaling`` (a ``rope_scaling`` group of type ``yarn``: the ``xing``
+    head's, models/xing_backbone.py) pair ``i`` turns by
+    ``yarn_frequencies``' rate in the place of ``theta ** (-2 i / dim)``,
+    and cos and sin are multiplied by ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``; every other head passes none."""
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (1, b, t))
-    return mrope_angles(pos, dim, (dim // 2,), theta)
+    if scaling is None:
+        return mrope_angles(pos, dim, (dim // 2,), theta)
+    ang = pos[0].astype(jnp.float32)[..., None] * jnp.asarray(
+        yarn_frequencies(dim, theta, scaling), jnp.float32)
+    m = (yarn_mscale(scaling["factor"], scaling.get("mscale", 1.0))
+         / yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0.0)))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def yarn_frequencies(dim: int, theta: float, scaling) -> np.ndarray:
+    """YaRN's ``dim // 2`` rotary rates (float64): pair ``i``'s plain rate
+    ``f_i = theta ** (-2 i / dim)`` stays where the pair turns more than
+    ``beta_fast`` times over the original context (``i <= low``), is
+    divided by ``factor`` where it turns less than ``beta_slow`` times
+    (``i >= high``), and between the two is ``f_i (1 - r_i) + f_i / factor
+    r_i`` with ``r_i = (i - low) / (high - low)``: whatever the sequence
+    length, so a 16-position window turns by them too."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    plain = theta ** (-2.0 * i / dim)
+
+    def pair_of(turns: float) -> float:
+        return (dim * math.log(scaling["original_max_position_embeddings"]
+                               / (turns * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    low = max(math.floor(pair_of(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(scaling["beta_slow"])), dim - 1)
+    r = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return plain * (1.0 - r) + plain / scaling["factor"] * r
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 where the
+    context is not stretched). The softmax scale of a latent-attention
+    layer is multiplied by ``yarn_mscale(factor, mscale_all_dim) ** 2``
+    (``latent_attention``'s ``scale_by``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def rotate(x, cos, sin, interleave: bool = False):
@@ -293,13 +334,14 @@ def attention(u, layer: Params, cos, sin, cfg, window: int, key_scale=None):
 
 def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
                            rope: int, dv: int, window: int,
-                           interleave: bool = False):
+                           interleave: bool = False, scale_by: float = 1.0):
     """The core of latent attention as three einsums over ``[b, t, h, d]``,
     under the kernel's signature (ops/pallas/window_attention.
     window_attention): its reference, and what runs off the TPU. ->
     float32 [P, heads x dv], which ``Wo``'s product rounds. With
     ``interleave`` the rotary part of ``q`` turns by interleaved pairs
-    (``rotate``); ``k_rope`` comes turned, by the same pairing."""
+    (``rotate``); ``k_rope`` comes turned, by the same pairing. The scores
+    are scaled by ``(nope + rope) ** -0.5`` times ``scale_by``."""
     dt, t = kvb.dtype, window
     b = q.shape[0] // t
     q = q.reshape(b, t, heads, nope + rope)
@@ -311,7 +353,7 @@ def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
           + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt),
                        k_rope.reshape(b, t, rope),
                        preferred_element_type=jnp.float32))
-    sc = sc * ((nope + rope) ** -0.5)
+    sc = sc * ((nope + rope) ** -0.5 * scale_by)
     causal = jnp.tril(jnp.ones((t, t), bool))
     p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
     o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), kvb[..., nope:],
@@ -319,7 +361,8 @@ def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
     return o.reshape(b * t, heads * dv)
 
 
-def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False):
+def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False,
+                          scale_by: float = 1.0):
     """What runs the core of latent attention over ``q`` [P, heads x (nope
     + rope)] and ``kvb`` [P, heads x (nope + v)] (arrays or shapes): the
     Pallas kernel (ops/pallas/window_attention.py) on a TPU where its
@@ -328,24 +371,28 @@ def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False):
     backend and shapes, and announced once a compile. The kernel turns
     rotate-half pairs, so with ``interleave`` the einsums run on every
     backend and the announcement says why: the pairs are never re-paired
-    silently."""
+    silently. ``scale_by`` multiplies the softmax scale in either core (the
+    ``xing`` head's YaRN factor; 1 for the others)."""
     from igaming_platform_tpu.ops.pallas import window_attention as kernel
 
     widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
                   dv=cfg.v_dim, window=window)
+    scaled = dict(scale_by=scale_by)
     if interleave:
         _, backend = kernel_declines()
         announce_core("xla-einsum (interleaved rotary pairs: the window "
                       "kernel turns by halves)", backend, "attention core")
-        return partial(latent_core_by_einsums, **widths, interleave=True)
+        return partial(latent_core_by_einsums, **widths, **scaled,
+                       interleave=True)
     why, backend = kernel_declines(lambda: not kernel.supports(q, kvb, **widths))
     announce_core("xla-einsum" if why else "pallas-windows", backend,
                   "attention core")
-    return partial(
-        latent_core_by_einsums if why else kernel.window_attention, **widths)
+    return partial(latent_core_by_einsums if why else kernel.window_attention,
+                   **widths, **scaled)
 
 
-def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False):
+def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False,
+                     scale_by: float = 1.0):
     """Multi-head latent attention over normed hidden states ``a`` [B, T,
     hidden], in its expanded form -> [B, T, hidden] (``pangu``: before its
     post-norm). The core (the rotary part of ``q``, scores, mask, softmax,
@@ -360,7 +407,9 @@ def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False):
     (no ``wq_a``) the queries are ``a Wq``; with ``interleave`` the rotary
     pairs are interleaved on both sides; with a head-wise gate (``wgate``
     [hidden, heads]) each head's output is multiplied by ``sigmoid(a
-    Wgate)`` of its head, in float32, before ``Wo`` rounds it."""
+    Wgate)`` of its head, in float32, before ``Wo`` rounds it. ``scale_by``
+    (the ``xing`` head's: YaRN's attention factor squared) multiplies the
+    softmax scale ``(nope + rope) ** -0.5``."""
     b, t, _ = a.shape
     dt = cfg.operand_dtype
     # position-major from here to the last product: [P, channels], P = B x T
@@ -382,7 +431,7 @@ def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False):
         k_rope = k_rope.astype(dt).reshape(b * t, -1)
         # heads of [k_nope | v]: rounded before any other use
         kvb = mm(ckv, layer["wkv_b"], cfg).astype(dt)
-    core = latent_attention_core(q, kvb, cfg, t, interleave)
+    core = latent_attention_core(q, kvb, cfg, t, interleave, scale_by)
     with jax.named_scope("core"):
         o = core(q, kvb, k_rope, cos.reshape(b * t, -1), sin.reshape(b * t, -1))
     if "wgate" in layer:
@@ -392,6 +441,102 @@ def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False):
                  * gate[:, :, None]).reshape(b * t, -1)
     with jax.named_scope("out"):
         return mm(o, layer["wo"], cfg).reshape(b, t, -1)
+
+
+def stream_squares(xs):
+    """``sum(vec(x)^2)`` a position over the streams ``xs`` (``n`` arrays [P,
+    hidden]) -> [P]: what the maps' norm divides by, before its mean. Taken
+    where the streams are written (``models/xing_backbone.hyper_sublayer``
+    takes it under the write's scope), it costs no pass of its own."""
+    return sum(jnp.sum(x * x, axis=-1) for x in xs)
+
+
+def hyper_maps(xs, hc: Params, cfg, squares=None):
+    """The three maps of a hyper-connected sublayer from its ``n`` streams
+    ``xs`` (a sequence of [P, hidden] float32 arrays: each stream an array
+    of its own, so that a pass over all of them is one fusion with ``n``
+    operands and, where it writes them, ``n`` results) and the sublayer's own
+    ``hc``: ``phi`` [n x hidden, 2 n + n^2], ``b`` [2 n + n^2] and ``a`` [3]
+    (pre, post, res), all float32 -> ``(pre [n, P], post [n, P], res [n, n,
+    P])``, positions along the lanes:
+
+    ``m = (vec(x) phi) (mean(vec(x)^2) + cfg.eps)^-1/2``, an RMSNorm over
+    all ``n x hidden`` numbers of a position without a gain, its division
+    after the product, which multiplies unrounded float32 operands
+    (``Precision.HIGHEST``: 2 n + n^2 columns, a stream's rows of ``phi``
+    against that stream, summed over the streams); ``pre = sigmoid(a_pre
+    m[:n] + b[:n])``, ``post = 2 sigmoid(a_post m[n:2n] + b[n:2n])``, ``res =
+    sinkhorn(clip(a_res mat(m[2n:]) + mat(b[2n:]), cfg.hc_clip))``: ``res[i,
+    j]`` is what stream ``i`` takes of stream ``j`` (``sinkhorn``). ``cfg``
+    gives ``eps``, ``hc_clip`` (low, high), ``hc_rounds`` and ``hc_eps``.
+    ``squares`` is ``stream_squares(xs)`` where the caller has it already
+    (from the pass that wrote the streams)."""
+    n = len(xs)
+    p, hidden = xs[0].shape
+    phi = hc["phi"].reshape(n, hidden, -1)
+    # [2 n + n^2, P], as ``mm_t`` lays a product: positions along the lanes
+    m = sum(jax.lax.dot_general(phi[i], x, (((0,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+            for i, x in enumerate(xs))
+    if squares is None:
+        squares = stream_squares(xs)
+    m = m * jax.lax.rsqrt(squares / (n * hidden) + cfg.eps)
+    # ``a`` a row of ``m``: pre, post and res in that order
+    a = jnp.repeat(hc["a"], np.array([n, n, n * n]),
+                   total_repeat_length=2 * n + n * n)
+    z = m * a[:, None] + hc["b"][:, None]
+    pre = jax.nn.sigmoid(z[:n])
+    post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+    res = sinkhorn(jnp.clip(z[2 * n:], *cfg.hc_clip).reshape(n, n, p),
+                   cfg.hc_rounds, cfg.hc_eps)
+    return pre, post, res
+
+
+def sinkhorn(z, rounds: int, eps: float):
+    """``exp(z)`` [n, n, ...], then ``rounds`` times each column divided by
+    its sum + ``eps`` and each row by its sum + ``eps``: towards a matrix
+    whose rows and columns sum to 1 (the last division leaves the rows'
+    sums there; the columns' follow where the alternation has converged,
+    which 20 rounds do not reach where the logits lie at a clip's bounds).
+    The sums are written out as adds of the ``n`` slices, so a round is
+    elementwise over what follows the two leading axes and the rounds
+    fuse. With no round it is ``exp(z)``."""
+    def total(m, axis):
+        parts = [jax.lax.index_in_dim(m, i, axis) for i in range(m.shape[axis])]
+        return sum(parts[1:], parts[0])
+
+    m = jnp.exp(z)
+    for _ in range(rounds):
+        m = m / (total(m, 0) + eps)   # a column: over the rows i
+        m = m / (total(m, 1) + eps)   # a row: over the columns j
+    return m
+
+
+def hyper_read(xs, pre):
+    """What a hyper-connected sublayer reads: ``u = sum_i pre[i] x[i]``,
+    streams ``xs`` (``n`` arrays [P, hidden]) and ``pre`` [n, P] -> [P,
+    hidden]. The result stands behind an ``optimization_barrier``: it is
+    made once, in a pass of its own over the streams, where XLA would else
+    recompute it from all ``n`` streams inside each of its consumers (the
+    norm's sum of squares and the norm itself: my chip runs, PR 52)."""
+    u = sum(pre[i][:, None] * x for i, x in enumerate(xs))
+    return jax.lax.optimization_barrier(u)
+
+
+def hyper_write(xs, res, post, y):
+    """What a hyper-connected sublayer leaves: ``x'[i] = sum_j res[i, j]
+    x[j] + post[i] y`` over streams ``xs`` (``n`` arrays [P, hidden]),
+    ``res`` [n, n, P], ``post`` [n, P] and the sublayer's result ``y`` [P,
+    hidden] -> a tuple of ``n`` float32 arrays [P, hidden]. With one stream
+    and the maps at 1 it is ``x + y``. ``y`` stands behind an
+    ``optimization_barrier``, so the ``n`` results are siblings over the
+    same operands (one pass over the streams for all of them) and none is
+    the epilogue of the product that made ``y``."""
+    y = jax.lax.optimization_barrier(y)
+    return tuple(
+        sum((res[i, j][:, None] * x for j, x in enumerate(xs)),
+            post[i][:, None] * y) for i in range(len(xs)))
 
 
 def score_last(params: Params, hid, lengths, logit_scale=None):
@@ -424,7 +569,9 @@ def announce_core(core: str, backend: str, part: str = "expert core") -> None:
     back to position order; ``attention core``: the window kernel or the
     einsums, with the kernel's reason where it declines; ``state-space
     core`` and ``linear-attention core``: the form the recurrence is
-    computed in): the choice is made at
+    computed in; ``residual path``: how many streams a layer carries and
+    the rounds of its mixing map, where that is not the one stream):
+    the choice is made at
     trace time and is otherwise invisible. ``announced_cores`` keeps the
     last word of each part."""
     _ANNOUNCED[part] = f"{core} (backend={backend})"

@@ -1,5 +1,6 @@
 """The dropless expert layer the backbones with experts call (``keye``,
-``lfm2``: every expert held; ``pangu``: a chip's share), owned by no model.
+``lfm2``: every expert held; ``pangu``, ``ling``: a chip's share; ``xing``:
+every expert held and a window's padding left out), owned by no model.
 
 ``grouped_experts`` takes positions, the router's choice and weights (each
 backbone's own router made them) and the stacked expert weights held here;
@@ -117,19 +118,22 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
     (position, expert) pair whose expert is held is computed, whatever
     the routing, and a pair whose expert lies elsewhere is never gathered
     or multiplied: what it would add is left out, and nothing stands in
-    for the chip that holds it. With ``live`` [P] bool (a share only) the
-    pairs of positions that are not live, a window's padding, are left
-    out the same way.
+    for the chip that holds it. With ``live`` [P] bool the pairs of
+    positions that are not live, a window's padding, are left out the same
+    way: by the share's passes, also where every expert is held (the
+    ``xing`` head: its share is all of them, and the pairs left out are
+    the padding's alone).
 
     The pairs are sorted by local expert (absent ones take a key past the
     last and sort behind), so each held expert's rows are contiguous and
     the three products run grouped over the stacked weights
     (``_expert_products``).
 
-    - Every expert held: one pass over all pairs; the results return to
+    - Every expert held and every position routed: one pass over all
+      pairs; the results return to
       position order by the inverse permutation and are summed over a
       position's experts in float32.
-    - A share: the held pairs are worked ``pass_rows`` at a time by a loop
+    - A share, or a ``live`` mask: the held pairs are worked ``pass_rows`` at a time by a loop
       whose trip count is ``ceil(held pairs / pass_rows)`` (one pass at a
       routing anywhere near uniform, more under skew, none where no pair
       is held). Each pass gathers its rows, multiplies them, and every
@@ -148,9 +152,8 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
 
     n, k = top_e.shape
     held = layer["wg"].shape[0]
-    everything = held == cfg.experts
+    everything = held == cfg.experts and live is None
     if everything:
-        assert live is None, "every position is routed where every expert is held"
         flat_e = top_e.reshape(-1)
     else:
         local = top_e - first_expert

@@ -25,6 +25,7 @@ from igaming_platform_tpu.models import (
     lfm2_backbone,
     ling_backbone,
     pangu_backbone,
+    xing_backbone,
 )
 from igaming_platform_tpu.models.sequence import (
     EVENT_DIM,
@@ -185,6 +186,13 @@ HEADS = {
     # beside 512 experts routed inside the 4 best of 8 groups, a chip's
     # share of 64 held: 2.77 G parameters, 5.53 GB
     "ling": _backbone(ling_backbone, ling_backbone.LingConfig()),
+    # one dense and four expert layers of a hyper-connected latent-attention
+    # backbone at its published widths: four residual streams a layer, mixed
+    # around each sublayer by a map of 20 Sinkhorn rounds;
+    # latent attention of 32 heads with YaRN; a shared expert beside 64
+    # bias-chosen experts of width 1,024, every one held: 3.11 G parameters,
+    # 6.22 GB
+    "xing": _backbone(xing_backbone, xing_backbone.XingConfig()),
 }
 
 

@@ -880,6 +880,13 @@ class ServiceMetrics:
             "beside attention, counts under both), kind=dense|moe its "
             "feed-forward (a head without layers reads 0 for all six)",
         )
+        self.session_head_residual_streams = self.registry.gauge(
+            f"{service}_session_head_residual_streams",
+            "Residual streams a layer of the session head carries, set once "
+            "at boot: 1 for a head that threads one stream through h + "
+            "f(norm(h)) (and for one without layers), more where a layer "
+            "mixes several around each sublayer (hyper-connections)",
+        )
         self.session_lock_wait_seconds_total = self.registry.counter(
             f"{service}_session_lock_wait_seconds_total",
             "Seconds index-mode chunks waited for the session lock "

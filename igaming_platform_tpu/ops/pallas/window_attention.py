@@ -199,10 +199,10 @@ def _kernel(q_ref, kv_ref, kr_ref, cos_ref, sin_ref, o_ref, *, window: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "nope", "rope", "dv", "window", "group", "interpret"))
+    "heads", "nope", "rope", "dv", "window", "group", "interpret", "scale_by"))
 def _window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
                       rope: int, dv: int, window: int, group: int,
-                      interpret: bool):
+                      interpret: bool, scale_by: float = 1.0):
     p = q.shape[0]
     qk, kvw = nope + rope, nope + dv
     unit = _unit(nope, rope, dv)
@@ -220,7 +220,7 @@ def _window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
     by_tile = lambda i, g: (i, 0)
     return pl.pallas_call(
         functools.partial(_kernel, window=window, nope=nope, rope=rope, dv=dv,
-                          unit=unit, scale=qk ** -0.5),
+                          unit=unit, scale=qk ** -0.5 * scale_by),
         out_shape=jax.ShapeDtypeStruct((p, heads * dv), kv.dtype),
         grid=(p // _TILE, heads // group),
         in_specs=[pl.BlockSpec((_TILE, group * qk), by_group),
@@ -245,7 +245,7 @@ def _window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
 
 def window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
                      rope: int, dv: int, window: int,
-                     interpret: bool = False):
+                     interpret: bool = False, scale_by: float = 1.0):
     """Causal attention inside windows of ``window`` consecutive positions,
     every head against its own keys and the one shared rotary key.
 
@@ -256,7 +256,9 @@ def window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
     in the operands' dtype; ``cos``, ``sin`` [P, rope / 2] float32, a
     position's rotary angles -> [P, heads x dv] in the operands' dtype: per
     head ``softmax((q_nope k_nope^T + rot(q_rope) k_rope^T) / sqrt(nope +
-    rope)) v`` over the keys of the query's window at or before it. P is
+    rope)) v`` over the keys of the query's window at or before it (the
+    scores times ``scale_by`` besides: a rotary scaling's attention factor,
+    1 without one). P is
     whole windows; a last tile that the windows do not fill is padded with
     zeros here and cut from the result. ``interpret=True`` runs the Pallas
     interpreter, the only way to run the kernel off the TPU, and always the
@@ -269,7 +271,7 @@ def window_attention(q, kv, k_rope, cos, sin, *, heads: int, nope: int,
     group = _heads_per_step(heads, _unit(nope, rope, dv))
     out = _window_attention(q, kv, k_rope, cos, sin, heads=heads, nope=nope,
                             rope=rope, dv=dv, window=window, group=group,
-                            interpret=interpret)
+                            interpret=interpret, scale_by=scale_by)
     return out[:p] if pad else out
 
 

@@ -484,6 +484,8 @@ class SessionStateManager:
         self.head_experts = row.experts
         # what the stack is made of: layers by kind, a kind it lacks at 0
         self.head_layers = dict(row.layers)
+        # residual streams a layer carries: one but for a hyper-connected head
+        self.head_residual_streams = getattr(row.config, "streams", 1)
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}
@@ -552,7 +554,7 @@ class SessionStateManager:
         self._export_head()
 
     def _export_head(self) -> None:
-        """What the head holds and is made of, fixed at boot: three gauges
+        """What the head holds and is made of, fixed at boot: four gauges
         and one a kind of layer."""
         m = self._metrics
         if m is not None:
@@ -561,6 +563,7 @@ class SessionStateManager:
             m.session_head_experts_routed.set(self.head_experts[1])
             for kind, count in self.head_layers.items():
                 m.session_head_layers.set(count, kind=kind)
+            m.session_head_residual_streams.set(self.head_residual_streams)
 
     def _export(self, warm: int, cold: int, bypass: int, appends: int,
                 rehydrations: int, regrows: int = 0,
@@ -628,6 +631,7 @@ class SessionStateManager:
                 "head_experts_held": self.head_experts[0],
                 "head_experts_routed": self.head_experts[1],
                 "head_layers": dict(self.head_layers),
+                "head_residual_streams": self.head_residual_streams,
                 "head_cores": announced_cores(),
                 "lock_wait_s": self.lock_wait_s,
                 "lock_held_s": self.lock_held_s,

@@ -527,6 +527,75 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
         f"50), 984582656 with the einsum core (PR 49)")
 
 
+def test_hyper_connected_step_fits_beside_the_state_and_keeps_its_scopes(
+        topo, tpu_backend, capsys):
+    """The fused step with the ``xing`` backbone in it, at the cell's size
+    (3,145,728 accounts, the 256-row rung): in place on the ring, its
+    arguments are the state plus 6.22 GB of weights, and the four float32
+    streams (235 MB a copy) are what its temporaries are made of. The three
+    ``head/hc/*`` scopes, attention's and the expert layer's are all in the
+    compiled module; the core of attention is ``_window_attention`` once a
+    layer at ``pangu``'s head widths; every expert is held and a window's
+    padding is left out, so the pairs go through the share's pass loop:
+    hidden 3584 is no multiple of 2,048, so the rows are gathered for the
+    Pallas grouped kernels (``_gate_up``, ``_down``) and the way back is
+    ``_combine_held``, once an expert layer; ``pass_rows`` is what
+    ``combine`` keeps in VMEM (4,608 rows of 3,584 float32). No ``[P, n,
+    n]`` array with the two small axes minor is in it: the maps lie
+    positions along the lanes. Code, temporaries and arguments are
+    printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.expert_layer import pass_rows
+    from igaming_platform_tpu.models.session_heads import HEADS
+    from igaming_platform_tpu.serve import session_state as ss
+
+    capacity = 3_145_728
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["xing"].config
+    compiled = _compile_step("xing", capacity, capacity + 1, one, one)
+    ring = ss.ring_size(capacity + 1, ss.default_events())
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nxing step for a described v5e: code "
+              f"{mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 9.0e9 < mem.argument_size_in_bytes < 9.1e9, mem
+    assert mem.temp_size_in_bytes <= XING_TEMPS_256, mem
+    text = compiled.as_text()
+    for scope in ("head/embed", "head/hc/maps", "head/hc/read", "head/hc/write",
+                  "head/attn/core", "head/mlp/dense", "head/moe/route",
+                  "head/moe/shared", "head/moe/experts", "head/exit"):
+        assert scope in text, scope
+    positions = BATCH * ss.default_events()
+    pairs = positions * cfg.top_k
+    assert pass_rows(pairs, cfg.experts, cfg.experts, cfg.hidden) == 4608
+    assert f"[{pairs},{cfg.hidden}]" not in text
+    assert f"f32[{positions},{cfg.streams},{cfg.streams}]" not in text
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line
+             and "pallas_call" in line]
+    found = {}
+    for line in calls:
+        name = re.match(r"\s*%([\w-]+?)(\.\d+)? = ", line).group(1)
+        found[name] = found.get(name, 0) + 1
+    moe = cfg.layers - cfg.dense_layers
+    assert found == {"_window_attention": cfg.layers, "_gate_up": moe,
+                     "_down": moe, "_combine_held": moe}, found
+    assert all("head/attn/core" in c for c in calls if "_window_attention" in c)
+    assert _expert_kernels(text, capsys, "xing")
+    _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
+
+
+# What the ``xing`` step holds in temporaries at the 256-row rung (PR 52):
+# the four float32 streams (235 MB) four times over (the maps, what is read,
+# what is written and a sublayer's own intermediates).
+XING_TEMPS_256 = 931_794_432
+
+
 # What the ``ling`` step holds in temporaries at the 256-row rung since PR 51,
 # the routers' sorts out of it (479,243,264 B at PR 50 with the delta kernel,
 # 984,582,656 with the einsum core, PR 49); the 64-row rung reads 52,811,776 B
@@ -581,7 +650,9 @@ def test_delta_core_is_one_call_a_layer(topo, tpu_backend, batch):
     ("lfm2", 5_242_880, 302_540_288,
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4}),
     ("ling", 3_145_728, LING_TEMPS_256,
-     {"_gate_up": 6, "_down": 6, "_combine_held": 6, "_delta_window": 6})])
+     {"_gate_up": 6, "_down": 6, "_combine_held": 6, "_delta_window": 6}),
+    ("xing", 3_145_728, XING_TEMPS_256,
+     {"_window_attention": 5, "_gate_up": 4, "_down": 4, "_combine_held": 4})])
 def test_the_64_row_rung_compiles_beside_the_256_one(
         topo, tpu_backend, capsys, head, capacity, temps_256, in_tree):
     """The ladder's 64-row rung of each backbone's step (serve/scorer.py:
@@ -628,7 +699,7 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
         name = re.match(r"\s*%([\w-]+?)(\.\d+)? = ", line).group(1)
         found[name] = found.get(name, 0) + 1
     assert found == in_tree, found
-    if head in ("pangu", "lfm2", "ling"):    # who routes by decoder_parts.route
+    if head in ("pangu", "lfm2", "ling", "xing"):  # who routes by decoder_parts.route
         from igaming_platform_tpu.models.session_heads import HEADS
 
         _assert_the_router_sorts_and_gathers_nothing(

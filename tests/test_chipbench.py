@@ -32,6 +32,7 @@ from chipbench.tests.test_seam import sourced as _seam_sourced
 from chipbench.tests.test_span_metrics import *  # noqa: F401,F403
 from chipbench.tests.test_trace_reduce import *  # noqa: F401,F403
 from chipbench.tests.test_traffic import *  # noqa: F401,F403
+from chipbench.tests.test_xing_cell import *  # noqa: F401,F403
 
 
 LAST_EIGHT = (
@@ -82,7 +83,7 @@ def test_the_manifest_keeps_the_span_metrics_together_and_validates():
 LAST_NINE = (
     "chipbench/tests/test_falconh1_cell.py holds PR 45's nine metrics to the "
     "LAST nine places of per_layer; PR 46's padded_rows_per_row follows them "
-    "(and PR 49's configuration, cell and ten metrics), for the reason "
+    "(and PR 49's and PR 52's configurations, cells and ten metrics each), for the reason "
     "LAST_EIGHT gives. A benchmark PR repairs the case: "
     "PERF.md Open question 9")
 
@@ -104,9 +105,11 @@ def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypat
     names = [m["name"] for m in manifest["per_layer"]]
     last = max(names.index(name) for name in _falconh1.METRICS)
     assert names[last + 1] == "padded_rows_per_row"  # PR 46
-    assert all(name.startswith(("ling_", "kda_")) for name in names[last + 2:])  # PR 49
+    assert all(name.startswith(("ling_", "kda_")) for name in names[last + 2:last + 12])  # PR 49
+    assert all(name.startswith(("xing_", "hc_")) for name in names[last + 12:])  # PR 52
     cells = [w["name"] for w in manifest["workloads"]]
-    assert cells[cells.index(_falconh1.CELL) + 1:] == ["ling-kda-insession"]  # PR 49
+    assert cells[cells.index(_falconh1.CELL) + 1:] == [
+        "ling-kda-insession", "xing-mhc-insession"]  # PR 49, PR 52
     cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
                configs=manifest["configs"][:cells.index(_falconh1.CELL) + 1],
                workloads=manifest["workloads"][:cells.index(_falconh1.CELL) + 1])
