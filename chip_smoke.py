@@ -73,6 +73,10 @@ EXPERTS_TOL = 2.0 ** -7
 BACKBONE_TOL = 0.05
 # combine against a gather and a weighted sum: float32 summation order alone
 COMBINE_TOL = 1e-5
+# The stream kernels against the ``jax.numpy`` residual path, as a share of
+# the largest value: float32 summation order alone, through the maps (1.9e-6
+# of unit values on a v5e at 4,096 positions: PERF.md, section 5, PR 53)
+STREAMS_TOL = 1e-4
 
 
 def check(cond: bool, message: str) -> None:
@@ -702,7 +706,8 @@ def phase_kernels(interpret: bool = False, *,
                   top_k: int = 8,
                   second_shape: tuple = (16384, 2048, 1536, 64, 4),
                   share_shape: tuple = (2048, 7680, 4096, 1000),
-                  grouped_windows: int = 32, delta_windows: int = 32) -> dict:
+                  grouped_windows: int = 32, delta_windows: int = 32,
+                  stream_tiles: int = 32) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -722,7 +727,10 @@ def phase_kernels(interpret: bool = False, *,
     ``ling`` head's mixer (32 heads of 128; the taps, the decay and the head
     norm inside) on ``delta_windows`` windows of 16 against the mixer's XLA
     path (``kda_one_chunk`` its core), with the core a trace would pick
-    there."""
+    there; and the two stream kernels of the ``xing`` head's residual path
+    (four streams of 3,584, 20 rounds) on ``stream_tiles`` tiles of 128
+    positions against the ``jax.numpy`` functions, with the path a trace
+    would pick there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -965,6 +973,33 @@ def phase_kernels(interpret: bool = False, *,
     report[f"delta_window_W{delta_windows}"] = {"max_err": err, "core": picked[0]}
     check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
           f"delta rule on {delta_windows} windows: max err {err} > {BACKBONE_TOL}")
+
+    from igaming_platform_tpu.models import decoder_parts, xing_backbone
+    from igaming_platform_tpu.ops.pallas import hyper_streams as hs
+
+    cfg = xing_backbone.XingConfig()
+    n, p = cfg.streams, stream_tiles * hs.TILE
+    ks = jax.random.split(jax.random.key(p + 2), n + 2)
+    xs = tuple(jax.random.normal(key, (p, cfg.hidden), jnp.float32)
+               for key in ks[:n])
+    hc = xing_backbone.init_hyper(ks[n], cfg)
+    y = jax.random.normal(ks[n + 1], (p, cfg.hidden), jnp.float32)
+    picked = _said_by_the_expert_layer(lambda: xing_backbone.residual_path(p, cfg))
+    u, maps = hs.maps_and_read(xs, hc, cfg, interpret=interpret)
+    left, squares = hs.write(xs, maps, y, interpret=interpret)
+
+    def plain(xs, hc, y):
+        pre, post, res = decoder_parts.hyper_maps(xs, hc, cfg)
+        left = decoder_parts.hyper_write(xs, res, post, y)
+        return (decoder_parts.hyper_read(xs, pre), *left,
+                decoder_parts.stream_squares(left))
+
+    err = max(float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+              for got, want in zip((u, *left, squares), jax.jit(plain)(xs, hc, y),
+                                   strict=True))
+    report[f"hyper_streams_T{stream_tiles}"] = {"max_err": err, "path": picked[0]}
+    check(err <= STREAMS_TOL,
+          f"stream kernels on {stream_tiles} tiles: max err {err} > {STREAMS_TOL}")
     return report
 
 
@@ -1005,8 +1040,8 @@ BACKBONES = {
     # every expert held and the padding left out: the share's passes at a
     # third shape (hidden 3584: the rows gathered, 4,608 a pass)
     "xing": ("risk-seqhead-xing4.0-29b-a4b", "xing4_29b_a4b", "xing_backbone",
-             {"residual_path": "hyper-connections, 4 streams, 20 Sinkhorn "
-                               "rounds",
+             {"residual_path": "pallas-streams (tile=128, 4 streams, 20 "
+                               "Sinkhorn rounds)",
               "expert_core": "pallas-grouped (tm=256, ts=64, slots=3/4, "
                              "rows=gathered)", "way_back": "pallas-rows",
               "attention_core": "pallas-windows"}),

@@ -470,7 +470,10 @@ def hyper_maps(xs, hc: Params, cfg, squares=None):
     j]`` is what stream ``i`` takes of stream ``j`` (``sinkhorn``). ``cfg``
     gives ``eps``, ``hc_clip`` (low, high), ``hc_rounds`` and ``hc_eps``.
     ``squares`` is ``stream_squares(xs)`` where the caller has it already
-    (from the pass that wrote the streams)."""
+    (from the pass that wrote the streams). On a TPU this, ``sinkhorn`` and
+    ``hyper_read`` are one kernel over tiles of positions
+    (ops/pallas/hyper_streams.maps_and_read, held to these functions by
+    tests/test_hyper_streams.py) where it takes the shapes."""
     n = len(xs)
     p, hidden = xs[0].shape
     phi = hc["phi"].reshape(n, hidden, -1)
@@ -519,7 +522,10 @@ def hyper_read(xs, pre):
     hidden]. The result stands behind an ``optimization_barrier``: it is
     made once, in a pass of its own over the streams, where XLA would else
     recompute it from all ``n`` streams inside each of its consumers (the
-    norm's sum of squares and the norm itself: my chip runs, PR 52)."""
+    norm's sum of squares and the norm itself: my chip runs, PR 52). The
+    barrier stays with this ``jax.numpy`` form, which still runs on a TPU
+    where the stream kernels decline (ops/pallas/hyper_streams.py makes
+    ``u`` in the maps' pass: a custom call's result is made once)."""
     u = sum(pre[i][:, None] * x for i, x in enumerate(xs))
     return jax.lax.optimization_barrier(u)
 
@@ -532,7 +538,8 @@ def hyper_write(xs, res, post, y):
     and the maps at 1 it is ``x + y``. ``y`` stands behind an
     ``optimization_barrier``, so the ``n`` results are siblings over the
     same operands (one pass over the streams for all of them) and none is
-    the epilogue of the product that made ``y``."""
+    the epilogue of the product that made ``y``: kept for the TPU steps the
+    stream kernels decline, as ``hyper_read``'s."""
     y = jax.lax.optimization_barrier(y)
     return tuple(
         sum((res[i, j][:, None] * x for j, x in enumerate(xs)),
@@ -569,8 +576,10 @@ def announce_core(core: str, backend: str, part: str = "expert core") -> None:
     back to position order; ``attention core``: the window kernel or the
     einsums, with the kernel's reason where it declines; ``state-space
     core`` and ``linear-attention core``: the form the recurrence is
-    computed in; ``residual path``: how many streams a layer carries and
-    the rounds of its mixing map, where that is not the one stream):
+    computed in; ``residual path``: the two stream kernels with their tile
+    (``pallas-streams (tile=128, ...)``) or ``xla`` with the kernels' reason,
+    and how many streams a layer carries and the rounds of its mixing map,
+    where that is not the one stream):
     the choice is made at
     trace time and is otherwise invisible. ``announced_cores`` keeps the
     last word of each part."""

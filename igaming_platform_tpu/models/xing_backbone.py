@@ -68,9 +68,24 @@ the maps (``phi``'s product on unrounded float32 operands at
 ``Precision.HIGHEST``), the Sinkhorn rounds, softmax, router scores, bias
 and top-k and the logit are float32.
 
+**On a TPU the residual path is two Pallas calls a sublayer**
+(ops/pallas/hyper_streams.py, where its ``declines`` has nothing to say:
+float32 streams of whole 128-lane vregs, whole tiles of 128 positions):
+``maps_and_read`` holds a tile's ``n`` streams in VMEM and makes steps 1, 2
+and ``u`` of step 3 in one pass, ``write`` makes ``X'`` and its
+``stream_squares`` in another, over the streams it read: two reads and one
+write of the streams a sublayer where the ``jax.numpy`` functions are four
+and one. ``residual_path`` picks while tracing, from backend and shapes, and
+announces ``pallas-streams (tile=128, ...)`` or ``xla (<why>; ...)``.
+Elsewhere ``decoder_parts.hyper_maps``, ``hyper_read``, ``hyper_write`` and
+``stream_squares`` run as they are: the CPU path, the kernels' reference,
+and what one stream takes.
+
 ``jax.named_scope`` marks the parts: ``head/embed``, ``head/hc/maps`` (the
-product with ``phi``, the sigmoids, the clip, ``exp`` and the rounds),
-``head/hc/read`` (``u``), ``head/hc/write`` (``X'``), ``head/attn`` (with
+product with ``phi``, the sigmoids, the clip, ``exp`` and the rounds; on a
+TPU the kernel that also makes ``u``), ``head/hc/read`` (``u`` on the
+``jax.numpy`` path; on a TPU there is no such scope: the read is the maps'
+pass), ``head/hc/write`` (``X'`` and its squares), ``head/attn`` (with
 its norm; inside it ``q``, ``kv``, ``core``, ``out``), ``head/mlp/dense``,
 ``head/moe/route``, ``head/moe/shared``, ``head/moe/experts``,
 ``head/exit`` (the streams' sum and the final norm).
@@ -238,13 +253,44 @@ def init_backbone(key, cfg: XingConfig) -> Params:
     return tree_around(layers, matrix((cfg.in_dim, d), cfg.in_dim), next(keys), d)
 
 
-def hyper_sublayer(x, hc: Params, cfg: XingConfig, sublayer, squares=None):
+def residual_path(positions: int, cfg: XingConfig) -> bool:
+    """Whether the residual path of a step over ``positions`` positions runs
+    as the two stream kernels (ops/pallas/hyper_streams.py): on a TPU where
+    ``declines`` has nothing to say, picked while tracing from backend and
+    shapes and announced once a compile, with the kernels' reason where the
+    ``jax.numpy`` functions run."""
+    from igaming_platform_tpu.ops.pallas import hyper_streams
+
+    n = cfg.streams
+    why, backend = kernel_declines(lambda: hyper_streams.declines(
+        positions, cfg.hidden, n, jnp.float32))
+    what = f"{n} streams, {cfg.hc_rounds} Sinkhorn rounds"
+    announce_core(f"xla ({why}; {what})" if why else
+                  f"pallas-streams (tile={hyper_streams.TILE}, {what})",
+                  backend, "residual path")
+    return not why
+
+
+def hyper_sublayer(x, hc: Params, cfg: XingConfig, sublayer, squares=None,
+                   kernels: bool = False):
     """One hyper-connected sublayer over the streams ``x`` (``n`` arrays [P,
     hidden]): the maps from the streams, what the sublayer reads,
     ``sublayer`` (its norm inside it) on that, and the streams it leaves ->
     ``(streams, their stream_squares)``: the pass that writes the streams
     also sums their squares, which the next sublayer's maps divide by
-    (``squares``: the last sublayer's; the first takes its own)."""
+    (``squares``: the last sublayer's; the first takes its own). With
+    ``kernels`` (``residual_path``) the maps and the read are one pass over
+    the streams under ``head/hc/maps`` and the write with the squares
+    another under ``head/hc/write``, written over the streams it read; else
+    the ``jax.numpy`` functions, the read under ``head/hc/read``."""
+    if kernels:
+        from igaming_platform_tpu.ops.pallas import hyper_streams
+
+        with jax.named_scope("head/hc/maps"):
+            u, maps = hyper_streams.maps_and_read(x, hc, cfg, squares)
+        y = sublayer(u)
+        with jax.named_scope("head/hc/write"):
+            return hyper_streams.write(x, maps, y)
     with jax.named_scope("head/hc/maps"):
         pre, post, res = hyper_maps(x, hc, cfg, squares)
     with jax.named_scope("head/hc/read"):
@@ -264,9 +310,7 @@ def backbone_hidden(params: Params, x, lengths, cfg: XingConfig):
     b, t, _ = x.shape
     n = cfg.streams
     live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(b * t)
-    _, backend = kernel_declines()
-    announce_core(f"hyper-connections, {n} streams, {cfg.hc_rounds} Sinkhorn "
-                  "rounds", backend, "residual path")
+    kernels = residual_path(b * t, cfg)
     with jax.named_scope("head/embed"):
         # the entry: the projected event copied into every stream; the
         # streams position-major, n arrays [P, hidden] with P = B x T, from
@@ -298,9 +342,9 @@ def backbone_hidden(params: Params, x, lengths, cfg: XingConfig):
                                            cfg, live=live)
 
         streams, squares = hyper_sublayer(streams, layer["hc_attn"], cfg,
-                                          attend, squares)
+                                          attend, squares, kernels)
         streams, squares = hyper_sublayer(streams, layer["hc_mlp"], cfg,
-                                          feed_forward, squares)
+                                          feed_forward, squares, kernels)
     with jax.named_scope("head/exit"):
         h = sum(streams[1:], streams[0])
         return rms_norm(h, params["gf"], cfg.eps).reshape(b, t, -1)

@@ -150,13 +150,14 @@ def _assert_in_place(compiled, ring_elems: int) -> None:
     assert mem.alias_size_in_bytes >= 4 * ring_elems, mem
 
 
-def _expert_kernels(text: str, capsys, head: str) -> list[str]:
-    """The Pallas calls under ``head/moe/experts`` of a compiled step, the
-    VMEM each asks for printed (its scoped region: what XLA keeps of its
-    own in VMEM across the call lies under ``offset``)."""
+def _kernels_under(text: str, capsys, head: str,
+                    scope: str = "head/moe/experts") -> list[str]:
+    """The Pallas calls under ``scope`` of a compiled step, the VMEM each
+    asks for printed (its scoped region: what XLA keeps of its own in VMEM
+    across the call lies under ``offset``)."""
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "custom-call(" in line
-               and "head/moe/experts" in line]
+               and scope in line]
     asks = {}
     for line in kernels:
         name = re.match(r"\s*%(\w+?)(\.\d+)? = ", line).group(1)
@@ -247,7 +248,7 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     # 396,118,528 B (PR 37); 615,414,272 B with XLA's gather and sum (PR 35)
     assert mem.temp_size_in_bytes <= 615_414_272, mem
     text = compiled.as_text()
-    kernels = _expert_kernels(text, capsys, "keye")
+    kernels = _kernels_under(text, capsys, "keye")
     for name in ("_gate_up", "_down", "_combine_rows"):
         calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
         assert len(calls) == 4, (name, kernels)
@@ -401,7 +402,7 @@ def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
     assert 9.8e9 < mem.argument_size_in_bytes < 10.0e9, mem
     assert mem.temp_size_in_bytes <= 615_414_272, mem  # keye's bound
     text = compiled.as_text()
-    kernels = _expert_kernels(text, capsys, "lfm2")
+    kernels = _kernels_under(text, capsys, "lfm2")
     moe = len(cfg.layer_types) - cfg.dense_layers
     for name in ("_gate_up", "_down", "_combine_rows"):
         calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
@@ -512,7 +513,7 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
                 and ("head/kda/conv" in line or "head/kda/core" in line)]
     state = f"{cfg.heads},{cfg.head_dim},{cfg.head_dim}]"
     assert "32,128,128]" == state and state not in text
-    kernels = _expert_kernels(text, capsys, "ling")
+    kernels = _kernels_under(text, capsys, "ling")
     assert kernels, "the held experts' products run as no kernel"
     delta = [line for line in text.splitlines() if "tpu_custom_call" in line
              and "custom-call(" in line and "_delta_window" in line]
@@ -566,10 +567,11 @@ def test_hyper_connected_step_fits_beside_the_state_and_keeps_its_scopes(
     assert 9.0e9 < mem.argument_size_in_bytes < 9.1e9, mem
     assert mem.temp_size_in_bytes <= XING_TEMPS_256, mem
     text = compiled.as_text()
-    for scope in ("head/embed", "head/hc/maps", "head/hc/read", "head/hc/write",
+    for scope in ("head/embed", "head/hc/maps", "head/hc/write",
                   "head/attn/core", "head/mlp/dense", "head/moe/route",
                   "head/moe/shared", "head/moe/experts", "head/exit"):
         assert scope in text, scope
+    assert "head/hc/read" not in text  # the read is the maps' pass
     positions = BATCH * ss.default_events()
     pairs = positions * cfg.top_k
     assert pass_rows(pairs, cfg.experts, cfg.experts, cfg.hidden) == 4608
@@ -584,16 +586,43 @@ def test_hyper_connected_step_fits_beside_the_state_and_keeps_its_scopes(
         found[name] = found.get(name, 0) + 1
     moe = cfg.layers - cfg.dense_layers
     assert found == {"_window_attention": cfg.layers, "_gate_up": moe,
-                     "_down": moe, "_combine_held": moe}, found
+                     "_down": moe, "_combine_held": moe,
+                     **XING_STREAM_CALLS}, found
     assert all("head/attn/core" in c for c in calls if "_window_attention" in c)
-    assert _expert_kernels(text, capsys, "xing")
+    assert _kernels_under(text, capsys, "xing")
+    _assert_the_residual_path_is_two_passes(text, capsys, positions, cfg)
     _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
 
 
-# What the ``xing`` step holds in temporaries at the 256-row rung (PR 52):
-# the four float32 streams (235 MB) four times over (the maps, what is read,
-# what is written and a sublayer's own intermediates).
-XING_TEMPS_256 = 931_794_432
+# The stream kernels of the ``xing`` step (ops/pallas/hyper_streams.py): every
+# hyper-connected sublayer of the five layers held, the first among them.
+XING_STREAM_CALLS = {"_streams_maps_read": 10, "_streams_write": 10}
+
+
+def _assert_the_residual_path_is_two_passes(text, capsys, positions, cfg):
+    """Each stream kernel under its ``head/hc/*`` scope (the VMEM it asks
+    printed), and no fusion left under ``head/hc`` that reads all the
+    streams: XLA's passes over them are out of the step."""
+    under = _kernels_under(text, capsys, "xing", "head/hc/")
+    assert len(under) == sum(XING_STREAM_CALLS.values())
+    for line in under:
+        scope = "head/hc/maps" if "_streams_maps_read" in line else "head/hc/write"
+        assert scope in line, line[:300]
+    stream = f"f32[{positions},{cfg.hidden}]"
+    over_all = [line for line in text.splitlines()
+                if " fusion(" in line and "head/hc" in line
+                and line.split(" fusion(", 1)[1].count(stream) >= cfg.streams]
+    assert not over_all, over_all[0][:400]
+
+
+# What the ``xing`` step holds in temporaries at the 256-row rung since PR 53:
+# the four float32 streams (235 MB) once, written in place by the stream
+# kernels, beside a sublayer's own intermediates (931,794,432 B at PR 52,
+# where XLA's passes held the streams four times over). Read: 488,598,016 B;
+# the bound is four times the 64-row rung's 122,840,064 B, which the rung's
+# test holds under a quarter of it (``phi`` turned columns-first, 1.4 MB a
+# sublayer, does not shrink with the rung).
+XING_TEMPS_256 = 491_360_256
 
 
 # What the ``ling`` step holds in temporaries at the 256-row rung since PR 51,
@@ -652,7 +681,8 @@ def test_delta_core_is_one_call_a_layer(topo, tpu_backend, batch):
     ("ling", 3_145_728, LING_TEMPS_256,
      {"_gate_up": 6, "_down": 6, "_combine_held": 6, "_delta_window": 6}),
     ("xing", 3_145_728, XING_TEMPS_256,
-     {"_window_attention": 5, "_gate_up": 4, "_down": 4, "_combine_held": 4})])
+     {"_window_attention": 5, "_gate_up": 4, "_down": 4, "_combine_held": 4,
+      **XING_STREAM_CALLS})])
 def test_the_64_row_rung_compiles_beside_the_256_one(
         topo, tpu_backend, capsys, head, capacity, temps_256, in_tree):
     """The ladder's 64-row rung of each backbone's step (serve/scorer.py:

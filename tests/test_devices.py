@@ -183,7 +183,8 @@ def test_smoke_kernels_phase_tiny_interpreted():
         interpret=True, attention_shapes=((1, 2, 64, 32),),
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
         expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
-        share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8)
+        share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8,
+        stream_tiles=1)
     assert report["interpret"] is True
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
@@ -211,3 +212,8 @@ def test_smoke_kernels_phase_tiny_interpreted():
     assert delta["max_err"] <= chip_smoke.BACKBONE_TOL
     assert delta["core"] == ("linear-attention core: one chunk by einsums "
                              "(not a TPU) (backend=cpu)")
+    # the stream kernels (xing's residual path) on one tile of 128 positions
+    streams = report["hyper_streams_T1"]
+    assert streams["max_err"] <= chip_smoke.STREAMS_TOL
+    assert streams["path"] == ("residual path: xla (not a TPU; 4 streams, 20 "
+                               "Sinkhorn rounds) (backend=cpu)")

@@ -688,7 +688,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert snap["head_layers"] == LAYERS
     assert snap["head_residual_streams"] == 4
     assert snap["head_cores"]["residual path"] == (
-        "hyper-connections, 4 streams, 20 Sinkhorn rounds (backend=cpu)")
+        "xla (not a TPU; 4 streams, 20 Sinkhorn rounds) (backend=cpu)")
     assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"
     assert snap["head_cores"]["combine"] == "xla-gather (backend=cpu)"
     assert snap["head_cores"]["attention core"] == "xla-einsum (backend=cpu)"
@@ -734,7 +734,7 @@ def test_chip_smoke_phase_runs_the_head_against_its_reference():
     assert report["max_err"] < 1e-4 and report["rows"] == 8
     assert report["head"] == "xing"
     assert report["residual_path"] == (
-        "residual path: hyper-connections, 4 streams, 20 Sinkhorn rounds "
+        "residual path: xla (not a TPU; 4 streams, 20 Sinkhorn rounds) "
         "(backend=cpu)")
     assert report["expert_core"] == "expert core: xla-ragged-dot (backend=cpu)"
     assert report["way_back"] == "combine: xla-gather (backend=cpu)"
