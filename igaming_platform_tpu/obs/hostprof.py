@@ -35,23 +35,38 @@ speedscope JSON at ``/debug/hostprofz?format=...``. Sampling a thread
 NOT in the registry is an analyzer violation (MX08): the registry is
 the contract that keeps profiling hooks off jit roots and hot loops.
 
+The heartbeat (always on with Tier A): everything above is recorded when
+a span ENDS, so a request that is stuck leaves nothing until it is no
+longer stuck. One daemon thread sleeps a fixed 50 ms and reads how late
+it woke (the OS's wake-up plus the wait for the interpreter: what every
+handler thread pays when it comes back from a lock, a device wait or a
+native call), and on the same tick looks for ``rpc.*`` roots open longer
+than ``STALL_DUMP_MS``: while one is, it records every thread's stack,
+open spans and CPU time (:class:`Heartbeat`, ``/debug/stallz``).
+
 Overhead contract: Tier A is one dict update per completed stage span
 (the bench artifact's profiler-on/off A/B holds the e2e ratio ≥ 0.90);
-Tier B costs only while running and only for registered threads.
+Tier B costs only while running and only for registered threads; the
+heartbeat is 20 wakes a second on its own thread and nothing on a
+request's path.
 """
 
 from __future__ import annotations
 
 import gc
 import json
+import logging
 import os
 import sys
+import tempfile
 import threading
 import time
 import weakref
 from collections import deque
 
-from igaming_platform_tpu.obs import tracing
+from igaming_platform_tpu.obs import runtime_telemetry, tracing
+
+logger = logging.getLogger(__name__)
 
 _STAGE_PREFIX = "score."
 # Per-stage reservoir of recent per-span µs/row samples — the "rolling
@@ -135,6 +150,17 @@ def _format_frame(frame) -> str:
     if base.endswith(".py"):
         base = base[:-3]
     return f"{base}.{code.co_name}"
+
+
+def _format_stack(frame) -> list[str]:
+    """A thread's frames root first (flamegraph convention), the
+    innermost ``_MAX_STACK_DEPTH`` of them."""
+    parts: list[str] = []
+    while frame is not None and len(parts) < _MAX_STACK_DEPTH:
+        parts.append(_format_frame(frame))
+        frame = frame.f_back
+    parts.reverse()
+    return parts
 
 
 class StackSampler:
@@ -223,13 +249,7 @@ class StackSampler:
                 frame = frames.get(ident)
                 if frame is None:
                     continue
-                parts: list[str] = []
-                depth = 0
-                while frame is not None and depth < _MAX_STACK_DEPTH:
-                    parts.append(_format_frame(frame))
-                    frame = frame.f_back
-                    depth += 1
-                parts.reverse()  # root-first, flamegraph convention
+                parts = _format_stack(frame)
                 span = actives.get(ident)
                 span_name = span.name if span is not None else "idle"
                 key = ";".join([role, f"span:{span_name}", *parts])
@@ -319,6 +339,434 @@ class StackSampler:
 
 
 # ---------------------------------------------------------------------------
+# The heartbeat: how late a runnable thread runs, and who holds a stuck RPC
+
+# One period, no knob: the lateness is only comparable between processes
+# that sleep the same time.
+HEARTBEAT_S = 0.05
+_STALL_MAX_SAMPLES = 40    # samples an incident keeps (identical ones folded)
+_STALL_RING = 16           # incidents /debug/stallz keeps, and files on disk
+_STALL_MAX_SPANS = 16      # open spans listed for one thread
+STALL_KINDS = ("waiting", "interpreter_blocked")
+
+
+def _stall_threshold_s() -> float:
+    try:
+        return max(0.0, float(os.environ.get("STALL_DUMP_MS", "500"))) / 1e3
+    except ValueError:
+        return 0.5
+
+
+class _Incident:
+    """An incident while it is open: the roots it has seen held (the
+    Span objects, so the record can say how each one ended) beside what
+    the record will keep."""
+
+    __slots__ = ("seq", "start", "opened_unix", "roots", "samples",
+                 "signature", "dropped", "late_max", "gap")
+
+    def __init__(self, seq: int, start: float):
+        self.seq = seq
+        self.start = start
+        self.opened_unix = time.time()
+        self.roots: dict[int, "tracing.Span"] = {}
+        self.samples: list[dict] = []
+        self.signature: tuple = ()
+        self.dropped = 0
+        self.late_max = 0.0
+        # wall and process CPU between the two ticks around the latest wake
+        self.gap = (0.0, 0.0)
+
+
+class Heartbeat:
+    """A thread that times its own wake-ups and watches for held RPCs.
+
+    Every ``HEARTBEAT_S`` it adds one tick and how late it woke
+    (``risk_host_heartbeat_{ticks,late_seconds}_total``). With
+    ``STALL_DUMP_MS`` > 0 the same tick looks through the threads' open
+    spans for ``rpc.*`` roots older than that. While there is one, an
+    INCIDENT is open and each tick records, for every thread of the
+    process, its Python stack, its chain of open spans with their ages
+    and its CPU clock, until the last such root has closed or
+    ``_STALL_MAX_SAMPLES`` samples are kept (a sample equal to the one
+    before it is counted, not kept). What it costs is paid on this
+    thread and only while an RPC is held: a request's path has no line
+    of it.
+
+    An incident says its ``kind``. ``waiting``: the ticks kept time, so
+    the interpreter ran and the stacks name who held what (a lock's
+    holder, a device wait, a socket). ``interpreter_blocked``: a tick
+    itself woke later than the threshold, so no Python thread could run
+    (the GIL held in native code, or the process not scheduled); the
+    process's CPU over that gap tells the two apart, and each thread's
+    own CPU clock over it (``ran``) names who computed. This thread's
+    samples come just after the block, not during it: stacks DURING it
+    would take ``faulthandler.dump_traceback_later``, whose C thread
+    walks the frames of every thread without the GIL. That timer also
+    fires when this thread was merely starved, or when a stopped process
+    resumes, and then reads threads that are running: it killed 3 of the
+    73 boots it was armed in under the chip benchmark (PERF.md, PR 54),
+    so the server does not arm it.
+    """
+
+    def __init__(self, profiler: "HostProfiler"):
+        self._profiler = profiler
+        self.stall_s = _stall_threshold_s()
+        self.dump_dir = os.environ.get("STALL_DUMP_DIR") or tempfile.gettempdir()
+        self.ticks = 0
+        self.late_s = 0.0
+        self.stalls = dict.fromkeys(STALL_KINDS, 0)
+        self.stall_seconds = 0.0  # the held roots' time past the threshold
+        # what flush() has already added to the /metrics counters
+        self._flushed = {"ticks": 0, "late_s": 0.0, "stall_seconds": 0.0,
+                         **dict.fromkeys(STALL_KINDS, 0)}
+        self.incidents: deque = deque(maxlen=_STALL_RING)
+        self._open: _Incident | None = None
+        self._seq = 0
+        # the ring, the open incident and what flush() has taken
+        self._lock = threading.Lock()
+        self._thread: threading.Thread | None = None
+        self._stop = threading.Event()
+        self._woke = 0.0
+        self._cpu_seen = 0.0
+
+    @property
+    def running(self) -> bool:
+        return self._thread is not None and self._thread.is_alive()
+
+    def start(self) -> None:
+        if self.running:
+            return
+        self._stop.clear()
+        self._thread = threading.Thread(
+            target=self._run, name="hostprof-heartbeat", daemon=True)
+        self._thread.start()
+
+    def stop(self) -> None:
+        thread = self._thread
+        self._stop.set()
+        if thread is not None:
+            thread.join(timeout=2.0)
+        self._thread = None
+        with self._lock:
+            self._open = None
+
+    # -- the tick --------------------------------------------------------------
+
+    def _run(self) -> None:
+        clock, wait = time.perf_counter, self._stop.wait
+        self._woke, self._cpu_seen = clock(), time.process_time()
+        while True:
+            due = clock() + HEARTBEAT_S
+            # A fixed sleep is the measurement: how much later than asked
+            # does a thread that becomes runnable get to run.
+            if wait(HEARTBEAT_S):  # noqa: CC05 — a heartbeat keeps its period
+                return
+            self.tick(due, clock())
+
+    def tick(self, due: float, woke: float) -> None:
+        """One wake: asked for at ``due``, got at ``woke``."""
+        late = max(0.0, woke - due)
+        self.ticks += 1
+        self.late_s += late
+        if self.stall_s:
+            try:
+                self._watch(due, woke, late)
+            except Exception:  # the heartbeat goes on, and says so
+                logger.exception("stall watch failed")
+
+    def _watch(self, due: float, woke: float, late: float) -> None:
+        cpu = time.process_time()
+        gap = (woke - self._woke, cpu - self._cpu_seen)
+        self._woke, self._cpu_seen = woke, cpu
+        held = live = self._overdue(woke)
+        blocked = late >= self.stall_s
+        if blocked:
+            # nothing ran since the block began: a root that was held may
+            # have ended before this thread got its turn
+            held = live | self._ended_since(due)
+        inc = self._open
+        if inc is not None or held:
+            closed = None
+            with self._lock:
+                if inc is None:
+                    self._seq += 1
+                    first = min(r.mono_start for r in held.values())
+                    inc = self._open = _Incident(
+                        self._seq, min(woke, first + self.stall_s))
+                inc.roots.update(held)
+                if late >= inc.late_max:
+                    inc.late_max, inc.gap = late, gap
+                if live or blocked:  # held now, or over the gap this tick ends
+                    self._sample(inc, woke, late, cpu)
+                if not live:
+                    closed = self._close(inc, woke)
+                    self._open = None
+            if closed is not None:
+                self._publish(closed, inc.start, woke)
+
+    def _overdue(self, now: float) -> dict:
+        """The ``rpc.*`` roots open since before ``now - stall_s``."""
+        limit = now - self.stall_s
+        out = {}
+        for span in tracing.active_spans_by_thread().values():
+            root = span.root if span.root is not None else span
+            if (0.0 < root.mono_start <= limit and not root.mono_end
+                    and root.name.startswith("rpc.")):
+                out[id(root)] = root
+        return out
+
+    def _ended_since(self, due: float) -> dict:
+        """The ``rpc.*`` roots past the threshold that ended after this
+        tick was due, off the span ring (newest last)."""
+        out = {}
+        for span in reversed(tracing.DEFAULT_COLLECTOR.recent()):
+            if span.mono_end < due:
+                break
+            if (span.root is span and span.name.startswith("rpc.")
+                    and span.mono_end - span.mono_start >= self.stall_s):
+                out[id(span)] = span
+        return out
+
+    def _sample(self, inc: _Incident, now: float, late: float,
+                process_cpu: float) -> None:
+        if len(inc.samples) >= _STALL_MAX_SAMPLES:
+            inc.dropped += 1
+            return
+        # EVERY thread, the ones outside the scoring registry too: what a
+        # handler waits for may be held by any thread of the process, and
+        # this is the one place that may read them. On the heartbeat's
+        # thread, read-only, and only while an RPC is held.
+        frames = sys._current_frames()  # noqa: MX08 — the stall watch; see above
+        cpu_of: dict[int, float] = {}
+        for ident in frames:  # first: a thread that ends takes its clock along
+            try:
+                cpu_of[ident] = time.clock_gettime(
+                    time.pthread_getcpuclockid(ident))
+            except (AttributeError, OSError, OverflowError):  # noqa: CC04 — no such clock here, or the thread has just ended: it reads None
+                pass
+        actives = tracing.active_spans_by_thread()
+        roles = registered_threads()
+        names = {t.ident: t.name for t in threading.enumerate()}
+        me = threading.get_ident()
+        threads = []
+        for ident, frame in frames.items():
+            if ident == me:
+                continue
+            chain = []
+            span = actives.get(ident)
+            while span is not None and len(chain) < _STALL_MAX_SPANS:
+                chain.append(span)
+                span = span._parent
+            threads.append({
+                "thread": roles.get(ident) or f"other:{names.get(ident, ident)}",
+                "ident": ident,
+                "cpu_ms": (round(cpu_of[ident] * 1e3, 3)
+                           if ident in cpu_of else None),
+                "spans": [{"name": s.name,
+                           "age_ms": round((now - s.mono_start) * 1e3, 1)}
+                          for s in reversed(chain)],
+                "stack": ";".join(_format_stack(frame)),
+            })
+        threads.sort(key=lambda t: (not t["spans"], t["thread"], t["ident"]))
+        signature = tuple(
+            (t["ident"], t["stack"], tuple(s["name"] for s in t["spans"]))
+            for t in threads)
+        t_ms = round((now - inc.start) * 1e3, 1)
+        rt = runtime_telemetry.DEFAULT
+        state = {
+            "late_ms": round(late * 1e3, 3),
+            "process_cpu_ms": round(process_cpu * 1e3, 3),
+            "gc_running": bool(self._profiler._gc_start_ns),
+            "gc_collections": sum(g["collections"] for g in gc.get_stats()),
+            "compiles": (rt.compile_watcher.compiles_total
+                         if rt is not None else None),
+        }
+        if signature == inc.signature:
+            last = inc.samples[-1]
+            last["count"] += 1
+            last["until"] = {"t_ms": t_ms, **state}
+            for kept, seen in zip(last["threads"], threads):
+                kept["cpu_ms_until"] = seen["cpu_ms"]
+            return
+        inc.signature = signature
+        inc.samples.append({"t_ms": t_ms, "count": 1, **state,
+                            "threads": threads})
+
+    # -- an incident's end -------------------------------------------------------
+
+    def _close(self, inc: _Incident, now: float) -> dict:
+        """The record of a closed incident, written out, counted and in
+        the ring (under ``_lock``)."""
+        blocked = inc.late_max >= self.stall_s
+        kind = "interpreter_blocked" if blocked else "waiting"
+        held, past = [], 0.0
+        for root in inc.roots.values():
+            duration = (root.mono_end or now) - root.mono_start
+            past += max(0.0, duration - self.stall_s)
+            held.append({"trace_id": root.trace_id, "method": root.name[4:],
+                         "rows": root.attributes.get("rows"),
+                         "duration_ms": round(duration * 1e3, 3)})
+        record = {
+            "id": inc.seq,
+            "kind": kind,
+            "opened_unix": round(inc.opened_unix, 3),
+            "length_ms": round((now - inc.start) * 1e3, 1),
+            "threshold_ms": self.stall_s * 1e3,
+            "late_max_ms": round(inc.late_max * 1e3, 3),
+            "held": held,
+            "ran": _who_ran(inc.samples),
+            "samples": inc.samples,
+            "samples_dropped": inc.dropped,
+            "file": None,
+        }
+        if blocked:
+            wall, cpu = inc.gap
+            record["blocked"] = {
+                "gap_ms": round(wall * 1e3, 1),
+                "process_cpu_ms": round(cpu * 1e3, 1),
+                # CPU that kept pace with the gap: somebody computed with
+                # the GIL held; CPU that stood still: a call that kept the
+                # GIL slept, or the whole process was not scheduled
+                "reading": ("computing with the GIL held" if cpu > 0.5 * wall
+                            else "the GIL held by a call that slept, or the "
+                                 "process stopped"),
+            }
+        # before anyone can read the record: its file is part of it
+        record["file"] = self._write_file(record)
+        self.stalls[kind] += 1
+        self.stall_seconds += past
+        self.incidents.append(record)
+        return record
+
+    def _write_file(self, record: dict) -> str | None:
+        path = os.path.join(
+            self.dump_dir,
+            f"stall-{os.getpid()}-{record['id'] % _STALL_RING:02d}.txt")
+        try:
+            os.makedirs(self.dump_dir, exist_ok=True)
+            with open(path, "w", encoding="utf-8") as f:
+                f.write(render_incident(record))
+        except OSError:
+            logger.warning("stall watch: could not write %s", path,
+                           exc_info=True)
+            return None
+        return path
+
+    def _publish(self, record: dict, start: float, end: float) -> None:
+        """Where a closed incident goes beside the ring and its file: one
+        WARNING line and one ``host.stall`` span."""
+        trace_ids = [h["trace_id"] for h in record["held"]]
+        logger.warning(
+            "rpc stall #%d kind=%s length_ms=%.0f held=%d trace_ids=%s file=%s",
+            record["id"], record["kind"], record["length_ms"], len(trace_ids),
+            ",".join(trace_ids), record["file"])
+        # on the spans' clock, so /debug/spans, OTLP and a device trace's
+        # idle gaps see the stall where it was
+        tracing.emit_span("host.stall", start, end, kind=record["kind"],
+                          samples=len(record["samples"]),
+                          trace_ids=",".join(trace_ids))
+
+    # -- readers -----------------------------------------------------------------
+
+    def flush(self, m) -> None:
+        """What has grown since the last render, onto the four counters.
+        Two renders at once (the sidecar serves each on a thread of its
+        own) must not both take the same growth."""
+        with self._lock:
+            seen, kept = {"ticks": self.ticks, "late_s": self.late_s,
+                          "stall_seconds": self.stall_seconds,
+                          **self.stalls}, self._flushed
+            grown = {k: v - kept[k] for k, v in seen.items()}
+            self._flushed = seen
+        if grown["ticks"]:
+            m.host_heartbeat_ticks_total.inc(grown["ticks"])
+            m.host_heartbeat_late_seconds_total.inc(grown["late_s"])
+        for kind in STALL_KINDS:
+            if grown[kind]:
+                m.rpc_stalls_total.inc(grown[kind], kind=kind)
+        if grown["stall_seconds"]:
+            m.rpc_stall_seconds_total.inc(grown["stall_seconds"])
+
+    def summary(self) -> dict:
+        """The counts, without the incidents (``/debug/hostprofz``)."""
+        ticks = self.ticks
+        return {
+            "running": self.running,
+            "heartbeat_ms": HEARTBEAT_S * 1e3,
+            "threshold_ms": self.stall_s * 1e3,
+            "dump_dir": self.dump_dir,
+            "ticks": ticks,
+            "wake_late_us": round(self.late_s / ticks * 1e6, 3) if ticks else None,
+            "stalls": dict(self.stalls),
+            "stall_seconds": round(self.stall_seconds, 6),
+        }
+
+    def snapshot(self) -> dict:
+        """``/debug/stallz``: the counts, the incident that is open now
+        and the ring of closed ones."""
+        with self._lock:
+            incidents = list(self.incidents)
+            inc = self._open
+            open_now = None if inc is None else {
+                "id": inc.seq,
+                "open_ms": round((time.perf_counter() - inc.start) * 1e3, 1),
+                "trace_ids": [r.trace_id for r in inc.roots.values()],
+                "samples": list(inc.samples),
+            }
+        return {**self.summary(), "open": open_now, "incidents": incidents}
+
+
+def _who_ran(samples: list[dict]) -> list[dict]:
+    """The threads whose CPU clock moved over the incident, most first:
+    a thread that ran, told from the ones that slept."""
+    first: dict[int, tuple] = {}
+    last: dict[int, float] = {}
+    for sample in samples:
+        for t in sample["threads"]:
+            if t["cpu_ms"] is None:
+                continue
+            first.setdefault(t["ident"], (t["cpu_ms"], t["thread"], t["stack"]))
+            last[t["ident"]] = t.get("cpu_ms_until") or t["cpu_ms"]
+    ran = [{"thread": thread, "cpu_ms": round(last[ident] - cpu0, 3),
+            "leaf": stack.rsplit(";", 1)[-1]}
+           for ident, (cpu0, thread, stack) in first.items()
+           if last[ident] - cpu0 >= 1.0]
+    return sorted(ran, key=lambda r: -r["cpu_ms"])[:5]
+
+
+def render_incident(record: dict) -> str:
+    """An incident as text: what the file under ``STALL_DUMP_DIR`` holds."""
+    lines = [
+        f"rpc stall #{record['id']} kind={record['kind']} "
+        f"length_ms={record['length_ms']} threshold_ms={record['threshold_ms']} "
+        f"late_max_ms={record['late_max_ms']} pid={os.getpid()} "
+        f"opened_unix={record['opened_unix']}"]
+    if "blocked" in record:
+        lines.append("blocked: " + json.dumps(record["blocked"]))
+    for h in record["held"]:
+        lines.append("held: " + json.dumps(h))
+    for r in record["ran"]:
+        lines.append("ran: " + json.dumps(r))
+    for i, sample in enumerate(record["samples"]):
+        head = {k: v for k, v in sample.items() if k != "threads"}
+        lines.append(f"sample {i}: " + json.dumps(head))
+        for t in sample["threads"]:
+            spans = " > ".join(f"{s['name']} ({s['age_ms']} ms)"
+                               for s in t["spans"]) or "-"
+            cpu = t["cpu_ms"]
+            if "cpu_ms_until" in t:
+                cpu = f"{cpu} -> {t['cpu_ms_until']}"
+            lines.append(f"  {t['thread']} [{t['ident']}] cpu_ms {cpu}")
+            lines.append(f"    spans: {spans}")
+            lines.append(f"    stack: {t['stack']}")
+    if record["samples_dropped"]:
+        lines.append(f"ticks not sampled (the incident had its "
+                     f"{_STALL_MAX_SAMPLES}): {record['samples_dropped']}")
+    return "\n".join(lines) + "\n"
+
+
+# ---------------------------------------------------------------------------
 # Tier A: µs/row stage accounting + GC/heap watch
 
 
@@ -359,8 +807,8 @@ class HostProfiler:
 
     Installed once (``install()``/``get_default()``): rides the tracing
     module's extra span sink — never wraps serving code — and a
-    ``gc.callbacks`` hook. ``HOSTPROF=0`` disables Tier A entirely;
-    ``HOSTPROF_HZ>0`` starts the sampler at boot.
+    ``gc.callbacks`` hook. ``HOSTPROF=0`` disables Tier A entirely, the
+    heartbeat with it; ``HOSTPROF_HZ>0`` starts the sampler at boot.
     """
 
     def __init__(self, enabled: bool = True):
@@ -369,6 +817,7 @@ class HostProfiler:
         self._stages: dict[str, _StageAcc] = {}
         self._rpc = _StageAcc()
         self.sampler = StackSampler()
+        self.heartbeat = Heartbeat(self)
         self.metrics = None
         self._installed = False
         self._gc_installed = False
@@ -395,6 +844,7 @@ class HostProfiler:
         self._installed = True
         tracing.add_span_sink(self._on_span)
         self.install_gc_watch()
+        self.heartbeat.start()
         return self
 
     def uninstall(self) -> None:
@@ -409,6 +859,7 @@ class HostProfiler:
             self._gc_installed = False
         if self.sampler.running:
             self.sampler.stop()
+        self.heartbeat.stop()
 
     def bind_metrics(self, metrics) -> None:
         """Attach a ServiceMetrics so stage costs / GC pauses land on
@@ -423,10 +874,12 @@ class HostProfiler:
     def flush_counters(self) -> None:
         """Registry refresher: the exclusive wall and CPU each stage has
         gathered since the last render, onto
-        ``host_stage_{self,cpu}_seconds_total``. Off the request path."""
+        ``host_stage_{self,cpu}_seconds_total``, and the heartbeat's ticks,
+        lateness and stalls onto their four. Off the request path."""
         m = self.metrics
         if m is None:
             return
+        self.heartbeat.flush(m)
         with self._lock:
             grown = []
             for stage, acc in self._stages.items():
@@ -645,6 +1098,7 @@ class HostProfiler:
             "gc": self.gc_snapshot(),
             "heap": self._heap_block(),
             "sampler": self.sampler.snapshot(),
+            "heartbeat": self.heartbeat.summary(),
         }
 
     def reset(self) -> None:
@@ -687,6 +1141,13 @@ def get_default() -> HostProfiler:
             if enabled and boot_hz > 0:
                 _DEFAULT.sampler.start(boot_hz)
         return _DEFAULT
+
+
+def stall_incidents_total() -> int:
+    """The default profiler's closed incidents of both kinds; 0 where no
+    profiler has been made (a read makes none)."""
+    profiler = _DEFAULT
+    return 0 if profiler is None else sum(profiler.heartbeat.stalls.values())
 
 
 def install(metrics=None) -> HostProfiler:

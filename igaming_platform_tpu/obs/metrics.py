@@ -946,6 +946,39 @@ class ServiceMetrics:
             "rest is the *_wait stages (device, lock, lane), GIL wait, "
             "preemption, steal",
         )
+        # The heartbeat and its stall watch (obs/hostprof.Heartbeat),
+        # brought up to date at every render like the two above.
+        self.host_heartbeat_ticks_total = self.registry.counter(
+            f"{service}_host_heartbeat_ticks_total",
+            "Wakes of the heartbeat thread, which sleeps a fixed 50 ms: "
+            "about 20 a second; the denominator of the mean wake-up "
+            "lateness",
+        )
+        self.host_heartbeat_late_seconds_total = self.registry.counter(
+            f"{service}_host_heartbeat_late_seconds_total",
+            "Seconds the heartbeat thread woke later than it asked to, "
+            "summed: the OS's timer and wake-up plus the wait for the "
+            "interpreter lock. Over host_heartbeat_ticks_total it is the "
+            "mean lateness of a TIMED wake: an upper indicator of what a "
+            "handler thread pays when it comes back from a lock, a device "
+            "wait or a native call (a signalled wake pays less), 0.7 ms "
+            "on an idle process under a sandboxed kernel, 1-2.4 ms under "
+            "load, and it swings by about half between runs",
+        )
+        self.rpc_stalls_total = self.registry.counter(
+            f"{service}_rpc_stalls_total",
+            "Incidents in which an rpc.* root stayed open past "
+            "STALL_DUMP_MS, by {kind}: waiting (the interpreter ran; the "
+            "sampled stacks name the holder) or interpreter_blocked (a "
+            "heartbeat tick itself woke later than the threshold: the GIL "
+            "held in native code, or the process not scheduled). Each one "
+            "is at /debug/stallz with every thread's stack",
+        )
+        self.rpc_stall_seconds_total = self.registry.counter(
+            f"{service}_rpc_stall_seconds_total",
+            "Seconds the RPCs held in stall incidents spent past "
+            "STALL_DUMP_MS, summed over the held RPCs",
+        )
         self.gc_collections_total = self.registry.counter(
             f"{service}_gc_collections_total",
             "Python GC collections by {generation} — a hot gen-2 rate "

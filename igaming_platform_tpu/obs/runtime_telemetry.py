@@ -205,6 +205,12 @@ class CompileWatcher:
 class StepTimeAnomalyDetector:
     """EWMA + EW-variance step-time anomaly detection for one stage.
 
+    It is fed completed spans, so it sees a slow stage AFTER it ended,
+    and the profile it triggers records what the device does next. What
+    sees a stage that is stuck, while it lasts, is the stall watch
+    (obs/hostprof.Heartbeat, ``/debug/stallz``); :meth:`RuntimeTelemetry.
+    snapshot` points there.
+
     A sample is anomalous when it exceeds ``mean + k*sigma`` AND the
     absolute floor (``min_ms``) AND the warmup count has passed — the
     floor keeps microsecond-scale jitter from paging, warmup keeps the
@@ -331,6 +337,12 @@ class RuntimeTelemetry:
         ladder rung), at the same seam."""
         if self.metrics is not None:
             self.metrics.launch_padded_rows_total.inc(rows)
+
+    def note_occupancy(self, rows: int) -> None:
+        """The real rows of that launch: over its rung, the ladder's
+        occupancy (``risk_batch_occupancy``)."""
+        if self.metrics is not None:
+            self.metrics.batch_occupancy.observe(rows)
 
     def observe_span(self, span) -> None:
         name = getattr(span, "name", "")
@@ -459,6 +471,13 @@ class RuntimeTelemetry:
                 "step_time": detectors,
             }
         out["compile"] = self.compile_watcher.snapshot()
+        # an anomaly is a stage that WAS slow; an RPC that is stuck now, or
+        # was for seconds, is the stall watch's, with every thread's stack
+        from igaming_platform_tpu.obs import hostprof
+        out["stalls"] = {
+            "incidents_total": hostprof.stall_incidents_total(),
+            "see": "/debug/stallz",
+        }
         engine = self._engine
         pipeline = getattr(engine, "pipeline", None) if engine else None
         if pipeline is not None and hasattr(pipeline, "arena_stats"):
@@ -530,3 +549,11 @@ def note_padded_rows(rows: int) -> None:
     t = DEFAULT
     if t is not None:
         t.note_padded_rows(rows)
+
+
+def note_occupancy(rows: int) -> None:
+    """Launch-seam helper (serve/scorer._note_launch). No-op without a
+    process-default telemetry."""
+    t = DEFAULT
+    if t is not None:
+        t.note_occupancy(rows)

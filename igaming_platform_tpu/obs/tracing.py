@@ -437,6 +437,11 @@ class SpanCollector:
             except Exception:  # noqa: BLE001 — metrics must not fail tracing
                 pass
 
+    def recent(self) -> list[Span]:
+        """The ring as it stands, oldest first; nothing is taken out."""
+        with self._lock:
+            return list(self._spans)
+
     def drain(self) -> list[Span]:
         self.report_drops()
         with self._lock:
@@ -504,6 +509,23 @@ def span(name: str, collector: SpanCollector | None = None, *,
     s.cpu_sampled = next(_ROOT_SEQ) % CPU_SAMPLE_EVERY == 0
     # the trace's one reading of the wall clock
     s._wall_offset = time.time() - time.perf_counter()
+    return s
+
+
+def emit_span(name: str, mono_start: float, mono_end: float,
+              **attributes) -> Span:
+    """A span of something that was watched from outside and never
+    entered: it gets a trace of its own and the given ``perf_counter``
+    stamps, and goes to the ring and the span sinks as a completed span
+    does. No root sink sees it (it is no request), and it touches no
+    thread's chain."""
+    s = Span(name, mono_start=mono_start, mono_end=mono_end,
+             trace_id=_new_trace_id(), attributes=attributes)
+    s._sid = (_SPAN_ID_BASE + next(_SPAN_SEQ)) & _SPAN_ID_MASK or 1
+    s._tid = threading.get_ident()
+    offset = s._wall_offset = time.time() - time.perf_counter()
+    s.start, s.end = mono_start + offset, mono_end + offset
+    s._complete()
     return s
 
 

@@ -1244,6 +1244,7 @@ class TPUScoringEngine:
                 len(host), sum(int(a.nbytes) for a in host))
         _rt.note_h2d(*cost)
         _rt.note_padded_rows(idxsp.shape[0])
+        _rt.note_occupancy(int(args[-1]))
 
     def _blacklist_flags(self, n: int, ips, devices, fingerprints) -> np.ndarray:
         """Per-request blacklist vector from the host sets — the cheap

@@ -673,6 +673,18 @@ class RiskServer:
                     # stop when investigating a slow request.
                     from igaming_platform_tpu.obs.flight import DEFAULT_RECORDER
                     self._send(200, DEFAULT_RECORDER.to_json())
+                elif self.path == "/debug/stallz":
+                    # Stall watch: the last incidents in which an RPC
+                    # stayed open past STALL_DUMP_MS, each with every
+                    # thread's stack, open spans and CPU while it lasted,
+                    # and the heartbeat's wake-up lateness. An RPC of
+                    # seconds starts here, before /debug/flightz, which
+                    # sees a request only once it has ended (runbook:
+                    # docs/operations.md "Reading a slow request").
+                    from igaming_platform_tpu.obs import hostprof as _hostprof_mod
+
+                    self._send(200, json.dumps(
+                        _hostprof_mod.get_default().heartbeat.snapshot()))
                 elif self.path.startswith("/debug/hostprofz"):
                     # Host-plane cost observatory: per-stage µs/row
                     # table, GC pause accounting, heap gauges and the

@@ -62,6 +62,12 @@ os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 # ``enabled=True``; the tests of deadlines send explicit ones.
 os.environ.setdefault("BURN_SHED", "0")
 os.environ.setdefault("DEADLINE_DEFAULT_MS", "600000")
+# The stall watch (obs/hostprof.Heartbeat) samples every thread while an
+# RPC stays open past STALL_DUMP_MS, which a starved test machine's first
+# compiles do all the time, and writes a file and a WARNING for each. Off
+# for the suite (subprocess servers inherit it; the heartbeat itself still
+# runs and counts); tests/test_stall_watch.py sets it.
+os.environ.setdefault("STALL_DUMP_MS", "0")
 
 # A hang costs one test, not the run (the driver's clock is 1470 s).
 TEST_TIMEOUT_S = 300

@@ -23,6 +23,7 @@ from chipbench.tests import test_span_metrics as _span_metrics
 from chipbench.tests.conftest import copy as _bare_copy
 from chipbench.tests.test_bounds import *  # noqa: F401,F403
 from chipbench.tests.test_falconh1_cell import *  # noqa: F401,F403
+from chipbench.tests.test_heartbeat_metrics import *  # noqa: F401,F403
 from chipbench.tests.test_lfm2_cell import *  # noqa: F401,F403
 from chipbench.tests.test_ling_cell import *  # noqa: F401,F403
 from chipbench.tests.test_manifest import *  # noqa: F401,F403
@@ -55,7 +56,7 @@ def test_the_manifest_lists_them_last_and_validates():  # noqa: F811
 def test_the_manifest_keeps_the_span_metrics_together_and_validates():
     """What the case above held that still holds: the eight follow everything
     the benchmark had before them, together and without a ``workloads`` key,
-    whatever came later names its cells (but for the one metric since that
+    whatever came later names its cells (but for the two metrics since that
     every cell reads too), and every cell reads the eight."""
     from chipbench import run, validate
 
@@ -72,9 +73,11 @@ def test_the_manifest_keeps_the_span_metrics_together_and_validates():
         entry = by_name[name]
         assert (entry["unit"], entry["layer"]) == (unit, layer)
         assert "workloads" not in entry
-    # PR 46's counter is read wherever the index program is launched
+    # PR 46's counter is read wherever the index program is launched, and
+    # PR 54's heartbeat runs in every process
     assert [n for n in names[first + len(expected):]
-            if "workloads" not in by_name[n]] == ["padded_rows_per_row"]
+            if "workloads" not in by_name[n]] == ["padded_rows_per_row",
+                                                  "wake_late_us"]
     for cell in manifest["workloads"]:
         got = {m["name"] for m in validate.load_cell(cell["name"])["per_layer"]}
         assert set(expected) <= got, cell["name"]
@@ -106,7 +109,8 @@ def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypat
     last = max(names.index(name) for name in _falconh1.METRICS)
     assert names[last + 1] == "padded_rows_per_row"  # PR 46
     assert all(name.startswith(("ling_", "kda_")) for name in names[last + 2:last + 12])  # PR 49
-    assert all(name.startswith(("xing_", "hc_")) for name in names[last + 12:])  # PR 52
+    assert all(name.startswith(("xing_", "hc_")) for name in names[last + 12:last + 22])  # PR 52
+    assert names[last + 22:] == ["wake_late_us", "rpc_over_50ms_share"]  # PR 54
     cells = [w["name"] for w in manifest["workloads"]]
     assert cells[cells.index(_falconh1.CELL) + 1:] == [
         "ling-kda-insession", "xing-mhc-insession"]  # PR 49, PR 52

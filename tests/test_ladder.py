@@ -126,5 +126,8 @@ def test_padded_rows_are_counted_at_every_launch_of_the_index_program(
             ids, amounts, types, now = next(frames(1, rows, 300))
             eng.score_columns_cached(ids, amounts, types, now=now)
             assert read.value() - before == padded == eng._pick_shape(rows)
+            # the real rows beside the rung: the ladder's occupancy
+            occupancy = metrics.batch_occupancy
+            assert occupancy._sums[()] == rows * occupancy.count() > 0
         finally:
             eng.close()

@@ -143,9 +143,12 @@ REPO_CONFIG = {
         # Decisions enter the ledger from request threads and the two
         # batcher-side callback roles declared above — nothing else.
         "note_decisions": ("main", "continuous-batcher", "batch-collector"),
-        # The sampler registry is read by the hostprof sampler and by
-        # snapshot()/export endpoints on caller threads only.
-        "registered_threads": ("main", "hostprof-sampler"),
+        # The sampler registry is read by the hostprof sampler, by the
+        # heartbeat's stall watch (to name the threads it samples while an
+        # RPC is held) and by snapshot()/export endpoints on caller
+        # threads only.
+        "registered_threads": ("main", "hostprof-sampler",
+                               "hostprof-heartbeat"),
     },
 }
 

@@ -12,9 +12,12 @@ children; in a
 trace that reads thread CPU and in one that does not
 (``tracing.CPU_SAMPLE_EVERY``). Prints one JSON line: microseconds, the
 median over ``--repeat`` rounds, and what the two clocks a span reads
-cost on this machine. A tight loop on warm caches: in a serving process
-a span costs several times this (PERF.md, PR 38). A CPU stopwatch: the
-figures go into PERF.md, never into a test.
+cost on this machine, and (c) what the heartbeat pays a tick
+(``hostprof.Heartbeat``, 20 a second on its own thread): the stall
+watch's look with one RPC in flight and none held, and the process clock
+it reads. A tight loop on warm caches: in a serving process a span costs
+several times this (PERF.md, PR 38). A CPU stopwatch: the figures go into
+PERF.md, never into a test.
 """
 
 from __future__ import annotations
@@ -104,13 +107,29 @@ def measure(repeat: int) -> dict:
                      - statistics.median(roots[False, reads_cpu])) / CHILDREN
         out["child_span_reading_cpu_us" if reads_cpu else "child_span_us"] = (
             round(per_child, 2))
-    for clock in (time.thread_time, time.perf_counter):
+    for clock in (time.thread_time, time.perf_counter, time.process_time):
         t0 = time.perf_counter()
         for _ in range(20_000):
             clock()
         out[f"{clock.__name__}_call_us"] = round(
             (time.perf_counter() - t0) / 20_000 * 1e6, 3)
+    out.update(heartbeat_tick(repeat))
     return out
+
+
+def heartbeat_tick(repeat: int) -> dict:
+    """One tick of a heartbeat that is not running (nothing else calls
+    it), with a root open on this thread as a request in flight would be."""
+    hb = hostprof.Heartbeat(hostprof.get_default())
+    hb.stall_s = 0.5  # the watch on, whatever STALL_DUMP_MS says here
+    tick_us = []
+    with span("rpc.ScoreBatch"):
+        for _ in range(repeat):
+            t0 = time.perf_counter()
+            hb.tick(t0, t0)
+            tick_us.append((time.perf_counter() - t0) * 1e6)
+    assert not hb.snapshot()["incidents"]
+    return {"heartbeat_tick_us": round(statistics.median(tick_us), 2)}
 
 
 def main() -> None:
