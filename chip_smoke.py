@@ -707,7 +707,7 @@ def phase_kernels(interpret: bool = False, *,
                   second_shape: tuple = (16384, 2048, 1536, 64, 4),
                   share_shape: tuple = (2048, 7680, 4096, 1000),
                   grouped_windows: int = 32, delta_windows: int = 32,
-                  stream_tiles: int = 32) -> dict:
+                  ssd_windows: int = 32, stream_tiles: int = 32) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -727,7 +727,11 @@ def phase_kernels(interpret: bool = False, *,
     ``ling`` head's mixer (32 heads of 128; the taps, the decay and the head
     norm inside) on ``delta_windows`` windows of 16 against the mixer's XLA
     path (``kda_one_chunk`` its core), with the core a trace would pick
-    there; and the two stream kernels of the ``xing`` head's residual path
+    there; and the state-space window kernel at the ``falconh1`` head's
+    mixer (32 heads of 128, a state of 256 in 2 groups; the taps, the gate
+    and the grouped norm inside) on ``ssd_windows`` windows of 16 against
+    the mixer's XLA path (``ssd_one_chunk`` its core), with the core a trace
+    would pick there; and the two stream kernels of the ``xing`` head's residual path
     (four streams of 3,584, 20 rounds) on ``stream_tiles`` tiles of 128
     positions against the ``jax.numpy`` functions, with the path a trace
     would pick there."""
@@ -974,6 +978,34 @@ def phase_kernels(interpret: bool = False, *,
     check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
           f"delta rule on {delta_windows} windows: max err {err} > {BACKBONE_TOL}")
 
+    from igaming_platform_tpu.models import falconh1_backbone
+    from igaming_platform_tpu.ops.pallas import ssd_window as sw
+
+    cfg = falconh1_backbone.FalconH1Config()
+    n = ssd_windows * t
+    width, _, bc, _, nh = cfg.segments
+    ks = jax.random.split(jax.random.key(n + 3), 7)
+    p = jax.random.normal(ks[0], (n, sum(cfg.segments)), jnp.float32)
+    layer = {"taps": jax.random.normal(ks[1], (width + 2 * bc, cfg.conv_taps),
+                                       jnp.float32) * 0.5,
+             "conv_b": jax.random.normal(ks[2], (width + 2 * bc,), jnp.float32) * 0.25,
+             "dt_bias": jax.random.normal(ks[3], (nh,), jnp.float32) - 1.0,
+             "a_log": jnp.log(jax.random.uniform(ks[4], (nh,), jnp.float32, 1.0, 16.0)),
+             "d_skip": 1.0 + 0.5 * jax.random.normal(ks[5], (nh,), jnp.float32),
+             "gn": 1.0 + 0.1 * jax.random.normal(ks[6], (width,), jnp.float32)}
+    picked = _said_by_the_expert_layer(
+        lambda: falconh1_backbone._core_is_the_kernel(n, cfg, t))
+    got = sw.ssd_window(
+        p, layer["taps"], layer["conv_b"], layer["dt_bias"], layer["a_log"],
+        layer["d_skip"], layer["gn"], heads=nh, state=cfg.ssm_state,
+        groups=cfg.ssm_groups, window=t, eps=cfg.eps, interpret=interpret)
+    want = jax.jit(lambda a: falconh1_backbone._core_by_xla(a, layer, cfg, t))(p)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    report[f"ssd_window_W{ssd_windows}"] = {"max_err": err, "core": picked[0]}
+    check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
+          f"state-space mixer on {ssd_windows} windows: max err {err} > "
+          f"{BACKBONE_TOL}")
+
     from igaming_platform_tpu.models import decoder_parts, xing_backbone
     from igaming_platform_tpu.ops.pallas import hyper_streams as hs
 
@@ -1018,7 +1050,9 @@ def phase_kernels(interpret: bool = False, *,
 # and its expert kernels say how they are fed (the ring's slots of
 # ``gate_up`` / ``down``, the rows brought in by ``gate_up`` itself);
 # ``falconh1`` has no expert layer, and its state-space core says which form
-# it runs (the dual form over the one chunk a window is).
+# it runs (since PR 55 the window kernel of ops/pallas/ssd_window.py: the
+# taps, the dual form over the one chunk a window is, the gate and the
+# grouped norm as one call a layer).
 BACKBONES = {
     "pangu": ("risk-seqhead-openpangu-ultra-moe-718b", "openpangu_ultra",
               "pangu_backbone",
@@ -1029,7 +1063,9 @@ BACKBONES = {
                              "rows=in-kernel)", "way_back": "pallas-rows"}),
     "falconh1": ("risk-seqhead-falcon-h1-34b", "falcon_h1_34b",
                  "falconh1_backbone",
-                 {"ssm_core": "dual form, one chunk, 16 <= 128"}),
+                 {"ssm_core": "window kernel (tile=128, 32 heads of 128, state "
+                              "256 in 2 groups, window 16, taps=4, gate and "
+                              "norm inside)"}),
     "ling": ("risk-seqhead-ling-3.0-flash", "ling_3_flash", "ling_backbone",
              {"linear_core": "pallas-windows (32 heads of 128, window 16, "
                              "prologue=taps, norm=inside)",
@@ -1068,7 +1104,8 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     Pallas kernels at their second shape; ``falconh1``: four layers of a
     Mamba-2 mixer beside grouped-query attention and a dense SwiGLU of
     21,504, no expert, 3.44 GB (chipbench/heads/falcon_h1_34b.py), whose
-    state-space core runs in its dual form against the reference's
+    state-space core runs in its dual form (on a TPU inside the window
+    kernel of ops/pallas/ssd_window.py) against the reference's
     recurrence; ``ling``: one dense and six expert layers, five Kimi Delta
     Attention layers to one of latent attention, a shared expert beside 64
     of 512 group-routed experts held, 5.53 GB

@@ -575,8 +575,11 @@ def announce_core(core: str, backend: str, part: str = "expert core") -> None:
     they are the kernels how those are fed; ``combine``: the results' way
     back to position order; ``attention core``: the window kernel or the
     einsums, with the kernel's reason where it declines; ``state-space
-    core`` and ``linear-attention core``: the form the recurrence is
-    computed in; ``residual path``: the two stream kernels with their tile
+    core``: ``window kernel (tile=128, heads, state and groups, window,
+    taps, gate and norm inside)`` where ops/pallas/ssd_window.py runs a
+    Mamba-2 mixer between its projections, or ``dual form, one chunk, T <=
+    chunk`` with the kernel's reason in brackets where XLA does;
+    ``linear-attention core``: the form the recurrence is computed in; ``residual path``: the two stream kernels with their tile
     (``pallas-streams (tile=128, ...)``) or ``xla`` with the kernels' reason,
     and how many streams a layer carries and the rounds of its mixing map,
     where that is not the one stream):

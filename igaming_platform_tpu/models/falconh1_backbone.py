@@ -47,6 +47,20 @@ position-major; ``N`` an RMSNorm with a learned gain):
    mlp_multipliers[1]`` with ``f = N_ff(r)`` (``decoder_parts.swiglu``
    with a gate scale).
 
+**Which form runs where.** On a TPU, where the shapes allow (heads and a
+state of whole 128-lane vregs, windows of 8, 16, 32, 64 or 128 positions,
+whole tiles of 128 positions: the cell's at both rungs), everything of the
+mixer between ``W_in``'s product and ``W_out``'s is ONE Pallas call a
+layer, ``ops/pallas/ssd_window.py``: it reads ``p`` where the product wrote
+it and writes the gated, normed operand of ``W_out`` in the operands'
+dtype. Off the TPU, or where the kernel declines, the same arithmetic runs
+by XLA (``_core_by_xla``: ``causal_taps``, ``ssd_one_chunk``, the gate and
+``rms_norm``), which is also what the kernel is held to. ``ssm_mixer``
+picks while tracing, from the backend and the shapes alone
+(``_core_is_the_kernel``), and announces the ``state-space core``:
+``window kernel (tile=128, ...)`` or ``dual form, one chunk, T <= chunk
+(the kernel's reason)``. Float32 with no operand rounded either way.
+
 After the last layer one more RMSNorm. A window's padding (positions past
 its length) is computed with the rest of the batch: the convolution, the
 dual form's ``L`` and the attention mask are causal, so nothing that is
@@ -68,8 +82,10 @@ residual stream, norms, the convolution, the gate, softmax, the muP
 scalars and the logit float32.
 
 ``jax.named_scope`` marks the parts: ``head/embed``, ``head/ssm`` (inside
-it ``in``, ``conv``, ``scan``, ``gate``, ``out``; the norm both mixers read
-is computed under ``head/ssm``), ``head/attn`` (the attention branch and
+it ``in``, ``scan`` and ``out`` where the kernel runs, ``scan`` then the
+whole call with the taps and the gate in it; ``in``, ``conv``, ``scan``,
+``gate``, ``out`` by XLA; the norm both mixers read is computed under
+``head/ssm``), ``head/attn`` (the attention branch and
 the three-way add), ``head/mlp/dense`` (its norm, the SwiGLU and its add),
 ``head/score`` (the final norm and the scoring column).
 """
@@ -225,6 +241,14 @@ def init_backbone(key, cfg: FalconH1Config) -> Params:
         next(keys), d, cfg.lm_head_multiplier)
 
 
+def _refuse_past_one_chunk(t: int, cfg: FalconH1Config) -> None:
+    if t > cfg.chunk:
+        raise ValueError(
+            f"a window of {t} positions is longer than one chunk "
+            f"(mamba_chunk_size {cfg.chunk}): the dual form over one chunk "
+            "holds no state for a second one")
+
+
 def ssd_one_chunk(x, bm, cm, dt, layer: Params, cfg: FalconH1Config):
     """The state-space core in its dual form over one chunk: ``x`` [B, T,
     heads, head_dim], ``bm`` and ``cm`` [B, T, groups, state], ``dt`` [B,
@@ -236,13 +260,7 @@ def ssd_one_chunk(x, bm, cm, dt, layer: Params, cfg: FalconH1Config):
     from ``H_{-1} = 0`` gives. ``c_t - c_s <= 0`` for ``s <= t`` (``A`` is
     negative, ``dt`` positive), so nothing overflows."""
     t = x.shape[1]
-    if t > cfg.chunk:
-        raise ValueError(
-            f"a window of {t} positions is longer than one chunk "
-            f"(mamba_chunk_size {cfg.chunk}): the dual form over one chunk "
-            "holds no state for a second one")
-    announce_core(f"dual form, one chunk, {t} <= {cfg.chunk}",
-                  kernel_declines()[1], "state-space core")
+    _refuse_past_one_chunk(t, cfg)
     highest = jax.lax.Precision.HIGHEST
     c = jnp.cumsum(dt * -jnp.exp(layer["a_log"]), axis=1)       # [B, T, H]
     causal = jnp.tril(jnp.ones((t, t), bool))[:, :, None]
@@ -254,14 +272,65 @@ def ssd_one_chunk(x, bm, cm, dt, layer: Params, cfg: FalconH1Config):
     return y + layer["d_skip"][:, None] * x
 
 
+def _core_is_the_kernel(positions: int, cfg: FalconH1Config, window: int) -> bool:
+    """Whether everything of the mixer between its two projections over
+    ``positions`` positions in windows of ``window`` runs as the Pallas
+    kernel (ops/pallas/ssd_window.py: on a TPU, where it takes the shapes)
+    or as ``_core_by_xla``. Picked while tracing, from backend and shapes,
+    and announced once a compile as the ``state-space core``: ``window
+    kernel (tile=128, ...)``, or the dual form with the kernel's reason. A
+    window past ``chunk`` is refused either way."""
+    from igaming_platform_tpu.ops.pallas import ssd_window as kernel
+
+    _refuse_past_one_chunk(window, cfg)
+    nh, hd = cfg.ssm_heads, cfg.ssm_head_dim
+    why, backend = kernel_declines(lambda: kernel.declines(
+        positions, heads=nh, head_dim=hd, state=cfg.ssm_state,
+        groups=cfg.ssm_groups, window=window, taps=cfg.conv_taps))
+    announce_core(
+        f"dual form, one chunk, {window} <= {cfg.chunk} ({why})" if why else
+        f"window kernel (tile=128, {nh} heads of {hd}, state {cfg.ssm_state} "
+        f"in {cfg.ssm_groups} groups, window {window}, taps={cfg.conv_taps}, "
+        "gate and norm inside)",
+        backend, "state-space core")
+    return not why
+
+
 def ssm_mixer(u, layer: Params, cfg: FalconH1Config, window: int):
     """The Mamba-2 mixer over normed hidden states ``u`` [P, hidden] -> [P,
-    hidden], its output multiplier applied."""
-    width, _, bc, _, nh = cfg.segments
-    t = window
-    b = u.shape[0] // t
+    hidden], its output multiplier applied. Everything between the two
+    projections (the taps with their bias, ``silu``, ``softplus``, the
+    decay, the dual form, the gate and the grouped norm) is one Pallas
+    kernel over the in-projection's result as it lies where
+    ``_core_is_the_kernel`` finds that it takes the layer; elsewhere the
+    same arithmetic by XLA, ``ssd_one_chunk`` its core: float32 with no
+    operand rounded either way."""
+    from igaming_platform_tpu.ops.pallas import ssd_window as kernel
+
     with jax.named_scope("in"):
         p = mm(u * cfg.ssm_in_multiplier, layer["w_in"], cfg) * mup_vector(cfg)
+    if _core_is_the_kernel(u.shape[0], cfg, window):
+        with jax.named_scope("scan"):
+            g = kernel.ssd_window(
+                p, layer["taps"], layer["conv_b"], layer["dt_bias"],
+                layer["a_log"], layer["d_skip"], layer["gn"],
+                heads=cfg.ssm_heads, state=cfg.ssm_state,
+                groups=cfg.ssm_groups, window=window, eps=cfg.eps,
+                out_dtype=cfg.operand_dtype)
+    else:
+        g = _core_by_xla(p, layer, cfg, window)
+    with jax.named_scope("out"):
+        return mm(g, layer["w_out"], cfg) * cfg.ssm_out_multiplier
+
+
+def _core_by_xla(p, layer: Params, cfg: FalconH1Config, t: int):
+    """The in-projection's result ``p`` [P, sum(segments)] -> the gated,
+    normed operand of the out-projection [P, ssm_width] float32, as the
+    kernel returns it (there rounded): the taps, ``ssd_one_chunk``, the gate
+    and the grouped norm over ``[b, t, ...]``."""
+    width, _, bc, _, nh = cfg.segments
+    b = p.shape[0] // t
+    with jax.named_scope("in"):
         z, xbc, dt = p[:, :width], p[:, width:2 * width + 2 * bc], p[:, -nh:]
     with jax.named_scope("conv"):
         xbc = jax.nn.silu(causal_taps(xbc.reshape(b, t, -1), layer["taps"],
@@ -276,8 +345,7 @@ def ssm_mixer(u, layer: Params, cfg: FalconH1Config, window: int):
         # the gate first, then the norm over each group's channels
         g = (y * jax.nn.silu(z)).reshape(b * t, cfg.ssm_groups, -1)
         g = rms_norm(g, layer["gn"].reshape(cfg.ssm_groups, -1), cfg.eps)
-    with jax.named_scope("out"):
-        return mm(g.reshape(b * t, width), layer["w_out"], cfg) * cfg.ssm_out_multiplier
+    return g.reshape(b * t, width)
 
 
 def backbone_hidden(params: Params, x, cfg: FalconH1Config):

@@ -423,12 +423,17 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
     """The fused step with the ``falconh1`` backbone in it, at the cell's
     size (5,242,880 accounts, one 256-row rung): in place on the ring, its
     arguments are the state plus 3.44 GB of weights. Every layer has both
-    mixers and the MLP under the scopes the trace reads them by; the
-    state-space core is the dual form over one chunk, so no array of a
-    state's size (32 x 128 x 256 a window) is in the module, and the
-    convolution's taps are no ``convolution``. There is no expert layer:
-    no Pallas kernel and no grouped product. Code size and temporaries are
-    printed."""
+    mixers and the MLP under the scopes the trace reads them by. Since PR 55
+    everything of a state-space mixer between its two projections (the taps
+    with their bias, ``silu``, ``softplus``, the decay, the dual form over
+    one chunk, the gate and the grouped norm) is ONE Pallas call a layer,
+    ``_ssd_window`` (ops/pallas/ssd_window.py) under ``head/ssm/scan``, over
+    the in-projection's own ``[4096, 9248]`` result and writing the
+    out-projection's bfloat16 operand: no ``head/ssm/conv`` and no
+    ``head/ssm/gate`` is left, no copy of the projection's result, none of
+    the einsum form's layouts, no array of a state's size (32 x 128 x 256 a
+    window), and the taps are no ``convolution``. There is no expert layer:
+    no grouped product. Code size and temporaries are printed."""
     from jax.sharding import SingleDeviceSharding
 
     from igaming_platform_tpu.models.session_heads import HEADS
@@ -448,13 +453,26 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
     assert _ring_sized_copies(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 8.1e9 < mem.argument_size_in_bytes < 8.3e9, mem
-    assert mem.temp_size_in_bytes <= 2 * 2**30, mem
+    assert mem.temp_size_in_bytes <= FALCONH1_TEMPS_256, mem
     text = compiled.as_text()
-    for scope in ("head/embed", "head/ssm/in", "head/ssm/conv", "head/ssm/scan",
-                  "head/ssm/gate", "head/ssm/out", "head/attn",
-                  "head/mlp/dense", "head/score"):
+    for scope in ("head/embed", "head/ssm/in", "head/ssm/scan", "head/ssm/out",
+                  "head/attn", "head/mlp/dense", "head/score"):
         assert scope in text, scope
-    assert "tpu_custom_call" not in text and "%ragged-dot" not in text
+    for scope in ("head/ssm/conv", "head/ssm/gate"):
+        assert scope not in text, scope
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line
+             and "custom-call(" in line]
+    assert len(calls) == cfg.layers and "%ragged-dot" not in text
+    assert all("_ssd_window" in line and "head/ssm/scan" in line for line in calls)
+    batch = BATCH * ss.default_events()
+    wide = sum(cfg.segments)
+    # the kernel reads the product's result where it lies: nothing copies it
+    assert f"f32[{batch},{wide}]" in text
+    assert not [line for line in text.splitlines()
+                if f"f32[{batch},{wide}]" in line.split("=")[0] and " copy(" in line]
+    for layout in (f"[{BATCH},16,32,128]", f"[{BATCH},16,16,32]",
+                   f"[{BATCH},32,16,16]", f"f32[{batch},4096]"):
+        assert layout not in text, layout
     assert not [line for line in text.splitlines() if " convolution(" in line
                 and "head/ssm/conv" in line]
     state = f"{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]"
@@ -625,6 +643,14 @@ def _assert_the_residual_path_is_two_passes(text, capsys, positions, cfg):
 XING_TEMPS_256 = 491_360_256
 
 
+# What the ``falconh1`` step holds in temporaries at the 256-row rung since PR
+# 55 (read: 627,736,576 B; 631,744,000 at PR 46 with the float32 passes between
+# the mixer's projections, which were never the peak: the MLP's two ``[4096,
+# 21504]`` float32 products are); the 64-row rung reads 139,813,888 B
+# (142,620,160 at PR 46).
+FALCONH1_TEMPS_256 = 627_736_576
+
+
 # What the ``ling`` step holds in temporaries at the 256-row rung since PR 51,
 # the routers' sorts out of it (479,243,264 B at PR 50 with the delta kernel,
 # 984,582,656 with the einsum core, PR 49); the 64-row rung reads 52,811,776 B
@@ -669,10 +695,51 @@ def test_delta_core_is_one_call_a_layer(topo, tpu_backend, batch):
     assert f"f32[{batch * 16},4096]" in text
 
 
+@pytest.mark.parametrize("batch", [256, 64])
+def test_state_space_core_is_one_call_a_layer(topo, tpu_backend, batch):
+    """One state-space mixer compiled alone at each rung's positions: three
+    device programs in a row, the in-projection with its multipliers fused
+    in, one custom call under the scope ``scan`` where ``ssm_scan_ms`` reads
+    it, and the out-projection reading the call's bfloat16 result. The
+    in-projection's result is written positions-major and read where it
+    lies: no ``copy`` of it (handed ``dt`` as a transposed slice, XLA wrote
+    the product channels-major and copied all 151 MB of it for the call:
+    ``dt`` is the kernel's fifth block view for that reason), no float32
+    slice of it, none of the einsum form's ``[b, 16, ...]`` layouts, and no
+    float32 ``[P, 4096]`` between the projections."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models import falconh1_backbone as fb
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = fb.FalconH1Config()
+    layer = jax.eval_shape(
+        lambda: fb.init_backbone(jax.random.key(0), cfg))["layers"][0]
+    layer = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one), layer)
+    positions, wide = batch * 16, sum(cfg.segments)
+    u = _spec((positions, cfg.hidden), jnp.float32, one)
+    text = jax.jit(lambda u, layer: fb.ssm_mixer(u, layer, cfg, 16)).lower(
+        u, layer).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line
+             and "custom-call(" in line]
+    assert len(calls) == 1 and "_ssd_window" in calls[0] and "/scan/" in calls[0]
+    assert f"bf16[{positions},{cfg.ssm_width}]" in calls[0].split("=")[1]
+    made = [line for line in text.splitlines()
+            if f" f32[{positions},{wide}]" in line.split("(")[0]]
+    assert made and all("{1,0:" in line and " copy(" not in line
+                        and " slice(" not in line for line in made), made
+    for layout in (f"[{batch},16,32,128]", f"[{batch},16,16,32]",
+                   f"[{batch},16,5120]", f"[{batch},16,2,256]",
+                   f"f32[{positions},{cfg.ssm_width}]"):
+        assert layout not in text, layout
+
+
 @pytest.mark.parametrize("head,capacity,temps_256,in_tree", [
     ("pangu", 3_145_728, 950_890_496,
      {"_window_attention": 5, "_combine_held": 4, "ragged-dot-none": 12}),
-    ("falconh1", 5_242_880, 631_744_000, {}),
+    ("falconh1", 5_242_880, FALCONH1_TEMPS_256, {"_ssd_window": 4}),
     ("keye", 5_242_880, 373_297_152,
      {"_gate_up": 4, "_down": 4, "_combine_rows": 4,
       "_grouped_window_attention": 4}),

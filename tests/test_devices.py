@@ -184,7 +184,7 @@ def test_smoke_kernels_phase_tiny_interpreted():
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
         expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
         share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8,
-        stream_tiles=1)
+        ssd_windows=8, stream_tiles=1)
     assert report["interpret"] is True
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
@@ -212,6 +212,11 @@ def test_smoke_kernels_phase_tiny_interpreted():
     assert delta["max_err"] <= chip_smoke.BACKBONE_TOL
     assert delta["core"] == ("linear-attention core: one chunk by einsums "
                              "(not a TPU) (backend=cpu)")
+    # the state-space window kernel (falconh1's mixer) on one tile of 8 windows
+    ssd = report["ssd_window_W8"]
+    assert ssd["max_err"] <= chip_smoke.BACKBONE_TOL
+    assert ssd["core"] == ("state-space core: dual form, one chunk, 16 <= 128 "
+                           "(not a TPU) (backend=cpu)")
     # the stream kernels (xing's residual path) on one tile of 128 positions
     streams = report["hyper_streams_T1"]
     assert streams["max_err"] <= chip_smoke.STREAMS_TOL

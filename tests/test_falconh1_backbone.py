@@ -341,6 +341,151 @@ def test_the_dual_form_equals_the_explicit_recurrence(t):
         np.testing.assert_array_equal(later[:, :t - 1], got[:, :t - 1])
 
 
+# -- the mixer's core as the window kernel (ops/pallas/ssd_window.py) ------------
+
+
+def wide_source() -> dict:
+    """The small size with state-space heads and a state as wide as the
+    kernel takes them: 2 heads of 128 channels, a state of 128 a channel in
+    2 groups."""
+    return small_source(mamba_n_heads=2, mamba_d_head=128, mamba_d_ssm=256,
+                        mamba_d_state=128)
+
+
+@pytest.fixture
+def ssm_core_by_kernel(monkeypatch, caplog):
+    """Runs ``fn`` as a TPU would trace the state-space mixers, the window
+    kernel through the Pallas interpreter, and returns what it computed
+    with what the state-space core said. Steered here, in the test, and for
+    this part alone."""
+    import functools
+
+    from igaming_platform_tpu.ops.pallas import ssd_window as sw
+
+    def run(fn):
+        dp.announce_core.cache_clear()
+        caplog.clear()
+        with monkeypatch.context() as m, caplog.at_level("INFO", logger=dp.logger.name):
+            m.setattr(fb, "kernel_declines",
+                      lambda declines=None: (declines and declines(), "tpu"))
+            m.setattr(sw, "ssd_window", functools.partial(
+                sw.ssd_window, interpret=True))
+            out = fn()
+        said = [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("state-space core")]
+        dp.announce_core.cache_clear()
+        return out, said
+    return run
+
+
+KERNEL_SAYS = ("state-space core: window kernel (tile=128, 2 heads of 128, state "
+               "128 in 2 groups, window 16, taps=4, gate and norm inside) "
+               "(backend=tpu)")
+
+
+def test_mixer_through_the_window_kernel_equals_the_mixer_by_xla(
+        head, ssm_core_by_kernel):
+    """One state-space sublayer on a stream with spread, float32 operands:
+    both projections are the same XLA either way, so what differs is the
+    order of float32 sums between them."""
+    source = wide_source()
+    cfg = program_config(source, operand_dtype=jnp.float32)
+    layer = head.make_params(7, source)["layers"][1]
+    u = jnp.asarray(np.random.default_rng(9).normal(0, 1, (128, 128)), jnp.float32)
+    mixer = lambda: np.asarray(jax.jit(
+        lambda x: fb.ssm_mixer(x, layer, cfg, 16))(u))
+    by_kernel, said = ssm_core_by_kernel(mixer)
+    by_xla = mixer()
+    assert said == [KERNEL_SAYS]
+    assert dp.announced_cores()["state-space core"] == (
+        "dual form, one chunk, 16 <= 128 (not a TPU) (backend=cpu)")
+    scale = np.abs(by_xla).max()
+    assert scale > 1e-3
+    np.testing.assert_allclose(by_kernel, by_xla, atol=2e-5 * scale, rtol=0)
+
+
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+def test_head_through_the_window_kernel_equals_the_reference(
+        head, operands, ssm_core_by_kernel):
+    """The whole head with every state-space mixer's core the Pallas kernel
+    (8 windows: one tile of 128 positions, mixed lengths) against the plain
+    reference's recurrence, under the limits the XLA path meets, and
+    against the XLA path itself; the kernel's result leaves in the
+    operands' dtype, rounded where ``mm`` rounds the XLA path's."""
+    dt = jnp.dtype(operands)
+    source = wide_source()
+    cfg = program_config(source, operand_dtype=dt)
+    params = head.make_params(7, source)
+    d = head.dims_of(source)
+    x, lens = windows(8, (1, 4, 16, 7, 9, 2), seed=7)
+    (logit, hidden), said = ssm_core_by_kernel(
+        lambda: program_logits(cfg, params, x, lens))
+    assert said == [KERNEL_SAYS]
+    want_logit = head._logits(params, x, lens, d, dt)
+    want_hidden = head._logits(params, x, lens, d, dt, hidden=True)
+    atol = 2e-5 if operands == "float32" else ROUNDING
+    np.testing.assert_allclose(logit, want_logit, atol=atol, rtol=0)
+    np.testing.assert_allclose(hidden, want_hidden, atol=atol, rtol=0)
+    by_xla, _ = program_logits(cfg, params, x, lens)
+    np.testing.assert_allclose(logit, by_xla, atol=atol, rtol=0)
+    assert np.std(want_logit) > 0.01
+
+
+@pytest.mark.parametrize("backend,over,window,said", [
+    ("cpu", {}, 16, "dual form, one chunk, 16 <= 128 (not a TPU) (backend=cpu)"),
+    ("tpu", {}, 16, "window kernel (tile=128, 32 heads of 128, state 256 in 2 "
+                    "groups, window 16, taps=4, gate and norm inside) "
+                    "(backend=tpu)"),
+    ("tpu", {}, 8, "window kernel (tile=128, 32 heads of 128, state 256 in 2 "
+                   "groups, window 8, taps=4, gate and norm inside) "
+                   "(backend=tpu)"),
+    ("tpu", {"ssm_head_dim": 64}, 16,
+     "dual form, one chunk, 16 <= 128 (head width 64 is not whole 128-lane "
+     "vregs) (backend=tpu)"),
+    ("tpu", {"ssm_state": 16}, 16,
+     "dual form, one chunk, 16 <= 128 (a state of 16 is not whole 128-lane "
+     "vregs) (backend=tpu)"),
+    ("tpu", {}, 12, "dual form, one chunk, 12 <= 128 (windows of 12 are not "
+                    "whole 8-row vregs that divide a tile of 128) (backend=tpu)"),
+], ids=["off-the-tpu", "published", "window8", "head64", "state16", "window12"])
+def test_state_space_core_is_announced_with_the_reason_it_declines(
+        backend, over, window, said, monkeypatch, caplog):
+    """The choice is made while tracing, from the backend and the layer's
+    shapes alone; the boot's log line and ``/debug/sessionz``'s
+    ``head_cores`` carry it, with the kernel's own reason beside the dual
+    form."""
+    cfg = fb.FalconH1Config(**over)
+    dp.announce_core.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with caplog.at_level("INFO", logger=dp.logger.name):
+        by_kernel = fb._core_is_the_kernel(256 * window, cfg, window)
+    assert by_kernel is said.startswith("window kernel")
+    assert [r.getMessage() for r in caplog.records] == [
+        f"state-space core: {said}"]
+    assert dp.announced_cores()["state-space core"] == said
+    dp.announce_core.cache_clear()
+
+
+@pytest.mark.parametrize("rows,takes", [(256, True), (64, True), (6, False)],
+                         ids=["256-row-rung", "64-row-rung", "six-windows"])
+def test_both_rungs_are_whole_tiles_and_a_part_filled_one_takes_the_einsums(
+        rows, takes, monkeypatch):
+    """The 64-row rung is 1,024 positions and the 256-row one 4,096: whole
+    tiles, the kernel at both. A batch that is not (a test's, 6 windows)
+    declines in the kernel's words and runs ``ssd_one_chunk``; a window past
+    one chunk is refused whichever core would run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dp.announce_core.cache_clear()
+    assert fb._core_is_the_kernel(rows * 16, fb.FalconH1Config(), 16) is takes
+    said = dp.announced_cores()["state-space core"]
+    assert said.startswith("window kernel (tile=128, ") if takes else said == (
+        "dual form, one chunk, 16 <= 128 (96 positions are not whole tiles of "
+        "128) (backend=tpu)")
+    with pytest.raises(ValueError, match="longer than one chunk"):
+        fb._core_is_the_kernel(rows * 256, fb.FalconH1Config(), 256)
+    dp.announce_core.cache_clear()
+
+
 def test_the_reference_runs_the_recurrence_the_loop_runs(head):
     """The reference's own scan against the same explicit loop: the two
     sides of the cell's comparison are independent of each other and both
@@ -812,7 +957,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert snap["head_layers"] == LAYERS
     # which form the state-space core said it runs when the step was traced
     assert snap["head_cores"]["state-space core"] == (
-        "dual form, one chunk, 16 <= 128 (backend=cpu)")
+        "dual form, one chunk, 16 <= 128 (not a TPU) (backend=cpu)")
     text = text.replace(".0\n", "\n")
     for name, value in (("resident_bytes", resident), ("experts_held", 0),
                         ("experts_routed", 0)):
@@ -911,7 +1056,24 @@ def test_chip_smoke_phase_runs_the_head_against_its_reference():
     assert report["max_err"] < 1e-4 and report["rows"] == 8
     assert report["head"] == "falconh1"
     assert report["ssm_core"] == (
-        "state-space core: dual form, one chunk, 16 <= 128 (backend=cpu)")
+        "state-space core: dual form, one chunk, 16 <= 128 (not a TPU) "
+        "(backend=cpu)")
     assert report["expert_core"] is None and report["way_back"] is None
     assert report["attention_core"] is None  # this head's core is einsums
     assert report["resident_bytes"] > 0
+
+
+def test_chip_smoke_expects_the_window_kernel_on_a_tpu(monkeypatch):
+    """What ``chip_smoke.BACKBONES`` holds the phase to on a TPU at the
+    published widths is what the mixer announces there, on the phase's 32
+    windows (four whole tiles)."""
+    import chip_smoke
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dp.announce_core.cache_clear()
+    assert fb._core_is_the_kernel(32 * 16, session_heads.HEADS["falconh1"].config, 16)
+    line = chip_smoke.CORE_LINES["ssm_core"]
+    assert set(chip_smoke.BACKBONES["falconh1"][3]) == {"ssm_core"}
+    assert dp.announced_cores()[line] == (
+        f"{chip_smoke.BACKBONES['falconh1'][3]['ssm_core']} (backend=tpu)")
+    dp.announce_core.cache_clear()
