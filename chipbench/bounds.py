@@ -11,7 +11,9 @@ of one unchanged program lie apart: from the runs of a cell this draws,
 from a fixed seed, 1,000 pairs of disjoint sixes and takes
 ``|median(A) - median(B)| / median(A)`` of each. A bound is the 95th
 percentile of that in the cell where it is widest, rounded up to the
-next 0.005, and never outside 0.01 to 0.10.
+next 0.005, and never outside 0.01 to 0.10. A cell with fewer than twelve
+runs has no such draws (its ``aa_median`` and ``aa_p95`` read ``None``)
+and is held by its spread alone.
 
 That is the least a bound may be. Beside it stands the spread the driver
 judges a bound's width by: the distance between the first and third
@@ -83,6 +85,8 @@ def too_tight_share(values, bound: float, draws: int = DRAWS,
         far = np.argmax(np.abs(six - np.median(six)))
         return spread(np.delete(six, far).tolist())
 
+    if len(values) < 2 * SET:  # one set: nothing to draw
+        return float(trimmed(values) > bound / 2)
     over = 0
     for _ in range(draws):
         pick = rng.permutation(len(values))[:2 * SET]
@@ -112,14 +116,17 @@ def derive(root: str = validate.ROOT) -> dict:
                       and not (name == "setup_s" and line.get("first"))]
             if not values:
                 continue
-            diffs = aa_differences(values)
+            # a cell with one set of runs (a four-chip cell's cost four
+            # times a run's: PERF.md, PR 56) has a spread and no draws
+            diffs = aa_differences(values) if len(values) >= 2 * SET else None
             cells[cell] = {"runs": len(values),
                            "median": statistics.median(values),
-                           "aa_median": float(np.median(diffs)),
-                           "aa_p95": float(np.percentile(diffs, 95)),
+                           "aa_median": None if diffs is None else float(np.median(diffs)),
+                           "aa_p95": None if diffs is None else float(np.percentile(diffs, 95)),
                            "spread": spread(values)}
         if cells:
-            out[name] = {"bound": round_up(max(c["aa_p95"] for c in cells.values())),
+            out[name] = {"bound": round_up(max(c["aa_p95"] or 0.0
+                                               for c in cells.values())),
                          "cells": cells}
     return out
 
@@ -130,9 +137,10 @@ def main() -> int:
     rc = 0
     for name, d in derive().items():
         for cell, c in d["cells"].items():
+            draws = ("one set, no draws" if c["aa_p95"] is None else
+                     f"A/A median {c['aa_median']:.4%}  95th {c['aa_p95']:.4%}")
             print(f"{name:12s} {cell:24s} runs {c['runs']:3d}  median "
-                  f"{c['median']:.4f}  A/A median {c['aa_median']:.4%}  "
-                  f"95th {c['aa_p95']:.4%}  spread {c['spread']:.4%}")
+                  f"{c['median']:.4f}  {draws}  spread {c['spread']:.4%}")
         short = stated[name] < d["bound"]
         rc |= short
         widest = max(c["spread"] for c in d["cells"].values())

@@ -9,7 +9,10 @@ harness edits nothing in it. Two seams are used from outside:
 to a seeded time during the output check, so that inter-event gaps come
 from the seed; and where the session head has parameters they are
 replaced, before any traffic, by the seeded tree that the head's file
-under ``chipbench/heads/`` makes.
+under ``chipbench/heads/`` makes. Where the configuration preloads
+session events, they go in as a restart brings them back: appended to
+the host index (``session_state.group_chunk`` and ``prepare_chunk``, the
+calls scoring makes), then put into the ring by the admission hook.
 """
 
 from __future__ import annotations
@@ -33,6 +36,11 @@ SCORE_BATCH = "/risk.v1.RiskService/ScoreBatch"
 REHEARSAL = {"resident_accounts": 4096, "store_loaded_accounts": 1024,
              "fill_chunk": 1024, "pool_frames": 64, "warm_up_s": 0.5}
 TRACE_SLICE_S = 2.5
+# Histories are drawn and handed to the host index this many draws at a
+# time (16 MB of float64): arrays of that size come from the allocator's
+# heap again and again, where larger ones are mapped, and faulted in, anew.
+PRELOAD_DRAWS = 1 << 21
+PRELOAD_PROBES = 64  # accounts whose window is read back after the fill
 WARM_UP_S = 1.0  # of the pool's own traffic from every client, before t0
 RPC_TIMEOUT_S = 120.0
 
@@ -66,6 +74,51 @@ class Clock:
         return self.now
 
 
+def head_operand_dtype(head, platform: str, stated: str) -> str:
+    """The operand dtype the session head's reference is read at. XLA's CPU
+    backend multiplies float32 operands as they are and only the MXU rounds
+    them, so a head that leaves the rounding to the default precision (the
+    transformer head's ``x @ w``) is float32 in a rehearsal; a head whose
+    program casts its operands itself (``CASTS_OPERANDS`` in its
+    reference's file) is at the stated dtype on any backend. The trunk
+    casts explicitly on both."""
+    if platform == "cpu" and not getattr(head, "CASTS_OPERANDS", False):
+        return "float32"
+    return stated
+
+
+def account_major_groups(session_state, ids: list[str], counts, verify=False):
+    """What ``session_state.group_chunk`` makes of a chunk whose rows lie
+    account after account (``counts[u]`` rows of ``ids[u]``), written down
+    without its pass over every row in Python: a history's rows are tens
+    of millions and their grouping is known. ``verify`` holds the result
+    to ``group_chunk`` itself, field for field."""
+    counts = np.asarray(counts, np.int64)
+    nu, b = len(ids), int(counts.sum())
+    if nu == b:
+        rows = np.arange(b)
+        groups = session_state.ChunkGroups(
+            ids, rows, np.zeros((b,), np.int32), [1] * b, range(b), rows,
+            range(b + 1))
+    else:
+        bounds = np.concatenate([[0], np.cumsum(counts)])
+        uidx = np.repeat(np.arange(nu), counts)
+        occ = (np.arange(b) - bounds[:-1][uidx]).astype(np.int32)
+        groups = session_state.ChunkGroups(
+            ids, uidx, occ, counts.tolist(), bounds[:-1].tolist(),
+            np.arange(b), bounds.tolist())
+    if verify:
+        theirs = session_state.group_chunk(
+            np.array(ids, dtype=object).repeat(counts).tolist())
+        for name in groups.__slots__:
+            mine, want = getattr(groups, name), getattr(theirs, name)
+            if not (type(mine) is type(want) and list(mine) == list(want)
+                    and getattr(mine, "dtype", None) == getattr(want, "dtype", None)):
+                raise SystemExit(f"preload: group_chunk's {name} is no longer "
+                                 "what the harness writes for account-major rows")
+    return groups
+
+
 class Run:
     def __init__(self, spec: dict, *, seed: int, seconds: float, trace: bool,
                  rehearse: bool):
@@ -83,6 +136,7 @@ class Run:
             self.mix["pool_frames"] = REHEARSAL["pool_frames"]
         self.phase_s: dict[str, float] = {}
         self.pool = None
+        self.history = None  # account id -> its preloaded history
         self.index_mode = self.mix["rpc"] == "index"
 
     # -- boot ---------------------------------------------------------------
@@ -180,20 +234,20 @@ class Run:
             load(pop.id_of_rank(r), created_at=created[r],
                  **{k: v[r] for k, v in cols.items()})
         self.phase_s["store"] = time.perf_counter() - t0
+        spec = traffic.history_spec(cfg["session_events_preloaded"])
+        preloaded = 0 if spec is None else self.preload(spec)
         t1 = time.perf_counter()
         chunk = int(cfg["fill_chunk"])
-        # The ring's admission sync (SessionStateManager._sync) does not
-        # compile on a v5e once the ring passes ~1.5M slots: XLA re-lays the
-        # whole ring out in (8,128) tiles, 10.7 times its size (PERF.md,
-        # PR 24). A never-seen account's window is all zeros, which is what a
-        # freshly booted ring already holds in every slot, so the fill admits
-        # with the hook detached and leaves the same state the sync would:
-        # every resident account's window is EMPTY when the window starts
-        # (`session_events_preloaded` 0, under `reduced`). No entry point of
-        # the program can preload them: a warm window lives in a host buffer
-        # of its own per account (`_AcctSession`, 3 KB), and only scoring
-        # traffic writes one.
-        hook, inner.cache.session_hook = inner.cache.session_hook, None
+        hook = inner.cache.session_hook
+        if spec is None:
+            # A never-seen account's window is all zeros, which is what a
+            # freshly booted ring already holds in every slot, so the fill
+            # admits with the hook detached and leaves the same state the
+            # sync would, at no launch: every resident account's window is
+            # EMPTY when the window starts (`session_events_preloaded` 0,
+            # under `reduced`). The eight cells the benchmark had before
+            # PR 56 are measured from that state.
+            inner.cache.session_hook = None
         try:
             for lo in range(0, resident, chunk):
                 inner.cache.lookup(pop.ids[lo:lo + chunk], now=FILL_NOW)
@@ -203,14 +257,96 @@ class Run:
         stats = inner.cache.stats()
         if stats["occupancy"] != resident or stats["evictions"]:
             raise SystemExit(f"fill left the cache at {stats}")
+        if spec is not None:
+            self.probe_windows(spec)
         self.phase_s["fill"] = time.perf_counter() - t1
         log(f"fill: population+store {self.phase_s['store']:.2f} s, "
             f"{resident} accounts admitted in {self.phase_s['fill']:.2f} s, "
-            f"{int(cfg['session_events_preloaded'])} session events preloaded")
+            f"{preloaded} session events preloaded")
         self.memory_line("after fill")
 
+    def preload(self, spec: dict) -> int:
+        """Every resident account's history into the host index, as
+        scoring appends events to it: ``rounds`` chunks an account, round
+        ``j`` carrying the ``j``-th share of its events on that round's
+        clock, so the gaps between rounds are real and seeded and the
+        events of one round share an arrival, as the rows of one frame do.
+        Host only; the admission that follows puts each window into the
+        ring (``on_admit``), as after a restart. One block of accounts is
+        held at a time beside the index."""
+        from igaming_platform_tpu.serve import session_state
+
+        t0 = time.perf_counter()
+        session, pop = self.inner.session, self.pop
+        resident = pop.n
+        clocks = traffic.history_clocks(self.seed, spec["rounds"])
+        ids = np.array(pop.ids, dtype=object)[pop.perm]  # by rank
+        block = max(1, PRELOAD_DRAWS // (1 + 3 * spec["high"]))
+        events = 0
+        for lo in range(0, resident, block):
+            h = traffic.histories(self.mix, self.seed, lo,
+                                  min(block, resident - lo), spec)
+            amounts = h["amounts"].astype(np.float32)  # as the wire decoder
+            types = h["types"].astype(np.int32)        # hands them on
+            for j in np.unique(h["round"]):
+                rows = np.flatnonzero(h["round"] == j)
+                present, counts = np.unique(h["account"][rows],
+                                            return_counts=True)
+                groups = account_major_groups(
+                    session_state, ids[lo + present].tolist(), counts,
+                    verify=events == 0)
+                with session.lock:
+                    session.prepare_chunk(groups, amounts[rows], types[rows],
+                                          float(clocks[j]))
+            events += len(amounts)
+        self.phase_s["preload"] = time.perf_counter() - t0
+        log(f"preload: {events} events of {resident} accounts "
+            f"({spec['low']}-{spec['high']} each, {spec['rounds']} rounds "
+            f"ending {clocks[-1] - clocks[0]:.0f} s apart) into the host "
+            f"index in {self.phase_s['preload']:.2f} s")
+        return events
+
+    def probe_windows(self, spec: dict) -> None:
+        """The guarantee the preload rests on, read back: for a seeded
+        sample of accounts the host index's window and the ring's rows of
+        the account's slot are, bit for bit, the last ``SESSION_EVENTS``
+        events of its history as the reference encodes them."""
+        from igaming_platform_tpu.serve import session_state
+
+        session, pop = self.inner.session, self.pop
+        n_ev = session.n_events
+        ranks = traffic.rng_for(self.seed, "probe").choice(
+            pop.n, size=min(PRELOAD_PROBES, pop.n), replace=False)
+        ids = [pop.id_of_rank(r) for r in ranks]
+        wants = [reference.encode_history(
+            traffic.history_of(self.mix, self.seed, r, spec))[0][-n_ev:]
+            for r in ranks]
+        ring = None
+        if session.plan is None:  # a sharded ring is not gathered whole
+            slots = self.inner.cache.lookup(ids, now=FILL_NOW)
+            ring = np.asarray(session_state.ring_rows(
+                session.session_ring, self.jax.numpy.asarray(slots), n_ev))
+        for i, (account, want) in enumerate(zip(ids, wants)):
+            twin = session.twin_window(account)
+            if twin.shape != want.shape or twin.tobytes() != want.tobytes():
+                raise SystemExit(
+                    f"preload: the host index holds {twin.shape[0]} events of "
+                    f"{account} that are not its history's last {len(want)}")
+            if ring is not None and (
+                    ring[i, :len(want)].tobytes() != want.tobytes()
+                    or ring[i, len(want):].any()):
+                raise SystemExit(f"preload: the ring's rows of {account} are "
+                                 f"not its history's last {len(want)} events")
+        log(f"preload: {len(ids)} probed accounts hold their history's last "
+            f"events in the host index"
+            + ("" if ring is None else " and in the ring")
+            + f", {sum(len(w) == n_ev for w in wants)} of them a full window")
+
     def memory_line(self, when: str) -> dict:
-        stats = self.device.memory_stats() or {}
+        # the fullest of the chips the cell runs on
+        chips = self.jax.devices()[:int(self.spec["cell"]["chips"])]
+        stats = max((d.memory_stats() or {} for d in chips),
+                    key=lambda s: s.get("peak_bytes_in_use") or 0)
         log(f"memory {when}: bytes_in_use={stats.get('bytes_in_use')} "
             f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}")
         return stats
@@ -228,6 +364,13 @@ class Run:
             self.mix, self.pop, self.seed,
             loaded=int(cfg["store_loaded_accounts"]),
             stored=int(cfg["store_accounts"]))
+        spec = traffic.history_spec(cfg["session_events_preloaded"])
+        if spec is not None:
+            # the reference starts each account it meets from the history
+            # the fill gave it: the first check RPC scores warm windows
+            ranks = {a: self.pop.rank_of_id(a) for rpc in seq for a in rpc["ids"]}
+            mix, seed = self.mix, self.seed
+            self.history = lambda a: traffic.history_of(mix, seed, ranks[a], spec)
         clock, real_clock = Clock(), ledger_mod.wall_clock
         self.check_log = []
         ledger_mod.wall_clock = clock
@@ -270,23 +413,36 @@ class Run:
         come out as not correct."""
         cfg = self.config
         stated = cfg["precision"]["reference_operand_dtype"]
-        # XLA's CPU backend multiplies float32 operands as they are; only
-        # the MXU rounds them. The trunk casts explicitly on both.
-        head_stated = "float32" if self.device.platform == "cpu" else stated
+        head_stated = head_operand_dtype(self.head, self.device.platform, stated)
+
+        step = int(cfg["env"]["BATCH_SIZE"])
 
         def replay(dtype, head_dtype):
             ref = reference.Reference(
                 self.params, head=self.head, head_params=self.head_params,
                 n_events=int(cfg["env"]["SESSION_EVENTS"]),
-                operand_dtype=dtype, head_operand_dtype=head_dtype)
+                operand_dtype=dtype, head_operand_dtype=head_dtype,
+                history=self.history)
             for rpc, base, _ in self.check_log:
                 if self.index_mode:
-                    yield ref.score_index(rpc["ids"], base, rpc["amounts"],
-                                          rpc["types"], rpc["clock"])
+                    # the server scores a frame in chunks of BATCH_SIZE
+                    # rows, one after the other on one clock: an account
+                    # that repeats across chunks sees its earlier event
+                    yield reference.concat([
+                        ref.score_index(rpc["ids"][lo:lo + step],
+                                        base[lo:lo + step],
+                                        rpc["amounts"][lo:lo + step],
+                                        rpc["types"][lo:lo + step], rpc["clock"])
+                        for lo in range(0, len(rpc["ids"]), step)])
                 else:
                     yield ref.score_rows(base, rpc["amounts"], rpc["types"])
 
         wants = list(replay(stated, head_stated))
+        if self.index_mode and not control:
+            first, n_ev = wants[0]["lengths"], int(cfg["env"]["SESSION_EVENTS"])
+            log(f"windows at the first check RPC: {int((first == n_ev).sum())} "
+                f"of {len(first)} rows full, mean length {first.mean():.1f} "
+                f"of {n_ev}")
         exacts = list(replay("float32", "float32"))
         if control:
             gots = [reference.as_reply(o)
@@ -438,11 +594,15 @@ class Run:
         rows_ok = int(rows[ok].sum())
         # a shed or failed RPC counts as slower than any reply
         latency_ms = np.where(ok, (done - due) * 1000.0, np.inf)
+        chunk_rows = int(self.config["env"]["BATCH_SIZE"])
         pads: dict[int, int] = {}  # padded batch -> executions
         for size, count in zip(*np.unique(rows[ok], return_counts=True)):
-            shape = int(self.inner._pick_shape(int(size)))
-            pads[shape] = pads.get(shape, 0) + int(count)
-        chunk_rows = int(self.config["env"]["BATCH_SIZE"])
+            # a frame runs as whole chunks of BATCH_SIZE rows and its rest
+            for part, times in ((chunk_rows, int(size) // chunk_rows),
+                                (int(size) % chunk_rows, 1)):
+                if part and times:
+                    shape = int(self.inner._pick_shape(part))
+                    pads[shape] = pads.get(shape, 0) + times * int(count)
         chunks_ok = int(np.ceil(rows[ok] / chunk_rows).sum())
         counters = {k: c1[k] - c0.get(k, 0.0) for k in c1}
         counters.update({"client.rpcs_sent": float(len(rows)),

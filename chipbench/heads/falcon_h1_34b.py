@@ -351,6 +351,10 @@ def operand_dtype(rnd):
 
 
 HEAD_CANDIDATES = 16
+# The program's head rounds its operands itself (``decoder_parts.mm`` casts
+# both to the stated dtype), on the CPU as on the MXU: ``harness.judge``
+# reads a rehearsal's reference at the stated dtype too.
+CASTS_OPERANDS = True
 BLOCK_ROWS = 32  # windows a block: every shape below is one block's
 
 

@@ -160,15 +160,49 @@ def with_context(base: np.ndarray, amounts, types) -> np.ndarray:
 # -- the stateful replay ------------------------------------------------------
 
 
+def encode_events(amounts, types, gaps) -> np.ndarray:
+    """Event rows ``[n, EVENT_WIDTH]`` float32 of transactions that came
+    ``gaps`` seconds after their account's previous arrival: log1p of the
+    amount (scored as float32) and of the gap, the type's column, the
+    neutral game weight."""
+    n = len(amounts)
+    ev = np.zeros((n, EVENT_WIDTH), F32)
+    amounts32 = np.asarray(amounts).astype(F32)
+    ev[:, 0] = np.log1p(np.maximum(amounts32.astype(np.float64), 0.0))
+    ev[:, 1] = np.log1p(np.maximum(np.asarray(gaps, np.float64), 0.0))
+    types = np.asarray(types).astype(np.int64)
+    ev[np.arange(n), 2 + TX_EVENT_COL[np.clip(types, 0, 4)]] = 1.0
+    ev[:, 10] = 1.0
+    return ev
+
+
+def encode_history(history: dict) -> tuple[np.ndarray, float]:
+    """An account's preloaded history (``amounts``, ``types`` and each
+    event's arrival ``clocks``, oldest first, as
+    ``traffic.history_of`` gives it) as event rows, and its last arrival.
+    Events that share an arrival came in one chunk: each sees the gap to
+    the arrival before theirs, and those of the first arrival see none."""
+    clocks = np.asarray(history["clocks"], np.float64)
+    arrivals = np.unique(clocks)
+    before = np.concatenate([arrivals[:1], arrivals[:-1]])  # first: gap 0
+    gaps = clocks - before[np.searchsorted(arrivals, clocks)]
+    return (encode_events(history["amounts"], history["types"], gaps),
+            float(clocks[-1]) if len(clocks) else 0.0)
+
+
 class Reference:
     """Scores RPCs in the order the harness sent them, keeping each
     account's events as a plain list."""
 
     def __init__(self, params: dict, *, head, head_params: dict | None,
                  n_events: int = 16, operand_dtype: str = "bfloat16",
-                 head_operand_dtype: str | None = None):
+                 head_operand_dtype: str | None = None, history=None):
         """``head`` is a module of ``chipbench/heads/`` (or anything with
-        its ``forward``), ``head_params`` what its ``make_params`` gave."""
+        its ``forward``), ``head_params`` what its ``make_params`` gave.
+        ``history``, where the deployment preloads session events, gives
+        an account's history (``encode_history``'s argument) by its id:
+        the account starts from it the first time it is met."""
+        self.history = history
         self.params = params
         self.head = head
         self.head_params = head_params
@@ -199,15 +233,14 @@ class Reference:
         account inside a chunk do not see each other), then all the
         chunk's events are appended in row order."""
         n, n_ev = len(ids), self.n_events
-        amounts32 = np.asarray(amounts).astype(F32)
-        types = np.asarray(types).astype(np.int64)
-        ev = np.zeros((n, EVENT_WIDTH), F32)
-        ev[:, 0] = np.log1p(np.maximum(amounts32.astype(np.float64), 0.0))
-        for i, a in enumerate(ids):
-            if self.events.get(a):
-                ev[i, 1] = math.log1p(max(0.0, clock - self.last_ts[a]))
-        ev[np.arange(n), 2 + TX_EVENT_COL[np.clip(types, 0, 4)]] = 1.0
-        ev[:, 10] = 1.0
+        if self.history is not None:
+            for a in set(ids) - self.events.keys():
+                events, last = encode_history(self.history(a))
+                self.events[a] = list(events[-n_ev:])
+                self.last_ts[a] = last
+        gaps = [max(0.0, clock - self.last_ts[a]) if self.events.get(a) else 0.0
+                for a in ids]
+        ev = encode_events(amounts, types, gaps)
         win = np.zeros((n, n_ev, EVENT_WIDTH), F32)
         lengths = np.zeros((n,), np.int64)
         for i, a in enumerate(ids):
@@ -229,7 +262,13 @@ class Reference:
         score, action = combine(rule, ml2)
         return {"rule_score": rule, "ml_score": ml2, "score": score,
                 "action": action, "cold": ~warm, "fold": fold,
-                "ml_base": ml, "sprob": sprob, "warm": warm}
+                "ml_base": ml, "sprob": sprob, "warm": warm,
+                "lengths": lengths}
+
+
+def concat(parts: list[dict]) -> dict:
+    """The outputs of consecutive chunks as one RPC's."""
+    return {key: np.concatenate([p[key] for p in parts]) for key in parts[0]}
 
 
 # -- the comparison -----------------------------------------------------------

@@ -13,7 +13,11 @@ BOUNDED = [m["name"] for m in validate.load_manifest()["end_to_end"]]
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_has_its_aa_runs_all_correct(cell):
     lines = bounds.load_runs()[cell]
-    assert len(lines) >= 18
+    # 18 a cell until PR 56, whose chip budget gave its one-chip cell two
+    # sets of six and its four-chip cell, at four times a run's cost, one
+    assert len(lines) >= (bounds.SET if cell == "mesh4-index-flatout"
+                          else 2 * bounds.SET if cell == "keye-deep128-insession"
+                          else 18)
     assert all(line["correct"] and line["failed"] == 0 for line in lines)
     assert len({line["seed"] for line in lines}) == len(lines)
     run_seconds = validate.load_manifest()["run_seconds"]
@@ -28,7 +32,7 @@ def test_no_bound_is_under_what_the_aa_runs_give(metric):
     derived = bounds.derive()[metric]
     assert set(derived["cells"]) == set(CELLS)
     assert stated >= derived["bound"], derived
-    assert all(stated >= c["aa_p95"] for c in derived["cells"].values())
+    assert all(stated >= (c["aa_p95"] or 0.0) for c in derived["cells"].values())
     if metric != "setup_s":  # judged by its median alone, never by its spread
         widest = max(c["spread"] for c in derived["cells"].values())
         assert stated <= 8 * widest
@@ -64,3 +68,6 @@ def test_spread_is_the_interquartile_distance_over_the_median():
     calm = [100.0, 100.5, 101.0, 99.5, 99.0, 100.2] * 2
     assert bounds.too_tight_share(calm[:11] + [70.0], 0.05) == 0.0
     assert bounds.too_tight_share(list(np.linspace(90, 110, 12)), 0.05) == 1.0
+    # one set of six is read as it stands, without draws
+    assert bounds.too_tight_share(calm[:5] + [70.0], 0.05) == 0.0
+    assert bounds.too_tight_share(list(np.linspace(90, 110, 6)), 0.05) == 1.0

@@ -114,3 +114,120 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
     for name in os.listdir(os.path.join(ROOT, "chipbench", "layer_metrics")):
         m = validate.load_data("layer_metrics", name[:-5])
         assert READERS[m["reader"]](m, r) is None, name
+
+
+# -- PR 56: preloaded session events, and the two cells that came with them ----
+
+DEEP_CONFIG, DEEP_CELL = "risk-seqhead-keye-vl2-30b-a3b-deep128", "keye-deep128-insession"
+MESH_CONFIG, MESH_CELL = "risk-stateful-mesh4-20m", "mesh4-index-flatout"
+
+
+def test_the_deep_window_configuration_is_keyes_at_128_events_and_states_its_cut():
+    cfg = validate.load_data("configs", DEEP_CONFIG)
+    keye = validate.load_data("configs", "risk-seqhead-keye-vl2-30b-a3b")
+    source = validate.load_source(DEEP_CONFIG)
+    assert source == validate.load_source("risk-seqhead-keye-vl2-30b-a3b")
+    assert cfg["source"] == keye["source"] == source["source_url"]
+    # every key of the source stands as in keye's file: same widths, same cut
+    for key in cfg["source_keys"]:
+        assert cfg[key] == keye[key], key
+    assert cfg["head"]["reference"] == "keye_vl2"
+    assert cfg["reduced"] == [k for k in keye["reduced"]
+                              if k != "session_events_preloaded"]
+    assert all(cfg["reduced_why"][k] for k in cfg["reduced"])
+    assert cfg["session_events_preloaded"] == {"events": "1-256", "rounds": 8}
+    assert cfg["env"]["SESSION_EVENTS"] == "128" and cfg["env"]["BATCH_SIZE"] == "64"
+    assert cfg["resident_accounts"] == 655_360 == int(cfg["env"]["FEATURE_CACHE_CAPACITY"])
+    assert cfg["resident_accounts"] % cfg["fill_chunk"] == 0
+    assert cfg["limits"].keys() == keye["limits"].keys()
+    for key in ("SESSION_EVENTS", "BATCH_SIZE", "FEATURE_CACHE_CAPACITY"):
+        assert cfg["assumed"]["non_default_env"][key], key
+    assert "on_admit" in cfg["assumed"]["session_events_preloaded"]
+    spec = validate.load_cell(DEEP_CELL)
+    assert spec["traffic"]["name"] == "index-insession" and spec["cell"]["chips"] == 1
+    names = {m["name"] for m in spec["per_layer"]}
+    assert names >= {"backbone_step_ms", "backbone_step_roofline", "moe_experts_ms",
+                     "moe_experts_roofline", "head_attention_ms",
+                     "head_real_position_share", "rpc_over_50ms_share"}
+    # the step the cell runs is priced at its own window: 64 rows of 128 events
+    cost = validate.load_code("costs", "keye_backbone_step").keye_backbone_step
+    deep, flat = cost(cfg, 64, index_mode=True), cost(keye, 256, index_mode=True)
+    assert 3.9e12 < deep["flops"] < 4.0e12 and 1.9e12 < flat["flops"] < 2.0e12
+
+
+def test_the_mesh_configuration_is_cell_ones_on_four_chips_and_states_its_cut():
+    cfg = validate.load_data("configs", MESH_CONFIG)
+    one = validate.load_data("configs", "risk-stateful-5m-pattern")
+    assert cfg["chips"] == 4 and cfg["env"]["MESH_DEVICES"] == "4"
+    assert cfg["resident_accounts"] == 4 * one["resident_accounts"] == int(
+        cfg["env"]["FEATURE_CACHE_CAPACITY"])
+    assert {k: v for k, v in cfg["env"].items()
+            if k not in ("MESH_DEVICES", "FEATURE_CACHE_CAPACITY")} == {
+        k: v for k, v in one["env"].items() if k != "FEATURE_CACHE_CAPACITY"}
+    assert cfg["session_events_preloaded"] == 0 and cfg["reduced"] == one["reduced"]
+    assert cfg["limits"] == one["limits"] and cfg["guarantees"] == one["guarantees"]
+    assert "four-chip" in cfg["reduced_why"]["chips"]
+    assert cfg["assumed"]["non_default_env"]["MESH_DEVICES"]
+    spec = validate.load_cell(MESH_CELL)
+    assert spec["traffic"]["name"] == "index-flatout" and spec["cell"]["chips"] == 4
+    names = {m["name"] for m in spec["per_layer"]}
+    assert names >= {"sharded_step_ms", "sharded_step_roofline",
+                     "state_lookup_us_per_row", "cache_hit_share"}
+    assert not names & {"device_step_ms", "fused_step_roofline"}
+    manifest = validate.load_manifest()
+    four = [w["name"] for w in manifest["workloads"] if w["chips"] == 4]
+    assert four == [MESH_CELL] and len(manifest["workloads"]) // 4 >= 1
+    for m in manifest["per_layer"]:
+        if m["name"].startswith("sharded_step"):
+            assert m["workloads"] == [MESH_CELL]
+
+
+def test_a_chip_of_the_mesh_scores_the_whole_batch_and_receives_the_other_shards_rows():
+    cfg = validate.load_data("configs", MESH_CONFIG)
+    one = validate.load_data("configs", "risk-stateful-5m-pattern")
+    sharded = validate.load_code("costs", "sharded_step").sharded_step
+    fused = validate.load_code("costs", "fused_step").fused_step
+    for batch in (64, 256):
+        mine, base = sharded(cfg, batch, index_mode=True), fused(one, batch, index_mode=True)
+        assert mine["flops"] == base["flops"]
+        row = 30 * 4 + 1 + 16 * 12 * 4 + 8
+        assert mine["bytes"] == base["bytes"] + batch * row * 3 // 4
+        assert sharded(one, batch, index_mode=True) == base  # one device: nothing arrives
+        assert sharded(cfg, batch, index_mode=False) == fused(one, batch, index_mode=False)
+
+
+@pytest.mark.parametrize("config,change,needle", [
+    (DEEP_CONFIG, {"session_events_preloaded": {"events": "1-256"}}, "rounds"),
+    (DEEP_CONFIG, {"session_events_preloaded": 16}, "session_events_preloaded is 0 or"),
+    (DEEP_CONFIG, {"session_events_preloaded": {"events": "256-1", "rounds": 8}}, "low <= high"),
+    (DEEP_CONFIG, {"reduced+": "session_events_preloaded"}, "is no cut"),
+    (DEEP_CONFIG, {"session_events_preloaded": 0}, "a cut that reduced has to name"),
+    ("risk-stateful-5m-pattern",
+     {"session_events_preloaded": {"events": "1-32", "rounds": 4}}, "is no cut"),
+], ids=["no-rounds", "a-count", "backwards", "full-windows-called-a-cut",
+        "empty-windows-not-called-one", "warm-windows-still-called-a-cut"])
+def test_session_events_preloaded_is_held_to_its_shape_and_to_reduced(
+        copy, config, change, needle):
+    path = copy / "chipbench" / "configs" / f"{config}.json"
+    cfg = json.loads(path.read_text())
+    for key, value in change.items():
+        if key == "reduced+":
+            cfg["reduced"].append(value)
+            cfg["reduced_why"][value] = "windows start short"
+            _edit(copy, lambda m: next(c for c in m["configs"] if c["name"] == config)[
+                "reduced"].append(value))
+        else:
+            cfg[key] = value
+    path.write_text(json.dumps(cfg))
+    errors = validate.check_manifest(str(copy))
+    assert errors and any(needle in e for e in errors), errors
+
+
+def test_a_short_preload_may_stand_in_reduced(copy):
+    """Windows preloaded short of SESSION_EVENTS are still a cut."""
+    config = "risk-stateful-5m-pattern"
+    path = copy / "chipbench" / "configs" / f"{config}.json"
+    cfg = json.loads(path.read_text())
+    cfg["session_events_preloaded"] = {"events": "1-8", "rounds": 2}
+    path.write_text(json.dumps(cfg))
+    assert validate.check_manifest(str(copy)) == []

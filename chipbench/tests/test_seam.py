@@ -265,7 +265,7 @@ def sourced(copy):
     there, a cost, a per-operation roofline metric and a cell."""
     tmp_path, base = copy, copy / "chipbench"
     before = {p: p.read_bytes() for p in base.rglob("*") if p.is_file()}
-    (base / "sources").mkdir()
+    (base / "sources").mkdir(exist_ok=True)  # ``copy`` brings the directory
     _write(base / "sources" / f"{SOURCED}.json", SOURCE)
     (base / "heads" / "toy_backbone.py").write_text(BACKBONE_HEAD)
     (base / "costs" / "toy_attention.py").write_text(BACKBONE_COST)

@@ -81,12 +81,16 @@ def test_the_xing_configuration_is_held_to_its_source_and_states_its_cut():
     mine = [m for m in manifest["per_layer"] if m["name"] in METRICS]
     assert all(m["workloads"] == [CELL] and m["moves"] == "txns_per_s"
                for m in mine) and len(mine) == 10
-    # appended after everything the benchmark had: seven configurations,
-    # seven cells, sixty-five metrics (a later PR's entries follow these)
-    assert [c["name"] for c in manifest["configs"]].index(CONFIG) == 7
-    assert [w["name"] for w in manifest["workloads"]].index(CELL) == 7
+    # appended after everything the benchmark had then, ling's entries last:
+    # held to what they follow, not to a count (a `benchmark` PR puts
+    # entries before them: PR 56's two cells)
+    configs = [c["name"] for c in manifest["configs"]]
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert configs[configs.index(CONFIG) - 1] == "risk-seqhead-ling-3.0-flash"
+    assert cells[cells.index(CELL) - 1] == "ling-kda-insession"
     per_layer = [m["name"] for m in manifest["per_layer"]]
-    assert set(per_layer[65:75]) == METRICS
+    first = per_layer.index("ling_real_position_share") + 1
+    assert set(per_layer[first:first + 10]) == METRICS
 
 
 @pytest.mark.parametrize("key,value,needle", [

@@ -49,6 +49,7 @@ class Population:
         self.n = int(resident)
         self.ids = account_ids(self.n, seed)
         self.perm = rng_for(seed, "perm").permutation(self.n)
+        self._rank = None
         weights = np.arange(1, self.n + 1, dtype=np.float64) ** -float(
             spec["exponent"])
         self._cdf = np.cumsum(weights)
@@ -61,20 +62,129 @@ class Population:
     def id_of_rank(self, rank: int) -> str:
         return self.ids[int(self.perm[int(rank)])]
 
+    def rank_of_id(self, account_id: str) -> int:
+        """The popularity rank of one of ``ids`` (the inverse of
+        ``id_of_rank``; the permutation's inverse is made when first asked
+        for)."""
+        if self._rank is None:
+            self._rank = np.empty(self.n, np.int64)
+            self._rank[self.perm] = np.arange(self.n)
+        return int(self._rank[int(account_id.rsplit("-", 1)[1])])
+
 
 def draw_context(mix: dict, rng: np.random.Generator, k: int):
     """(amounts int64 cents, tx-type codes uint8) for ``k`` rows."""
     a = mix["amounts"]
     if a["distribution"] != "lognormal":
         raise ValueError(f"unknown amount distribution {a}")
-    amounts = np.exp(rng.normal(np.log(a["median_cents"]), a["sigma"], k))
-    amounts = np.clip(np.rint(amounts), a["min_cents"], a["max_cents"]).astype(
+    amounts = _clip_amounts(
+        a, np.exp(rng.normal(np.log(a["median_cents"]), a["sigma"], k)))
+    codes, p = _type_codes(mix)
+    types = codes[rng.choice(len(codes), size=k, p=p)]
+    return amounts, types
+
+
+def _clip_amounts(a: dict, amounts: np.ndarray) -> np.ndarray:
+    return np.clip(np.rint(amounts), a["min_cents"], a["max_cents"]).astype(
         np.int64)
+
+
+def _type_codes(mix: dict) -> tuple[np.ndarray, np.ndarray]:
+    """The mix's tx types as wire codes, and the share of each."""
     names = list(mix["tx_types"])
     p = np.array([mix["tx_types"][t] for t in names], np.float64)
-    codes = np.array([TX_TYPES.index(t) for t in names], np.uint8)
-    types = codes[rng.choice(len(names), size=k, p=p / p.sum())]
-    return amounts, types
+    return np.array([TX_TYPES.index(t) for t in names], np.uint8), p / p.sum()
+
+
+# -- histories: what a resident account had sent before the run ---------------
+
+# The last round of every history arrives at this instant, five minutes
+# before the earliest clock the output check puts on an RPC
+# (``check_sequence``: 1,800,000,000 s and up to a day more): the first
+# checked event of an account then has a gap of minutes to a day behind
+# it, one a player sends and the head has not saturated on (a gap of years
+# pins its probability: PERF.md, PR 56). The timed window runs on the wall
+# clock, which is earlier until January 2027: there an account's first
+# event reads no gap, as a never-seen account's does in every cell.
+HISTORY_END = 1_799_999_700.0
+
+
+def history_spec(value) -> dict | None:
+    """A configuration's ``session_events_preloaded``: 0 (every window
+    starts empty) gives ``None``; ``{"events": "<low>-<high>", "rounds":
+    R}`` gives ``{"low", "high", "rounds"}``. ``ValueError`` for any other
+    shape."""
+    if isinstance(value, int) and not isinstance(value, bool) and value == 0:
+        return None
+    if not (isinstance(value, dict) and set(value) == {"events", "rounds"}):
+        raise ValueError("session_events_preloaded is 0 or "
+                         '{"events": "<low>-<high>", "rounds": R}')
+    parts = str(value["events"]).split("-")
+    rounds = value["rounds"]
+    if (len(parts) != 2 or not all(p.isdigit() for p in parts)
+            or isinstance(rounds, bool) or not isinstance(rounds, int)):
+        raise ValueError('session_events_preloaded: events is "<low>-<high>" '
+                         "and rounds a whole number")
+    low, high = int(parts[0]), int(parts[1])
+    if not (0 <= low <= high and 1 <= high <= 65536 and 1 <= rounds <= 64):
+        raise ValueError("session_events_preloaded: 0 <= low <= high, "
+                         "1 <= high <= 65536 and 1 <= rounds <= 64")
+    return {"low": low, "high": high, "rounds": rounds}
+
+
+def history_clocks(seed: int, rounds: int) -> np.ndarray:
+    """The arrival time of each of the ``rounds`` rounds, oldest first: a
+    seeded gap of 20 s to 15 min apart, the last at ``HISTORY_END``."""
+    gaps = rng_for(seed, "histclk").uniform(20.0, 900.0, max(rounds - 1, 0))
+    return HISTORY_END - np.concatenate([np.cumsum(gaps[::-1])[::-1], [0.0]])
+
+
+def histories(mix: dict, seed: int, first_rank: int, n: int, spec: dict) -> dict:
+    """The histories of the accounts of rank ``first_rank .. first_rank +
+    n - 1``, oldest event first, account after account: ``counts`` [n]
+    (``k_r``, uniform on ``[low, high]``), and per event ``account`` (0 ..
+    n - 1), ``amounts`` (int64 cents) and ``types`` (wire codes) from the
+    mix's own distributions, and ``round``, the round it arrives in: an
+    account's events fall into ``rounds`` equal shares, the newest share
+    in the last round.
+
+    Deterministic in ``(mix, seed, rank)`` and computable for one account
+    without the others: rank ``r`` owns draws ``r * (1 + 3 * high)``
+    onwards of the one stream ``rng_for(seed, "history")`` (one for
+    ``k_r``, three an event), which the generator reaches by ``advance``.
+    So the fill asks for a chunk of accounts and the reference for one,
+    and both read the same events."""
+    low, high, rounds = spec["low"], spec["high"], spec["rounds"]
+    per = 1 + 3 * high
+    rng = rng_for(seed, "history")
+    rng.bit_generator.advance(int(first_rank) * per)
+    u = rng.random((int(n), per))
+    counts = np.minimum(low + (u[:, 0] * (high - low + 1)).astype(np.int64), high)
+    event = np.arange(high)[None, :]
+    keep = event < counts[:, None]
+    account, index = np.nonzero(keep)
+    u1, u2, u3 = (u[:, 1 + j::3][keep] for j in range(3))
+    # Box-Muller: one standard normal from two of the event's draws
+    z = np.sqrt(-2.0 * np.log1p(-u1)) * np.cos(2.0 * np.pi * u2)
+    a = mix["amounts"]
+    if a["distribution"] != "lognormal":
+        raise ValueError(f"unknown amount distribution {a}")
+    amounts = _clip_amounts(a, np.exp(np.log(a["median_cents"]) + a["sigma"] * z))
+    codes, p = _type_codes(mix)
+    types = codes[np.searchsorted(np.cumsum(p), u3, side="right").clip(
+        0, len(codes) - 1)]
+    k = counts[account]
+    return {"counts": counts, "account": account, "amounts": amounts,
+            "types": types,
+            "round": rounds - 1 - ((k - 1 - index) * rounds) // k}
+
+
+def history_of(mix: dict, seed: int, rank: int, spec: dict) -> dict:
+    """One account's history: ``amounts``, ``types`` and each event's
+    arrival ``clocks``, oldest first."""
+    h = histories(mix, seed, rank, 1, spec)
+    return {"amounts": h["amounts"], "types": h["types"],
+            "clocks": history_clocks(seed, spec["rounds"])[h["round"]]}
 
 
 # -- wire forms --------------------------------------------------------------

@@ -355,6 +355,34 @@ def _check_config_source(errors: list, path: str, name: str, data: dict,
     return set(listed)
 
 
+def _check_preload(errors: list, path: str, data: dict, reduced) -> None:
+    """``session_events_preloaded`` is 0 (every window starts empty) or
+    ``{"events": "<low>-<high>", "rounds": R}`` (``traffic.history_spec``).
+    It is a cut, and stands in ``reduced``, only where it leaves windows
+    short of what the deployment would hold: at 0, or with ``high`` under
+    ``SESSION_EVENTS``."""
+    from chipbench import traffic
+
+    try:
+        spec = traffic.history_spec(data.get("session_events_preloaded"))
+    except ValueError as exc:
+        errors.append(f"{path}: {exc}")
+        return
+    try:
+        n_events = int(data.get("env", {}).get("SESSION_EVENTS", 16))
+    except (TypeError, ValueError):
+        errors.append(f"{path}: env.SESSION_EVENTS is a whole number")
+        return
+    listed = isinstance(reduced, list) and "session_events_preloaded" in reduced
+    if listed and spec is not None and spec["high"] >= n_events:
+        errors.append(f"{path}: session_events_preloaded fills windows to "
+                      f"SESSION_EVENTS {n_events} and is no cut: reduced "
+                      "names it only at 0 or with its high under that")
+    if spec is None and not listed:
+        errors.append(f"{path}: session_events_preloaded 0 starts every "
+                      "window empty, a cut that reduced has to name")
+
+
 def check_manifest(root: str = ROOT) -> list[str]:
     """Every breach of the rules in ``BENCHMARK.json`` and the data files
     it names, one line each; empty when all hold."""
@@ -449,6 +477,7 @@ def check_manifest(root: str = ROOT) -> list[str]:
             if key not in data or key not in data.get("reduced_why", {}):
                 errors.append(f"{path}: reduced key {key!r} needs its value "
                               "and a line in reduced_why")
+        _check_preload(errors, path, data, reduced)
         if not data.get("guarantees"):
             errors.append(f"{path}: states no guarantees")
         if not any(w.get("config") == name for w in m["workloads"]):
