@@ -707,7 +707,8 @@ def phase_kernels(interpret: bool = False, *,
                   second_shape: tuple = (16384, 2048, 1536, 64, 4),
                   share_shape: tuple = (2048, 7680, 4096, 1000),
                   grouped_windows: int = 32, delta_windows: int = 32,
-                  ssd_windows: int = 32, stream_tiles: int = 32) -> dict:
+                  ssd_windows: int = 32, stream_tiles: int = 32,
+                  block_window: int = 4096) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -1032,6 +1033,37 @@ def phase_kernels(interpret: bool = False, *,
     report[f"hyper_streams_T{stream_tiles}"] = {"max_err": err, "path": picked[0]}
     check(err <= STREAMS_TOL,
           f"stream kernels on {stream_tiles} tiles: max err {err} > {STREAMS_TOL}")
+
+    from igaming_platform_tpu.models import mellum_backbone
+    from igaming_platform_tpu.ops.pallas import block_attention as ba
+
+    # the blocked attention core at the ``mellum`` head's attention (32 / 4
+    # heads of 128) on one window of ``block_window`` positions, a sliding
+    # layer and a full one, against the einsum form in query blocks
+    cfg, t = mellum_backbone.MellumConfig(), block_window
+    ks = jax.random.split(jax.random.key(t + 5), 4)
+    q = jax.random.normal(ks[0], (t, cfg.heads * cfg.head_dim), jnp.float32) * 3
+    k, v = (jax.random.normal(key, (t, cfg.kv_heads * cfg.head_dim),
+                              jnp.float32).astype(jnp.bfloat16) for key in ks[1:3])
+    gain = 1 + 0.1 * jax.random.normal(ks[3], (cfg.head_dim,), jnp.float32)
+    tables = mellum_backbone.angle_tables(cfg, t)
+    cores = {}
+    for kind in dict.fromkeys(cfg.layer_types):
+        widths = dict(heads=cfg.heads, kv_heads=cfg.kv_heads, window=t,
+                      band=mellum_backbone.band_of(kind, cfg), eps=cfg.eps)
+        picked = _said_by_the_expert_layer(
+            lambda kind=kind: mellum_backbone._attention_core(t, kind, cfg, t))
+        got = ba.block_attention(q, k, v, *tables[kind], gain, **widths,
+                                 interpret=interpret)
+        want = jax.jit(functools.partial(mellum_backbone.core_by_einsums, **widths))(
+            q, k, v, *tables[kind], gain)
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                    / jnp.max(jnp.abs(want)))
+        cores[kind] = {"max_err": err, "core": picked[0]}
+        check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
+              f"blocked attention ({kind}) on a window of {t}: max err {err} "
+              f"> {BACKBONE_TOL}")
+    report[f"block_attention_T{t}"] = cores
     return report
 
 
@@ -1081,16 +1113,29 @@ BACKBONES = {
               "expert_core": "pallas-grouped (tm=256, ts=64, slots=3/4, "
                              "rows=gathered)", "way_back": "pallas-rows",
               "attention_core": "pallas-windows"}),
+    # windows of 4,096 events, four times the band: the blocked core takes
+    # both kinds of layer; hidden 2304 is 18 lane tiles, which ``combine``
+    # does not take (not whole 8-row tiles of them): XLA's gather and sum
+    "mellum": ("risk-seqhead-mellum2-12b-a2.5b", "mellum2_12b_a2_5b",
+               "mellum_backbone",
+               {"window_core": "pallas-blocks (grouped 32/4 of 128, window 4096 "
+                               "in blocks of 512, band=1024: 21 of 64 key blocks)",
+                "full_core": "pallas-blocks (grouped 32/4 of 128, window 4096 in "
+                             "blocks of 512, band=None: 36 of 64 key blocks)",
+                "expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
+                               "rows=gathered)", "way_back": "xla-gather"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
               "attention_core": "attention core", "ssm_core": "state-space core",
               "linear_core": "linear-attention core",
-              "residual_path": "residual path"}
+              "residual_path": "residual path",
+              "window_core": "attention core (window)",
+              "full_core": "attention core (full)"}
 
 
 def phase_backbone(*, head_name: str = "pangu", cfg=None,
                    config: dict | None = None, rows: int = 32,
-                   seed: int = 36) -> dict:
+                   seed: int = 36, events: int = 16) -> dict:
     """A backbone session head at its published widths against its plain
     reference (float32 at ``highest`` over bfloat16-rounded operands) on
     one block of ``rows`` windows, and which cores ran its expert layer's
@@ -1116,7 +1161,11 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     YaRN under hyper-connections of four residual streams, a shared expert
     beside 64 bias-chosen experts, every one held, 6.22 GB
     (chipbench/heads/xing4_29b_a4b.py), whose maps the program computes
-    positions along the lanes against the reference's stream by stream."""
+    positions along the lanes against the reference's stream by stream;
+    ``mellum``: three sliding-window layers of 1,024 keys and one full one
+    with a rotary table a kind, 64 experts, every one held, 3.34 GB
+    (chipbench/heads/mellum2_12b_a2_5b.py), run on ``events`` = 4,096
+    positions a window so that the band clips (the others' windows are 16)."""
     import gc
 
     import jax
@@ -1135,9 +1184,9 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     head = validate.load_code("heads", reference_name)
     params = head.make_params(seed, config)
     rng = np.random.default_rng(seed)
-    lengths = rng.integers(1, 17, rows).astype(np.int32)
-    win = rng.normal(0.0, 1.0, (rows, 16, cfg.in_dim)).astype(np.float32)
-    win *= (np.arange(16)[None, :] < lengths[:, None])[..., None]
+    lengths = rng.integers(1, events + 1, rows).astype(np.int32)
+    win = rng.normal(0.0, 1.0, (rows, events, cfg.in_dim)).astype(np.float32)
+    win *= (np.arange(events)[None, :] < lengths[:, None])[..., None]
 
     got = []
     said = _said_by_the_expert_layer(lambda: got.append(np.asarray(jax.jit(
@@ -1255,6 +1304,8 @@ def main() -> int:
     run("backbone_falconh1", phase_backbone, head_name="falconh1")
     run("backbone_ling", phase_backbone, head_name="ling")
     run("backbone_xing", phase_backbone, head_name="xing")
+    run("backbone_mellum", phase_backbone, head_name="mellum", rows=2,
+        events=4096)
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

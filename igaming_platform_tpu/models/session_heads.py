@@ -24,6 +24,7 @@ from igaming_platform_tpu.models import (
     keye_backbone,
     lfm2_backbone,
     ling_backbone,
+    mellum_backbone,
     pangu_backbone,
     xing_backbone,
 )
@@ -110,11 +111,12 @@ def transformer_scores(sparams, window, lengths):
     return sequence_forward(sparams, window, SESSION_SEQ_CONFIG)["abuse"]
 
 
-# What a layer's operators (``conv``, ``attention``, ``ssm``, ``linear``:
-# linear attention) and its feed-forward (``dense``, ``moe``) may be: the
-# kinds a row's ``layers`` counts. A layer that runs two operators
-# (``falconh1``: ``ssm`` beside ``attention``) counts under both.
-LAYER_KINDS = ("conv", "attention", "ssm", "linear", "dense", "moe")
+# What a layer's operators (``conv``, ``attention``, ``window``: attention
+# inside a band of keys, ``ssm``, ``linear``: linear attention) and its
+# feed-forward (``dense``, ``moe``) may be: the kinds a row's ``layers``
+# counts. A layer that runs two operators (``falconh1``: ``ssm`` beside
+# ``attention``) counts under both.
+LAYER_KINDS = ("conv", "attention", "window", "ssm", "linear", "dense", "moe")
 _NO_LAYERS = dict.fromkeys(LAYER_KINDS, 0)
 
 
@@ -132,6 +134,10 @@ class Head:
     # layers of each kind in its stack, over exactly LAYER_KINDS (the
     # ``pattern`` head has no layer at all)
     layers: Mapping[str, int] = field(default_factory=_NO_LAYERS.copy)
+    # window length -> (key blocks its attention cores visit a scored row,
+    # key blocks of their squares); none for a head whose attention sweeps
+    # no blocks
+    key_blocks: Callable[[int], tuple[int, int]] | None = None
 
 
 def _backbone(module, cfg) -> Head:
@@ -147,7 +153,9 @@ def _backbone(module, cfg) -> Head:
             jax.random.key(_SESSION_HEAD_SEED), cfg),
         config=cfg,
         experts=(getattr(cfg, "held_experts", routed), routed),
-        layers=_NO_LAYERS | module.layer_kinds(cfg))
+        layers=_NO_LAYERS | module.layer_kinds(cfg),
+        key_blocks=(lambda window: module.key_blocks(cfg, window))
+        if hasattr(module, "key_blocks") else None)
 
 
 HEADS = {
@@ -193,6 +201,12 @@ HEADS = {
     # bias-chosen experts of width 1,024, every one held: 3.11 G parameters,
     # 6.22 GB
     "xing": _backbone(xing_backbone, xing_backbone.XingConfig()),
+    # one whole period of a stack that mixes windowed and full attention, at
+    # its published widths: three layers that read a band of 1,024 keys and
+    # one that reads every causal key by ``layer_types``, a rotary table a
+    # kind (YaRN on the full one); 64 softmax-routed experts of width 896,
+    # every one held, no shared expert: 1.67 G parameters, 3.34 GB
+    "mellum": _backbone(mellum_backbone, mellum_backbone.MellumConfig()),
 }
 
 

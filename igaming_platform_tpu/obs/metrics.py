@@ -854,6 +854,22 @@ class ServiceMetrics:
             "session_head_positions_total it is what the padding of short "
             "windows costs the head",
         )
+        self.session_head_key_blocks_visited_total = self.registry.counter(
+            f"{service}_session_head_key_blocks_visited_total",
+            "Key blocks the attention cores of a head that sweeps its keys "
+            "in blocks visited for session-scored rows, a query head: rows "
+            "x the blocks one window's layers visit at the traced window "
+            "and block (a banded layer skips what lies before its band, "
+            "every layer what lies past the diagonal); 0 for every other "
+            "head",
+        )
+        self.session_head_key_blocks_square_total = self.registry.counter(
+            f"{service}_session_head_key_blocks_square_total",
+            "Key blocks of the same layers' whole squares (rows x layers x "
+            "(window / block)^2): over it "
+            "session_head_key_blocks_visited_total is the share of the "
+            "square the cores sweep",
+        )
         self.session_head_resident_bytes = self.registry.gauge(
             f"{service}_session_head_resident_bytes",
             "Device bytes of the session head's parameter tree (0 for a "
@@ -875,10 +891,11 @@ class ServiceMetrics:
         self.session_head_layers = self.registry.gauge(
             f"{service}_session_head_layers",
             "Layers of the session head's stack by kind, set once at boot: "
-            "kind=conv|attention|ssm|linear is a layer's operator (linear: "
-            "linear attention; a layer that runs two, a state-space mixer "
-            "beside attention, counts under both), kind=dense|moe its "
-            "feed-forward (a head without layers reads 0 for all six)",
+            "kind=conv|attention|window|ssm|linear is a layer's operator "
+            "(window: attention inside a band of keys; linear: linear "
+            "attention; a layer that runs two, a state-space mixer beside "
+            "attention, counts under both), kind=dense|moe its feed-forward "
+            "(a head without layers reads 0 for all seven)",
         )
         self.session_head_residual_streams = self.registry.gauge(
             f"{service}_session_head_residual_streams",

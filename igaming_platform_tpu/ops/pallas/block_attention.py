@@ -1,0 +1,300 @@
+"""Pallas TPU kernel: causal grouped-query attention over deep windows in
+blocks of keys, with a band; only the key blocks the mask keeps are visited.
+
+``models/mellum_backbone.attention`` attends inside windows of ``T``
+positions (4,096 in its cell) in two kinds of layer: a *full* one, where
+query ``i`` reads every key ``j <= i``, and a *sliding* one, where it reads
+``j <= i`` with ``i - j < band`` (1,024). The short-window kernels beside
+this one (ops/pallas/window_attention.py) hold a whole window's scores at
+once and ask that the window divide a 128-position tile; at 4,096 positions
+a layer's ``[b, heads, t, s]`` scores are 4.3 GB. Here nothing of ``[t, s]``
+reaches HBM: a program holds one block of queries and walks the key blocks
+with an online softmax. One kernel serves both kinds of layer: ``band`` is
+``None`` in a full one.
+
+**A program** is one window, one key-value head and one block of ``block``
+query positions, for the ``rep = heads / kv_heads`` query heads that share
+the key-value head. ``q`` comes as ``Wq``'s product left it, position-major
+float32 ``[P, heads x hd]``: each head's slice is normed (its RMS norm over
+the head's ``hd`` lanes) and turned (rotate-half over the whole head: the
+other half comes by a lane roll, ``sin`` carries the pair's sign) in
+float32, rounded once, and the ``rep`` heads' rows are stacked into one
+``[rep x block, hd]`` operand in VMEM, so that one product against a key
+block serves all of them. The window's keys and values of that key-value
+head lie whole in VMEM (``[T, hd]`` each, 1 MB at 4,096 x 128 bfloat16):
+their block index changes only with the window and the key-value head, so
+they are read from HBM once a (window, key-value head) and the query blocks
+of it sweep them where they lie.
+
+**The sweep.** Query block ``i`` covers positions ``i x block ..``; the
+diagonal is key block ``i``. In a full layer it visits key blocks ``0 .. i``;
+in a sliding one ``lo .. i`` with ``lo = max(0, i x block - band + 1) //
+block``: 9 of 32 blocks at ``block`` 128 and a band of 1,024, 5 of 16 at
+256, 3 of 8 at 512. Three loops: the blocks the band's edge crosses (the mask applied),
+the blocks wholly inside (no mask: every pair is kept), and the diagonal
+(the mask applied). ``visited_blocks`` counts them; nothing else is read or
+multiplied. The softmax is online, in float32: a running maximum ``m`` and
+sum ``l`` a row, the accumulator ``acc`` ``[rep x block, hd]`` float32;
+a block's ``exp(s - m)`` is rounded once to the operands' dtype before its
+product with ``v``, and the division by ``l`` comes once, at the end. A
+row of an edge block may have every key masked: its maximum stays ``-inf``
+and is read as 0 for the subtraction, so the row adds exact zeros.
+
+Same arithmetic as the einsum form in query blocks
+(``models/mellum_backbone.core_by_einsums``), which stays its reference
+(tests/test_block_attention.py) and what runs off the TPU: operands in the
+dtype ``k`` comes in, products accumulated in float32, scale and mask in
+float32. What differs is where the probabilities are rounded (here before
+the division by the row's sum, there after it) and the order of float32
+sums.
+
+A window that is not whole blocks is padded here (zeros: keys past a real
+query, which causality masks) and cut from the result.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
+
+# Query positions a program takes, and keys a step of its sweep. Measured on
+# a v5e at the cell's shape, 2 windows of 4,096 (PERF.md, section 6, PR 57):
+# a sliding layer 4.62 / 3.48 / 2.16 ms and a full one 9.25 / 6.18 / 3.49 ms
+# at 128 / 256 / 512. A wider key block spreads a visit's pass over the
+# accumulator and the running maximum and sum over more keys; it also sweeps
+# more of the square (33% of it in a sliding layer at 512, 25% at 128).
+_BLOCK = 512
+
+# What the kernel may ask of the v5e's 128 MiB of VMEM.
+_VMEM_CAP = 96 * 2**20
+
+
+def block_for(window: int) -> int:
+    """Positions a block holds at windows of ``window``: ``_BLOCK``, or the
+    whole window in whole 16-row tiles where that is less."""
+    return min(_BLOCK, 16 * -(-window // 16))
+
+
+def swept_blocks(i: int, block: int, band: int | None) -> tuple[int, int]:
+    """``(lo, edge_end)`` for query block ``i``: it visits key blocks ``lo
+    .. i``; those before ``edge_end`` are crossed by the band's edge. The
+    kernel computes the same two numbers from its program id."""
+    if band is None:
+        return 0, 0
+    lo = max(i * block - band + 1, 0) // block
+    edge_end = (max((i + 1) * block - band, 0) + block - 1) // block
+    return lo, min(max(edge_end, lo), i)
+
+
+def visited_blocks(window: int, band: int | None,
+                   block: int | None = None) -> tuple[int, int]:
+    """``(key blocks one head's sweep of one window visits, key blocks of
+    the square)`` at ``block`` positions a block (``block_for(window)``
+    without one): what ``risk_session_head_key_blocks_*_total`` count a
+    layer."""
+    block = block or block_for(window)
+    n = -(-window // block)
+    return sum(i + 1 - swept_blocks(i, block, band)[0] for i in range(n)), n * n
+
+
+def _vmem(block: int, rep: int, hd: int, padded: int, q_size: int,
+          size: int) -> int:
+    """Both buffers of a program's blocks (queries, the window's keys and
+    values, the result, the angles), the stacked queries and the
+    accumulator, a block's float32 scores several times over, and room to
+    spare."""
+    rows = rep * block
+    blocks = (block * rep * hd * (q_size + size) + 2 * padded * hd * size
+              + 2 * block * hd * 4)
+    held = rows * hd * (size + 4) + 2 * rows * _LANES * 4
+    return 2 * blocks + held + 4 * rows * block * 4 + 4 * 2**20
+
+
+def declines(q, k, v, *, heads: int, kv_heads: int, window: int) -> str:
+    """Why ``block_attention`` does not take these operands, "" where it
+    does. ``q`` [P, heads x hd], ``k`` and ``v`` [P, kv_heads x hd] (arrays
+    or their shapes-and-dtypes). It takes heads of whole 128-lane vregs,
+    every key head shared by as many query heads, bfloat16 or float32 keys
+    and values of one dtype, whole windows, and a window's keys and values
+    of one head beside a program's blocks inside VMEM; anything else takes
+    the caller's einsums."""
+    if kv_heads <= 0 or heads <= 0 or heads % kv_heads:
+        return f"{heads} heads over {kv_heads} key heads"
+    if q.ndim != 2 or q.shape[1] % heads or (q.shape[1] // heads) % _LANES:
+        return f"head width {q.shape[1] / heads:g} is not whole {_LANES}-lane vregs"
+    hd, p = q.shape[1] // heads, q.shape[0]
+    if window <= 0 or p == 0 or p % window:
+        return f"{p} positions are not whole windows of {window}"
+    if k.shape != (p, kv_heads * hd) or v.shape != k.shape:
+        return f"k {k.shape}, v {v.shape} against q {q.shape}"
+    if k.dtype not in (jnp.bfloat16, jnp.float32) or v.dtype != k.dtype:
+        return f"operands {k.dtype} / {v.dtype}"
+    if not jnp.issubdtype(q.dtype, jnp.floating):
+        return f"q {q.dtype}"
+    block = block_for(window)
+    need = _vmem(block, heads // kv_heads, hd, block * -(-window // block),
+                 q.dtype.itemsize, k.dtype.itemsize)
+    if need > _VMEM_CAP:
+        return f"a program's blocks take {need} of {_VMEM_CAP} bytes of VMEM"
+    return ""
+
+
+def _kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, o_ref,
+            qs_ref, m_ref, l_ref, acc_ref, *, block: int, rep: int, hd: int,
+            band: int | None, eps: float, scale: float):
+    f32 = jnp.float32
+    dt = k_ref.dtype
+    i = pl.program_id(2)
+    cos, sin, gain = cos_ref[...], sin_ref[...], gain_ref[...]
+    for j in range(rep):
+        # a head's RMS norm, then rotate-half over the whole head: the
+        # pair's other half comes by a roll of half the lanes
+        x = q_ref[:, j * hd:(j + 1) * hd].astype(f32)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+        x = x * cos + pltpu.roll(x, hd // 2, 1) * sin
+        qs_ref[j * block:(j + 1) * block, :] = x.astype(dt)
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, f32)
+    l_ref[...] = jnp.zeros(l_ref.shape, f32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+    # a stacked row's query and a column's key, each inside its block
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, (rep * block, block), 0) % block
+             - jax.lax.broadcasted_iota(jnp.int32, (rep * block, block), 1))
+
+    def visit(kb, masked: bool):
+        at = pl.ds(pl.multiple_of(kb * block, block), block)
+        s = jax.lax.dot_general(qs_ref[...], k_ref[at, :],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32) * scale
+        m_prev = m_ref[...]
+        if masked:
+            d = ahead + (i - kb) * block  # the query's position less the key's
+            keep = d >= 0 if band is None else jnp.logical_and(d >= 0, d < band)
+            s = jnp.where(keep, s, -jnp.inf)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # a row with no key kept yet: subtract 0, every term is exp(-inf)
+            m_sub = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        else:
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_sub = m_new
+        alpha = jnp.exp(m_prev - m_sub)
+        e = jnp.exp(s - m_sub)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(e, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            e.astype(dt), v_ref[at, :], preferred_element_type=f32)
+        m_ref[...] = m_new
+
+    def loop(lo, hi, masked: bool):
+        def body(kb, carry):
+            visit(kb, masked)
+            return carry
+        jax.lax.fori_loop(lo, hi, body, 0)
+
+    if band is None:
+        edge_end = 0
+    else:
+        lo = jnp.maximum(i * block - (band - 1), 0) // block
+        edge_end = (jnp.maximum((i + 1) * block - band, 0) + block - 1) // block
+        edge_end = jnp.minimum(jnp.maximum(edge_end, lo), i)
+        loop(lo, edge_end, True)      # the band's edge crosses these
+    loop(edge_end, i, False)          # wholly inside: every pair is kept
+    visit(i, True)                    # the diagonal
+    out = acc_ref[...] / l_ref[...]
+    for j in range(rep):
+        o_ref[:, j * hd:(j + 1) * hd] = (
+            out[j * block:(j + 1) * block, :].astype(o_ref.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "kv_heads", "window", "band", "eps", "block", "interpret"))
+def _block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
+                     window: int, band: int | None, eps: float, block: int,
+                     interpret: bool):
+    """``window`` is whole blocks here (``block_attention`` pads)."""
+    p = q.shape[0]
+    hd, rep = q.shape[1] // heads, heads // kv_heads
+    n = window // block
+    size = k.dtype.itemsize
+    visited, _ = visited_blocks(window, band, block)
+    pairs = (p // window) * heads * visited * block * block
+    # the angles over the whole head's lanes, ``sin`` signed as rotate-half
+    # signs it; the gain a row
+    cos = jnp.concatenate([cos, cos], axis=1)
+    sin = jnp.concatenate([-sin, sin], axis=1)
+    rows = rep * block
+    return pl.pallas_call(
+        functools.partial(_kernel, block=block, rep=rep, hd=hd, band=band,
+                          eps=eps, scale=hd ** -0.5),
+        out_shape=jax.ShapeDtypeStruct((p, heads * hd), k.dtype),
+        grid=(p // window, kv_heads, n),
+        in_specs=[pl.BlockSpec((block, rep * hd), lambda b, g, i: (b * n + i, g)),
+                  pl.BlockSpec((window, hd), lambda b, g, i: (b, g)),
+                  pl.BlockSpec((window, hd), lambda b, g, i: (b, g)),
+                  pl.BlockSpec((block, hd), lambda b, g, i: (i, 0)),
+                  pl.BlockSpec((block, hd), lambda b, g, i: (i, 0)),
+                  pl.BlockSpec((1, hd), lambda b, g, i: (0, 0))],
+        out_specs=pl.BlockSpec((block, rep * hd), lambda b, g, i: (b * n + i, g)),
+        scratch_shapes=[pltpu.VMEM((rows, hd), k.dtype),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, hd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=min(_VMEM_CAP, _vmem(
+                block, rep, hd, window, q.dtype.itemsize, size))),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * pairs * hd,
+            transcendentals=pairs,
+            bytes_accessed=(q.size * q.dtype.itemsize
+                            + (k.size + v.size + p * heads * hd) * size)),
+        interpret=interpret,
+    )(q, k, v, cos, sin, gain.astype(jnp.float32).reshape(1, hd))
+
+
+def block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
+                    window: int, band: int | None, eps: float,
+                    block: int | None = None, interpret: bool = False):
+    """Causal grouped-query attention inside windows of ``window``
+    consecutive positions, with a band: query head ``j`` against key head
+    ``j // (heads // kv_heads)``, the query's head norm and rotary applied
+    here.
+
+    ``q`` [P, heads x hd], position-major as its projection accumulated it
+    (any float dtype; float32), NOT yet normed or turned; ``k`` and ``v``
+    [P, kv_heads x hd], ``k`` normed, turned and rounded, both in the
+    operands' dtype. ``cos``, ``sin`` [window, hd / 2] float32, the rotary
+    angles of a window's positions over the whole head (rotate-half: pair
+    ``i`` is channels ``i`` and ``i + hd / 2``), the same in every window;
+    ``gain`` [hd] the head norm's. ``band`` is the sliding window's width
+    (query ``i`` reads key ``j`` where ``0 <= i - j < band``) or ``None`` (a
+    full layer: every ``j <= i``) -> [P, heads x hd] in the operands' dtype,
+    position-major as ``wo``'s product reads it: per head
+    ``softmax(rot(norm(q)) k^T / sqrt(hd)) v`` over the kept keys of the
+    query's window. ``block`` is ``block_for(window)`` unless a test says
+    otherwise; a window that is not whole blocks is padded here and cut
+    from the result. ``interpret=True`` runs the Pallas interpreter, always
+    the caller's explicit choice."""
+    p = q.shape[0]
+    block = block or block_for(window)
+    pad = -window % block
+    if pad:
+        b = p // window
+
+        def padded(x):
+            x = jnp.pad(x.reshape(b, window, -1), ((0, 0), (0, pad), (0, 0)))
+            return x.reshape(b * (window + pad), -1)
+
+        q, k, v = padded(q), padded(k), padded(v)
+        cos, sin = (jnp.pad(x, ((0, pad), (0, 0))) for x in (cos, sin))
+    out = _block_attention(q, k, v, cos, sin, gain, heads=heads,
+                           kv_heads=kv_heads, window=window + pad, band=band,
+                           eps=eps, block=block, interpret=interpret)
+    if pad:
+        out = out.reshape(p // window, window + pad, -1)[:, :window]
+        out = out.reshape(p, -1)
+    return out

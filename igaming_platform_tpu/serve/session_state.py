@@ -486,6 +486,10 @@ class SessionStateManager:
         self.head_layers = dict(row.layers)
         # residual streams a layer carries: one but for a hyper-connected head
         self.head_residual_streams = getattr(row.config, "streams", 1)
+        # key blocks its attention cores (visit, would visit as whole
+        # squares) a scored row, where the head sweeps its keys in blocks
+        self.head_key_blocks = (row.key_blocks(self.n_events)
+                                if row.key_blocks else (0, 0))
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}
@@ -583,6 +587,10 @@ class SessionStateManager:
             # through the head; real_positions of them hold an event
             m.session_head_positions_total.inc(appends * self.n_events)
             m.session_head_real_positions_total.inc(real_positions)
+            visited, square = self.head_key_blocks
+            if square:
+                m.session_head_key_blocks_visited_total.inc(appends * visited)
+                m.session_head_key_blocks_square_total.inc(appends * square)
         if rehydrations:
             m.session_rehydrations_total.inc(rehydrations)
         if regrows:

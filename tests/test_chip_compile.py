@@ -526,7 +526,11 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
                   "head/moe/route", "head/moe/shared", "head/moe/experts",
                   "head/score"):
         assert scope in text, scope
-    assert "_window_attention" not in text
+    # as an instruction, not as a substring: the module's table of source
+    # files names ``tests/test_window_attention.py`` where this worker traced
+    # a shared jitted function from that file first
+    assert not [line for line in text.splitlines()
+                if re.match(r"\s*%_window_attention(\.\d+)? = ", line)]
     assert not [line for line in text.splitlines() if " convolution(" in line
                 and ("head/kda/conv" in line or "head/kda/core" in line)]
     state = f"{cfg.heads},{cfg.head_dim},{cfg.head_dim}]"
@@ -609,6 +613,71 @@ def test_hyper_connected_step_fits_beside_the_state_and_keeps_its_scopes(
     assert all("head/attn/core" in c for c in calls if "_window_attention" in c)
     assert _kernels_under(text, capsys, "xing")
     _assert_the_residual_path_is_two_passes(text, capsys, positions, cfg)
+    _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
+
+
+def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
+        topo, tpu_backend, capsys, monkeypatch):
+    """The fused step with the ``mellum`` backbone in it, at its cell's size
+    (20,480 accounts of 4,096 events, the 2-row step: 8,192 positions, four
+    layers at the published widths): in place on the 4.03 GB ring, its
+    arguments the state plus 3.34 GB of weights. The core of attention is
+    ``_block_attention`` (ops/pallas/block_attention.py) once a layer, three
+    times under ``head/attn/window/core`` and once under
+    ``head/attn/full/core``, on ``wq``'s float32 result as the product left
+    it; nothing of ``[b, g, j, t, s]`` at a whole window's keys and nothing
+    of a window's square is in the module (as einsums a layer's scores would
+    be ``f32[2,4,8,4096,4096]``, 4.3 GB). The expert layer's products are
+    the two grouped kernels a layer at 65,536 pairs (~1,024 rows an expert),
+    and the router sorts and gathers nothing. Code, temporaries and arguments are
+    printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.session_heads import HEADS
+    from igaming_platform_tpu.serve import session_state as ss
+
+    monkeypatch.setenv("SESSION_EVENTS", "4096")
+    capacity, batch = 20_480, 2
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["mellum"].config
+    compiled = _compile_step("mellum", capacity, capacity + 1, one, one,
+                             batch=batch)
+    ring = ss.ring_size(capacity + 1, 4096)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nmellum 2-row step of 4,096-event windows for a described "
+              f"v5e: code {mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 7.3e9 < mem.argument_size_in_bytes < 7.5e9, mem
+    assert mem.temp_size_in_bytes < 3.0e9, mem
+    text = compiled.as_text()
+    positions = batch * 4096
+    cores = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line
+             and re.match(r"\s*%_block_attention(\.\d+)? = ", line)]
+    assert len(cores) == cfg.layers, cores
+    for line in cores:
+        assert re.match(rf"\s*%_block_attention(\.\d+)? = bf16\[{positions},4096\]",
+                        line), line[:200]
+        for operand in (f"f32[{positions},4096]", f"bf16[{positions},512]"):
+            assert operand in line, (operand, line[:400])
+    assert sum("head/attn/window/core" in c for c in cores) == 3
+    assert sum("head/attn/full/core" in c for c in cores) == 1
+    for gone in ("4096,4096]", f"[{batch},4,8,"):
+        assert gone not in text, gone
+    kernels = _kernels_under(text, capsys, "mellum")
+    # hidden 2304 is 18 lane tiles, not whole 8-row tiles of them: the
+    # results' way back to position order is XLA's gather and sum here
+    # (``combine_supports``), the two grouped products the kernels
+    for name, count in (("_gate_up", cfg.layers), ("_down", cfg.layers),
+                        ("_combine_rows", 0)):
+        calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
+        assert len(calls) == count, (name, kernels)
+    assert "%ragged-dot-none" not in text
+    _kernels_under(text, capsys, "mellum", scope="head/attn")
     _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
 
 

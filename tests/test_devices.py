@@ -184,8 +184,18 @@ def test_smoke_kernels_phase_tiny_interpreted():
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
         expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
         share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8,
-        ssd_windows=8, stream_tiles=1)
+        ssd_windows=8, stream_tiles=1, block_window=40)
     assert report["interpret"] is True
+    # the blocked attention core (mellum's two kinds of layer) on one window
+    # of 40 positions, a tail of 8 past its blocks of 16-row tiles
+    blocked = report["block_attention_T40"]
+    assert set(blocked) == {"sliding_attention", "full_attention"}
+    for kind, said in blocked.items():
+        assert said["max_err"] <= chip_smoke.BACKBONE_TOL, kind
+        assert said["core"].startswith(
+            "attention core (" + ("window" if kind.startswith("sliding") else "full")
+            + "): einsum in query blocks (window 40 in blocks of 48")
+        assert said["core"].endswith("not a TPU) (backend=cpu)")
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
     # how the kernels are fed: hidden 1024 is gathered outside, hidden 2048
