@@ -1114,8 +1114,8 @@ BACKBONES = {
                              "rows=gathered)", "way_back": "pallas-rows",
               "attention_core": "pallas-windows"}),
     # windows of 4,096 events, four times the band: the blocked core takes
-    # both kinds of layer; hidden 2304 is 18 lane tiles, which ``combine``
-    # does not take (not whole 8-row tiles of them): XLA's gather and sum
+    # both kinds of layer; hidden 2304 is 18 lane tiles, which ``down``
+    # writes and ``combine`` copies at a row pitch of 24 sublanes (PR 58)
     "mellum": ("risk-seqhead-mellum2-12b-a2.5b", "mellum2_12b_a2_5b",
                "mellum_backbone",
                {"window_core": "pallas-blocks (grouped 32/4 of 128, window 4096 "
@@ -1123,7 +1123,7 @@ BACKBONES = {
                 "full_core": "pallas-blocks (grouped 32/4 of 128, window 4096 in "
                              "blocks of 512, band=None: 36 of 64 key blocks)",
                 "expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
-                               "rows=gathered)", "way_back": "xla-gather"}),
+                               "rows=gathered)", "way_back": "pallas-rows"}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
               "attention_core": "attention core", "ssm_core": "state-space core",

@@ -26,9 +26,9 @@ def _expert_products(xs, sizes, layer: Params, cfg,
     TPU, at shapes the kernels support, two Pallas grouped kernels
     (ops/pallas/grouped_experts.py: gate and up share one read of the
     rows, silu and the product in the epilogue; with ``whole_rows`` the
-    second writes [M, hidden / 128, 128], each row one piece of memory, for
-    ``combine`` to copy row by row). How they are fed is read from the
-    shapes and announced with the core: the weights through a ring of VMEM
+    second writes [M, pitch, 128], each row one piece of memory that starts
+    on a sublane tile, for ``combine`` to copy row by row). How they are fed
+    is read from the shapes and announced with the core: the weights through a ring of VMEM
     slots, the rows brought together inside ``gate_up`` out of the
     positions it holds in VMEM (``takes_rows``), or gathered here into a
     sorted copy. Elsewhere that gather and three ``lax.ragged_dot``
@@ -171,7 +171,7 @@ def grouped_experts(x, top_e, top_w, layer: Params, cfg, first_expert: int = 0,
         if _combine_by_kernel(results, rank):
             ys = _expert_products(xb, sizes, layer, cfg, whole_rows=True,
                                   rows=order // k)
-            return kernels.combine(ys, rank, top_w)
+            return kernels.combine(ys, rank, top_w, hidden=hidden)
         ys = _expert_products(xb, sizes, layer, cfg, rows=order // k)
         y = ys[rank.reshape(-1)].reshape(n, k, -1)
         return jnp.sum(y * top_w[..., None], axis=1)

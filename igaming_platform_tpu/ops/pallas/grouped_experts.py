@@ -15,7 +15,12 @@ gathered copy again; here it is three kernels, the two products:
   dtype. The gate and up weights stay two arguments: they are fused in
   the kernel, never concatenated at rest or per call.
 - ``down(mid, wd, sizes) -> ys``: [M, width] x [E, width, hidden] into
-  float32 [M, hidden].
+  float32 [M, hidden]; or, for ``combine``, with its rows whole: [M, pitch,
+  128], a row's ``hidden / 128`` lane chunks in the first sublanes of
+  ``pitch`` of them, where ``pitch`` is that many rounded up to whole (8,
+  128) tiles (``_pitch``: 16 at 2,048, 24 at 2,304), so that every row
+  starts on a tile and is one piece of memory. The sublanes past a row's
+  chunks are never written: they hold whatever the memory held.
 
 Both walk the same schedule. A *visit* is one (row tile, expert) pair
 whose rows intersect: ``M / tm`` tiles plus one more visit for every
@@ -83,8 +88,14 @@ What it is given decides how it reads (PERF.md, PR 37, has the readings):
   owed, so each is copied from HBM by a DMA of its own, a tile of
   positions ahead of the sum. A row of the (8, 128)-tiled [M, hidden]
   lies in hidden / 128 pieces of 512 B and Mosaic refuses to slice it;
-  as a leading index of [M, hidden / 128, 128] it is one piece, which is
-  what ``down(..., whole_rows=True)`` writes at the price of a plain store.
+  as a leading index of [M, pitch, 128] it is one piece, which is what
+  ``down(..., whole_rows=True)`` writes. Nothing in it needs a row to
+  *fill* whole tiles, only to start on one: at 2,304 (18 lane chunks) a
+  row rides at a pitch of 24 sublanes, its DMA carries the six idle ones
+  along (a row costs its descriptor, ~20 ns on a v5e from 2,048 to 3,072:
+  PERF.md, PR 58), the sum is formed over all 24 and only the first 18
+  are stored, so what the padding holds, a NaN too, reaches nothing.
+  Where ``hidden / 128`` divides by 8 the pitch is the row.
 - with ``take`` (a share's pass): few slots are owed (2-4% in the cell)
   and the pass's results are small enough to stay in VMEM, so they are
   copied there once, whole, and each taken slot adds its row from there;
@@ -114,6 +125,15 @@ _VMEM_CAP = 100 * 2**20
 _SUB_TILE = 64
 
 _LANES = 128
+
+
+def _pitch(chunks: int) -> int:
+    """Sublanes from one whole row to the next in ``[M, pitch, 128]``: a
+    row's ``chunks`` lane chunks rounded up to whole (8, 128) tiles, so
+    that every row starts on a tile and ``[M * pitch, 128]`` is the same
+    memory (24 at a hidden size of 2,304; ``chunks`` itself wherever it
+    divides by 8)."""
+    return 8 * pl.cdiv(chunks, 8)
 
 # Rows one turn of the loop that brings a sub-tile's rows together copies
 # (written out: Mosaic unrolls a loop whole or not at all).
@@ -166,13 +186,15 @@ def _slots(*weights) -> int:
 
 
 def _grouped_vmem(lhs_bytes: int, tm: int, ts: int, k: int, n: int, nw: int,
-                  slots: int, out_size: int, w_size: int) -> int:
+                  slots: int, out_size: int, w_size: int,
+                  out_n: int | None = None) -> int:
     """What one grouped kernel asks of VMEM: what it holds of its rows
-    (``lhs_bytes``), the output tile's double buffer, the ring of weight
+    (``lhs_bytes``), the output tile's double buffer (``out_n`` a row where
+    that is more than ``n``: rows whole at their pitch), the ring of weight
     slots, the float32 accumulators of one sub-tile (twice: the products
     and the epilogue's), and room to spare."""
-    return (lhs_bytes + 2 * tm * n * out_size + slots * nw * k * n * w_size
-            + 4 * nw * ts * n * 4 + 8 * 2**20)
+    return (lhs_bytes + 2 * tm * (out_n or n) * out_size
+            + slots * nw * k * n * w_size + 4 * nw * ts * n * 4 + 8 * 2**20)
 
 
 def _held_rows_bytes(positions: int, ts: int, k: int) -> int:
@@ -342,9 +364,9 @@ def _kernel(start_ref, end_ref, group_ref, tile_ref, first_ref, slot_ref,
             own = jnp.logical_and(rows >= start, rows < end)
             if whole_rows:
                 out = epilogue(*acc).astype(o_ref.dtype)
-                chunks = out.shape[1] // _LANES
-                for c in range(chunks):
-                    at = pl.ds(sub * (ts * chunks) + c, ts, stride=chunks)
+                pitch = o_ref.shape[0] // tm
+                for c in range(out.shape[1] // _LANES):
+                    at = pl.ds(sub * (ts * pitch) + c, ts, stride=pitch)
                     o_ref[at, :] = jnp.where(
                         own, out[:, c * _LANES:(c + 1) * _LANES], o_ref[at, :])
             else:
@@ -374,9 +396,11 @@ def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
     row_block = lambda v, start, end, group, tile, *_: (tile[v], 0)
     out_size = jnp.dtype(out_dtype).itemsize
     w_size = weights[0].dtype.itemsize
-    chunks = n // _LANES
-    out_shape, out_block = (((m * chunks, _LANES), (tm * chunks, _LANES))
-                            if whole_rows else ((m, n), (tm, n)))
+    # a row of the output: with its rows whole, a pitch of sublanes
+    out_n = _pitch(n // _LANES) * _LANES if whole_rows else n
+    out_shape, out_block = (
+        ((m * out_n // _LANES, _LANES), (tm * out_n // _LANES, _LANES))
+        if whole_rows else ((m, n), (tm, n)))
     if rows is None:
         prefetch, operands = (), (lhs,)
         in_specs = [pl.BlockSpec((tm, k), row_block)]
@@ -392,7 +416,8 @@ def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
                    pltpu.VMEM((ts * words, _LANES), jnp.uint32),
                    pltpu.SemaphoreType.DMA(())]
         lhs_bytes = _held_rows_bytes(lhs.shape[0], ts, k)
-    vmem = _grouped_vmem(lhs_bytes, tm, ts, k, n, nw, slots, out_size, w_size)
+    vmem = _grouped_vmem(lhs_bytes, tm, ts, k, n, nw, slots, out_size, w_size,
+                         out_n)
     schedule = _schedule(sizes, m, tm, slots)
     return pl.pallas_call(
         functools.partial(_kernel, tm=tm, ts=ts, nw=nw, slots=slots,
@@ -413,7 +438,7 @@ def _grouped(epilogue, lhs, weights, sizes, out_dtype, *, tm: int, ts: int,
         cost_estimate=pl.CostEstimate(
             flops=2 * nw * m * k * n,
             transcendentals=(nw - 1) * m * n,  # the gate-up epilogue's silu
-            bytes_accessed=(m * k * lhs.dtype.itemsize + m * n * out_size
+            bytes_accessed=(m * k * lhs.dtype.itemsize + m * out_n * out_size
                             + nw * e * k * n * w_size)),
         interpret=interpret,
     )(*schedule, *prefetch, *operands, *weights)
@@ -454,15 +479,21 @@ def gate_up(xs, wg, wu, sizes, *, rows=None, interpret: bool = False):
 def down(mid, wd, sizes, *, whole_rows: bool = False, interpret: bool = False):
     """``mid @ wd[e]`` for the expert ``e`` of each row: ``mid`` [M, width]
     x ``wd`` [E, width, hidden] -> float32 [M, hidden]; with ``whole_rows``
-    the same numbers as [M, hidden / 128, 128], in which a row is one
-    piece of memory (what ``combine`` copies row by row): the kernel
-    stores each lane chunk of a sub-tile with a sublane stride, at the
-    price of a plain store (PERF.md, PR 37)."""
+    the same numbers as [M, pitch, 128], in which a row is one piece of
+    memory (what ``combine`` copies row by row): lane chunk ``c`` of row
+    ``i`` is ``[i, c]``, and ``pitch`` is ``hidden / 128`` rounded up to
+    whole sublane tiles (``_pitch``: 24 at 2,304, ``hidden / 128`` itself
+    at 2,048). Sublanes ``hidden / 128 ..`` of a row are never written and
+    hold whatever the memory held; ``combine`` is told ``hidden`` and reads
+    none of them into a sum. The kernel stores each lane chunk of a
+    sub-tile with a sublane stride of ``pitch``, at the price of a plain
+    store (PERF.md, PR 37; at pitch 24, PR 58)."""
     tm, ts = _tiles(mid.shape[0])
     ys = _down(mid, wd, sizes, tm=tm, ts=ts, slots=_slots(wd),
                interpret=interpret, whole_rows=whole_rows)
     if whole_rows:
-        return ys.reshape(mid.shape[0], wd.shape[2] // _LANES, _LANES)
+        # [M * pitch, 128] -> [M, pitch, 128]: whole tiles, nothing moves
+        return ys.reshape(mid.shape[0], -1, _LANES)
     return ys
 
 
@@ -522,11 +553,13 @@ def _combine_rows_kernel(rows_ref, w_ref, ys_hbm, o_ref, buf, turn, sem, *,
                          tile: int, k: int, chunks: int, n_tiles: int):
     """Every slot taken. Grid step ``i`` starts the row copies of tile ``i``
     into buffer ``i % 2`` and sums tile ``i - 1`` out of the other, eight
-    positions a loop turn. A row of ``ys_hbm`` [M, chunks, 128] lands as
-    [chunks, 128]; a position's sum is formed in that shape, with its
-    weights as scalars, and eight of them are turned into position-major
-    [8, 128] tiles through ``turn`` (stored row after row, read back with a
-    stride of a row).
+    positions a loop turn. A row of ``ys_hbm`` [M, pitch, 128] lands as
+    [pitch, 128], whole tiles; a position's sum is formed in that shape,
+    with its weights as scalars, and eight of them are turned into
+    position-major [8, 128] tiles through ``turn`` (stored row after row,
+    read back with a stride of a row's pitch). Only the first ``chunks``
+    sublanes of a row hold results and only they are read out of ``turn``:
+    what the others held is multiplied and never stored.
 
     The two ends run the same loop: step 0 sums a buffer nothing has
     written into the block that step 1 then writes in full, and the last
@@ -536,6 +569,7 @@ def _combine_rows_kernel(rows_ref, w_ref, ys_hbm, o_ref, buf, turn, sem, *,
     trace and lower, which a serving process pays in seconds (PERF.md, PR
     37)."""
     i = pl.program_id(0)
+    pitch = ys_hbm.shape[1]
     into = jax.lax.rem(i, 2)   # the buffer this step fetches into
     outof = 1 - into           # and the one it sums out of
     fetch0 = jnp.minimum(i, n_tiles - 1) * (tile * k)
@@ -562,7 +596,7 @@ def _combine_rows_kernel(rows_ref, w_ref, ys_hbm, o_ref, buf, turn, sem, *,
             acc = buf[outof, at] * w_ref[sum0 + at]
             for j in range(1, k):
                 acc = acc + buf[outof, at + j] * w_ref[sum0 + at + j]
-            turn[pl.ds(pl.multiple_of(u * chunks, chunks), chunks), :] = acc
+            turn[pl.ds(pl.multiple_of(u * pitch, pitch), pitch), :] = acc
             return c
 
         jax.lax.fori_loop(0, 8, start, 0)
@@ -570,7 +604,7 @@ def _combine_rows_kernel(rows_ref, w_ref, ys_hbm, o_ref, buf, turn, sem, *,
         rows8 = pl.ds(pl.multiple_of(g * 8, 8), 8)
         for c in range(chunks):
             o_ref[rows8, c * _LANES:(c + 1) * _LANES] = turn[
-                pl.ds(c, 8, stride=chunks), :]
+                pl.ds(c, 8, stride=pitch), :]
         return carry
 
     jax.lax.fori_loop(0, tile // 8, eight_positions, 0)
@@ -580,12 +614,13 @@ def _combine_rows_kernel(rows_ref, w_ref, ys_hbm, o_ref, buf, turn, sem, *,
         landed(into)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _combine_rows(ys3, rows, weights, *, tile: int, interpret: bool):
-    m, chunks, _ = ys3.shape
+@functools.partial(jax.jit, static_argnames=("hidden", "tile", "interpret"))
+def _combine_rows(ys3, rows, weights, *, hidden: int, tile: int,
+                  interpret: bool):
+    m, pitch, _ = ys3.shape
     p, k = rows.shape
     n_tiles = p // tile
-    hidden = chunks * _LANES
+    chunks = hidden // _LANES
     return pl.pallas_call(
         functools.partial(_combine_rows_kernel, tile=tile, k=k, chunks=chunks,
                           n_tiles=n_tiles),
@@ -597,8 +632,8 @@ def _combine_rows(ys3, rows, weights, *, tile: int, interpret: bool):
             out_specs=pl.BlockSpec(
                 (tile, hidden), lambda i, *_: (jnp.maximum(i - 1, 0), 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, tile * k, chunks, _LANES), jnp.float32),
-                pltpu.VMEM((8 * chunks, _LANES), jnp.float32),
+                pltpu.VMEM((2, tile * k, pitch, _LANES), jnp.float32),
+                pltpu.VMEM((8 * pitch, _LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,))],
         ),
         compiler_params=pltpu.CompilerParams(
@@ -606,15 +641,17 @@ def _combine_rows(ys3, rows, weights, *, tile: int, interpret: bool):
             vmem_limit_bytes=min(_VMEM_CAP, _combine_rows_vmem(tile, k, hidden))),
         cost_estimate=pl.CostEstimate(
             flops=2 * p * k * hidden, transcendentals=0,
-            bytes_accessed=4 * (p * k * hidden + p * hidden + 2 * p * k)),
+            bytes_accessed=4 * (p * k * pitch * _LANES + p * hidden
+                                + 2 * p * k)),
         interpret=interpret,
     )(rows.reshape(-1), weights.reshape(-1), ys3)
 
 
 def _combine_rows_vmem(tile: int, k: int, hidden: int) -> int:
-    """Two buffers of a tile's rows, the output block twice, and room to
-    spare."""
-    return 2 * k * tile * hidden * 4 + 2 * tile * hidden * 4 + 4 * 2**20
+    """Two buffers of a tile's rows at their pitch, the output block twice,
+    and room to spare."""
+    row = _pitch(hidden // _LANES) * _LANES
+    return 2 * k * tile * row * 4 + 2 * tile * hidden * 4 + 4 * 2**20
 
 
 def _combine_held_kernel(taken_ref, rows_ref, w_ref, ys_hbm, *rest, tile: int,
@@ -698,12 +735,13 @@ def _combine_held(ys, rows, weights, take, onto=None, *, tile: int,
 
 
 def combine_supports(ys, rows, take=None) -> bool:
-    """Whether ``combine`` takes results ``ys`` ([M, hidden], or [M, hidden /
-    128, 128] as ``down(..., whole_rows=True)`` writes them) for ``rows`` [P,
-    k] (arrays or their shapes-and-dtypes): float32 rows of whole lane
-    tiles, and
-    - every slot taken: positions in whole tiles, a row a whole number of
-      sublane tiles, the two buffers inside the VMEM cap;
+    """Whether ``combine`` takes results ``ys`` ([M, hidden], or [M, pitch,
+    128] as ``down(..., whole_rows=True)`` writes them) for ``rows`` [P, k]
+    (arrays or their shapes-and-dtypes): float32 rows of whole lane tiles,
+    and
+    - every slot taken: positions in whole tiles, the two buffers of rows
+      at their pitch (``_pitch``: any number of lane tiles a row, rounded
+      up to whole sublane tiles) inside the VMEM cap;
     - with ``take``: the results themselves inside their share of VMEM.
     Anything else takes the caller's XLA expressions."""
     m = ys.shape[0]
@@ -713,28 +751,41 @@ def combine_supports(ys, rows, take=None) -> bool:
         return False
     if take is not None:
         return m * hidden * 4 <= HELD_RESULTS_BYTES
-    return (p % _COMBINE_TILE == 0 and hidden % (8 * _LANES) == 0
+    return (p % _COMBINE_TILE == 0
             and _combine_rows_vmem(_COMBINE_TILE, k, hidden) <= _VMEM_CAP)
 
 
 def combine(ys, rows, weights, take=None, onto=None, *,
-            interpret: bool = False):
+            hidden: int | None = None, interpret: bool = False):
     """The results' way back to position order: ``y[p] = sum_j weights[p, j]
     * ys[rows[p, j]]`` over the slots taken (all of them without ``take``),
     float32 throughout, slots added in ascending ``j``; with ``take`` and
     ``onto`` [P, hidden] (a share's carry, whose memory the result takes)
     the slots are added onto it, one after the other. ``ys`` float32 [M,
-    hidden] or [M, hidden / 128, 128], ``rows`` int32 [P, k], ``weights``
-    float32 [P, k], ``take`` bool [P, k] -> float32 [P, hidden]. Each row
-    that is owed is read once; a slot not taken is never read, so what
-    its row holds (a NaN too) reaches nothing."""
-    m, hidden = ys.shape[0], math.prod(ys.shape[1:])
+    hidden] or, its rows whole, [M, pitch, 128] with ``hidden`` the length
+    of a row where its pitch holds more (``down(..., whole_rows=True)`` at
+    a hidden size that is not whole sublane tiles: [M, 24, 128] does not
+    say whether a row is 2,304 or 3,072), ``rows`` int32 [P, k],
+    ``weights`` float32 [P, k], ``take`` bool [P, k] -> float32 [P,
+    hidden]. Each row that is owed is read once; a slot not taken is never
+    read, so what its row holds (a NaN too) reaches nothing, and neither
+    does what a row's padding holds."""
+    m = ys.shape[0]
+    if hidden is None:
+        hidden = math.prod(ys.shape[1:])
     if take is None:
         # a row must be one piece to be copied alone: as a leading index
-        # of [M, hidden / 128, 128] it is, in the (8, 128)-tiled [M,
-        # hidden] it lies in hidden / 128 pieces, 4 KB apart
+        # of [M, pitch, 128] it is, in the (8, 128)-tiled [M, hidden] it
+        # lies in hidden / 128 pieces, 4 KB apart
         assert onto is None, "a carry comes with a share's passes (``take``)"
-        return _combine_rows(ys.reshape(m, hidden // _LANES, _LANES), rows,
-                             weights, tile=_COMBINE_TILE, interpret=interpret)
+        if ys.ndim == 2:
+            chunks = hidden // _LANES
+            idle = _pitch(chunks) - chunks
+            ys = ys.reshape(m, chunks, _LANES)
+            if idle:
+                # a copy: ``down(..., whole_rows=True)`` writes the pitch itself
+                ys = jnp.pad(ys, ((0, 0), (0, idle), (0, 0)))
+        return _combine_rows(ys, rows, weights, hidden=hidden,
+                             tile=_COMBINE_TILE, interpret=interpret)
     return _combine_held(ys.reshape(m, hidden), rows, weights, take, onto,
                          tile=_COMBINE_TILE, interpret=interpret)

@@ -627,9 +627,11 @@ def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
     ``head/attn/full/core``, on ``wq``'s float32 result as the product left
     it; nothing of ``[b, g, j, t, s]`` at a whole window's keys and nothing
     of a window's square is in the module (as einsums a layer's scores would
-    be ``f32[2,4,8,4096,4096]``, 4.3 GB). The expert layer's products are
-    the two grouped kernels a layer at 65,536 pairs (~1,024 rows an expert),
-    and the router sorts and gathers nothing. Code, temporaries and arguments are
+    be ``f32[2,4,8,4096,4096]``, 4.3 GB). The expert layer is three kernels
+    a layer at 65,536 pairs (~1,024 rows an expert): the two grouped
+    products and, since PR 58, the way back (``_combine_rows`` over rows at
+    a pitch of 24 sublanes, hidden 2304 being 18 lane tiles); and the router
+    sorts and gathers nothing. Code, temporaries and arguments are
     printed."""
     from jax.sharding import SingleDeviceSharding
 
@@ -652,7 +654,9 @@ def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
     assert _ring_sized_copies(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 7.3e9 < mem.argument_size_in_bytes < 7.5e9, mem
-    assert mem.temp_size_in_bytes < 3.0e9, mem
+    # 1.02 GB (PR 58; 1.31 while XLA's way back held a gathered float32 copy
+    # of the experts' results, 0.60 GB, beside the results)
+    assert mem.temp_size_in_bytes < 1.2e9, mem
     text = compiled.as_text()
     positions = batch * 4096
     cores = [line for line in text.splitlines()
@@ -669,13 +673,26 @@ def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
     for gone in ("4096,4096]", f"[{batch},4,8,"):
         assert gone not in text, gone
     kernels = _kernels_under(text, capsys, "mellum")
-    # hidden 2304 is 18 lane tiles, not whole 8-row tiles of them: the
-    # results' way back to position order is XLA's gather and sum here
-    # (``combine_supports``), the two grouped products the kernels
+    # hidden 2304 is 18 lane tiles: ``down`` writes its rows whole at a
+    # pitch of 24 sublanes, three (8, 128) tiles (``f32[1572864,128]``, a
+    # bitcast away from ``[65536,24,128]``), and the way back to position
+    # order is ``_combine_rows`` once a layer, a DMA an owed row
     for name, count in (("_gate_up", cfg.layers), ("_down", cfg.layers),
-                        ("_combine_rows", 0)):
+                        ("_combine_rows", cfg.layers)):
         calls = [k for k in kernels if re.match(rf"\s*%{name}(\.\d+)? = ", k)]
         assert len(calls) == count, (name, kernels)
+    pairs, pitch = positions * cfg.top_k, 24
+    for line in kernels:
+        if re.match(r"\s*%_down(\.\d+)? = ", line):
+            assert f" = f32[{pairs * pitch},128]" in line, line[:200]
+        if re.match(r"\s*%_combine_rows(\.\d+)? = ", line):
+            assert f" = f32[{positions},{cfg.hidden}]" in line, line[:200]
+    # nothing of XLA's way back is left: no gathered float32 copy of the
+    # results (``ys[rank]``) nor any other array of their plain shape, and
+    # no re-layout copy of the results at their pitch
+    assert f"f32[{pairs},{cfg.hidden}]" not in text
+    results = pairs * pitch * 128
+    assert _ring_sized_copies(compiled, results) == []
     assert "%ragged-dot-none" not in text
     _kernels_under(text, capsys, "mellum", scope="head/attn")
     _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)

@@ -359,19 +359,29 @@ def test_first_visits_that_wait_by_the_kernels_own_rule(sizes, slots, waits):
 # -- down, its rows whole ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("skew", ["uniform", "several-empty",
-                                  "not-multiples-of-the-tile",
-                                  "fewer-rows-than-a-sub-tile"])
-def test_down_writes_the_same_numbers_with_its_rows_whole(skew):
+@pytest.mark.parametrize("skew,hidden", [
+    ("uniform", 256), ("several-empty", 256), ("not-multiples-of-the-tile", 256),
+    ("fewer-rows-than-a-sub-tile", 256), ("uniform", 1024),
+    ("not-multiples-of-the-tile", 384), ("fewer-rows-than-a-sub-tile", 384),
+    ("several-empty", 2304), ("not-multiples-of-the-tile", 2304)])
+def test_down_writes_the_same_numbers_with_its_rows_whole(skew, hidden):
+    """[rows, pitch, 128], a row's ``hidden / 128`` lane chunks in its first
+    sublanes and every row on a sublane tile: pitch 8 at hidden 256 (two
+    chunks) and 384 (three), 8 at 1,024 (the pitch is the row), 24 at
+    mellum's 2,304 (eighteen). What the sublanes past a row's chunks hold
+    is nobody's."""
     rows, sizes = SKEWS[skew]
-    xs, _, _, _ = operands(rows)
-    wd = operands(rows, seed=1)[1]  # [E, 128, 256]: hidden 256, two lane chunks
+    xs = operands(rows)[0]
+    wd = operands(rows, seed=1, shape=(EXPERTS, hidden, HIDDEN))[3]  # [E, 128, hidden]
     sizes = jnp.asarray(sizes, jnp.int32)
+    chunks = hidden // 128
+    pitch = {256: 8, 384: 8, 1024: 8, 2304: 24}[hidden]
+    assert ge._pitch(chunks) == pitch
     plain = ge.down(xs, wd, sizes, interpret=True)
     whole = ge.down(xs, wd, sizes, whole_rows=True, interpret=True)
-    assert whole.shape == (rows, 2, 128) and whole.dtype == jnp.float32
-    np.testing.assert_array_equal(np.asarray(whole).reshape(rows, 256),
-                                  np.asarray(plain))
+    assert whole.shape == (rows, pitch, 128) and whole.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(whole)[:, :chunks].reshape(rows, hidden), np.asarray(plain))
 
 
 # -- combine: the way back to position order --------------------------------------
@@ -404,22 +414,38 @@ def uneven_weights(p: int, k: int, seed: int = 1):
                                       -3.0, 1.0)
 
 
+def at_its_pitch(ys, filler=0.0):
+    """``ys`` [M, hidden] as ``down(..., whole_rows=True)`` hands it over:
+    [M, pitch, 128], the sublanes past a row's chunks holding ``filler``."""
+    m, hidden = ys.shape
+    chunks = hidden // 128
+    return jnp.pad(ys.reshape(m, chunks, 128),
+                   ((0, 0), (0, ge._pitch(chunks) - chunks), (0, 0)),
+                   constant_values=filler)
+
+
 @pytest.mark.parametrize("positions,k,hidden,whole_rows", [
     (128, 8, 1024, False), (128, 8, 1024, True), (64, 2, 2048, True),
-    (192, 4, 2048, True)],
-    ids=["k8", "k8-rows-whole", "k2-one-tile", "k4-rows-whole"])
+    (192, 4, 2048, True), (128, 8, 2304, True), (64, 2, 640, False),
+    (64, 2, 640, True), (64, 4, 384, False), (64, 4, 384, True)],
+    ids=["k8", "k8-rows-whole", "k2-one-tile", "k4-rows-whole",
+         "mellum-18-chunks-rows-whole", "5-chunks", "5-chunks-rows-whole",
+         "3-chunks-under-a-tile", "3-chunks-rows-whole"])
 def test_combine_every_slot_equals_gather_and_weighted_sum(positions, k, hidden,
                                                            whole_rows):
     """(a) a full permutation with uneven weights, from ``ys`` as [M, hidden]
-    and as ``down(..., whole_rows=True)`` hands it over."""
+    and as ``down(..., whole_rows=True)`` hands it over: at a hidden size of
+    whole sublane tiles [M, hidden / 128, 128], at any other a row at its
+    pitch with ``hidden`` saying how much of it is the row."""
     m = positions * k
     ys = results(m, hidden)
     rows = jnp.asarray(np.random.default_rng(3).permutation(m).reshape(
         positions, k).astype(np.int32))
     w = uneven_weights(positions, k)
     assert ge.combine_supports(ys, rows)
-    given = ys.reshape(m, hidden // 128, 128) if whole_rows else ys
-    got = ge.combine(given, rows, w, interpret=True)
+    given = at_its_pitch(ys) if whole_rows else ys
+    assert ge.combine_supports(given, rows)
+    got = ge.combine(given, rows, w, hidden=hidden, interpret=True)
     want = xla_every_slot(ys, rows, w)
     assert got.shape == (positions, hidden) and got.dtype == jnp.float32
     # float32 summation order alone: eight terms of up to 10 x |ys|
@@ -428,6 +454,31 @@ def test_combine_every_slot_equals_gather_and_weighted_sum(positions, k, hidden,
     # a slot left out would show
     less = xla_every_slot(ys, rows, w.at[5, 1].set(0.0))
     assert float(jnp.abs(less[5] - got[5]).max()) > 1e-3
+    if hidden % 1024 == 0:
+        # the pitch is the row: nothing needs saying
+        np.testing.assert_array_equal(
+            np.asarray(ge.combine(given, rows, w, interpret=True)), np.asarray(got))
+
+
+@pytest.mark.parametrize("k,hidden", [(8, 2304), (2, 640), (4, 384)])
+def test_what_a_rows_padding_holds_reaches_no_output(k, hidden):
+    """``down`` never writes the sublanes between a row's last chunk and its
+    pitch, so they hold whatever the memory held: NaN planted in all of
+    them, the result is finite and the one the zero-padded rows give, to
+    the bit."""
+    positions = 64
+    m = positions * k
+    ys = results(m, hidden, seed=11)
+    rows = jnp.asarray(np.random.default_rng(5).permutation(m).reshape(
+        positions, k).astype(np.int32))
+    w = uneven_weights(positions, k, seed=12)
+    poisoned = at_its_pitch(ys, jnp.nan)
+    chunks = hidden // 128
+    assert bool(jnp.isnan(poisoned[:, chunks:]).all()) and poisoned.shape[1] > chunks
+    got = np.asarray(ge.combine(poisoned, rows, w, hidden=hidden, interpret=True))
+    assert got.shape == (positions, hidden) and np.isfinite(got).all()
+    clean = ge.combine(at_its_pitch(ys), rows, w, hidden=hidden, interpret=True)
+    np.testing.assert_array_equal(got, np.asarray(clean))
 
 
 def a_share(positions: int, k: int, m: int, taken: int, seed: int = 4):
@@ -494,7 +545,6 @@ F32 = jnp.float32
 @pytest.mark.parametrize("why,m,hidden,positions,k,dtype,share", [
     ("bfloat16 results", 1024, 1024, 128, 8, jnp.bfloat16, False),
     ("hidden not lane-aligned", 1024, 1000, 128, 8, F32, False),
-    ("a row not whole sublane tiles", 1024, 640, 128, 8, F32, False),
     ("positions not whole tiles", 800, 1024, 100, 8, F32, False),
     ("no rows at all", 0, 1024, 128, 8, F32, False),
     ("two buffers past the VMEM cap", 8192, 32768, 1024, 8, F32, False),
@@ -510,6 +560,17 @@ def test_combine_supports_refuses(why, m, hidden, positions, k, dtype, share):
     assert not ge.combine_supports(ys, rows, take), why
 
 
+@pytest.mark.parametrize("results_shape,positions,k", [
+    ((65536, 24, 128), 8192, 8), ((65536, 2304), 8192, 8), ((1024, 640), 128, 8)],
+    ids=["mellum-rows-whole", "mellum", "5-chunks"])
+def test_combine_supports_the_mellum_cells_shape(results_shape, positions, k):
+    """A row needs to start on a sublane tile, not to fill whole ones: 18
+    lane chunks (mellum's 2,304) ride at a pitch of 24, 5 at 8. Every slot
+    of the cell's 8,192 positions at 8 a position."""
+    rows = jax.ShapeDtypeStruct((positions, k), jnp.int32)
+    assert ge.combine_supports(jax.ShapeDtypeStruct(results_shape, F32), rows)
+
+
 def test_combine_supports_the_lfm2_cells_shape():
     # every slot of 4,096 positions at 4 a position, rows whole
     rows = jax.ShapeDtypeStruct((4096, 4), jnp.int32)
@@ -521,6 +582,9 @@ def test_combine_supports_the_cells_shapes():
     take = jax.ShapeDtypeStruct((4096, 8), jnp.bool_)
     # keye: every slot of 4,096 positions, results with their rows whole
     assert ge.combine_supports(jax.ShapeDtypeStruct((32768, 16, 128), F32), rows)
+    # the buffers are reckoned at the rows' pitch: 12.6 MB at mellum's 2,304
+    assert ge._combine_rows_vmem(64, 8, 2304) == (
+        2 * 8 * 64 * 24 * 128 * 4 + 2 * 64 * 2304 * 4 + 4 * 2**20)
     # pangu: one pass of a share, as many rows as ``pass_rows`` allows
     assert el.pass_rows(32768, 8, 256, 7680) == 2048
     assert ge.combine_supports(jax.ShapeDtypeStruct((2048, 7680), F32), rows, take)
@@ -548,6 +612,94 @@ def test_the_way_back_is_chosen_from_backend_and_shapes_and_announced(
     backend = "cpu" if case == "cpu" else "tpu"
     assert [r.getMessage() for r in caplog.records] == [
         f"combine: {way} (backend={backend})"]
+
+
+# -- the other cells' programs ------------------------------------------------------
+
+
+def pallas_calls(jaxpr, found=None):
+    """Every ``pallas_call`` equation of a jaxpr, those inside its jitted
+    calls too."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    pallas_calls(inner, found)
+    return found
+
+
+def program_of(eqn):
+    """What a ``pallas_call`` hands Mosaic beside its body: grid, block
+    shapes, scratch shapes, result, the VMEM it asks for; and the body
+    itself, as a digest of its jaxpr's text."""
+    import hashlib
+
+    mapping = eqn.params["grid_mapping"]
+    return (mapping.grid,
+            [tuple(getattr(d, "block_size", d) for d in b.block_shape)
+             for b in mapping.block_mappings],
+            [str(getattr(a, "inner_aval", a)) for a in mapping.scratch_avals],
+            [str(a) for a in eqn.params["out_avals"]],
+            eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes,
+            hashlib.sha256(str(eqn.params["jaxpr"]).encode()).hexdigest()[:16])
+
+
+# (pairs, slots a position, positions, experts, width) at hidden 2,048, and
+# what ``_combine_rows`` and the whole-rows ``_down`` were there before a row
+# had a pitch (traced from commit 6812441, PR 57): where ``hidden / 128``
+# divides by 8 the pitch is the row and the programs are those, body and all.
+# A PR that changes these kernels renews the literals and measures the three
+# cells (``keye-backbone-insession``, ``lfm2-conv-insession``,
+# ``keye-deep128-insession``) against its parent.
+THE_CELLS_PROGRAMS = {
+    "keye": ((32768, 8, 4096, 128, 768), (
+        (65,), [(32768, 16, 128), (64, 2048)],
+        ["float32[2,512,16,128]", "float32[128,128]", "dma_sem[2]"],
+        ["float32[4096,2048]"], 13631488, "aeb9d9887e5d2495"), (
+        (255,), [(256, 768), (128, 768, 2048), (4096, 128)],
+        ["bfloat16[4,768,2048]", "dma_sem[1,4]"],
+        ["float32[524288,128]"], 28049408, "9a74b13bc59b3365")),
+    "lfm2": ((16384, 4, 4096, 64, 1536), (
+        (65,), [(16384, 16, 128), (64, 2048)],
+        ["float32[2,256,16,128]", "float32[128,128]", "dma_sem[2]"],
+        ["float32[4096,2048]"], 9437184, "992c5ecdf225a42c"), (
+        (127,), [(256, 1536), (64, 1536, 2048), (4096, 128)],
+        ["bfloat16[4,1536,2048]", "dma_sem[1,4]"],
+        ["float32[262144,128]"], 41418752, "0d0ae147a83609c6")),
+    "keye-deep128": ((65536, 8, 8192, 128, 768), (
+        (129,), [(65536, 16, 128), (64, 2048)],
+        ["float32[2,512,16,128]", "float32[128,128]", "dma_sem[2]"],
+        ["float32[8192,2048]"], 13631488, "a87e3e7e9c8a60e0"), (
+        (383,), [(256, 768), (128, 768, 2048), (4096, 128)],
+        ["bfloat16[4,768,2048]", "dma_sem[1,4]"],
+        ["float32[1048576,128]"], 28049408, "eaa37a8336e76908")),
+}
+
+
+@pytest.mark.parametrize("kernel", ["combine_rows", "down_rows_whole"])
+@pytest.mark.parametrize("cell", list(THE_CELLS_PROGRAMS))
+def test_a_pitch_leaves_the_other_cells_programs_as_they_were(cell, kernel):
+    (pairs, k, positions, experts, width), combine, down = THE_CELLS_PROGRAMS[cell]
+    shape = jax.ShapeDtypeStruct
+    if kernel == "combine_rows":
+        traced = jax.make_jaxpr(lambda ys, rows, w: ge.combine(ys, rows, w))(
+            shape((pairs, 16, 128), F32), shape((positions, k), jnp.int32),
+            shape((positions, k), F32))
+        want = combine
+    else:
+        traced = jax.make_jaxpr(
+            lambda mid, wd, sizes: ge.down(mid, wd, sizes, whole_rows=True))(
+            shape((pairs, width), jnp.bfloat16),
+            shape((experts, width, 2048), jnp.bfloat16),
+            shape((experts,), jnp.int32))
+        assert [a.shape for a in traced.out_avals] == [(pairs, 16, 128)]
+        want = down
+    (call,) = pallas_calls(traced.jaxpr)
+    assert program_of(call) == want
 
 
 # -- the experts' sizes -------------------------------------------------------------
