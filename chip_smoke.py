@@ -708,7 +708,7 @@ def phase_kernels(interpret: bool = False, *,
                   share_shape: tuple = (2048, 7680, 4096, 1000),
                   grouped_windows: int = 32, delta_windows: int = 32,
                   ssd_windows: int = 32, stream_tiles: int = 32,
-                  block_window: int = 4096) -> dict:
+                  block_window: int = 4096, scan_window: int = 2048) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -1064,6 +1064,32 @@ def phase_kernels(interpret: bool = False, *,
               f"blocked attention ({kind}) on a window of {t}: max err {err} "
               f"> {BACKBONE_TOL}")
     report[f"block_attention_T{t}"] = cores
+
+    from igaming_platform_tpu.models import phi4flash_backbone
+    from igaming_platform_tpu.ops.pallas import selective_scan as scan
+
+    # the selective scan at the ``phi4flash`` head's Mamba-1 mixer (5,120
+    # channels, a state of 16) on two windows of ``scan_window`` positions
+    # against the chunked form, with the core a trace would pick there
+    cfg, t = phi4flash_backbone.Phi4FlashConfig(), scan_window
+    ks = jax.random.split(jax.random.key(t + 6), 5)
+    wide, narrow = (2 * t, cfg.ssm_width), (2 * t, cfg.ssm_state)
+    x = jax.random.normal(ks[0], wide, jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], wide, jnp.float32) - 4.0)
+    bm, cm = (jax.random.normal(key, narrow, jnp.float32) for key in ks[2:4])
+    a_t = -jnp.broadcast_to(jnp.arange(1.0, cfg.ssm_state + 1)[:, None],
+                            (cfg.ssm_state, cfg.ssm_width))
+    d = 1 + 0.1 * jax.random.normal(ks[4], (cfg.ssm_width,), jnp.float32)
+    picked = _said_by_the_expert_layer(
+        lambda: phi4flash_backbone._scan_core(2 * t, cfg, t))
+    got = scan.selective_scan(x, dt, bm, cm, a_t, d, window=t,
+                              interpret=interpret)
+    want = jax.jit(functools.partial(phi4flash_backbone.scan_by_chunks, window=t,
+                                     chunk=cfg.scan_chunk))(x, dt, bm, cm, a_t, d)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    report[f"selective_scan_T{t}"] = {"max_err": err, "core": picked[0]}
+    check(bool(jnp.all(jnp.isfinite(got))) and err <= STREAMS_TOL,
+          f"selective scan on 2 windows of {t}: max err {err} > {STREAMS_TOL}")
     return report
 
 
@@ -1085,6 +1111,11 @@ def phase_kernels(interpret: bool = False, *,
 # it runs (since PR 55 the window kernel of ops/pallas/ssd_window.py: the
 # taps, the dual form over the one chunk a window is, the gate and the
 # grouped norm as one call a layer).
+PHI4FLASH_EINSUMS = (
+    "einsum in query blocks (differential, 40/20 of 64, values of 128; window "
+    "2048 in blocks of 512, band={band}: {visited} of 16 key blocks; the block "
+    "kernel takes heads of whole 128-lane vregs, values as wide as keys and "
+    "its own head norm and rotary)")
 BACKBONES = {
     "pangu": ("risk-seqhead-openpangu-ultra-moe-718b", "openpangu_ultra",
               "pangu_backbone",
@@ -1124,6 +1155,17 @@ BACKBONES = {
                              "blocks of 512, band=None: 36 of 64 key blocks)",
                 "expert_core": "pallas-grouped (tm=256, ts=64, slots=4/4, "
                                "rows=gathered)", "way_back": "pallas-rows"}),
+    # the model whole, 32 layers: the scan as one kernel a Mamba layer, the
+    # state in VMEM over windows of 2,048 events; differential attention
+    # (heads of 64, values of 128) by einsums in query blocks on a TPU too
+    "phi4flash": ("risk-seqhead-phi-4-mini-flash", "phi4_mini_flash",
+                  "phi4flash_backbone",
+                  {"ssm_core": "pallas-scan (tile=512, blocks of 256, the state "
+                               "in VMEM; 5120 channels, state 16, window 2048)",
+                   "window_core": PHI4FLASH_EINSUMS.format(
+                       band=512, visited=7),
+                   "full_core": PHI4FLASH_EINSUMS.format(
+                       band=None, visited=10)}),
 }
 CORE_LINES = {"expert_core": "expert core", "way_back": "combine",
               "attention_core": "attention core", "ssm_core": "state-space core",
@@ -1165,7 +1207,12 @@ def phase_backbone(*, head_name: str = "pangu", cfg=None,
     ``mellum``: three sliding-window layers of 1,024 keys and one full one
     with a rotary table a kind, 64 experts, every one held, 3.34 GB
     (chipbench/heads/mellum2_12b_a2_5b.py), run on ``events`` = 4,096
-    positions a window so that the band clips (the others' windows are 16)."""
+    positions a window so that the band clips (the others' windows are 16);
+    ``phi4flash``: Phi-4-mini-flash-reasoning whole, 32 layers (nine Mamba-1
+    scans, eight band layers and one full layer of differential attention,
+    then seven Gated Memory Units and seven cross-attention layers at the
+    scored position only), 6.68 GB (chipbench/heads/phi4_mini_flash.py, which
+    runs every layer at every position), on ``events`` = 2,048."""
     import gc
 
     import jax
@@ -1306,6 +1353,8 @@ def main() -> int:
     run("backbone_xing", phase_backbone, head_name="xing")
     run("backbone_mellum", phase_backbone, head_name="mellum", rows=2,
         events=4096)
+    run("backbone_phi4flash", phase_backbone, head_name="phi4flash", rows=2,
+        events=2048)
     run("mesh", phase_mesh, one_chip)
     run("cache", phase_cache, watcher, env["cache_dir"])
 

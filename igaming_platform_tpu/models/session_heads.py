@@ -26,6 +26,7 @@ from igaming_platform_tpu.models import (
     ling_backbone,
     mellum_backbone,
     pangu_backbone,
+    phi4flash_backbone,
     xing_backbone,
 )
 from igaming_platform_tpu.models.sequence import (
@@ -112,11 +113,14 @@ def transformer_scores(sparams, window, lengths):
 
 
 # What a layer's operators (``conv``, ``attention``, ``window``: attention
-# inside a band of keys, ``ssm``, ``linear``: linear attention) and its
-# feed-forward (``dense``, ``moe``) may be: the kinds a row's ``layers``
-# counts. A layer that runs two operators (``falconh1``: ``ssm`` beside
-# ``attention``) counts under both.
-LAYER_KINDS = ("conv", "attention", "window", "ssm", "linear", "dense", "moe")
+# inside a band of keys, ``ssm``, ``linear``: linear attention, ``memory``: a
+# gate over an earlier layer's scan output at the same position, ``cross``:
+# attention over an earlier layer's keys and values) and its feed-forward
+# (``dense``, ``moe``) may be: the kinds a row's ``layers`` counts. A layer
+# that runs two operators (``falconh1``: ``ssm`` beside ``attention``) counts
+# under both.
+LAYER_KINDS = ("conv", "attention", "window", "ssm", "linear", "memory",
+               "cross", "dense", "moe")
 _NO_LAYERS = dict.fromkeys(LAYER_KINDS, 0)
 
 
@@ -138,6 +142,17 @@ class Head:
     # key blocks of their squares); none for a head whose attention sweeps
     # no blocks
     key_blocks: Callable[[int], tuple[int, int]] | None = None
+    # window length -> (layer-positions a scored row costs, layer-positions
+    # of every layer at every position); none for a head whose every layer
+    # runs at every position
+    layer_positions: Callable[[int], tuple[int, int]] | None = None
+
+
+def _per_window(module, name: str, cfg):
+    """``module.<name>(cfg, window)`` as a function of the window, none
+    where the module has no such function."""
+    count = getattr(module, name, None)
+    return count and (lambda window: count(cfg, window))
 
 
 def _backbone(module, cfg) -> Head:
@@ -154,8 +169,8 @@ def _backbone(module, cfg) -> Head:
         config=cfg,
         experts=(getattr(cfg, "held_experts", routed), routed),
         layers=_NO_LAYERS | module.layer_kinds(cfg),
-        key_blocks=(lambda window: module.key_blocks(cfg, window))
-        if hasattr(module, "key_blocks") else None)
+        key_blocks=_per_window(module, "key_blocks", cfg),
+        layer_positions=_per_window(module, "layer_positions", cfg))
 
 
 HEADS = {
@@ -207,6 +222,15 @@ HEADS = {
     # kind (YaRN on the full one); 64 softmax-routed experts of width 896,
     # every one held, no shared expert: 1.67 G parameters, 3.34 GB
     "mellum": _backbone(mellum_backbone, mellum_backbone.MellumConfig()),
+    # a decoder-hybrid-decoder whole, all 32 layers at its published widths:
+    # nine Mamba-1 scans (d_inner 5120, a state of 16 a channel) and eight
+    # layers of differential attention inside a band of 512 keys, one over
+    # every causal key; then seven Gated Memory Units that read layer 16's
+    # scan output and seven cross-attention layers that read layer 17's keys
+    # and values, run at the scored position only; a dense SwiGLU of 10,240
+    # in every layer: 3.34 G parameters, 6.68 GB
+    "phi4flash": _backbone(phi4flash_backbone,
+                           phi4flash_backbone.Phi4FlashConfig()),
 }
 
 

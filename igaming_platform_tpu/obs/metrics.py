@@ -870,6 +870,21 @@ class ServiceMetrics:
             "session_head_key_blocks_visited_total is the share of the "
             "square the cores sweep",
         )
+        self.session_head_layer_positions_computed_total = self.registry.counter(
+            f"{service}_session_head_layer_positions_computed_total",
+            "Layer-positions a head whose stack narrows computed for "
+            "session-scored rows: rows x (the layers that mix positions x "
+            "SESSION_EVENTS + the layers after them x the one position that "
+            "is scored); 0 for every head that runs every layer at every "
+            "position",
+        )
+        self.session_head_layer_positions_whole_total = self.registry.counter(
+            f"{service}_session_head_layer_positions_whole_total",
+            "Layer-positions of the same rows with every layer at every "
+            "position (rows x layers x SESSION_EVENTS): over it "
+            "session_head_layer_positions_computed_total is the share of the "
+            "stack a scored row pays for",
+        )
         self.session_head_resident_bytes = self.registry.gauge(
             f"{service}_session_head_resident_bytes",
             "Device bytes of the session head's parameter tree (0 for a "
@@ -891,11 +906,13 @@ class ServiceMetrics:
         self.session_head_layers = self.registry.gauge(
             f"{service}_session_head_layers",
             "Layers of the session head's stack by kind, set once at boot: "
-            "kind=conv|attention|window|ssm|linear is a layer's operator "
-            "(window: attention inside a band of keys; linear: linear "
-            "attention; a layer that runs two, a state-space mixer beside "
+            "kind=conv|attention|window|ssm|linear|memory|cross is a layer's "
+            "operator (window: attention inside a band of keys; linear: "
+            "linear attention; memory: a gate over an earlier layer's scan "
+            "output; cross: attention over an earlier layer's keys and "
+            "values; a layer that runs two, a state-space mixer beside "
             "attention, counts under both), kind=dense|moe its feed-forward "
-            "(a head without layers reads 0 for all seven)",
+            "(a head without layers reads 0 for all nine)",
         )
         self.session_head_residual_streams = self.registry.gauge(
             f"{service}_session_head_residual_streams",

@@ -490,6 +490,10 @@ class SessionStateManager:
         # squares) a scored row, where the head sweeps its keys in blocks
         self.head_key_blocks = (row.key_blocks(self.n_events)
                                 if row.key_blocks else (0, 0))
+        # layer-positions a scored row (costs, would cost with every layer
+        # at every position), where the head's stack narrows
+        self.head_layer_positions = (row.layer_positions(self.n_events)
+                                     if row.layer_positions else (0, 0))
 
         self.lock = threading.RLock()
         self._twin: dict[str, _AcctSession] = {}
@@ -591,6 +595,11 @@ class SessionStateManager:
             if square:
                 m.session_head_key_blocks_visited_total.inc(appends * visited)
                 m.session_head_key_blocks_square_total.inc(appends * square)
+            computed, whole = self.head_layer_positions
+            if whole:
+                m.session_head_layer_positions_computed_total.inc(
+                    appends * computed)
+                m.session_head_layer_positions_whole_total.inc(appends * whole)
         if rehydrations:
             m.session_rehydrations_total.inc(rehydrations)
         if regrows:

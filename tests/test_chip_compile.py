@@ -698,6 +698,65 @@ def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
     _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
 
 
+def test_narrowing_step_scans_in_one_kernel_a_layer_and_holds_the_model_whole(
+        topo, tpu_backend, capsys, monkeypatch):
+    """The fused step with the ``phi4flash`` backbone in it, at its cell's
+    size (16,384 accounts of 2,048 events, the 2-row step: 4,096 positions,
+    all 32 layers at the published widths): in place on the 1.61 GB ring, its
+    arguments the state plus 6.68 GB of weights. The recurrence is
+    ``_selective_scan`` (ops/pallas/selective_scan.py) once a Mamba layer,
+    nine times under ``head/ssm/scan``, on ``x`` and ``dt`` position-major as
+    their products left them; nothing of ``[positions, channels, state]``
+    (1.34 GB a tensor) is in the module, nothing of a window's ``2048,2048``
+    square, and past layer 17 no product over all 4,096 positions: the
+    second half's matrices meet 2 rows. Code, temporaries and arguments are
+    printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models import phi4flash_backbone
+    from igaming_platform_tpu.models.session_heads import HEADS
+    from igaming_platform_tpu.serve import session_state as ss
+
+    monkeypatch.setenv("SESSION_EVENTS", "2048")
+    capacity, batch = 16_384, 2
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["phi4flash"].config
+    compiled = _compile_step("phi4flash", capacity, capacity + 1, one, one,
+                             batch=batch)
+    ring = ss.ring_size(capacity + 1, 2048)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nphi4flash 2-row step of 2,048-event windows for a described "
+              f"v5e: code {mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized_copies(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 8.2e9 < mem.argument_size_in_bytes < 8.4e9, mem
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    text = compiled.as_text()
+    positions = batch * 2048
+    scans = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line
+             and re.match(r"\s*%_selective_scan(\.\d+)? = ", line)]
+    assert len(scans) == 9 == phi4flash_backbone.kinds_of(cfg).count("ssm"), scans
+    wide = f"f32[{positions},{cfg.ssm_width}]"
+    for line in scans:
+        assert "head/ssm/scan" in line
+        assert re.match(rf"\s*%_selective_scan(\.\d+)? = {re.escape(wide)}", line), line[:200]
+        assert line.count(wide) >= 3, line[:400]          # y, x and dt
+        assert f"f32[{positions // 8},16,8]" in line, line[:400]   # B and C
+    _kernels_under(text, capsys, "phi4flash", scope="head/ssm/scan")
+    for gone in (f",{cfg.ssm_width},16]", "2048,2048]",
+                 f"[{positions},{cfg.ssm_width},"):
+        assert gone not in text, gone
+    # the second half meets 2 rows: no product of its scopes has 4,096 rows
+    second = [line for line in text.splitlines()
+              if "head/cross" in line and " convolution(" in line]
+    assert second and not [line for line in second
+                           if f"[{positions}," in line.split(" convolution(")[0]]
+
+
 # The stream kernels of the ``xing`` step (ops/pallas/hyper_streams.py): every
 # hyper-connected sublayer of the five layers held, the first among them.
 XING_STREAM_CALLS = {"_streams_maps_read": 10, "_streams_write": 10}

@@ -3,7 +3,8 @@ under the tier-1 command so that the harness, every file a cell brings
 and the A/A data are guarded by the run the driver makes (PERF.md Open
 question 9). Thin on purpose: it holds one case of its own, beside the one
 case of that directory that this tree can no longer pass (``LAST_EIGHT``),
-and since PR 46 a second pair of the same kind (``LAST_NINE``).
+and since PR 46 a second pair of the same kind (``LAST_NINE``), since PR 57 a
+third (``LAST_TWO``) and since PR 59 a fourth (``LAST_ELEVEN``).
 
 Every test module of that directory is imported here under its own
 names, so each case counts as one. Their fixtures come with them; the
@@ -20,6 +21,7 @@ import pytest
 from chipbench.conftest import add_sources
 from chipbench.tests import test_falconh1_cell as _falconh1
 from chipbench.tests import test_heartbeat_metrics as _heartbeat
+from chipbench.tests import test_mellum_cell as _mellum
 from chipbench.tests import test_span_metrics as _span_metrics
 from chipbench.tests.conftest import copy as _bare_copy
 from chipbench.tests.test_bounds import *  # noqa: F401,F403
@@ -29,6 +31,7 @@ from chipbench.tests.test_lfm2_cell import *  # noqa: F401,F403
 from chipbench.tests.test_ling_cell import *  # noqa: F401,F403
 from chipbench.tests.test_manifest import *  # noqa: F401,F403
 from chipbench.tests.test_mellum_cell import *  # noqa: F401,F403
+from chipbench.tests.test_phi4flash_cell import *  # noqa: F401,F403
 from chipbench.tests.test_reference import *  # noqa: F401,F403
 from chipbench.tests.test_seam import *  # noqa: F401,F403
 from chipbench.tests.test_seam import sourced as _seam_sourced
@@ -88,7 +91,7 @@ def test_the_manifest_keeps_the_span_metrics_together_and_validates():
 LAST_NINE = (
     "chipbench/tests/test_falconh1_cell.py holds PR 45's nine metrics to the "
     "LAST nine places of per_layer; PR 46's padded_rows_per_row follows them "
-    "(and PR 49's and PR 52's configurations, cells and ten metrics each, PR 57's and eleven), for the reason "
+    "(and PR 49's and PR 52's configurations, cells and ten metrics each, PR 57's and eleven, PR 59's and fifteen), for the reason "
     "LAST_EIGHT gives. A benchmark PR repairs the case: "
     "PERF.md Open question 9")
 
@@ -113,12 +116,14 @@ def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypat
     assert all(name.startswith(("ling_", "kda_")) for name in names[last + 2:last + 12])  # PR 49
     assert all(name.startswith(("xing_", "hc_")) for name in names[last + 12:last + 22])  # PR 52
     assert names[last + 22:last + 24] == ["wake_late_us", "rpc_over_50ms_share"]  # PR 54
-    assert all(name.startswith("mellum_") for name in names[last + 24:])  # PR 57
-    assert len(names[last + 24:]) == 11
+    assert all(name.startswith("mellum_") for name in names[last + 24:last + 35])  # PR 57
+    assert all(name.startswith(("phi4flash_", "selective_scan_"))
+               for name in names[last + 35:])  # PR 59
+    assert len(names[last + 35:]) == 15
     cells = [w["name"] for w in manifest["workloads"]]
     assert cells[cells.index(_falconh1.CELL) + 1:] == [
-        "ling-kda-insession", "xing-mhc-insession",
-        "mellum2-swa-deep4096"]  # PR 49, PR 52, PR 57
+        "ling-kda-insession", "xing-mhc-insession", "mellum2-swa-deep4096",
+        "phi4flash-yoco-deep2048"]  # PR 49, PR 52, PR 57, PR 59
     cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
                configs=manifest["configs"][:cells.index(_falconh1.CELL) + 1],
                workloads=manifest["workloads"][:cells.index(_falconh1.CELL) + 1])
@@ -130,8 +135,8 @@ def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypat
 LAST_TWO = (
     "chipbench/tests/test_heartbeat_metrics.py holds PR 54's two metrics to the "
     "LAST two places of per_layer and every cell outside its BACKBONE_CELLS to "
-    "the three host-bound ones; PR 57's cell and eleven metrics follow, for the "
-    "reason LAST_EIGHT gives. A benchmark PR repairs the case: PERF.md Open "
+    "the three host-bound ones; PR 57's cell and eleven metrics follow, and PR 59's "
+    "cell and fifteen, for the reason LAST_EIGHT gives. A benchmark PR repairs the case: PERF.md Open "
     "question 9")
 
 
@@ -144,24 +149,67 @@ def test_the_manifest_gives_the_share_to_the_six_backbone_cells_alone():  # noqa
 
 def test_the_manifest_gives_the_share_to_the_cells_it_named(monkeypatch):
     """The case above, every assertion as it stands, over the manifest cut
-    off before what PR 57 appended (its configuration, its cell and its
-    eleven metrics, which read neither of the two: ``wake_late_us`` lists no
-    cells and is read there too, ``rpc_over_50ms_share`` keeps its list)."""
+    off before what PR 57 and PR 59 appended (a configuration, a cell and
+    eleven and fifteen metrics, which read neither of the two:
+    ``wake_late_us`` lists no cells and is read there too,
+    ``rpc_over_50ms_share`` keeps its list)."""
     from chipbench import validate
 
     manifest = validate.load_manifest()
     names = [m["name"] for m in manifest["per_layer"]]
     last = names.index("rpc_over_50ms_share")
-    assert all(name.startswith("mellum_") for name in names[last + 1:])  # PR 57
-    assert manifest["workloads"][-1]["name"] == "mellum2-swa-deep4096"
-    assert "wake_late_us" in {
-        m["name"] for m in validate.load_cell("mellum2-swa-deep4096")["per_layer"]}
+    assert all(name.startswith("mellum_") for name in names[last + 1:last + 12])  # PR 57
+    assert all(name.startswith(("phi4flash_", "selective_scan_"))
+               for name in names[last + 12:]) and len(names[last + 12:]) == 15  # PR 59
+    later = ["mellum2-swa-deep4096", "phi4flash-yoco-deep2048"]
+    assert [w["name"] for w in manifest["workloads"][-2:]] == later
+    for cell in later:
+        assert "wake_late_us" in {
+            m["name"] for m in validate.load_cell(cell)["per_layer"]}
     cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
-               configs=manifest["configs"][:-1],
-               workloads=manifest["workloads"][:-1])
+               configs=manifest["configs"][:-2],
+               workloads=manifest["workloads"][:-2])
     monkeypatch.setattr(_heartbeat.validate, "load_manifest",
                         lambda *args, **kwargs: cut)
     _heartbeat.test_the_manifest_gives_the_share_to_the_six_backbone_cells_alone()
+
+
+LAST_ELEVEN = (
+    "chipbench/tests/test_mellum_cell.py holds PR 57's configuration, cell and "
+    "eleven metrics to the LAST places of their lists; PR 59's configuration, "
+    "cell and fifteen metrics follow, for the reason LAST_EIGHT gives. A "
+    "benchmark PR repairs the case: PERF.md Open question 9")
+
+
+@pytest.mark.xfail(strict=True, raises=AssertionError, reason=LAST_ELEVEN)
+def test_the_mellum_configuration_is_held_to_its_source_and_states_its_cut():  # noqa: F811
+    """That file's case of this name, run as it stands and expected to fail
+    on the first of its last assertions; strict, as above."""
+    _mellum.test_the_mellum_configuration_is_held_to_its_source_and_states_its_cut()
+
+
+def test_the_mellum_configuration_holds_with_later_entries_set_aside(monkeypatch):
+    """The case above, every assertion as it stands, over the manifest cut
+    off after the configuration, the cell and the eleven metrics it expects
+    last: what follows them is what PR 59 appended, named here."""
+    from chipbench import validate
+
+    manifest = validate.load_manifest()
+    names = [m["name"] for m in manifest["per_layer"]]
+    last = max(names.index(name) for name in _mellum.METRICS)
+    assert all(name.startswith(("phi4flash_", "selective_scan_"))
+               for name in names[last + 1:]) and len(names[last + 1:]) == 15
+    cells = [w["name"] for w in manifest["workloads"]]
+    at = cells.index(_mellum.CELL)
+    assert cells[at + 1:] == ["phi4flash-yoco-deep2048"]
+    assert [c["name"] for c in manifest["configs"]][at + 1:] == [
+        "risk-seqhead-phi-4-mini-flash"]
+    cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
+               configs=manifest["configs"][:at + 1],
+               workloads=manifest["workloads"][:at + 1])
+    monkeypatch.setattr(_mellum.validate, "load_manifest",
+                        lambda *args, **kwargs: cut)
+    _mellum.test_the_mellum_configuration_is_held_to_its_source_and_states_its_cut()
 
 
 @pytest.fixture

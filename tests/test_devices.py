@@ -184,8 +184,15 @@ def test_smoke_kernels_phase_tiny_interpreted():
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
         expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
         share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8,
-        ssd_windows=8, stream_tiles=1, block_window=40)
+        ssd_windows=8, stream_tiles=1, block_window=40, scan_window=24)
     assert report["interpret"] is True
+    # the selective scan (phi4flash's Mamba-1 mixer) on two windows of 24
+    # positions, three blocks of eight, against the chunked form
+    scanned = report["selective_scan_T24"]
+    assert scanned["max_err"] <= chip_smoke.STREAMS_TOL
+    assert scanned["core"] == (
+        "state-space core: chunks of 128 that hand the state on (5120 "
+        "channels, state 16, window 24; not a TPU) (backend=cpu)")
     # the blocked attention core (mellum's two kinds of layer) on one window
     # of 40 positions, a tail of 8 past its blocks of 16-row tiles
     blocked = report["block_attention_T40"]
