@@ -127,7 +127,10 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
     state (``ring_rows`` [B, N, D], ``cur``/``ln`` [B]): the last
     ``min(length, N-1)`` stored events in chronological order, then the
     new event, zero-padded to [B, N, D]. However the rows were gathered
-    (one device or an owner-select collective), the window math is this."""
+    (one device or an owner-select collective), the window math is this.
+    The window is handed on as 32-bit words behind one fence ("the ring's
+    at-rest layout" below says why): the same float32, bit for bit."""
+    import jax
     import jax.numpy as jnp
 
     lp = jnp.minimum(ln + 1, n_events)  # post-append window length
@@ -139,7 +142,9 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
     win = jnp.where(keep, win, 0.0)
     at_event = (k == hist[:, None])[..., None]
     win = jnp.where(at_event, events[:, None, :], win)
-    return win, lp
+    bits = jax.lax.optimization_barrier(
+        jax.lax.bitcast_convert_type(win, jnp.uint32))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32), lp
 
 
 # -- the ring's at-rest layout (ONE place) -----------------------------------
@@ -151,10 +156,16 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
 # tile) and, unlike every shaped layout tried, its windowed gather and
 # scatter compile IN PLACE on the TPU: a step moves O(batch) bytes, not
 # two re-layout copies of the whole ring (docs/performance.md "Session
-# ring layout" has the table of compiles). Everything that reads or
-# writes the ring goes through the three functions below; an index past
-# the end reads zeros and writes nothing (a slot-sharded body points
-# non-owned rows there).
+# ring layout" has the table of compiles). That is also why a window
+# leaves ``windows_from_state`` as uint32 words behind an optimization
+# barrier: a head's first product casts its operand to bfloat16, and the
+# TPU compiler's bfloat16 propagation carries a cast back through selects,
+# gathers and a bare barrier alike, onto the ring argument (at 128 events:
+# a convert of all 4 GB, a fifth of ``keye``'s step, PR 61); it stops at a
+# bitcast-convert, and the barrier keeps the pair from folding away.
+# Everything that reads or writes the ring goes through the three
+# functions below; an index past the end reads zeros and writes nothing
+# (a slot-sharded body points non-owned rows there).
 
 
 def ring_size(rows: int, n_events: int, shards: int = 1) -> int:

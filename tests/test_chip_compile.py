@@ -5,9 +5,10 @@ compile the fused session step and the admission sync at a deployment's
 size for a v5e that is described, not attached, and hold the compiled
 module to what the ring's flat at-rest layout promises
 (serve/session_state.py "the ring's at-rest layout"): the donated ring
-is written in place — no ring-sized ``copy``, no ring-sized temporary,
-outputs aliased onto the arguments. Nothing runs, so nothing here is a
-time; the chip numbers are in PERF.md.
+is written in place — no instruction whose result is ring-sized but the
+ring itself (``_ring_sized``: no ``copy``, no ``convert``, no fusion over
+it), no ring-sized temporary, outputs aliased onto the arguments. Nothing
+runs, so nothing here is a time; the chip numbers are in PERF.md.
 
 The topology is described inside a fixture of this file (never at
 import): only one process may load libtpu, and under xdist only the
@@ -129,23 +130,54 @@ def _compile_step(*args, **kwargs):
     return _lower_step(*args, **kwargs).compile()
 
 
-def _ring_sized_copies(compiled, ring_elems: int) -> list[str]:
-    """HLO ``copy`` instructions whose result holds a ring's worth of
-    elements (whatever its shape or layout)."""
+# How the donated ring passes through a compiled step: as itself. A float32
+# result of exactly the ring's size may be the argument, an element of the
+# gather's or the scatter's loop state, a bitcast, the in-place update, or the
+# fusion that wraps one; nothing else of a ring's size belongs in a step.
+_RING_ITSELF = ("parameter", "get-tuple-element", "bitcast",
+                "dynamic-update-slice", "scatter")
+_INSTRUCTION = re.compile(
+    r"\s*(ROOT\s+)?%?[\w.-]+ = (\w+)\[([\d,]*)\][^ ]* ([\w-]+)\(")
+
+
+def _ring_sized(compiled, ring_elems: int, only: tuple = ()) -> list[str]:
+    """The instructions of a compiled module whose result holds a ring's
+    worth of elements or more, whatever its opcode (``convert``, ``copy``,
+    ``fusion``...), type, shape or layout, other than the donated ring on its
+    way through (``_RING_ITSELF``): what "a step moves O(batch) bytes of the
+    ring" forbids (serve/session_state.py, "the ring's at-rest layout").
+    With ``only``, every instruction of those opcodes and that size instead
+    (a ``copy`` of some other array as large as ``ring_elems``)."""
+    lines = compiled.as_text().splitlines()
+    in_place, name = set(), None   # computations that end in an in-place update
+    for line in lines:
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+        m = _INSTRUCTION.match(line)
+        if m and m.group(1) and m.group(4) in ("dynamic-update-slice", "scatter"):
+            in_place.add(name)
     hits = []
-    for line in compiled.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT\s+)?%?[\w.-]+ = \w+\[([\d,]*)\][^ ]* copy\(",
-                     line)
-        if m and m.group(1):
-            elems = int(np.prod([int(d) for d in m.group(1).split(",")]))
-            if elems >= ring_elems:
-                hits.append(line.strip()[:160])
+    for line in lines:
+        m = _INSTRUCTION.match(line)
+        if not m or not m.group(3):
+            continue
+        _, dtype, dims, op = m.groups()
+        elems = int(np.prod([int(d) for d in dims.split(",")]))
+        if elems < ring_elems or (only and op not in only):
+            continue
+        wraps = re.search(r"calls=%?([\w.-]+)", line) if op == "fusion" else None
+        itself = (not only and dtype == "f32" and elems == ring_elems
+                  and (op in _RING_ITSELF
+                       or (wraps is not None and wraps.group(1) in in_place)))
+        if not itself:
+            hits.append(line.strip()[:160])
     return hits
 
 
 def _assert_in_place(compiled, ring_elems: int) -> None:
     mem = compiled.memory_analysis()
-    assert _ring_sized_copies(compiled, ring_elems) == []
+    assert _ring_sized(compiled, ring_elems) == []
     assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
     assert mem.alias_size_in_bytes >= 4 * ring_elems, mem
 
@@ -221,6 +253,53 @@ def test_fused_session_step_writes_the_ring_in_place(topo, tpu_backend, head):
         assert "tpu_custom_call" in compiled.as_text()
 
 
+# The deep cell's step (`keye-deep128-insession`: 655,360 accounts of 128
+# events, BATCH_SIZE=64) and what it may hold in temporaries: the model's own
+# intermediates at 8,192 positions (read: 744,876,032 B, of it ``_down``'s
+# ``f32[1048576,128]`` results 0.54 GB), which took the room of the parent's
+# bfloat16 ring once the windows were gathered (2,072,092,160 B: PERF.md,
+# section 6, PR 61). The small heads' steps at that depth hold next to nothing.
+DEEP_ACCOUNTS, DEEP_EVENTS, DEEP_BATCH = 655_360, 128, 64
+
+
+@pytest.mark.parametrize("head,temps", [
+    ("keye", 800_000_000), ("pattern", TEMP_LIMIT),
+    ("transformer", 2 * TEMP_LIMIT)])   # read: 68,979,712 B
+def test_deep_window_step_reads_the_ring_by_the_batchs_rows_alone(
+        topo, tpu_backend, capsys, monkeypatch, head, temps):
+    """The 64-row step over 128-event windows at the deep cell's size: no
+    instruction (``convert``, ``copy``, ``fusion``, whatever its shape or
+    layout) yields a ring's worth of elements but the aliased ring itself,
+    the ring is written in place, and the temporaries are the head's own.
+    At the parent ``bfloat16-propagation`` (the TPU compiler's pass after its
+    main fusion) read ``keye``'s first product back through the selects,
+    ``take_along_axis`` and the gather's loop to the ring argument and put
+    ``convert bf16[1006634496]`` there: 9.2 ms of a 47.2 ms step on the chip.
+    The pass walks through an ``optimization_barrier`` (it rewrote the
+    barrier's own type) and stops at a ``bitcast-convert``:
+    ``session_state.windows_from_state`` hands the window over as 32-bit
+    words behind one fence."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.serve import session_state as ss
+
+    monkeypatch.setenv("SESSION_EVENTS", str(DEEP_EVENTS))
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_step(head, DEEP_ACCOUNTS, DEEP_ACCOUNTS + 1, one, one,
+                             batch=DEEP_BATCH)
+    ring = ss.ring_size(DEEP_ACCOUNTS + 1, DEEP_EVENTS)
+    assert ring == 1_006_634_496
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n{head} 64-row step of 128-event windows for a described "
+              f"v5e: temporaries {mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert mem.temp_size_in_bytes < temps, mem
+    assert f"f32[{ring}]" in compiled.as_text()   # the search had a ring to find
+
+
 def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
         topo, tpu_backend, capsys):
     """The fused step with the ``keye`` backbone in it, at the cell's size
@@ -242,7 +321,7 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     compiled = _compile_step("keye", capacity, capacity + 1, one, one)
     ring = ss.ring_size(capacity + 1, ss.default_events())
     mem = compiled.memory_analysis()
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.7e9 < mem.argument_size_in_bytes < 9.9e9, mem
     # 396,118,528 B (PR 37); 615,414,272 B with XLA's gather and sum (PR 35)
@@ -334,7 +413,7 @@ def test_latent_attention_step_fits_beside_the_state_and_holds_a_share(
               f"{mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.0e9 < mem.argument_size_in_bytes < 9.1e9, mem
     assert mem.temp_size_in_bytes <= 1_623_082_496, mem
@@ -397,7 +476,7 @@ def test_short_convolution_step_fits_beside_the_state_and_groups_its_experts(
               f"{mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.8e9 < mem.argument_size_in_bytes < 10.0e9, mem
     assert mem.temp_size_in_bytes <= 615_414_272, mem  # keye's bound
@@ -450,7 +529,7 @@ def test_state_space_step_fits_beside_the_state_and_holds_no_state(
               f"{mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 8.1e9 < mem.argument_size_in_bytes < 8.3e9, mem
     assert mem.temp_size_in_bytes <= FALCONH1_TEMPS_256, mem
@@ -516,7 +595,7 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
               f"{mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 8.3e9 < mem.argument_size_in_bytes < 8.5e9, mem
     assert mem.temp_size_in_bytes <= 2 * 2**30, mem
@@ -584,7 +663,7 @@ def test_hyper_connected_step_fits_beside_the_state_and_keeps_its_scopes(
               f"{mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 9.0e9 < mem.argument_size_in_bytes < 9.1e9, mem
     assert mem.temp_size_in_bytes <= XING_TEMPS_256, mem
@@ -651,7 +730,7 @@ def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
               f"v5e: code {mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 7.3e9 < mem.argument_size_in_bytes < 7.5e9, mem
     # 1.02 GB (PR 58; 1.31 while XLA's way back held a gathered float32 copy
@@ -692,7 +771,7 @@ def test_deep_window_step_sweeps_its_key_blocks_in_one_kernel_for_both_kinds(
     # no re-layout copy of the results at their pitch
     assert f"f32[{pairs},{cfg.hidden}]" not in text
     results = pairs * pitch * 128
-    assert _ring_sized_copies(compiled, results) == []
+    assert _ring_sized(compiled, results, only=("copy",)) == []
     assert "%ragged-dot-none" not in text
     _kernels_under(text, capsys, "mellum", scope="head/attn")
     _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
@@ -730,7 +809,7 @@ def test_narrowing_step_scans_in_one_kernel_a_layer_and_holds_the_model_whole(
               f"v5e: code {mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B, arguments "
               f"{mem.argument_size_in_bytes} B")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 8.2e9 < mem.argument_size_in_bytes < 8.4e9, mem
     assert mem.temp_size_in_bytes < 1.5e9, mem
@@ -782,18 +861,20 @@ def _assert_the_residual_path_is_two_passes(text, capsys, positions, cfg):
 # the four float32 streams (235 MB) once, written in place by the stream
 # kernels, beside a sublayer's own intermediates (931,794,432 B at PR 52,
 # where XLA's passes held the streams four times over). Read: 488,598,016 B;
-# the bound is four times the 64-row rung's 122,840,064 B, which the rung's
+# the bound is four times the 64-row rung's 122,904,576 B, which the rung's
 # test holds under a quarter of it (``phi`` turned columns-first, 1.4 MB a
-# sublayer, does not shrink with the rung).
-XING_TEMPS_256 = 491_360_256
+# sublayer, does not shrink with the rung; 122,840,064 B until PR 61 stood
+# the window's words behind their fence, 64 KB at 64 rows).
+XING_TEMPS_256 = 491_618_304
 
 
 # What the ``falconh1`` step holds in temporaries at the 256-row rung since PR
-# 55 (read: 627,736,576 B; 631,744,000 at PR 46 with the float32 passes between
-# the mixer's projections, which were never the peak: the MLP's two ``[4096,
-# 21504]`` float32 products are); the 64-row rung reads 139,813,888 B
-# (142,620,160 at PR 46).
-FALCONH1_TEMPS_256 = 627_736_576
+# 55 (read: 627,768,832 B since PR 61, the window's words behind their fence
+# 32 KB of it, 627,736,576 before; 631,744,000 at PR 46 with the float32
+# passes between the mixer's projections, which were never the peak: the MLP's
+# two ``[4096, 21504]`` float32 products are); the 64-row rung reads
+# 139,813,888 B (142,620,160 at PR 46).
+FALCONH1_TEMPS_256 = 627_768_832
 
 
 # What the ``ling`` step holds in temporaries at the 256-row rung since PR 51,
@@ -928,7 +1009,7 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
               f"{mem.generated_code_size_in_bytes} B, temporaries "
               f"{mem.temp_size_in_bytes} B (a quarter of the 256-row "
               f"step's: {temps_256 // 4} B)")
-    assert _ring_sized_copies(compiled, ring) == []
+    assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert mem.temp_size_in_bytes <= temps_256 // 4, mem
     # the in-tree kernels' calls carry `pallas_call` in their op_name;
@@ -949,16 +1030,19 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
 
 
 @pytest.mark.parametrize("head,sketch,sha256", [
-    ("pattern", False, "c51b7511f2159529"), ("pattern", True, "154ee60b366c28a6"),
-    ("transformer", False, "7037c7eb0e6f4958"),
-    ("transformer", True, "1e8ca471c94e4fe3")])
+    ("pattern", False, "65dd860d54d1204b"), ("pattern", True, "a8840c1b072e56ac"),
+    ("transformer", False, "177210630f848043"),
+    ("transformer", True, "1b5d6b7b954cff6f")])
 def test_the_small_heads_step_is_the_one_the_ledger_measured(head, sketch, sha256):
     """The two host-bound cells' fused step never traces the expert layer:
     its StableHLO, lowered on this sandbox's CPU at 256 rows and 5,242,880
     slots, is byte for byte what PR 34 to PR 37 read (PERF.md, section 6),
     which is how a PR that works on a backbone shows that those cells'
     device program did not move. A PR that changes that step on purpose
-    writes the new prefixes here, and says so in PERF.md."""
+    writes the new prefixes here, and says so in PERF.md. PR 61 did: the
+    window leaves ``session_state.windows_from_state`` as 32-bit words
+    behind one fence in every session step (c51b7511f2159529,
+    154ee60b366c28a6, 7037c7eb0e6f4958 and 1e8ca471c94e4fe3 until then)."""
     import hashlib
 
     capacity = 5_242_880

@@ -743,6 +743,89 @@ def test_ring_state_matches_numpy_model(k, seed):
 
 
 # ---------------------------------------------------------------------------
+# The window a head receives: the parent's arithmetic, handed over as words
+
+
+def _windows_as_the_parent_built_them(ring_rows, cur, ln, events, n_events):
+    """``session_state.windows_from_state`` as it stood before PR 61, word
+    for word, without the fence it ends in now."""
+    import jax.numpy as jnp
+
+    lp = jnp.minimum(ln + 1, n_events)
+    hist = lp - 1
+    k = jnp.arange(n_events)[None, :]
+    pos = jnp.mod(cur[:, None] - hist[:, None] + k, n_events)
+    win = jnp.take_along_axis(ring_rows, pos[..., None], axis=1)
+    keep = (k < hist[:, None])[..., None]
+    win = jnp.where(keep, win, 0.0)
+    at_event = (k == hist[:, None])[..., None]
+    win = jnp.where(at_event, events[:, None, :], win)
+    return win, lp
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jitted"])
+@pytest.mark.parametrize("n_events", [16, 128])
+@pytest.mark.parametrize("seed", [5, 2_271_560_481])
+def test_windows_are_bit_for_bit_the_parents(seed, n_events, jitted):
+    """A seeded ring, cursor and length (a cold row of length 0, a row of
+    one event, a full row whose cursor wrapped, a full row at cursor 0, the
+    rest drawn; the ring holds negative zeros, denormals, an infinity and a
+    NaN's payload, which a pass through another type would not keep): the
+    window and the post-append length are the parent's, bit for bit and in
+    float32, against its arithmetic kept above and against a numpy ring."""
+    d = session_mod.EVENT_WIDTH
+    rng = np.random.default_rng(seed)
+    b = 9
+    rows = rng.standard_normal((b, n_events, d)).astype(np.float32)
+    rows[0, 0, :4] = [-0.0, 1e-42, np.inf, 0.1]
+    rows[2, 1, 0] = np.frombuffer(np.uint32(0x7FC12345).tobytes(), np.float32)[0]
+    cur = rng.integers(0, n_events, b).astype(np.int32)
+    ln = rng.integers(0, n_events + 1, b).astype(np.int32)
+    cur[:4] = [0, 1, 3, 0]
+    ln[:4] = [0, 1, n_events, n_events]
+    events = rng.standard_normal((b, d)).astype(np.float32)
+
+    fn = session_mod.windows_from_state
+    if jitted:
+        fn = jax.jit(fn, static_argnums=4)
+    win, lp = fn(rows, cur, ln, events, n_events)
+    was, was_lp = _windows_as_the_parent_built_them(rows, cur, ln, events, n_events)
+    assert win.dtype == np.float32 and win.shape == (b, n_events, d)
+    bits = np.asarray(win).view(np.uint32)
+    np.testing.assert_array_equal(bits, np.asarray(was).view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(lp), np.asarray(was_lp))
+
+    want = np.zeros((b, n_events, d), np.float32)
+    for i in range(b):
+        hist = min(int(ln[i]), n_events - 1)
+        for j in range(hist):
+            want[i, j] = rows[i, (cur[i] - hist + j) % n_events]
+        want[i, hist] = events[i]
+        assert int(lp[i]) == hist + 1
+    np.testing.assert_array_equal(bits, want.view(np.uint32))
+    assert not want[0, 1:].any() and (want[0, 0] == events[0]).all()  # the cold row
+
+
+def test_the_window_leaves_as_words_behind_one_fence():
+    """What holds the cast of a head's first product behind the gather
+    (session_state "the ring's at-rest layout"): the lowered function ends
+    in ``bitcast_convert`` to 32-bit words, one ``optimization_barrier`` on
+    them, and ``bitcast_convert`` back."""
+    b, n, d = 4, 128, session_mod.EVENT_WIDTH
+    f32, i32 = np.float32, np.int32
+    text = jax.jit(session_mod.windows_from_state, static_argnums=4).lower(
+        jax.ShapeDtypeStruct((b, n, d), f32), jax.ShapeDtypeStruct((b,), i32),
+        jax.ShapeDtypeStruct((b,), i32), jax.ShapeDtypeStruct((b, d), f32),
+        n).as_text()
+    words = f"tensor<{b}x{n}x{d}xui32>"
+    assert text.count("stablehlo.optimization_barrier") == 1
+    fence = [line for line in text.splitlines() if "optimization_barrier" in line]
+    assert words in fence[0], fence
+    assert text.count("stablehlo.bitcast_convert") == 2
+    assert "bf16" not in text
+
+
+# ---------------------------------------------------------------------------
 # Fused-step bit-exactness vs host reference at ladder shapes
 
 
