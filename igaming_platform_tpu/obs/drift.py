@@ -983,7 +983,7 @@ class DriftEngine:
         return snap
 
     def summary_block(self) -> dict:
-        """Compact per-arm artifact block (bench.py / soak harnesses)."""
+        """Compact artifact block (the drills, tools/drills/load_gen.py)."""
         snap = self.snapshot()
         return {
             "window_rows": snap["window"]["rows"],

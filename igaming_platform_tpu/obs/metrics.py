@@ -694,7 +694,7 @@ class ServiceMetrics:
             "report (CPU) — plus {shard, table} series for the "
             "slot-sharded state tables (feature_cache / session_ring "
             "bytes per mesh shard, the per-chip capacity accounting of "
-            "docs/performance.md 'Sharded state')",
+            "docs/architecture.md 'Sharded state')",
         )
         # Online learning loop (train/online.py, serve/shadow.py,
         # train/promote.py): shadow-scoring evidence, mined training
@@ -956,7 +956,7 @@ class ServiceMetrics:
             "gather/cache_lookup/pad/dispatch/readback/session/"
             "ledger_note/encode), from the monotonic span clock; bucket "
             "lines carry trace-id exemplars — the per-row capacity "
-            "figure docs/performance.md 'Reading a host flamegraph' "
+            "figure docs/architecture.md 'Reading a host flamegraph' "
             "explains",
             buckets=(0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 25, 50, 100, 250),
         )

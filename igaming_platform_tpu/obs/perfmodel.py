@@ -1,14 +1,9 @@
-"""FLOPs/bytes cost model + device peaks: turns bench timings into
-MFU / HBM-utilization figures so "fast" is normalized against what the
-hardware can do (the reference publishes no such figures at all —
-BASELINE.md; these make "matching-or-beating" auditable).
+"""Device peaks and the online step-time model.
 
-Costs come from XLA's own cost analysis of the compiled executable
-(``compiled.cost_analysis()``: ``flops`` and ``bytes accessed``) rather
-than hand-derived formulas, so they track the actual fused program.
-Peaks are a small per-``device_kind`` table of published chip specs;
-unknown kinds (e.g. a CPU rig) report achieved rates with null
-utilization instead of inventing a denominator.
+Peaks are a small per-``device_kind`` table of published chip specs
+(``chip_smoke.py`` refuses a device that is not in it); the benchmark's
+roofline shares are computed in ``chipbench/`` from its own cost files.
+``OnlineStepModel`` is what the deadline scheduler plans with.
 """
 
 from __future__ import annotations
@@ -37,61 +32,6 @@ def peak_for(device: Any) -> tuple[float, float] | None:
         if key in kind:
             return peaks
     return None
-
-
-def compiled_cost(compiled: Any) -> dict[str, float]:
-    """{"flops": F, "bytes": B} per execution of a compiled executable,
-    from XLA's cost analysis; zeros when the backend exposes none."""
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — cost analysis is best-effort
-        cost = {}
-    return {
-        "flops": float(cost.get("flops", 0.0)),
-        "bytes": float(cost.get("bytes accessed", 0.0)),
-    }
-
-
-def cost_of(fn: Any, *example_args, **lower_kwargs) -> dict[str, float]:
-    """Lower+compile ``fn`` (a jax-jittable callable or an existing
-    jitted wrapper) on example args and return its per-call cost."""
-    import jax
-
-    wrapped = fn if hasattr(fn, "lower") else jax.jit(fn)
-    compiled = wrapped.lower(*example_args, **lower_kwargs).compile()
-    return compiled_cost(compiled)
-
-
-def utilization(
-    cost: dict[str, float], seconds_per_call: float, device: Any
-) -> dict[str, float | None]:
-    """Achieved rates + utilization vs the device's published peaks.
-
-    Returns achieved_tflops / achieved_hbm_gbps always (when the cost
-    model has the numerator), and mfu / hbm_util only when the device
-    kind has a known peak — a CPU-rig line carries nulls rather than a
-    made-up denominator.
-    """
-    out: dict[str, float | None] = {
-        "achieved_tflops": None, "achieved_hbm_gbps": None,
-        "mfu": None, "hbm_util": None,
-    }
-    if not seconds_per_call > 0.0:  # also catches NaN (below-resolution)
-        return out
-    flops_s = cost.get("flops", 0.0) / seconds_per_call
-    bytes_s = cost.get("bytes", 0.0) / seconds_per_call
-    if flops_s > 0:
-        out["achieved_tflops"] = round(flops_s / 1e12, 4)
-    if bytes_s > 0:
-        out["achieved_hbm_gbps"] = round(bytes_s / 1e9, 2)
-    peaks = peak_for(device)
-    if peaks is not None:
-        peak_flops, peak_hbm = peaks
-        if flops_s > 0:
-            out["mfu"] = round(flops_s / peak_flops, 4)
-        if bytes_s > 0:
-            out["hbm_util"] = round(bytes_s / peak_hbm, 4)
-    return out
 
 
 class OnlineStepModel:
@@ -180,42 +120,3 @@ class OnlineStepModel:
                 "ewma_ms": {str(k): round(v, 4)
                             for k, v in sorted(self._ewma_ms.items())},
             }
-
-
-def device_step_time(fn, *args, n: int = 17, reps: int = 3) -> float:
-    """Per-step device time (seconds) for a jitted ``fn(*args)``.
-
-    Dispatch is asynchronous, so timing a loop of dispatches measures
-    the enqueue, and a per-step fence folds the constant dispatch and
-    readback cost into every step. This is a TWO-POINT fit with a real
-    data readback as the fence: time 1 dispatch + device_get, time ``n``
-    dispatches + device_get of only the last result, and take the slope.
-    Per-device execution is in-order under PJRT, so the n dispatches
-    execute back-to-back and the difference is (n-1) steps of pure
-    device time — the constant dispatch overhead and the readback
-    latency cancel.
-    """
-    import time as _t
-
-    import jax as _jax
-
-    _jax.device_get(fn(*args))  # compile + warm the readback path
-
-    def total(k: int) -> float:
-        best = float("inf")
-        for _ in range(reps):
-            t0 = _t.perf_counter()
-            for _ in range(k - 1):
-                fn(*args)
-            _jax.device_get(fn(*args))
-            best = min(best, _t.perf_counter() - t0)
-        return best
-
-    diff = total(n) - total(1)
-    if diff <= 0:
-        # Per-step time is below the fence's timing noise (a tiny
-        # elementwise op behind a much longer readback). A clamp would
-        # publish a nonsense rate — return NaN so callers report "below
-        # timing resolution" instead.
-        return float("nan")
-    return diff / (n - 1)

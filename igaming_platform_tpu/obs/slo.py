@@ -400,7 +400,7 @@ class SLOEngine:
         }
 
     def summary_block(self) -> dict:
-        """Compact per-arm artifact block (bench.py / load_gen)."""
+        """Compact artifact block (tools/drills/load_gen.py)."""
         snap = self.snapshot()
         fast = snap["windows"]["fast"]
         return {

@@ -7,8 +7,8 @@ them a FIRST-CLASS INPUT: a fault plan is a seed plus a list of (seam,
 fault) specs, injected at well-known choke points on the serving path, so
 recovery behaviour (supervisor breakers, follower resurrection, degraded
 scoring) becomes something tests assert and soaks measure — availability
-during fault and time-to-recovery land in `CHAOS_r06.json` artifacts
-instead of war stories.
+during fault and time-to-recovery land in the drills' artifacts
+(tools/drills/soak.py) instead of war stories.
 
 Seams (each a single ``chaos.fire(seam)`` call at the choke point):
 
@@ -32,8 +32,8 @@ Seams (each a single ``chaos.fire(seam)`` call at the choke point):
 Fleet-level *process* faults — replica SIGKILL (pod death) and replica
 wedge (SIGSTOP, the process stops answering but the sockets stay open) —
 cannot be fired from inside the victim: they are scheduled by the fleet
-harness (``benchmarks/fleet.py`` ``FleetFaultSchedule``, driven by
-``benchmarks/soak.py --fleet-chaos``) and recorded in the FLEET_CHAOS
+harness (``tools/drills/fleet.py`` ``FleetFaultSchedule``, driven by
+``tools/drills/soak.py --fleet-chaos``) and recorded in that drill's
 artifact next to the seam injections above.
 
 Fault kinds: ``delay`` (sleep ``ms``), ``wedge`` (a LONG sleep — a step

@@ -26,7 +26,8 @@ Semantics:
 - time-derived features (TIME_SINCE_LAST_TX, SESSION_DURATION, velocity
   windows) are exact as of the last delta and drift with wall time
   between events — `max_age_s` bounds that drift by treating older rows
-  as misses (see docs/performance.md for the staleness story);
+  as misses (docs/architecture.md, "The device-resident feature cache",
+  has the staleness story);
 - slot reclamation is CLOCK (second-chance): one reference bit per
   slot, a rotating hand, O(1) amortized per admission;
 - `flags` is a per-slot sticky bool column (e.g. account-level block

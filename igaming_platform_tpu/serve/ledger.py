@@ -1374,7 +1374,7 @@ class DecisionLedger:
         return stats
 
     def stats_block(self) -> dict:
-        """The ``ledger_block`` artifact shape (load_gen / bench)."""
+        """The ``ledger_block`` artifact shape (tools/drills/load_gen.py)."""
         s = self.stats()
         return {
             "records_appended": s["records_appended"],

@@ -36,10 +36,9 @@ Podracer pod-as-unit-of-failure topology. Three pieces:
   ``risk_hedge_total{outcome}``.
 
 The equivalent *client-side* picker (no extra hop) lives here too
-(:class:`AccountAffinityPicker`) and is what ``benchmarks/load_gen.py
---fleet`` drives; ``benchmarks/fleet.py`` spawns the replica processes
-and ``benchmarks/soak.py --fleet-chaos`` kills them under load
-(FLEET_CHAOS_r07.json).
+(:class:`AccountAffinityPicker`) and is what ``tools/drills/load_gen.py
+--fleet`` drives; ``tools/drills/fleet.py`` spawns the replica processes
+and ``tools/drills/soak.py --fleet-chaos`` kills them under load.
 """
 
 from __future__ import annotations

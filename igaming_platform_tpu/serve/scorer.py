@@ -1695,19 +1695,6 @@ class TPUScoringEngine:
                 cat["ml_score"], rtms, x if include_features else None,
             )
 
-    def step_cost(self, n_rows: int | None = None) -> dict[str, float]:
-        """XLA FLOPs/bytes per execution of the compiled packed score
-        step at the ladder shape fitting ``n_rows`` (obs/perfmodel) —
-        the numerator for bench utilization figures."""
-        from igaming_platform_tpu.obs.perfmodel import cost_of
-
-        shape = self._pick_shape(n_rows or self.batch_size)
-        x = np.zeros((shape, NUM_FEATURES), dtype=self._wire_dtype)
-        bl = np.zeros((shape,), dtype=bool)
-        with self._params_lock:
-            params = self._params
-        return cost_of(self._packed_fn, params, x, bl, self._thresholds)
-
     # -- raw array path (bench / replay) -------------------------------------
 
     def score_arrays(self, x: np.ndarray, blacklisted: np.ndarray | None = None) -> dict:

@@ -155,7 +155,7 @@ def windows_from_state(ring_rows, cur, ln, events, n_events: int):
 # the slot axis minor-most precisely to avoid padding 16 and 12 to a
 # tile) and, unlike every shaped layout tried, its windowed gather and
 # scatter compile IN PLACE on the TPU: a step moves O(batch) bytes, not
-# two re-layout copies of the whole ring (docs/performance.md "Session
+# two re-layout copies of the whole ring (docs/architecture.md "Session
 # ring layout" has the table of compiles). That is also why a window
 # leaves ``windows_from_state`` as uint32 words behind an optimization
 # barrier: a head's first product casts its operand to bfloat16, and the

@@ -5,7 +5,7 @@ device step that never returns, dead multihost followers (previously
 "fails every RPC until the mesh is rebuilt" — and no rebuild existed),
 and feature-store/broker flaps. The compliance-grade fraud-serving
 posture is that a fraud scorer must degrade to a CONSERVATIVE answer
-rather than go dark — `ABUSE_DEGRADED_r05.json` measured the CPU
+rather than go dark — `tools/abuse_degraded_eval.py` measured the CPU
 heuristic tier at precision 1.0 / recall 0.37, good enough to keep
 catching the blatant patterns with zero false accusations while the
 device path heals.
@@ -30,7 +30,7 @@ Three layers:
 
 Chaos plans (serve/chaos.py) inject faults at exactly the seams these
 breakers guard, so tests/test_supervisor_chaos.py and
-``benchmarks/soak.py --chaos`` measure the healing instead of assuming it.
+``tools/drills/soak.py --chaos`` measure the healing instead of assuming it.
 """
 
 from __future__ import annotations
@@ -427,8 +427,8 @@ def heuristic_scores(x: np.ndarray, bl: np.ndarray,
     the class of scalar signals the reference itself ships
     (engine.go:420-483), same result-dict contract as the compiled step.
 
-    Deliberately biased toward precision (the `ABUSE_DEGRADED_r05.json`
-    posture): every rule is a blatant-pattern match, so a degraded window
+    Deliberately biased toward precision (the posture
+    `tools/abuse_degraded_eval.py` measures): every rule is a blatant-pattern match, so a degraded window
     blocks the obvious fraud and approves the rest rather than guessing —
     recall is what the device tier is for."""
     x = np.asarray(x, dtype=np.float32)

@@ -12,7 +12,7 @@ gate said what. Consumers:
 - ``train/promote.py`` — the online promotion controller's admit/rollback
   decisions (thresholds overridable per-deployment via ``PROMOTE_*``
   env vars, the same pattern as the SLO plane's ``SLO_*``);
-- ``benchmarks/soak.py --online-chaos`` — the ONLINE_r10 gate table;
+- ``tools/drills/soak.py --online-chaos`` — that drill's gate table;
 - ``tests/test_eval.py`` / ``tests/test_online_promotion.py``.
 """
 

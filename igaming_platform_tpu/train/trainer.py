@@ -213,16 +213,6 @@ class Trainer:
                 log_fn(self.state.step, self.materialize_metrics(metrics))
         return self.materialize_metrics(metrics)
 
-    def step_cost(self, batch: Batch) -> dict[str, float]:
-        """XLA's per-step FLOPs/bytes for this trainer's compiled step
-        (obs/perfmodel) — the numerator for MFU reporting."""
-        from igaming_platform_tpu.obs.perfmodel import compiled_cost
-
-        lowered = self._step_fn.lower(
-            self.state.params, self.state.opt_state, *self.put_batch(batch)
-        )
-        return compiled_cost(lowered.compile())
-
     def export_params(self):
         """Hand the live params to the serving engine (zero-copy on the
         same devices; the engine wraps them in {"mlp"-style} dict itself)."""

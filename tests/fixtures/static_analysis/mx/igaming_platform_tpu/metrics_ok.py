@@ -12,7 +12,7 @@ lat = registry.histogram("latency_ms", "Request latency in milliseconds")
 
 def timed_dispatch(fn, x):
     # Timing dispatch WITHOUT block_until_ready inside the clock
-    # bracket is fine (two-point fences live in obs/perfmodel.py).
+    # bracket is fine.
     t0 = time.perf_counter()
     y = fn(x)
     t1 = time.perf_counter()

@@ -203,3 +203,50 @@ def test_rollback_does_not_poison_statement_cache(pg):
     conn.execute("INSERT INTO pc VALUES (?)", (2,))
     assert conn.execute("SELECT COUNT(*) FROM pc").fetchone()[0] == 1
     conn.close()
+
+
+def test_wallet_verbs_over_grpc_on_the_postgres_store(pg):
+    """wallet.v1 over a real socket -> handler -> WalletService ->
+    PostgresStore -> the protocol-v3 server: the one stack no other test
+    drives end to end (the gRPC tests run on the in-memory repositories,
+    the tests above call the service directly). Held to its books, not to
+    a rate."""
+    import grpc
+
+    from igaming_platform_tpu.proto_gen.wallet.v1 import wallet_pb2
+    from igaming_platform_tpu.serve.grpc_server import (
+        WalletGrpcService,
+        make_wallet_stub,
+        serve_wallet,
+    )
+
+    store = PostgresStore(pg.url)
+    server, _, port = serve_wallet(WalletGrpcService(_wallet(store)), 0)
+    channel = grpc.insecure_channel(f"localhost:{port}")
+    stub = make_wallet_stub(channel)
+    try:
+        acct = stub.CreateAccount(wallet_pb2.CreateAccountRequest(
+            player_id="wire-pg", currency="USD"), timeout=30).account
+        dep = stub.Deposit(wallet_pb2.DepositRequest(
+            account_id=acct.id, amount=10_000, idempotency_key="wire-dep"),
+            timeout=30)
+        assert dep.new_balance == 10_000
+        stub.Bet(wallet_pb2.BetRequest(
+            account_id=acct.id, amount=1_000, idempotency_key="wire-bet",
+            game_id="g1"), timeout=30)
+        stub.Win(wallet_pb2.WinRequest(
+            account_id=acct.id, amount=250, idempotency_key="wire-win",
+            game_id="g1"), timeout=30)
+        # The same key again is the same transaction, not a second one.
+        again = stub.Deposit(wallet_pb2.DepositRequest(
+            account_id=acct.id, amount=10_000, idempotency_key="wire-dep"),
+            timeout=30)
+        assert again.transaction.id == dep.transaction.id
+        bal = stub.GetBalance(wallet_pb2.GetBalanceRequest(
+            account_id=acct.id), timeout=30)
+        assert bal.balance == 9_250
+        assert store.ledger.verify_balance(acct.id, bal.balance)
+    finally:
+        channel.close()
+        server.stop(0)
+        store.close()

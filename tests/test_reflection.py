@@ -11,7 +11,7 @@ from igaming_platform_tpu.proto_gen.grpc.reflection.v1alpha import reflection_pb
 from igaming_platform_tpu.serve.reflection import SERVICE_NAME, reflection_handler
 
 # Imported for their descriptor-pool registration side effect (the
-# underscore alias marks a deliberate side-effect import for tools/lint.py).
+# underscore alias marks a deliberate side-effect import for the analyzer).
 from igaming_platform_tpu.proto_gen.risk.v1 import risk_pb2 as _risk_pb2  # noqa: F401
 from igaming_platform_tpu.proto_gen.wallet.v1 import wallet_pb2 as _wallet_pb2  # noqa: F401
 

@@ -419,11 +419,8 @@ def test_load_gen_retry_helper_honors_pushback(monkeypatch):
     """The satellite fix: the client retry path consumes the server's
     grpc-retry-pushback-ms hint (PR 5 emitted it; no in-tree client
     respected it) with a jittered bounded sleep, counted in the stats."""
-    import sys as _sys
-    from pathlib import Path
-
-    _sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmarks"))
-    from load_gen import _RetryStats, _call_with_retry
+    from tools.drills import load_gen
+    from tools.drills.load_gen import _RetryStats, _call_with_retry
 
     calls = {"n": 0}
 
@@ -454,8 +451,6 @@ def test_load_gen_retry_helper_honors_pushback(monkeypatch):
                           request_serializer=lambda b: b,
                           response_deserializer=lambda b: b)
     try:
-        import load_gen
-
         slept: list[float] = []
         real_sleep = time.sleep
 

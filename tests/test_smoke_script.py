@@ -1,4 +1,4 @@
-"""`make api-test` (benchmarks/smoke.py) stays green against live
+"""`make api-test` (tools/drills/smoke.py) stays green against live
 risk + wallet servers — the reference's grpcurl smoke surface."""
 
 import os
@@ -11,10 +11,7 @@ from igaming_platform_tpu.core.config import (
     WalletServiceConfig,
 )
 
-_SMOKE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks", "smoke.py",
-)
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_api_smoke_against_live_services():
@@ -32,9 +29,9 @@ def test_api_smoke_against_live_services():
             grpc_port=0, http_port=0,
         )
         proc = subprocess.run(
-            [sys.executable, _SMOKE,
+            [sys.executable, "-m", "tools.drills.smoke",
              f"localhost:{risk.grpc_port}", f"localhost:{wallet.grpc_port}"],
-            capture_output=True, text=True, timeout=120,
+            capture_output=True, text=True, timeout=120, cwd=_REPO,
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
         assert "FAIL" not in proc.stdout

@@ -51,9 +51,6 @@ from igaming_platform_tpu.serve.supervisor import (
     heuristic_scores,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
 @pytest.fixture(autouse=True)
 def _clear_chaos():
     chaos.clear()
@@ -580,8 +577,7 @@ def test_graceful_stop_drains_admitted_requests_under_load():
 
 
 def test_availability_block_accounting():
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    from load_gen import availability_block
+    from tools.drills.load_gen import availability_block
 
     t0 = 100.0
     events = []

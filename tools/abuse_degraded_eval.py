@@ -41,7 +41,7 @@ def _metrics(y: np.ndarray, pred: np.ndarray) -> dict:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="ABUSE_DEGRADED_r05.json")
+    ap.add_argument("--out", default="build/ABUSE_DEGRADED_r05.json")
     ap.add_argument("--n-test", type=int, default=1024)
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--threshold", type=float, default=0.5)
@@ -99,6 +99,7 @@ def main() -> None:
             "responses); same held-out labeled sequences for both paths"
         ),
     }
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result))

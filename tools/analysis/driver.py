@@ -28,8 +28,8 @@ from tools.analysis.engine import (
 )
 
 REPO_ROOT = Path(__file__).resolve().parent.parent.parent
-ROOTS = ("igaming_platform_tpu", "benchmarks", "tests", "tools")
-TOP_FILES = ("bench.py", "__graft_entry__.py", "chip_smoke.py")
+ROOTS = ("igaming_platform_tpu", "tests", "tools")
+TOP_FILES = ("__graft_entry__.py", "chip_smoke.py")
 # proto_gen is generated; the fixture corpus under tests/ is a zoo of
 # deliberate violations the driver must not trip over in repo mode.
 EXCLUDED_PARTS = {"proto_gen", "fixtures"}
@@ -51,13 +51,13 @@ REPO_CONFIG = {
     # the harnesses that assemble engines.
     "paramswap_scope": (
         "igaming_platform_tpu/serve/", "igaming_platform_tpu/train/",
-        "benchmarks/", "tools/", "bench.py",
+        "tools/",
     ),
     # CC08 session-state-mutation discipline: anywhere the session ring
     # state could be rebound — the serving layer plus the harnesses and
     # tools that assemble session-enabled engines.
     "sessionstate_scope": (
-        "igaming_platform_tpu/serve/", "benchmarks/", "tools/",
+        "igaming_platform_tpu/serve/", "tools/",
     ),
     # MX07 bounded-handoff findings stay inside the production serving +
     # observability code (the reachability walk itself crosses files).

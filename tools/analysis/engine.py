@@ -1,7 +1,7 @@
 """Rule engine for the in-tree static analyzer.
 
-The analyzer grew out of ``tools/lint.py`` (a single file of inlined
-checks) into a framework: each check is a :class:`Rule` with a stable ID
+The analyzer grew out of a single file of inlined checks into a
+framework: each check is a :class:`Rule` with a stable ID
 (``JX*`` jit/tracing, ``CC*`` concurrency, ``MX*`` metrics/measurement,
 ``PY*`` general hygiene), every file is parsed exactly once into a
 :class:`FileContext`, and cross-file rules see the whole parse forest

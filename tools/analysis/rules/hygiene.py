@@ -1,4 +1,4 @@
-"""PY* — general hygiene rules, ported from the original tools/lint.py.
+"""PY* — general hygiene rules, ported from the original single-file linter.
 
 Behavior is unchanged from the single-file linter except that
 suppression is now rule-scoped (PY06 makes a blanket ``# noqa`` itself a

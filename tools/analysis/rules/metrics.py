@@ -1,6 +1,6 @@
 """MX* — metrics and measurement-integrity rules.
 
-Ports of the round-5/PR-2 checks from tools/lint.py, behavior-preserving
+Ports of the round-5/PR-2 checks of the single-file linter, behavior-preserving
 except for one deliberate fix (ISSUE 3 satellite): the help-text check
 used to require the metric *name* to be a positional string literal, so
 ``registry.counter(name="x", help_text="")`` — or any non-literal name,
@@ -68,12 +68,9 @@ def _calls_by_scope(tree: ast.Module) -> dict[int, list[ast.Call]]:
 @rule("MX01", "timed-block-until-ready",
       "block_until_ready() bracketed by clock reads folds dispatch and "
       "readback overhead into the figure (or, unfenced in a loop, times "
-      "the enqueue); every step timing must go through "
-      "obs/perfmodel.device_step_time's two-point readback fence. Only "
-      "obs/perfmodel.py may time that way.")
+      "the enqueue); a step's time is read from the profiler's trace "
+      "(chipbench/trace_reduce.py), never from a host stopwatch.")
 def timed_block_until_ready(ctx: FileContext):
-    if ctx.path.name == "perfmodel.py" and ctx.path.parent.name == "obs":
-        return
     if "block_until_ready" not in ctx.src:
         return  # cheap text prescreen before the scope traversal
     for calls in _calls_by_scope(ctx.tree).values():
@@ -91,9 +88,9 @@ def timed_block_until_ready(ctx: FileContext):
         for line in bur_lines:
             if lo < line < hi:
                 yield line, (
-                    "block_until_ready() inside a timed region — step "
-                    "timings go through the two-point readback fence; use "
-                    "obs/perfmodel.device_step_time")
+                    "block_until_ready() inside a timed region — read a "
+                    "step's time from the profiler's trace "
+                    "(chipbench/trace_reduce.py)")
 
 
 def _help_argument(node: ast.Call) -> ast.AST | None:

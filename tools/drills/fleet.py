@@ -1,9 +1,7 @@
 """Scoring-replica fleet rig: N risk-server OS processes + fault schedule.
 
-The wallet already has a replica harness (benchmarks/replicas.py: K
-stateless wallet processes over one Postgres). This is the SCORING
-fleet's equivalent, and the unit of failure is the replica process — the
-Podracer pod-as-unit topology: each replica is a full production-wired
+The unit of failure is the replica process — the Podracer pod-as-unit
+topology: each replica is a full production-wired
 risk server (supervised engine, gRPC + health, HTTP sidecar with
 /debug/supervisorz), booted as its own OS process, killed/wedged/
 restarted by the harness while a router (serve/router.py) or client-side
@@ -23,7 +21,7 @@ Parsed from a plan string (``FLEET_FAULTS`` env in soak --fleet-chaos)::
 
     kill:replica=1:at=8; restart:replica=1:at=16; wedge:replica=2:at=20
 
-Driven by ``benchmarks/soak.py --fleet-chaos`` -> FLEET_CHAOS_r07.json.
+Driven by ``python -m tools.drills.soak --fleet-chaos``.
 """
 
 from __future__ import annotations
@@ -36,9 +34,8 @@ import sys
 import threading
 import time
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 # ---------------------------------------------------------------------------
@@ -110,12 +107,12 @@ class ReplicaProc:
     def spawn(self, grpc_port: int = 0, http_port: int = 0) -> "ReplicaProc":
         env = dict(os.environ, JAX_PLATFORMS="cpu", **self.env_extra)
         self.proc = subprocess.Popen(
-            [sys.executable, os.path.abspath(__file__), "--replica",
+            [sys.executable, "-m", "tools.drills.fleet", "--replica",
              "--port", str(grpc_port), "--http-port", str(http_port),
              "--ml-backend", self.ml_backend,
              "--batch", str(self.batch_size)],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-            env=env)
+            env=env, cwd=REPO)
         deadline = time.monotonic() + self.boot_timeout_s
         line = ""
         while time.monotonic() < deadline:

@@ -1,14 +1,13 @@
-"""gRPC load generator — the end-to-end wire-path benchmark.
+"""gRPC load generator — the client rig of the fault drills.
 
-Measures what a client actually sees: risk.v1 ScoreBatch RPCs over a real
+Drives what a client actually sends: risk.v1 ScoreBatch RPCs over a real
 gRPC socket, through request decode, the (native) feature-store gather,
-the compiled device step, and the native response encoder — txns/s
-sustained at ingress plus RPC-level p50/p99. This is the number VERDICT
-round 1 asked for: the serving path, not the device path
-(engine.go:262-323 is the matching reference surface; its README claims
-< 50 ms per scoring call).
+the compiled device step, and the native response encoder, and counts
+what came back (errors by status code, sheds, retries, availability per
+window). On this rig's CPU its rates and percentiles say that traffic
+flowed, never how fast the system is: the benchmark is ``chipbench/``.
 
-Run standalone:  python benchmarks/load_gen.py [addr] [--wire-mode=row|index]
+Run standalone:  python -m tools.drills.load_gen [addr] [--wire-mode=row|index]
 (no addr: starts an in-process server on a free port with the native
 feature store and the multitask backend — the production wiring).
 
@@ -43,11 +42,9 @@ import uuid
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import grpc
 
-import grpc  # noqa: E402
-
-from igaming_platform_tpu.proto_gen.risk.v1 import risk_pb2  # noqa: E402
+from igaming_platform_tpu.proto_gen.risk.v1 import risk_pb2
 
 
 def _build_request_payloads(
@@ -400,7 +397,7 @@ def run_grpc_load(
     shed = [0]
     retry_stats = _RetryStats()
     # Failures broken down by gRPC status code: a single opaque counter
-    # (1236 in BENCH_r05) cannot tell DEADLINE_EXCEEDED backpressure from
+    # cannot tell DEADLINE_EXCEEDED backpressure from
     # UNAVAILABLE crashes at a glance. Guarded by errors_lock — worker
     # threads share the dict.
     errors_by_code: dict[str, int] = {}
@@ -472,7 +469,7 @@ def run_grpc_load(
                     metadata, retry_stats, retry_rng)
             except grpc.RpcError as exc:
                 # Shed vs failure must not conflate (the soak harness's
-                # discipline, benchmarks/soak.py): RESOURCE_EXHAUSTED is
+                # discipline, tools/drills/soak.py): RESOURCE_EXHAUSTED is
                 # the admission gate's LOUD backpressure — the bulk
                 # caller's contract is retry-with-backoff — while any
                 # other status is a real serving failure. Folding sheds
@@ -632,7 +629,7 @@ def run_paced_load(
     Every request carries ``risk-deadline-ms: deadline_ms`` — the
     deadline scheduler's admission contract — and the artifact counts
     ``scored_after_deadline``: OK responses that arrived after their
-    budget (the server should have shed them; the DEADLINE_r12 gate
+    budget (the server should have shed them; the deadline drill's gate
     pins this at zero).
     """
     rng = np.random.default_rng(seed)
@@ -911,7 +908,7 @@ def main() -> None:
         elif arg == "--pace":
             raise SystemExit("use --pace=RATE_RPS")
         elif arg == "--pace-gates":
-            # make bench-paced: exit non-zero unless p99 < the SLO bound
+            # Exit non-zero unless p99 < the SLO bound
             # and zero requests were scored after their deadline.
             pace_gates = True
         elif arg.startswith("--drift-ramp="):
@@ -963,11 +960,11 @@ def main() -> None:
                 p99 = paced.get("rpc_p99_ms")
                 if p99 is None or p99 >= bound:
                     raise SystemExit(
-                        f"bench-paced gate FAILED: p99 {p99} ms >= "
+                        f"paced gate FAILED: p99 {p99} ms >= "
                         f"{bound} ms bound")
                 if paced.get("scored_dead", 0) != 0:
                     raise SystemExit(
-                        "bench-paced gate FAILED: "
+                        "paced gate FAILED: "
                         f"{paced['scored_dead']} requests "
                         "scored after their deadline")
         finally:

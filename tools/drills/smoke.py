@@ -3,15 +3,13 @@ api-test` grpcurl calls (/root/reference/Makefile:231-241), as python
 stubs (the image has no grpcurl; the servers do expose reflection-free
 generic handlers, so stubs come from the shared method tables).
 
-Usage: python benchmarks/smoke.py [risk_addr] [wallet_addr]
+Usage: python -m tools.drills.smoke [risk_addr] [wallet_addr]
 Defaults: localhost:50052 / localhost:50051; wallet checks are skipped
 when no wallet server is listening.
 """
 
 import sys
 import uuid
-
-sys.path.insert(0, __import__("os").path.dirname(__import__("os").path.dirname(__import__("os").path.abspath(__file__))))
 
 import grpc
 

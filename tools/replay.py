@@ -9,8 +9,8 @@ feature snapshot, and diffs the outputs BIT-EXACT — score, action,
 reason mask, rule score, and the ml score's IEEE-754 bits. Decisions
 taken in the DEGRADED_CPU_HEURISTIC tier replay through the SAME
 conservative scorer (serve/supervisor.heuristic_scores), so a chaos
-window's answers are provable, not just available. The verdict lands in
-a ``REPLAY_r08.json``-shaped artifact.
+window's answers are provable, not just available. The verdict is the
+``replay`` block of the ledger drill's artifact (tools/drills/soak.py).
 
 Pinned checkpoint: by default the repo's seeded convention (multitask
 params from ``jax.random.key(0)``, the same init every serving harness
