@@ -724,7 +724,9 @@ def phase_kernels(interpret: bool = False, *,
     kernel's grouped-query form at the ``keye`` head's attention (32 query
     / 4 key heads of 128, a random mask with the diagonal kept) on
     ``grouped_windows`` windows of 16 against the einsum core, with the
-    core a trace would pick there; and the delta-rule window kernel at the
+    core a trace would pick there, and handed no mask (as the ``keye`` cells
+    call it since PR 63: the indexer not traced) to the bits of the causal
+    mask handed over; and the delta-rule window kernel at the
     ``ling`` head's mixer (32 heads of 128; the taps, the decay and the head
     norm inside) on ``delta_windows`` windows of 16 against the mixer's XLA
     path (``kda_one_chunk`` its core), with the core a trace would pick
@@ -945,11 +947,24 @@ def phase_kernels(interpret: bool = False, *,
         q, k, v, cos, sin, gain, keep)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
                 / jnp.max(jnp.abs(want)))
-    report[f"grouped_attention_W{grouped_windows}"] = {"max_err": err,
-                                                       "core": picked[0]}
+    # handed no mask, as ``attention`` calls it where ``topk`` covers the
+    # window and the indexer is not traced (both ``keye`` cells): the causal
+    # rule alone, to the bits of the causal mask handed over
+    causal = jnp.tile(jnp.tril(jnp.ones((t, t), bool)), (grouped_windows, 1))
+    alone, handed = (wa.grouped_window_attention(
+        q.T, k, v.T, cos, sin, gain, mask, **widths, interpret=interpret)
+        for mask in (None, causal))
+    not_traced = _said_by_the_expert_layer(
+        lambda: keye_backbone._attention_core(n, None, cfg, t))
+    same = bool(jnp.all(jnp.isfinite(alone))) and bool(jnp.array_equal(alone, handed))
+    report[f"grouped_attention_W{grouped_windows}"] = {
+        "max_err": err, "core": picked[0], "core_with_no_mask": not_traced[0],
+        "no_mask_same_bits_as_causal": same}
     check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
           f"grouped attention on {grouped_windows} windows: max err {err} "
           f"> {BACKBONE_TOL}")
+    check(same, f"grouped attention on {grouped_windows} windows: handed no mask it "
+          "differs from the causal mask handed over")
 
     from igaming_platform_tpu.models import ling_backbone
     from igaming_platform_tpu.ops.pallas import delta_window as dw

@@ -17,7 +17,16 @@ the final norm (every product reads and writes it so):
    frequency pairs each); causal.
 2. The indexer scores every causal key for every query (``sum_h w[t,h]
    * relu(qi[t,h] . ki[s]) / sqrt(d)``) and query ``t`` attends only to
-   its ``min(topk, t + 1)`` best keys. On a TPU, where
+   its ``min(topk, t + 1)`` best keys. It runs where it can drop a key:
+   in windows longer than ``topk``. Where ``topk`` covers the window
+   (2048 over the cells' 16 and 128 events) the best ``min(topk, t + 1)``
+   of ``t + 1`` causal keys are all of them whatever the scores, the
+   selection IS the causal mask, and the indexer is not traced: its three
+   matrices stay in the tree at rest, unread by the step, and the core
+   masks by the causal rule alone. Decided while tracing from the
+   configuration's ``topk`` and the window's length, and said in the
+   ``attention core`` line (``mask=keep``, or ``mask=causal`` with the
+   reason). On a TPU, where
    ``ops/pallas/window_attention``'s grouped-query form takes the layer
    (windows that divide 128, heads of whole 128-lane vregs: the published
    widths do), the core (q's head norm and rotary, scores, that mask,
@@ -194,8 +203,9 @@ def _core_by_einsums(q, k, v, cos, sin, gain, keep, *, heads: int,
     grouped_window_attention, which takes ``q`` and ``v`` channel-major),
     and what runs off the TPU. ``q`` [P, heads x hd] float32 as ``wq`` left
     it (its head norm and rotary happen here), ``k`` and ``v`` [P, kv_heads
-    x hd] ready and rounded, ``keep`` [P, window] -> float32 [P, heads x
-    hd], which ``wo``'s product rounds."""
+    x hd] ready and rounded, ``keep`` [P, window] or None (the causal rule
+    alone, as the kernel masks when it is handed none) -> float32 [P, heads
+    x hd], which ``wo``'s product rounds."""
     dt, t = k.dtype, window
     b, hd = q.shape[0] // t, q.shape[1] // heads
     cos, sin = cos.reshape(b, t, -1), sin.reshape(b, t, -1)
@@ -205,7 +215,9 @@ def _core_by_einsums(q, k, v, cos, sin, gain, keep, *, heads: int,
     sc = jnp.einsum("btgjd,bsgd->bgjts", q.astype(dt),
                     k.reshape(b, t, kv_heads, hd),
                     preferred_element_type=jnp.float32) * (hd ** -0.5)
-    sc = jnp.where(keep.reshape(b, 1, 1, t, t), sc, -jnp.inf)
+    mask = (jnp.tril(jnp.ones((t, t), bool)) if keep is None
+            else keep.reshape(b, 1, 1, t, t))
+    sc = jnp.where(mask, sc, -jnp.inf)
     p = jax.nn.softmax(sc, axis=-1)
     o = jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt),
                    v.reshape(b, t, kv_heads, hd),
@@ -219,7 +231,9 @@ def _attention_core(positions: int, keep, cfg: BackboneConfig, window: int) -> b
     (ops/pallas/window_attention.py: on a TPU, where it takes the
     operands' shapes) or as ``_core_by_einsums``. Picked while tracing,
     from backend and shapes, and announced once a compile, with the
-    kernel's reason where it declines."""
+    kernel's reason where it declines. ``keep`` is the indexer's mask (or
+    its shape), or None where ``attention`` did not trace the indexer: the
+    line says which (``mask=keep``, or ``mask=causal`` and why)."""
     from igaming_platform_tpu.ops.pallas import window_attention as kernel
 
     nh, nkv, hd, dt = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.operand_dtype
@@ -228,9 +242,11 @@ def _attention_core(positions: int, keep, cfg: BackboneConfig, window: int) -> b
         jax.ShapeDtypeStruct((positions, nkv * hd), dt),
         jax.ShapeDtypeStruct((nkv * hd, positions), dt),
         heads=nh, kv_heads=nkv, window=window, keep=keep))
+    mask = ("mask=keep" if keep is not None else
+            f"mask=causal; indexer not traced: topk {cfg.idx_topk} >= window {window}")
     announce_core(
-        f"einsum ({why})" if why else
-        f"pallas-windows (grouped {nh}/{nkv} of {hd}, window {window}, mask=keep)",
+        f"einsum ({why}; {mask})" if why else
+        f"pallas-windows (grouped {nh}/{nkv} of {hd}, window {window}, {mask})",
         backend, "attention core")
     return not why
 
@@ -238,8 +254,12 @@ def _attention_core(positions: int, keep, cfg: BackboneConfig, window: int) -> b
 def attention(h, layer: Params, cos, sin, cfg: BackboneConfig, window: int):
     """The attention sublayer over the residual stream ``h`` [P, hidden] in
     windows of ``window`` positions (``cos``, ``sin`` [P, head_dim / 2]) ->
-    [P, hidden]. The core (the query's head norm and rotary, scores, the
-    indexer's mask, softmax, ``p v``) is one Pallas kernel where
+    [P, hidden]. The indexer is traced only where it can drop a key
+    (``idx_topk < window``: both are static); where ``idx_topk`` covers the
+    window its selection is the causal mask for every input, and the core
+    is handed no mask and applies the causal rule itself, the same bits
+    for less work. The core (the query's head norm and rotary, scores, the
+    mask, softmax, ``p v``) is one Pallas kernel where
     ``_attention_core`` finds that it takes the layer: ``wq`` and ``wv``
     then leave their results channel-major ([channels, P]: ``w^T a^T``,
     the same products), the kernel reads them where they were written and
@@ -256,8 +276,10 @@ def attention(h, layer: Params, cos, sin, cfg: BackboneConfig, window: int):
     k = rms_norm(mm(a, layer["wk"], cfg).reshape(1, p, nkv, hd), layer["kn"],
                  cfg.eps)
     k = rotate(k, cos[None], sin[None]).astype(dt).reshape(p, nkv * hd)
-    with jax.named_scope("indexer"):
-        keep = indexer_keep(a, layer, cos, sin, cfg, window)
+    keep = None
+    if cfg.idx_topk < window:
+        with jax.named_scope("indexer"):
+            keep = indexer_keep(a, layer, cos, sin, cfg, window)
     widths = dict(heads=nh, kv_heads=nkv, window=window, eps=cfg.eps)
     if _attention_core(p, keep, cfg, window):
         # q as the product leaves it: float32, since the head norm and the

@@ -336,48 +336,79 @@ def test_backbone_step_fits_beside_the_state_and_groups_its_experts(
     _assert_rows_come_in_by_the_kernel(text, 32768)
 
 
-@pytest.mark.parametrize("batch,parents_temps", [(256, 394_860_544),
-                                                 (64, 20_904_960)])
+@pytest.mark.parametrize("events,batch,topk,parents_temps", [
+    (16, 256, 2048, 394_860_544), (16, 64, 2048, 20_904_960),
+    (128, 64, 2048, 744_876_032), (16, 64, 8, 20_904_960)],
+    ids=["256-rows", "64-rows", "64-rows-of-128-events", "64-rows-topk-8"])
 def test_backbone_step_runs_attention_where_the_projections_wrote(
-        topo, tpu_backend, capsys, batch, parents_temps):
-    """``keye``'s session step at both rungs of its cell (PR 47): the core
-    of attention is the window kernel's grouped form, one custom call a
-    layer under ``head/attn`` (the scope ``head_attention_ms`` reads), on
-    ``wq``'s float32 result as the product left it (channel-major:
-    ``[heads x 128, P]``). Nothing of ``[b, heads,
-    t, s]`` and no copy of ``q`` turned heads-first is left in the compiled
-    module (at the parent: ``f32[256,4,16,16,8]`` scores and a copy of
-    them, ``bf16[256,4,8,16,16]`` probabilities, ``f32[256,16,32,128]``
-    and ``bf16[256,16,32,128]`` copies of ``q``), and the step's
-    temporaries stay within 10% of the parent's compile read
-    (394,860,544 B at 256 rows, 20,904,960 at 64: the kernel's operands
-    are arrays the einsums' fusions never wrote whole; what is read here
-    is 373,297,152 and 22,832,640)."""
+        topo, tpu_backend, capsys, monkeypatch, events, batch, topk,
+        parents_temps):
+    """``keye``'s session step at both rungs of its cell (PR 47) and at the
+    deep cell's 64 rows of 128 events: the core of attention is the window
+    kernel's grouped form, one custom call a layer under ``head/attn`` (the
+    scope ``head_attention_ms`` reads), on ``wq``'s float32 result as the
+    product left it (channel-major: ``[heads x 128, P]``). Nothing of ``[b,
+    heads, t, s]`` and no copy of ``q`` turned heads-first is left in the
+    compiled module (at PR 47's parent: ``f32[256,4,16,16,8]`` scores and a
+    copy of them, ``bf16[256,4,8,16,16]`` probabilities,
+    ``f32[256,16,32,128]`` and ``bf16[256,16,32,128]`` copies of ``q``),
+    and the step's temporaries stay within 10% of the parent's compile read
+    (394,860,544 B at 256 rows, 20,904,960 at 64, 744,876,032 at 128
+    events).
+
+    Since PR 63 the indexer is traced only where it can drop a key. At the
+    published ``topk`` 2048 over 16 or 128 events its selection is the
+    causal mask, which the kernel builds from two iotas: the call has no
+    ``s32[window, P]`` mask operand, and nothing under ``head/attn`` is the
+    indexer's (no ``head/attn/indexer`` scope, no ``top_k``, no sort; the
+    temporaries read here are 318,620,160 B at 256 rows where PR 47 read
+    373,297,152, 17,929,216 at 64 rows where the mask's step holds
+    21,526,016, and 735,391,232 at 128 events). At a ``topk`` under the
+    window (8 of 16) the indexer is computed and its mask is the kernel's
+    seventh operand, as at PR 47."""
     from jax.sharding import SingleDeviceSharding
 
-    capacity = 5_242_880
+    from igaming_platform_tpu.models import keye_backbone, session_heads
+
+    monkeypatch.setenv("SESSION_EVENTS", str(events))
+    monkeypatch.setitem(session_heads.HEADS, "keye", session_heads._backbone(
+        keye_backbone, keye_backbone.BackboneConfig(idx_topk=topk)))
+    capacity = 5_242_880 if events == 16 else DEEP_ACCOUNTS
+    positions = batch * events
     one = SingleDeviceSharding(topo.devices[0])
     compiled = _compile_step("keye", capacity, capacity + 1, one, one, batch=batch)
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     with capsys.disabled():
-        print(f"\nkeye {batch}-row step for a described v5e: temporaries "
+        print(f"\nkeye {batch}-row step of {events}-event windows at topk "
+              f"{topk} for a described v5e: temporaries "
               f"{mem.temp_size_in_bytes} B (the parent's {parents_temps})")
     assert mem.temp_size_in_bytes <= parents_temps * 1.1, mem
     cores = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "custom-call(" in line
              and "head/attn/core" in line]
     assert len(cores) == 4, cores
+    mask = f"s32[{events},{positions}]"
     for line in cores:
         assert re.match(r"\s*%_grouped_window_attention(\.\d+)? = "
-                        rf"bf16\[4096,{batch * 16}\]", line), line[:200]
-        # q as ``wq`` accumulated it, k and v, the mask's rows over a tile
-        for operand in (f"f32[4096,{batch * 16}]", f"bf16[{batch * 16},512]",
-                        f"bf16[512,{batch * 16}]", f"s32[16,{batch * 16}]"):
+                        rf"bf16\[4096,{positions}\]", line), line[:200]
+        # q as ``wq`` accumulated it, k and v
+        for operand in (f"f32[4096,{positions}]", f"bf16[{positions},512]",
+                        f"bf16[512,{positions}]"):
             assert operand in line, (operand, line[:400])
-    for gone in (f"[{batch},4,8,16,16]", f"[{batch},4,16,16,8]",
-                 f"[{batch},16,32,128]", f"[{batch},16,4,8,128]",
-                 f"f32[{batch},16,4096]"):
+        # the mask's rows over a tile: only where the indexer can drop a key
+        assert (mask in line) is (topk < events), line[:400]
+    under_attention = re.findall(r'op_name="jit\(_body\)/(head/attn/[^"]*)"', text)
+    assert under_attention
+    selecting = [name for name in under_attention
+                 if "indexer" in name or "top_k" in name or "sort" in name]
+    if topk < events:
+        assert any(name.endswith("head/attn/indexer/top_k") for name in selecting)
+    else:
+        assert selecting == [] and mask not in text
+    for gone in (f"[{batch},4,8,{events},{events}]", f"[{batch},4,{events},{events},8]",
+                 f"[{batch},{events},32,128]", f"[{batch},{events},4,8,128]",
+                 f"f32[{batch},{events},4096]"):
         assert gone not in text, gone
 
 

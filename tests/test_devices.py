@@ -223,7 +223,13 @@ def test_smoke_kernels_phase_tiny_interpreted():
     # tile part filled, and the core a trace would pick here, off the TPU
     grouped = report["grouped_attention_W11"]
     assert grouped["max_err"] <= chip_smoke.BACKBONE_TOL
-    assert grouped["core"] == "attention core: einsum (not a TPU) (backend=cpu)"
+    assert grouped["core"] == (
+        "attention core: einsum (not a TPU; mask=keep) (backend=cpu)")
+    # handed no mask (``topk`` covers the window: the indexer is not traced)
+    assert grouped["no_mask_same_bits_as_causal"] is True
+    assert grouped["core_with_no_mask"] == (
+        "attention core: einsum (not a TPU; mask=causal; indexer not traced: "
+        "topk 2048 >= window 16) (backend=cpu)")
     # the delta-rule window kernel (ling's mixer) on one tile of 8 windows
     delta = report["delta_window_W8"]
     assert delta["max_err"] <= chip_smoke.BACKBONE_TOL

@@ -323,7 +323,7 @@ def test_head_with_the_kernels_on_equals_the_xla_path(operands, monkeypatch, cap
     assert said == {f"expert core: {core} (backend=tpu)",
                     "combine: pallas-rows (backend=tpu)",
                     "attention core: einsum (head width 16 is not whole "
-                    "128-lane vregs) (backend=tpu)"}
+                    "128-lane vregs; mask=keep) (backend=tpu)"}
     assert np.ptp(by_xla) > 1e-3
     # float32 summation order in the way back (and, with bfloat16 operands,
     # inside the products, before ``mid`` is rounded once)
@@ -346,24 +346,26 @@ def wide_heads(**over) -> kb.BackboneConfig:
     return kb.BackboneConfig(**kw)
 
 
-def attention_layer(cfg, windows_n: int, seed: int = 0):
+def attention_layer(cfg, windows_n: int, seed: int = 0, events: int = 16):
     """One seeded layer with gains that are not 1, a residual stream [P,
-    hidden] and the angles of positions 0 .. 15 of every window."""
+    hidden] and the angles of positions 0 .. ``events - 1`` of every window."""
     layer = dict(kb.init_backbone(jax.random.key(21 + seed), cfg)["layers"][0])
     ks = jax.random.split(jax.random.key(22 + seed), 3)
     for name, key in zip(("qn", "kn"), ks[:2], strict=True):
         layer[name] = 1 + 0.2 * jax.random.normal(key, layer[name].shape, jnp.float32)
-    p = windows_n * 16
+    p = windows_n * events
     h = jax.random.normal(ks[2], (p, cfg.hidden), jnp.float32)
-    pos3 = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (3, windows_n, 16))
+    pos3 = jnp.broadcast_to(jnp.arange(events, dtype=jnp.int32),
+                            (3, windows_n, events))
     cos, sin = (a.reshape(p, -1) for a in dp.mrope_angles(
         pos3, cfg.head_dim, cfg.mrope_section, cfg.rope_theta))
     return layer, h, cos, sin
 
 
-def run_attention(cfg, layer, h, cos, sin):
+def run_attention(cfg, layer, h, cos, sin, attention=None, events: int = 16):
+    attention = attention or kb.attention
     return np.asarray(jax.jit(
-        lambda h, c, s: kb.attention(h, layer, c, s, cfg, 16))(h, cos, sin))
+        lambda h, c, s: attention(h, layer, c, s, cfg, events))(h, cos, sin))
 
 
 @pytest.fixture
@@ -373,14 +375,14 @@ def attention_by_kernel(monkeypatch, caplog):
     computed with what ``_announce_core`` said."""
     from igaming_platform_tpu.ops.pallas import window_attention as wa
 
-    def run(*args):
+    def run(*args, **kwargs):
         dp.announce_core.cache_clear()
         caplog.clear()
         with monkeypatch.context() as m, caplog.at_level("INFO", logger=dp.logger.name):
             m.setattr(jax, "default_backend", lambda: "tpu")
             m.setattr(wa, "grouped_window_attention", functools.partial(
                 wa.grouped_window_attention, interpret=True))
-            out = run_attention(*args)
+            out = run_attention(*args, **kwargs)
         return out, [r.getMessage() for r in caplog.records]
     return run
 
@@ -390,8 +392,10 @@ def attention_by_kernel(monkeypatch, caplog):
 def test_attention_through_the_kernel_equals_the_einsum_path(
         operands, windows_n, attention_by_kernel):
     """The sublayer on the seeded tree at the cell's two rungs (1,024 and
-    4,096 positions): projections, indexer and ``wo`` are the same XLA
-    either way, the core is the kernel or the einsums. ``wo`` sums 4,096
+    4,096 positions): projections and ``wo`` are the same XLA either way,
+    the core is the kernel or the einsums, and at the cell's ``topk`` 2048
+    neither traces the indexer: both mask by the causal rule alone, and the
+    kernel's line says so. ``wo`` sums 4,096
     products of the core's results, so a result that fell to the other
     side of a bfloat16 boundary (one in a thousand, tests/
     test_window_attention.py) moves an output by 2^-8 of one term."""
@@ -400,7 +404,8 @@ def test_attention_through_the_kernel_equals_the_einsum_path(
     by_einsum = run_attention(cfg, *args)
     by_kernel, said = attention_by_kernel(cfg, *args)
     assert said == ["attention core: pallas-windows (grouped 32/4 of 128, "
-                    "window 16, mask=keep) (backend=tpu)"]
+                    "window 16, mask=causal; indexer not traced: topk 2048 >= "
+                    "window 16) (backend=tpu)"]
     assert dp.announced_cores()["attention core"] == said[0].split(": ", 1)[1]
     assert by_kernel.shape == by_einsum.shape == (windows_n * 16, cfg.hidden)
     scale = np.abs(by_einsum).max()
@@ -429,34 +434,181 @@ def test_the_indexer_is_computed_not_assumed(core, attention_by_kernel):
                                atol=2e-3 * np.abs(got).max(), rtol=0)
 
 
+NOT_TRACED = "mask=causal; indexer not traced: topk 2048 >= window"
+
+
 @pytest.mark.parametrize("backend,over,window,said", [
-    ("cpu", {}, 16, "einsum (not a TPU) (backend=cpu)"),
-    ("tpu", {}, 16, "pallas-windows (grouped 32/4 of 128, window 16, mask=keep) "
-                    "(backend=tpu)"),
-    ("tpu", {"heads": 4, "kv_heads": 2, "head_dim": 16}, 16,
-     "einsum (head width 16 is not whole 128-lane vregs) (backend=tpu)"),
+    ("cpu", {}, 16, f"einsum (not a TPU; {NOT_TRACED} 16) (backend=cpu)"),
+    ("cpu", {"idx_topk": 4}, 16, "einsum (not a TPU; mask=keep) (backend=cpu)"),
+    ("tpu", {}, 16, "pallas-windows (grouped 32/4 of 128, window 16, "
+                    f"{NOT_TRACED} 16) (backend=tpu)"),
+    ("tpu", {}, 128, "pallas-windows (grouped 32/4 of 128, window 128, "
+                     f"{NOT_TRACED} 128) (backend=tpu)"),
+    ("tpu", {"idx_topk": 16}, 16,
+     "pallas-windows (grouped 32/4 of 128, window 16, mask=causal; indexer not "
+     "traced: topk 16 >= window 16) (backend=tpu)"),
+    ("tpu", {"idx_topk": 15}, 16,
+     "pallas-windows (grouped 32/4 of 128, window 16, mask=keep) (backend=tpu)"),
+    ("tpu", {"idx_topk": 64}, 128,
+     "pallas-windows (grouped 32/4 of 128, window 128, mask=keep) (backend=tpu)"),
+    ("tpu", {"heads": 4, "kv_heads": 2, "head_dim": 16, "idx_topk": 4}, 16,
+     "einsum (head width 16 is not whole 128-lane vregs; mask=keep) (backend=tpu)"),
     ("tpu", {"heads": 20, "kv_heads": 3}, 16,
-     "einsum (20 heads over 3 key heads) (backend=tpu)"),
+     f"einsum (20 heads over 3 key heads; {NOT_TRACED} 16) (backend=tpu)"),
     ("tpu", {}, 12, "einsum (windows of 12 are not whole 8-row vregs that "
-                    "divide a tile of 128) (backend=tpu)"),
-    ("tpu", {"operand_dtype": jnp.float16}, 16,
-     "einsum (operands float16 / float16) (backend=tpu)"),
-], ids=["off-the-tpu", "published", "small-heads", "uneven-sharing", "window12",
-        "float16"])
+                    f"divide a tile of 128; {NOT_TRACED} 12) (backend=tpu)"),
+    ("tpu", {"operand_dtype": jnp.float16, "idx_topk": 4}, 16,
+     "einsum (operands float16 / float16; mask=keep) (backend=tpu)"),
+], ids=["off-the-tpu", "off-the-tpu-pruning", "published", "published-deep",
+        "topk-is-the-window", "topk-one-under", "deep-pruning", "small-heads",
+        "uneven-sharing", "window12", "float16"])
 def test_attention_core_is_announced_with_the_reason_it_declines(
         backend, over, window, said, monkeypatch, caplog):
     """The choice is made while tracing, from the backend and the layer's
     shapes alone; the boot's log line and ``/debug/sessionz``'s
-    ``head_cores`` carry it, with the kernel's own reason beside ``einsum``."""
+    ``head_cores`` carry it, with the kernel's own reason beside ``einsum``
+    and, since PR 63, what masks on either core: ``mask=keep`` where the
+    indexer was traced (``idx_topk`` under the window: ``attention`` hands
+    its mask over), or ``mask=causal`` with the reason where it was not."""
     cfg = wide_heads(**over)
     dp.announce_core.cache_clear()
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    keep = jax.ShapeDtypeStruct((64 * window, window), jnp.bool_)
+    # as ``attention`` calls it: the indexer's mask, or none
+    keep = (jax.ShapeDtypeStruct((64 * window, window), jnp.bool_)
+            if cfg.idx_topk < window else None)
     with caplog.at_level("INFO", logger=dp.logger.name):
         by_kernel = kb._attention_core(64 * window, keep, cfg, window)
     assert by_kernel is said.startswith("pallas-windows")
     assert [r.getMessage() for r in caplog.records] == [f"attention core: {said}"]
     assert dp.announced_cores()["attention core"] == said
+
+
+# -- the selection where ``topk`` covers the window: the causal mask -------------
+
+
+def _indexer_inputs(cfg, events: int, scores: str):
+    """Four windows of normed-like hidden states with a seeded layer whose
+    indexer scores are what ``scores`` says: ``random``; ``nan`` (head
+    weights of NaN: every score NaN); ``-inf`` (head weights of -inf over
+    positive inputs: every score -inf, or NaN where a head's ``relu`` left
+    0); ``a row`` (one position's input NaN: that query's row of scores
+    and that key's column)."""
+    layer, a, cos, sin = attention_layer(cfg, 4, seed=3, events=events)
+    if scores == "nan":
+        layer["ww"] = jnp.full_like(layer["ww"], jnp.nan)
+    elif scores == "-inf":
+        layer["ww"], a = jnp.full_like(layer["ww"], -jnp.inf), jnp.abs(a) + 0.1
+    elif scores == "a row":
+        a = a.at[events + 5].set(jnp.nan)
+    if scores != "random":  # the poison reaches the scores
+        w = np.asarray(dp.mm(a, layer["ww"], cfg))
+        assert not np.isfinite(w).all()
+    return layer, a, cos, sin
+
+
+@pytest.mark.parametrize("scores", ["random", "nan", "-inf", "a row"])
+@pytest.mark.parametrize("events,topk", [(16, 16), (16, 2048), (128, 128),
+                                         (128, 2048)])
+def test_the_selection_is_the_causal_mask_where_topk_covers_the_window(
+        events, topk, scores):
+    """What ``attention`` rests on when it leaves the indexer out: with
+    ``idx_topk >= window`` ``top_k`` returns all ``window`` keys of every
+    query, a permutation whatever the scores are (finite, NaN or ``-inf``),
+    so ``indexer_keep`` IS the causal mask, for every input."""
+    cfg = wide_heads(idx_topk=topk)
+    layer, a, cos, sin = _indexer_inputs(cfg, events, scores)
+    keep = np.asarray(jax.jit(lambda a, c, s: kb.indexer_keep(
+        a, layer, c, s, cfg, events))(a, cos, sin))
+    causal = np.tile(np.tril(np.ones((events, events), bool)), (4, 1))
+    np.testing.assert_array_equal(keep, causal)
+    if scores == "random":  # and one key fewer is a selection: it drops keys
+        pruned = np.asarray(jax.jit(lambda a, c, s: kb.indexer_keep(
+            a, layer, c, s, wide_heads(idx_topk=events - 1), events))(a, cos, sin))
+        last = np.arange(len(pruned)) % events == events - 1
+        assert (pruned[last].sum(axis=1) == events - 1).all()
+        np.testing.assert_array_equal(pruned[~last], causal[~last])
+
+
+def _attention_with_the_mask_handed_over(interpret):
+    """``attention`` as PR 63's parent traced it at every ``topk``: the
+    indexer's mask computed and handed to the core (the einsums, or the
+    kernel through the interpreter where ``interpret`` is set)."""
+    from igaming_platform_tpu.ops.pallas import window_attention as wa
+
+    def attention(h, layer, cos, sin, cfg, window):
+        p, dt = h.shape[0], cfg.operand_dtype
+        nh, nkv, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
+        a = dp.rms_norm(h, layer["g1"], cfg.eps)
+        k = dp.rms_norm(dp.mm(a, layer["wk"], cfg).reshape(1, p, nkv, hd),
+                        layer["kn"], cfg.eps)
+        k = dp.rotate(k, cos[None], sin[None]).astype(dt).reshape(p, nkv * hd)
+        keep = kb.indexer_keep(a, layer, cos, sin, cfg, window)
+        widths = dict(heads=nh, kv_heads=nkv, window=window, eps=cfg.eps)
+        if interpret:
+            q, v = dp.mm_t(layer["wq"], a, cfg), dp.mm_t(layer["wv"], a, cfg).astype(dt)
+            o = wa.grouped_window_attention(q, k, v, cos, sin, layer["qn"], keep,
+                                            **widths, interpret=True)
+            return jax.lax.dot_general(o, layer["wo"].astype(dt),
+                                       (((0,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+        q, v = dp.mm(a, layer["wq"], cfg), dp.mm(a, layer["wv"], cfg).astype(dt)
+        o = kb._core_by_einsums(q, k, v, cos, sin, layer["qn"], keep, **widths)
+        return dp.mm(o, layer["wo"], cfg)
+    return attention
+
+
+@pytest.mark.parametrize("core", ["einsum", "kernel"])
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+@pytest.mark.parametrize("events,windows_n", [(16, 64), (128, 8)])
+def test_attention_without_the_indexer_is_bit_equal_to_the_mask_handed_over(
+        events, windows_n, operands, core, attention_by_kernel, monkeypatch):
+    """At the cells' ``topk`` 2048 over 16 and 128 events the sublayer that
+    traces no indexer returns the bits of the one that computes the mask
+    and hands it over, on the einsum path (the CPU tests' and replay's) and
+    through the kernel (the chip's, in the Pallas interpreter): the scores
+    a caller reads are the parent's."""
+    cfg = wide_heads(operand_dtype=jnp.dtype(operands))
+    args = attention_layer(cfg, windows_n, seed=2, events=events)
+    traced = []
+    monkeypatch.setattr(kb, "indexer_keep", lambda *a, _f=kb.indexer_keep: (
+        traced.append(1), _f(*a))[1])
+    if core == "kernel":
+        now, said = attention_by_kernel(cfg, *args, events=events)
+        assert f"window {events}, {NOT_TRACED} {events})" in said[0]
+    else:
+        now = run_attention(cfg, *args, events=events)
+    assert traced == []
+    was = run_attention(cfg, *args, events=events,
+                        attention=_attention_with_the_mask_handed_over(
+                            interpret=core == "kernel"))
+    assert traced == [1] and np.isfinite(now).all() and np.ptp(now) > 0.01
+    np.testing.assert_array_equal(now, was)
+
+
+@pytest.mark.parametrize("topk,traced,sha256", [
+    (8, True, "97b106f2039253a0"), (16, False, "5b75ef3f6eacf415"),
+    (2048, False, "5b75ef3f6eacf415")])
+def test_the_head_lowers_the_indexer_only_where_it_can_drop_a_key(
+        topk, traced, sha256):
+    """``backbone_scores`` at the small size the other backbones' files pin
+    ``keye`` at (tests/test_falconh1_backbone.py), 16-event windows: with
+    ``idx_topk`` 8 under the window the StableHLO is the parent's byte for
+    byte (its digest since PR 47); with a ``topk`` that covers the window
+    (16, or the published 2048: the same module) the indexer's ``top_k`` is
+    not in it and the router's, two layers' worth, is all that is left.
+    PR 63 meant to change this one; every other head's digest stands."""
+    import hashlib
+
+    cfg = kb.BackboneConfig(hidden=128, layers=2, heads=4, kv_heads=2,
+                            head_dim=32, experts=8, top_k=2, expert_width=64,
+                            idx_heads=2, idx_dim=16, idx_topk=topk,
+                            mrope_section=(4, 6, 6))
+    params = jax.eval_shape(lambda: kb.init_backbone(jax.random.key(0), cfg))
+    text = jax.jit(lambda p, w, l: kb.backbone_scores(p, w, l, cfg)).lower(
+        params, jax.ShapeDtypeStruct((6, 16, 12), jnp.float32),
+        jax.ShapeDtypeStruct((6,), jnp.int32)).as_text()
+    assert text.count("top_k") == (4 if traced else 2)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == sha256
 
 
 def _hidden_with_a_3d_stream(params, x, pos3, cfg):
