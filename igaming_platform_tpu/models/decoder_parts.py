@@ -332,6 +332,46 @@ def attention(u, layer: Params, cos, sin, cfg, window: int, key_scale=None):
     return mm(o.reshape(b * t, nh * hd), layer["wo"], cfg)
 
 
+def core_by_einsums(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
+                    window: int, band: int | None, eps: float,
+                    block: int | None = None):
+    """The core of grouped-query attention over deep windows in query
+    blocks, two einsums a block over ``[b, t, h, d]`` (the ``mellum`` and
+    ``kexaone`` heads' layers): the reference of the kernel (ops/pallas/
+    block_attention.block_attention, under its signature) and what runs off
+    the TPU. ``q``
+    [P, heads x hd] float32 as ``wq`` left it (its head norm and rotary
+    happen here), ``k`` and ``v`` [P, kv_heads x hd] ready and rounded,
+    ``cos`` and ``sin`` [window, hd / 2] -> float32 [P, heads x hd], which
+    ``wo``'s product rounds. A block of queries meets the keys from the
+    first its band keeps (the window's first in a full layer) to its own
+    last, under the mask written as its two inequalities; no ``[t, s]``
+    array of the whole window stands at once."""
+    from igaming_platform_tpu.ops.pallas.block_attention import block_for
+
+    dt, t = k.dtype, window
+    b, hd = q.shape[0] // t, q.shape[1] // heads
+    block = block or block_for(t)
+    q = rotate(rms_norm(q.reshape(b, t, heads, hd), gain, eps), cos[None],
+               sin[None])
+    # query head j reads key-value head j // (heads // kv_heads)
+    q = q.reshape(b, t, kv_heads, heads // kv_heads, hd).astype(dt)
+    k, v = (x.reshape(b, t, kv_heads, hd) for x in (k, v))
+    out = []
+    for lo in range(0, t, block):
+        hi = min(lo + block, t)
+        first = 0 if band is None else max(lo - band + 1, 0)
+        i = jnp.arange(lo, hi)[:, None]
+        j = jnp.arange(first, hi)[None, :]
+        keep = j <= i if band is None else (j <= i) & (i - j < band)
+        sc = jnp.einsum("btgjd,bsgd->bgjts", q[:, lo:hi], k[:, first:hi],
+                        preferred_element_type=jnp.float32) * (hd ** -0.5)
+        p = jax.nn.softmax(jnp.where(keep, sc, -jnp.inf), axis=-1)
+        out.append(jnp.einsum("bgjts,bsgd->btgjd", p.astype(dt), v[:, first:hi],
+                              preferred_element_type=jnp.float32))
+    return jnp.concatenate(out, axis=1).reshape(b * t, heads * hd)
+
+
 def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
                            rope: int, dv: int, window: int,
                            interleave: bool = False, scale_by: float = 1.0):

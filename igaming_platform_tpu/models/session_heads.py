@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from igaming_platform_tpu.models import (
     falconh1_backbone,
     keye_backbone,
+    kexaone_backbone,
     lfm2_backbone,
     ling_backbone,
     mellum_backbone,
@@ -118,9 +119,10 @@ def transformer_scores(sparams, window, lengths):
 # attention over an earlier layer's keys and values) and its feed-forward
 # (``dense``, ``moe``) may be: the kinds a row's ``layers`` counts. A layer
 # that runs two operators (``falconh1``: ``ssm`` beside ``attention``) counts
-# under both.
+# under both. ``mtp`` counts multi-token-prediction modules, each once; a
+# module's own layer counts under its operators' kinds besides.
 LAYER_KINDS = ("conv", "attention", "window", "ssm", "linear", "memory",
-               "cross", "dense", "moe")
+               "cross", "mtp", "dense", "moe")
 _NO_LAYERS = dict.fromkeys(LAYER_KINDS, 0)
 
 
@@ -231,6 +233,15 @@ HEADS = {
     # in every layer: 3.34 G parameters, 6.68 GB
     "phi4flash": _backbone(phi4flash_backbone,
                            phi4flash_backbone.Phi4FlashConfig()),
+    # a post-norm stack read at two depths, at its published widths: one dense
+    # and four expert layers (a band of 128 keys on four of the five, every
+    # causal key and no rotary on the other; a shared expert beside 128
+    # sigmoid-routed experts of width 2,048, a chip's share of 8 held), then
+    # the multi-token-prediction module (a join of the stack's output with the
+    # next event's embedding and one more full-attention expert layer, run at
+    # the one position the score reads) under the same scoring head: 2.80 G
+    # parameters, 5.59 GB
+    "kexaone": _backbone(kexaone_backbone, kexaone_backbone.KExaoneConfig()),
 }
 
 

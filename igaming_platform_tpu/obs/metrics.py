@@ -911,8 +911,10 @@ class ServiceMetrics:
             "linear attention; memory: a gate over an earlier layer's scan "
             "output; cross: attention over an earlier layer's keys and "
             "values; a layer that runs two, a state-space mixer beside "
-            "attention, counts under both), kind=dense|moe its feed-forward "
-            "(a head without layers reads 0 for all nine)",
+            "attention, counts under both), kind=dense|moe its feed-forward, "
+            "kind=mtp a multi-token-prediction module behind the stack "
+            "(counted once; its own layer's operators count under their "
+            "kinds besides; a head without layers reads 0 for all ten)",
         )
         self.session_head_residual_streams = self.registry.gauge(
             f"{service}_session_head_residual_streams",

@@ -41,7 +41,7 @@ row of an edge block may have every key masked: its maximum stays ``-inf``
 and is read as 0 for the subtraction, so the row adds exact zeros.
 
 Same arithmetic as the einsum form in query blocks
-(``models/mellum_backbone.core_by_einsums``), which stays its reference
+(``models/decoder_parts.core_by_einsums``), which stays its reference
 (tests/test_block_attention.py) and what runs off the TPU: operands in the
 dtype ``k`` comes in, products accumulated in float32, scale and mask in
 float32. What differs is where the probabilities are rounded (here before

@@ -867,6 +867,73 @@ def test_narrowing_step_scans_in_one_kernel_a_layer_and_holds_the_model_whole(
                            if f"[{positions}," in line.split(" convolution(")[0]]
 
 
+def test_two_depth_step_reads_the_module_at_one_position_a_row(
+        topo, tpu_backend, capsys, monkeypatch):
+    """The fused step with the ``kexaone`` backbone in it, at its cell's size
+    (24,576 accounts of 2,048 events, the 2-row step: 4,096 positions, five
+    layers and the multi-token-prediction module at the published widths): in
+    place on the 2.42 GB ring, its arguments the state plus 5.6 GB of weights.
+    The stack's cores are ``_block_attention`` (ops/pallas/block_attention.py)
+    once a layer, four under ``head/attn/window/core`` and one under
+    ``head/attn/full/core``, at 64 / 8 heads; nothing of a window's
+    ``2048,2048`` square is in the module. Of the module the join and the
+    ``K, V`` product meet all 4,096 positions and no other product under
+    ``head/mtp`` does. The expert products are ``ragged-dot`` (two slots of a
+    6144 x 2048 gate and up are 96 MiB, past what ``grouped_experts.supports``
+    grants: ``pangu``'s case) and the way back ``_combine_held`` in each
+    stack layer's pass loop. Code, temporaries and arguments are printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models.session_heads import HEADS
+    from igaming_platform_tpu.serve import session_state as ss
+
+    monkeypatch.setenv("SESSION_EVENTS", "2048")
+    capacity, batch = 24_576, 2
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["kexaone"].config
+    compiled = _compile_step("kexaone", capacity, capacity + 1, one, one,
+                             batch=batch)
+    ring = ss.ring_size(capacity + 1, 2048)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nkexaone 2-row step of 2,048-event windows for a described "
+              f"v5e: code {mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 7.9e9 < mem.argument_size_in_bytes < 8.1e9, mem
+    assert mem.temp_size_in_bytes < 0.9e9, mem  # 0.68 GB at PR 65
+    text = compiled.as_text()
+    positions = batch * 2048
+    qw, kvw = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    cores = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line
+             and re.match(r"\s*%_block_attention(\.\d+)? = ", line)]
+    assert len(cores) == cfg.layers, cores
+    for line in cores:
+        assert re.match(rf"\s*%_block_attention(\.\d+)? = bf16\[{positions},{qw}\]",
+                        line), line[:200]
+        for operand in (f"f32[{positions},{qw}]", f"bf16[{positions},{kvw}]"):
+            assert operand in line, (operand, line[:400])
+    assert sum("head/attn/window/core" in c for c in cores) == 4
+    assert sum("head/attn/full/core" in c for c in cores) == 1
+    assert not [c for c in cores if "head/mtp" in c]
+    assert "2048,2048]" not in text
+    # the module: two products over every position (the join, and K with V),
+    # every other one over the 2 rows that are read
+    module = [line.split(" convolution(")[0] for line in text.splitlines()
+              if "head/mtp" in line and " convolution(" in line]
+    whole = [line for line in module if f"[{positions}," in line]
+    assert module and 1 <= len(whole) <= 3, whole
+    assert not [line for line in whole if f"[{positions},{qw}]" in line], whole
+    _kernels_under(text, capsys, "kexaone", scope="head/attn")
+    back = _kernels_under(text, capsys, "kexaone")
+    assert len(back) >= 4 and all("_combine_held" in line for line in back)
+    assert "ragged-dot" in text
+    _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
+
+
 # The stream kernels of the ``xing`` step (ops/pallas/hyper_streams.py): every
 # hyper-connected sublayer of the five layers held, the first among them.
 XING_STREAM_CALLS = {"_streams_maps_read": 10, "_streams_write": 10}

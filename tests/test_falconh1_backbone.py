@@ -903,7 +903,7 @@ def environment():
     os.environ.update(saved)
 
 
-LAYERS = {"conv": 0, "attention": 4, "window": 0, "ssm": 4, "linear": 0, "memory": 0, "cross": 0, "dense": 4, "moe": 0}
+LAYERS = {"conv": 0, "attention": 4, "window": 0, "ssm": 4, "linear": 0, "memory": 0, "cross": 0, "mtp": 0, "dense": 4, "moe": 0}
 
 
 def test_score_batch_on_the_session_path_equals_the_reference(

@@ -620,7 +620,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert (snap["head_experts_held"], snap["head_experts_routed"]) == (
         c.experts, c.experts) == (64, 64)
     assert snap["head_layers"] == {"conv": 4, "attention": 1, "window": 0, "ssm": 0,
-                                   "linear": 0, "memory": 0, "cross": 0, "dense": 1, "moe": 4}
+                                   "linear": 0, "memory": 0, "cross": 0, "mtp": 0, "dense": 1, "moe": 4}
     # what the step's expert layer said it runs as when it was traced (here
     # the CPU's cores; on a TPU the kernels and how they are fed)
     assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"
@@ -679,9 +679,9 @@ def test_replay_verifies_a_ledger_written_under_the_head(small_lfm2, monkeypatch
 
 
 @pytest.mark.parametrize("name,layers", [
-    ("pattern", {"conv": 0, "attention": 0, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "dense": 0,
+    ("pattern", {"conv": 0, "attention": 0, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "mtp": 0, "dense": 0,
                  "moe": 0}),
-    ("transformer", {"conv": 0, "attention": 1, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0,
+    ("transformer", {"conv": 0, "attention": 1, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "mtp": 0,
                      "dense": 1, "moe": 0})])
 def test_layer_gauge_of_the_small_heads(name, layers):
     from igaming_platform_tpu.obs.metrics import ServiceMetrics

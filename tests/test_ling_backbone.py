@@ -43,7 +43,7 @@ CONFIG = "risk-seqhead-ling-3.0-flash"
 CELL = "ling-kda-insession"
 PUBLISHED = validate.load_source(CONFIG)["config"]
 EXPERTS, HELD, FIRST = 32, 8, 8
-LAYERS = {"conv": 0, "attention": 1, "window": 0, "ssm": 0, "linear": 6, "memory": 0, "cross": 0, "dense": 1, "moe": 6}
+LAYERS = {"conv": 0, "attention": 1, "window": 0, "ssm": 0, "linear": 6, "memory": 0, "cross": 0, "mtp": 0, "dense": 1, "moe": 6}
 
 
 def small_source(held: int = HELD, first: int = FIRST, **over) -> dict:
@@ -1179,7 +1179,7 @@ def test_unknown_head_lists_the_new_name():
     assert "'ling'" in str(err.value) and "'falconh1'" in str(err.value)
     assert all(set(row.layers) == set(session_heads.LAYER_KINDS)
                for row in session_heads.HEADS.values())
-    assert len(session_heads.HEADS) == 10  # PR 52: ``xing``; PR 57: ``mellum``; PR 59: ``phi4flash``
+    assert len(session_heads.HEADS) == 11  # PR 52: ``xing``; PR 57: ``mellum``; PR 59: ``phi4flash``; PR 65: ``kexaone``
 
 
 def test_chip_smoke_phase_runs_the_head_against_its_reference():

@@ -235,7 +235,7 @@ def test_the_row_of_heads_and_what_it_holds():
     assert cfg.layer_types == (mb.SLIDING,) * 3 + (mb.FULL,)
     assert row.experts == (64, 64)
     assert row.layers == {"conv": 0, "attention": 1, "window": 3, "ssm": 0,
-                          "linear": 0, "memory": 0, "cross": 0, "dense": 0, "moe": 4}
+                          "linear": 0, "memory": 0, "cross": 0, "mtp": 0, "dense": 0, "moe": 4}
     full = jax.eval_shape(row.init)
     leaves = jax.tree.leaves(full)
     assert sum(math.prod(a.shape) for a in leaves) == pytest.approx(1.671e9, rel=1e-3)
@@ -245,7 +245,7 @@ def test_the_row_of_heads_and_what_it_holds():
     assert row.key_blocks(4096) == (99, 256)
     assert row.key_blocks(16) == (4, 4)  # inside the band nothing is skipped
     assert all(r.key_blocks is None for name, r in session_heads.HEADS.items()
-               if name not in ("mellum", "phi4flash"))
+               if name not in ("mellum", "phi4flash", "kexaone"))
     with pytest.raises(ValueError) as err:
         session_heads.session_head("kimi")
     assert "'mellum'" in str(err.value)
@@ -347,7 +347,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert counters["risk_session_head_key_blocks_visited_total"] == visited * numbers["rows"]
     assert counters["risk_session_head_key_blocks_square_total"] == square * numbers["rows"]
     assert snap["head_layers"] == {"conv": 0, "attention": 1, "window": 3,
-                                   "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "dense": 0, "moe": 4}
+                                   "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "mtp": 0, "dense": 0, "moe": 4}
     assert snap["head_cores"]["attention core (window)"].startswith(
         "einsum in query blocks (window 64 in blocks of 64, band=16")
     assert snap["head_cores"]["expert core"] == "xla-ragged-dot (backend=cpu)"

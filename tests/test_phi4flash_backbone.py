@@ -264,8 +264,8 @@ def test_the_row_of_heads_and_what_it_holds():
     row = session_heads.HEADS["phi4flash"]
     assert row.config == pb.Phi4FlashConfig() and row.experts == (0, 0)
     assert row.layers == {"conv": 0, "attention": 1, "window": 8, "ssm": 9,
-                          "linear": 0, "memory": 7, "cross": 7, "dense": 32,
-                          "moe": 0}
+                          "linear": 0, "memory": 7, "cross": 7, "mtp": 0,
+                          "dense": 32, "moe": 0}
     full = jax.eval_shape(row.init)
     leaves = jax.tree.leaves(full)
     assert sum(math.prod(a.shape) for a in leaves) == pytest.approx(3.340e9, rel=1e-3)
@@ -278,7 +278,8 @@ def test_the_row_of_heads_and_what_it_holds():
     # row meets one row of 4
     assert row.key_blocks(2048) == (8 * 7 + 4, 9 * 16)
     assert all(r.layer_positions is None
-               for name, r in session_heads.HEADS.items() if name != "phi4flash")
+               for name, r in session_heads.HEADS.items()
+               if name not in ("phi4flash", "kexaone"))
     assert "'phi4flash'" in str(pytest.raises(
         ValueError, session_heads.session_head, "kimi").value)
 
@@ -387,7 +388,7 @@ def test_score_batch_on_the_session_path_equals_the_reference(
     assert counters["risk_session_head_key_blocks_square_total"] == square * rows
     assert snap["head_layers"] == {"conv": 0, "attention": 1, "window": 2,
                                    "ssm": 3, "linear": 0, "memory": 1,
-                                   "cross": 1, "dense": 8, "moe": 0}
+                                   "cross": 1, "mtp": 0, "dense": 8, "moe": 0}
     assert snap["head_cores"]["state-space core"].startswith(
         "chunks of 8 that hand the state on")
 

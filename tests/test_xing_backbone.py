@@ -39,7 +39,7 @@ CONFIG = "risk-seqhead-xing4.0-29b-a4b"
 CELL = "xing-mhc-insession"
 PUBLISHED = validate.load_source(CONFIG)["config"]
 EXPERTS = 8
-LAYERS = {"conv": 0, "attention": 3, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "dense": 1, "moe": 2}
+LAYERS = {"conv": 0, "attention": 3, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "mtp": 0, "dense": 1, "moe": 2}
 
 
 def small_source(**over) -> dict:
@@ -607,7 +607,7 @@ def test_the_programs_pinned_tree_has_the_references_shape(tree):
     assert xb.layer_kinds(xb.XingConfig()) == {"attention": 5, "dense": 1, "moe": 4}
     row = session_heads.HEADS["xing"]
     assert row.experts == (64, 64) and row.config.streams == 4
-    assert row.layers == {"conv": 0, "attention": 5, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0,
+    assert row.layers == {"conv": 0, "attention": 5, "window": 0, "ssm": 0, "linear": 0, "memory": 0, "cross": 0, "mtp": 0,
                           "dense": 1, "moe": 4}
 
 
