@@ -149,20 +149,19 @@ def band_of(kind: str, cfg: KExaoneConfig) -> int | None:
 
 
 def key_blocks(cfg: KExaoneConfig, window: int) -> tuple[int, int]:
-    """``(key blocks the cores of one window's layers visit, key blocks of
-    their squares)`` a query head, at the block either core sweeps by
-    (``block_attention.block_for``): a stack layer's blocks by its band, and
-    of the module, whose one query a row may be the last but one, the one
-    row of blocks it meets."""
+    """``((query, key) pairs the cores of one window's layers score, pairs
+    of their squares)`` a query head (``block_attention.visited_blocks``'s
+    unit): a stack layer's by its band, in the form its core runs, and of
+    the module, whose one query a row may be the last but one, the one row
+    of ``block_for(window)``-key blocks it meets."""
     from igaming_platform_tpu.ops.pallas.block_attention import (
-        block_for,
+        one_row,
         visited_blocks,
     )
 
     counts = [visited_blocks(window, band_of(t, cfg)) for t in cfg.layer_types]
-    row = -(-window // block_for(window))
-    return (sum(v for v, _ in counts) + row,
-            sum(s for _, s in counts) + row * row)
+    counts.append(one_row(window))
+    return sum(v for v, _ in counts), sum(s for _, s in counts)
 
 
 def layer_positions(cfg: KExaoneConfig, window: int) -> tuple[int, int]:
@@ -249,15 +248,13 @@ def _attention_core(positions: int, kind: str, cfg: KExaoneConfig, window: int):
     from igaming_platform_tpu.ops.pallas import block_attention as kernel
 
     nh, nkv, hd, dt = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.operand_dtype
+    band = band_of(kind, cfg)
     why, backend = kernel_declines(lambda: kernel.declines(
         jax.ShapeDtypeStruct((positions, nh * hd), jnp.float32),
         jax.ShapeDtypeStruct((positions, nkv * hd), dt),
         jax.ShapeDtypeStruct((positions, nkv * hd), dt),
-        heads=nh, kv_heads=nkv, window=window))
-    band = band_of(kind, cfg)
-    visited, square = kernel.visited_blocks(window, band)
-    swept = (f"window {window} in blocks of {kernel.block_for(window)}, "
-             f"band={band}: {visited} of {square} key blocks"
+        heads=nh, kv_heads=nkv, window=window, band=band))
+    swept = (kernel.describe(window, band, sweep=bool(why))
              + ("" if kind == SLIDING else "; no rotary: unit cos, zero sin"))
     announce_core(
         f"einsum in query blocks ({swept}; {why})" if why else

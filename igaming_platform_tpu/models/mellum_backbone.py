@@ -122,9 +122,9 @@ def band_of(kind: str, cfg: MellumConfig) -> int | None:
 
 
 def key_blocks(cfg: MellumConfig, window: int) -> tuple[int, int]:
-    """``(key blocks the cores of one window's layers visit, key blocks of
-    their squares)`` a query head, at the block either core sweeps by
-    (``block_attention.block_for``): what the server's
+    """``((query, key) pairs the cores of one window's layers score, pairs
+    of their squares)`` a query head, the area of the key blocks each sweep
+    visits (``block_attention.visited_blocks``'s unit): what the server's
     ``risk_session_head_key_blocks_*_total`` count a scored row."""
     from igaming_platform_tpu.ops.pallas.block_attention import visited_blocks
 
@@ -198,15 +198,13 @@ def _attention_core(positions: int, kind: str, cfg: MellumConfig, window: int):
     from igaming_platform_tpu.ops.pallas import block_attention as kernel
 
     nh, nkv, hd, dt = cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.operand_dtype
+    band = band_of(kind, cfg)
     why, backend = kernel_declines(lambda: kernel.declines(
         jax.ShapeDtypeStruct((positions, nh * hd), jnp.float32),
         jax.ShapeDtypeStruct((positions, nkv * hd), dt),
         jax.ShapeDtypeStruct((positions, nkv * hd), dt),
-        heads=nh, kv_heads=nkv, window=window))
-    band = band_of(kind, cfg)
-    visited, square = kernel.visited_blocks(window, band)
-    swept = (f"window {window} in blocks of {kernel.block_for(window)}, "
-             f"band={band}: {visited} of {square} key blocks")
+        heads=nh, kv_heads=nkv, window=window, band=band))
+    swept = kernel.describe(window, band, sweep=bool(why))
     announce_core(
         f"einsum in query blocks ({swept}; {why})" if why else
         f"pallas-blocks (grouped {nh}/{nkv} of {hd}, {swept})",

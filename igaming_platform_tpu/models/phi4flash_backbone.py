@@ -185,19 +185,19 @@ def layer_positions(cfg: Phi4FlashConfig, window: int) -> tuple[int, int]:
 
 
 def key_blocks(cfg: Phi4FlashConfig, window: int) -> tuple[int, int]:
-    """``(key blocks the cores of one window's layers visit, key blocks of
-    their squares)`` a query head, at the block the sweep goes by
-    (``block_attention.block_for``): a band layer's blocks, and of the full
-    layer, whose one query a row is the last, the one row of blocks it
-    meets."""
+    """``((query, key) pairs the cores of one window's layers score, pairs
+    of their squares)`` a query head, the area of the key blocks the sweep
+    goes by (``block_attention.visited_blocks``'s unit): a band layer's
+    blocks, and of the full layer, whose one query a row is the last, the
+    one row of blocks it meets."""
     from igaming_platform_tpu.ops.pallas.block_attention import (
-        block_for,
+        one_row,
         visited_blocks,
     )
 
     bands = kinds_of(cfg).count(BAND)
     visited, square = visited_blocks(window, cfg.sliding_window)
-    row = -(-window // block_for(window))
+    row, _ = one_row(window)
     return bands * visited + row, (bands + 1) * square
 
 
@@ -433,14 +433,12 @@ def _announce_attention(kind: str, cfg: Phi4FlashConfig, window: int) -> None:
 
     _, backend = kernel_declines()
     band = cfg.sliding_window if kind == BAND else None
-    visited, square = kernel.visited_blocks(window, band)
     announce_core(
         f"einsum in query blocks (differential, {cfg.heads}/{cfg.kv_heads} "
-        f"of {cfg.head_dim}, values of {2 * cfg.head_dim}; window {window} "
-        f"in blocks of {kernel.block_for(window)}, band={band}: {visited} of "
-        f"{square} key blocks; the block kernel takes heads of whole "
-        "128-lane vregs, values as wide as keys and its own head norm and "
-        "rotary)", backend,
+        f"of {cfg.head_dim}, values of {2 * cfg.head_dim}; "
+        f"{kernel.describe(window, band, sweep=True)}; the block kernel takes "
+        "heads of whole 128-lane vregs, values as wide as keys and its own "
+        "head norm and rotary)", backend,
         f"attention core ({'window' if kind == BAND else 'full'})")
 
 

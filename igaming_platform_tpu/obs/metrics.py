@@ -856,19 +856,20 @@ class ServiceMetrics:
         )
         self.session_head_key_blocks_visited_total = self.registry.counter(
             f"{service}_session_head_key_blocks_visited_total",
-            "Key blocks the attention cores of a head that sweeps its keys "
-            "in blocks visited for session-scored rows, a query head: rows "
-            "x the blocks one window's layers visit at the traced window "
-            "and block (a banded layer skips what lies before its band, "
-            "every layer what lies past the diagonal); 0 for every other "
-            "head",
+            "The area, in (query, key) pairs, of the key blocks the "
+            "attention cores of a head that goes over its keys in blocks "
+            "scored for session-scored rows, a query head: rows x the pairs "
+            "one window's layers score at the traced window, each layer in "
+            "the blocks of the form it runs (a banded layer skips what lies "
+            "before its band, every layer what lies past the diagonal); 0 "
+            "for every other head",
         )
         self.session_head_key_blocks_square_total = self.registry.counter(
             f"{service}_session_head_key_blocks_square_total",
-            "Key blocks of the same layers' whole squares (rows x layers x "
-            "(window / block)^2): over it "
+            "Pairs of the same layers' whole squares (rows x layers x "
+            "padded window^2): over it "
             "session_head_key_blocks_visited_total is the share of the "
-            "square the cores sweep",
+            "square the cores score",
         )
         self.session_head_layer_positions_computed_total = self.registry.counter(
             f"{service}_session_head_layer_positions_computed_total",

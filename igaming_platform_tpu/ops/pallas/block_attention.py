@@ -1,44 +1,69 @@
 """Pallas TPU kernel: causal grouped-query attention over deep windows in
-blocks of keys, with a band; only the key blocks the mask keeps are visited.
+blocks of keys, with a band; only the key blocks the mask keeps are visited,
+and a narrow band's keys are read in one visit.
 
 ``models/mellum_backbone.attention`` attends inside windows of ``T``
 positions (4,096 in its cell) in two kinds of layer: a *full* one, where
 query ``i`` reads every key ``j <= i``, and a *sliding* one, where it reads
-``j <= i`` with ``i - j < band`` (1,024). The short-window kernels beside
-this one (ops/pallas/window_attention.py) hold a whole window's scores at
-once and ask that the window divide a 128-position tile; at 4,096 positions
-a layer's ``[b, heads, t, s]`` scores are 4.3 GB. Here nothing of ``[t, s]``
-reaches HBM: a program holds one block of queries and walks the key blocks
-with an online softmax. One kernel serves both kinds of layer: ``band`` is
-``None`` in a full one.
+``j <= i`` with ``i - j < band`` (1,024; ``models/kexaone_backbone``'s band
+is 128 in windows of 2,048). The short-window kernels beside this one
+(ops/pallas/window_attention.py) hold a whole window's scores at once and ask
+that the window divide a 128-position tile; at 4,096 positions a layer's
+``[b, heads, t, s]`` scores are 4.3 GB. Here nothing of ``[t, s]`` reaches
+HBM: a program holds one block of queries and meets the keys its mask keeps.
+One kernel function serves both kinds of layer (``band`` is ``None`` in a
+full one) in two forms, chosen while tracing from ``band`` and the block
+alone (``one_visit``): **the sweep** for a full layer and a band wider than
+half a block, **one visit** for a band of at most half a block.
 
-**A program** is one window, one key-value head and one block of ``block``
-query positions, for the ``rep = heads / kv_heads`` query heads that share
-the key-value head. ``q`` comes as ``Wq``'s product left it, position-major
+**A program** is one window, one key-value head and one block of query
+positions, for the ``rep = heads / kv_heads`` query heads that share the
+key-value head. ``q`` comes as ``Wq``'s product left it, position-major
 float32 ``[P, heads x hd]``: each head's slice is normed (its RMS norm over
 the head's ``hd`` lanes) and turned (rotate-half over the whole head: the
 other half comes by a lane roll, ``sin`` carries the pair's sign) in
 float32, rounded once, and the ``rep`` heads' rows are stacked into one
-``[rep x block, hd]`` operand in VMEM, so that one product against a key
-block serves all of them. The window's keys and values of that key-value
+``[rep x rows, hd]`` operand in VMEM, so that one product against a block of
+keys serves all of them. The window's keys and values of that key-value
 head lie whole in VMEM (``[T, hd]`` each, 1 MB at 4,096 x 128 bfloat16):
 their block index changes only with the window and the key-value head, so
 they are read from HBM once a (window, key-value head) and the query blocks
-of it sweep them where they lie.
+of it read them where they lie.
 
-**The sweep.** Query block ``i`` covers positions ``i x block ..``; the
-diagonal is key block ``i``. In a full layer it visits key blocks ``0 .. i``;
-in a sliding one ``lo .. i`` with ``lo = max(0, i x block - band + 1) //
-block``: 9 of 32 blocks at ``block`` 128 and a band of 1,024, 5 of 16 at
-256, 3 of 8 at 512. Three loops: the blocks the band's edge crosses (the mask applied),
-the blocks wholly inside (no mask: every pair is kept), and the diagonal
-(the mask applied). ``visited_blocks`` counts them; nothing else is read or
-multiplied. The softmax is online, in float32: a running maximum ``m`` and
-sum ``l`` a row, the accumulator ``acc`` ``[rep x block, hd]`` float32;
-a block's ``exp(s - m)`` is rounded once to the operands' dtype before its
-product with ``v``, and the division by ``l`` comes once, at the end. A
-row of an edge block may have every key masked: its maximum stays ``-inf``
-and is read as 0 for the subtraction, so the row adds exact zeros.
+**The sweep.** A query block holds ``block`` positions (512); block ``i``
+covers positions ``i x block ..`` and the diagonal is key block ``i``. In a
+full layer it visits key blocks ``0 .. i``; in a sliding one ``lo .. i``
+with ``lo = max(0, i x block - band + 1) // block``: 9 of 32 blocks at
+``block`` 128 and a band of 1,024, 5 of 16 at 256, 3 of 8 at 512. Three
+loops: the blocks the band's edge crosses (the mask applied), the blocks
+wholly inside (no mask: every pair is kept), and the diagonal (the mask
+applied). Nothing else is read or multiplied. The softmax is online, in
+float32: a running maximum ``m`` and sum ``l`` a row, the accumulator
+``acc`` ``[rep x block, hd]`` float32; a block's ``exp(s - m)`` is rounded
+once to the operands' dtype before its product with ``v``, and the division
+by ``l`` comes once, at the end. A row of an edge block may have every key
+masked: its maximum stays ``-inf`` and is read as 0 for the subtraction, so
+the row adds exact zeros.
+
+**One visit.** What a visit of the sweep costs is fixed by its rows, not its
+keys: a pass over the accumulator, the running maximum and sum, and an
+``exp`` over the block's scores (``_BLOCK``'s comment has the timings). A
+band of 128 under blocks of 512 pays that for 7 of 16 blocks where its keys
+fill a quarter of one. So where ``band <= block // 2`` a query block is as
+tall as the band in whole 16-row tiles (``qb``, 128) and reads ONE slab of
+keys: the ``keys`` positions (``qb + band - 1`` in whole 128-lane tiles,
+256; the whole padded window where that is less) that end with the block's
+last row, so the slab holds every key any of its rows keeps. One product
+``q k^T``, the mask ``0 <= i - j < band`` over the whole slab, the row's
+maximum, ``exp``, the row's sum, the product with ``v``, one division: no
+loop over key blocks, no ``m``, ``l`` or ``acc`` carried, no rescale. The
+first blocks' slabs start at key 0 and hold keys past their rows, which the
+same mask drops; a row keeps its own key always, so no row is empty.
+Measured on a v5e at ``kexaone``'s cell shape (2 windows of 2,048, 64 / 8
+heads of 128, band 128; PERF.md, section 6, PR 66): a sliding layer
+1.51 ms as the sweep, 0.66 ms as one visit.
+
+``visited_blocks`` counts what either form scores, in (query, key) pairs.
 
 Same arithmetic as the einsum form in query blocks
 (``models/decoder_parts.core_by_einsums``), which stays its reference
@@ -48,8 +73,8 @@ float32. What differs is where the probabilities are rounded (here before
 the division by the row's sum, there after it) and the order of float32
 sums.
 
-A window that is not whole blocks is padded here (zeros: keys past a real
-query, which causality masks) and cut from the result.
+A window that is not whole query blocks of its form is padded here (zeros:
+keys past a real query, which causality masks) and cut from the result.
 """
 
 from __future__ import annotations
@@ -62,13 +87,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+_SUBLANES = 16  # rows of a bfloat16 tile
 
-# Query positions a program takes, and keys a step of its sweep. Measured on
-# a v5e at the cell's shape, 2 windows of 4,096 (PERF.md, section 6, PR 57):
-# a sliding layer 4.62 / 3.48 / 2.16 ms and a full one 9.25 / 6.18 / 3.49 ms
-# at 128 / 256 / 512. A wider key block spreads a visit's pass over the
-# accumulator and the running maximum and sum over more keys; it also sweeps
-# more of the square (33% of it in a sliding layer at 512, 25% at 128).
+# Query positions a program of the sweep takes, and keys a step of it.
+# Measured on a v5e at mellum's cell shape, 2 windows of 4,096 (PERF.md,
+# section 6, PR 57): a sliding layer 4.62 / 3.48 / 2.16 ms and a full one
+# 9.25 / 6.18 / 3.49 ms at 128 / 256 / 512. A wider key block spreads a
+# visit's pass over the accumulator and the running maximum and sum over more
+# keys; it also sweeps more of the square (33% of it in a sliding layer at
+# 512, 25% at 128). So smaller square blocks lose, and a band far under a
+# block is not served by shrinking this: where ``band <= _BLOCK // 2``
+# (``one_visit``; it turns at a band of 256) a query block as tall as the band
+# makes one visit of its one slab of keys, with no accumulator to pass over.
+# At kexaone's cell shape (2 windows of 2,048, band 128; PERF.md, section 6,
+# PR 66) a sliding layer reads 1.51 ms as the sweep and 0.66 ms as one visit
+# of 256 keys a 128-row block (0.81 ms at 256-row blocks of 384 keys, 0.86 at
+# 512-row blocks of 640: a taller block scores more pairs outside the band).
 _BLOCK = 512
 
 # What the kernel may ask of the v5e's 128 MiB of VMEM.
@@ -78,13 +112,29 @@ _VMEM_CAP = 96 * 2**20
 def block_for(window: int) -> int:
     """Positions a block holds at windows of ``window``: ``_BLOCK``, or the
     whole window in whole 16-row tiles where that is less."""
-    return min(_BLOCK, 16 * -(-window // 16))
+    return min(_BLOCK, _SUBLANES * -(-window // _SUBLANES))
+
+
+def one_visit(window: int, band: int | None,
+              block: int | None = None) -> tuple[int, int] | None:
+    """``(rows a query block holds, keys of its slab)`` where the one-visit
+    form runs, ``None`` where the sweep does: a band no wider than half the
+    block (``block_for(window)`` without one). A query block is as tall as
+    the band in whole 16-row tiles; its slab ends with the block's last row
+    and reaches back over the band in whole 128-lane tiles, or is the whole
+    padded window where that is less."""
+    block = block or block_for(window)
+    if band is None or band > block // 2:
+        return None
+    qb = _SUBLANES * -(-band // _SUBLANES)
+    padded = qb * -(-window // qb)
+    return qb, min(_LANES * -(-(qb + band - 1) // _LANES), padded)
 
 
 def swept_blocks(i: int, block: int, band: int | None) -> tuple[int, int]:
-    """``(lo, edge_end)`` for query block ``i``: it visits key blocks ``lo
-    .. i``; those before ``edge_end`` are crossed by the band's edge. The
-    kernel computes the same two numbers from its program id."""
+    """``(lo, edge_end)`` for query block ``i`` of the sweep: it visits key
+    blocks ``lo .. i``; those before ``edge_end`` are crossed by the band's
+    edge. The kernel computes the same two numbers from its program id."""
     if band is None:
         return 0, 0
     lo = max(i * block - band + 1, 0) // block
@@ -92,38 +142,84 @@ def swept_blocks(i: int, block: int, band: int | None) -> tuple[int, int]:
     return lo, min(max(edge_end, lo), i)
 
 
-def visited_blocks(window: int, band: int | None,
-                   block: int | None = None) -> tuple[int, int]:
+def _swept(window: int, band: int | None, block: int) -> tuple[int, int]:
     """``(key blocks one head's sweep of one window visits, key blocks of
-    the square)`` at ``block`` positions a block (``block_for(window)``
-    without one): what ``risk_session_head_key_blocks_*_total`` count a
-    layer."""
-    block = block or block_for(window)
+    the square)`` at ``block`` positions a block."""
     n = -(-window // block)
     return sum(i + 1 - swept_blocks(i, block, band)[0] for i in range(n)), n * n
 
 
-def _vmem(block: int, rep: int, hd: int, padded: int, q_size: int,
+def visited_blocks(window: int, band: int | None,
+                   block: int | None = None) -> tuple[int, int]:
+    """``((query, key) pairs one head scores in one window, pairs of the
+    padded square)`` in the form that runs: the area of the key blocks the
+    sweep visits, or of the slabs, one a query block. Pairs and not blocks,
+    so that a sum over layers whose blocks differ in shape is still a share
+    of the squares: what ``risk_session_head_key_blocks_*_total`` count a
+    layer."""
+    block = block or block_for(window)
+    form = one_visit(window, band, block)
+    if form:
+        qb, keys = form
+        n = -(-window // qb)
+        return n * qb * keys, (n * qb) ** 2
+    visited, square = _swept(window, band, block)
+    return visited * block * block, square * block * block
+
+
+def one_row(window: int) -> tuple[int, int]:
+    """``visited_blocks`` of a layer that reads ONE query a window (not this
+    kernel's: a backbone's own core): the row of ``block_for(window)``
+    blocks that query meets, and the padded square, in pairs."""
+    block = block_for(window)
+    n = -(-window // block)
+    return n * block * block, (n * block) ** 2
+
+
+def describe(window: int, band: int | None, *, sweep: bool = False) -> str:
+    """How a layer's core goes over a window of ``window``, for the line a
+    backbone announces: the form ``block_attention`` runs or, with
+    ``sweep``, the key blocks a sweep by ``block_for(window)`` visits
+    whatever the band (the einsum forms go in query blocks of that size)."""
+    form = None if sweep else one_visit(window, band)
+    if form:
+        return (f"window {window}, band={band}: one visit of {form[1]} keys a "
+                f"{form[0]}-row block")
+    block = block_for(window)
+    visited, square = _swept(window, band, block)
+    return (f"window {window} in blocks of {block}, band={band}: {visited} of "
+            f"{square} key blocks")
+
+
+def _vmem(qb: int, keys: int, rep: int, hd: int, padded: int, q_size: int,
           size: int) -> int:
-    """Both buffers of a program's blocks (queries, the window's keys and
-    values, the result, the angles), the stacked queries and the
-    accumulator, a block's float32 scores several times over, and room to
-    spare."""
-    rows = rep * block
-    blocks = (block * rep * hd * (q_size + size) + 2 * padded * hd * size
-              + 2 * block * hd * 4)
+    """A program of ``qb`` query positions that scores ``keys`` keys a
+    visit: both buffers of its blocks (queries, the window's keys and
+    values, the result, the angles), the stacked queries and the float32
+    result (the sweep's accumulator), a visit's float32 scores several
+    times over, and room to spare."""
+    rows = rep * qb
+    blocks = (qb * rep * hd * (q_size + size) + 2 * padded * hd * size
+              + 2 * qb * hd * 4)
     held = rows * hd * (size + 4) + 2 * rows * _LANES * 4
-    return 2 * blocks + held + 4 * rows * block * 4 + 4 * 2**20
+    return 2 * blocks + held + 4 * rows * keys * 4 + 4 * 2**20
 
 
-def declines(q, k, v, *, heads: int, kv_heads: int, window: int) -> str:
+def _program(window: int, band: int | None, block: int) -> tuple[int, int]:
+    """``(query positions a program takes, keys it scores a visit)`` in the
+    form that runs."""
+    return one_visit(window, band, block) or (block, block)
+
+
+def declines(q, k, v, *, heads: int, kv_heads: int, window: int,
+             band: int | None = None) -> str:
     """Why ``block_attention`` does not take these operands, "" where it
     does. ``q`` [P, heads x hd], ``k`` and ``v`` [P, kv_heads x hd] (arrays
     or their shapes-and-dtypes). It takes heads of whole 128-lane vregs,
     every key head shared by as many query heads, bfloat16 or float32 keys
     and values of one dtype, whole windows, and a window's keys and values
-    of one head beside a program's blocks inside VMEM; anything else takes
-    the caller's einsums."""
+    of one head beside a program's blocks inside VMEM, in the form a layer
+    of ``band`` runs; anything else takes the caller's einsums."""
     if kv_heads <= 0 or heads <= 0 or heads % kv_heads:
         return f"{heads} heads over {kv_heads} key heads"
     if q.ndim != 2 or q.shape[1] % heads or (q.shape[1] // heads) % _LANES:
@@ -137,12 +233,33 @@ def declines(q, k, v, *, heads: int, kv_heads: int, window: int) -> str:
         return f"operands {k.dtype} / {v.dtype}"
     if not jnp.issubdtype(q.dtype, jnp.floating):
         return f"q {q.dtype}"
-    block = block_for(window)
-    need = _vmem(block, heads // kv_heads, hd, block * -(-window // block),
+    qb, keys = _program(window, band, block_for(window))
+    need = _vmem(qb, keys, heads // kv_heads, hd, qb * -(-window // qb),
                  q.dtype.itemsize, k.dtype.itemsize)
     if need > _VMEM_CAP:
         return f"a program's blocks take {need} of {_VMEM_CAP} bytes of VMEM"
     return ""
+
+
+def _stack_queries(q_ref, cos_ref, sin_ref, gain_ref, qs_ref, *, qb: int,
+                   rep: int, hd: int, eps: float):
+    """The ``rep`` heads of a query block, normed, turned and rounded, one
+    under the other in ``qs_ref`` [rep x qb, hd]."""
+    f32 = jnp.float32
+    cos, sin, gain = cos_ref[...], sin_ref[...], gain_ref[...]
+    for j in range(rep):
+        # a head's RMS norm, then rotate-half over the whole head: the
+        # pair's other half comes by a roll of half the lanes
+        x = q_ref[:, j * hd:(j + 1) * hd].astype(f32)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+        x = x * cos + pltpu.roll(x, hd // 2, 1) * sin
+        qs_ref[j * qb:(j + 1) * qb, :] = x.astype(qs_ref.dtype)
+
+
+def _unstack(out, o_ref, *, qb: int, rep: int, hd: int):
+    for j in range(rep):
+        o_ref[:, j * hd:(j + 1) * hd] = (
+            out[j * qb:(j + 1) * qb, :].astype(o_ref.dtype))
 
 
 def _kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, o_ref,
@@ -151,14 +268,8 @@ def _kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, o_ref,
     f32 = jnp.float32
     dt = k_ref.dtype
     i = pl.program_id(2)
-    cos, sin, gain = cos_ref[...], sin_ref[...], gain_ref[...]
-    for j in range(rep):
-        # a head's RMS norm, then rotate-half over the whole head: the
-        # pair's other half comes by a roll of half the lanes
-        x = q_ref[:, j * hd:(j + 1) * hd].astype(f32)
-        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
-        x = x * cos + pltpu.roll(x, hd // 2, 1) * sin
-        qs_ref[j * block:(j + 1) * block, :] = x.astype(dt)
+    _stack_queries(q_ref, cos_ref, sin_ref, gain_ref, qs_ref, qb=block,
+                   rep=rep, hd=hd, eps=eps)
     m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, f32)
     l_ref[...] = jnp.zeros(l_ref.shape, f32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
@@ -204,10 +315,32 @@ def _kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, o_ref,
         loop(lo, edge_end, True)      # the band's edge crosses these
     loop(edge_end, i, False)          # wholly inside: every pair is kept
     visit(i, True)                    # the diagonal
-    out = acc_ref[...] / l_ref[...]
-    for j in range(rep):
-        o_ref[:, j * hd:(j + 1) * hd] = (
-            out[j * block:(j + 1) * block, :].astype(o_ref.dtype))
+    _unstack(acc_ref[...] / l_ref[...], o_ref, qb=block, rep=rep, hd=hd)
+
+
+def _visit_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, o_ref,
+                  qs_ref, *, qb: int, keys: int, rep: int, hd: int, band: int,
+                  eps: float, scale: float):
+    f32 = jnp.float32
+    dt = k_ref.dtype
+    i = pl.program_id(2)
+    _stack_queries(q_ref, cos_ref, sin_ref, gain_ref, qs_ref, qb=qb, rep=rep,
+                   hd=hd, eps=eps)
+    # the slab ends with the block's last row; the first blocks' starts at
+    # key 0 and holds keys past their rows, which the mask drops
+    first = pl.multiple_of(jnp.maximum((i + 1) * qb - keys, 0), _SUBLANES)
+    at = pl.ds(first, keys)
+    s = jax.lax.dot_general(qs_ref[...], k_ref[at, :], (((1,), (1,)), ((), ())),
+                            preferred_element_type=f32) * scale
+    # the query's position less the key's; a row keeps its own key always
+    d = (jax.lax.broadcasted_iota(jnp.int32, (rep * qb, keys), 0) % qb
+         - jax.lax.broadcasted_iota(jnp.int32, (rep * qb, keys), 1)
+         + (i * qb - first))
+    s = jnp.where(jnp.logical_and(d >= 0, d < band), s, -jnp.inf)
+    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    out = jnp.dot(e.astype(dt), v_ref[at, :], preferred_element_type=f32)
+    _unstack(out / jnp.sum(e, axis=-1, keepdims=True), o_ref, qb=qb, rep=rep,
+             hd=hd)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -215,38 +348,44 @@ def _kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, gain_ref, o_ref,
 def _block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
                      window: int, band: int | None, eps: float, block: int,
                      interpret: bool):
-    """``window`` is whole blocks here (``block_attention`` pads)."""
+    """``window`` is whole query blocks here (``block_attention`` pads)."""
     p = q.shape[0]
     hd, rep = q.shape[1] // heads, heads // kv_heads
-    n = window // block
     size = k.dtype.itemsize
-    visited, _ = visited_blocks(window, band, block)
-    pairs = (p // window) * heads * visited * block * block
+    form = one_visit(window, band, block)
+    qb, keys = form or (block, block)
+    n = window // qb
+    pairs = (p // window) * heads * visited_blocks(window, band, block)[0]
     # the angles over the whole head's lanes, ``sin`` signed as rotate-half
     # signs it; the gain a row
     cos = jnp.concatenate([cos, cos], axis=1)
     sin = jnp.concatenate([-sin, sin], axis=1)
-    rows = rep * block
+    rows = rep * qb
+    widths = dict(rep=rep, hd=hd, band=band, eps=eps, scale=hd ** -0.5)
+    scratch = [pltpu.VMEM((rows, hd), k.dtype)]  # the stacked queries
+    if form:
+        kernel = functools.partial(_visit_kernel, qb=qb, keys=keys, **widths)
+    else:
+        kernel = functools.partial(_kernel, block=block, **widths)
+        scratch += [pltpu.VMEM((rows, 1), jnp.float32),   # m
+                    pltpu.VMEM((rows, 1), jnp.float32),   # l
+                    pltpu.VMEM((rows, hd), jnp.float32)]  # acc
     return pl.pallas_call(
-        functools.partial(_kernel, block=block, rep=rep, hd=hd, band=band,
-                          eps=eps, scale=hd ** -0.5),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((p, heads * hd), k.dtype),
         grid=(p // window, kv_heads, n),
-        in_specs=[pl.BlockSpec((block, rep * hd), lambda b, g, i: (b * n + i, g)),
+        in_specs=[pl.BlockSpec((qb, rep * hd), lambda b, g, i: (b * n + i, g)),
                   pl.BlockSpec((window, hd), lambda b, g, i: (b, g)),
                   pl.BlockSpec((window, hd), lambda b, g, i: (b, g)),
-                  pl.BlockSpec((block, hd), lambda b, g, i: (i, 0)),
-                  pl.BlockSpec((block, hd), lambda b, g, i: (i, 0)),
+                  pl.BlockSpec((qb, hd), lambda b, g, i: (i, 0)),
+                  pl.BlockSpec((qb, hd), lambda b, g, i: (i, 0)),
                   pl.BlockSpec((1, hd), lambda b, g, i: (0, 0))],
-        out_specs=pl.BlockSpec((block, rep * hd), lambda b, g, i: (b * n + i, g)),
-        scratch_shapes=[pltpu.VMEM((rows, hd), k.dtype),
-                        pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, hd), jnp.float32)],
+        out_specs=pl.BlockSpec((qb, rep * hd), lambda b, g, i: (b * n + i, g)),
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=min(_VMEM_CAP, _vmem(
-                block, rep, hd, window, q.dtype.itemsize, size))),
+                qb, keys, rep, hd, window, q.dtype.itemsize, size))),
         cost_estimate=pl.CostEstimate(
             flops=4 * pairs * hd,
             transcendentals=pairs,
@@ -276,12 +415,14 @@ def block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
     position-major as ``wo``'s product reads it: per head
     ``softmax(rot(norm(q)) k^T / sqrt(hd)) v`` over the kept keys of the
     query's window. ``block`` is ``block_for(window)`` unless a test says
-    otherwise; a window that is not whole blocks is padded here and cut
-    from the result. ``interpret=True`` runs the Pallas interpreter, always
-    the caller's explicit choice."""
+    otherwise; a band of at most half of it takes the one-visit form
+    (``one_visit``), any other and a full layer the sweep. A window that is
+    not whole query blocks of the form is padded here and cut from the
+    result. ``interpret=True`` runs the Pallas interpreter, always the
+    caller's explicit choice."""
     p = q.shape[0]
     block = block or block_for(window)
-    pad = -window % block
+    pad = -window % _program(window, band, block)[0]
     if pad:
         b = p // window
 

@@ -1,8 +1,9 @@
 """The blocked attention kernel (ops/pallas/block_attention.py) in the
 Pallas interpreter against the einsum form in query blocks
 (models/mellum_backbone.core_by_einsums), which is what runs off the TPU:
-both layer kinds, windows that are and are not whole blocks, the band's
-edge read key by key."""
+both layer kinds and both forms (the sweep, and the one visit of a narrow
+band), windows that are and are not whole blocks, the band's edge read key by
+key."""
 
 import jax
 import jax.numpy as jnp
@@ -15,13 +16,13 @@ from igaming_platform_tpu.ops.pallas import block_attention as ba
 HEADS, KV, HD = 8, 2, 128
 
 
-def operands(window: int, rows: int = 2, seed: int = 0):
+def operands(window: int, rows: int = 2, seed: int = 0, dtype=jnp.bfloat16):
     key = jax.random.key(seed)
     p = rows * window
     draw = lambda i, shape: jax.random.normal(jax.random.fold_in(key, i), shape)
     q = draw(1, (p, HEADS * HD))
-    k = draw(2, (p, KV * HD)).astype(jnp.bfloat16)
-    v = draw(3, (p, KV * HD)).astype(jnp.bfloat16)
+    k = draw(2, (p, KV * HD)).astype(dtype)
+    v = draw(3, (p, KV * HD)).astype(dtype)
     gain = 1.0 + 0.1 * draw(4, (HD,))
     return q, k, v, gain
 
@@ -30,27 +31,60 @@ def tables(window: int, kind: str = mb.FULL):
     return mb.angle_tables(mb.MellumConfig(), window)[kind]
 
 
-@pytest.mark.parametrize("band", [16, 5, 40, None],
-                         ids=["band16", "band5", "band40", "full"])
-@pytest.mark.parametrize("window,block", [(64, 16), (64, 32), (56, 16), (40, 16),
-                                          (16, 16), (24, 32)],
-                         ids=["whole16", "whole32", "tail8", "tail8-of-40",
-                              "one-block", "shorter-than-a-block"])
-def test_kernel_equals_the_einsum_form_in_query_blocks(window, block, band):
-    q, k, v, gain = operands(window)
+BF16, F32 = jnp.bfloat16, jnp.float32
+# (window, block, band, operands' dtype, the form that runs: the one visit's
+# (query rows, slab keys) or None for the sweep)
+SMALL = [(window, block, band, BF16, ...)
+         for band in (16, 5, 40, None)
+         for window, block in ((64, 16), (64, 32), (56, 16), (40, 16), (16, 16),
+                               (24, 32))]
+NARROW = [
+    # kexaone's sliding layer, and a window of three query blocks
+    (2048, None, 128, BF16, (128, 256)), (384, None, 128, BF16, (128, 256)),
+    (384, None, 128, F32, (128, 256)),
+    # bands that are not whole lanes; the windows padded to whole query
+    # blocks (576, 1040) and cut
+    (512, None, 96, BF16, (96, 256)), (1000, None, 200, BF16, (208, 512)),
+    (1000, None, 200, F32, (208, 512)),
+    # a window that is not whole query blocks
+    (2000, None, 128, BF16, (128, 256)),
+    # the band at half a block, and one row wider
+    (1024, None, 256, BF16, (256, 512)), (1024, None, 257, BF16, None),
+    (1024, None, 257, F32, None),
+    # one query block: the slab is the whole window
+    (128, None, 64, BF16, (64, 128)),
+]
+
+
+def case_id(case) -> str:
+    window, block, band, dtype, _ = case
+    return f"T{window}-block{block}-band{band}-{jnp.dtype(dtype).name}"
+
+
+@pytest.mark.parametrize("case", SMALL + NARROW, ids=case_id)
+def test_kernel_equals_the_einsum_form_in_query_blocks(case):
+    window, block, band, dtype, form = case
+    if form is not ...:
+        assert ba.one_visit(window, band, block) == form
+    q, k, v, gain = operands(window, dtype=dtype)
     cos, sin = tables(window)
     widths = dict(heads=HEADS, kv_heads=KV, window=window, band=band, eps=1e-6)
     want = mb.core_by_einsums(q, k, v, cos, sin, gain, block=block, **widths)
     got = ba.block_attention(q, k, v, cos, sin, gain, block=block,
                              interpret=True, **widths)
-    assert got.shape == want.shape and got.dtype == jnp.bfloat16
+    assert got.shape == want.shape and got.dtype == dtype
     # the kernel rounds its result once to the operands' dtype and its
-    # probabilities before the division: a bfloat16 step of the values
+    # probabilities before the division: a bfloat16 step of the values; in
+    # float32 only the order of the sums differs
+    tol = 0.02 if dtype == BF16 else 1e-4
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
-                               atol=0.02, rtol=0.02)
-    # one sweep of the whole window is the same sum
-    whole = mb.core_by_einsums(q, k, v, cos, sin, gain, block=window, **widths)
-    np.testing.assert_allclose(np.asarray(want), np.asarray(whole), atol=2e-3)
+                               atol=tol, rtol=tol)
+    if window <= 64:
+        # one sweep of the whole window is the same sum
+        whole = mb.core_by_einsums(q, k, v, cos, sin, gain, block=window,
+                                   **widths)
+        np.testing.assert_allclose(np.asarray(want), np.asarray(whole),
+                                   atol=2e-3)
 
 
 def kept_keys(core, window: int, band, block: int):
@@ -71,7 +105,8 @@ def kept_keys(core, window: int, band, block: int):
 
 @pytest.mark.parametrize("core", ["einsums", "kernel"])
 @pytest.mark.parametrize("band,window,block", [(16, 64, 16), (16, 64, 32),
-                                               (24, 56, 16), (None, 64, 16)])
+                                               (24, 56, 16), (None, 64, 16),
+                                               (40, 128, 128), (5, 56, 16)])
 def test_the_band_keeps_what_the_two_inequalities_keep(core, band, window, block):
     run = (mb.core_by_einsums if core == "einsums" else
            lambda *a, **kw: ba.block_attention(*a, interpret=True, **kw))
@@ -93,8 +128,11 @@ def test_the_band_keeps_what_the_two_inequalities_keep(core, band, window, block
     (1024, 1024, 256, 10, 16), (16, 1024, None, 1, 1), (64, 16, 16, 7, 16)])
 def test_visited_blocks_are_what_the_sweep_visits(window, band, block, visited,
                                                   square):
-    assert ba.visited_blocks(window, band, block) == (visited, square)
     blk = block or ba.block_for(window)
+    assert ba.one_visit(window, band, blk) is None
+    # the blocks' area, in (query, key) pairs
+    assert ba.visited_blocks(window, band, block) == (visited * blk * blk,
+                                                      square * blk * blk)
     n = -(-window // blk)
     i, j = np.arange(window)[:, None], np.arange(window)[None, :]
     keep = (j <= i) if band is None else (j <= i) & (i - j < band)
@@ -108,12 +146,60 @@ def test_visited_blocks_are_what_the_sweep_visits(window, band, block, visited,
         assert inside  # the blocks swept without a mask keep every pair
 
 
+@pytest.mark.parametrize("window,band,block,form", [
+    (2048, 128, None, (128, 256)), (384, 128, None, (128, 256)),
+    (1000, 200, None, (208, 512)), (1024, 256, None, (256, 512)),
+    (128, 64, None, (64, 128)), (64, 5, 16, (16, 64)), (56, 5, 16, (16, 64))])
+def test_one_visit_reads_every_kept_key_in_its_slab(window, band, block, form):
+    """The geometry the one-visit kernel computes from its program id: a
+    query block's slab holds every key its rows keep, starts on a 16-row
+    tile inside the padded window, and the count is the slabs' area."""
+    assert ba.one_visit(window, band, block) == form
+    qb, keys = form
+    n = -(-window // qb)
+    assert qb % 16 == 0 and qb >= band and keys <= n * qb
+    for i in range(n):
+        first = max((i + 1) * qb - keys, 0)
+        assert first % 16 == 0 and first + keys <= n * qb
+        assert first <= max(i * qb - band + 1, 0)  # the first row's last key
+        assert first + keys >= (i + 1) * qb        # the last row's own key
+    assert ba.visited_blocks(window, band, block) == (n * qb * keys,
+                                                      (n * qb) ** 2)
+
+
+def test_a_share_summed_over_layers_of_two_forms_is_a_share_of_the_squares():
+    """``visited_blocks`` counts pairs: mellum's layers all sweep by 512, so
+    its share is the blocks' (99 of 256 at 4,096 events); kexaone's sliding
+    layers run the one visit, 16 slabs of 128 x 256 where the sweep visited
+    7 of 16 blocks of 512 x 512, beside a full layer whose count is the
+    sweep's."""
+    from igaming_platform_tpu.models import kexaone_backbone as kb
+    from igaming_platform_tpu.models import phi4flash_backbone as pb
+
+    cell = 512 * 512
+    assert ba.visited_blocks(4096, 1024) == (21 * cell, 64 * cell)
+    assert ba.visited_blocks(4096, None) == (36 * cell, 64 * cell)
+    assert mb.key_blocks(mb.MellumConfig(), 4096) == (99 * cell, 256 * cell)
+    assert ba.visited_blocks(2048, None) == (10 * cell, 16 * cell)
+    # 16 slabs of 128 x 256: the area of 2 blocks where the sweep visited 7
+    assert ba.visited_blocks(2048, 128) == (2 * cell, 16 * cell)
+    assert ba.one_row(2048) == (4 * cell, 16 * cell)
+    visited, square = kb.key_blocks(kb.KExaoneConfig(), 2048)
+    # four sliding layers of 2 blocks' area, the full one's 10, the module's 4
+    assert (visited, square) == ((4 * 2 + 10 + 4) * cell, 6 * 16 * cell)
+    # phi4flash's band of 512 keeps the sweep's count: 41.667%
+    assert pb.key_blocks(pb.Phi4FlashConfig(), 2048) == ((8 * 7 + 4) * cell,
+                                                         9 * 16 * cell)
+
+
 @pytest.mark.parametrize("change,why", [
     (dict(heads=6), "6 heads over 4 key heads"),
     (dict(hd=64), "head width 64 is not whole 128-lane vregs"),
     (dict(window=48), "positions are not whole windows of 48"),
     (dict(dtype=jnp.float16), "operands float16"),
     (dict(window=1 << 17, p=1 << 18), "of VMEM"),
+    (dict(window=1 << 17, p=1 << 18, band=128), "of VMEM"),
+    (dict(window=2048, band=128), ""),
     (dict(), "")])
 def test_declines_says_what_the_kernel_takes(change, why):
     heads, kv = change.get("heads", 32), 4
@@ -122,5 +208,6 @@ def test_declines_says_what_the_kernel_takes(change, why):
     said = ba.declines(jax.ShapeDtypeStruct((p, heads * hd), jnp.float32),
                        jax.ShapeDtypeStruct((p, kv * hd), dt),
                        jax.ShapeDtypeStruct((p, kv * hd), dt),
-                       heads=heads, kv_heads=kv, window=window)
+                       heads=heads, kv_heads=kv, window=window,
+                       band=change.get("band"))
     assert (why in said) if why else said == ""

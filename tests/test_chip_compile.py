@@ -875,8 +875,12 @@ def test_two_depth_step_reads_the_module_at_one_position_a_row(
     place on the 2.42 GB ring, its arguments the state plus 5.6 GB of weights.
     The stack's cores are ``_block_attention`` (ops/pallas/block_attention.py)
     once a layer, four under ``head/attn/window/core`` and one under
-    ``head/attn/full/core``, at 64 / 8 heads; nothing of a window's
-    ``2048,2048`` square is in the module. Of the module the join and the
+    ``head/attn/full/core``, at 64 / 8 heads: the sliding layers' in the
+    one-visit form (a band of 128 is under half a block of 512: 16 query
+    blocks of 128 rows a window, each against one slab of 256 keys), the full
+    layer's the sweep of 10 of 16 blocks of 512, read off the pairs each call
+    says it scores; nothing of a window's ``2048,2048`` square is in the
+    module. Of the module the join and the
     ``K, V`` product meet all 4,096 positions and no other product under
     ``head/mtp`` does. The expert products are ``ragged-dot`` (two slots of a
     6144 x 2048 gate and up are 96 MiB, past what ``grouped_experts.supports``
@@ -884,6 +888,7 @@ def test_two_depth_step_reads_the_module_at_one_position_a_row(
     stack layer's pass loop. Code, temporaries and arguments are printed."""
     from jax.sharding import SingleDeviceSharding
 
+    from igaming_platform_tpu.models import decoder_parts
     from igaming_platform_tpu.models.session_heads import HEADS
     from igaming_platform_tpu.serve import session_state as ss
 
@@ -891,8 +896,18 @@ def test_two_depth_step_reads_the_module_at_one_position_a_row(
     capacity, batch = 24_576, 2
     one = SingleDeviceSharding(topo.devices[0])
     cfg = HEADS["kexaone"].config
+    decoder_parts.announce_core.cache_clear()
     compiled = _compile_step("kexaone", capacity, capacity + 1, one, one,
                              batch=batch)
+    # what ``/debug/sessionz``'s ``head_cores`` would say of this step
+    said = decoder_parts.announced_cores()
+    assert said["attention core (window)"] == (
+        "pallas-blocks (grouped 64/8 of 128, window 2048, band=128: one visit "
+        "of 256 keys a 128-row block) (backend=tpu)")
+    assert said["attention core (full)"] == (
+        "pallas-blocks (grouped 64/8 of 128, window 2048 in blocks of 512, "
+        "band=None: 10 of 16 key blocks; no rotary: unit cos, zero sin) "
+        "(backend=tpu)")
     ring = ss.ring_size(capacity + 1, 2048)
     mem = compiled.memory_analysis()
     with capsys.disabled():
@@ -919,6 +934,13 @@ def test_two_depth_step_reads_the_module_at_one_position_a_row(
     assert sum("head/attn/window/core" in c for c in cores) == 4
     assert sum("head/attn/full/core" in c for c in cores) == 1
     assert not [c for c in cores if "head/mtp" in c]
+    # a call's grid, by the pairs it scores (one ``exp`` each): windows x
+    # heads x (16 blocks of 128 rows x a slab of 256 keys | 10 blocks of
+    # 512 x 512), and no float32 accumulator beside the one-visit form's
+    # stacked queries in VMEM
+    for c in cores:
+        pairs = 16 * 128 * 256 if "window/core" in c else 10 * 512 * 512
+        assert f'"transcendentals":"{batch * cfg.heads * pairs}"' in c, c[-900:]
     assert "2048,2048]" not in text
     # the module: two products over every position (the join, and K with V),
     # every other one over the 2 rows that are read

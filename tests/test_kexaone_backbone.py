@@ -25,6 +25,7 @@ from igaming_platform_tpu.models import session_heads
 CONFIG = "risk-seqhead-k-exaone-236b-a23b"
 CELL = "kexaone-mtp-deep2048"
 EVENTS, BAND = 48, 8
+BLOCK = 512 * 512  # pairs of a block at the cell's window: the counters' unit is pairs
 SWITCHES = ("WITHOUT_BAND", "ROPE_ON_FULL", "WITHOUT_MTP", "JOIN_SAME_EVENT",
             "WITHOUT_SHARED")
 
@@ -194,7 +195,8 @@ def test_a_window_inside_the_band_is_full_attention():
         got = program(params, win, lengths, small_config(
             sliding_window=band, operand_dtype=jnp.float32))
         np.testing.assert_allclose(got, want, atol=2e-5)
-    assert kb.key_blocks(small_config(), BAND) == (6, 6)
+    # every layer the whole square of the window's one 16-row block
+    assert kb.key_blocks(small_config(), BAND) == (6 * 256, 6 * 256)
 
 
 def test_the_band_is_the_sources_list():
@@ -274,8 +276,9 @@ def test_padding_cannot_reach_the_score(small):
 def test_the_einsum_core_equals_the_kernel_at_64_over_8_heads(band):
     """``decoder_parts.core_by_einsums`` against ``block_attention`` in the
     Pallas interpreter at the published 64 / 8 heads of 128, a band (128)
-    narrower than the kernel's block (here 256 of a 512-event window), and
-    the full layer's unit cos and zero sin, which leave ``q`` as it was."""
+    of half the kernel's block (here 256 of a 512-event window: the one-visit
+    form, as at the cell's 512), and the full layer's unit cos and zero sin,
+    which leave ``q`` as it was."""
     from igaming_platform_tpu.ops.pallas import block_attention as ba
 
     window, heads, kv, hd = 512, 64, 8, 128
@@ -289,7 +292,9 @@ def test_the_einsum_core_equals_the_kernel_at_64_over_8_heads(band):
     cos, sin = kb.angle_tables(kb.KExaoneConfig(), window)[kind]
     widths = dict(heads=heads, kv_heads=kv, window=window, band=band, eps=1e-5,
                   block=256)
-    assert ba.declines(q, k, v, heads=heads, kv_heads=kv, window=window) == ""
+    assert ba.declines(q, k, v, heads=heads, kv_heads=kv, window=window,
+                       band=band) == ""
+    assert ba.one_visit(window, band, 256) == (None if band is None else (128, 256))
     want = dp.core_by_einsums(q, k, v, cos, sin, gain, **widths)
     got = ba.block_attention(q, k, v, cos, sin, gain, interpret=True, **widths)
     assert got.shape == want.shape and got.dtype == jnp.bfloat16
@@ -301,7 +306,7 @@ def test_the_einsum_core_equals_the_kernel_at_64_over_8_heads(band):
         np.testing.assert_array_equal(np.asarray(turned).reshape(q.shape),
                                       np.asarray(q))
     else:
-        assert ba.visited_blocks(2048, 128) == (7, 16)
+        assert ba.visited_blocks(2048, 128) == (2 * BLOCK, 16 * BLOCK)
 
 
 def test_the_scopes_and_the_cores_said(small):
@@ -340,9 +345,11 @@ def test_the_row_of_heads_and_what_it_holds():
                for a in leaves) == pytest.approx(5.595e9, rel=1e-3)
     # the cell's window: five layers at 2,048 positions, the module's at one
     assert row.layer_positions(2048) == (5 * 2048 + 1, 6 * 2048)
-    # four band layers of 7 of 16 blocks of 512 keys, the full one's 10, and
-    # the module's one query a row meets one row of 4
-    assert row.key_blocks(2048) == (4 * 7 + 10 + 4, 6 * 16)
+    # in pairs, by blocks of 512 x 512: four band layers' one visit a query
+    # block, 16 slabs of 128 x 256 (2 blocks' area where the sweep visited 7),
+    # the full one's 10 of 16, and the module's one query a row meets one
+    # row of 4
+    assert row.key_blocks(2048) == ((4 * 2 + 10 + 4) * BLOCK, 6 * 16 * BLOCK)
     assert "'kexaone'" in str(pytest.raises(
         ValueError, session_heads.session_head, "kimi").value)
 
@@ -357,7 +364,7 @@ def test_the_server_counts_the_module_once_and_its_blocks(monkeypatch):
     manager = ss.SessionStateManager(8, n_events=2048, head="kexaone",
                                      metrics=metrics)
     assert manager.head_layer_positions == (10241, 12288)
-    assert manager.head_key_blocks == (42, 96)
+    assert manager.head_key_blocks == (22 * BLOCK, 96 * BLOCK)
     with manager.lock:
         manager.prepare_chunk(ss.group_chunk(["a", "b", "a"]),
                               np.array([100.0, 200.0, 300.0], np.float32),
@@ -365,8 +372,8 @@ def test_the_server_counts_the_module_once_and_its_blocks(monkeypatch):
     text = metrics.registry.render_text().replace(".0\n", "\n")
     assert "risk_session_head_layer_positions_computed_total 30723" in text
     assert "risk_session_head_layer_positions_whole_total 36864" in text
-    assert "risk_session_head_key_blocks_visited_total 126" in text
-    assert "risk_session_head_key_blocks_square_total 288" in text
+    assert f"risk_session_head_key_blocks_visited_total {3 * 22 * BLOCK}" in text
+    assert f"risk_session_head_key_blocks_square_total {3 * 96 * BLOCK}" in text
     for kind, count in (("window", 4), ("attention", 2), ("dense", 1), ("moe", 5),
                         ("mtp", 1), ("ssm", 0)):
         assert f'risk_session_head_layers{{kind="{kind}"}} {count}' in text

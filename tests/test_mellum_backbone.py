@@ -22,6 +22,7 @@ from igaming_platform_tpu.models import session_heads
 CONFIG = "risk-seqhead-mellum2-12b-a2.5b"
 CELL = "mellum2-swa-deep4096"
 EVENTS, BAND = 64, 16
+BLOCK = 512 * 512  # pairs of a block at the cell's window: the counters' unit is pairs
 # what a row's probability may differ by: the cell's per-row limit
 TOLERANCE = validate.load_data("configs", CONFIG)["limits"]["fraud_prob_max_err"]
 
@@ -242,8 +243,9 @@ def test_the_row_of_heads_and_what_it_holds():
     assert sum(math.prod(a.shape) * a.dtype.itemsize
                for a in leaves) == pytest.approx(3.342e9, rel=1e-3)
     # the cell's window at the kernel's block: 3 x 21 + 36 of 4 x 64 blocks
-    assert row.key_blocks(4096) == (99, 256)
-    assert row.key_blocks(16) == (4, 4)  # inside the band nothing is skipped
+    # (in pairs: the blocks' area)
+    assert row.key_blocks(4096) == (99 * BLOCK, 256 * BLOCK)
+    assert row.key_blocks(16) == (4 * 256, 4 * 256)  # inside the band nothing is skipped
     assert all(r.key_blocks is None for name, r in session_heads.HEADS.items()
                if name not in ("mellum", "phi4flash", "kexaone"))
     with pytest.raises(ValueError) as err:
@@ -260,14 +262,14 @@ def test_the_server_counts_key_blocks_a_scored_row(monkeypatch):
     metrics = ServiceMetrics("risk")
     manager = ss.SessionStateManager(8, n_events=4096, head="mellum",
                                      metrics=metrics)
-    assert manager.head_key_blocks == (99, 256)
+    assert manager.head_key_blocks == (99 * BLOCK, 256 * BLOCK)
     with manager.lock:
         manager.prepare_chunk(ss.group_chunk(["a", "b", "a"]),
                               np.array([100.0, 200.0, 300.0], np.float32),
                               np.array([2, 2, 0], np.int32), 1_700_000_000.0)
     text = metrics.registry.render_text().replace(".0\n", "\n")
-    assert "risk_session_head_key_blocks_visited_total 297" in text
-    assert "risk_session_head_key_blocks_square_total 768" in text
+    assert f"risk_session_head_key_blocks_visited_total {297 * BLOCK}" in text
+    assert f"risk_session_head_key_blocks_square_total {768 * BLOCK}" in text
     assert 'risk_session_head_layers{kind="window"} 3' in text
     # a head that sweeps no blocks counts none
     plain = ServiceMetrics("risk")

@@ -24,6 +24,7 @@ from igaming_platform_tpu.models import session_heads
 CONFIG = "risk-seqhead-phi-4-mini-flash"
 CELL = "phi4flash-yoco-deep2048"
 EVENTS, BAND, CHUNK = 24, 8, 8
+BLOCK = 512 * 512  # pairs of a block at the cell's window: the counters' unit is pairs
 
 
 def misses(got, stated, exact) -> bool:
@@ -276,7 +277,7 @@ def test_the_row_of_heads_and_what_it_holds():
     assert 17 * 2048 + 15 == pytest.approx(0.5315 * 32 * 2048, rel=1e-3)
     # eight band layers of 7 of 16 blocks, and the full layer's one query a
     # row meets one row of 4
-    assert row.key_blocks(2048) == (8 * 7 + 4, 9 * 16)
+    assert row.key_blocks(2048) == ((8 * 7 + 4) * BLOCK, 9 * 16 * BLOCK)
     assert all(r.layer_positions is None
                for name, r in session_heads.HEADS.items()
                if name not in ("phi4flash", "kexaone"))
@@ -301,7 +302,7 @@ def test_the_server_counts_layer_positions_a_scored_row(monkeypatch):
     text = metrics.registry.render_text().replace(".0\n", "\n")
     assert "risk_session_head_layer_positions_computed_total 104493" in text
     assert "risk_session_head_layer_positions_whole_total 196608" in text
-    assert "risk_session_head_key_blocks_visited_total 180" in text
+    assert f"risk_session_head_key_blocks_visited_total {180 * BLOCK}" in text
     for kind, count in (("ssm", 9), ("window", 8), ("attention", 1),
                         ("memory", 7), ("cross", 7), ("dense", 32), ("moe", 0)):
         assert f'risk_session_head_layers{{kind="{kind}"}} {count}' in text
