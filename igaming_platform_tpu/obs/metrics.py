@@ -627,7 +627,8 @@ class ServiceMetrics:
         self.h2d_transfers_total = self.registry.counter(
             f"{service}_h2d_transfers_total",
             "Host (numpy) arguments handed to the index-mode scoring "
-            "program, one host-to-device transfer each: reckoned once per "
+            "program, one host-to-device transfer each (the packed chunk: "
+            "one a launch, placed once a device): reckoned once per "
             "(program, padded shape), added per launch",
         )
         self.h2d_bytes_total = self.registry.counter(

@@ -17,17 +17,22 @@ it holds their shardings for the three placements and what they donate.
 
 Call forms, uniform over every ``(sketch, shadow)``::
 
-    cached(params, cand, table, flags, idxs, amounts, types, bl, thr, n)
+    cached(params, cand, table, flags, chunk, thr)
       -> (packed[, sketch][, shadow_packed])
     session(params, sparams, table, flags, ring, cursor, length,
-            idxs, sidx, occ, amounts, types, events, bl, thr, cand, n)
+            chunk, thr, cand)
       -> (packed, ring', cursor', length'[, sketch][, shadow_packed])
 
-``packed`` is int32 [5, B] (score, action, reason_mask, rule_score,
-ml_score bits); ``cand`` is the shadow candidate's param tree (None
-without one), ``sparams`` the session head's, ``n`` the count of real
-rows. All three are TRACED arguments, never closure constants: a new
-candidate or a replaced head tree reuses the compiled executables.
+``chunk`` is the launch's ONE host array: the padded rows' columns as
+32-bit words, int32 [B, CHUNK_WORDS] (:func:`pack_chunk` writes it on
+the host, :func:`unpack_chunk` reads it at the top of either body), so a
+launch is one host-to-device transfer whatever the placement. ``thr``
+lives on the device (placed when an operator sets it). ``packed`` is
+int32 [5, B] (score, action, reason_mask, rule_score, ml_score bits);
+``cand`` is the shadow candidate's param tree (None without one),
+``sparams`` the session head's. Both are TRACED arguments, never
+closure constants: a new candidate or a replaced head tree reuses the
+compiled executables.
 """
 
 from __future__ import annotations
@@ -56,6 +61,83 @@ from igaming_platform_tpu.serve.session_state import (
 
 _TX_COLS = (int(F.TX_AMOUNT), int(F.TX_TYPE_DEPOSIT),
             int(F.TX_TYPE_WITHDRAW), int(F.TX_TYPE_BET))
+
+# The packed chunk's word layout, a row of int32 words a padded row: the
+# table index, the within-batch occurrence rank, the amount's float32
+# bits, the wire tx code, the flags, then the event's EVENT_WIDTH float32
+# words by their bits. A pad row is all zeros but for its rank (pad rows
+# all append to the scratch slot: distinct ranks keep them off each
+# other), so it gathers slot 0, is scored and is discarded.
+W_IDX, W_OCC, W_AMOUNT, W_TYPE, W_FLAGS, W_EVENT = range(6)
+CHUNK_WORDS = W_EVENT + EVENT_WIDTH
+FLAG_BLACKLIST = 1  # the request's blacklist bit
+FLAG_REAL = 2       # a real row, not padding: the count of them is ``n``
+
+
+def pack_chunk(shape: int, idxs, amounts, types, bl, occ=None) -> np.ndarray:
+    """The host half of a launch: one FRESH int32 [shape, CHUNK_WORDS]
+    array holding what the chunk's ids and wire columns decide (fresh by
+    design: jax may alias host memory zero-copy on the CPU backend, so a
+    pooled buffer could be read by an in-flight dispatch). The event
+    words stay zero until ``session_state.encode_events_host`` writes
+    them through :func:`chunk_events`; ``occ`` is the session family's
+    (session_state.group_chunk)."""
+    n = len(idxs)
+    chunk = np.zeros((shape, CHUNK_WORDS), dtype=np.int32)  # noqa: MX04 — fresh per dispatch (zero-copy aliasing)
+    chunk[:n, W_IDX] = idxs
+    chunk.view(np.float32)[:n, W_AMOUNT] = amounts
+    chunk[:n, W_TYPE] = types
+    chunk[:n, W_FLAGS] = FLAG_REAL + FLAG_BLACKLIST * np.asarray(bl, dtype=bool)
+    if occ is not None:
+        chunk[:n, W_OCC] = occ
+    chunk[n:, W_OCC] = np.arange(shape - n, dtype=np.int32)
+    return chunk
+
+
+def chunk_events(chunk: np.ndarray, n: int) -> np.ndarray:
+    """The event words of the first ``n`` rows of a packed chunk as the
+    float32 [n, EVENT_WIDTH] they are: a view, not a copy."""
+    return chunk[:n, W_EVENT:].view(np.float32)
+
+
+def chunk_columns(chunk: np.ndarray):
+    """``(idxs, amounts, types)`` of a packed chunk as host views, for
+    the split drift sketch (its own launch, obs/drift.py)."""
+    return (chunk[:, W_IDX], chunk.view(np.float32)[:, W_AMOUNT],
+            chunk[:, W_TYPE])
+
+
+class Chunk(NamedTuple):
+    """A packed chunk's columns on the device, bit for bit what the host
+    wrote. ``real`` marks the rows that are not padding; ``n`` counts
+    them."""
+
+    idxs: Any
+    occ: Any
+    amounts: Any
+    types: Any
+    events: Any
+    bl: Any
+    real: Any
+    n: Any
+
+
+def unpack_chunk(chunk) -> Chunk:
+    """The device half: slices and bitcasts of the one array, at the top
+    of either family's body. The columns leave behind one fence, so the
+    rest of the step is compiled as it was when each was an argument of
+    its own (without it XLA re-plans a backbone step's VMEM around the
+    slices: ``keye``'s and ``lfm2``'s normed positions went back to being
+    copied out to HBM for the expert kernels, tests/test_chip_compile.py
+    ``_assert_rows_come_in_by_the_kernel``)."""
+    f32 = lambda w: jax.lax.bitcast_convert_type(w, jnp.float32)  # noqa: E731
+    flags = chunk[:, W_FLAGS]
+    real = (flags & FLAG_REAL) != 0
+    return jax.lax.optimization_barrier(Chunk(
+        idxs=chunk[:, W_IDX], occ=chunk[:, W_OCC],
+        amounts=f32(chunk[:, W_AMOUNT]), types=chunk[:, W_TYPE],
+        events=f32(chunk[:, W_EVENT:]), bl=(flags & FLAG_BLACKLIST) != 0,
+        real=real, n=jnp.sum(real.astype(jnp.int32))))
 
 
 def stack_packed(out: dict):
@@ -150,12 +232,13 @@ def cached_body(score_fn, acc: SlotAccess, sketch: bool, shadow: bool):
     """The ``cached`` family's body: compose -> score [-> sketch]
     [-> shadow]."""
 
-    def _cached_body(params, cand, table, flags, idxs, amounts, types, bl,
-                     thr, n):
-        x, blv = compose_rows(acc.take, table, flags, idxs, amounts, types, bl)
+    def _cached_body(params, cand, table, flags, chunk, thr):
+        c = unpack_chunk(chunk)
+        x, blv = compose_rows(acc.take, table, flags, c.idxs, c.amounts,
+                              c.types, c.bl)
         packed = stack_packed(score_fn(params, x, blv, thr))
         return epilogue(
-            [packed], x, packed, n, sketch,
+            [packed], x, packed, c.n, sketch,
             (lambda: stack_packed(score_fn(cand, x, blv, thr)))
             if shadow else None)
 
@@ -168,10 +251,11 @@ def session_body(score_fn, cfg, spec, acc: SlotAccess, sketch: bool,
     over the post-append window -> fold -> in-place append [-> sketch]
     [-> shadow].
 
-    ``idxs`` indexes the feature table; ``sidx`` the ring (pad rows ->
-    ``capacity``: the scratch slot on one device, nobody's slot on a
-    slot-sharded mesh); ``occ`` is the host-computed within-batch
-    occurrence rank, so duplicate accounts append at distinct offsets.
+    The chunk's index column indexes the feature table; ``sidx``, derived
+    from it here, the ring (pad rows -> ``capacity``: the scratch slot on
+    one device, nobody's slot on a slot-sharded mesh); ``occ`` is the
+    host-computed within-batch occurrence rank, so duplicate accounts
+    append at distinct offsets.
     A row whose post-append window is WARM (>= ``min_events``) and whose
     head probability reaches ``flag_threshold`` has its ML component
     raised to it (``SESSION_PATTERN`` bit) and recombines through the
@@ -206,9 +290,13 @@ def session_body(score_fn, cfg, spec, acc: SlotAccess, sketch: bool,
 
     # Named ``_body``: the benchmark finds this program in a trace by
     # ``^jit__body\(`` (chipbench/layer_metrics/device_step_ms.json).
-    def _body(params, sparams, table, flags, ring, cursor, length,
-              idxs, sidx, occ, amounts, types, events, bl, thr, cand, n):
-        x, blv = compose_rows(acc.take, table, flags, idxs, amounts, types, bl)
+    def _body(params, sparams, table, flags, ring, cursor, length, chunk,
+              thr, cand):
+        c = unpack_chunk(chunk)
+        occ, events = c.occ, c.events
+        sidx = jnp.where(c.real, c.idxs, capacity)
+        x, blv = compose_rows(acc.take, table, flags, c.idxs, c.amounts,
+                              c.types, c.bl)
         out = score_fn(params, x, blv, thr)
 
         # -- session head over the post-append window ---------------------
@@ -235,7 +323,7 @@ def session_body(score_fn, cfg, spec, acc: SlotAccess, sketch: bool,
         cursor2, length2 = advance_counters(cursor, length, li, ln, occ,
                                             owned, n_events)
         return epilogue(
-            [packed, ring2, cursor2, length2], x, packed, n, sketch,
+            [packed, ring2, cursor2, length2], x, packed, c.n, sketch,
             (lambda: _session_fold(score_fn(cand, x, blv, thr), sprob, fold,
                                    cold, thr))
             if shadow else None)
@@ -245,14 +333,12 @@ def session_body(score_fn, cfg, spec, acc: SlotAccess, sketch: bool,
 
 # What each positional argument and leading output of a family is, for
 # placement: a param ``tree``, the feature ``table``, a 1-D per-``slot``
-# array, a per-row ``vec`` / ``row`` column, a small ``repl`` value, the
-# ``packed`` [5, B] result.
+# array, the packed ``chunk`` (a row a padded row), a small ``repl``
+# value, the ``packed`` [5, B] result.
 _ARGS = {
-    "cached": ("tree", "tree", "table", "slot",
-               "vec", "vec", "vec", "vec", "repl", "repl"),
+    "cached": ("tree", "tree", "table", "slot", "chunk", "repl"),
     "session": ("tree", "tree", "table", "slot", "slot", "slot", "slot",
-                "vec", "vec", "vec", "vec", "vec", "row", "vec", "repl",
-                "tree", "repl"),
+                "chunk", "repl", "tree"),
 }
 _OUTS = {"cached": ("packed",), "session": ("packed", "slot", "slot", "slot")}
 # ring, cursor, length: outputs alias them; nothing else is donated.
@@ -291,8 +377,7 @@ def build(score_fn, cfg, *, family: str, sketch: bool, shadow: bool,
         repl = NamedSharding(mesh, P())
         place = {
             "tree": None, "table": repl, "slot": repl, "repl": repl,
-            "vec": NamedSharding(mesh, P(AXIS_DATA)),
-            "row": NamedSharding(mesh, P(AXIS_DATA, None)),
+            "chunk": NamedSharding(mesh, P(AXIS_DATA, None)),
             "packed": NamedSharding(mesh, P(None, AXIS_DATA)),
         }
         return jax.jit(
@@ -303,16 +388,11 @@ def build(score_fn, cfg, *, family: str, sketch: bool, shadow: bool,
     return jax.jit(body, donate_argnums=donate)
 
 
-def warm_columns(shape: int, capacity: int) -> dict[str, Any]:
-    """Dummy batch columns of one ladder shape, for AOT warm-up: every
-    row gathers slot 0 with tx type "other" and appends (session) to
-    slot ``capacity`` at its own rank: no real account's window moves."""
-    return {
-        "idxs": np.zeros((shape,), dtype=np.int32),
-        "sidx": np.full((shape,), capacity, dtype=np.int32),
-        "occ": np.arange(shape, dtype=np.int32),
-        "amounts": np.zeros((shape,), dtype=np.float32),
-        "types": np.full((shape,), 4, dtype=np.int32),
-        "events": np.zeros((shape, EVENT_WIDTH), dtype=np.float32),
-        "bl": np.zeros((shape,), dtype=bool),
-    }
+def warm_columns(shape: int) -> np.ndarray:
+    """The dummy batch columns of one ladder shape, packed, for AOT
+    warm-up: no row is real, so every row gathers slot 0 with tx type
+    "other" and appends (session) to slot ``capacity`` at its own rank:
+    no real account's window moves."""
+    chunk = pack_chunk(shape, (), (), (), ())
+    chunk[:, W_TYPE] = 4
+    return chunk

@@ -264,6 +264,10 @@ class TPUScoringEngine:
                 params = shard_model_params(mesh, ml_backend, params)
                 self._model_sharded = True
         params = self._params = self._place_params(params)
+        # What the index programs read: placed where they run when it is
+        # set, never re-sent by a launch. ``_thresholds`` stays the host
+        # copy (the row paths, get_thresholds, the heuristic tier).
+        self._thresholds_dev = self._place_replicated(self._thresholds)
 
         # WIRE_DTYPE=bf16 (opt-in): ship feature batches to the device as
         # bfloat16 — half the host->device bytes; the graph casts back to
@@ -586,9 +590,10 @@ class TPUScoringEngine:
                 fn = jax.jit(drift_mod.cached_sketch_kernel)
                 # AOT-warm every ladder shape against the live table.
                 for shape in self._shapes:
-                    c = index_program.warm_columns(shape, self.cache.capacity)
                     jax.device_get(fn(
-                        self.cache.table, c["idxs"], c["amounts"], c["types"],
+                        self.cache.table,
+                        *index_program.chunk_columns(
+                            index_program.warm_columns(shape)),
                         np.zeros((5, shape), dtype=np.int32), np.int32(0)))
                 self._drift_cached_fn = fn
         return self._drift_cached_fn
@@ -622,11 +627,11 @@ class TPUScoringEngine:
         except Exception:  # noqa: CC04 — drift observability must never fail scoring; the engine counts its errors
             drift.note_error()
 
-    def _note_drift_cached(self, idxsp, amtp, typp, packed, n: int,
-                           sketch=None) -> None:
+    def _note_drift_cached(self, chunk, packed, n: int, sketch=None) -> None:
         """Index-mode twin of ``_note_drift``: a fused cached/session
         variant computes the sketch in-graph; the split fallback re-gathers
-        the HBM-resident rows with one extra, honestly-counted launch."""
+        the HBM-resident rows with one extra, honestly-counted launch,
+        fed views of the launch's packed chunk."""
         drift = self.drift
         if drift is None or n <= 0:
             return
@@ -637,8 +642,10 @@ class TPUScoringEngine:
             fn = self._ensure_drift_cached_fn()
             if fn is None:
                 return
-            _device_dispatch("cached_sketch_kernel", idxsp.shape, idxsp.dtype)
-            drift.submit(fn(self.cache.table, idxsp, amtp, typp, packed,
+            _device_dispatch("cached_sketch_kernel", chunk.shape[:1],
+                             chunk.dtype)
+            drift.submit(fn(self.cache.table,
+                            *index_program.chunk_columns(chunk), packed,
                             np.int32(n)), n)
         except Exception:  # noqa: CC04 — drift observability must never fail scoring; the engine counts its errors
             drift.note_error()
@@ -732,21 +739,18 @@ class TPUScoringEngine:
         else:
             cache = cache if cache is not None else self.cache
             mgr = self.session
+            thr = self._thresholds_dev
             for shape in self._shapes:
-                c = index_program.warm_columns(shape, cache.capacity)
+                chunk = index_program.warm_columns(shape)
                 if family == "cached":
-                    res = ffn(
-                        params, cand, cache.table, cache.flags, c["idxs"],
-                        c["amounts"], c["types"], c["bl"], self._thresholds,
-                        np.int32(0))
+                    res = ffn(params, cand, cache.table, cache.flags, chunk,
+                              thr)
                 else:
                     with mgr.lock:
                         res = ffn(
                             params, mgr.head_params, cache.table, cache.flags,
                             mgr.session_ring, mgr.session_cursor,
-                            mgr.session_length, c["idxs"], c["sidx"],
-                            c["occ"], c["amounts"], c["types"], c["events"],
-                            c["bl"], self._thresholds, cand, np.int32(0))
+                            mgr.session_length, chunk, thr, cand)
                         mgr.adopt(res[1], res[2], res[3])
                 jax.device_get(res[0])
         self._fused_ready.add((family, sketch, shadow))  # noqa: CC10 — publish-once GIL-atomic set: each key added by exactly one warm thread, after every shape compiled
@@ -923,15 +927,18 @@ class TPUScoringEngine:
         """Served params live on the device(s) that score with them: a
         host (numpy) tree — what a seeded or freshly restored boot hands
         over — would otherwise be shipped host->device again by every
-        dispatch. Replicated over a mesh, on the default device without
-        one; a model-sharded tree already has its layout."""
+        dispatch. A model-sharded tree already has its layout."""
         if params is None or self._model_sharded:
             return params
+        return self._place_replicated(params)
+
+    def _place_replicated(self, tree: Any) -> Any:
+        """Replicated over the mesh, on the default device without one."""
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            return jax.device_put(params, NamedSharding(self._mesh, P()))
-        return jax.device_put(params)
+            return jax.device_put(tree, NamedSharding(self._mesh, P()))
+        return jax.device_put(tree)
 
     def swap_params(self, params: Any) -> None:  # analysis: param-swap-seam
         """Atomically install new model parameters (hot-swap from train/).
@@ -981,6 +988,7 @@ class TPUScoringEngine:
     def set_thresholds(self, block: int, review: int) -> None:
         """Runtime threshold tuning (engine.go:498-504) — no recompile."""
         self._thresholds = np.array([block, review], dtype=np.int32)
+        self._thresholds_dev = self._place_replicated(self._thresholds)
         if self._fn_host is not None:
             self._thresholds_host = jax.device_put(self._thresholds, self._host_cpu)
 
@@ -1140,21 +1148,12 @@ class TPUScoringEngine:
             with span("score.session", batch=n):
                 groups = session_mod.group_chunk(account_ids)
         with span("score.pad", batch=n):
-            idxsp, _ = pad_batch(idxs, shape)
-            amtp, _ = pad_batch(amounts, shape)
-            typp, _ = pad_batch(types, shape)
-            blp, _ = pad_batch(bl, shape)
-            if family == "session":
-                occp, _ = pad_batch(groups.occ, shape)
-                # Fresh per-chunk buffer by design: jax may alias host
-                # memory zero-copy on the CPU backend, so a pooled
-                # buffer could be read by an in-flight dispatch.
-                sidxp = np.full((shape,), mgr.capacity, dtype=np.int32)  # noqa: MX04 — scratch-slot pad template must be fresh per dispatch (zero-copy aliasing)
-                sidxp[:n] = idxs
-                if n < shape:
-                    # Pad rows all target the scratch slot: distinct
-                    # occurrence ranks keep their appends off each other.
-                    occp[n:] = np.arange(shape - n, dtype=np.int32)
+            # The launch's one host array (index_program.pack_chunk):
+            # every column the ids and the wire decide is written here,
+            # before the lock; the event words follow under it.
+            chunk = index_program.pack_chunk(
+                shape, idxs, amounts, types, bl,
+                occ=groups.occ if family == "session" else None)
         if snap is None:
             snap = self.params_snapshot()
         params = snap[0]
@@ -1185,18 +1184,18 @@ class TPUScoringEngine:
                     got = time.perf_counter()
                 ts = now if now is not None else ledger_mod.wall_clock()
                 # Session bookkeeping rides its own span (hostprof us/row).
+                # (the events need the host index's gaps, so they are
+                # encoded under the lock, into the chunk's event words).
                 with span("score.session", batch=n):
-                    events, _, post_len, seqs, audit = mgr.prepare_chunk(
-                        groups, amounts, types, ts)
-                with span("score.pad", batch=n):
-                    evp, _ = pad_batch(events, shape)
+                    _, _, post_len, seqs, audit = mgr.prepare_chunk(
+                        groups, amounts, types, ts,
+                        events_out=index_program.chunk_events(chunk, n))
                 with span("score.launch", batch=n):
                     args = (params, mgr.head_params, self.cache.table,
                             self.cache.flags, mgr.session_ring,
-                            mgr.session_cursor, mgr.session_length, idxsp,
-                            sidxp, occp, amtp, typp, evp, blp,
-                            self._thresholds, cand, np.int32(n))
-                    self._note_launch(label, idxsp, args)
+                            mgr.session_cursor, mgr.session_length, chunk,
+                            self._thresholds_dev, cand)
+                    self._note_launch(label, shape, n, args)
                     out, ring2, cur2, len2, *extra = ffn(*args)
                     mgr.adopt(ring2, cur2, len2)
                 mgr.note_lock(got - asked, time.perf_counter() - got)
@@ -1208,8 +1207,8 @@ class TPUScoringEngine:
         else:
             with span("score.launch", batch=n):
                 args = (params, cand, self.cache.table, self.cache.flags,
-                        idxsp, amtp, typp, blp, self._thresholds, np.int32(n))
-                self._note_launch(label, idxsp, args)
+                        chunk, self._thresholds_dev)
+                self._note_launch(label, shape, n, args)
                 out, *extra = ffn(*args)
         # After the family's outputs: [sketch][, shadow_packed]. Without
         # an in-graph sketch the split kernel re-gathers the scored rows
@@ -1217,25 +1216,28 @@ class TPUScoringEngine:
         sk = extra[0] if has_sketch else None
         sh = extra[-1] if sstate is not None else None
         with span("score.post_launch", batch=n):
-            self._note_drift_cached(idxsp, amtp, typp, out, n, sketch=sk)
-            self._note_shadow(out, None, blp, n, self._thresholds,
+            self._note_drift_cached(chunk, out, n, sketch=sk)
+            self._note_shadow(out, None, None, n, self._thresholds,
                               shadow_out=sh,
                               gen=sstate[0] if sstate is not None else None)
             if hasattr(out, "copy_to_host_async"):
                 out.copy_to_host_async()
         return out, n, smeta
 
-    def _note_launch(self, label: str, idxsp: np.ndarray, args: tuple) -> None:
+    def _note_launch(self, label: str, shape: int, n: int,
+                     args: tuple) -> None:
         """The launch seam of the index program (``_device_dispatch``), and
         what the launch hands over the link: every host (numpy) leaf of
-        ``args`` is its own host-to-device transfer. Which leaves are host
-        arrays is a property of the program and its padded shape, so it is
-        reckoned once per (label, shape) and added per launch, beside the
-        padded rows themselves (the rung the chunk ran)."""
+        ``args`` is its own host-to-device transfer (one leaf, the packed
+        chunk; a leaf is one placement on one device and one a device on
+        a mesh). Which leaves are host arrays is a property of the
+        program and its padded shape, so it is reckoned once per (label,
+        shape) and added per launch, beside the padded rows themselves
+        (the rung the chunk ran) and the real rows among them."""
         from igaming_platform_tpu.obs import runtime_telemetry as _rt
 
-        _device_dispatch(label, idxsp.shape, idxsp.dtype)
-        key = (label, idxsp.shape)
+        _device_dispatch(label, (shape,), np.int32)
+        key = (label, shape)
         cost = self._h2d_cost.get(key)
         if cost is None:
             host = [a for a in jax.tree_util.tree_leaves(args)
@@ -1243,8 +1245,8 @@ class TPUScoringEngine:
             cost = self._h2d_cost[key] = (
                 len(host), sum(int(a.nbytes) for a in host))
         _rt.note_h2d(*cost)
-        _rt.note_padded_rows(idxsp.shape[0])
-        _rt.note_occupancy(int(args[-1]))
+        _rt.note_padded_rows(shape)
+        _rt.note_occupancy(n)
 
     def _blacklist_flags(self, n: int, ips, devices, fingerprints) -> np.ndarray:
         """Per-request blacklist vector from the host sets — the cheap

@@ -93,15 +93,18 @@ def session_enabled_env() -> bool:
 # Event encoding + window hash (host side — shared verbatim by replay)
 
 
-def encode_events_host(amounts, tx_codes, dts) -> np.ndarray:
+def encode_events_host(amounts, tx_codes, dts, out=None) -> np.ndarray:
     """[B] amounts / wire tx codes / inter-event gaps -> [B, EVENT_WIDTH]
     float32 event rows. This is THE event codec: the serving path scatters
     these exact bytes into HBM, the session hash covers them, and
     tools/replay.py re-derives them from recorded values — float64
     arithmetic up to the final float32 cast so both sides agree bitwise.
+    ``out`` is a ZEROED float32 [B, EVENT_WIDTH] to write the rows into
+    (the event words of the launch's packed chunk,
+    serve/index_program.chunk_events), else they get an array of their own.
     """
     b = len(amounts)
-    ev = np.zeros((b, EVENT_WIDTH), dtype=np.float32)
+    ev = np.zeros((b, EVENT_WIDTH), dtype=np.float32) if out is None else out
     ev[:, 0] = np.log1p(np.maximum(np.asarray(amounts, np.float64), 0.0))
     ev[:, 1] = np.log1p(np.maximum(np.asarray(dts, np.float64), 0.0))
     codes = np.clip(np.asarray(tx_codes, np.int64), 0, len(_TX_EVENT_COL) - 1)
@@ -723,7 +726,8 @@ class SessionStateManager:
 
     # -- the append path (fused step prepare/adopt) ---------------------------
 
-    def prepare_chunk(self, groups: ChunkGroups, amounts, tx_codes, now: float):  # analysis: session-append-seam
+    def prepare_chunk(self, groups: ChunkGroups, amounts, tx_codes, now: float,
+                      events_out=None):  # analysis: session-append-seam
         """Under ``lock``, with the chunk already grouped by account
         (:func:`group_chunk`, before the lock): encode this chunk's
         events, compute every row's post-append window length and
@@ -732,7 +736,10 @@ class SessionStateManager:
         see the chunk-start state), then commit the events to the index
         in row order. The caller dispatches the fused step — which
         applies the identical semantics to the device ring — before
-        releasing the lock. Session hashes are NOT computed here: the
+        releasing the lock. ``events_out`` is where the events are encoded
+        (``encode_events_host``'s ``out``: the launch's packed chunk), so
+        the rows the device appends, the index commits and the audit
+        hashes are one array. Session hashes are NOT computed here: the
         returned :class:`SessionChunkAudit` carries the snapshots and
         hashes lazily on the ledger writer thread.
 
@@ -777,7 +784,7 @@ class SessionStateManager:
             dts = np.where(seq0 > 0, np.maximum(0.0, now - ulast[uidx]), 0.0)
         seqs = seq0 + occ + 1
         post_len = (np.minimum(seq0, n_ev - 1) + 1).astype(np.int32)
-        events = encode_events_host(amounts, tx_codes, dts)
+        events = encode_events_host(amounts, tx_codes, dts, out=events_out)
         audit = SessionChunkAudit(events, post_len, uidx, snaps)
 
         # Commit, rows of an account in chunk order (the device append

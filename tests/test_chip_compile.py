@@ -76,6 +76,7 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl,
 
     from igaming_platform_tpu.core.features import NUM_FEATURES
     from igaming_platform_tpu.models.multitask import init_multitask
+    from igaming_platform_tpu.serve import index_program
     from igaming_platform_tpu.serve import session_state as ss
 
     def abstract(make):
@@ -94,10 +95,8 @@ def _step_args(head_params, capacity, ring_rows, shards, state, repl,
         _spec((ss.ring_size(ring_rows, n, shards),), f32, state),  # ring
         _spec((ring_rows,), i32, state),                  # cursor
         _spec((ring_rows,), i32, state),                  # length
-        _spec((b,), i32, repl), _spec((b,), i32, repl), _spec((b,), i32, repl),
-        _spec((b,), f32, repl), _spec((b,), i32, repl),
-        _spec((b, ss.EVENT_WIDTH), f32, repl), _spec((b,), np.bool_, repl),
-        _spec((2,), i32, repl), None, _spec((), i32, repl),
+        _spec((b, index_program.CHUNK_WORDS), i32, repl),  # the packed chunk
+        _spec((2,), i32, repl), None,
     )
 
 
@@ -655,7 +654,7 @@ def test_delta_rule_step_fits_beside_the_state_and_holds_no_state(
     # the einsum core's float32 passes over [256, 16, 32, 128] and the
     # substitution's row stacks are gone
     assert mem.temp_size_in_bytes <= LING_TEMPS_256, (
-        f"{mem.temp_size_in_bytes} B of temporaries; {LING_TEMPS_256} since the "
+        f"{mem.temp_size_in_bytes} B of temporaries; 441004032 since the "
         f"router sorts nothing (PR 51), 479243264 with the delta kernel (PR "
         f"50), 984582656 with the einsum core (PR 49)")
 
@@ -984,8 +983,11 @@ def _assert_the_residual_path_is_two_passes(text, capsys, positions, cfg):
 # the bound is four times the 64-row rung's 122,904,576 B, which the rung's
 # test holds under a quarter of it (``phi`` turned columns-first, 1.4 MB a
 # sublayer, does not shrink with the rung; 122,840,064 B until PR 61 stood
-# the window's words behind their fence, 64 KB at 64 rows).
-XING_TEMPS_256 = 491_618_304
+# the window's words behind their fence, 64 KB at 64 rows; 122,904,576 B
+# until PR 67 made the chunk's columns temporaries, unpacked from the one
+# packed argument behind their fence, where each was an argument: 323,072 B
+# at 64 rows, and the 256-row step reads 488,852,992 B).
+XING_TEMPS_256 = 4 * 123_227_648
 
 
 # What the ``falconh1`` step holds in temporaries at the 256-row rung since PR
@@ -993,8 +995,10 @@ XING_TEMPS_256 = 491_618_304
 # 32 KB of it, 627,736,576 before; 631,744,000 at PR 46 with the float32
 # passes between the mixer's projections, which were never the peak: the MLP's
 # two ``[4096, 21504]`` float32 products are); the 64-row rung reads
-# 139,813,888 B (142,620,160 at PR 46).
-FALCONH1_TEMPS_256 = 627_768_832
+# 139,813,888 B (142,620,160 at PR 46). Since PR 67 628,088,320 B and
+# 140,233,728 B: the packed chunk's unpacked columns are temporaries where
+# each was an argument (319,488 B at 256 rows).
+FALCONH1_TEMPS_256 = 628_088_320
 
 
 # What the ``ling`` step holds in temporaries at the 256-row rung since PR 51,
@@ -1002,8 +1006,9 @@ FALCONH1_TEMPS_256 = 627_768_832
 # 984,582,656 with the einsum core, PR 49); the 64-row rung reads 52,811,776 B
 # (54,972,928 at PR 50, 132,454,912 at PR 49), and the step's code 26.4 / 22.8
 # MB (34.3 MB at the 64-row rung at PR 49: the unrolled substitution is out
-# of the program).
-LING_TEMPS_256 = 441_004_032
+# of the program). Since PR 67 441,259,008 B and 53,392,896 B (441,004,032
+# until then): the packed chunk's unpacked columns, as in ``falconh1``'s.
+LING_TEMPS_256 = 441_259_008
 
 
 @pytest.mark.parametrize("batch", [256, 64])
@@ -1150,9 +1155,9 @@ def test_the_64_row_rung_compiles_beside_the_256_one(
 
 
 @pytest.mark.parametrize("head,sketch,sha256", [
-    ("pattern", False, "65dd860d54d1204b"), ("pattern", True, "a8840c1b072e56ac"),
-    ("transformer", False, "177210630f848043"),
-    ("transformer", True, "1b5d6b7b954cff6f")])
+    ("pattern", False, "3c67509ec67a17b8"), ("pattern", True, "8496bf14577e9c1b"),
+    ("transformer", False, "f590c67c2d8b6af9"),
+    ("transformer", True, "933ba7dbaa33d794")])
 def test_the_small_heads_step_is_the_one_the_ledger_measured(head, sketch, sha256):
     """The two host-bound cells' fused step never traces the expert layer:
     its StableHLO, lowered on this sandbox's CPU at 256 rows and 5,242,880
@@ -1162,7 +1167,12 @@ def test_the_small_heads_step_is_the_one_the_ledger_measured(head, sketch, sha25
     writes the new prefixes here, and says so in PERF.md. PR 61 did: the
     window leaves ``session_state.windows_from_state`` as 32-bit words
     behind one fence in every session step (c51b7511f2159529,
-    154ee60b366c28a6, 7037c7eb0e6f4958 and 1e8ca471c94e4fe3 until then)."""
+    154ee60b366c28a6, 7037c7eb0e6f4958 and 1e8ca471c94e4fe3 until then).
+    PR 67 did again: the step takes the launch's ONE packed chunk and the
+    thresholds where it took seven columns, ``n`` and the thresholds as host
+    arrays, and unpacks the columns behind one fence
+    (``index_program.unpack_chunk``; 65dd860d54d1204b, a8840c1b072e56ac,
+    177210630f848043 and 1b5d6b7b954cff6f until then)."""
     import hashlib
 
     capacity = 5_242_880

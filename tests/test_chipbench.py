@@ -6,6 +6,8 @@ case of that directory that this tree can no longer pass (``LAST_EIGHT``),
 and since PR 46 a second pair of the same kind (``LAST_NINE``), since PR 57 a
 third (``LAST_TWO``) and since PR 59 a fourth (``LAST_ELEVEN``); PR 65's
 cell test holds its entries to no place, and the four name what it appended.
+Since PR 67 a fifth pair of another kind (``NINE_LEAVES``): a case of that
+directory counts the host arrays of a launch, and the launch has one.
 
 Every test module of that directory is imported here under its own
 names, so each case counts as one. Their fixtures come with them; the
@@ -88,6 +90,71 @@ def test_the_manifest_keeps_the_span_metrics_together_and_validates():
     for cell in manifest["workloads"]:
         got = {m["name"] for m in validate.load_cell(cell["name"])["per_layer"]}
         assert set(expected) <= got, cell["name"]
+
+
+NINE_LEAVES = (
+    "chipbench/tests/test_span_metrics.py's rehearsal holds a launch of the "
+    "index program to NINE host arguments (the seven arrays, the thresholds "
+    "and the row count); since PR 67 a launch hands over ONE, the packed chunk "
+    "(serve/index_program.pack_chunk), and the thresholds live on the device. "
+    "A PR that is no benchmark PR may not edit that file; a benchmark PR "
+    "writes 1 there: PERF.md Open question 9")
+
+
+@pytest.mark.xfail(strict=True, raises=AssertionError, reason=NINE_LEAVES)
+def test_a_rehearsed_cell_opens_every_span_and_the_leaves_tile_dispatch():  # noqa: F811
+    """That file's case of this name, run as it stands and expected to fail
+    where it counts the transfers of a launch, every assertion before that
+    one passed; strict, as above."""
+    _span_metrics.test_a_rehearsed_cell_opens_every_span_and_the_leaves_tile_dispatch()
+
+
+def test_a_rehearsed_cell_tiles_dispatch_and_hands_over_one_array_a_launch():
+    """The case above with the launch's host arguments counted as they are
+    since PR 67, one a launch and 68 bytes a padded row, and every other
+    assertion of it as it stands."""
+    from copy import deepcopy
+
+    from chipbench import harness, validate
+
+    spec = deepcopy(validate.load_cell("stateful-index-flatout"))
+    r = harness.Run(spec, seed=3_800_000_021, seconds=1.5, trace=False,
+                    rehearse=True)
+    r.boot()
+    try:
+        r.fill()
+        ok, _ = r.check()
+        s0, c0 = r.stage_totals(), r.counters()
+        result = r.window()
+        s1, c1 = r.stage_totals(), r.counters()
+    finally:
+        r.shutdown()
+    assert ok and result["correct"] and result["failed"] == 0
+    for name in _span_metrics.EXPECTED:
+        assert result["per_layer"].get(name, {}).get("value") is not None, name
+    stages = {k: s1[k] - s0.get(k, 0.0) for k in s1}
+    tiles = ("launch", "post_launch", "session", "pad", "lock_wait",
+             "dispatch.self")
+    assert all(stages[k] > 0 for k in tiles + ("dispatch", "lane_wait",
+                                               "device_wait", "readback"))
+    assert sum(stages[k] for k in tiles) == pytest.approx(
+        stages["dispatch"], rel=0.02)
+    assert stages["device_wait"] <= stages["readback"]
+    assert stages["readback.self"] == pytest.approx(
+        stages["readback"] - stages["device_wait"], rel=0.02)
+    grew = lambda k: c1[k] - c0[k]  # noqa: E731
+    launches = grew("risk_device_dispatches_total")
+    assert launches > 0 and grew("risk_h2d_transfers_total") == launches
+    assert grew("risk_h2d_bytes_total") == 68 * grew("risk_launch_padded_rows_total")
+    assert result["per_layer"]["h2d_transfers_per_chunk"]["value"] == 1.0
+    grown = {k: grew(k) for k in _span_metrics.COUNTERS if k.startswith("risk_")}
+    assert all(v > 0 for v in grown.values()), grown
+    assert (grown["risk_host_stage_cpu_seconds_total"]
+            <= grown["risk_host_stage_self_seconds_total"] * 1.05)
+    assert c1["risk_process_cpu_seconds_total"] > c0["risk_process_cpu_seconds_total"]
+    for name in ("risk_host_cpu_steal_seconds_total", "risk_host_cpu_seconds_total",
+                 "risk_process_runqueue_wait_seconds_total"):
+        assert c1[name] >= c0[name]
 
 
 LAST_NINE = (

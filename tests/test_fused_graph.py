@@ -608,6 +608,52 @@ def test_fused_single_dispatch_per_chunk_with_drift_and_shadow():
             tracing.add_span_sink(prev.observe_span)
 
 
+@pytest.mark.parametrize("session", [True, False])
+def test_fused_launch_hands_over_one_host_array(session):
+    """With the drift sketch AND the shadow branch in the program, a chunk's
+    launch still has ONE host (numpy) leaf, the packed chunk: sketch and
+    shadow read the columns the program unpacked, the candidate's tree and
+    the thresholds are device arrays."""
+    from igaming_platform_tpu.serve import index_program
+
+    fam = "session" if session else "cached"
+    eng = _engine(_mlp_params(0), fused=True, batch=16, tiers=(),
+                  cache=32, session=session)
+    eng.ensure_cache()
+    de = _drift()
+    eng.bind_drift(de)
+    sh = ShadowScorer(eng, _mlp_params(1))
+    eng.shadow = sh
+    seen = []
+    note = eng._note_launch
+
+    def spy(label, shape, n, args):
+        seen.append((label, n, [a for a in jax.tree.leaves(args)
+                                if isinstance(a, (np.ndarray, np.generic))]))
+        return note(label, shape, n, args)
+
+    eng._note_launch = spy
+    accts = [f"hl-{i % 9}" for i in range(13)]
+    try:
+        assert _wait_ready(eng, (fam, True, True))
+        eng.score_columns_cached(accts, [40.0 + i for i in range(13)],
+                                 ["bet"] * 13, now=NOW0)
+        assert sh.drain(30.0) and de.drain(10.0)
+        (label, n, host), = seen
+        assert label == f"fused_{fam}_step" and n == 13
+        assert len(host) == 1
+        assert host[0].shape == (16, index_program.CHUNK_WORDS)
+        assert de.rows_sketched == 13
+        # the candidate scored in the same dispatch: no echo, no skip
+        rep = sh.report()
+        assert rep["fused_batches"] == 1
+        assert rep["rows_skipped_no_snapshot"] == 0
+    finally:
+        sh.close()
+        eng.close()
+        de.close()
+
+
 # ---------------------------------------------------------------------------
 # int8-throughout variant
 

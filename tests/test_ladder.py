@@ -1,5 +1,6 @@
 """The index program's ladder of padded shapes (serve/scorer.py): a 64-row
-rung under the 256 one, and the counter that says which rung a launch ran.
+rung under the 256 one, the counter that says which rung a launch ran, and
+the counters of what a launch hands over the link: one packed chunk.
 """
 
 from __future__ import annotations
@@ -21,12 +22,12 @@ from igaming_platform_tpu.serve.scorer import TPUScoringEngine  # noqa: E402
 NOW0 = 1_700_000_000.0
 
 
-def make_engine(tiers, batch_size=256, capacity=320, **kw):
+def make_engine(tiers, batch_size=256, capacity=320, session_state=True, **kw):
     eng = TPUScoringEngine(
         ScoringConfig(), ml_backend="mock",
         batcher_config=BatcherConfig(batch_size=batch_size, latency_tiers=tiers,
                                      max_wait_ms=1.0),
-        feature_cache=capacity, session_state=True, **kw)
+        feature_cache=capacity, session_state=session_state, **kw)
     eng.ensure_cache()
     return eng
 
@@ -131,3 +132,37 @@ def test_padded_rows_are_counted_at_every_launch_of_the_index_program(
             assert occupancy._sums[()] == rows * occupancy.count() > 0
         finally:
             eng.close()
+
+
+@pytest.mark.parametrize("session", [True, False])
+@pytest.mark.parametrize("rows,shapes", [
+    (1, (64,)), (64, (64,)), (65, (256,)), (256, (256,)), (300, (256, 64))])
+def test_a_launch_hands_over_one_host_array(monkeypatch, rows, shapes, session):
+    """``risk_h2d_transfers_total`` rises by 1 a launch and
+    ``risk_h2d_bytes_total`` by the packed chunk's bytes (68 a padded row),
+    in the ``session`` and in the ``cached`` family: the chunk is the only
+    host leaf of the call (``_note_launch`` counts whatever leaves it has),
+    the thresholds are on the device."""
+    from igaming_platform_tpu.serve import index_program
+
+    monkeypatch.setenv("SESSION_HEAD", "pattern")
+    metrics = ServiceMetrics("risk")
+    monkeypatch.setattr(runtime_telemetry, "DEFAULT",
+                        runtime_telemetry.RuntimeTelemetry(metrics))
+    eng = make_engine((64,), session_state=session)
+    try:
+        assert isinstance(eng._thresholds_dev, jax.Array)
+        t0 = metrics.h2d_transfers_total.value()
+        b0 = metrics.h2d_bytes_total.value()
+        d0 = metrics.device_dispatches_total.value()
+        ids, amounts, types, now = next(frames(1, rows, 300))
+        eng.score_columns_cached(ids, amounts, types, now=now)
+        assert metrics.h2d_transfers_total.value() - t0 == len(shapes)
+        chunk_bytes = [index_program.warm_columns(s).nbytes for s in shapes]
+        assert chunk_bytes == [s * 4 * index_program.CHUNK_WORDS
+                               for s in shapes]
+        assert metrics.h2d_bytes_total.value() - b0 == sum(chunk_bytes)
+        # the launches themselves, beside the admissions' own dispatches
+        assert metrics.device_dispatches_total.value() - d0 >= len(shapes)
+    finally:
+        eng.close()

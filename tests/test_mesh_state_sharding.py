@@ -317,6 +317,51 @@ def test_steady_state_dispatches_unchanged_by_sharding(monkeypatch):
     assert counts["sharded"] == counts["replicated"] == 6
 
 
+@pytest.mark.parametrize("k", [None, 2, 4])
+def test_a_launch_is_one_host_leaf_on_every_placement(monkeypatch, k):
+    """The packed chunk is the call's one host (numpy) leaf on one device
+    and on the slot-sharded mesh, where it is placed once a device; the
+    thresholds are a device array replicated over the mesh, replaced when
+    an operator sets them, and the next chunk reads the new ones."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from igaming_platform_tpu.serve import index_program
+
+    eng = make_engine(k, capacity=16, batch_size=8, tiers=())
+    launches = []
+    note = eng._note_launch
+
+    def spy(label, shape, n, args):
+        launches.append([a for a in jax.tree.leaves(args)
+                         if isinstance(a, (np.ndarray, np.generic))])
+        return note(label, shape, n, args)
+
+    monkeypatch.setattr(eng, "_note_launch", spy)
+    accts = [f"hl{i}" for i in range(11)]
+    base = eng.score_columns_cached(accts, [90] * 11, ["bet"] * 11, now=NOW0)
+    assert len(launches) == 2  # 8 + 3 rows
+    for host in launches:
+        assert len(host) == 1 and host[0].dtype == np.int32
+        assert host[0].shape == (8, index_program.CHUNK_WORDS)
+    assert [int((h[0][:, index_program.W_FLAGS] & 2).astype(bool).sum())
+            for h in launches] == [8, 3]
+    thr = eng._thresholds_dev
+    assert isinstance(thr, jax.Array)
+    if k is not None:
+        assert thr.sharding.is_equivalent_to(
+            NamedSharding(eng._mesh, P()), thr.ndim)
+        assert len(thr.sharding.device_set) == k
+    assert (base["action"] != 3).any()
+    eng.set_thresholds(0, 0)
+    assert eng._thresholds_dev is not thr and eng.get_thresholds() == (0, 0)
+    np.testing.assert_array_equal(np.asarray(eng._thresholds_dev), [0, 0])
+    got = eng.score_columns_cached(accts, [90] * 11, ["bet"] * 11,
+                                   now=NOW0 + 30.0)
+    assert (got["action"] == 3).all()  # block at 0: every row
+    close_engine(eng)
+
+
 def test_shard_gauges_exposed_with_bounded_labels():
     from igaming_platform_tpu.obs.metrics import ServiceMetrics
 

@@ -610,9 +610,9 @@ def test_two_threads_leave_twin_ring_and_ledger_in_agreement():
     lock_order = []
     inner = mgr.prepare_chunk
 
-    def recording(groups, amounts, codes, now):
+    def recording(groups, amounts, codes, now, **kw):
         lock_order.append(now)  # called with the session lock held
-        return inner(groups, amounts, codes, now)
+        return inner(groups, amounts, codes, now, **kw)
 
     mgr.prepare_chunk = recording
     errors = []
@@ -706,18 +706,15 @@ def test_ring_state_matches_numpy_model(k, seed):
         # 30 steps of ~9 rows wrap the 16-event windows), the rest pad.
         b = int(rng.integers(5, 14))
         slots = rng.integers(0, cap, b).astype(np.int32)
-        sidx = np.full((shape,), cap, np.int32)
-        sidx[:b] = slots
-        occ = np.arange(shape, dtype=np.int32)
-        occ[:b] = session_mod.occurrence_rank_host(slots.astype(np.int64))
-        occ[b:] = np.arange(shape - b)
-        events = rng.random((shape, d), dtype=np.float32)
+        occ = session_mod.occurrence_rank_host(slots.astype(np.int64))
+        events = rng.random((b, d), dtype=np.float32)
+        # the launch's one host array: slots, ranks and events as words
+        chunk = index_program.pack_chunk(
+            shape, slots, np.zeros((b,), np.float32),
+            np.full((b,), 4, np.int32), np.zeros((b,), bool), occ=occ)
+        index_program.chunk_events(chunk, b)[:] = events
         res = step(None, mgr.head_params, table, flags, mgr.session_ring,
-                   mgr.session_cursor, mgr.session_length,
-                   np.where(sidx < cap, sidx, 0).astype(np.int32), sidx, occ,
-                   np.zeros((shape,), np.float32),
-                   np.full((shape,), 4, np.int32), events,
-                   np.zeros((shape,), bool), thr, None, np.int32(b))
+                   mgr.session_cursor, mgr.session_length, chunk, thr, None)
         mgr.adopt(*res[1:4])
         cur0 = cur.copy()
         for i in range(b):
@@ -874,9 +871,9 @@ def test_sequence_head_bit_exact_vs_host_reference(n_rows):
     bl = np.zeros((n_rows,), bool)
     base, = eng._ensure_fused("cached", False, False)(
         eng.get_params(), None, eng.cache.table, eng.cache.flags,
-        jnp.asarray(idxs), jnp.asarray(np.asarray(amounts, np.float32)),
-        jnp.asarray(np.asarray(codes, np.int32)), jnp.asarray(bl),
-        eng._thresholds, np.int32(n_rows))
+        index_program.pack_chunk(n_rows, idxs, np.asarray(amounts, np.float32),
+                                 np.asarray(codes, np.int32), bl),
+        eng._thresholds_dev)
     base = np.asarray(jax.device_get(base))
     base_ml = base[4].view(np.float32)
     warm = lps >= mgr.min_events
