@@ -229,13 +229,21 @@ def route(x, layer: Params, cfg, groups: int | None = None,
         chosen_by = within_kept_groups(chosen_by, groups, kept_groups)
     top_s, top_e = largest_by_rounds(chosen_by, cfg.top_k)  # [top_k, P]
     if chosen_by is not s:
-        expert = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        top_s = jnp.stack([jnp.sum(jnp.where(expert == e, s, 0.0), axis=0)
-                           for e in top_e])
+        top_s = scores_of(s, top_e)
     # the two small results position-major, as the expert layer reads them
     top_e, top_s = top_e.T, top_s.T
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + cfg.renorm_eps)
     return top_e, w * cfg.routed_scale
+
+
+def scores_of(scores, chosen):
+    """``scores`` [experts, P] at the experts ``chosen`` [k, P] -> [k, P]:
+    each read back as ``sum(where(iota == e, scores, 0))`` over the experts,
+    the score itself plus zeros: no gather and no product that could round
+    it (what a router reads its weights by where a bias chose)."""
+    expert = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    return jnp.stack([jnp.sum(jnp.where(expert == e, scores, 0.0), axis=0)
+                      for e in chosen])
 
 
 @partial(jax.jit, static_argnums=1)
@@ -374,31 +382,60 @@ def core_by_einsums(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
 
 def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
                            rope: int, dv: int, window: int,
-                           interleave: bool = False, scale_by: float = 1.0):
+                           interleave: bool = False, scale_by: float = 1.0,
+                           block: int | None = None):
     """The core of latent attention as three einsums over ``[b, t, h, d]``,
     under the kernel's signature (ops/pallas/window_attention.
     window_attention): its reference, and what runs off the TPU. ->
     float32 [P, heads x dv], which ``Wo``'s product rounds. With
     ``interleave`` the rotary part of ``q`` turns by interleaved pairs
     (``rotate``); ``k_rope`` comes turned, by the same pairing. The scores
-    are scaled by ``(nope + rope) ** -0.5`` times ``scale_by``."""
+    are scaled by ``(nope + rope) ** -0.5`` times ``scale_by``.
+
+    A window deeper than one block (``block``, else ``block_attention.
+    block_for(window)`` positions: the ``longcat`` head's 2,048-event
+    windows) runs in query blocks as ``core_by_einsums`` does: a block of
+    queries meets the keys from the window's first to its own last under
+    the mask written as its inequality, and no ``[t, t]`` array of the
+    whole window stands at once. A window that fits one block (every other
+    head's 16 keys) is the three einsums over the whole of it."""
+    from igaming_platform_tpu.ops.pallas.block_attention import block_for
+
     dt, t = kvb.dtype, window
     b = q.shape[0] // t
+    block = block or block_for(t)
     q = q.reshape(b, t, heads, nope + rope)
     q_rope = rotate(q[..., nope:], cos.reshape(b, t, -1), sin.reshape(b, t, -1),
                     interleave)
     kvb = kvb.reshape(b, t, heads, nope + dv)
-    sc = (jnp.einsum("bthd,bshd->bhts", q[..., :nope].astype(dt),
-                     kvb[..., :nope], preferred_element_type=jnp.float32)
-          + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt),
-                       k_rope.reshape(b, t, rope),
-                       preferred_element_type=jnp.float32))
-    sc = sc * ((nope + rope) ** -0.5 * scale_by)
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), kvb[..., nope:],
-                   preferred_element_type=jnp.float32)
-    return o.reshape(b * t, heads * dv)
+    if block >= t:
+        sc = (jnp.einsum("bthd,bshd->bhts", q[..., :nope].astype(dt),
+                         kvb[..., :nope], preferred_element_type=jnp.float32)
+              + jnp.einsum("bthd,bsd->bhts", q_rope.astype(dt),
+                           k_rope.reshape(b, t, rope),
+                           preferred_element_type=jnp.float32))
+        sc = sc * ((nope + rope) ** -0.5 * scale_by)
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+        o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), kvb[..., nope:],
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b * t, heads * dv)
+    q_nope, q_rope = q[..., :nope].astype(dt), q_rope.astype(dt)
+    k_rope = k_rope.reshape(b, t, rope)
+    out = []
+    for lo in range(0, t, block):
+        hi = min(lo + block, t)
+        keep = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
+        sc = (jnp.einsum("bthd,bshd->bhts", q_nope[:, lo:hi], kvb[:, :hi, :, :nope],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bthd,bsd->bhts", q_rope[:, lo:hi], k_rope[:, :hi],
+                           preferred_element_type=jnp.float32))
+        sc = sc * ((nope + rope) ** -0.5 * scale_by)
+        p = jax.nn.softmax(jnp.where(keep, sc, -jnp.inf), axis=-1)
+        out.append(jnp.einsum("bhts,bshd->bthd", p.astype(dt),
+                              kvb[:, :hi, :, nope:],
+                              preferred_element_type=jnp.float32))
+    return jnp.concatenate(out, axis=1).reshape(b * t, heads * dv)
 
 
 def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False,
@@ -412,35 +449,83 @@ def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False,
     rotate-half pairs, so with ``interleave`` the einsums run on every
     backend and the announcement says why: the pairs are never re-paired
     silently. ``scale_by`` multiplies the softmax scale in either core (the
-    ``xing`` head's YaRN factor; 1 for the others)."""
+    ``xing`` head's YaRN factor; 1 for the others). Where the einsums run
+    over a window deeper than one block the announcement says in what
+    query blocks."""
     from igaming_platform_tpu.ops.pallas import window_attention as kernel
+    from igaming_platform_tpu.ops.pallas.block_attention import block_for
 
     widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
                   dv=cfg.v_dim, window=window)
     scaled = dict(scale_by=scale_by)
+    block = block_for(window)
+    einsums = ("xla-einsum" if block >= window else
+               f"xla-einsum in query blocks of {block} (window {window}, "
+               f"{cfg.heads} heads)")
     if interleave:
         _, backend = kernel_declines()
-        announce_core("xla-einsum (interleaved rotary pairs: the window "
+        announce_core(f"{einsums} (interleaved rotary pairs: the window "
                       "kernel turns by halves)", backend, "attention core")
         return partial(latent_core_by_einsums, **widths, **scaled,
                        interleave=True)
     why, backend = kernel_declines(lambda: not kernel.supports(q, kvb, **widths))
-    announce_core("xla-einsum" if why else "pallas-windows", backend,
+    announce_core(einsums if why else "pallas-windows", backend,
                   "attention core")
     return partial(latent_core_by_einsums if why else kernel.window_attention,
                    **widths, **scaled)
 
 
+def latent_queries(a, layer: Params, cfg, scale: float | None = None):
+    """The queries of latent attention over normed hidden states ``a`` [P,
+    hidden] -> float32 [P, heads x (nope + rope)], heads of ``[q_nope |
+    q_rope]`` as the product leaves them (the rotary part turns before it is
+    rounded, inside the core): ``Nq(a Wq_a) Wq_b``, or ``a Wq`` in a layer
+    without a query latent (no ``wq_a``). ``scale`` (the ``longcat`` head's
+    ``mla_scale_q_lora``: ``sqrt(hidden / q_rank)``) multiplies both parts
+    in float32."""
+    if "wq_a" in layer:
+        cq = rms_norm(mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
+        q = mm(cq, layer["wq_b"], cfg)
+    else:
+        q = mm(a, layer["wq"], cfg)
+    return q if scale is None else q * scale
+
+
+def latent_keys_values(a, layer: Params, cos, sin, cfg, window: int,
+                       interleave: bool = False, scale: float | None = None):
+    """The keys and values of latent attention over normed hidden states
+    ``a`` [P, hidden] in windows of ``window`` -> ``(k_rope [P, rope], kvb
+    [P, heads x (nope + v)])``, both rounded: ``a Wkv_a`` -> ``[ckv |
+    k_rope]``; the one rotary key head, shared by every query head, turned;
+    ``Nkv(ckv) Wkv_b`` -> heads of ``[k_nope | v]``. ``scale`` (the
+    ``longcat`` head's ``mla_scale_kv_lora``: ``sqrt(hidden / kv_rank)``)
+    multiplies the normed latent in float32 before ``Wkv_b``'s product
+    rounds it, so it reaches ``k_nope`` and ``v`` and not ``k_rope``."""
+    p, dt = a.shape[0], cfg.operand_dtype
+    kv = mm(a, layer["wkv_a"], cfg)
+    ckv = rms_norm(kv[:, :cfg.kv_rank], layer["kvn"], cfg.eps)
+    if scale is not None:
+        ckv = ckv * scale
+    k_rope = rotate(kv[:, cfg.kv_rank:].reshape(p // window, window, 1, -1),
+                    cos, sin, interleave)
+    k_rope = k_rope.astype(dt).reshape(p, -1)
+    # heads of [k_nope | v]: rounded before any other use
+    return k_rope, mm(ckv, layer["wkv_b"], cfg).astype(dt)
+
+
 def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False,
-                     scale_by: float = 1.0):
+                     scale_by: float = 1.0, q_scale: float | None = None,
+                     kv_scale: float | None = None):
     """Multi-head latent attention over normed hidden states ``a`` [B, T,
     hidden], in its expanded form -> [B, T, hidden] (``pangu``: before its
     post-norm). The core (the rotary part of ``q``, scores, mask, softmax,
     ``p v``) is one Pallas kernel over the projections' results as they
     lie where ``window_attention.supports`` holds on a TPU, else three
-    einsums over ``[b, t, h, d]``: the same expanded form at the same
-    precision either way. ``cfg`` gives ``heads``, ``kv_rank``,
-    ``nope_dim``, ``rope_dim``, ``v_dim`` and ``eps``.
+    einsums over ``[b, t, h, d]`` (in query blocks where the window is
+    deeper than one): the same expanded form at the same precision either
+    way. ``cfg`` gives ``heads`` (those held here: the ``longcat`` head's
+    are a chip's share, whose part of ``Wo``'s product goes on as it is),
+    ``kv_rank``, ``nope_dim``, ``rope_dim``, ``v_dim`` and ``eps``.
 
     What the ``ling`` head's layer differs by is read off the layer and one
     argument (``pangu`` has and passes none of it): without a query latent
@@ -449,28 +534,17 @@ def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False,
     [hidden, heads]) each head's output is multiplied by ``sigmoid(a
     Wgate)`` of its head, in float32, before ``Wo`` rounds it. ``scale_by``
     (the ``xing`` head's: YaRN's attention factor squared) multiplies the
-    softmax scale ``(nope + rope) ** -0.5``."""
+    softmax scale ``(nope + rope) ** -0.5``. ``q_scale`` and ``kv_scale``
+    (the ``longcat`` head's two latent scales; every other head passes
+    none) are ``latent_queries``' and ``latent_keys_values``'."""
     b, t, _ = a.shape
-    dt = cfg.operand_dtype
     # position-major from here to the last product: [P, channels], P = B x T
     a = a.reshape(b * t, -1)
     with jax.named_scope("q"):
-        if "wq_a" in layer:
-            cq = rms_norm(mm(a, layer["wq_a"], cfg), layer["qn"], cfg.eps)
-            # heads of [q_nope | q_rope] as the product leaves them: float32,
-            # since the rotary part turns before it is rounded
-            q = mm(cq, layer["wq_b"], cfg)
-        else:
-            q = mm(a, layer["wq"], cfg)
+        q = latent_queries(a, layer, cfg, q_scale)
     with jax.named_scope("kv"):
-        kv = mm(a, layer["wkv_a"], cfg)
-        ckv = rms_norm(kv[:, :cfg.kv_rank], layer["kvn"], cfg.eps)
-        # one rotary key head, shared by every query head
-        k_rope = rotate(kv[:, cfg.kv_rank:].reshape(b, t, 1, -1), cos, sin,
-                        interleave)
-        k_rope = k_rope.astype(dt).reshape(b * t, -1)
-        # heads of [k_nope | v]: rounded before any other use
-        kvb = mm(ckv, layer["wkv_b"], cfg).astype(dt)
+        k_rope, kvb = latent_keys_values(a, layer, cos, sin, cfg, t, interleave,
+                                         kv_scale)
     core = latent_attention_core(q, kvb, cfg, t, interleave, scale_by)
     with jax.named_scope("core"):
         o = core(q, kvb, k_rope, cos.reshape(b * t, -1), sin.reshape(b * t, -1))
@@ -584,6 +658,14 @@ def hyper_write(xs, res, post, y):
     return tuple(
         sum((res[i, j][:, None] * x for j, x in enumerate(xs)),
             post[i][:, None] * y) for i in range(len(xs)))
+
+
+def rows_at(x, at, window: int):
+    """``x`` [B x T, w] (or [B, T, w]) -> the row at position ``at`` [B] of
+    each window of ``window`` positions, [B, w]: what a stack that narrows
+    gathers where it goes on at one position a row."""
+    x = x.reshape(-1, window, x.shape[-1])
+    return jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
 
 
 def score_last(params: Params, hid, lengths, logit_scale=None):

@@ -87,6 +87,7 @@ from igaming_platform_tpu.models.decoder_parts import (
     rope_angles,
     rotate,
     route,
+    rows_at,
     swiglu,
     tree_around,
 )
@@ -331,12 +332,6 @@ def feed_forward(x, layer: Params, cfg: KExaoneConfig, live):
                                    cfg.first_expert, live)
 
 
-def _rows_at(x, at, window: int):
-    """``x`` [B x T, w] -> the row at ``at`` of each window, [B, w]."""
-    x = x.reshape(-1, window, x.shape[-1])
-    return jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
-
-
 def _logit(params: Params, normed):
     """The scoring head on normed hidden states [B, hidden]: one float32
     output column, a multiply-reduce, never the MXU."""
@@ -406,7 +401,7 @@ def mtp_module(params: Params, e, f, lengths, cfg: KExaoneConfig, window: int,
         if narrowed:
             with jax.named_scope("attn/full"):
                 k, v = keys_and_values(u, layer, kind, cos, sin, cfg, window)
-                u = _rows_at(u, at, window)
+                u = rows_at(u, at, window)
                 q = mm(u, layer["wq"], cfg)
                 with jax.named_scope("core"):
                     o = one_query_core(q, k.reshape(b, window, -1),
@@ -421,7 +416,7 @@ def mtp_module(params: Params, e, f, lengths, cfg: KExaoneConfig, window: int,
             live = (jnp.arange(window)[None, :] + 1 < lengths[:, None]).reshape(-1)
         u = u + rms_norm(feed_forward(u, layer, cfg, live), layer["pf"], cfg.eps)
         if not narrowed:
-            u = _rows_at(u, at, window)
+            u = rows_at(u, at, window)
         return rms_norm(u, mtp["gm"], cfg.eps)
 
 
@@ -437,7 +432,7 @@ def backbone_logits(params: Params, window, lengths, cfg: KExaoneConfig,
     m = mtp_module(params, e, f, lengths, cfg, t, narrowed)
     with jax.named_scope("head/score"):
         last = jnp.clip(lengths - 1, 0, t - 1)
-        return _logit(params, _rows_at(f, last, t)), _logit(params, m)
+        return _logit(params, rows_at(f, last, t)), _logit(params, m)
 
 
 def backbone_scores(params: Params, window, lengths, cfg: KExaoneConfig):

@@ -95,6 +95,7 @@ from igaming_platform_tpu.models.decoder_parts import (
     kernel_declines,
     mm,
     rms_norm,
+    rows_at,
     score_last,
     swiglu,
     tree_around,
@@ -493,12 +494,6 @@ def _mlp(h, layer: Params, cfg: Phi4FlashConfig):
     return h + swiglu(layer_norm(h, layer["n2"], cfg.eps), layer["dense"], cfg)
 
 
-def _last_rows(x, last, window: int):
-    """``x`` [B x T, w] -> the row at ``last`` of each window, [B, w]."""
-    x = x.reshape(-1, window, x.shape[-1])
-    return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-
-
 # -- the stack ----------------------------------------------------------------
 
 
@@ -547,7 +542,7 @@ def backbone_scores(params: Params, window, lengths, cfg: Phi4FlashConfig):
     with jax.named_scope("head/attn/full"):
         u, k, v = _keys_and_values(h, layer, cfg)
         k, v = k.reshape(b, t, -1), v.reshape(b, t, -1)
-        h, m, u = (_last_rows(a, last, t) for a in (h, m, u))
+        h, m, u = (rows_at(a, last, t) for a in (h, m, u))
         _announce_attention(FULL, cfg, t)
         q = mm(u, layer["wqkv"][:, :d], cfg) + layer["bqkv"][:d]
         with jax.named_scope("core"):

@@ -25,6 +25,7 @@ from igaming_platform_tpu.models import (
     kexaone_backbone,
     lfm2_backbone,
     ling_backbone,
+    longcat_backbone,
     mellum_backbone,
     pangu_backbone,
     phi4flash_backbone,
@@ -242,6 +243,16 @@ HEADS = {
     # the one position the score reads) under the same scoring head: 2.80 G
     # parameters, 5.59 GB
     "kexaone": _backbone(kexaone_backbone, kexaone_backbone.KExaoneConfig()),
+    # four double layers of a shortcut-expert backbone at its published
+    # widths: in each, two latent attentions (interleaved rotary pairs, two
+    # latent scales; a chip's share of 16 of 64 heads held) and two dense
+    # SwiGLUs of 12,288, and one expert branch that leaves the stream after
+    # the first attention and rejoins it after the second MLP: a softmax
+    # router of 768 outputs, 12 a position, over 512 experts of width 2,048 (a
+    # chip's share of 8 held) and 256 identity experts that multiply nothing;
+    # the last layer's second half at the scored position only: 3.30 G
+    # parameters, 6.60 GB
+    "longcat": _backbone(longcat_backbone, longcat_backbone.LongcatConfig()),
 }
 
 

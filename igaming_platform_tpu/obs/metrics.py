@@ -916,7 +916,9 @@ class ServiceMetrics:
             "attention, counts under both), kind=dense|moe its feed-forward, "
             "kind=mtp a multi-token-prediction module behind the stack "
             "(counted once; its own layer's operators count under their "
-            "kinds besides; a head without layers reads 0 for all ten)",
+            "kinds besides; a head without layers reads 0 for all ten). A "
+            "layer of the longcat head holds two attention and two dense "
+            "sublayers and one moe branch across them: 8 / 8 / 4 for 4 layers",
         )
         self.session_head_residual_streams = self.registry.gauge(
             f"{service}_session_head_residual_streams",

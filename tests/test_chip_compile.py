@@ -955,6 +955,76 @@ def test_two_depth_step_reads_the_module_at_one_position_a_row(
     _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
 
 
+def test_double_layer_step_holds_no_square_of_a_window_and_narrows_its_last_half(
+        topo, tpu_backend, capsys, monkeypatch):
+    """The fused step with the ``longcat`` backbone in it, at its cell's size
+    (16,384 accounts of 2,048 events, the 2-row step: 4,096 positions, four
+    double layers at the published widths with 16 of 64 heads and 8 of 512
+    experts held): in place on the 1.61 GB ring, its arguments the state plus
+    6.6 GB of weights. The eight latent attentions turn interleaved rotary
+    pairs, which the window kernel does not, so their cores are the einsums
+    in query blocks of 512: nothing of a window's ``2048,2048`` square, at 16
+    heads or at one, is among the step's temporaries. Of the last layer's
+    second attention the ``K, V`` products meet all 4,096 positions and no
+    other product of its scope does; the expert branch's scopes are all
+    there, the held experts' products ``ragged-dot`` (6144 x 2048 slots:
+    ``kexaone``'s case), and the router sorts and gathers nothing. Code,
+    temporaries and arguments are printed."""
+    from jax.sharding import SingleDeviceSharding
+
+    from igaming_platform_tpu.models import decoder_parts
+    from igaming_platform_tpu.models.session_heads import HEADS
+    from igaming_platform_tpu.serve import session_state as ss
+
+    monkeypatch.setenv("SESSION_EVENTS", "2048")
+    capacity, batch = 16_384, 2
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = HEADS["longcat"].config
+    decoder_parts.announce_core.cache_clear()
+    compiled = _compile_step("longcat", capacity, capacity + 1, one, one,
+                             batch=batch)
+    said = decoder_parts.announced_cores()
+    assert said["attention core"] == (
+        "xla-einsum in query blocks of 512 (window 2048, 16 heads) "
+        "(interleaved rotary pairs: the window kernel turns by halves) "
+        "(backend=tpu)")
+    assert said["attention core (narrowed)"].startswith(
+        "xla-einsum, one query a row (window 2048, 16 heads")
+    ring = ss.ring_size(capacity + 1, 2048)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nlongcat 2-row step of 2,048-event windows for a described "
+              f"v5e: code {mem.generated_code_size_in_bytes} B, temporaries "
+              f"{mem.temp_size_in_bytes} B, arguments "
+              f"{mem.argument_size_in_bytes} B")
+    assert _ring_sized(compiled, ring) == []
+    assert mem.alias_size_in_bytes >= 4 * ring, mem
+    assert 8.1e9 < mem.argument_size_in_bytes < 8.4e9, mem
+    assert mem.temp_size_in_bytes < 0.9e9, mem  # 0.68 GB at PR 68
+    text = compiled.as_text()
+    positions = batch * 2048
+    # no [heads, t, t] array of a whole window, nor one head's square
+    # (a pass of the held experts is 2,048 rows of width 2,048: not a window's)
+    assert not re.search(r"\[(\d+,)+2048,2048\]", text)
+    assert not [line for line in text.splitlines()
+                if "head/attn" in line and "2048,2048]" in line]
+    for scope in ("head/attn/0/q", "head/attn/0/kv", "head/attn/0/core",
+                  "head/attn/0/out", "head/attn/1/core", "head/mlp/dense",
+                  "head/moe/route", "head/moe/experts", "head/moe/zero"):
+        assert scope in text, scope
+    # the last layer's second attention: K and V over every position, the
+    # queries and Wo over the 2 rows that are read. A product's result names
+    # its rows first; the last attention's are told from the seven whole
+    # ones' by their count.
+    products = [line.split(" convolution(")[0] for line in text.splitlines()
+                if "head/attn/1/" in line and " convolution(" in line]
+    queries = [line for line in products
+               if f",{cfg.heads * (cfg.nope_dim + cfg.rope_dim)}]" in line]
+    assert [f"[{positions}," in line for line in queries].count(False) >= 1, queries
+    assert "ragged-dot" in text
+    _assert_the_router_sorts_and_gathers_nothing(text, positions, cfg.top_k)
+
+
 # The stream kernels of the ``xing`` step (ops/pallas/hyper_streams.py): every
 # hyper-connected sublayer of the five layers held, the first among them.
 XING_STREAM_CALLS = {"_streams_maps_read": 10, "_streams_write": 10}

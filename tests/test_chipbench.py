@@ -4,8 +4,9 @@ and the A/A data are guarded by the run the driver makes (PERF.md Open
 question 9). Thin on purpose: it holds one case of its own, beside the one
 case of that directory that this tree can no longer pass (``LAST_EIGHT``),
 and since PR 46 a second pair of the same kind (``LAST_NINE``), since PR 57 a
-third (``LAST_TWO``) and since PR 59 a fourth (``LAST_ELEVEN``); PR 65's
-cell test holds its entries to no place, and the four name what it appended.
+third (``LAST_TWO``) and since PR 59 a fourth (``LAST_ELEVEN``); PR 65's and
+PR 68's cell tests hold their entries to no place, and the four name what they
+appended.
 Since PR 67 a fifth pair of another kind (``NINE_LEAVES``): a case of that
 directory counts the host arrays of a launch, and the launch has one.
 
@@ -33,6 +34,7 @@ from chipbench.tests.test_heartbeat_metrics import *  # noqa: F401,F403
 from chipbench.tests.test_kexaone_cell import *  # noqa: F401,F403
 from chipbench.tests.test_lfm2_cell import *  # noqa: F401,F403
 from chipbench.tests.test_ling_cell import *  # noqa: F401,F403
+from chipbench.tests.test_longcat_cell import *  # noqa: F401,F403
 from chipbench.tests.test_manifest import *  # noqa: F401,F403
 from chipbench.tests.test_mellum_cell import *  # noqa: F401,F403
 from chipbench.tests.test_phi4flash_cell import *  # noqa: F401,F403
@@ -160,7 +162,7 @@ def test_a_rehearsed_cell_tiles_dispatch_and_hands_over_one_array_a_launch():
 LAST_NINE = (
     "chipbench/tests/test_falconh1_cell.py holds PR 45's nine metrics to the "
     "LAST nine places of per_layer; PR 46's padded_rows_per_row follows them "
-    "(and PR 49's and PR 52's configurations, cells and ten metrics each, PR 57's and eleven, PR 59's and fifteen, PR 65's and fifteen), for the reason "
+    "(and PR 49's and PR 52's configurations, cells and ten metrics each, PR 57's and eleven, PR 59's and fifteen, PR 65's and fifteen, PR 68's and eight), for the reason "
     "LAST_EIGHT gives. A benchmark PR repairs the case: "
     "PERF.md Open question 9")
 
@@ -188,12 +190,14 @@ def test_the_falconh1_configuration_holds_with_later_metrics_set_aside(monkeypat
     assert all(name.startswith("mellum_") for name in names[last + 24:last + 35])  # PR 57
     assert all(name.startswith(("phi4flash_", "selective_scan_"))
                for name in names[last + 35:last + 50])  # PR 59
-    assert all(name.startswith("kexaone_") for name in names[last + 50:])  # PR 65
-    assert len(names[last + 50:]) == 15
+    assert all(name.startswith("kexaone_") for name in names[last + 50:last + 65])  # PR 65
+    assert all(name.startswith("longcat_") for name in names[last + 65:])  # PR 68
+    assert len(names[last + 65:]) == 8
     cells = [w["name"] for w in manifest["workloads"]]
     assert cells[cells.index(_falconh1.CELL) + 1:] == [
         "ling-kda-insession", "xing-mhc-insession", "mellum2-swa-deep4096",
-        "phi4flash-yoco-deep2048", "kexaone-mtp-deep2048"]  # PR 49, 52, 57, 59, 65
+        "phi4flash-yoco-deep2048", "kexaone-mtp-deep2048",
+        "longcat-scmoe-deep2048"]  # PR 49, 52, 57, 59, 65, 68
     cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
                configs=manifest["configs"][:cells.index(_falconh1.CELL) + 1],
                workloads=manifest["workloads"][:cells.index(_falconh1.CELL) + 1])
@@ -206,7 +210,7 @@ LAST_TWO = (
     "chipbench/tests/test_heartbeat_metrics.py holds PR 54's two metrics to the "
     "LAST two places of per_layer and every cell outside its BACKBONE_CELLS to "
     "the three host-bound ones; PR 57's cell and eleven metrics follow, and PR 59's "
-    "and PR 65's cells and fifteen each, for the reason LAST_EIGHT gives. A benchmark PR repairs the case: PERF.md Open "
+    "and PR 65's cells and fifteen each and PR 68's and eight, for the reason LAST_EIGHT gives. A benchmark PR repairs the case: PERF.md Open "
     "question 9")
 
 
@@ -219,8 +223,9 @@ def test_the_manifest_gives_the_share_to_the_six_backbone_cells_alone():  # noqa
 
 def test_the_manifest_gives_the_share_to_the_cells_it_named(monkeypatch):
     """The case above, every assertion as it stands, over the manifest cut
-    off before what PR 57, PR 59 and PR 65 appended (a configuration, a cell
-    and eleven, fifteen and fifteen metrics, which read neither of the two:
+    off before what PR 57, PR 59, PR 65 and PR 68 appended (a configuration, a
+    cell and eleven, fifteen, fifteen and eight metrics, which read neither of
+    the two:
     ``wake_late_us`` lists no cells and is read there too,
     ``rpc_over_50ms_share`` keeps its list)."""
     from chipbench import validate
@@ -231,17 +236,18 @@ def test_the_manifest_gives_the_share_to_the_cells_it_named(monkeypatch):
     assert all(name.startswith("mellum_") for name in names[last + 1:last + 12])  # PR 57
     assert all(name.startswith(("phi4flash_", "selective_scan_"))
                for name in names[last + 12:last + 27])  # PR 59
-    assert all(name.startswith("kexaone_") for name in names[last + 27:]) \
-        and len(names[last + 27:]) == 15  # PR 65
+    assert all(name.startswith("kexaone_") for name in names[last + 27:last + 42])  # PR 65
+    assert all(name.startswith("longcat_") for name in names[last + 42:]) \
+        and len(names[last + 42:]) == 8  # PR 68
     later = ["mellum2-swa-deep4096", "phi4flash-yoco-deep2048",
-             "kexaone-mtp-deep2048"]
-    assert [w["name"] for w in manifest["workloads"][-3:]] == later
+             "kexaone-mtp-deep2048", "longcat-scmoe-deep2048"]
+    assert [w["name"] for w in manifest["workloads"][-4:]] == later
     for cell in later:
         assert "wake_late_us" in {
             m["name"] for m in validate.load_cell(cell)["per_layer"]}
     cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
-               configs=manifest["configs"][:-3],
-               workloads=manifest["workloads"][:-3])
+               configs=manifest["configs"][:-4],
+               workloads=manifest["workloads"][:-4])
     monkeypatch.setattr(_heartbeat.validate, "load_manifest",
                         lambda *args, **kwargs: cut)
     _heartbeat.test_the_manifest_gives_the_share_to_the_six_backbone_cells_alone()
@@ -250,7 +256,7 @@ def test_the_manifest_gives_the_share_to_the_cells_it_named(monkeypatch):
 LAST_ELEVEN = (
     "chipbench/tests/test_mellum_cell.py holds PR 57's configuration, cell and "
     "eleven metrics to the LAST places of their lists; PR 59's and PR 65's configuration, "
-    "cell and fifteen metrics each follow, for the reason LAST_EIGHT gives. A "
+    "cell and fifteen metrics each follow, and PR 68's and eight, for the reason LAST_EIGHT gives. A "
     "benchmark PR repairs the case: PERF.md Open question 9")
 
 
@@ -264,7 +270,8 @@ def test_the_mellum_configuration_is_held_to_its_source_and_states_its_cut():  #
 def test_the_mellum_configuration_holds_with_later_entries_set_aside(monkeypatch):
     """The case above, every assertion as it stands, over the manifest cut
     off after the configuration, the cell and the eleven metrics it expects
-    last: what follows them is what PR 59 and PR 65 appended, named here."""
+    last: what follows them is what PR 59, PR 65 and PR 68 appended, named
+    here."""
     from chipbench import validate
 
     manifest = validate.load_manifest()
@@ -272,13 +279,16 @@ def test_the_mellum_configuration_holds_with_later_entries_set_aside(monkeypatch
     last = max(names.index(name) for name in _mellum.METRICS)
     assert all(name.startswith(("phi4flash_", "selective_scan_"))
                for name in names[last + 1:last + 16])  # PR 59
-    assert all(name.startswith("kexaone_") for name in names[last + 16:]) \
-        and len(names[last + 16:]) == 15  # PR 65
+    assert all(name.startswith("kexaone_") for name in names[last + 16:last + 31])  # PR 65
+    assert all(name.startswith("longcat_") for name in names[last + 31:]) \
+        and len(names[last + 31:]) == 8  # PR 68
     cells = [w["name"] for w in manifest["workloads"]]
     at = cells.index(_mellum.CELL)
-    assert cells[at + 1:] == ["phi4flash-yoco-deep2048", "kexaone-mtp-deep2048"]
+    assert cells[at + 1:] == ["phi4flash-yoco-deep2048", "kexaone-mtp-deep2048",
+                              "longcat-scmoe-deep2048"]
     assert [c["name"] for c in manifest["configs"]][at + 1:] == [
-        "risk-seqhead-phi-4-mini-flash", "risk-seqhead-k-exaone-236b-a23b"]
+        "risk-seqhead-phi-4-mini-flash", "risk-seqhead-k-exaone-236b-a23b",
+        "risk-seqhead-longcat-flash-omni"]
     cut = dict(manifest, per_layer=manifest["per_layer"][:last + 1],
                configs=manifest["configs"][:at + 1],
                workloads=manifest["workloads"][:at + 1])

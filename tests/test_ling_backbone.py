@@ -1179,7 +1179,7 @@ def test_unknown_head_lists_the_new_name():
     assert "'ling'" in str(err.value) and "'falconh1'" in str(err.value)
     assert all(set(row.layers) == set(session_heads.LAYER_KINDS)
                for row in session_heads.HEADS.values())
-    assert len(session_heads.HEADS) == 11  # PR 52: ``xing``; PR 57: ``mellum``; PR 59: ``phi4flash``; PR 65: ``kexaone``
+    assert len(session_heads.HEADS) == 12  # PR 52: ``xing``; PR 57: ``mellum``; PR 59: ``phi4flash``; PR 65: ``kexaone``; PR 68: ``longcat``
 
 
 def test_chip_smoke_phase_runs_the_head_against_its_reference():

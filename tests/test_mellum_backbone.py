@@ -247,7 +247,7 @@ def test_the_row_of_heads_and_what_it_holds():
     assert row.key_blocks(4096) == (99 * BLOCK, 256 * BLOCK)
     assert row.key_blocks(16) == (4 * 256, 4 * 256)  # inside the band nothing is skipped
     assert all(r.key_blocks is None for name, r in session_heads.HEADS.items()
-               if name not in ("mellum", "phi4flash", "kexaone"))
+               if name not in ("mellum", "phi4flash", "kexaone", "longcat"))
     with pytest.raises(ValueError) as err:
         session_heads.session_head("kimi")
     assert "'mellum'" in str(err.value)

@@ -280,7 +280,7 @@ def test_the_row_of_heads_and_what_it_holds():
     assert row.key_blocks(2048) == ((8 * 7 + 4) * BLOCK, 9 * 16 * BLOCK)
     assert all(r.layer_positions is None
                for name, r in session_heads.HEADS.items()
-               if name not in ("phi4flash", "kexaone"))
+               if name not in ("phi4flash", "kexaone", "longcat"))
     assert "'phi4flash'" in str(pytest.raises(
         ValueError, session_heads.session_head, "kimi").value)
 
