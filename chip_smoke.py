@@ -708,7 +708,8 @@ def phase_kernels(interpret: bool = False, *,
                   share_shape: tuple = (2048, 7680, 4096, 1000),
                   grouped_windows: int = 32, delta_windows: int = 32,
                   ssd_windows: int = 32, stream_tiles: int = 32,
-                  block_window: int = 4096, scan_window: int = 2048) -> dict:
+                  block_window: int = 4096, scan_window: int = 2048,
+                  latent_window: int = 2048) -> dict:
     """Every Pallas entry point at the shapes the repo uses — flash
     forward resident (S=64 is what CheckBonusAbuse serves, S=256, S=2048)
     and tiled (S=8192), backward at S=2048, the GBDT forest at
@@ -737,7 +738,10 @@ def phase_kernels(interpret: bool = False, *,
     would pick there; and the two stream kernels of the ``xing`` head's residual path
     (four streams of 3,584, 20 rounds) on ``stream_tiles`` tiles of 128
     positions against the ``jax.numpy`` functions, with the path a trace
-    would pick there."""
+    would pick there; and the blocked latent core of the ``longcat`` head's
+    attention (16 heads of 128 + 64 / 128, interleaved rotary pairs) on one
+    window of ``latent_window`` positions against the einsums in query
+    blocks, with the core a trace would pick there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1079,6 +1083,37 @@ def phase_kernels(interpret: bool = False, *,
               f"blocked attention ({kind}) on a window of {t}: max err {err} "
               f"> {BACKBONE_TOL}")
     report[f"block_attention_T{t}"] = cores
+
+    from igaming_platform_tpu.models import longcat_backbone
+
+    # the blocked latent core at the ``longcat`` head's attention (16 heads
+    # of 128 + 64 / 128, interleaved rotary pairs) on one window of
+    # ``latent_window`` positions against the einsums in query blocks, with
+    # the core a trace would pick there
+    cfg, t = longcat_backbone.LongcatConfig(), latent_window
+    widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
+                  dv=cfg.v_dim, window=t)
+    ks = jax.random.split(jax.random.key(t + 7), 3)
+    q = jax.random.normal(ks[0], (t, cfg.heads * (cfg.nope_dim + cfg.rope_dim)),
+                          jnp.float32) * 2
+    kvb = jax.random.normal(ks[1], (t, cfg.heads * (cfg.nope_dim + cfg.v_dim)),
+                            jnp.float32).astype(jnp.bfloat16)
+    k_rope = jax.random.normal(ks[2], (t, cfg.rope_dim),
+                               jnp.float32).astype(jnp.bfloat16)
+    cos, sin = (x.reshape(t, -1) for x in decoder_parts.rope_angles(
+        1, t, cfg.rope_dim, cfg.rope_theta))
+    picked = _said_by_the_expert_layer(
+        lambda: decoder_parts.latent_attention_core(q, kvb, cfg, t, interleave=True))
+    got = ba.latent_block_attention(q, kvb, k_rope, cos, sin, **widths,
+                                    interleave=True, interpret=interpret)
+    want = jax.jit(functools.partial(decoder_parts.latent_core_by_einsums, **widths,
+                                     interleave=True))(q, kvb, k_rope, cos, sin)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                / jnp.max(jnp.abs(want)))
+    report[f"latent_block_attention_T{t}"] = {"max_err": err, "core": picked[0]}
+    check(bool(jnp.all(jnp.isfinite(got))) and err <= BACKBONE_TOL,
+          f"blocked latent attention on a window of {t}: max err {err} "
+          f"> {BACKBONE_TOL}")
 
     from igaming_platform_tpu.models import phi4flash_backbone
     from igaming_platform_tpu.ops.pallas import selective_scan as scan

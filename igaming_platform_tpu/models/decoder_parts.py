@@ -385,8 +385,10 @@ def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
                            interleave: bool = False, scale_by: float = 1.0,
                            block: int | None = None):
     """The core of latent attention as three einsums over ``[b, t, h, d]``,
-    under the kernel's signature (ops/pallas/window_attention.
-    window_attention): its reference, and what runs off the TPU. ->
+    under the kernels' signature (ops/pallas/window_attention.
+    window_attention at windows that fit one block, ops/pallas/
+    block_attention.latent_block_attention at deeper ones): their
+    reference, and what runs off the TPU. ->
     float32 [P, heads x dv], which ``Wo``'s product rounds. With
     ``interleave`` the rotary part of ``q`` turns by interleaved pairs
     (``rotate``); ``k_rope`` comes turned, by the same pairing. The scores
@@ -441,35 +443,51 @@ def latent_core_by_einsums(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
 def latent_attention_core(q, kvb, cfg, window: int, interleave: bool = False,
                           scale_by: float = 1.0):
     """What runs the core of latent attention over ``q`` [P, heads x (nope
-    + rope)] and ``kvb`` [P, heads x (nope + v)] (arrays or shapes): the
-    Pallas kernel (ops/pallas/window_attention.py) on a TPU where its
-    ``supports`` holds, else ``latent_core_by_einsums``; either way a
-    function of ``(q, kvb, k_rope, cos, sin)``. Picked while tracing, from
-    backend and shapes, and announced once a compile. The kernel turns
-    rotate-half pairs, so with ``interleave`` the einsums run on every
-    backend and the announcement says why: the pairs are never re-paired
-    silently. ``scale_by`` multiplies the softmax scale in either core (the
-    ``xing`` head's YaRN factor; 1 for the others). Where the einsums run
-    over a window deeper than one block the announcement says in what
-    query blocks."""
+    + rope)] and ``kvb`` [P, heads x (nope + v)] (arrays or shapes): a
+    function of ``(q, kvb, k_rope, cos, sin)``, picked while tracing from
+    the backend, the window's depth and the operands' shapes, and announced
+    once a compile.
+
+    A window that fits one block (``block_attention.block_for``: every
+    head's 16 keys): the window kernel (ops/pallas/window_attention.py) on
+    a TPU where its ``supports`` holds, else ``latent_core_by_einsums``.
+    That kernel turns rotate-half pairs, so with ``interleave`` the einsums
+    run there on every backend and the announcement says why: the pairs are
+    never re-paired silently. A window deeper than one block (the
+    ``longcat`` head's 2,048 events): the blocked kernel
+    (ops/pallas/block_attention.latent_block_attention, which turns the
+    pairing it is told) on a TPU where its ``latent_declines`` says
+    nothing, else the einsums in query blocks, with the kernel's reason
+    announced. ``scale_by`` multiplies the softmax scale in every core (the
+    ``xing`` head's YaRN factor; 1 for the others)."""
+    from igaming_platform_tpu.ops.pallas import block_attention as blocks
     from igaming_platform_tpu.ops.pallas import window_attention as kernel
-    from igaming_platform_tpu.ops.pallas.block_attention import block_for
 
     widths = dict(heads=cfg.heads, nope=cfg.nope_dim, rope=cfg.rope_dim,
                   dv=cfg.v_dim, window=window)
     scaled = dict(scale_by=scale_by)
-    block = block_for(window)
-    einsums = ("xla-einsum" if block >= window else
-               f"xla-einsum in query blocks of {block} (window {window}, "
-               f"{cfg.heads} heads)")
+    block = blocks.block_for(window)
+    if block < window:
+        why, backend = kernel_declines(
+            lambda: blocks.latent_declines(q, kvb, **widths))
+        pairs = "interleaved" if interleave else "rotate-half"
+        announce_core(
+            f"xla-einsum in query blocks of {block} (window {window}, "
+            f"{cfg.heads} heads; {why})" if why else
+            f"pallas-blocks (latent, {cfg.heads} heads of {cfg.nope_dim} + "
+            f"{cfg.rope_dim} / {cfg.v_dim}, {pairs} rotary pairs, "
+            f"{blocks.describe(window, None)})", backend, "attention core")
+        return partial(latent_core_by_einsums if why else
+                       blocks.latent_block_attention, **widths, **scaled,
+                       interleave=interleave)
     if interleave:
         _, backend = kernel_declines()
-        announce_core(f"{einsums} (interleaved rotary pairs: the window "
+        announce_core("xla-einsum (interleaved rotary pairs: the window "
                       "kernel turns by halves)", backend, "attention core")
         return partial(latent_core_by_einsums, **widths, **scaled,
                        interleave=True)
     why, backend = kernel_declines(lambda: not kernel.supports(q, kvb, **widths))
-    announce_core(einsums if why else "pallas-windows", backend,
+    announce_core("xla-einsum" if why else "pallas-windows", backend,
                   "attention core")
     return partial(latent_core_by_einsums if why else kernel.window_attention,
                    **widths, **scaled)
@@ -520,17 +538,20 @@ def latent_attention(a, layer: Params, cos, sin, cfg, interleave: bool = False,
     hidden], in its expanded form -> [B, T, hidden] (``pangu``: before its
     post-norm). The core (the rotary part of ``q``, scores, mask, softmax,
     ``p v``) is one Pallas kernel over the projections' results as they
-    lie where ``window_attention.supports`` holds on a TPU, else three
-    einsums over ``[b, t, h, d]`` (in query blocks where the window is
-    deeper than one): the same expanded form at the same precision either
-    way. ``cfg`` gives ``heads`` (those held here: the ``longcat`` head's
+    lie where ``latent_attention_core`` finds one that takes them on a TPU
+    (the window kernel at windows that fit one block, the blocked kernel
+    at deeper ones), else three einsums over ``[b, t, h, d]`` (in query
+    blocks where the window is deeper than one): the same expanded form at
+    the same precision either way. ``cfg`` gives ``heads`` (those held here: the ``longcat`` head's
     are a chip's share, whose part of ``Wo``'s product goes on as it is),
     ``kv_rank``, ``nope_dim``, ``rope_dim``, ``v_dim`` and ``eps``.
 
     What the ``ling`` head's layer differs by is read off the layer and one
     argument (``pangu`` has and passes none of it): without a query latent
     (no ``wq_a``) the queries are ``a Wq``; with ``interleave`` the rotary
-    pairs are interleaved on both sides; with a head-wise gate (``wgate``
+    pairs are interleaved on both sides (the blocked kernel turns them, the
+    window kernel does not: at a window that fits one block the einsums run
+    then, on every backend); with a head-wise gate (``wgate``
     [hidden, heads]) each head's output is multiplied by ``sigmoid(a
     Wgate)`` of its head, in float32, before ``Wo`` rounds it. ``scale_by``
     (the ``xing`` head's: YaRN's attention factor squared) multiplies the

@@ -31,9 +31,12 @@ of ``[k_nope | v]`` (the scale reaches ``k_nope`` and ``v``, not ``k_rope``).
 Scores ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)``, causal
 inside the window, softmax in float32, times ``v``; ``Wo``. The expanded form,
 every position of every window computed, no latent cached; over a window
-deeper than one block the core runs in query blocks
-(``decoder_parts.latent_core_by_einsums``) and nothing ``[t, t]`` of a whole
-window stands at once.
+deeper than one block the core runs in query blocks and nothing ``[t, t]`` of
+a whole window stands at once: on a TPU as one Pallas kernel an attention
+(ops/pallas/block_attention.latent_block_attention: a query block's scores
+stay in VMEM and the interleaved pairs turn inside), elsewhere as
+``decoder_parts.latent_core_by_einsums``; ``decoder_parts.
+latent_attention_core`` picks from the window's depth and the shapes.
 
 ``MoE``: ``p = softmax(u Wr)`` over ALL ``experts`` outputs in float32; the
 ``top_k`` largest of ``p + rb`` chosen (the bias chooses and does not weigh);

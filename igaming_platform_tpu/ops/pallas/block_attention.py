@@ -1,6 +1,12 @@
-"""Pallas TPU kernel: causal grouped-query attention over deep windows in
-blocks of keys, with a band; only the key blocks the mask keeps are visited,
-and a narrow band's keys are read in one visit.
+"""Pallas TPU kernels: causal attention over deep windows in blocks of keys;
+only the key blocks the mask keeps are visited. Two entries, picked by the
+operands a caller has: grouped-query attention with a band
+(``block_attention``, ``declines``: shared key heads, the head norm and a
+rotary over the whole head inside; a narrow band's keys read in one visit),
+which this header describes, and further down latent attention
+(``latent_block_attention``, ``latent_declines``: per-head ``[k_nope | v]``
+beside one shared rotary key, the rotary part turned inside by the pairing the
+caller states), whose own header says where it differs and why.
 
 ``models/mellum_backbone.attention`` attends inside windows of ``T``
 positions (4,096 in its cell) in two kinds of layer: a *full* one, where
@@ -85,6 +91,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from igaming_platform_tpu.ops.pallas.window_attention import _unit
 
 _LANES = 128
 _SUBLANES = 16  # rows of a bfloat16 tile
@@ -395,6 +403,19 @@ def _block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
     )(q, k, v, cos, sin, gain.astype(jnp.float32).reshape(1, hd))
 
 
+def _pad_windows(x, window: int, pad: int):
+    """``x`` [windows x window, C] with ``pad`` rows of zeros after every
+    window."""
+    x = jnp.pad(x.reshape(-1, window, x.shape[1]), ((0, 0), (0, pad), (0, 0)))
+    return x.reshape(-1, x.shape[2])
+
+
+def _cut_windows(x, window: int, pad: int):
+    """``_pad_windows`` undone: every window's first ``window`` rows."""
+    x = x.reshape(-1, window + pad, x.shape[1])[:, :window]
+    return x.reshape(-1, x.shape[2])
+
+
 def block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
                     window: int, band: int | None, eps: float,
                     block: int | None = None, interpret: bool = False):
@@ -420,22 +441,276 @@ def block_attention(q, k, v, cos, sin, gain, *, heads: int, kv_heads: int,
     not whole query blocks of the form is padded here and cut from the
     result. ``interpret=True`` runs the Pallas interpreter, always the
     caller's explicit choice."""
-    p = q.shape[0]
     block = block or block_for(window)
     pad = -window % _program(window, band, block)[0]
     if pad:
-        b = p // window
-
-        def padded(x):
-            x = jnp.pad(x.reshape(b, window, -1), ((0, 0), (0, pad), (0, 0)))
-            return x.reshape(b * (window + pad), -1)
-
-        q, k, v = padded(q), padded(k), padded(v)
+        q, k, v = (_pad_windows(x, window, pad) for x in (q, k, v))
         cos, sin = (jnp.pad(x, ((0, pad), (0, 0))) for x in (cos, sin))
     out = _block_attention(q, k, v, cos, sin, gain, heads=heads,
                            kv_heads=kv_heads, window=window + pad, band=band,
                            eps=eps, block=block, interpret=interpret)
+    return _cut_windows(out, window, pad) if pad else out
+
+
+# --- the latent form: per-head keys beside one shared rotary key -------------
+#
+# ``models/decoder_parts.latent_attention`` over windows deeper than one block
+# (the ``longcat`` head's 2,048 events). The same blocks as a full layer's
+# sweep above (``block_for``; query block ``i`` meets key blocks ``0 .. i``,
+# the diagonal's pairs masked ``key <= query``: ``visited_blocks(window,
+# None)`` counts them) over a latent layer's operands: every head has its own
+# ``[k_nope | v]`` columns of ``kvb`` and all share the one rotary key
+# ``k_rope``, so no head's rows stack on another's.
+#
+# **A program** is one window, one *unit* of heads and one query block. Heads
+# go in units of one or two (``window_attention._unit``: at the published 128 +
+# 64 every other head's ``q`` columns lie 64 lanes off a vreg boundary; the
+# grid's blocks are whole vregs and the half-vreg shift happens in VMEM, by
+# static slices). The window's ``kvb`` columns of the unit and ``k_rope`` lie
+# whole in VMEM, their block index changing only with the window and the unit,
+# so HBM gives them once.
+#
+# **One product a head, of contraction 256.** ``s = q_nope k_nope^T +
+# rot(q_rope) k_rope^T`` is ``[q_nope | rot(q_rope) | 0] [k_nope | k_rope |
+# 0]^T``: 192 channels are two passes of the 128-deep MXU either way, and the
+# zeros add exact zeros. The window's first query block lays the unit's keys
+# out so (``kc_ref``, whole vregs a head) and the window's other blocks read
+# them where they lie (the query blocks of one window and unit run in order:
+# the grid's last axis is ``arbitrary``). Measured on a v5e at ``longcat``'s
+# cell shape (2 windows of 2,048, 16 heads; PERF.md, section 6, PR 69): as two
+# products a head 0.76 ms a core, as one of 192 channels concatenated a visit
+# 0.72.
+#
+# **The rotary part turns inside**, in float32 before its one rounding, BY THE
+# PAIRING THE CALLER STATES: a unit's rotary parts are put side by side in
+# whole vregs and a pair's other channel comes by lane rolls, ``shift`` lanes
+# away: interleaved pairs (channels ``2 i`` and ``2 i + 1``: ``shift`` 1, the
+# neighbour above an even lane and below an odd one) or rotate-half (``i`` and
+# ``i + rope / 2``: ``shift`` ``rope / 2``). ``sin`` comes laid over the unit's
+# lanes with the pair's sign.
+#
+# **One visit a query block**, of every key block it keeps: what a visit of the
+# sweep costs is fixed by its rows, not its keys (the header's finding of a
+# narrow band, PR 66), and it holds for a whole core too. As the sweep with its
+# online softmax (a unit's two heads side by side in one rolled loop over key
+# blocks) this form read 0.76 ms a core at blocks of 512, 1.10 at 256 and 0.55
+# at 1,024, which scores a fifth more pairs: 3.7 ns a row and visit against 1.9
+# ps a scored pair, four fifths of the time in the passes over the running
+# maximum, the sum and the accumulator. So query block ``i`` scores its ``(i +
+# 1) x block`` keys at once: one product, the mask, the row's maximum, ``exp``
+# rounded once to the operands' dtype, its product with ``v``, one division;
+# nothing carried, nothing rescaled: **0.45 ms a core** (the einsums in query
+# blocks 3.27). The slab's length is static in each of the ``window / block``
+# branches a program picks from by its block's index; the branches are what is
+# unrolled (four at 2,048), never the heads of a layer. A program's scores are
+# ``[block, window]`` float32 at most, which is what ``latent_declines`` holds
+# against ``_VMEM_CAP``: a window too deep for it takes the caller's einsums.
+#
+# The order of roundings is the grouped forms': ``exp(s - m)`` rounded before
+# the division by the row's sum, where the einsum reference divides first.
+
+
+def _latent_widths(nope: int, rope: int, dv: int) -> tuple[int, int]:
+    """``(heads a program takes, lanes a head's widened keys take)``: the
+    unit, and ``nope + rope`` in whole vregs."""
+    return _unit(nope, rope, dv), _LANES * -(-(nope + rope) // _LANES)
+
+
+def _latent_vmem(block: int, nope: int, rope: int, dv: int, padded: int,
+                 q_size: int, size: int) -> int:
+    """A program of ``block`` query positions of one unit of heads: both
+    buffers of its blocks (queries, the window's ``kvb`` columns of the unit
+    and ``k_rope``, the result, the angles), the unit's widened keys, its
+    widened queries, a head's float32 scores against the whole window
+    several times over, and room to spare."""
+    unit, kw = _latent_widths(nope, rope, dv)
+    blocks = (block * unit * ((nope + rope) * q_size + dv * size)
+              + padded * (unit * (nope + dv) + rope) * size
+              + 2 * block * unit * rope * 4)
+    held = unit * kw * (padded + block) * size
+    return 2 * blocks + held + 4 * unit * block * padded * 4 + 4 * 2**20
+
+
+def latent_declines(q, kvb, *, heads: int, nope: int, rope: int, dv: int,
+                    window: int) -> str:
+    """Why ``latent_block_attention`` does not take these operands, "" where
+    it does. ``q`` [P, heads x (nope + rope)], ``kvb`` [P, heads x (nope +
+    dv)] (arrays or their shapes-and-dtypes; ``k_rope`` [P, rope] comes in
+    ``kvb``'s dtype). It takes
+    ``k_nope`` and ``v`` of whole 128-lane vregs and a rotary part of whole
+    64-lane halves (a unit of two heads is then whole vregs), heads in whole
+    units, bfloat16 or float32 keys and values of one dtype, whole windows,
+    and a query block's scores against the whole window beside a program's
+    blocks inside VMEM; anything else takes the caller's einsums."""
+    if (nope <= 0 or dv <= 0 or nope % _LANES or dv % _LANES or rope <= 0
+            or rope % (_LANES // 2)):
+        return (f"widths {nope} + {rope} / {dv} are not whole {_LANES}-lane "
+                f"vregs beside a rotary part of whole {_LANES // 2}-lane halves")
+    unit, _ = _latent_widths(nope, rope, dv)
+    if heads <= 0 or heads % unit:
+        return f"{heads} heads are not whole units of {unit}"
+    p = q.shape[0]
+    if window <= 0 or p == 0 or p % window:
+        return f"{p} positions are not whole windows of {window}"
+    if (q.shape != (p, heads * (nope + rope))
+            or kvb.shape != (p, heads * (nope + dv))):
+        return f"kvb {kvb.shape} against q {q.shape}"
+    if kvb.dtype not in (jnp.bfloat16, jnp.float32):
+        return f"operands {kvb.dtype}"
+    if not jnp.issubdtype(q.dtype, jnp.floating):
+        return f"q {q.dtype}"
+    block = block_for(window)
+    need = _latent_vmem(block, nope, rope, dv, block * -(-window // block),
+                        q.dtype.itemsize, kvb.dtype.itemsize)
+    if need > _VMEM_CAP:
+        return f"a program's blocks take {need} of {_VMEM_CAP} bytes of VMEM"
+    return ""
+
+
+def _latent_kernel(q_ref, kv_ref, kr_ref, cos_ref, sin_ref, o_ref, kc_ref, *,
+                   block: int, nope: int, rope: int, dv: int, shift: int,
+                   scale: float):
+    f32 = jnp.float32
+    dt = kv_ref.dtype
+    i = pl.program_id(2)
+    qk, kvw = nope + rope, nope + dv
+    window, unit = kc_ref.shape[0], o_ref.shape[1] // dv
+    kw = kc_ref.shape[1] // unit
+
+    def widened(parts, rows):
+        """``[nope part | rotary part | 0]`` over a head's ``kw`` lanes."""
+        zeros = [jnp.zeros((rows, kw - qk), dt)] if kw > qk else []
+        return jnp.concatenate(parts + zeros, axis=1)
+
+    @pl.when(i == 0)
+    def _():
+        # the window's keys of the unit, [k_nope | k_rope | 0] a head: laid
+        # out by the window's first query block, read by all of them
+        k_rope = kr_ref[...]
+        for h in range(unit):
+            kc_ref[:, h * kw:(h + 1) * kw] = widened(
+                [kv_ref[:, h * kvw:h * kvw + nope], k_rope], window)
+
+    # the unit's rotary parts side by side, whole vregs: a pair's other
+    # channel lies ``shift`` lanes up from the pair's first and as many down
+    # from its second, and ``sin`` carries the pair's sign
+    first = jax.lax.broadcasted_iota(
+        jnp.int32, (block, unit * rope), 1) % (2 * shift) < shift
+    q = q_ref[...]
+    r = jnp.concatenate([q[:, h * qk + nope:(h + 1) * qk]
+                         for h in range(unit)], axis=1).astype(f32)
+    other = jnp.where(first, pltpu.roll(r, unit * rope - shift, 1),
+                      pltpu.roll(r, shift, 1))
+    r = (r * cos_ref[...] + other * sin_ref[...]).astype(dt)
+    qc = [widened([q[:, h * qk:h * qk + nope].astype(dt),
+                   r[:, h * rope:(h + 1) * rope]], block) for h in range(unit)]
+
+    def visit(j: int):
+        """Query block ``j``: its ``j + 1`` key blocks at once."""
+        keys = (j + 1) * block
+        # the key is not after the query (the diagonal block's pairs alone
+        # can fail it)
+        keep = (jax.lax.broadcasted_iota(jnp.int32, (block, keys), 1)
+                <= jax.lax.broadcasted_iota(jnp.int32, (block, keys), 0)
+                + j * block)
+        out = []
+        for h in range(unit):
+            s = jax.lax.dot_general(
+                qc[h], kc_ref[:keys, h * kw:(h + 1) * kw],
+                (((1,), (1,)), ((), ())), preferred_element_type=f32) * scale
+            s = jnp.where(keep, s, -jnp.inf)
+            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            o = jnp.dot(e.astype(dt), kv_ref[:keys, h * kvw + nope:(h + 1) * kvw],
+                        preferred_element_type=f32)
+            out.append((o / jnp.sum(e, axis=-1, keepdims=True)).astype(o_ref.dtype))
+        o_ref[...] = jnp.concatenate(out, axis=1)
+
+    for j in range(window // block):
+        pl.when(i == j)(functools.partial(visit, j))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "nope", "rope", "dv", "window", "interleave", "scale_by", "block",
+    "interpret"))
+def _latent_block_attention(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
+                            rope: int, dv: int, window: int, interleave: bool,
+                            scale_by: float, block: int, interpret: bool):
+    """``window`` is whole query blocks here (``latent_block_attention``
+    pads)."""
+    p = q.shape[0]
+    qk, kvw = nope + rope, nope + dv
+    unit, kw = _latent_widths(nope, rope, dv)
+    size = kvb.dtype.itemsize
+    n = window // block
+    pairs = (p // window) * heads * visited_blocks(window, None, block)[0]
+    # the angles over a unit's lanes, ``sin`` signed as the pairing signs it
+    if interleave:
+        cos = jnp.repeat(cos, 2, axis=1)
+        sin = jnp.stack([-sin, sin], axis=-1).reshape(p, rope)
+    else:
+        cos = jnp.concatenate([cos, cos], axis=1)
+        sin = jnp.concatenate([-sin, sin], axis=1)
+    cos, sin = jnp.tile(cos, (1, unit)), jnp.tile(sin, (1, unit))
+    by_block = lambda b, g, i: (b * n + i, g)
+    angles = pl.BlockSpec((block, unit * rope), lambda b, g, i: (b * n + i, 0))
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, block=block, nope=nope, rope=rope,
+                          dv=dv, shift=1 if interleave else rope // 2,
+                          scale=qk ** -0.5 * scale_by),
+        out_shape=jax.ShapeDtypeStruct((p, heads * dv), kvb.dtype),
+        grid=(p // window, heads // unit, n),
+        in_specs=[pl.BlockSpec((block, unit * qk), by_block),
+                  pl.BlockSpec((window, unit * kvw), lambda b, g, i: (b, g)),
+                  pl.BlockSpec((window, rope), lambda b, g, i: (b, 0)),
+                  angles, angles],
+        out_specs=pl.BlockSpec((block, unit * dv), by_block),
+        scratch_shapes=[pltpu.VMEM((window, unit * kw), kvb.dtype)],  # the keys
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=min(_VMEM_CAP, _latent_vmem(
+                block, nope, rope, dv, window, q.dtype.itemsize, size))),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (qk + dv),
+            transcendentals=pairs,
+            bytes_accessed=(q.size * q.dtype.itemsize
+                            + (kvb.size + k_rope.size + p * heads * dv) * size
+                            + 2 * p * unit * rope * 4)),
+        interpret=interpret,
+    )(q, kvb, k_rope, cos, sin)
+
+
+def latent_block_attention(q, kvb, k_rope, cos, sin, *, heads: int, nope: int,
+                           rope: int, dv: int, window: int,
+                           interleave: bool = False, scale_by: float = 1.0,
+                           block: int | None = None, interpret: bool = False):
+    """Causal latent attention inside windows of ``window`` consecutive
+    positions, every head against its own keys and the one shared rotary
+    key, in blocks of keys: ``decoder_parts.latent_core_by_einsums``'
+    signature, which stays its reference and what runs off the TPU.
+
+    ``q`` [P, heads x (nope + rope)], a head's ``[q_nope | q_rope]`` with
+    the rotary part NOT yet turned (any float dtype; float32 as the
+    projection leaves it); ``kvb`` [P, heads x (nope + dv)], a head's
+    ``[k_nope | v]`` in the operands' dtype; ``k_rope`` [P, rope], turned,
+    in the operands' dtype; ``cos``, ``sin`` [P, rope / 2] float32, a
+    position's rotary angles -> [P, heads x dv] in the operands' dtype,
+    position-major as ``Wo``'s product reads it: per head ``softmax((q_nope
+    k_nope^T + rot(q_rope) k_rope^T) x (nope + rope) ** -0.5 x scale_by) v``
+    over the keys of the query's window at or before it. ``rot`` turns
+    interleaved pairs with ``interleave`` (channels ``2 i`` and ``2 i +
+    1``), else rotate-half pairs (``i`` and ``i + rope / 2``); ``k_rope``
+    comes turned by the same pairing. ``block`` is ``block_for(window)``
+    unless a test says otherwise. A window that is not whole query blocks is
+    padded here (zeros: keys past a real query, which causality masks) and
+    cut from the result. ``interpret=True`` runs the Pallas interpreter,
+    always the caller's explicit choice."""
+    block = block or block_for(window)
+    pad = -window % block
     if pad:
-        out = out.reshape(p // window, window + pad, -1)[:, :window]
-        out = out.reshape(p, -1)
-    return out
+        q, kvb, k_rope, cos, sin = (_pad_windows(x, window, pad)
+                                    for x in (q, kvb, k_rope, cos, sin))
+    out = _latent_block_attention(
+        q, kvb, k_rope, cos, sin, heads=heads, nope=nope, rope=rope, dv=dv,
+        window=window + pad, interleave=interleave, scale_by=scale_by,
+        block=block, interpret=interpret)
+    return _cut_windows(out, window, pad) if pad else out

@@ -211,3 +211,127 @@ def test_declines_says_what_the_kernel_takes(change, why):
                        heads=heads, kv_heads=kv, window=window,
                        band=change.get("band"))
     assert (why in said) if why else said == ""
+
+
+# -- the latent form -------------------------------------------------------------
+
+
+def latent_operands(window: int, heads: int, nope: int, rope: int, dv: int,
+                    dtype, rows: int = 2, seed: int = 0):
+    from igaming_platform_tpu.models import decoder_parts as dp
+
+    key = jax.random.key(seed)
+    p = rows * window
+    draw = lambda i, shape: jax.random.normal(jax.random.fold_in(key, i), shape)
+    q = draw(1, (p, heads * (nope + rope)))
+    kvb = draw(2, (p, heads * (nope + dv))).astype(dtype)
+    k_rope = draw(3, (p, rope)).astype(dtype)
+    cos, sin = dp.rope_angles(rows, window, rope, 1e4)
+    widths = dict(heads=heads, nope=nope, rope=rope, dv=dv, window=window)
+    return (q, kvb, k_rope, cos.reshape(p, -1), sin.reshape(p, -1)), widths
+
+
+# (window, block, heads, nope, rope, dv, interleave, scale_by, operands' dtype)
+LATENT = [
+    # longcat's widths: a unit of two heads, the second 64 lanes off a vreg
+    (64, 16, 4, 128, 64, 128, True, 1.0, BF16),
+    (64, 16, 4, 128, 64, 128, False, 1.0, BF16),
+    (64, 16, 4, 128, 64, 128, True, 1.0, F32),
+    (64, 16, 4, 128, 64, 128, False, 1.0, F32),
+    # a softmax scale that is not the widths' alone (a rotary scaling's factor)
+    (64, 32, 2, 128, 64, 128, True, 0.7, BF16),
+    (64, 32, 2, 128, 64, 128, False, 1.3, F32),
+    # windows padded to whole query blocks (64, 48) and cut
+    (56, 16, 2, 128, 64, 128, True, 1.0, BF16),
+    (40, 16, 2, 128, 64, 128, False, 0.7, BF16),
+    # one query block a window: the diagonal alone
+    (16, 16, 2, 128, 64, 128, True, 1.0, BF16),
+    # a unit of one head (every width whole vregs), and of two with a wider
+    # rotary part beside narrower values: odd lane offsets on the q side only
+    (64, 16, 3, 128, 128, 128, True, 1.0, BF16),
+    (64, 16, 3, 128, 128, 128, False, 1.0, BF16),
+    (48, 16, 2, 256, 64, 128, True, 1.0, BF16),
+    (48, 16, 2, 128, 192, 256, False, 1.0, F32),
+    # the block the cell runs (512), a window of two of them and one padded
+    (1024, None, 2, 128, 64, 128, True, 1.0, BF16),
+    (1000, None, 2, 128, 64, 128, True, 1.0, F32),
+]
+
+
+def latent_id(case) -> str:
+    window, block, heads, nope, rope, dv, interleave, scale_by, dtype = case
+    return (f"T{window}-block{block}-{heads}x{nope}+{rope}/{dv}-"
+            f"{'interleaved' if interleave else 'halves'}-x{scale_by:g}-"
+            f"{jnp.dtype(dtype).name}")
+
+
+@pytest.mark.parametrize("case", LATENT, ids=latent_id)
+def test_latent_kernel_equals_the_three_einsums_in_query_blocks(case):
+    from igaming_platform_tpu.models import decoder_parts as dp
+
+    window, block, heads, nope, rope, dv, interleave, scale_by, dtype = case
+    operands, widths = latent_operands(window, heads, nope, rope, dv, dtype,
+                                       rows=2 if window <= 64 else 1)
+    assert ba.latent_declines(*operands[:2], **widths) == ""
+    said = dict(interleave=interleave, scale_by=scale_by, block=block)
+    want = dp.latent_core_by_einsums(*operands, **widths, **said)
+    got = ba.latent_block_attention(*operands, **widths, **said, interpret=True)
+    assert got.shape == want.shape and got.dtype == dtype
+    # the grouped kernel's tolerance: a bfloat16 step of the values where the
+    # result is rounded once and the probabilities before the division; in
+    # float32 only the order of the sums differs
+    tol = 0.02 if dtype == BF16 else 1e-4
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=tol, rtol=tol)
+    if window <= 64:
+        # the other pairing is another function: the kernel turned the one
+        # it was told
+        other = dp.latent_core_by_einsums(*operands, **widths, block=block,
+                                          interleave=not interleave,
+                                          scale_by=scale_by)
+        assert np.abs(np.asarray(got, np.float32) - np.asarray(other)).max() > 10 * tol
+
+
+def test_latent_kernel_reads_the_keys_at_or_before_the_query():
+    """With ``q`` zero every kept key weighs the same, and key ``j``'s value
+    is one-hot at channel ``j``: the result says which keys each query
+    read, every head the same."""
+    window, heads, nope, rope, dv = 64, 2, 128, 64, 128
+    (q, kvb, k_rope, cos, sin), widths = latent_operands(window, heads, nope, rope,
+                                                        dv, BF16, rows=1)
+    head = jnp.concatenate([jnp.ones((window, nope), BF16),
+                            jnp.eye(window, dv, dtype=BF16)], axis=1)
+    out = ba.latent_block_attention(jnp.zeros_like(q), jnp.tile(head, (1, heads)),
+                                    k_rope, cos, sin, **widths, block=16,
+                                    interleave=True, interpret=True)
+    out = np.asarray(out, np.float32).reshape(window, heads, dv)
+    assert (out == out[:, :1]).all()
+    i, j = np.arange(window)[:, None], np.arange(window)[None, :]
+    assert ((out[:, 0, :window] > 0) == (j <= i)).all()
+
+
+@pytest.mark.parametrize("change,why", [
+    (dict(nope=64), "widths 64 + 64 / 128 are not whole 128-lane vregs"),
+    (dict(dv=192), "widths 128 + 64 / 192 are not whole 128-lane vregs"),
+    (dict(rope=32), "beside a rotary part of whole 64-lane halves"),
+    (dict(heads=15), "15 heads are not whole units of 2"),
+    (dict(heads=15, rope=128), ""),
+    (dict(window=48), "positions are not whole windows of 48"),
+    (dict(kvb_heads=8), "against q"),
+    (dict(dtype=jnp.float16), "operands float16"),
+    (dict(dtype=jnp.float32), ""),
+    (dict(q_dtype=jnp.int32), "q int32"),
+    (dict(window=1 << 17, p=1 << 18), "of VMEM"),
+    (dict(window=16, p=4096), ""),
+    (dict(), "")])
+def test_latent_declines_says_what_the_kernel_takes(change, why):
+    heads, nope = change.get("heads", 16), change.get("nope", 128)
+    rope, dv = change.get("rope", 64), change.get("dv", 128)
+    window, p = change.get("window", 2048), change.get("p", 4096)
+    dt = change.get("dtype", jnp.bfloat16)
+    shape = jax.ShapeDtypeStruct
+    said = ba.latent_declines(
+        shape((p, heads * (nope + rope)), change.get("q_dtype", jnp.float32)),
+        shape((p, change.get("kvb_heads", heads) * (nope + dv)), dt),
+        heads=heads, nope=nope, rope=rope, dv=dv, window=window)
+    assert (why in said) if why else said == ""

@@ -962,9 +962,14 @@ def test_double_layer_step_holds_no_square_of_a_window_and_narrows_its_last_half
     double layers at the published widths with 16 of 64 heads and 8 of 512
     experts held): in place on the 1.61 GB ring, its arguments the state plus
     6.6 GB of weights. The eight latent attentions turn interleaved rotary
-    pairs, which the window kernel does not, so their cores are the einsums
-    in query blocks of 512: nothing of a window's ``2048,2048`` square, at 16
-    heads or at one, is among the step's temporaries. Of the last layer's
+    pairs, which the window kernel does not; since PR 69 the seven whole
+    cores are ``_latent_block_attention`` (ops/pallas/block_attention.py,
+    which turns the pairing it is told) once an attention under
+    ``head/attn/[01]/core``, on ``Wq_b``'s float32 result as the product left
+    it: nothing of a window's ``2048,2048`` square, at 16 heads or at one,
+    and no ``[.., 512, 2048]`` float32 scores of a query block are among the
+    step's temporaries. The last one's one query a row stays einsums. Of the
+    last layer's
     second attention the ``K, V`` products meet all 4,096 positions and no
     other product of its scope does; the expert branch's scopes are all
     there, the held experts' products ``ragged-dot`` (6144 x 2048 slots:
@@ -985,8 +990,8 @@ def test_double_layer_step_holds_no_square_of_a_window_and_narrows_its_last_half
                              batch=batch)
     said = decoder_parts.announced_cores()
     assert said["attention core"] == (
-        "xla-einsum in query blocks of 512 (window 2048, 16 heads) "
-        "(interleaved rotary pairs: the window kernel turns by halves) "
+        "pallas-blocks (latent, 16 heads of 128 + 64 / 128, interleaved rotary "
+        "pairs, window 2048 in blocks of 512, band=None: 10 of 16 key blocks) "
         "(backend=tpu)")
     assert said["attention core (narrowed)"].startswith(
         "xla-einsum, one query a row (window 2048, 16 heads")
@@ -1000,9 +1005,30 @@ def test_double_layer_step_holds_no_square_of_a_window_and_narrows_its_last_half
     assert _ring_sized(compiled, ring) == []
     assert mem.alias_size_in_bytes >= 4 * ring, mem
     assert 8.1e9 < mem.argument_size_in_bytes < 8.4e9, mem
-    assert mem.temp_size_in_bytes < 0.9e9, mem  # 0.68 GB at PR 68
+    # 0.58 GB since PR 69 (0.68 at PR 68, whose einsum cores stood a query
+    # block's float32 scores in HBM)
+    assert mem.temp_size_in_bytes < 0.8e9, mem
     text = compiled.as_text()
     positions = batch * 2048
+    # the seven whole cores: one kernel an attention, on the projections'
+    # results as they lie (q float32, kvb and k_rope rounded)
+    cores = [line for line in _kernels_under(text, capsys, "longcat",
+                                             scope="head/attn")
+             if re.match(r"\s*%_latent_block_attention(\.\d+)? = ", line)]
+    assert len(cores) == 2 * cfg.layers - 1, cores
+    qk, kvw = cfg.nope_dim + cfg.rope_dim, cfg.nope_dim + cfg.v_dim
+    for line in cores:
+        assert re.search(r"head/attn/[01]/core", line), line[:300]
+        assert re.match(rf"\s*%_latent_block_attention(\.\d+)? = "
+                        rf"bf16\[{positions},{cfg.heads * cfg.v_dim}\]", line), line[:200]
+        for operand in (f"f32[{positions},{cfg.heads * qk}]",
+                        f"bf16[{positions},{cfg.heads * kvw}]",
+                        f"bf16[{positions},{cfg.rope_dim}]"):
+            assert operand in line, (operand, line[:400])
+    assert sum("head/attn/0/core" in c for c in cores) == cfg.layers
+    assert sum("head/attn/1/core" in c for c in cores) == cfg.layers - 1
+    # no query block's scores against its window's keys stand in HBM
+    assert not re.search(r"f32\[(\d+,)*512,2048\]", text)
     # no [heads, t, t] array of a whole window, nor one head's square
     # (a pass of the held experts is 2,048 rows of width 2,048: not a window's)
     assert not re.search(r"\[(\d+,)+2048,2048\]", text)

@@ -184,7 +184,8 @@ def test_smoke_kernels_phase_tiny_interpreted():
         backward_shape=(1, 2, 64, 16), gbdt_batch=256, gbdt_tile=128,
         expert_shape=(512, 1024, 128, 8), second_shape=(256, 2048, 128, 4, 1),
         share_shape=(64, 128, 32, 20), grouped_windows=11, delta_windows=8,
-        ssd_windows=8, stream_tiles=1, block_window=40, scan_window=24)
+        ssd_windows=8, stream_tiles=1, block_window=40, scan_window=24,
+        latent_window=40)
     assert report["interpret"] is True
     # the selective scan (phi4flash's Mamba-1 mixer) on two windows of 24
     # positions, three blocks of eight, against the chunked form
@@ -203,6 +204,14 @@ def test_smoke_kernels_phase_tiny_interpreted():
             "attention core (" + ("window" if kind.startswith("sliding") else "full")
             + "): einsum in query blocks (window 40 in blocks of 48")
         assert said["core"].endswith("not a TPU) (backend=cpu)")
+    # the blocked latent core (longcat's attention) on one window of 40
+    # positions: one query block of 48 there, so what a trace picks is the
+    # one-block core
+    latent = report["latent_block_attention_T40"]
+    assert latent["max_err"] <= chip_smoke.BACKBONE_TOL
+    assert latent["core"] == (
+        "attention core: xla-einsum (interleaved rotary pairs: the window "
+        "kernel turns by halves) (backend=cpu)")
     assert report["gbdt_vs_gather"] <= chip_smoke.GBDT_TOL
     assert max(report["grouped_experts_M512_E8"]) <= chip_smoke.EXPERTS_TOL
     # how the kernels are fed: hidden 1024 is gathered outside, hidden 2048

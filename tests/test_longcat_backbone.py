@@ -389,6 +389,44 @@ def test_the_scopes_and_the_cores_said(small):
         "pairs) (backend=cpu)")
 
 
+@pytest.mark.parametrize("backend,heads,window,said", [
+    # the cell's windows on a chip: the blocked kernel, told the pairing
+    ("tpu", 16, 2048,
+     "pallas-blocks (latent, 16 heads of 128 + 64 / 128, interleaved rotary "
+     "pairs, window 2048 in blocks of 512, band=None: 10 of 16 key blocks)"),
+    # off it, and where the kernel declines: the einsums in query blocks,
+    # with the reason
+    ("cpu", 16, 2048,
+     "xla-einsum in query blocks of 512 (window 2048, 16 heads; not a TPU)"),
+    ("tpu", 15, 2048,
+     "xla-einsum in query blocks of 512 (window 2048, 15 heads; 15 heads are "
+     "not whole units of 2)"),
+    # a window that fits one block: what every latent head ran before
+    ("tpu", 16, 16,
+     "xla-einsum (interleaved rotary pairs: the window kernel turns by halves)"),
+    ("tpu", 16, 512,
+     "xla-einsum (interleaved rotary pairs: the window kernel turns by halves)"),
+])
+def test_the_core_is_picked_from_the_windows_depth_and_the_shapes(
+        monkeypatch, backend, heads, window, said):
+    """``latent_attention_core`` at the published widths, from shapes alone:
+    a window deeper than one block takes the blocked kernel on a TPU where
+    it takes the operands, and says which core a compile took either way."""
+    from igaming_platform_tpu.ops.pallas import block_attention as ba
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = lb.LongcatConfig(heads=heads)
+    p = 2 * window
+    q = jax.ShapeDtypeStruct((p, heads * (cfg.nope_dim + cfg.rope_dim)), jnp.float32)
+    kvb = jax.ShapeDtypeStruct((p, heads * (cfg.nope_dim + cfg.v_dim)), jnp.bfloat16)
+    core = dp.latent_attention_core(q, kvb, cfg, window, interleave=True)
+    assert dp.announced_cores()["attention core"] == f"{said} (backend={backend})"
+    kernel = said.startswith("pallas-blocks")
+    assert core.func is (ba.latent_block_attention if kernel
+                         else dp.latent_core_by_einsums)
+    assert core.keywords["interleave"] is True and core.keywords["window"] == window
+
+
 def test_the_row_of_heads_and_what_it_holds():
     import math
 
