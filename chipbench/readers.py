@@ -96,13 +96,15 @@ def trace_program_ms(m: dict, r: Readings):
 
 
 def _cost(m: dict, r: Readings):
-    return getattr(validate.load_code("costs", m["cost"], r.root), m["cost"])
+    name = validate.cost_name(m, r.config)
+    return getattr(validate.load_code("costs", name, r.root), name)
 
 
 def _roofline_share(m: dict, r: Readings, runs: int, measured_ns: float):
     """The least time the chip could take for ``runs`` executions (the
     larger of operations over peak FLOP/s and bytes over peak bytes/s,
-    from the file under ``chipbench/costs/`` that the metric names), over
+    from the file under ``chipbench/costs/`` that the metric names, or that
+    the cell's configuration names where the metric leaves it to it), over
     their measured device time. The padded batch of each execution is
     read from its name's row count where the harness recorded how many
     executions each padded shape had; the shares are weighted by those

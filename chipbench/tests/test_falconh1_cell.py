@@ -12,10 +12,11 @@ from chipbench import reference, validate
 CONFIG = "risk-seqhead-falcon-h1-34b"
 CELL = "falconh1-ssm-insession"
 BATCH = 256  # the cell's one rung
-METRICS = {"falconh1_step_ms", "falconh1_step_roofline", "ssm_mixer_ms",
-           "ssm_mixer_roofline", "ssm_scan_ms", "falconh1_attention_ms",
-           "falconh1_mlp_ms", "falconh1_mlp_roofline",
-           "falconh1_real_position_share"}
+# six since PR 70: the step's time and roofline share and the share of real
+# positions are read under the names every cell reads them by
+METRICS = {"ssm_mixer_ms", "ssm_mixer_roofline", "ssm_scan_ms",
+           "falconh1_attention_ms", "falconh1_mlp_ms", "falconh1_mlp_roofline"}
+SHARED = {"device_step_ms", "device_step_roofline", "head_real_position_share"}
 
 
 def test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut():
@@ -50,16 +51,17 @@ def test_the_falconh1_configuration_is_held_to_its_source_and_states_its_cut():
     assert spec["traffic"]["name"] == "index-insession"
     assert spec["cell"]["chips"] == 1
     names = {m["name"] for m in spec["per_layer"]}
-    assert names >= METRICS
+    assert names >= METRICS | SHARED
     assert not {n for n in names if n.startswith(("lfm2_", "mla_", "moe_"))}
+    assert cfg["step_cost"] == "falconh1_backbone_step"
     manifest = validate.load_manifest()
     mine = [m for m in manifest["per_layer"] if m["name"] in METRICS]
     assert all(m["workloads"] == [CELL] and m["moves"] == "txns_per_s"
-               for m in mine) and len(mine) == 9
+               for m in mine) and len(mine) == 6
     # appended at the end of their lists
     assert manifest["configs"][-1]["name"] == CONFIG
     assert manifest["workloads"][-1]["name"] == CELL
-    assert {m["name"] for m in manifest["per_layer"][-9:]} == METRICS
+    assert {m["name"] for m in manifest["per_layer"][-6:]} == METRICS
 
 
 @pytest.mark.parametrize("key,value,needle", [

@@ -44,12 +44,16 @@ def test_the_configuration_is_held_to_its_source_and_states_its_cut():
     spec = validate.load_cell(CELL)
     assert spec["traffic"]["name"] == "index-insession"
     names = {m["name"] for m in spec["per_layer"]}
-    assert names >= {"lfm2_step_ms", "lfm2_step_roofline", "shortconv_ms",
-                     "shortconv_roofline", "lfm2_experts_ms",
+    # the step's time, its roofline share and the share of real positions
+    # under the names every cell reads them by (PR 70)
+    assert names >= {"shortconv_ms", "shortconv_roofline", "lfm2_experts_ms",
                      "lfm2_experts_roofline", "lfm2_attention_ms",
-                     "lfm2_real_position_share", "lfm2_dense_mlp_ms",
-                     "lfm2_route_ms"}
-    assert "head_real_position_share" not in names and "moe_experts_ms" not in names
+                     "lfm2_dense_mlp_ms", "device_step_ms",
+                     "device_step_roofline", "head_real_position_share"}
+    assert cfg["step_cost"] == "lfm2_backbone_step"
+    assert "moe_experts_ms" not in names
+    assert not names & {"lfm2_step_ms", "lfm2_step_roofline",
+                        "lfm2_real_position_share", "lfm2_route_ms"}
 
 
 @pytest.mark.parametrize("key,value,needle", [

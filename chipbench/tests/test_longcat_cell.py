@@ -15,11 +15,15 @@ from chipbench.tests.test_phi4flash_cell import _traced
 CONFIG = "risk-seqhead-longcat-flash-omni"
 CELL = "longcat-scmoe-deep2048"
 BATCH = 2  # the cell's one rung
-# eight, not ISSUE 68's fourteen: ``per_layer`` may hold 128 and held 120
-METRICS = ["longcat_step_ms", "longcat_step_roofline", "longcat_attention_ms",
-           "longcat_attention_roofline", "longcat_attention_core_ms",
-           "longcat_moe_branch_ms", "longcat_experts_ms",
+# eight, not ISSUE 68's fourteen: ``per_layer`` may hold 128 and held 120.
+# Since PR 70 the step's time and roofline share are ``device_step_ms`` and
+# ``device_step_roofline`` here as in every cell, and their two places hold
+# the dense MLPs and the router, two of the six PR 68 left out
+METRICS = ["longcat_attention_ms", "longcat_attention_roofline",
+           "longcat_attention_core_ms", "longcat_dense_mlp_ms",
+           "longcat_moe_branch_ms", "longcat_route_ms", "longcat_experts_ms",
            "longcat_zero_experts_ms"]
+STEP = ["device_step_ms", "device_step_roofline"]
 REDUCED = ["num_layers", "n_routed_experts", "num_attention_heads", "chips",
            "store_accounts", "store_loaded_accounts"]
 ASSUMED = ("router", "rotary", "latent_scales", "identity_experts",
@@ -95,7 +99,8 @@ def test_the_longcat_configuration_is_held_to_its_source_and_states_its_cut():
     assert spec["cell"]["chips"] == 1
     assert spec["cell"]["traffic"] == "index-deepreview"
     names = {m["name"] for m in spec["per_layer"]}
-    assert names >= set(METRICS)
+    assert names >= set(METRICS + STEP)
+    assert cfg["step_cost"] == "longcat_backbone_step"
     assert not {n for n in names if n.startswith(
         ("lfm2_", "mla_", "moe_", "falconh1_", "ssm_", "ling_", "kda_", "xing_",
          "hc_", "backbone_", "head_", "mellum_", "phi4flash_", "selective_scan_",
@@ -234,21 +239,24 @@ def test_the_longcat_step_holds_its_parts_by_hand():
 
 def test_the_longcat_metric_files_load_and_name_their_readers():
     spec = validate.load_cell(CELL)
-    mine = {m["name"]: m for m in spec["per_layer"] if m["name"] in METRICS}
-    assert set(mine) == set(METRICS)
+    mine = {m["name"]: m for m in spec["per_layer"] if m["name"] in METRICS + STEP}
+    assert set(mine) == set(METRICS + STEP)
     for m in mine.values():
         assert m["reader"] in readers.READERS
         if "cost" in m:
-            assert callable(getattr(validate.load_code("costs", m["cost"]), m["cost"]))
+            file = validate.cost_name(m, spec["config"])
+            assert callable(getattr(validate.load_code("costs", file), file))
     for name, pattern in (("longcat_attention_ms", "head/attn/[01]"),
                           ("longcat_attention_core_ms", "head/attn/[01]/core"),
+                          ("longcat_dense_mlp_ms", "head/mlp/dense"),
+                          ("longcat_route_ms", "head/moe/route"),
                           ("longcat_moe_branch_ms", "head/moe/|ragged-dot"),
                           ("longcat_experts_ms", "head/moe/experts|ragged-dot"),
                           ("longcat_zero_experts_ms", "head/moe/zero")):
         assert mine[name]["pattern"] == pattern
-    for name, file in (("longcat_step_roofline", "longcat_backbone_step"),
+    for name, file in (("device_step_roofline", "longcat_backbone_step"),
                        ("longcat_attention_roofline", "longcat_latent_attention")):
-        assert mine[name]["cost"] == file
+        assert validate.cost_name(mine[name], spec["config"]) == file
 
 
 def test_the_longcat_metrics_read_a_recorded_trace():
@@ -256,7 +264,7 @@ def test_the_longcat_metrics_read_a_recorded_trace():
     that has no such scopes (the parent's) each reader but the step's returns
     nothing and raises nothing."""
     spec = validate.load_cell(CELL)
-    mine = {m["name"]: m for m in spec["per_layer"] if m["name"] in METRICS}
+    mine = {m["name"]: m for m in spec["per_layer"] if m["name"] in METRICS + STEP}
     ms = 1_000_000
 
     def attention(i: int, core_ms: float) -> list:
@@ -280,7 +288,7 @@ def test_the_longcat_metrics_read_a_recorded_trace():
                          pad_rows={2: 5}, device_kind="TPU v5 lite",
                          trace=_traced(ops), trace_window=(0, 10**12))
     got = readers.read_all(list(mine.values()), r, lambda line: None)
-    assert set(got) == set(METRICS)
+    assert set(got) == set(METRICS + STEP)
     value = lambda name: got[name]["value"]
     attention_ms = 7 * 1.5 + 1.125
     assert value("longcat_attention_ms") == pytest.approx(attention_ms)
@@ -288,12 +296,14 @@ def test_the_longcat_metrics_read_a_recorded_trace():
     assert value("longcat_moe_branch_ms") == pytest.approx(4 * 1.875)
     assert value("longcat_experts_ms") == pytest.approx(4 * 1.25)
     assert value("longcat_zero_experts_ms") == pytest.approx(4 * 0.125)
+    assert value("longcat_dense_mlp_ms") == pytest.approx(7 * 12 + 0.01)
+    assert value("longcat_route_ms") == pytest.approx(4 * 0.5)
     step_ms = attention_ms + 4 * 1.875 + 7 * 12 + 0.01 + 2 + 0.001
-    assert value("longcat_step_ms") == pytest.approx(step_ms)
+    assert value("device_step_ms") == pytest.approx(step_ms)
     # a share of a roofline is the cost file's least time over the time read
     assert value("longcat_attention_roofline") == pytest.approx(
         100 * 11.186 / attention_ms, abs=0.05)
-    assert value("longcat_step_roofline") == pytest.approx(
+    assert value("device_step_roofline") == pytest.approx(
         100 * 78.30 / step_ms, abs=0.05)
     # the parent's program has no such scopes
     bare = readers.Readings(config=spec["config"], rows_ok=10, stages={},
@@ -301,4 +311,4 @@ def test_the_longcat_metrics_read_a_recorded_trace():
                             trace=_traced([("fusion.1", "jit(_body)/head/ssm/scan", ms)]),
                             trace_window=(0, 10**12))
     got = readers.read_all(list(mine.values()), bare, lambda line: None)
-    assert set(got) == {"longcat_step_ms", "longcat_step_roofline"}
+    assert set(got) == set(STEP)

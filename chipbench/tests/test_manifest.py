@@ -146,11 +146,12 @@ def test_the_deep_window_configuration_is_keyes_at_128_events_and_states_its_cut
     spec = validate.load_cell(DEEP_CELL)
     assert spec["traffic"]["name"] == "index-insession" and spec["cell"]["chips"] == 1
     names = {m["name"] for m in spec["per_layer"]}
-    assert names >= {"backbone_step_ms", "backbone_step_roofline", "moe_experts_ms",
+    assert names >= {"device_step_ms", "device_step_roofline", "moe_experts_ms",
                      "moe_experts_roofline", "head_attention_ms",
                      "head_real_position_share", "rpc_over_50ms_share"}
     # the step the cell runs is priced at its own window: 64 rows of 128 events
-    cost = validate.load_code("costs", "keye_backbone_step").keye_backbone_step
+    assert cfg["step_cost"] == keye["step_cost"] == "keye_backbone_step"
+    cost = validate.load_code("costs", cfg["step_cost"]).keye_backbone_step
     deep, flat = cost(cfg, 64, index_mode=True), cost(keye, 256, index_mode=True)
     assert 3.9e12 < deep["flops"] < 4.0e12 and 1.9e12 < flat["flops"] < 2.0e12
 
@@ -171,15 +172,15 @@ def test_the_mesh_configuration_is_cell_ones_on_four_chips_and_states_its_cut():
     spec = validate.load_cell(MESH_CELL)
     assert spec["traffic"]["name"] == "index-flatout" and spec["cell"]["chips"] == 4
     names = {m["name"] for m in spec["per_layer"]}
-    assert names >= {"sharded_step_ms", "sharded_step_roofline",
+    assert names >= {"device_step_ms", "device_step_roofline",
                      "state_lookup_us_per_row", "cache_hit_share"}
-    assert not names & {"device_step_ms", "fused_step_roofline"}
+    # the step every cell reads is priced here as the slot-sharded one
+    assert cfg["step_cost"] == "sharded_step" and one["step_cost"] == "fused_step"
     manifest = validate.load_manifest()
     four = [w["name"] for w in manifest["workloads"] if w["chips"] == 4]
     assert four == [MESH_CELL] and len(manifest["workloads"]) // 4 >= 1
-    for m in manifest["per_layer"]:
-        if m["name"].startswith("sharded_step"):
-            assert m["workloads"] == [MESH_CELL]
+    assert not [m["name"] for m in manifest["per_layer"]
+                if m["name"].startswith("sharded_step")]
 
 
 def test_a_chip_of_the_mesh_scores_the_whole_batch_and_receives_the_other_shards_rows():
@@ -231,3 +232,94 @@ def test_a_short_preload_may_stand_in_reduced(copy):
     cfg["session_events_preloaded"] = {"events": "1-8", "rounds": 2}
     path.write_text(json.dumps(cfg))
     assert validate.check_manifest(str(copy)) == []
+
+
+# -- PR 70: the step's time and roofline share as one metric each ---------------
+
+STEP = ("device_step_ms", "device_step_roofline")
+RETIRED = {"dispatches_per_chunk", "lfm2_route_ms", "fused_step_roofline",
+           "backbone_step_ms", "backbone_step_roofline", "mla_step_ms",
+           "mla_step_roofline", "sharded_step_ms", "sharded_step_roofline",
+           "lfm2_step_ms", "lfm2_step_roofline", "falconh1_step_ms",
+           "falconh1_step_roofline", "longcat_step_ms", "longcat_step_roofline",
+           "lfm2_real_position_share", "falconh1_real_position_share"}
+CELLS = {w["name"]: w for w in validate.load_manifest()["workloads"]}
+CONFIGS = sorted({w["config"] for w in CELLS.values()})
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_every_cell_reads_the_step_under_the_two_shared_names(cell):
+    spec = validate.load_cell(cell)
+    names = [m["name"] for m in spec["per_layer"]]
+    assert [names.count(n) for n in STEP] == [1, 1]
+    assert not set(names) & RETIRED
+    # how many positions were real is said under one name or the family's,
+    # never both, and not at all where the head counts no positions
+    shares = [n for n in names if n.endswith("real_position_share")]
+    assert len(shares) <= (0 if validate.head_name(spec["config"])
+                           in ("pattern", "transformer") else 1), shares
+
+
+def test_the_manifest_holds_114_entries_and_the_two_have_no_list():
+    manifest = validate.load_manifest()
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert len(names) == 114 and not set(names) & RETIRED
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert all("workloads" not in by_name[n] for n in STEP)
+    assert by_name["head_real_position_share"]["workloads"] == [
+        "keye-backbone-insession", "keye-deep128-insession",
+        "lfm2-conv-insession", "falconh1-ssm-insession"]
+    # a retired entry took its file with it
+    assert {f[:-5] for f in os.listdir(os.path.join(
+        ROOT, "chipbench", "layer_metrics"))} == set(names)
+    roofline = validate.load_data("layer_metrics", "device_step_roofline")
+    assert roofline["cost"] == validate.STEP_COST == {"config": "step_cost"}
+    # a metric that names its own file keeps it, whatever the configuration
+    assert validate.cost_name({"cost": "fused_step"},
+                              {"step_cost": "sharded_step"}) == "fused_step"
+    assert "cost" not in validate.load_data("layer_metrics", "device_step_ms")
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_a_configurations_step_cost_prices_its_smallest_rung_above_zero(config):
+    cfg = validate.load_data("configs", config)
+    name = cfg["step_cost"]
+    cost = getattr(validate.load_code("costs", name), name)
+    roofline = validate.load_data("layer_metrics", "device_step_roofline")
+    assert validate.cost_name(roofline, cfg) == name
+    for cell in (w for w in CELLS.values() if w["config"] == config):
+        rows = validate.load_data("traffic", cell["traffic"])["rows"]
+        rung = min(min(rows), int(cfg["env"]["BATCH_SIZE"]))
+        priced = cost(cfg, rung, index_mode=True)
+        assert priced["flops"] > 0 and priced["bytes"] > 0, (cell["name"], rung)
+        more = cost(cfg, 2 * rung, index_mode=True)
+        assert more["flops"] > priced["flops"] and more["bytes"] > priced["bytes"]
+
+
+@pytest.mark.parametrize("change,needle", [
+    (lambda c: c.pop("step_cost"), "missing keys ['step_cost']"),
+    (lambda c: c.update(step_cost="absent_step"),
+     "step_cost: no file chipbench/costs/absent_step.py"),
+    (lambda c: c.update(step_cost="empty_step"),
+     "step_cost: chipbench/costs/empty_step.py does not define ['empty_step']"),
+    (lambda c: c.update(step_cost={"config": "step_cost"}), "step_cost: costs name"),
+], ids=["no-key", "no-file", "no-function", "not-a-name"])
+def test_a_configuration_without_a_cost_for_its_step_is_refused(copy, change,
+                                                                needle):
+    (copy / "chipbench" / "costs" / "empty_step.py").write_text("x = 1\n")
+    assert validate.check_manifest(str(copy)) == []
+    path = copy / "chipbench" / "configs" / "risk-seqhead-lfm2-24b-a2b.json"
+    cfg = json.loads(path.read_text())
+    change(cfg)
+    path.write_text(json.dumps(cfg))
+    errors = validate.check_manifest(str(copy))
+    assert [e for e in errors if needle in e
+            and "risk-seqhead-lfm2-24b-a2b.json" in e], errors
+
+
+def test_a_metric_leaves_its_cost_to_the_configuration_in_one_way(copy):
+    path = copy / "chipbench" / "layer_metrics" / "device_step_roofline.json"
+    metric = json.loads(path.read_text())
+    path.write_text(json.dumps(dict(metric, cost={"config": "head"})))
+    errors = validate.check_manifest(str(copy))
+    assert any("device_step_roofline.json: cost:" in e for e in errors), errors

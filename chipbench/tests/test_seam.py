@@ -70,7 +70,7 @@ def root(copy):
                    "published": {"d_model": 8, "num_hidden_layers": 4},
                    "deployment": "one layer of four"}
     (base / "configs" / "risk-stateful-5m-toy.json").write_text(json.dumps(cfg))
-    metric = json.loads((base / "layer_metrics" / "fused_step_roofline.json").read_text())
+    metric = json.loads((base / "layer_metrics" / "device_step_roofline.json").read_text())
     metric.update(name="toy_step_roofline", cost="toy_step")
     (base / "layer_metrics" / "toy_step_roofline.json").write_text(json.dumps(metric))
     with open(tmp_path / "BENCHMARK.json") as f:
@@ -80,7 +80,7 @@ def root(copy):
                          "reduced": cfg["reduced"], "why": "a head of its own"})
     m["workloads"].append({"name": "toy-index-flatout", "config": cfg["name"],
                            "traffic": "index-flatout", "chips": 1, "why": "w"})
-    entry = next(x for x in m["per_layer"] if x["name"] == "fused_step_roofline")
+    entry = next(x for x in m["per_layer"] if x["name"] == "device_step_roofline")
     m["per_layer"].append(dict(entry, name="toy_step_roofline",
                                workloads=["toy-index-flatout"]))
     with open(tmp_path / "BENCHMARK.json", "w") as f:

@@ -84,7 +84,7 @@ def test_recorded_v5e_slice_reduces():
     from chipbench import validate
     pattern = validate.load_data("layer_metrics", "device_step_ms")["pattern"]
     assert pattern == validate.load_data(
-        "layer_metrics", "fused_step_roofline")["pattern"]
+        "layer_metrics", "device_step_roofline")["pattern"]
     other = trace_reduce.Trace(programs={0: [("jit_sync(7)", window[0], 10)]})
     assert not trace_reduce.program_executions(other, pattern, window)
     runs = trace_reduce.program_executions(trace, pattern, window)
@@ -226,3 +226,46 @@ def test_one_operations_roofline_share_on_the_recorded_step(step):
     assert [s.split(":")[0] for s in said[1:]] == [
         "MISSING per-layer metric grouped_roofline",
         "MISSING per-layer metric grouped_ms"]
+
+
+# -- PR 70: the step's time and roofline share, one metric each ----------------
+
+@pytest.mark.parametrize("file,config,step_ms,runs", [
+    ("small_trace.json", "risk-stateful-5m-pattern", None, 0),
+    ("recorded_v5e_slice.json", "risk-stateful-5m-pattern", 28.1, 3),
+    ("recorded_v5e_seqhead_step.json", "risk-stateful-5m-seqhead", 1.367, 2),
+], ids=["hand-built", "slice", "seqhead-step"])
+def test_the_two_step_metrics_on_each_recorded_trace(file, config, step_ms, runs):
+    """``device_step_ms`` and ``device_step_roofline`` through their files
+    over every trace kept here: the figures the cases above hold, and for
+    the share what a metric that names the configuration's cost file
+    itself (as each retired ``<family>_step_roofline`` did) reads."""
+    from chipbench import peaks, validate
+    from chipbench.readers import read_all
+    files = [validate.load_data("layer_metrics", name)
+             for name in ("device_step_ms", "device_step_roofline")]
+    cfg = validate.load_data("configs", config)
+    trace = trace_reduce.load_json(os.path.join(DATA, file))
+    r = _readings(trace, config=cfg, device_kind="TPU v5 lite",
+                  pad_rows={256: 9})
+    said = []
+    got = read_all(files, r, said.append)
+    if step_ms is None:
+        # no program of the step's name ran: nothing, never 0
+        assert got == {} and len(said) == 2 and all("MISSING" in s for s in said)
+        return
+    assert len(trace_reduce.program_executions(
+        trace, files[0]["pattern"], r.trace_window)) == runs
+    assert got["device_step_ms"] == {
+        "value": pytest.approx(step_ms, abs=0.002 * step_ms), "unit": "ms"}
+    name = cfg["step_cost"]
+    c = getattr(validate.load_code("costs", name), name)(cfg, 256, index_mode=True)
+    p = peaks.peaks_for("TPU v5 lite")
+    least = max(c["flops"] / p["flops_per_s"], c["bytes"] / p["bytes_per_s"])
+    share = got["device_step_roofline"]["value"]
+    assert share == pytest.approx(
+        100.0 * least / (got["device_step_ms"]["value"] / 1e3))
+    assert 0.0 < share <= 100.0
+    clone = dict(files[1], name="fused_step_roofline", cost=name)
+    assert READERS["trace_roofline_share"](clone, r) == share
+    assert said == ["device_step_roofline is bound by bytes"]

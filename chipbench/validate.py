@@ -6,8 +6,10 @@ that belongs to one of them sits in a file of its own under
 ``layer_metrics/<metric>.json``) which this module finds by name. So
 does the code a configuration or a metric brings: the plain reference of
 a session head (``heads/<name>.py``) and the operations and bytes of a
-kernel (``costs/<name>.py``). A later PR adds a cell by adding files and
-entries, never by editing one.
+kernel (``costs/<name>.py``). A configuration names the cost of its whole
+step under ``step_cost``, so the step's roofline share is one metric that
+every cell reads. A later PR adds a cell by adding files and entries,
+never by editing one.
 
 A configuration taken from a published source holds that source's keys
 at its own top level, under the source's names (``source_keys`` lists
@@ -47,8 +49,8 @@ _ENTRY_KEYS = {
 _CONFIG_KEYS = {"name", "source", "chips", "resident_accounts", "ml_backend",
                 "trunk", "env", "fill_chunk", "store_accounts",
                 "store_loaded_accounts", "session_events_preloaded",
-                "precision", "guarantees", "reduced", "reduced_why", "assumed",
-                "limits"}
+                "step_cost", "precision", "guarantees", "reduced",
+                "reduced_why", "assumed", "limits"}
 # A configuration may bring its session head: ``{"reference": <name of a
 # file under heads/>, ...}``. The other keys are that head's own (the
 # source's value of each reduced key under ``published``, the
@@ -64,6 +66,12 @@ CODE_DEFINES = {"heads": lambda name: ("make_params", "forward"),
 _TRAFFIC_KEYS = {"name", "loop", "clients", "rpc", "rows", "pool_frames",
                  "accounts", "tx_types", "amounts", "check"}
 _LAYER_KEYS = {"name", "layer", "unit", "better", "source", "moves", "reader"}
+# A roofline metric's ``cost`` names its file under ``costs/``, or is this:
+# the cell's configuration names the file, under ``step_cost``. The whole
+# step's cost differs by configuration and by nothing else, so one metric
+# (``device_step_roofline``) reads it in every cell, and a new
+# configuration brings its step's roofline as its own file and a cost file.
+STEP_COST = {"config": "step_cost"}
 # Parameters each generic reader takes (chipbench/readers.py).
 READER_PARAMS = {
     "hostprof_us_per_row": {"stages"},
@@ -131,6 +139,13 @@ def head_name(config: dict) -> str:
     and without one the value of ``SESSION_HEAD`` (default ``pattern``)."""
     return (config.get("head", {}).get("reference")
             or config.get("env", {}).get("SESSION_HEAD", "pattern"))
+
+
+def cost_name(metric: dict, config: dict) -> str:
+    """The file under ``costs/`` that prices this metric in a cell of this
+    configuration."""
+    return (config["step_cost"] if metric["cost"] == STEP_COST
+            else metric["cost"])
 
 
 def load_cell(workload: str, root: str = ROOT) -> dict:
@@ -467,6 +482,11 @@ def check_manifest(root: str = ROOT) -> list[str]:
                 load_code("heads", head_name(data), root)
             except (OSError, ValueError, AttributeError, SyntaxError) as exc:
                 errors.append(f"{path}: session head: {exc}")
+        if "step_cost" in data:
+            try:
+                load_code("costs", str(data["step_cost"]), root)
+            except (OSError, ValueError, AttributeError, SyntaxError) as exc:
+                errors.append(f"{path}: step_cost: {exc}")
         if data.get("name") != name:
             errors.append(f"{path}: name {data.get('name')!r} != {name!r}")
         if data.get("source") != c.get("source"):
@@ -580,7 +600,7 @@ def check_manifest(root: str = ROOT) -> list[str]:
                           f"{sorted(READER_PARAMS)}")
         else:
             _check_keys(errors, lpath, d, _LAYER_KEYS, READER_PARAMS[reader])
-        if "cost" in d:
+        if "cost" in d and d["cost"] != STEP_COST:
             try:
                 load_code("costs", str(d["cost"]), root)
             except (OSError, ValueError, AttributeError, SyntaxError) as exc:
